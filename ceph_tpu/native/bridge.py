@@ -41,6 +41,41 @@ _LIB_SRCS = ("gf256.cc", "rs.cc", "registry.cc", "capi.cc", "crc32c.cc",
              "wirepath.cc")
 
 
+def _host_fingerprint() -> str:
+    """What `-march=native` resolved against: the CPU's feature flags.  A
+    .so built on another CPU (a copied tree, a shared volume) may use
+    instructions this one traps on (SIGILL, not an exception), so the
+    build is stamped with this and rebuilt on a mismatch."""
+    import hashlib
+    import platform
+
+    flags = platform.machine() + platform.processor()
+    try:
+        with open("/proc/cpuinfo") as f:
+            flags += next((ln for ln in f if ln.startswith("flags")), "")
+    except OSError:
+        pass
+    return hashlib.sha1(flags.encode()).hexdigest()
+
+
+def _up_to_date(out: str, deps) -> bool:
+    """`out` exists, is newer than every dep, and was built on this CPU."""
+    try:
+        with open(out + ".host") as f:
+            if f.read() != _host_fingerprint():
+                return False
+        lib_mtime = os.path.getmtime(out)
+    except OSError:
+        return False
+    return all(os.path.getmtime(d) <= lib_mtime
+               for d in deps if os.path.exists(d))
+
+
+def _stamp(out: str) -> None:
+    with open(out + ".host", "w") as f:
+        f.write(_host_fingerprint())
+
+
 def build(force: bool = False, sanitize: Optional[bool] = None) -> str:
     """Compile the native library (idempotent; rebuilds when any source
     is newer than the .so, so an old build can never miss symbols the
@@ -57,14 +92,11 @@ def build(force: bool = False, sanitize: Optional[bool] = None) -> str:
     srcs = [os.path.join(_NATIVE, f) for f in _LIB_SRCS]
     out = os.path.join(_BUILD, "sanitize", "libceph_tpu_ec.so") \
         if sanitize else _LIB
-    if os.path.exists(out) and not force:
-        lib_mtime = os.path.getmtime(out)
-        hdrs = [os.path.join(_NATIVE, f)
-                for f in ("gf256.h", "rs.h", "ec_api.h", "plugin_common.h",
-                          "wirepath.h")]
-        if all(os.path.getmtime(s) <= lib_mtime
-               for s in srcs + hdrs if os.path.exists(s)):
-            return out
+    hdrs = [os.path.join(_NATIVE, f)
+            for f in ("gf256.h", "rs.h", "ec_api.h", "plugin_common.h",
+                      "wirepath.h")]
+    if not force and _up_to_date(out, srcs + hdrs):
+        return out
     os.makedirs(os.path.dirname(out), exist_ok=True)
     cmd = [
         "g++", "-std=c++17", "-O3", "-march=native", "-fPIC", "-shared",
@@ -81,6 +113,7 @@ def build(force: bool = False, sanitize: Optional[bool] = None) -> str:
             f"warnings from a newer compiler, set "
             f"CEPH_TPU_NATIVE_WERROR=0:\n"
             f"{(e.stderr or b'').decode(errors='replace')}") from e
+    _stamp(out)
     return out
 
 
@@ -473,11 +506,8 @@ def build_wirepy(force: bool = False) -> Optional[str]:
         return None
     srcs = [os.path.join(_NATIVE, f) for f in _WIREPY_SRCS]
     hdrs = [os.path.join(_NATIVE, "wirepath.h")]
-    if os.path.exists(_PYLIB) and not force:
-        lib_mtime = os.path.getmtime(_PYLIB)
-        if all(os.path.getmtime(s) <= lib_mtime
-               for s in srcs + hdrs if os.path.exists(s)):
-            return _PYLIB
+    if not force and _up_to_date(_PYLIB, srcs + hdrs):
+        return _PYLIB
     os.makedirs(os.path.dirname(_PYLIB), exist_ok=True)
     cmd = [
         "g++", "-std=c++17", "-O3", "-march=native", "-fPIC", "-shared",
@@ -491,6 +521,7 @@ def build_wirepy(force: bool = False) -> Optional[str]:
             f"warnings from a newer compiler, set "
             f"CEPH_TPU_NATIVE_WERROR=0:\n"
             f"{(e.stderr or b'').decode(errors='replace')}") from e
+    _stamp(_PYLIB)
     return _PYLIB
 
 

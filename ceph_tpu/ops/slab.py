@@ -89,12 +89,9 @@ def donate_enabled() -> bool:
         return False
     global _DONATE
     if _DONATE is None:
-        if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-            _DONATE = False
-        else:
-            from ceph_tpu.utils.jaxdev import probe_backend
+        from ceph_tpu.utils.jaxdev import accelerator_live
 
-            _DONATE = probe_backend() not in ("cpu", "unavailable")
+        _DONATE = accelerator_live()
     return _DONATE
 
 
@@ -155,9 +152,16 @@ def slab_install(slab, data, idx: np.ndarray):
     donation-annotated when the backend supports it.  Returns the NEW
     slab array; the caller must forget the old one (it may be freed).
     ``data`` is never donated (it may alias a shared batch product)."""
-    page_words = int(slab.shape[1])
     nb = bucket_rows(int(idx.shape[0]))
-    donate = donate_enabled()
+    idx = np.asarray(idx, dtype=np.int32)
+    data = jnp.asarray(data, dtype=jnp.uint32)
+    idx, data = _pad_rows(idx, data, nb)
+    fn = install_fn(int(slab.shape[1]), nb, donate_enabled())
+    return fn(slab, data, jnp.asarray(idx))
+
+
+def install_fn(page_words: int, nb: int, donate: bool):
+    """The jitted (LRU-cached) install kernel for one page geometry."""
 
     def build():
         def _install(s, d, i):
@@ -167,29 +171,25 @@ def slab_install(slab, data, idx: np.ndarray):
             return jax.jit(_install, donate_argnums=(0,))
         return jax.jit(_install)
 
-    idx = np.asarray(idx, dtype=np.int32)
-    data = jnp.asarray(data, dtype=jnp.uint32)
-    idx, data = _pad_rows(idx, data, nb)
-    fn = _kernel(("install", page_words, nb, donate), build)
-    return fn(slab, data, jnp.asarray(idx))
+    return _kernel(("install", page_words, nb, donate), build)
+
+
+def gather_fn(page_words: int, nb: int):
+    """The jitted (LRU-cached) gather kernel for one page geometry."""
+    return _kernel(("gather", page_words, nb),
+                   lambda: jax.jit(lambda s, i: s[i]))
 
 
 def slab_gather(slab, idx: np.ndarray):
     """Gather rows ``idx`` from the sub-slab as a fresh [n, page_words]
     device array (never a view — safe across later donated installs)."""
-    page_words = int(slab.shape[1])
     n = int(idx.shape[0])
     nb = bucket_rows(n)
     idx = np.asarray(idx, dtype=np.int32)
     if nb != n:
         idx = np.concatenate(
             [idx, np.full(nb - n, idx[-1], dtype=idx.dtype)])
-
-    def build():
-        return jax.jit(lambda s, i: s[i])
-
-    fn = _kernel(("gather", page_words, nb), build)
-    out = fn(slab, jnp.asarray(idx))
+    out = gather_fn(int(slab.shape[1]), nb)(slab, jnp.asarray(idx))
     return out if nb == n else out[:n]
 
 

@@ -42,8 +42,11 @@ DEVICE-DISPATCH CIRCUIT BREAKER (the robustness layer): every lane owns a
 breaker with three states.  CLOSED: dispatches go to the device; one that
 RAISES is rescued host-side (the group's futures resolve with
 byte-identical numpy GF(2) results — submitters never see the device
-die) and trips the lane OPEN; one that completes but exceeds
-``dispatch_timeout`` trips it after the fact.  OPEN: the lane's groups
+die), is LOGGED with its traceback (a compile refusal or OOM on a lane's
+first dispatch must not pass for a working device) and trips the lane
+OPEN; one that completes but exceeds ``dispatch_timeout`` — XLA compile
+seconds inside it not counted, a first compile is not a sick lane —
+trips it after the fact.  OPEN: the lane's groups
 are served by the CPU mirrors (``_cpu_apply_request``) until the
 cooldown elapses (doubling per consecutive trip, capped).  HALF-OPEN:
 one group re-probes the device; success closes the breaker, failure
@@ -72,6 +75,7 @@ seconds paid at admit()/read().
 from __future__ import annotations
 
 import collections
+import logging
 import threading
 import time
 from collections import OrderedDict
@@ -82,6 +86,8 @@ from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 import numpy as np
 
 from ceph_tpu.common.perf_counters import PerfCounters, PerfCountersBuilder
+
+log = logging.getLogger("ceph_tpu.ec.batch")
 
 #: the six dispatch lanes, in promotion order (int8 trio, packed-bit trio)
 LANES = ("packed", "planar", "resident",
@@ -102,6 +108,8 @@ def _build_ec_tpu_perf() -> PerfCounters:
       bytes                u64         bytes dispatched (incl. bucket padding)
       queue_wait           longrunavg  submit -> launch wait per request
       dispatch_dev         longrunavg  launch -> fan-out device seconds per dispatch
+      dispatch_compile     longrunavg  XLA compile seconds inside a dispatch
+      mesh_shard_failed    u64         batches the mesh could not lay out
       group_size           histogram   coalesced requests per dispatch (pow2 buckets)
       submit_group         u64         multi-item submit_group() calls (the
                                        whole-stripe-group handoff seam)
@@ -125,6 +133,12 @@ def _build_ec_tpu_perf() -> PerfCounters:
                           f"packed-equivalent bytes submitted on {lane}")
     b.add_time_avg("queue_wait", "submit -> launch coalescing wait")
     b.add_time_avg("dispatch_dev", "launch -> fan-out device time")
+    b.add_time_avg("dispatch_compile",
+                   "XLA compile seconds inside a dispatch (per dispatch "
+                   "that compiled; excluded from the watchdog)")
+    b.add_u64_counter("mesh_shard_failed",
+                      "batches the mesh could not lay out (served on one "
+                      "device instead)")
     b.add_histogram("group_size", "coalesced requests per dispatch")
     b.add_u64_counter("submit_group", "multi-item group submits")
     b.add_histogram("group_submit_size", "items per group submit")
@@ -293,6 +307,7 @@ class _Launched:
     t_launch: float
     span: Any = None  # child of a submitter's trace, or queue-tracer root
     wait_s: float = 0.0  # mean submit->launch wait across the group
+    compile_mark: float = 0.0  # worker-thread compile seconds at launch
 
 
 class BatchingQueue:
@@ -328,12 +343,15 @@ class BatchingQueue:
         # — the same compiled ops run SPMD over all devices, collectives
         # inserted by XLA where a consumer needs them.  mesh=None means
         # auto-detect; mesh=False pins the queue single-device (bench
-        # arms and n=1 dryruns that must not auto-engage).
+        # arms and single-device comparisons that must not auto-engage).
         if mesh is None:
             from ceph_tpu.parallel.mesh import shared_mesh
 
             mesh = shared_mesh()
         self.mesh = mesh or None
+        from ceph_tpu.utils.jaxdev import compile_meter
+
+        self._compiles = compile_meter()
         # the ec_tpu perf counter set (schema: _build_ec_tpu_perf).  The
         # legacy bare ints (submits/dispatches/bytes_dispatched/...) are
         # now read-only views over it — daemons add this set to their
@@ -836,6 +854,7 @@ class BatchingQueue:
                 self._complete_cpu(g, wait_s)
                 continue
             sp = self._dispatch_span(g)
+            compile_mark = self._compiles.thread_seconds()
             if self.inject_dispatch_delay:
                 # osd_debug_inject_dispatch_delay: counted into the
                 # dispatch elapsed (t_launch = now, above) so the
@@ -856,10 +875,15 @@ class BatchingQueue:
                     state = self._launch_packed(g)
                 if sp is not None:
                     sp.event("launched")
-                launched.append(_Launched(g, state, now, sp, wait_s))
+                launched.append(_Launched(g, state, now, sp, wait_s,
+                                          compile_mark))
             except Exception as e:
                 # device launch failure: trip the breaker and RESCUE the
-                # group host-side — submitters never see the device die
+                # group host-side — submitters never see the device die,
+                # the log does
+                log.error("ec batch launch failed on lane %s (%d requests); "
+                          "served from the CPU, breaker tripped", g.kind,
+                          len(g.requests), exc_info=e)
                 if sp is not None:
                     sp.event(f"launch failed: {type(e).__name__}")
                     sp.finish()
@@ -888,6 +912,9 @@ class BatchingQueue:
             except Exception as e:
                 # device completion failure: trip the breaker and rescue
                 # the group host-side (byte-identical CPU mirrors)
+                log.error("ec batch completion failed on lane %s (%d "
+                          "requests); served from the CPU, breaker tripped",
+                          g.kind, len(g.requests), exc_info=e)
                 if lc.span is not None:
                     lc.span.event(f"complete failed: {type(e).__name__}")
                     lc.span.finish()
@@ -895,10 +922,22 @@ class BatchingQueue:
                 self._complete_cpu(g, lc.wait_s)
                 continue
             device_s = time.monotonic() - lc.t_launch
-            if self.dispatch_timeout and device_s > self.dispatch_timeout:
+            # XLA compiles this thread ran since the launch (this
+            # dispatch's own first compile, or a later round's while this
+            # one was in flight) are host work, not evidence about the
+            # lane: the watchdog judges what is left
+            compile_s = self._compiles.thread_seconds() - lc.compile_mark
+            if compile_s > 0:
+                self.perf.tinc("dispatch_compile", compile_s)
+            if (self.dispatch_timeout
+                    and device_s - compile_s > self.dispatch_timeout):
                 # the dispatch COMPLETED (results are good) but blew the
                 # watchdog budget: the lane is sick — trip so the next
                 # groups take the CPU path until a probe proves it healthy
+                log.error("ec batch dispatch on lane %s took %.1fs (%.1fs "
+                          "of it compiling) against dispatch_timeout %.1fs; "
+                          "breaker tripped", g.kind, device_s, compile_s,
+                          self.dispatch_timeout)
                 self._breaker_failure(g.kind)
             else:
                 self._breaker_success(g.kind)
@@ -965,8 +1004,13 @@ class BatchingQueue:
 
                     batch = jnp.pad(batch, ((0, 0), (0, extra)))
             return self.mesh.shard_batch(batch), True
-        except Exception:
-            return batch, False  # sick mesh: single-device still serves
+        except Exception as e:
+            # sick mesh: single-device still serves, but never in silence
+            # (sharded_dispatch < dispatch and this counter say so)
+            self.perf.inc("mesh_shard_failed")
+            log.error("mesh layout of a %s batch failed; dispatching on "
+                      "one device", batch.shape, exc_info=e)
+            return batch, False
 
     def _stage_packed_batch(self, g: _Group, align: int = 1):
         """The shared launch preamble for packed-byte request groups:

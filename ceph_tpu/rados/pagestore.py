@@ -95,9 +95,8 @@ def device_slab_resolved(flag: Optional[bool] = None) -> bool:
     forces it on (CPU-backend tests exercise the jitted kernels on
     jax-cpu arrays), =0 forces the host arm; otherwise the config flag
     (``osd_tier_device_slab``; False pins the host arm) gates the AUTO
-    rule — device arm only when a real device backend is live (an
-    explicit JAX_PLATFORMS=cpu is an operator decision and wins, the
-    shared_batching_queue discipline)."""
+    rule — device arm only when a real device backend is live
+    (jaxdev.accelerator_live, the shared_batching_queue discipline)."""
     env = os.environ.get("CEPH_TPU_DEVICE_SLAB", "")
     if env == "1":
         return True
@@ -105,11 +104,9 @@ def device_slab_resolved(flag: Optional[bool] = None) -> bool:
         return False
     if flag is not None and not flag:
         return False
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        return False
-    from ceph_tpu.utils.jaxdev import probe_backend
+    from ceph_tpu.utils.jaxdev import accelerator_live
 
-    return probe_backend() not in ("cpu", "unavailable")
+    return accelerator_live()
 
 
 @dataclass

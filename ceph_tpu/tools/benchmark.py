@@ -193,6 +193,10 @@ def bench_decode(codec, args) -> int:
 def main(argv=None) -> int:
     args = parse_args(argv)
     profile = build_profile(args)
+    if args.plugin == "tpu":
+        from ceph_tpu.utils.jaxdev import accelerator_live
+
+        accelerator_live()  # a live device: compiles go through the cache
     try:
         codec = make_codec(args, profile)
     except Exception as e:

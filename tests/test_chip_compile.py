@@ -1,0 +1,210 @@
+"""AOT compiles for the v5e of the programs chip_smoke.py's main path hits,
+at the shapes it hits them with (on-chip-measurement guide §2, third
+rehearsal): the TPU compiler is installed here and compiles for a chip
+that is described, not attached.  Nothing runs, so these say nothing of
+results or times — only that the chip's compiler accepts the programs
+and that they fit the device's memory.
+
+The topology is described inside a module-scoped fixture (never at
+import: only one process may load the TPU library, and every xdist
+worker imports every test file), the persistent compilation cache is off
+around the compiles (an entry written without a chip cannot be read
+back), and everything compiles in the test's own process.
+
+What the compiler said when these were written (PR 23; compiler seconds
+on the sandbox CPU and bytes from memory_analysis — not device metrics):
+every program is accepted, both Pallas kernels included.  The packed-bit
+boundary does NOT fuse: temp memory is 60x the data bytes for the encode
+apply (1.0 GB per 16 MiB dispatch), 160x for a decode matrix, 66x for
+to_packedbit and 220x for from_packedbit (3.7 GB for 11 rows x 2 MiB) —
+the lane-hostile [R, B//32, 32] relayout in ops/gf2.py.  And compile
+time GROWS with width below 2 MiB columns: 6 s at 128 KiB, 24 s at
+512 KiB (one 4 MiB object), 58 s at 1 MiB, then 4 s at 2 MiB.  That is
+why the one-object width is marked slow here (the tier-1 run keeps the
+16 MiB dispatch width), and why the queue's watchdog no longer counts
+compile seconds (parallel/service.py).
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+K, M = 8, 3
+HBM_BYTES = 16 << 30  # one v5e chip
+
+#: the queue's 16 MiB dispatch ([8, 2 MiB]) and one 4 MiB object
+#: ([8, 512 KiB]) — the widths chip_smoke.py's traffic produces
+WIDTHS = {"dispatch16MiB": 2 << 20, "object4MiB": 512 << 10}
+BOTH_WIDTHS = ["dispatch16MiB",
+               pytest.param("object4MiB", marks=pytest.mark.slow)]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mats():
+    """The bit-matrices the smoke's codecs produce: the k=8 m=3
+    reed_sol_van generator, the decode matrix for erasures {1,4,9}, and
+    the cauchy_good k=10 m=4 generator."""
+    from ceph_tpu.ec.gf import gf
+    from ceph_tpu.ec.matrices import (cauchy_good_matrix,
+                                      matrix_to_bitmatrix,
+                                      vandermonde_coding_matrix)
+
+    mat = vandermonde_coding_matrix(K, M, 8)
+    full = np.vstack([np.eye(K, dtype=np.int64), mat])
+    chosen = [c for c in range(K + M) if c not in (1, 4, 9)][:K]
+    inv = gf(8).invert_matrix(full[chosen])
+    return {
+        "encode": matrix_to_bitmatrix(mat, 8).astype(np.uint8),
+        "decode": matrix_to_bitmatrix(inv, 8).astype(np.uint8),
+        "cauchy": matrix_to_bitmatrix(
+            cauchy_good_matrix(10, 4, 8), 8).astype(np.uint8),
+    }
+
+
+def _compile(fn, *specs, **static):
+    compiled = fn.lower(*specs, **static).compile()
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes)
+    # beside the smoke's 1 GiB resident store and a second round in flight
+    assert total < HBM_BYTES // 2, f"program needs {total} B of a 16 GiB chip"
+    print(f"MEM args={ma.argument_size_in_bytes} out={ma.output_size_in_bytes}"
+          f" temp={ma.temp_size_in_bytes}")
+    return compiled
+
+
+def _spec(shape, dtype, sharding):
+    import jax
+
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("width", BOTH_WIDTHS)
+@pytest.mark.parametrize("matrix", ["encode", "decode"])
+def test_apply_packedbit(one_chip, mats, matrix, width):
+    from ceph_tpu.ops.gf2 import apply_packedbit_fn
+
+    _compile(apply_packedbit_fn(mats[matrix]),
+             _spec((K, WIDTHS[width]), np.uint8, one_chip))
+
+
+@pytest.mark.parametrize("width", BOTH_WIDTHS)
+def test_encode_packedbit_resident(one_chip, mats, width):
+    from ceph_tpu.ops.gf2 import encode_packedbit_resident_fn
+
+    _compile(encode_packedbit_resident_fn(mats["encode"]),
+             _spec((K, WIDTHS[width]), np.uint8, one_chip))
+
+
+@pytest.mark.parametrize("width", BOTH_WIDTHS)
+def test_packedbit_converters(one_chip, width):
+    from ceph_tpu.ops.gf2 import from_packedbit, to_packedbit
+
+    B = WIDTHS[width]
+    n = K + M
+    _compile(to_packedbit, _spec((n, B), np.uint8, one_chip))
+    _compile(from_packedbit, _spec((n * 8, B // 32), np.uint32, one_chip),
+             out_rows=n)
+
+
+def test_xor_packed_planes_decode(one_chip, mats):
+    """The resident decode: the 3-erasure signature over u32 plane words."""
+    from ceph_tpu.ops.gf2 import xor_packed_fn
+
+    _compile(xor_packed_fn(mats["decode"]),
+             _spec((K * 8, (2 << 20) // 32), np.uint32, one_chip))
+
+
+def test_xor_packed_cauchy_rows(one_chip, mats):
+    """cauchy_good k=10 m=4 through the _apply_rows seam: the schedule
+    over raw uint8 packet rows of one 4 MiB object (pow2-bucketed)."""
+    from ceph_tpu.ops.gf2 import bucket_columns, xor_packed_fn
+
+    cols = bucket_columns(-(-(4 << 20) // 10) // 8)
+    _compile(xor_packed_fn(mats["cauchy"]),
+             _spec((10 * 8, cols), np.uint8, one_chip))
+
+
+@pytest.mark.parametrize("rows", [1, 256])
+def test_slab_kernels(one_chip, rows):
+    from ceph_tpu.ops.slab import gather_fn, install_fn
+
+    pw = (64 << 10) // 4  # osd_tier_page_bytes default, in u32 words
+    slab = _spec((256, pw), np.uint32, one_chip)
+    idx = _spec((rows,), np.int32, one_chip)
+    compiled = _compile(install_fn(pw, rows, True), slab,
+                        _spec((rows, pw), np.uint32, one_chip), idx)
+    # donated: the update must be in place, not a second slab
+    assert "input_output_alias" in compiled.as_text()
+    _compile(gather_fn(pw, rows), slab, idx)
+
+
+def test_pallas_apply_bytes_w8(one_chip):
+    """Off by default (CEPH_TPU_PALLAS); interpret mode in the other tests.
+    TILE_B 32768: [k*8, TILE_B] int32 intermediates against scoped VMEM."""
+    from ceph_tpu.ops.pallas_gf2 import pallas_apply_bytes_w8
+
+    compiled = _compile(pallas_apply_bytes_w8,
+                        _spec((M * 8, K * 8), np.int8, one_chip),
+                        _spec((K, 2 << 20), np.uint8, one_chip), out_rows=M)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_pallas_gf2_matmul(one_chip):
+    from ceph_tpu.ops.pallas_gf2 import pallas_gf2_matmul
+
+    compiled = _compile(pallas_gf2_matmul,
+                        _spec((M * 8, K * 8), np.int8, one_chip),
+                        _spec((K * 8, 2 << 20), np.int8, one_chip))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("width", [
+    "object4MiB", pytest.param("dispatch16MiB", marks=pytest.mark.slow)])
+def test_mesh_encode_resident_four_chips(topo, mats, width):
+    """The --multichip step's write program on a 2x2 mesh of the described
+    devices, columns sharded as MeshDispatcher lays them out: every output
+    spans the four chips and the hot path holds no collective.  (Here the
+    16 MiB dispatch is the slow one: its per-chip shard is [8, 512 KiB].)"""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ceph_tpu.ops.gf2 import encode_packedbit_resident_fn
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("stripe", "col"))
+    cols = NamedSharding(mesh, P(None, ("stripe", "col")))
+    compiled = _compile(encode_packedbit_resident_fn(mats["encode"]),
+                        _spec((K, WIDTHS[width]), np.uint8, cols))
+    for out in compiled.output_shardings:
+        assert len(out.device_set) == 4, out
+    text = compiled.as_text()
+    for op in ("all-gather", "all-reduce", "all-to-all",
+               "collective-permute"):
+        assert op not in text, op

@@ -2,7 +2,8 @@
 BatchingQueue lays dispatch batches out over a jax.sharding.Mesh
 (ceph_tpu/parallel/mesh.py), so every EC dispatch runs SPMD across the
 device grid — validated here on the conftest's virtual 8-device CPU
-mesh, exactly as the driver's dryrun_multichip does."""
+mesh (`python chip_smoke.py --multichip` runs the same step on four
+real chips)."""
 
 import asyncio
 import os

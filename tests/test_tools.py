@@ -235,8 +235,8 @@ class TestCephadmDeploy:
                 capture_output=True, text=True, timeout=120,
                 env=__import__(
                     "ceph_tpu.utils.jaxdev",
-                    fromlist=["scrub_accelerator_env"]
-                ).scrub_accelerator_env())
+                    fromlist=["cpu_child_env"]
+                ).cpu_child_env())
             assert out.returncode == 0, out.stderr[-300:]
             st = _json.loads(out.stdout)
             assert st["osdmap"]["num_up_osds"] == 3
