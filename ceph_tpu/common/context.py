@@ -16,7 +16,7 @@ from ceph_tpu.common.admin_socket import AdminSocket
 from ceph_tpu.common.config import Config
 from ceph_tpu.common.log import Log
 from ceph_tpu.common.perf_counters import PerfCountersCollection
-from ceph_tpu.common.tracing import Tracer
+from ceph_tpu.common.tracing import LOOP_PERF, Tracer
 from ceph_tpu.common.tracked_op import OpTracker
 
 VERSION = "1.0.0-tpu"
@@ -49,6 +49,9 @@ class Context:
                 self.conf.get("osd_op_tracker_max_events", 128) or 128))
         self.perf.add(self.op_tracker.perf)
         self.op_tracker.register_asok(self.asok)
+        # the process's `loop` set (tracing.py: loop meter + section self
+        # times): one object in every daemon's collection, as `ec_tpu` is
+        self.perf.add(LOOP_PERF)
         self.tracer = Tracer(service=name)
         self.tracer.register_asok(self.asok)
         # runtime debug levels: the Log caches per-subsystem levels (one

@@ -72,12 +72,14 @@ class PerfCounters:
         with self._lock:
             c.value = value
 
-    def tinc(self, name: str, seconds: float) -> None:
-        """Add one latency observation to a longrunavg."""
+    def tinc(self, name: str, seconds: float, count: int = 1) -> None:
+        """Add one latency observation to a longrunavg (or `count` of
+        them that sum to `seconds`: a meter that sums locally and folds
+        in at dump time)."""
         c = self._counters[name]
         with self._lock:
             c.sum += seconds
-            c.count += 1
+            c.count += count
 
     @contextlib.contextmanager
     def time_avg(self, name: str):
@@ -112,6 +114,16 @@ class PerfCounters:
             c.count += 1
             c.sum += value
 
+    def hmerge(self, name: str, buckets: List[int], total: float) -> None:
+        """Add observations already bucketed as hinc buckets them (index =
+        bit length of the value), `total` being their sum."""
+        c = self._counters[name]
+        with self._lock:
+            for i, n in enumerate(buckets):
+                c.buckets[i] += n
+            c.count += sum(buckets)
+            c.sum += total
+
     def get(self, name: str) -> Any:
         c = self._counters[name]
         if c.kind == U64:
@@ -128,6 +140,11 @@ class PerfCounters:
         """Zero every counter in the set (the `perf reset` admin command):
         tests and bench warmup/timed windows isolate measurement intervals
         instead of diffing snapshots by hand."""
+        if self.presample is not None:
+            try:
+                self.presample()  # what a meter summed until now goes too
+            except Exception:
+                pass
         with self._lock:
             for c in self._counters.values():
                 c.value = 0

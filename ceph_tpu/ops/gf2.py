@@ -583,7 +583,8 @@ def to_packedbit(data: jnp.ndarray) -> jnp.ndarray:
     """Packed [n, B] uint8 chunks (w=8 byte layout, B % 32 == 0) ->
     [n*8, B//32] uint32 plane words — the ENTRY boundary for packed-bit
     residency, paid once per object."""
-    return _bits_to_words(unpack_bits_bytes(data, 8))
+    with jax.named_scope("to_packedbit"):
+        return _bits_to_words(unpack_bits_bytes(data, 8))
 
 
 @functools.partial(jax.jit, static_argnames=("out_rows",))
@@ -591,7 +592,8 @@ def from_packedbit(planes: jnp.ndarray, out_rows: int) -> jnp.ndarray:
     """[out_rows*8, Wc] uint32 plane words -> packed [out_rows, Wc*32]
     uint8 — the EXIT boundary, paid once when bytes leave for the
     wire/store."""
-    return pack_bits_bytes(_words_to_bits(planes), 8, out_rows)
+    with jax.named_scope("from_packedbit"):
+        return pack_bits_bytes(_words_to_bits(planes), 8, out_rows)
 
 
 def gf2_apply_packedbit(bitmatrix: np.ndarray, data) -> "jnp.ndarray":
@@ -612,9 +614,14 @@ def apply_packedbit_fn(bitmatrix: np.ndarray):
     def build(ops, outs):
         @jax.jit
         def _run(x):
-            planes = _bits_to_words(unpack_bits_bytes(x, 8))
-            pouts = _schedule_apply(ops, outs, C, planes)
-            return pack_bits_bytes(_words_to_bits(pouts), 8, out_rows)
+            # the three stages as named scopes: each op's name in the TPU
+            # plane's "XLA Ops" line carries its scope (PERF.md section 3)
+            with jax.named_scope("to_packedbit"):
+                planes = _bits_to_words(unpack_bits_bytes(x, 8))
+            with jax.named_scope("xor_apply"):
+                pouts = _schedule_apply(ops, outs, C, planes)
+            with jax.named_scope("from_packedbit"):
+                return pack_bits_bytes(_words_to_bits(pouts), 8, out_rows)
 
         return _run
 
@@ -640,9 +647,13 @@ def encode_packedbit_resident_fn(bitmatrix: np.ndarray):
     def build(ops, outs):
         @jax.jit
         def _run(x):
-            planes = _bits_to_words(unpack_bits_bytes(x, 8))
-            pouts = _schedule_apply(ops, outs, C, planes)
-            packed = pack_bits_bytes(_words_to_bits(pouts), 8, out_rows)
+            with jax.named_scope("to_packedbit"):
+                planes = _bits_to_words(unpack_bits_bytes(x, 8))
+            with jax.named_scope("xor_apply"):
+                pouts = _schedule_apply(ops, outs, C, planes)
+            with jax.named_scope("from_packedbit"):
+                packed = pack_bits_bytes(_words_to_bits(pouts), 8,
+                                         out_rows)
             return packed, jnp.concatenate([planes, pouts], axis=0)
 
         return _run

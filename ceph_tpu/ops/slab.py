@@ -165,7 +165,8 @@ def install_fn(page_words: int, nb: int, donate: bool):
 
     def build():
         def _install(s, d, i):
-            return s.at[i].set(d)
+            with jax.named_scope("slab_install"):
+                return s.at[i].set(d)
 
         if donate:
             return jax.jit(_install, donate_argnums=(0,))
@@ -176,8 +177,11 @@ def install_fn(page_words: int, nb: int, donate: bool):
 
 def gather_fn(page_words: int, nb: int):
     """The jitted (LRU-cached) gather kernel for one page geometry."""
-    return _kernel(("gather", page_words, nb),
-                   lambda: jax.jit(lambda s, i: s[i]))
+    def _gather(s, i):
+        with jax.named_scope("slab_gather"):
+            return s[i]
+
+    return _kernel(("gather", page_words, nb), lambda: jax.jit(_gather))
 
 
 def slab_gather(slab, idx: np.ndarray):

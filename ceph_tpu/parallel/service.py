@@ -85,6 +85,7 @@ from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
+from ceph_tpu.common import tracing
 from ceph_tpu.common.perf_counters import PerfCounters, PerfCountersBuilder
 
 log = logging.getLogger("ceph_tpu.ec.batch")
@@ -109,6 +110,12 @@ def _build_ec_tpu_perf() -> PerfCounters:
       queue_wait           longrunavg  submit -> launch wait per request
       dispatch_dev         longrunavg  launch -> fan-out device seconds per dispatch
       dispatch_compile     longrunavg  XLA compile seconds inside a dispatch
+      launch               longrunavg  of dispatch_dev, on the queue's thread:
+                                       host array prep, device_put, program
+                                       enqueue (returns before the device ran)
+      fetch                longrunavg  of dispatch_dev: np.asarray of the
+                                       result = program wait + D2H
+      h2d_bytes, d2h_bytes u64         bytes staged to / fetched from the device
       mesh_shard_failed    u64         batches the mesh could not lay out
       group_size           histogram   coalesced requests per dispatch (pow2 buckets)
       submit_group         u64         multi-item submit_group() calls (the
@@ -136,6 +143,12 @@ def _build_ec_tpu_perf() -> PerfCounters:
     b.add_time_avg("dispatch_compile",
                    "XLA compile seconds inside a dispatch (per dispatch "
                    "that compiled; excluded from the watchdog)")
+    b.add_time_avg("launch", "host prep + device_put + program enqueue "
+                             "per dispatch (queue thread)")
+    b.add_time_avg("fetch", "np.asarray of a dispatch's result: program "
+                            "wait + D2H (queue thread)")
+    b.add_u64_counter("h2d_bytes", "bytes staged to the device")
+    b.add_u64_counter("d2h_bytes", "bytes fetched from the device")
     b.add_u64_counter("mesh_shard_failed",
                       "batches the mesh could not lay out (served on one "
                       "device instead)")
@@ -518,6 +531,7 @@ class BatchingQueue:
         return self._submit(mbits, planes, w, out_rows, "packedbit_planes",
                             span)
 
+    @tracing.sectioned("ecplan", "queue_submit")
     def submit_group(self, items, span=None) -> List[Future]:
         """Group-aware submit (the messenger/recovery whole-stripe-group
         handoff seam): queue a LIST of lane submissions — each item is
@@ -574,6 +588,7 @@ class BatchingQueue:
             self._oldest = now
         return nbytes
 
+    @tracing.sectioned("ecplan", "queue_submit")
     def _submit(self, mbits, regions, w, out_rows, kind,
                 span=None) -> Future:
         fut: Future = Future()
@@ -619,6 +634,7 @@ class BatchingQueue:
             return int(regions.shape[0]) * int(regions.shape[1]) * 4
         return regions.nbytes
 
+    @tracing.sectioned("queue", "group_build")
     def _take_locked(self, budget: Optional[int] = None) -> List[_Group]:
         """Detach queued work for one round.  With a `budget`, the round
         is bounded to ~budget packed bytes (whole requests; at least
@@ -861,18 +877,20 @@ class BatchingQueue:
                 # watchdog sees the slow dispatch
                 time.sleep(self.inject_dispatch_delay)
             try:
-                if g.kind == "planar":
-                    state = self._launch_planar(g)
-                elif g.kind == "resident":
-                    state = self._launch_resident(g)
-                elif g.kind == "packedbit":
-                    state = self._launch_packedbit(g)
-                elif g.kind == "packedbit_resident":
-                    state = self._launch_packedbit_resident(g)
-                elif g.kind == "packedbit_planes":
-                    state = self._launch_packedbit_planes(g)
-                else:
-                    state = self._launch_packed(g)
+                with tracing.section("devbound", "launch"), \
+                        self.perf.time_avg("launch"):
+                    if g.kind == "planar":
+                        state = self._launch_planar(g)
+                    elif g.kind == "resident":
+                        state = self._launch_resident(g)
+                    elif g.kind == "packedbit":
+                        state = self._launch_packedbit(g)
+                    elif g.kind == "packedbit_resident":
+                        state = self._launch_packedbit_resident(g)
+                    elif g.kind == "packedbit_planes":
+                        state = self._launch_packedbit_planes(g)
+                    else:
+                        state = self._launch_packed(g)
                 if sp is not None:
                     sp.event("launched")
                 launched.append(_Launched(g, state, now, sp, wait_s,
@@ -968,6 +986,16 @@ class BatchingQueue:
         # synchronous drain (flush()/close()): launch then complete
         self._complete_safe(self._launch_safe(groups))
 
+    def _fetch(self, result) -> np.ndarray:
+        """A dispatch's result on the host.  np.asarray blocks until the
+        program ran and its output crossed D2H; nothing else waits for the
+        device, so this is the `fetch` half of dispatch_dev."""
+        with tracing.section("devbound", "fetch"), \
+                self.perf.time_avg("fetch"):
+            out = np.asarray(result)
+        self.perf.inc("d2h_bytes", out.nbytes)
+        return out
+
     def _note_dispatch(self, nbytes: int, sharded: bool) -> None:
         """Dispatch-complete accounting shared by every lane."""
         self.perf.inc("dispatch")
@@ -1028,6 +1056,7 @@ class BatchingQueue:
         if pad:
             batch = np.pad(batch, ((0, 0), (0, pad)))
         nbytes = batch.nbytes
+        self.perf.inc("h2d_bytes", nbytes)
         batch, sharded = self._maybe_shard(batch, pad_np=True, align=align)
         if not sharded:
             batch = jax.device_put(batch)  # async H2D staging
@@ -1058,7 +1087,7 @@ class BatchingQueue:
 
     def _complete_packed(self, g: _Group, state) -> None:
         widths, out, sharded, nbytes = state
-        out = np.asarray(out)  # blocks until compute + D2H done
+        out = self._fetch(out)
         self._note_dispatch(nbytes, sharded)
         off = 0
         for width, req in zip(widths, g.requests):
@@ -1126,7 +1155,7 @@ class BatchingQueue:
 
     def _complete_resident(self, g: _Group, state) -> None:
         widths, packed, all_bits, sharded, nbytes, cols = state
-        packed = np.asarray(packed)  # blocks until ready
+        packed = self._fetch(packed)
         self._note_dispatch(nbytes, sharded)
         # planar columns per packed byte-column depends on w (w=16: B//2)
         cfac = all_bits.shape[1] / cols
@@ -1176,7 +1205,7 @@ class BatchingQueue:
         # alias the same underlying buffer; only the slab argument,
         # which this plane never hands out, is donatable.
         widths, packed, planes, sharded, nbytes = state
-        packed = np.asarray(packed)  # blocks until ready
+        packed = self._fetch(packed)
         self._note_dispatch(nbytes, sharded)
         if len(g.requests) == 1 and packed.shape[1] == widths[0]:
             # single-request group covering the full (unpadded) batch:

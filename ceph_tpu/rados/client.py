@@ -42,6 +42,7 @@ import uuid
 from typing import Dict, List, Optional, Tuple
 
 from ceph_tpu.common.perf_counters import PerfCounters, PerfCountersBuilder
+from ceph_tpu.common import tracing
 from ceph_tpu.common.tracing import Tracer
 from ceph_tpu.rados.clog import ClogEntry, LogClient, decode_entries
 from ceph_tpu.rados.messenger import BufferList, Messenger
@@ -276,6 +277,7 @@ class RadosClient:
         return self._clog
 
     async def start(self) -> None:
+        tracing.install_loop_meter()
         self.messenger.dispatcher = self._dispatch
         # rx batches resolve their reply futures in one pass (and the
         # batch's frames get ONE piggybacked ack instead of one each —
@@ -393,9 +395,10 @@ class RadosClient:
             ):
                 self._mon_fut.set_result(msg)
         elif isinstance(msg, MOSDOpReply):
-            fut = self._replies.pop(msg.reqid, None)
-            if fut and not fut.done():
-                fut.set_result(msg)
+            with tracing.section("client", "reply_match"):
+                fut = self._replies.pop(msg.reqid, None)
+                if fut and not fut.done():
+                    fut.set_result(msg)
 
     # -- MOSDBackoff handling (reference Objecter::_handle_backoff) ----------
 
@@ -480,7 +483,8 @@ class RadosClient:
         messenger's `wire` set (clients own no admin socket — tools,
         benches, and embedding daemons read this)."""
         return {"objecter": self.perf.dump(),
-                "wire": self.messenger.perf.dump()}
+                "wire": self.messenger.perf.dump(),
+                "loop": tracing.LOOP_PERF.dump()}
 
     @property
     def mon_addr(self) -> Tuple[str, int]:
@@ -957,21 +961,23 @@ class RadosClient:
             await self.refresh_map()
         # ONE reqid per logical op: resends carry the same id so the PG
         # log's dup detection can recognize them (reference osd_reqid_t)
-        op.reqid = uuid.uuid4().hex
-        if not getattr(op, "client", ""):
-            op.client = self.name
-        rec = _OpRecord(op, time.monotonic() + self.op_deadline)
-        # root span for the whole logical op (across every resend); its
-        # context rides the MOSDOp so the primary's osd_op span — and
-        # through it the k+m sub-write peers — stitch under ONE trace_id
-        span = None
-        if self._trace_on:
-            span = self.tracer.new_trace(f"client_op {op.op} {op.oid}")
-            span.tag("reqid", op.reqid).tag("pool", op.pool_id)
-            op.trace_id, op.span_id = span.context()
-        self.perf.inc("op")
-        self._inflight[op.reqid] = rec
-        self.perf.set("inflight", len(self._inflight))
+        with tracing.section("client", "submit"):
+            op.reqid = uuid.uuid4().hex
+            if not getattr(op, "client", ""):
+                op.client = self.name
+            rec = _OpRecord(op, time.monotonic() + self.op_deadline)
+            # root span for the whole logical op (across every resend);
+            # its context rides the MOSDOp so the primary's osd_op span —
+            # and through it the k+m sub-write peers — stitch under ONE
+            # trace_id
+            span = None
+            if self._trace_on:
+                span = self.tracer.new_trace(f"client_op {op.op} {op.oid}")
+                span.tag("reqid", op.reqid).tag("pool", op.pool_id)
+                op.trace_id, op.span_id = span.context()
+            self.perf.inc("op")
+            self._inflight[op.reqid] = rec
+            self.perf.set("inflight", len(self._inflight))
         try:
             reply = await self._op_submit(op, rec, retries, span)
             if span is not None:
@@ -1035,7 +1041,8 @@ class RadosClient:
                 await asyncio.sleep(self._retry_pause(attempt))
                 attempt += 1
                 continue
-            pg, primary = self._calc_target(op)
+            with tracing.section("client", "calc_target"):
+                pg, primary = self._calc_target(op)
             if primary is None:
                 last_error = "no primary (all acting osds down)"
                 last_code = 0
@@ -1177,12 +1184,14 @@ class RadosClient:
         (reference SnapContext on every write).  ``client`` overrides
         the entity name this op carries (simulated-tenant identity for
         the macro traffic harness; default: this client's name)."""
-        self._check_oid(oid)
-        seq, snaps = self._write_snapc(pool_id, snapc)
-        await self._op(MOSDOp(op="write", pool_id=pool_id, oid=oid, data=data,
-                              offset=-1 if offset is None else int(offset),
-                              snapc_seq=seq, snapc_snaps=list(snaps),
-                              client=client))
+        with tracing.section("client", "build_op"):
+            self._check_oid(oid)
+            seq, snaps = self._write_snapc(pool_id, snapc)
+            op = MOSDOp(op="write", pool_id=pool_id, oid=oid, data=data,
+                        offset=-1 if offset is None else int(offset),
+                        snapc_seq=seq, snapc_snaps=list(snaps),
+                        client=client)
+        await self._op(op)
 
     async def multi(self, pool_id: int, oid: str, ops,
                     snapc: Optional[Tuple[int, List[int]]] = None):
@@ -1321,10 +1330,11 @@ class RadosClient:
         off the promotion path (scans, backups); "willneed" asks the
         primary to promote the object to device residency on this read
         regardless of its recency (still promotion-throttled)."""
-        self._check_oid(oid)
-        reply = await self._op(MOSDOp(op="read", pool_id=pool_id, oid=oid,
-                                      snap_read=int(snap),
-                                      fadvise=fadvise, client=client))
+        with tracing.section("client", "build_op"):
+            self._check_oid(oid)
+            op = MOSDOp(op="read", pool_id=pool_id, oid=oid,
+                        snap_read=int(snap), fadvise=fadvise, client=client)
+        reply = await self._op(op)
         data = reply.data
         if isinstance(data, BufferList):
             # colocated fastpath hands the primary's scatter-gather read

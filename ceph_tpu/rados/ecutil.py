@@ -22,11 +22,15 @@ per-shard concatenations.
 from __future__ import annotations
 
 import json
+import time
 import zlib
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
+
+from ceph_tpu.common import tracing
+from ceph_tpu.common.perf_counters import PerfCountersBuilder
 
 
 @dataclass(frozen=True)
@@ -158,6 +162,18 @@ def _mapped_shard_list(codec, data_rows: np.ndarray,
     return out  # type: ignore[return-value]
 
 
+# the `ecplan` set: how many stripe plans the served paths made (its time
+# is the loop set's `self_ecplan`, from the sections below).  One per
+# process, listed by every OSD's collection like `gf2_sched`.
+ECPLAN_PERF = (PerfCountersBuilder("ecplan")
+               .add_u64_counter("plans", "encode/decode plans built for "
+                                         "the queue")
+               .add_u64_counter("stripes", "stripes those plans covered")
+               .add_u64_counter("packs", "resident bit-rows packed to "
+                                         "bytes (_pack_rows)")
+               .create_perf_counters())
+
+
 def _packedbit_route(codec) -> bool:
     """Whether this codec's queue plans ride the packed-bit XOR-schedule
     lane (the w=8 production lane, ceph_tpu/ops/gf2.py lane-promotion
@@ -167,6 +183,7 @@ def _packedbit_route(codec) -> bool:
     return packedbit_enabled() and getattr(codec, "w", 8) == 8
 
 
+@tracing.sectioned("ecplan", "encode_plan")
 def _encode_plan_parts(codec, sinfo: StripeInfo, arr: np.ndarray,
                        n_stripes: int):
     """The submit-free half of the queue encode plan: when the codec is
@@ -183,6 +200,8 @@ def _encode_plan_parts(codec, sinfo: StripeInfo, arr: np.ndarray,
     n = codec.get_chunk_count()
     m = n - k
     w = getattr(codec, "w", 8)
+    ECPLAN_PERF.inc("plans")
+    ECPLAN_PERF.inc("stripes", n_stripes)
     # columns = stripes concatenated; one submit -> one device call
     flat = np.ascontiguousarray(
         arr.transpose(1, 0, 2).reshape(k, n_stripes * sinfo.chunk_size))
@@ -194,6 +213,7 @@ def _encode_plan_parts(codec, sinfo: StripeInfo, arr: np.ndarray,
         kind = "packed"
         mat = np.asarray(mbits).astype(np.int8)
 
+    @tracing.sectioned("ecplan", "reassemble")
     def reassemble(parity: np.ndarray) -> List[np.ndarray]:
         p = np.asarray(parity).reshape(m, n_stripes * sinfo.chunk_size)
         out: List[np.ndarray] = []
@@ -350,6 +370,7 @@ async def batched_encode_group_async(codec, sinfo: StripeInfo, buffers,
     return out
 
 
+@tracing.sectioned("ecplan", "decode_plan")
 def _queue_decode_plan(codec, sinfo: StripeInfo,
                        arrays: Dict[int, np.ndarray], object_size: int,
                        queue, span=None):
@@ -564,30 +585,34 @@ async def planar_encode_async(codec, sinfo: StripeInfo, data: bytes,
     as batched_encode); w MUST be recorded with the resident (w=16/w=4
     pools unpack to different plane layouts) — or None when the codec is
     not planar-eligible."""
-    if not planar_eligible(codec):
-        return None
-    padded = sinfo.pad_to_stripe(data)
+    with tracing.section("ecplan", "planar_pad"):
+        if not planar_eligible(codec):
+            return None
+        padded = sinfo.pad_to_stripe(data)
     if not len(padded):
         return None
     import asyncio
 
-    k = codec.get_data_chunk_count()
-    n = codec.get_chunk_count()
-    m = n - k
-    w = getattr(codec, "w", 8)
-    n_stripes = max(1, len(padded) // sinfo.stripe_width)
-    flat = np.ascontiguousarray(
-        np.frombuffer(padded, dtype=np.uint8)
-        .reshape(n_stripes, k, sinfo.chunk_size)
-        .transpose(1, 0, 2).reshape(k, n_stripes * sinfo.chunk_size))
-    L = flat.shape[1]
-    # the packed-bit production lane needs whole u32 words per plane row
-    # (w=8 byte codecs guarantee it: chunk_size is a multiple of w*4=32)
-    packedbit = _packedbit_route(codec) and L % 32 == 0
-    if packedbit:
-        mbits = np.asarray(codec.bit_generator()).astype(np.uint8)
-    else:
-        mbits = np.asarray(codec.bit_generator()).astype(np.int8)
+    with tracing.section("ecplan", "planar_plan"):
+        k = codec.get_data_chunk_count()
+        n = codec.get_chunk_count()
+        m = n - k
+        w = getattr(codec, "w", 8)
+        n_stripes = max(1, len(padded) // sinfo.stripe_width)
+        ECPLAN_PERF.inc("plans")
+        ECPLAN_PERF.inc("stripes", n_stripes)
+        flat = np.ascontiguousarray(
+            np.frombuffer(padded, dtype=np.uint8)
+            .reshape(n_stripes, k, sinfo.chunk_size)
+            .transpose(1, 0, 2).reshape(k, n_stripes * sinfo.chunk_size))
+        L = flat.shape[1]
+        # the packed-bit production lane needs whole u32 words per plane row
+        # (w=8 byte codecs guarantee it: chunk_size is a multiple of w*4=32)
+        packedbit = _packedbit_route(codec) and L % 32 == 0
+        if packedbit:
+            mbits = np.asarray(codec.bit_generator()).astype(np.uint8)
+        else:
+            mbits = np.asarray(codec.bit_generator()).astype(np.int8)
     if queue is not None:
         if packedbit:
             parity, all_bits = await asyncio.wrap_future(
@@ -610,8 +635,9 @@ async def planar_encode_async(codec, sinfo: StripeInfo, data: bytes,
         else:
             parity, all_bits = gf2_encode_resident(mbits, buf, w, m)
         parity = np.asarray(parity)
-    parity = parity[:, :L]
-    blobs = [flat[i] for i in range(k)] + [parity[j] for j in range(m)]
+    with tracing.section("ecplan", "planar_reassemble"):
+        parity = parity[:, :L]
+        blobs = [flat[i] for i in range(k)] + [parity[j] for j in range(m)]
     return blobs, all_bits, n, L, w
 
 
@@ -620,6 +646,7 @@ async def planar_encode_async(codec, sinfo: StripeInfo, data: bytes,
 SLAB_IO_BOUNDARY = ("_pack_rows",)
 
 
+@tracing.sectioned("ecplan", "pack_rows")
 def _pack_rows(bits, w: int, n_rows: int, L: int,
                store=None) -> np.ndarray:
     """Resident bit-rows -> packed [n_rows, L] uint8 (the one exit
@@ -628,6 +655,7 @@ def _pack_rows(bits, w: int, n_rows: int, L: int,
     gather result is a device array and the np.asarray here is the
     single d2h of the read — counted on the store (``d2h_gathers``)
     when the caller hands it in."""
+    t0 = time.monotonic()
     if np.dtype(bits.dtype) == np.uint32:
         from ceph_tpu.ops.gf2 import from_packedbit
 
@@ -636,12 +664,17 @@ def _pack_rows(bits, w: int, n_rows: int, L: int,
         from ceph_tpu.ops.gf2 import from_planar
 
         out = np.asarray(from_planar(bits, w, n_rows))[:, :L]
+    ECPLAN_PERF.inc("packs")
     note = getattr(store, "note_d2h", None)
     if note is not None:
         note()
+        # the store's exit boundary: its own read() ticks pack_s, and so
+        # does the served read, which packs here
+        store.perf.tinc("pack_s", time.monotonic() - t0)
     return out
 
 
+@tracing.sectioned("ecplan", "planar_rows")
 def planar_rows(store, key, version) -> Optional[List[np.ndarray]]:
     """All n shard rows packed from the planar resident under `key`, or
     None when absent, at a different version, or PARTIAL (a paged
@@ -662,6 +695,7 @@ def planar_rows(store, key, version) -> Optional[List[np.ndarray]]:
     return [rows[i] for i in range(n_rows)]
 
 
+@tracing.sectioned("ecplan", "planar_shard_bytes")
 def planar_shard_bytes(store, key, version, shard: int) -> Optional[bytes]:
     """ONE shard's packed bytes from the resident's bit-rows — the
     writeback flush/sub-read shape: a dirty resident's deferred local
@@ -680,6 +714,7 @@ def planar_shard_bytes(store, key, version, shard: int) -> Optional[bytes]:
                       store=store).reshape(-1).tobytes()
 
 
+@tracing.sectioned("ecplan", "planar_object_bytes")
 def planar_object_bytes(store, key, version, k: int, cs: int,
                         object_size: int) -> Optional[bytes]:
     """The logical object bytes packed from the planar resident's DATA

@@ -133,6 +133,7 @@ from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ceph_tpu.common import tracing
 from ceph_tpu.common.perf_counters import PerfCounters, PerfCountersBuilder
 from ceph_tpu.common.throttle import Throttle
 from ceph_tpu.utils import wirepath as _wirepath
@@ -195,7 +196,16 @@ def _build_wire_perf() -> PerfCounters:
     encode cost a scatter-gather/zero-copy PR can remove; io seconds are
     the socket's.  With the corked outbox, tx_io is per FLUSH WINDOW (not
     per message): sum(tx_io)/tx_msgs is the per-message socket cost and
-    drops as flush windows batch more frames."""
+    drops as flush windows batch more frames.
+
+    tx_io and rx_io INCLUDE AWAITS (the drain; every readexactly of the
+    payload): on a busy loop they measure how long the connection waited
+    for the loop, not what the messenger did — 209 s of rx_io in a 30 s
+    window is parked readers (PERF.md, PR 26).  The messenger's own time
+    is the `loop` set's `self_messenger` (common/tracing.py): the
+    sections here (encode_frame with the blob's crc, sock_write, rx_drain
+    with its crc_verify, decode) plus asyncio's transport reads and
+    writes."""
     b = PerfCountersBuilder("wire")
     b.add_u64_counter("tx_msgs", "messages sent")
     b.add_u64_counter("tx_bytes", "frame bytes sent")
@@ -1348,20 +1358,21 @@ class Connection:
             segs = [blob]
             blob_len = len(blob)
         if blob_crc is None:
-            if not self.crc_enabled:
-                blob_crc = 0
-            elif self.wp is not None and len(segs) > 1 \
-                    and self.crc_fn is checksum:
-                # multi-piece BufferList: ONE released-GIL call chains
-                # the crc across every piece (was one ctypes round-trip
-                # per piece)
-                blob_crc = self.wp.wirepy_crc_chain(segs)
-                self.messenger.perf.inc("native_tx_calls")
-                self.messenger.perf.inc("native_bytes", blob_len)
-            else:
-                blob_crc = 0
-                for s in segs:
-                    blob_crc = self.crc_fn(s, blob_crc)
+            with tracing.section("messenger", "crc"):
+                if not self.crc_enabled:
+                    blob_crc = 0
+                elif self.wp is not None and len(segs) > 1 \
+                        and self.crc_fn is checksum:
+                    # multi-piece BufferList: ONE released-GIL call chains
+                    # the crc across every piece (was one ctypes
+                    # round-trip per piece)
+                    blob_crc = self.wp.wirepy_crc_chain(segs)
+                    self.messenger.perf.inc("native_tx_calls")
+                    self.messenger.perf.inc("native_bytes", blob_len)
+                else:
+                    blob_crc = 0
+                    for s in segs:
+                        blob_crc = self.crc_fn(s, blob_crc)
         else:
             self.messenger.perf.inc("tx_crc_reused")
         prefix = _BLOB_PFX.pack(len(pickled), blob_crc)
@@ -1459,7 +1470,8 @@ class Connection:
                     t_io = time.monotonic()
                     try:
                         with perf.time_avg("tx_io"):
-                            self.writer.writelines(segs)
+                            with tracing.section("messenger", "sock_write"):
+                                self.writer.writelines(segs)
                             await self.writer.drain()
                     except (ConnectionError, OSError,
                             asyncio.TimeoutError) as e:
@@ -1559,6 +1571,15 @@ class Connection:
         self.writer = corked
 
     async def send(self, msg: Any) -> None:
+        # whoever awaits a send, the steps it takes are the messenger's
+        # time (tracing.mark: the caller's layer is put back after)
+        was = tracing.mark("messenger")
+        try:
+            await self._send(msg)
+        finally:
+            tracing.mark(was)
+
+    async def _send(self, msg: Any) -> None:
         conf = self.messenger.conf
         inj = _cget(conf, "ms_inject_socket_failures", 0)
         injected = bool(inj) and random.randrange(inj) == 0
@@ -1584,26 +1605,29 @@ class Connection:
         self.out_seq += 1
         seq = self.out_seq
         t_frame = time.monotonic()
-        pickled, blob, fixed = encode_payload_parts(msg)
-        flags = FLAG_FIXED if fixed else 0
-        if blob is not None:
-            # cached-crc reuse: a message that already carries a crc of
-            # EXACTLY its blob bytes (BLOB_CRC_ATTR) skips the wire crc
-            # pass — only when this connection's negotiated checksum is
-            # the shared resolver the app-level crc was computed with
-            pre_crc = None
-            crc_attr = getattr(type(msg), "BLOB_CRC_ATTR", None)
-            if crc_attr is not None and self.crc_enabled \
-                    and self.crc_fn is checksum:
-                v = msg.__dict__.get(crc_attr) or 0
-                if v:
-                    pre_crc = v & 0xFFFFFFFF
-            data = self._frame_segments(msg.TYPE_ID, msg.VERSION, pickled,
-                                        blob, seq, flags, blob_crc=pre_crc)
-        else:
-            pre_crc = None
-            data = self._frame(msg.TYPE_ID, msg.VERSION, pickled, seq,
-                               flags)
+        with tracing.section("messenger", "encode_frame"):
+            pickled, blob, fixed = encode_payload_parts(msg)
+            flags = FLAG_FIXED if fixed else 0
+            if blob is not None:
+                # cached-crc reuse: a message that already carries a crc
+                # of EXACTLY its blob bytes (BLOB_CRC_ATTR) skips the wire
+                # crc pass — only when this connection's negotiated
+                # checksum is the shared resolver the app-level crc was
+                # computed with
+                pre_crc = None
+                crc_attr = getattr(type(msg), "BLOB_CRC_ATTR", None)
+                if crc_attr is not None and self.crc_enabled \
+                        and self.crc_fn is checksum:
+                    v = msg.__dict__.get(crc_attr) or 0
+                    if v:
+                        pre_crc = v & 0xFFFFFFFF
+                data = self._frame_segments(
+                    msg.TYPE_ID, msg.VERSION, pickled, blob, seq, flags,
+                    blob_crc=pre_crc)
+            else:
+                pre_crc = None
+                data = self._frame(msg.TYPE_ID, msg.VERSION, pickled, seq,
+                                   flags)
         self.messenger._note_tx(type(msg).__name__,
                                 sum(self._seg_len(p) for p in data)
                                 if isinstance(data, list) else len(data),
@@ -1789,8 +1813,9 @@ class Connection:
             perf = self.messenger.perf
             bad_idx = len(frames)
             if voffs:
-                bad_region = self.wp.wirepy_verify_regions(
-                    pend, voffs, vlens, vwants)
+                with tracing.section("messenger", "crc_verify"):
+                    bad_region = self.wp.wirepy_verify_regions(
+                        pend, voffs, vlens, vwants)
                 perf.inc("native_rx_calls")
                 perf.inc("native_bytes", sum(vlens))
                 if bad_region >= 0:
@@ -1851,7 +1876,10 @@ class Connection:
                 and (self.crc_fn is checksum or not self.crc_enabled):
             # native burst drain: every fully-buffered frame verifies in
             # one released-GIL call and lands pre-scattered in the stash
-            self._rx_drain_native()
+            r = self.reader
+            if len(r._pending) - r._off >= _HDR.size:
+                with tracing.section("messenger", "rx_drain"):
+                    self._rx_drain_native()
         if stash:
             (type_id, version, seq, payload, cost, blob, fixed,
              verified) = stash.popleft()
@@ -1919,19 +1947,24 @@ class Connection:
                                                              uninit=True)
                 else:
                     blob = await self.reader.readexactly(blob_len)
-                if crc and self.crc_enabled \
-                        and self.crc_fn(pickled, self.crc_fn(head)) != crc:
-                    raise BadFrame(f"crc mismatch on frame type {type_id}")
-                if blob_crc and self.crc_enabled:
-                    if self.crc_fn(blob) != blob_crc:
-                        raise BadFrame(f"blob crc mismatch on type {type_id}")
-                    blob_verified = True
+                with tracing.section("messenger", "crc_verify"):
+                    if crc and self.crc_enabled and self.crc_fn(
+                            pickled, self.crc_fn(head)) != crc:
+                        raise BadFrame(
+                            f"crc mismatch on frame type {type_id}")
+                    if blob_crc and self.crc_enabled:
+                        if self.crc_fn(blob) != blob_crc:
+                            raise BadFrame(
+                                f"blob crc mismatch on type {type_id}")
+                        blob_verified = True
                 payload = pickled
             else:
                 payload = await self.reader.readexactly(length)
-                if crc and self.crc_enabled \
-                        and self.crc_fn(payload) != crc:
-                    raise BadFrame(f"crc mismatch on frame type {type_id}")
+                with tracing.section("messenger", "crc_verify"):
+                    if crc and self.crc_enabled \
+                            and self.crc_fn(payload) != crc:
+                        raise BadFrame(
+                            f"crc mismatch on frame type {type_id}")
                 if flags & FLAG_COMPRESSED:
                     payload = zlib.decompress(payload)
         except BaseException:
@@ -2556,6 +2589,10 @@ class Messenger:
         self.name = name
         self.conf = conf if conf is not None else {}
         self.entity_type = entity_type
+        # whose time a dispatched message's handler is (tracing.mark):
+        # the op path's, the client's, or the cluster's housekeeping
+        self._daemon_layer = {"osd": "osd", "client": "client"}.get(
+            entity_type, "background")
         # resolve the frame checksum NOW (may g++-build the native
         # library, seconds): daemon construction, never the hot path
         checksum_kind()
@@ -2761,7 +2798,11 @@ class Messenger:
             return
         if self.home_loop is None \
                 or self.home_loop is asyncio.get_running_loop():
-            await self.dispatcher(conn, msg)
+            was = tracing.mark(self._daemon_layer)
+            try:
+                await self.dispatcher(conn, msg)
+            finally:
+                tracing.mark(was)
             return
         fut = asyncio.run_coroutine_threadsafe(
             self.dispatcher(conn, msg), self.home_loop)
@@ -2772,7 +2813,11 @@ class Messenger:
             return
         if self.home_loop is None \
                 or self.home_loop is asyncio.get_running_loop():
-            await self.group_dispatcher(conn, msgs)
+            was = tracing.mark(self._daemon_layer)
+            try:
+                await self.group_dispatcher(conn, msgs)
+            finally:
+                tracing.mark(was)
             return
         fut = asyncio.run_coroutine_threadsafe(
             self.group_dispatcher(conn, msgs), self.home_loop)
@@ -3446,8 +3491,9 @@ class Messenger:
                             continue
                         try:
                             t_dec = time.monotonic()
-                            msg = decode_message(type_id, version, payload,
-                                                 blob, fixed)
+                            with tracing.section("messenger", "decode"):
+                                msg = decode_message(type_id, version,
+                                                     payload, blob, fixed)
                             if verified:
                                 # the frame layer checked the blob's crc:
                                 # handlers holding an app-level crc of the

@@ -31,6 +31,7 @@ import time
 import uuid
 from typing import Any, Dict, List, Optional, Set, Tuple
 
+from ceph_tpu.common import tracing
 from ceph_tpu.common.context import Context
 from ceph_tpu.common.perf_counters import PerfCountersBuilder
 from ceph_tpu.ec.interface import ErasureCodeError
@@ -338,6 +339,7 @@ class Monitor:
     # -- lifecycle -----------------------------------------------------------
 
     async def start(self, host: str = "127.0.0.1", port: int = 0) -> Tuple[str, int]:
+        tracing.install_loop_meter()
         self.messenger.dispatcher = self._dispatch
         if self.monmap:
             host, port = self.monmap[self.rank]

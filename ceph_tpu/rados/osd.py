@@ -48,6 +48,7 @@ from typing import Any, Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
+from ceph_tpu.common import tracing
 from ceph_tpu.common.context import Context
 from ceph_tpu.common.perf_counters import PerfCountersBuilder
 from ceph_tpu.ec.interface import ErasureCodeError
@@ -55,7 +56,7 @@ from ceph_tpu.ec.registry import registry
 from ceph_tpu.rados.crush import CRUSH_ITEM_NONE
 from ceph_tpu.rados.extent_cache import ExtentCache
 from ceph_tpu.utils.checksum import verify_any as crc_verify_any
-from ceph_tpu.rados.ecutil import (HashInfo, StripeInfo,
+from ceph_tpu.rados.ecutil import (ECPLAN_PERF, HashInfo, StripeInfo,
                                    batched_encode_async,
                                    batched_encode_group_async,
                                    decode_object_async,
@@ -345,8 +346,14 @@ class OSD:
             .add_u64_counter("recovery_errors", "repair rounds that errored")
             .add_u64_counter("op_queued", "ops entering the sharded queue")
             .add_u64_counter("op_dequeued", "ops drained")
-            .add_time_avg("op_queue_lat", "op service time")
             .add_u64_counter("heartbeat_failures", "peer failures reported")
+            .add_u64_counter("gather_timeouts",
+                             "sub-op gathers that gave up waiting for a "
+                             "reply (_gather's 5 s)")
+            .add_u64_counter("short_gather_acks",
+                             "puts acknowledged with fewer sub-write "
+                             "acks than live shards (each kicks the "
+                             "PG's recovery)")
             .add_u64_counter("backoffs_sent",
                              "MOSDBackoff blocks sent (op dropped, client "
                              "parks until release)")
@@ -547,9 +554,14 @@ class OSD:
         # sets are process-shared (as the resources are); every
         # colocated OSD dumps the same numbers.
         self.ctx.perf.add(self.messenger.perf)
+        for worker in getattr(self.messenger.reactors, "workers", ()):
+            meter = getattr(worker, "meter", None)  # thread-mode reactors
+            if meter is not None:
+                self.ctx.perf.add(meter.perf)
         from ceph_tpu.ops.gf2 import SCHED_PERF
 
         self.ctx.perf.add(SCHED_PERF)
+        self.ctx.perf.add(ECPLAN_PERF)
         try:
             from ceph_tpu.ec.plugins.tpu import PLUGIN_PERF
 
@@ -568,6 +580,7 @@ class OSD:
     # -- lifecycle -----------------------------------------------------------
 
     async def start(self) -> int:
+        tracing.install_loop_meter()
         self.messenger.dispatcher = self._dispatch
         self.messenger.group_dispatcher = self._dispatch_group
         self.addr = await self.messenger.bind()
@@ -1027,6 +1040,7 @@ class OSD:
         return None
 
     async def _ping_loop(self, interval: float) -> None:
+        tracing.mark("background")
         ticks = 0
         while not self._stopped:
             if self._inject_crash:
@@ -1095,6 +1109,7 @@ class OSD:
         """OSD<->OSD liveness (maybe_update_heartbeat_peers + heartbeat,
         OSD.cc:5278,5837): ping every up peer; a peer silent past the grace
         is reported to the mon as MOSDFailure."""
+        tracing.mark("background")
         grace = float(self.conf.get("osd_heartbeat_grace", 2.0) or 2.0)
         while not self._stopped:
             await asyncio.sleep(interval)
@@ -1261,6 +1276,10 @@ class OSD:
             i += 1
 
     async def _dispatch(self, conn, msg) -> None:
+        if isinstance(msg, (MOSDPing, MOSDPGHitSet, MMapReply, MLogAck)):
+            # liveness, hit-set archives and mon traffic: not the op
+            # path's time (the messenger puts the `osd` mark back)
+            tracing.mark("background")
         if isinstance(msg, MMapReply):
             if msg.osdmap is not None:
                 self._on_map(msg.osdmap)
@@ -1299,12 +1318,14 @@ class OSD:
             # queued_for_pg -> reached_pg gap measures real queue wait;
             # when the client propagated a trace context, our op span
             # JOINS it as a child — the cross-daemon stitch point
-            tracked = self._track_client_op(msg)
-            # client ops ride the sharded op queue: PG-pinned shard keeps
-            # per-PG order; scheduler arbitrates client vs recovery
-            # classes; a full queue blocks HERE so the messenger stops
-            # reading and backpressure reaches the sender
-            pg_key = self._pg_key_of(msg)
+            with tracing.section("osd", "track_op"):
+                tracked = self._track_client_op(msg)
+                # client ops ride the sharded op queue: PG-pinned shard
+                # keeps per-PG order; scheduler arbitrates client vs
+                # recovery classes; a full queue blocks HERE so the
+                # messenger stops reading and backpressure reaches the
+                # sender
+                pg_key = self._pg_key_of(msg)
             if msg.op in ("notify", "deep-scrub", "repair"):
                 # notify gathers watcher acks for seconds and touches no
                 # PG state: it runs as its OWN task so neither the shard
@@ -2249,7 +2270,7 @@ class OSD:
             for _ in range(expected):
                 out.append(await asyncio.wait_for(q.get(), timeout=timeout))
         except asyncio.TimeoutError:
-            pass
+            self.perf.inc("gather_timeouts")
         finally:
             self._collectors.pop(tid, None)
         return out
@@ -3059,53 +3080,55 @@ class OSD:
         return MOSDOpReply(ok=True, data=str(trimmed).encode())
 
     async def _do_write(self, op: MOSDOp) -> MOSDOpReply:
-        pool = self.osdmap.pools[op.pool_id]
-        pg, acting = self._acting(pool, op.oid)
-        if self._primary(pool, pg, acting) != self.osd_id:
-            return MOSDOpReply(ok=False, code=-errno.ESTALE,
-                               error="not primary")
-        live = [a for a in acting if a != CRUSH_ITEM_NONE]
-        if len(live) < pool.min_size:
-            return MOSDOpReply(
-                ok=False, code=-errno.EAGAIN,
-                error=f"degraded below min_size ({len(live)}/{pool.min_size})",
-                backoff=float(self.conf.get("osd_backoff_secs", 0.5) or 0),
-            )
-        log = self._pglog(op.pool_id, pg)
-        if log.has_reqid(op.reqid) and op.reqid not in self._failed_writes:
-            # client resend of an op we already applied (pg log dups role)
-            return MOSDOpReply(ok=True)
-        self._failed_writes.discard(op.reqid)
-        if op.offset >= 0 and not op.data:
-            return MOSDOpReply(ok=True)  # zero-length overwrite: no-op
+        with tracing.section("osd", "write_check"):
+            pool = self.osdmap.pools[op.pool_id]
+            pg, acting = self._acting(pool, op.oid)
+            if self._primary(pool, pg, acting) != self.osd_id:
+                return MOSDOpReply(ok=False, code=-errno.ESTALE,
+                                   error="not primary")
+            live = [a for a in acting if a != CRUSH_ITEM_NONE]
+            if len(live) < pool.min_size:
+                return MOSDOpReply(
+                    ok=False, code=-errno.EAGAIN,
+                    error=f"degraded below min_size ({len(live)}/{pool.min_size})",
+                    backoff=float(self.conf.get("osd_backoff_secs", 0.5) or 0),
+                )
+            log = self._pglog(op.pool_id, pg)
+            if log.has_reqid(op.reqid) and op.reqid not in self._failed_writes:
+                # client resend of an op we already applied (pg log dups role)
+                return MOSDOpReply(ok=True)
+            self._failed_writes.discard(op.reqid)
+            if op.offset >= 0 and not op.data:
+                return MOSDOpReply(ok=True)  # zero-length overwrite: no-op
         cow_err = await self._make_writeable(op, pool, pg, acting)
         if cow_err is not None:
             return cow_err
         if pool.pool_type != "ec":
             return await self._do_write_replicated(op, pool, pg, acting)
-        codec = self._codec(pool)
-        sinfo = self._sinfo(pool)
-        n = codec.get_chunk_count()
-        tracked = getattr(op, "_tracked", None)
-        parent = tracked.trace if tracked is not None else None
-        # the EC pipeline span is a CHILD of the op span (which itself
-        # joined the client's trace): the whole write renders as one tree
-        span = (parent.child("ec write") if parent is not None
-                else self.ctx.tracer.new_trace("ec write"))
-        span.event("start ec write")
+        with tracing.section("osd", "write_plan"):
+            codec = self._codec(pool)
+            sinfo = self._sinfo(pool)
+            n = codec.get_chunk_count()
+            tracked = getattr(op, "_tracked", None)
+            parent = tracked.trace if tracked is not None else None
+            # the EC pipeline span is a CHILD of the op span (which itself
+            # joined the client's trace): the whole write renders as one tree
+            span = (parent.child("ec write") if parent is not None
+                    else self.ctx.tracer.new_trace("ec write"))
+            span.event("start ec write")
 
-        def mark(event: str) -> None:
-            if tracked is not None:
-                tracked.mark_event(event)
-        # splice plan: chunk_off >= 0 means each shard splices `blobs[shard]`
-        # into its stored blob at chunk_off (per-stripe RMW, the reference's
-        # write plan ECTransaction.cc:37-95); -1 replaces the whole blob
-        data = op.data
-        chunk_off = -1
-        shard_size = 0
-        base_version = 0
-        object_size = len(op.data)
-        full_for_cache: Optional[bytes] = bytes(op.data)
+            def mark(event: str) -> None:
+                if tracked is not None:
+                    tracked.mark_event(event)
+            # splice plan: chunk_off >= 0 means each shard splices `blobs[shard]`
+            # into its stored blob at chunk_off (per-stripe RMW, the reference's
+            # write plan ECTransaction.cc:37-95); -1 replaces the whole blob
+            data = op.data
+            chunk_off = -1
+            shard_size = 0
+            base_version = 0
+            object_size = len(op.data)
+            full_for_cache: Optional[bytes] = bytes(op.data)
         if op.offset >= 0:
             span.event("rmw read")
             mark("rmw_read")
@@ -3221,108 +3244,111 @@ class OSD:
             blobs = await batched_encode_async(codec, sinfo, data,
                                                queue=self._ec_queue,
                                                span=span)
-        span.event("encoded")
-        mark("encoded")
-        # one crc pass per shard, shared by the hinfo record and every
-        # sub-write's chunk_crc (a fresh object's chained hinfo crc IS
-        # the shard crc)
-        shard_crcs = ([shard_crc(blobs[i])
-                       for i in range(codec.get_chunk_count())]
-                      if chunk_off < 0 else None)
-        hinfo_blob = (self._hinfo_for(pool, blobs, crcs=shard_crcs)
-                      if chunk_off < 0 else b"")
-        # Allocate the eversion only after every await above; from here to
-        # the local apply the path is synchronous, so the head cannot move
-        # underneath us.
-        entry = LogEntry(version=log.next_version(self.osdmap.epoch),
-                         op="write", oid=op.oid, prior_version=log.head,
-                         reqid=op.reqid)
-        version = pack_eversion(entry.version)
-        entry.object_version = version
-        entry_blob = entry.encode()
-        tid = uuid.uuid4().hex
-        local_ok = 0
-        wb_shards: set = set()
-        if chunk_off < 0 and planar is None and self._planar is not None:
-            # gated / ineligible / empty full write: it supersedes any
-            # existing resident, and the resident must die NOW, dirty
-            # included — the write-through applies below land the newer
-            # version, and a surviving writeback record would later
-            # replay its OLD deferred shard bytes over them (the flush
-            # validates against the resident's own meta; same
-            # synchronous window as the applies, so the agent cannot
-            # interleave)
-            self._planar.drop(self._planar_key(op.pool_id, op.oid),
-                              force=True)
-        if install == "writeback" and planar is not None:
-            # writeback: the local shard applies defer into dirty pages
-            # (log entry commits NOW, flush replays the applies later);
-            # still synchronous — no await between the eversion above
-            # and here, so the head cannot move underneath the install
-            locals_ = [s for s, o_ in enumerate(acting)
-                       if o_ == self.osd_id]
-            if locals_:
-                wb_shards = self._tier_writeback_install(
-                    op, pool, pg, planar, version, object_size, entry,
-                    locals_, shard_crcs, hinfo_blob, data)
-                if wb_shards:
-                    span.event(f"writeback install ({len(wb_shards)} "
-                               f"local applies deferred)")
-        remote: List[Tuple[int, int]] = []  # (shard, osd)
-        for shard, osd in enumerate(acting):
-            if osd == CRUSH_ITEM_NONE:
-                continue
-            if osd == self.osd_id:
-                if shard in wb_shards:
-                    # deferred to flush: the dirty page IS this shard's
-                    # copy until then (counted acked — same durability
-                    # as the store apply, both are process-local)
-                    local_ok += 1
+        with tracing.section("osd", "write_commit"):
+            span.event("encoded")
+            mark("encoded")
+            # one crc pass per shard, shared by the hinfo record and every
+            # sub-write's chunk_crc (a fresh object's chained hinfo crc IS
+            # the shard crc)
+            with tracing.section("osd", "shard_crc"):
+                shard_crcs = ([shard_crc(blobs[i])
+                               for i in range(codec.get_chunk_count())]
+                              if chunk_off < 0 else None)
+            hinfo_blob = (self._hinfo_for(pool, blobs, crcs=shard_crcs)
+                          if chunk_off < 0 else b"")
+            # Allocate the eversion only after every await above; from here to
+            # the local apply the path is synchronous, so the head cannot move
+            # underneath us.
+            entry = LogEntry(version=log.next_version(self.osdmap.epoch),
+                             op="write", oid=op.oid, prior_version=log.head,
+                             reqid=op.reqid)
+            version = pack_eversion(entry.version)
+            entry.object_version = version
+            entry_blob = entry.encode()
+            tid = uuid.uuid4().hex
+            local_ok = 0
+            wb_shards: set = set()
+            if chunk_off < 0 and planar is None and self._planar is not None:
+                # gated / ineligible / empty full write: it supersedes any
+                # existing resident, and the resident must die NOW, dirty
+                # included — the write-through applies below land the newer
+                # version, and a surviving writeback record would later
+                # replay its OLD deferred shard bytes over them (the flush
+                # validates against the resident's own meta; same
+                # synchronous window as the applies, so the agent cannot
+                # interleave)
+                self._planar.drop(self._planar_key(op.pool_id, op.oid),
+                                  force=True)
+            if install == "writeback" and planar is not None:
+                # writeback: the local shard applies defer into dirty pages
+                # (log entry commits NOW, flush replays the applies later);
+                # still synchronous — no await between the eversion above
+                # and here, so the head cannot move underneath the install
+                locals_ = [s for s, o_ in enumerate(acting)
+                           if o_ == self.osd_id]
+                if locals_:
+                    wb_shards = self._tier_writeback_install(
+                        op, pool, pg, planar, version, object_size, entry,
+                        locals_, shard_crcs, hinfo_blob, data)
+                    if wb_shards:
+                        span.event(f"writeback install ({len(wb_shards)} "
+                                   f"local applies deferred)")
+            remote: List[Tuple[int, int]] = []  # (shard, osd)
+            for shard, osd in enumerate(acting):
+                if osd == CRUSH_ITEM_NONE:
                     continue
-                # the local shard gets a sub-write span of its own, so
-                # the stitched trace shows ALL k+m shard applies (the
-                # remote peers record theirs in their own rings)
-                with span.child(f"ec_sub_write s{shard}") as lsp:
-                    lsp.tag("osd", self.osd_id).tag("local", True)
-                    # memoryview, not bytes(): ownership of the fresh
-                    # encode-output row passes to the store (Owned
-                    # marking in _apply_shard_write) — no per-shard copy
-                    if self._apply_shard_write(
-                        op.pool_id, op.oid, shard,
-                        memoryview(np.ascontiguousarray(blobs[shard])),
-                        version,
-                        object_size, pg=pg, entry=entry,
-                        chunk_off=chunk_off,
+                if osd == self.osd_id:
+                    if shard in wb_shards:
+                        # deferred to flush: the dirty page IS this shard's
+                        # copy until then (counted acked — same durability
+                        # as the store apply, both are process-local)
+                        local_ok += 1
+                        continue
+                    # the local shard gets a sub-write span of its own, so
+                    # the stitched trace shows ALL k+m shard applies (the
+                    # remote peers record theirs in their own rings)
+                    with span.child(f"ec_sub_write s{shard}") as lsp:
+                        lsp.tag("osd", self.osd_id).tag("local", True)
+                        # memoryview, not bytes(): ownership of the fresh
+                        # encode-output row passes to the store (Owned
+                        # marking in _apply_shard_write) — no per-shard copy
+                        if self._apply_shard_write(
+                            op.pool_id, op.oid, shard,
+                            memoryview(np.ascontiguousarray(blobs[shard])),
+                            version,
+                            object_size, pg=pg, entry=entry,
+                            chunk_off=chunk_off,
+                            shard_size=shard_size, hinfo=hinfo_blob,
+                            prior_version=base_version,
+                            chunk_crc=(shard_crcs[shard]
+                                       if shard_crcs is not None else None),
+                        ):
+                            local_ok += 1
+                else:
+                    remote.append((shard, osd))
+            q = self._collector(tid)
+            sends = []
+            # trace propagation on the fan-out: each peer joins a child
+            # ec_sub_write span under OUR ec-write span (feature-gated)
+            w_tid, w_sid = (span.context() if self._trace_on else ("", ""))
+            with tracing.section("osd", "fanout_build"):
+                for shard, osd in remote:
+                    # memoryview: the shard row rides the messenger's blob lane
+                    # without a bytes() copy; crc reuses the per-shard pass above
+                    chunk = memoryview(np.ascontiguousarray(blobs[shard]))
+                    crc = (shard_crcs[shard] if shard_crcs is not None
+                           else shard_crc(chunk))
+                    msg = MECSubWrite(
+                        pool_id=op.pool_id, pg=pg, oid=op.oid, shard=shard, chunk=chunk,
+                        version=version, object_size=object_size,
+                        chunk_crc=crc, tid=tid, reply_to=self.addr,
+                        log_entry=entry_blob, chunk_off=chunk_off,
                         shard_size=shard_size, hinfo=hinfo_blob,
                         prior_version=base_version,
-                        chunk_crc=(shard_crcs[shard]
-                                   if shard_crcs is not None else None),
-                    ):
-                        local_ok += 1
-            else:
-                remote.append((shard, osd))
-        q = self._collector(tid)
-        sends = []
-        # trace propagation on the fan-out: each peer joins a child
-        # ec_sub_write span under OUR ec-write span (feature-gated)
-        w_tid, w_sid = (span.context() if self._trace_on else ("", ""))
-        for shard, osd in remote:
-            # memoryview: the shard row rides the messenger's blob lane
-            # without a bytes() copy; crc reuses the per-shard pass above
-            chunk = memoryview(np.ascontiguousarray(blobs[shard]))
-            crc = (shard_crcs[shard] if shard_crcs is not None
-                   else shard_crc(chunk))
-            msg = MECSubWrite(
-                pool_id=op.pool_id, pg=pg, oid=op.oid, shard=shard, chunk=chunk,
-                version=version, object_size=object_size,
-                chunk_crc=crc, tid=tid, reply_to=self.addr,
-                log_entry=entry_blob, chunk_off=chunk_off,
-                shard_size=shard_size, hinfo=hinfo_blob,
-                prior_version=base_version,
-                from_osd=self.osd_id, epoch=self.osdmap.epoch,
-                trace_id=w_tid, span_id=w_sid,
-            )
-            sends.append(self.messenger.send(self.osdmap.addr_of(osd), msg))
+                        from_osd=self.osd_id, epoch=self.osdmap.epoch,
+                        trace_id=w_tid, span_id=w_sid,
+                    )
+                    sends.append(self.messenger.send(self.osdmap.addr_of(osd), msg))
         # CONCURRENT stripe fan-out: all k+m sub-writes enqueue and their
         # per-connection flushes interleave on the loop, instead of each
         # send serializing on the previous one's socket drain; a failed
@@ -3337,53 +3363,55 @@ class OSD:
         mark("sub_writes_sent")
         mark("waiting_for_subops")
         replies = await self._gather(tid, q, sent)
-        span.event("commit gathered")
-        mark("commit_gathered")
-        span.finish()
-        acks = local_ok + sum(1 for r in replies if r.ok)  # self + remote
-        if acks < pool.min_size:
-            # the entry is logged but the write failed: a same-reqid resend
-            # must re-execute rather than be deduped into false success
-            self._mark_failed_write(op.reqid)
-            self._cache_drop(op.pool_id, op.oid)
-            return MOSDOpReply(
-                ok=False, code=-errno.EBUSY,
-                error=f"write acked by {acks} < min_size {pool.min_size}"
-            )
-        if acks < len(live):
-            # acked but DEGRADED: a member missed its sub-write (lost
-            # frame, refused splice).  The reference marks it missing and
-            # recovers promptly; waiting for the next interval change
-            # would leave the object one failure from loss
-            self._kick_recovery(pool, pg)
-        if planar is not None and not wb_shards:
-            # install the residency only once the write is DURABLE (and
-            # under the version it landed as): a failed write must not
-            # leave resident rows that reads would serve.  (A writeback
-            # install already landed — dirty, pre-fan-out — because its
-            # pages ARE the deferred local applies.)
-            pkey = self._planar_key(op.pool_id, op.oid)
-            k_ = codec.get_data_chunk_count()
-            if self._install_resident(pkey, planar, version,
-                                      object_size, k_):
-                # seed the exit-boundary memo with the just-written
-                # bytes: the first resident-hit read serves host bytes
-                # instead of paying a device pack (memo_put contract)
-                if isinstance(data, bytes) and len(data) == object_size:
-                    self._planar.memo_put(pkey, version, data)
-        if full_for_cache is not None:
-            self._cache_put(op.pool_id, op.oid, version, full_for_cache)
-        elif chunk_off >= 0:
-            # segment RMW: pin the freshly-written stripes at the NEW
-            # version; carry_from upgrades the entry in place (nothing
-            # outside this extent changed — our write made the version)
-            self._extent_cache.put_extent(
-                (op.pool_id, op.oid), version,
-                sinfo.aligned_chunk_offset_to_logical_offset(chunk_off),
-                data, size_hint=object_size, carry_from=base_version)
-        else:
-            self._cache_drop(op.pool_id, op.oid)
-        return MOSDOpReply(ok=True)
+        with tracing.section("osd", "write_finish"):
+            span.event("commit gathered")
+            mark("commit_gathered")
+            span.finish()
+            acks = local_ok + sum(1 for r in replies if r.ok)  # self + remote
+            if acks < pool.min_size:
+                # the entry is logged but the write failed: a same-reqid resend
+                # must re-execute rather than be deduped into false success
+                self._mark_failed_write(op.reqid)
+                self._cache_drop(op.pool_id, op.oid)
+                return MOSDOpReply(
+                    ok=False, code=-errno.EBUSY,
+                    error=f"write acked by {acks} < min_size {pool.min_size}"
+                )
+            if acks < len(live):
+                # acked but DEGRADED: a member missed its sub-write (lost
+                # frame, refused splice).  The reference marks it missing and
+                # recovers promptly; waiting for the next interval change
+                # would leave the object one failure from loss
+                self.perf.inc("short_gather_acks")
+                self._kick_recovery(pool, pg)
+            if planar is not None and not wb_shards:
+                # install the residency only once the write is DURABLE (and
+                # under the version it landed as): a failed write must not
+                # leave resident rows that reads would serve.  (A writeback
+                # install already landed — dirty, pre-fan-out — because its
+                # pages ARE the deferred local applies.)
+                pkey = self._planar_key(op.pool_id, op.oid)
+                k_ = codec.get_data_chunk_count()
+                if self._install_resident(pkey, planar, version,
+                                          object_size, k_):
+                    # seed the exit-boundary memo with the just-written
+                    # bytes: the first resident-hit read serves host bytes
+                    # instead of paying a device pack (memo_put contract)
+                    if isinstance(data, bytes) and len(data) == object_size:
+                        self._planar.memo_put(pkey, version, data)
+            if full_for_cache is not None:
+                self._cache_put(op.pool_id, op.oid, version, full_for_cache)
+            elif chunk_off >= 0:
+                # segment RMW: pin the freshly-written stripes at the NEW
+                # version; carry_from upgrades the entry in place (nothing
+                # outside this extent changed — our write made the version)
+                self._extent_cache.put_extent(
+                    (op.pool_id, op.oid), version,
+                    sinfo.aligned_chunk_offset_to_logical_offset(chunk_off),
+                    data, size_hint=object_size, carry_from=base_version)
+            else:
+                self._cache_drop(op.pool_id, op.oid)
+            return MOSDOpReply(ok=True)
 
     async def _read_stripe_range(self, op: MOSDOp, pool: PoolInfo, codec,
                                  sinfo: StripeInfo, s0: int,
@@ -3489,52 +3517,53 @@ class OSD:
         pool = self.osdmap.pools[op.pool_id]
         if pool.pool_type != "ec":
             return await self._do_read_replicated(op, pool, exclude_shards)
-        codec = self._codec(pool)
-        pg, acting = self._acting(pool, op.oid)
-        k = codec.get_data_chunk_count()
-        if (self._planar is not None and not exclude_shards
-                and self._primary(pool, pg, acting) == self.osd_id):
-            # planar fast path — a TRUE zero-shard-read: the primary's PG
-            # log is the authoritative per-object version source, so when
-            # the HBM resident matches the log's newest entry for this
-            # oid, the data rows pack straight out — no sub-reads, no
-            # decode.  Any mismatch (trimmed window, rewound log, stale
-            # resident, delete) falls through to the quorum path.
-            # exclude_shards (scrub repair) always takes the quorum path:
-            # repair must observe the STORED shards, not our cache.
-            ent = self._pglog(op.pool_id, pg).latest_entry(op.oid)
-            if ent is not None and ent.op == "write":
-                # meta-only probe (no gather): the paged store would pay
-                # a page-table gather for a get_planar here, and the
-                # memo inside planar_object_bytes serves the common case
-                meta = self._planar.resident_meta(
-                    self._planar_key(op.pool_id, op.oid))
-                if meta is not None:
-                    if (meta and len(meta) >= 3
-                            and meta[0] == ent.object_version):
-                        data = planar_object_bytes(
-                            self._planar,
-                            self._planar_key(op.pool_id, op.oid),
-                            ent.object_version, k,
-                            self._sinfo(pool).chunk_size, meta[2])
-                        if data is None:
-                            # raw fast-ack resident (w=0, whole-object
-                            # bytes, no planar rows): the memo inside
-                            # planar_object_bytes missed — gather the
-                            # object straight off the page table
-                            rr = getattr(self._planar, "read_raw", None)
-                            data = rr(self._planar_key(
-                                op.pool_id, op.oid)) if rr else None
-                        if data is not None:
-                            self.perf.inc("planar_read_hits")
-                            self.tier_perf.inc("resident_hit")
-                            self.tier_perf.inc("resident_hit_bytes",
-                                               len(data))
-                            t = getattr(op, "_tracked", None)
-                            if t is not None:
-                                t.mark_event("resident_hit")
-                            return MOSDOpReply(ok=True, data=data,
-                                               version=ent.object_version)
+        with tracing.section("osd", "read_resident"):
+            codec = self._codec(pool)
+            pg, acting = self._acting(pool, op.oid)
+            k = codec.get_data_chunk_count()
+            if (self._planar is not None and not exclude_shards
+                    and self._primary(pool, pg, acting) == self.osd_id):
+                # planar fast path — a TRUE zero-shard-read: the primary's PG
+                # log is the authoritative per-object version source, so when
+                # the HBM resident matches the log's newest entry for this
+                # oid, the data rows pack straight out — no sub-reads, no
+                # decode.  Any mismatch (trimmed window, rewound log, stale
+                # resident, delete) falls through to the quorum path.
+                # exclude_shards (scrub repair) always takes the quorum path:
+                # repair must observe the STORED shards, not our cache.
+                ent = self._pglog(op.pool_id, pg).latest_entry(op.oid)
+                if ent is not None and ent.op == "write":
+                    # meta-only probe (no gather): the paged store would pay
+                    # a page-table gather for a get_planar here, and the
+                    # memo inside planar_object_bytes serves the common case
+                    meta = self._planar.resident_meta(
+                        self._planar_key(op.pool_id, op.oid))
+                    if meta is not None:
+                        if (meta and len(meta) >= 3
+                                and meta[0] == ent.object_version):
+                            data = planar_object_bytes(
+                                self._planar,
+                                self._planar_key(op.pool_id, op.oid),
+                                ent.object_version, k,
+                                self._sinfo(pool).chunk_size, meta[2])
+                            if data is None:
+                                # raw fast-ack resident (w=0, whole-object
+                                # bytes, no planar rows): the memo inside
+                                # planar_object_bytes missed — gather the
+                                # object straight off the page table
+                                rr = getattr(self._planar, "read_raw", None)
+                                data = rr(self._planar_key(
+                                    op.pool_id, op.oid)) if rr else None
+                            if data is not None:
+                                self.perf.inc("planar_read_hits")
+                                self.tier_perf.inc("resident_hit")
+                                self.tier_perf.inc("resident_hit_bytes",
+                                                   len(data))
+                                t = getattr(op, "_tracked", None)
+                                if t is not None:
+                                    t.mark_event("resident_hit")
+                                return MOSDOpReply(ok=True, data=data,
+                                                   version=ent.object_version)
         available = {
             shard: osd for shard, osd in enumerate(acting)
             if osd != CRUSH_ITEM_NONE and shard not in exclude_shards
@@ -4579,6 +4608,7 @@ class OSD:
 
     # -- shard side ----------------------------------------------------------
 
+    @tracing.sectioned("osd", "shard_apply")
     def _apply_shard_write(
         self, pool_id: int, oid: str, shard: int, chunk: bytes, version: int,
         object_size: int, pg: Optional[int] = None,
@@ -4645,9 +4675,10 @@ class OSD:
         )
         if entry is not None and pg is not None:
             self._log_in_txn(txn, pool_id, pg, entry)
-        self.store.queue_transaction(txn)
-        self._update_hinfo(pool_id, oid, shard, blob, chunk, hinfo,
-                           chunk_off, appended)
+        with tracing.section("store", "commit"):
+            self.store.queue_transaction(txn)
+            self._update_hinfo(pool_id, oid, shard, blob, chunk, hinfo,
+                               chunk_off, appended)
         return True
 
     def _update_hinfo(self, pool_id: int, oid: str, shard: int, blob: bytes,
@@ -4736,66 +4767,67 @@ class OSD:
                     if (self._primary(pool, msg.pg, acting)
                             not in (sender, None)):
                         ok = False
-            if not ok:
-                tracked.mark_event("refused_interval")
-            elif msg.chunk_crc and not getattr(msg, "_wire_verified", False) \
-                    and not crc_verify_any(msg.chunk, msg.chunk_crc):
-                # _wire_verified: the frame layer already checked the blob
-                # against chunk_crc (the sender reused it as the wire crc)
-                # — a second pass over the same bytes proves nothing new
-                ok = False  # corrupted in flight
-                tracked.mark_event("refused_crc")
-            else:
-                entry = LogEntry.decode(msg.log_entry) \
-                    if msg.log_entry else None
-                if entry is not None:
-                    entry.version = tuple(entry.version)
-                    entry.prior_version = tuple(entry.prior_version)
-                enospc = False
-                try:
-                    ok = self._apply_shard_write(
-                        msg.pool_id, msg.oid, msg.shard, msg.chunk,
-                        msg.version,
-                        msg.object_size, pg=msg.pg, entry=entry,
-                        chunk_off=msg.chunk_off,
-                        shard_size=msg.shard_size,
-                        hinfo=msg.hinfo, prior_version=msg.prior_version,
-                        # just verified against the frame: reuse, don't
-                        # re-crc
-                        chunk_crc=msg.chunk_crc or None,
-                    )
-                except ENOSPCError:
-                    # this shard's store is failsafe-full: refuse (one
-                    # missing ack at the primary), never mutate
-                    ok = False
-                    enospc = True
-                # another primary wrote this object: cached decode is
-                # stale.  EXCEPTION: an adopted raw fast-ack copy at (or
-                # past) this sub-write's version IS the cache-tier
-                # durability of an ACKED write — this sub-write is that
-                # write's own flush landing, and force-dropping the copy
-                # here would reopen the acked-data-loss window the
-                # replication closed (primary dies mid-flush).  The copy
-                # is released only by the owner's post-flush clear.
-                self._extent_cache.drop((msg.pool_id, msg.oid))
-                _pkey = self._planar_key(msg.pool_id, msg.oid)
-                _spare = False
-                _ps = self._paged_store()
-                if _ps is not None:
-                    _snap = _ps.peek_dirty(_pkey)
-                    if _snap is not None \
-                            and isinstance(_snap[0], CacheDirtyRecord) \
-                            and _snap[0].version >= msg.version:
-                        _spare = True
-                if not _spare and self._planar is not None:
-                    self._planar.drop(_pkey, force=True)
-                # ONE event per outcome: an ENOSPC refusal must not also
-                # count as a splice/crc refusal in the op timeline
-                tracked.mark_event("applied" if ok
-                                   else "refused_enospc" if enospc
-                                   else "refused_splice")
-                if ok:
-                    self.perf.inc("subop_w")
+            with tracing.section("osd", "sub_write_apply"):
+                if not ok:
+                    tracked.mark_event("refused_interval")
+                elif msg.chunk_crc and not getattr(msg, "_wire_verified", False) \
+                        and not crc_verify_any(msg.chunk, msg.chunk_crc):
+                    # _wire_verified: the frame layer already checked the blob
+                    # against chunk_crc (the sender reused it as the wire crc)
+                    # — a second pass over the same bytes proves nothing new
+                    ok = False  # corrupted in flight
+                    tracked.mark_event("refused_crc")
+                else:
+                    entry = LogEntry.decode(msg.log_entry) \
+                        if msg.log_entry else None
+                    if entry is not None:
+                        entry.version = tuple(entry.version)
+                        entry.prior_version = tuple(entry.prior_version)
+                    enospc = False
+                    try:
+                        ok = self._apply_shard_write(
+                            msg.pool_id, msg.oid, msg.shard, msg.chunk,
+                            msg.version,
+                            msg.object_size, pg=msg.pg, entry=entry,
+                            chunk_off=msg.chunk_off,
+                            shard_size=msg.shard_size,
+                            hinfo=msg.hinfo, prior_version=msg.prior_version,
+                            # just verified against the frame: reuse, don't
+                            # re-crc
+                            chunk_crc=msg.chunk_crc or None,
+                        )
+                    except ENOSPCError:
+                        # this shard's store is failsafe-full: refuse (one
+                        # missing ack at the primary), never mutate
+                        ok = False
+                        enospc = True
+                    # another primary wrote this object: cached decode is
+                    # stale.  EXCEPTION: an adopted raw fast-ack copy at (or
+                    # past) this sub-write's version IS the cache-tier
+                    # durability of an ACKED write — this sub-write is that
+                    # write's own flush landing, and force-dropping the copy
+                    # here would reopen the acked-data-loss window the
+                    # replication closed (primary dies mid-flush).  The copy
+                    # is released only by the owner's post-flush clear.
+                    self._extent_cache.drop((msg.pool_id, msg.oid))
+                    _pkey = self._planar_key(msg.pool_id, msg.oid)
+                    _spare = False
+                    _ps = self._paged_store()
+                    if _ps is not None:
+                        _snap = _ps.peek_dirty(_pkey)
+                        if _snap is not None \
+                                and isinstance(_snap[0], CacheDirtyRecord) \
+                                and _snap[0].version >= msg.version:
+                            _spare = True
+                    if not _spare and self._planar is not None:
+                        self._planar.drop(_pkey, force=True)
+                    # ONE event per outcome: an ENOSPC refusal must not also
+                    # count as a splice/crc refusal in the op timeline
+                    tracked.mark_event("applied" if ok
+                                       else "refused_enospc" if enospc
+                                       else "refused_splice")
+                    if ok:
+                        self.perf.inc("subop_w")
         finally:
             if span is not None:
                 span.tag("ok", ok)
@@ -5318,6 +5350,7 @@ class OSD:
                          meta=(version, n_cols, object_size))
         return True
 
+    @tracing.sectioned("osd", "tier_install_decision")
     def _tier_write_install(self, op: MOSDOp, pool: PoolInfo, pg: int,
                             acting: List[int], nbytes: int,
                             full: bool) -> Optional[str]:
@@ -6164,6 +6197,7 @@ class OSD:
         self.messenger._tasks.add(t)
         t.add_done_callback(self.messenger._tasks.discard)
 
+    @tracing.sectioned("osd", "tier_observe_read")
     def _tier_observe_read(self, op: MOSDOp, reply: MOSDOpReply) -> None:
         """Read-path tier hook (reference PrimaryLogPG::maybe_promote):
         record the hit in the PG's hit-set archive and, when the
@@ -6307,9 +6341,11 @@ class OSD:
                  if a not in (CRUSH_ITEM_NONE, self.osd_id)]
         if not peers:
             return
-        msg = MOSDPGHitSet(pool_id=pool.pool_id, pg=pg,
-                           from_osd=self.osd_id, epoch=self.osdmap.epoch,
-                           archive=arch.encode())
+        with tracing.section("background", "hitset_encode"):
+            msg = MOSDPGHitSet(pool_id=pool.pool_id, pg=pg,
+                               from_osd=self.osd_id,
+                               epoch=self.osdmap.epoch,
+                               archive=arch.encode())
         span = None
         if self._trace_on:
             span = self.ctx.tracer.new_trace("hitset push")
@@ -6317,6 +6353,7 @@ class OSD:
             msg.trace_id, msg.span_id = span.context()
 
         async def _send() -> None:
+            tracing.mark("background")
             tracked = self.ctx.op_tracker.create(
                 f"hitset_push({pool.pool_id}.{pg})")
             try:
@@ -6339,6 +6376,7 @@ class OSD:
         self.messenger._tasks.add(t)
         t.add_done_callback(self.messenger._tasks.discard)
 
+    @tracing.sectioned("background", "hitset_apply")
     def _handle_pg_hit_set(self, msg: MOSDPGHitSet) -> None:
         if msg.from_osd == self.osd_id or self.osdmap is None:
             return
@@ -6436,6 +6474,7 @@ class OSD:
     async def _tier_agent_pass(self) -> None:
         # the evict agent's pass is a tracked op like any other: a
         # wedged agent shows up in dump_ops_in_flight with its age
+        tracing.mark("background")
         tracked = self.ctx.op_tracker.create("tier_agent_pass")
         try:
             with self.tier_perf.time_avg("agent_pass_s"):
@@ -6450,6 +6489,7 @@ class OSD:
             self._tier_agent_busy = False
             tracked.finish()
 
+    @tracing.sectioned("background", "tier_agent")
     def _tier_agent_once(self) -> None:
         """One flush/evict pass.  Flush plane first (paged store only):
         dirty residents flush on the dirty-ratio / age / fullness

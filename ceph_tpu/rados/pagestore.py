@@ -64,6 +64,7 @@ from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
+from ceph_tpu.common import tracing
 from ceph_tpu.common.perf_counters import PerfCounters, PerfCountersBuilder
 
 _SLAB_SHIFT = 8  # 2**8 pages per lazily-committed sub-slab
@@ -473,6 +474,7 @@ class PagedResidentStore:
                                               idx)
         return pages
 
+    @tracing.sectioned("store", "resident_install")
     def put_planar(self, key: Any, bits, w: int = 8,
                    n_rows: Optional[int] = None, meta: Any = None,
                    trim: Optional[int] = None,
@@ -605,6 +607,7 @@ class PagedResidentStore:
 
     # -- raw dirty objects (writeback fast-ack path) -------------------------
 
+    @tracing.sectioned("store", "resident_install_raw")
     def put_raw(self, key: Any, data: bytes, meta: Any = None,
                 dirty_info: Any = None,
                 now: Optional[float] = None) -> bool:
@@ -629,6 +632,7 @@ class PagedResidentStore:
             e = self._entries.get(key)
             return e is not None and e.w == 0
 
+    @tracing.sectioned("store", "resident_read_raw")
     def read_raw(self, key: Any) -> Optional[bytes]:
         """The raw entry's object bytes (None when absent, partial, or
         not a raw entry).  On the device arm the single materialization
@@ -710,6 +714,7 @@ class PagedResidentStore:
             out = jax.lax.bitcast_convert_type(out, jnp.int8)
         return out.reshape(r1 - r0, e.cols)
 
+    @tracing.sectioned("store", "resident_gather")
     def gather_rows(self, key: Any, r0: int, r1: int):
         """[r1-r0, cols] array gathered from the page table, or None
         when the entry is absent or any needed page was evicted (a
@@ -722,6 +727,7 @@ class PagedResidentStore:
                 return None
             return self._gather_locked(e, r0, r1)
 
+    @tracing.sectioned("store", "resident_probe")
     def touch(self, key: Any):
         """(w, n_rows, meta) with LRU refresh + hit/miss counting — the
         read path's entry probe, materializing nothing."""
@@ -857,6 +863,7 @@ class PagedResidentStore:
 
     # -- eviction ------------------------------------------------------------
 
+    @tracing.sectioned("store", "resident_evict")
     def drop(self, key: Any, force: bool = False) -> bool:
         """Remove `key` if resident; True when an entry was actually
         dropped.  A DIRTY entry refuses (flush-before-evict: writeback
@@ -880,6 +887,7 @@ class PagedResidentStore:
         self.perf.inc("page_evictions", freed)
         return True
 
+    @tracing.sectioned("store", "resident_shed")
     def shed_parity(self, key: Any) -> int:
         """Partial eviction: free the CLEAN page suffix past the
         data-row boundary (the parity rows).  The data prefix keeps
@@ -965,6 +973,7 @@ class PagedResidentStore:
         if got is not None:
             self.memo_bytes -= self._memo_charge(self._memo_raw.pop(key))
 
+    @tracing.sectioned("store", "memo_get")
     def memo_get(self, key: Any, version: Any):
         with self._lock:
             if key not in self._entries:
@@ -974,6 +983,7 @@ class PagedResidentStore:
             return None
         return got[1]
 
+    @tracing.sectioned("store", "memo_put")
     def memo_put(self, key: Any, version: Any, value: Any) -> None:
         """As PlanarShardStore.memo_put, but the cap accounting is in
         PAGE units against the pool's byte size — the memo gauge can

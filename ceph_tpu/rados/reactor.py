@@ -40,6 +40,8 @@ import threading
 import traceback
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from ceph_tpu.common import tracing
+
 # Per-process identity token: two messengers whose handshakes carry the
 # same token ARE the same process, so an in-process ring is reachable.
 # Random (not pid): pid alone would false-positive across containers or
@@ -60,6 +62,9 @@ class ReactorWorker(threading.Thread):
         super().__init__(name=f"{name}-reactor-{index}", daemon=True)
         self.index = index
         self.loop = asyncio.new_event_loop()
+        # this reactor's own `loop.<thread name>` counter set (the owning
+        # daemon lists it beside the home loop's `loop`)
+        self.meter = tracing.install_loop_meter(self.loop, name=self.name)
         self._started = threading.Event()
         # shard accounting for dump_reactors / the bench's reactor
         # balance: plain ints under the GIL, written only from this

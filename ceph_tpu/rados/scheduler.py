@@ -59,6 +59,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Awaitable, Callable, Dict, List, Optional, Tuple
 
+from ceph_tpu.common import tracing
 from ceph_tpu.rados.qos import ClientRegistry, ClientState, QosParams
 
 CLASS_CLIENT = "client"
@@ -425,23 +426,25 @@ class ShardedOpQueue:
         cost = max(1, cost)
         await self._budget.get(cost)  # blocks when queues are full
         self.inflight_ops += 1
-        shard = self.shard_of(pg_key)
-        # ordered=False: shard by PG but skip the per-key ordering chain
-        # (background throttle waiters need scheduling arbitration only;
-        # chaining them onto a PG's client tail from inside a sweep that
-        # itself waits on the grant could deadlock the sweep)
-        self._scheds[shard].enqueue(op_class, run, cost, priority=priority,
-                                    order_key=pg_key if ordered else None,
-                                    client=client,
-                                    qos=qos, qos_cost=qos_cost)
-        if self.perf is not None:
-            self.perf.inc("op_queued")
-        if self.sched_perf is not None:
-            self.sched_perf.ensure(f"enqueue_{op_class}")
-            self.sched_perf.inc(f"enqueue_{op_class}")
-            self.sched_perf.set("queue_depth", self.depth())
-            self.sched_perf.set("qos_clients", self.qos_clients())
-        self._events[shard].set()
+        with tracing.section("osd", "opq_enqueue"):
+            shard = self.shard_of(pg_key)
+            # ordered=False: shard by PG but skip the per-key ordering
+            # chain (background throttle waiters need scheduling
+            # arbitration only; chaining them onto a PG's client tail from
+            # inside a sweep that itself waits on the grant could deadlock
+            # the sweep)
+            self._scheds[shard].enqueue(
+                op_class, run, cost, priority=priority,
+                order_key=pg_key if ordered else None, client=client,
+                qos=qos, qos_cost=qos_cost)
+            if self.perf is not None:
+                self.perf.inc("op_queued")
+            if self.sched_perf is not None:
+                self.sched_perf.ensure(f"enqueue_{op_class}")
+                self.sched_perf.inc(f"enqueue_{op_class}")
+                self.sched_perf.set("queue_depth", self.depth())
+                self.sched_perf.set("qos_clients", self.qos_clients())
+            self._events[shard].set()
 
     async def _drain(self, shard: int) -> None:
         """Shard worker: ops with the SAME order_key (PG) run strictly in
@@ -473,7 +476,6 @@ class ShardedOpQueue:
                     await asyncio.gather(after, return_exceptions=True)
                     await slots.acquire()
                     holds_slot = True
-                t0 = time.monotonic()
                 try:
                     await item.run()
                 except asyncio.CancelledError:
@@ -484,8 +486,6 @@ class ShardedOpQueue:
                     traceback.print_exc()
                 if self.perf is not None:
                     self.perf.inc("op_dequeued")
-                    self.perf.tinc("op_queue_lat",
-                                   time.monotonic() - t0)
             finally:
                 if holds_slot:
                     slots.release()
@@ -504,7 +504,8 @@ class ShardedOpQueue:
             # into tasks up front would hand ordering to the FIFO
             # semaphore and bypass QoS entirely under load.
             await slots.acquire()
-            item = sched.dequeue()
+            with tracing.section("osd", "opq_dequeue"):
+                item = sched.dequeue()
             if item is None:
                 slots.release()
                 event.clear()

@@ -14,6 +14,7 @@ import argparse
 import asyncio
 from typing import Dict, List, Optional
 
+from ceph_tpu.common import tracing
 from ceph_tpu.rados.bluestore import BlueStore
 from ceph_tpu.rados.client import RadosClient
 from ceph_tpu.rados.mon import Monitor
@@ -85,6 +86,7 @@ class Cluster:
         return ports
 
     async def start(self) -> None:
+        tracing.install_loop_meter()
         if self.n_mons == 1:
             mon = Monitor(self.conf,
                           data_path=(f"{self.data_dir}/mon.0/store.db"

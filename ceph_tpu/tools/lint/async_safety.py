@@ -15,6 +15,11 @@ exactly where review keeps catching the same three defects:
   STORED loop from sync code may run on a foreign thread — the home-loop
   idiom is ``call_soon_threadsafe`` (messenger.py/reactor.py hop this
   way everywhere; this checker keeps it that way);
+- ``await-in-section``: a ``with tracing.section(...)`` block (or a
+  ``@tracing.sectioned`` coroutine) containing an ``await`` — a section
+  is self time of SYNCHRONOUS work on one thread's stack
+  (common/tracing.py); across a suspension it would time the whole
+  loop's other work and corrupt the stack the loop meter resets per step;
 - ``shm-ring-payload`` (cross-process seam): objects queued onto a
   shared-memory ring (ShmRingPipe ``put_record``/``send_bytes``/
   ``send_gather``) must be WIRE BYTES or fixed-layout packs — a live
@@ -75,6 +80,9 @@ _OBJECTISH = re.compile(
 
 
 _LOCKISH = re.compile(r"(^|[^a-z])(lock|mutex)")
+# common/tracing.py's self-time sections: synchronous work only
+_SECTION = re.compile(r"^(tracing\.)?section\(")
+_SECTIONED = re.compile(r"^(tracing\.)?sectioned\(")
 
 
 def _lockish(src: str) -> bool:
@@ -109,6 +117,17 @@ class _Scanner(ast.NodeVisitor):
         self._visit_func(node, False)
 
     def visit_AsyncFunctionDef(self, node):
+        for deco in node.decorator_list:
+            src = ast.unparse(deco)
+            if _SECTIONED.match(src):
+                self.findings.append(Finding(
+                    check="async-safety/await-in-section",
+                    file=self.relpath, line=node.lineno,
+                    key=f"{src}@L{node.lineno}",
+                    message=f"`@{src}` on `async def {node.name}`: it "
+                            f"would time the creation of the coroutine, "
+                            f"not its steps — put sections around the "
+                            f"synchronous stretches inside"))
         self._visit_func(node, True)
 
     def visit_Lambda(self, node):
@@ -240,6 +259,17 @@ class _Scanner(ast.NodeVisitor):
         if has_await:
             for item in node.items:
                 src = ast.unparse(item.context_expr)
+                if _SECTION.match(src):
+                    self.findings.append(Finding(
+                        check="async-safety/await-in-section",
+                        file=self.relpath, line=node.lineno,
+                        key=f"{src}@L{node.lineno}",
+                        message=f"`{src}` holds an `await` in "
+                                f"{self._func_name()}: a section times "
+                                f"synchronous work — across a suspension "
+                                f"it would measure everybody else's steps "
+                                f"and break the per-step stack (end it "
+                                f"before the await, open another after)"))
                 if _lockish(src):
                     self.findings.append(Finding(
                         check="async-safety/lock-across-await",

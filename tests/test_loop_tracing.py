@@ -1,0 +1,524 @@
+"""The loop instrument (common/tracing.py): sections of synchronous work
+with self time, the event-loop meter and its closure, task-sticky marks,
+the lint rule that keeps awaits out of sections, Span on the profiler's
+clock, and the gather counters that say when a put was acknowledged with
+fewer sub-write acks than shards."""
+
+import asyncio
+import os
+import threading
+import time
+
+import pytest
+
+from ceph_tpu.common import tracing
+from ceph_tpu.common.context import Context
+from ceph_tpu.common.perf_counters import PerfCountersBuilder
+from ceph_tpu.common.tracing import LOOP_PERF, Tracer
+from ceph_tpu.tools import trace_export
+from ceph_tpu.tools.lint import async_safety
+
+
+def spin(seconds: float) -> None:
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+def moved(before: dict, after: dict, key: str, part: str = "sum") -> float:
+    def val(d):
+        v = d.get(key, 0)
+        return v.get(part, v.get("avgcount", 0)) if isinstance(v, dict) else v
+    return val(after) - val(before)
+
+
+def metered(coro_fn, timeout: float = 60.0, sample_every: int = 1):
+    """Run `coro_fn()` on a fresh metered loop; its result and the delta
+    of the `loop` set over the run.  Every turn of the loop is sampled,
+    unless the test is about the sampling."""
+    async def go():
+        meter = tracing.install_loop_meter()
+        meter.sample_every = sample_every
+        await asyncio.sleep(0)  # the meter sees whole turns from here
+        before = LOOP_PERF.dump()
+        t0 = time.perf_counter()
+        out = await asyncio.wait_for(coro_fn(), timeout)
+        await asyncio.sleep(0)  # a dump sees the turns that have ended
+        wall = time.perf_counter() - t0
+        after = LOOP_PERF.dump()
+        meter.remove()
+        return out, before, after, wall
+    return asyncio.run(go())
+
+
+# -- sections ------------------------------------------------------------------
+
+
+class TestSections:
+    def test_self_time_is_duration_minus_children(self):
+        async def work():
+            with tracing.section("osd", "outer"):
+                spin(0.02)
+                with tracing.section("ecplan", "inner"):
+                    spin(0.03)
+                    with tracing.section("store", "innermost"):
+                        spin(0.01)
+                spin(0.01)
+        _, b, a, _ = metered(work)
+        assert moved(b, a, "self_osd") == pytest.approx(0.03, abs=0.008)
+        assert moved(b, a, "self_ecplan") == pytest.approx(0.03, abs=0.008)
+        assert moved(b, a, "self_store") == pytest.approx(0.01, abs=0.005)
+        assert moved(b, a, "self_osd", "avgcount") == 1
+
+    def test_siblings_both_leave_their_parent(self):
+        async def work():
+            with tracing.section("osd", "outer"):
+                for _ in range(2):
+                    with tracing.section("store", "commit"):
+                        spin(0.01)
+        _, b, a, _ = metered(work)
+        assert moved(b, a, "self_store") == pytest.approx(0.02, abs=0.006)
+        assert moved(b, a, "self_osd") < 0.004
+
+    def test_sectioned_decorator_is_one_section(self):
+        @tracing.sectioned("ecplan", "plan")
+        def plan(n):
+            spin(0.01)
+            return n + 1
+
+        async def work():
+            return plan(1)
+        out, b, a, _ = metered(work)
+        assert out == 2 and plan.__name__ == "plan"
+        assert moved(b, a, "self_ecplan") == pytest.approx(0.01, abs=0.005)
+
+    def test_a_section_on_another_thread_lands_in_thread_keys(self):
+        before = LOOP_PERF.dump()
+
+        def worker():
+            with tracing.section("devbound", "fetch"):
+                spin(0.02)
+        t = threading.Thread(target=worker)
+        t.start()
+        t.join()
+        after = LOOP_PERF.dump()
+        assert moved(before, after, "thread_devbound") == \
+            pytest.approx(0.02, abs=0.008)
+        assert "self_devbound" not in after
+
+    def test_loop_and_thread_time_never_mix(self):
+        async def work():
+            def off_loop():
+                with tracing.section("store", "x"):
+                    spin(0.02)
+            await asyncio.get_running_loop().run_in_executor(None, off_loop)
+            with tracing.section("store", "x"):
+                spin(0.01)
+        _, b, a, _ = metered(work)
+        assert moved(b, a, "thread_store") == pytest.approx(0.02, abs=0.008)
+        assert moved(b, a, "self_store") == pytest.approx(0.01, abs=0.006)
+
+    def test_a_section_raising_still_counts_and_unwinds(self):
+        async def work():
+            try:
+                with tracing.section("osd", "boom"):
+                    spin(0.01)
+                    raise ValueError("x")
+            except ValueError:
+                pass
+            with tracing.section("store", "after"):
+                spin(0.01)
+        _, b, a, _ = metered(work)
+        assert moved(b, a, "self_osd") == pytest.approx(0.01, abs=0.005)
+        assert moved(b, a, "self_store") == pytest.approx(0.01, abs=0.005)
+
+    def test_importing_tracing_imports_no_jax(self):
+        import subprocess
+        import sys
+        code = ("import sys\n"
+                "from ceph_tpu.common import tracing\n"
+                "from ceph_tpu.common.context import Context\n"
+                "Context('mon.a')\n"
+                "with tracing.section('osd', 'x'):\n    pass\n"
+                "print('jax' in sys.modules)\n")
+        out = subprocess.run([sys.executable, "-c", code], text=True,
+                             capture_output=True, timeout=60,
+                             cwd=os.path.dirname(os.path.dirname(
+                                 os.path.abspath(__file__))))
+        assert out.stdout.strip() == "False", out.stderr[-500:]
+
+
+# -- the loop meter ------------------------------------------------------------
+
+
+class TestLoopMeter:
+    def test_busy_plus_select_closes_on_wall(self):
+        async def toy():
+            async def worker(i):
+                for _ in range(40):
+                    spin(0.001)
+                    await asyncio.sleep(0.002)
+            await asyncio.gather(*(
+                asyncio.get_running_loop().create_task(
+                    worker(i), name=f"osd.{i}/toy") for i in range(3)))
+        _, b, a, wall = metered(toy)
+        busy, select = moved(b, a, "busy"), moved(b, a, "select")
+        assert busy + select == pytest.approx(wall, rel=0.05)
+        assert busy >= 0.12 * 0.9  # 120 steps of 1 ms, at least
+        assert moved(b, a, "steps") >= 120
+        assert moved(b, a, "step_us", "count") == moved(b, a, "steps")
+        # CPU time of the loop thread: the spinning is CPU, the select not
+        assert moved(b, a, "cpu") == pytest.approx(busy, rel=0.25)
+
+    def test_one_turn_in_sixteen_is_sampled_and_scaled_to_busy(self):
+        """The default (one in sixteen): most turns run as if there were no
+        meter, and what
+        the sampled ones summed is scaled, so that the layers still sum to
+        the (exact) busy time and keep their proportions."""
+        async def toy():
+            async def worker():
+                for _ in range(800):
+                    with tracing.section("store", "x"):
+                        spin(0.0001)
+                    spin(0.0001)
+                    await asyncio.sleep(0)  # a turn of the loop each
+            await asyncio.get_running_loop().create_task(
+                worker(), name="osd.0/toy")
+        _, b, a, wall = metered(toy, sample_every=tracing.LoopMeter.SAMPLE_EVERY)
+        busy = moved(b, a, "busy")
+        assert busy + moved(b, a, "select") == pytest.approx(wall, rel=0.05)
+        assert moved(b, a, "sampled") == pytest.approx(busy / 16, rel=0.3)
+        total = sum(moved(b, a, k) for k in a if k.startswith("self_"))
+        assert total == pytest.approx(busy, rel=0.02)
+        assert moved(b, a, "self_store") == pytest.approx(busy / 2, rel=0.2)
+        assert moved(b, a, "self_osd") == pytest.approx(busy / 2, rel=0.2)
+        assert moved(b, a, "steps") == pytest.approx(800, rel=0.15)
+
+    def test_a_dump_in_the_middle_of_a_sampled_turn_loses_nothing(self):
+        """The harness snapshots the counters from inside a step.  What
+        the turn used before the dump belongs to the dump's interval, the
+        rest to the next: or the scaled layers would drift off busy."""
+        async def toy():
+            async def worker():
+                for i in range(60):
+                    spin(0.001)
+                    if i % 5 == 0:
+                        LOOP_PERF.dump()
+                    with tracing.section("store", "x"):
+                        spin(0.001)
+                    await asyncio.sleep(0)
+            await asyncio.get_running_loop().create_task(
+                worker(), name="osd.0/toy")
+        _, b, a, _ = metered(toy, sample_every=3)
+        busy = moved(b, a, "busy")
+        total = sum(moved(b, a, k) for k in a if k.startswith("self_"))
+        assert total == pytest.approx(busy, rel=0.02)
+        assert moved(b, a, "self_store") == pytest.approx(busy / 2, rel=0.25)
+
+    def test_self_times_sum_to_busy(self):
+        async def toy():
+            async def worker():
+                for _ in range(20):
+                    with tracing.section("store", "x"):
+                        spin(0.001)
+                    spin(0.001)
+                    await asyncio.sleep(0)
+            await asyncio.get_running_loop().create_task(
+                worker(), name="osd.0/toy")
+        _, b, a, _ = metered(toy)
+        total = sum(moved(b, a, k) for k in a if k.startswith("self_"))
+        assert total == pytest.approx(moved(b, a, "busy"), rel=0.02)
+        assert moved(b, a, "self_store") == pytest.approx(0.02, abs=0.01)
+        assert moved(b, a, "self_osd") == pytest.approx(0.02, abs=0.012)
+
+    def test_lag_grows_when_a_callback_blocks(self):
+        async def quiet():
+            await asyncio.sleep(0.3)
+
+        async def blocked():
+            for _ in range(3):
+                time.sleep(0.1)  # noqa: the point of the test
+                await asyncio.sleep(0.01)
+        _, b, a, _ = metered(quiet)
+        calm = moved(b, a, "lag") / max(1, moved(b, a, "lag", "avgcount"))
+        _, b, a, _ = metered(blocked)
+        stalled = moved(b, a, "lag") / max(1, moved(b, a, "lag", "avgcount"))
+        assert moved(b, a, "lag_us", "count") >= 3
+        assert stalled > 0.03 and stalled > 2 * calm  # calm: ~0 unloaded
+
+    @pytest.mark.parametrize("name,kind,layer", [
+        ("osd.3/reader", "kind_task_osd_reader", "self_osd"),
+        ("client/objecter", "kind_task_client_objecter", "self_client"),
+        ("mon.0/tick", "kind_task_mon_tick", "self_background"),
+        ("Task-77", "kind_task_test_loop_tracing_TestLoopMeter_test_uncovered"
+                    "_step_time_goes_to_the_kinds_layer__locals__toy__locals_"
+                    "_body", "self_unnamed"),
+    ])
+    def test_uncovered_step_time_goes_to_the_kinds_layer(self, name, kind,
+                                                         layer):
+        async def toy():
+            async def body():
+                spin(0.02)
+            await asyncio.get_running_loop().create_task(body(), name=name)
+        _, b, a, _ = metered(toy)
+        assert moved(b, a, kind) == pytest.approx(0.02, abs=0.008)
+        assert moved(b, a, layer) >= 0.015
+
+    def test_unnamed_tasks_are_kinds_of_the_code_they_run(self):
+        async def toy():
+            async def helper():
+                spin(0.01)
+            await asyncio.get_running_loop().create_task(helper())
+        _, b, a, _ = metered(toy)
+        kinds = [k for k in a if k.startswith("kind_task_test_loop_tracing")
+                 and "helper" in k]
+        assert kinds and moved(b, a, kinds[0]) >= 0.008
+
+    def test_timers_and_transport_io_are_kinds(self):
+        async def toy():
+            async def echo(reader, writer):
+                writer.write(await reader.readexactly(4))
+                await writer.drain()
+                writer.close()
+            server = await asyncio.start_server(echo, "127.0.0.1", 0)
+            port = server.sockets[0].getsockname()[1]
+            r, w = await asyncio.open_connection("127.0.0.1", port)
+            w.write(b"ping")
+            assert await r.readexactly(4) == b"ping"
+            w.close()
+            server.close()
+            await asyncio.sleep(0.01)
+        _, b, a, _ = metered(toy)
+        assert moved(b, a, "kind_io_read", "avgcount") >= 2
+        assert moved(b, a, "kind_timer", "avgcount") >= 1
+        # asyncio's own socket work is named: it is the messenger's
+        assert moved(b, a, "self_messenger") > 0
+
+    def test_mark_relabels_the_rest_of_a_step_and_restores(self):
+        async def toy():
+            async def send():
+                was = tracing.mark("messenger")
+                try:
+                    spin(0.01)
+                    await asyncio.sleep(0)
+                    spin(0.01)  # a later step: the task's kind (osd) again
+                finally:
+                    assert tracing.mark(was) is None  # nothing to undo here
+
+            async def handler():
+                spin(0.01)
+                was = tracing.mark("store")
+                spin(0.01)
+                assert tracing.mark(was) == "store"
+                await send()
+                spin(0.01)
+            await asyncio.get_running_loop().create_task(
+                handler(), name="osd.1/op")
+        _, b, a, _ = metered(toy)
+        assert moved(b, a, "self_messenger") == pytest.approx(0.01, abs=0.006)
+        assert moved(b, a, "self_store") == pytest.approx(0.01, abs=0.006)
+        assert moved(b, a, "self_osd") == pytest.approx(0.03, abs=0.01)
+
+    def test_mark_off_a_metered_loop_is_a_noop(self):
+        assert tracing.mark("osd") is None
+
+    def test_a_named_loop_gets_a_set_of_its_own(self):
+        loop = asyncio.new_event_loop()
+        try:
+            meter = tracing.install_loop_meter(loop, name="osd.0-reactor-0")
+            meter.sample_every = 1
+            assert meter.perf.name == "loop.osd.0-reactor-0"
+            assert meter.perf is not LOOP_PERF
+            assert tracing.install_loop_meter(loop) is meter  # idempotent
+
+            async def body():
+                with tracing.section("messenger", "crc"):
+                    spin(0.01)
+            before = LOOP_PERF.dump()
+            loop.run_until_complete(body())
+            got = meter.perf.dump()
+            assert got["self_messenger"]["sum"] >= 0.008
+            assert got["busy"]["sum"] >= 0.008
+            assert moved(before, LOOP_PERF.dump(), "self_messenger") == 0
+            meter.remove()
+        finally:
+            loop.close()
+
+    def test_reactor_workers_meter_their_loops(self):
+        from ceph_tpu.rados.reactor import ReactorWorker
+
+        w = ReactorWorker("osd.9", 0)
+        try:
+            assert w.meter.perf.name == "loop.osd.9-reactor-0"
+            w.meter.sample_every = 1
+            w.ensure_started()
+            done = threading.Event()
+
+            async def body():
+                spin(0.01)
+                done.set()
+            w.spawn(body())
+            assert done.wait(5.0)
+            assert w.meter.perf.dump()["busy"]["sum"] >= 0.008
+        finally:
+            w.stop()
+
+    def test_the_loop_set_is_in_every_daemons_collection_once(self):
+        a, b = Context("osd.0"), Context("mon.a")
+        assert a.perf.get("loop") is LOOP_PERF is b.perf.get("loop")
+        assert set(LOOP_PERF.dump()) >= {
+            "busy", "cpu", "select", "lag", "steps", "step_us", "lag_us",
+            *("self_" + layer for layer in tracing.LOOP_LAYERS)}
+
+    def test_reset_keeps_nothing_from_before(self):
+        async def toy():
+            spin(0.02)
+            await asyncio.sleep(0)
+            LOOP_PERF.reset()
+            await asyncio.sleep(0)
+            return LOOP_PERF.dump()
+        got, _, _, _ = metered(toy)
+        assert got["busy"]["sum"] < 0.01
+
+
+# -- tinc with a count, hmerge ---------------------------------------------------
+
+
+def test_perf_counters_fold_in_presummed_observations():
+    pc = (PerfCountersBuilder("t").add_time_avg("lat")
+          .add_histogram("us").create_perf_counters())
+    pc.tinc("lat", 0.5)
+    pc.tinc("lat", 1.5, 3)
+    assert pc.get("lat") == (4, 2.0)
+    pc.hinc("us", 5)
+    buckets = [0] * 32
+    buckets[3] = 2
+    pc.hmerge("us", buckets, 11.0)
+    got = pc.dump()["us"]
+    assert got["count"] == 3 and got["sum"] == 16.0
+    assert got["buckets"][3] == 3
+
+
+# -- the lint rule ---------------------------------------------------------------
+
+
+class TestLintRefusesAwaitInSection:
+    @pytest.mark.parametrize("body,bad", [
+        ("async def f(self):\n"
+         "    with tracing.section('osd', 'x'):\n"
+         "        await g()\n", True),
+        ("async def f(self):\n"
+         "    with section('osd', 'x'), other():\n"
+         "        async with lock:\n"
+         "            pass\n", True),
+        ("async def f(self):\n"
+         "    with tracing.section('osd', 'x'):\n"
+         "        y = g()\n"
+         "    await y\n", False),
+        ("@tracing.sectioned('osd', 'x')\n"
+         "async def f(self):\n"
+         "    return 1\n", True),
+        ("@tracing.sectioned('osd', 'x')\n"
+         "def f(self):\n"
+         "    return 1\n", False),
+    ])
+    def test_cases(self, body, bad):
+        found = [f for f in async_safety.check([("x.py", body)])
+                 if f.check == "async-safety/await-in-section"]
+        assert bool(found) == bad, found
+
+
+# -- Span on the profiler's clock --------------------------------------------------
+
+
+class TestSpanClock:
+    def test_times_are_integer_ns_of_time_ns(self):
+        tr = Tracer(service="osd.0")
+        t0 = time.time_ns()
+        sp = tr.new_trace("op")
+        sp.event("a")
+        sp.finish()
+        t1 = time.time_ns()
+        assert isinstance(sp.start_ns, int) and isinstance(sp.end_ns, int)
+        assert t0 <= sp.start_ns <= sp.events[0]["time_ns"] <= sp.end_ns <= t1
+        assert sp.start == sp.start_ns / 1e9 and sp.end == sp.end_ns / 1e9
+
+    def test_dump_and_trace_export_keep_their_shape(self):
+        tr = Tracer(service="osd.0")
+        root = tr.new_trace("client_op")
+        child = root.child("osd_op")
+        child.event("queued")
+        child.tag("osd", 3).finish()
+        root.finish()
+        dumped = tr.dump()
+        assert [set(d) for d in dumped] == [
+            {"trace_id", "span_id", "parent_id", "name", "start", "duration",
+             "events", "tags", "service"}] * 2
+        d = dumped[0]
+        assert isinstance(d["start"], float) and isinstance(d["duration"],
+                                                            float)
+        assert set(d["events"][0]) == {"time", "event"}
+        assert isinstance(d["events"][0]["time"], float)
+        assert abs(d["start"] - time.time()) < 5.0  # seconds since the epoch
+        j = trace_export.to_jaeger(root.trace_id, dumped)["data"][0]
+        assert [s["operationName"] for s in j["spans"]] == [
+            "client_op", "osd_op"]
+        for s in j["spans"]:
+            assert abs(s["startTime"] - time.time() * 1e6) < 5e6  # µs
+            assert s["duration"] >= 1
+        assert j["spans"][1]["logs"][0]["fields"][0]["value"] == "queued"
+        assert trace_export.resolve_parents(dumped)["__orphans__"] == 0
+
+
+# -- gather counters on a cluster ----------------------------------------------------
+
+
+class TestGatherCounters:
+    def test_short_gather_acks_counts_a_withheld_sub_write_reply(self):
+        from ceph_tpu.rados.types import MECSubWriteReply
+        from ceph_tpu.rados.vstart import Cluster
+
+        async def go():
+            cluster = Cluster(n_osds=5, conf={
+                "osd_auto_repair": False, "osd_heartbeat_interval": 0.2})
+            await cluster.start()
+            tracing.install_loop_meter().sample_every = 1  # a short run
+            try:
+                c = await cluster.client()
+                pool = await c.create_pool("p", pg_num=4, profile={
+                    "plugin": "jerasure", "technique": "reed_sol_van",
+                    "k": "2", "m": "2"})
+                await c.put(pool, "warm", os.urandom(20_000))
+                total = lambda key: sum(  # noqa: E731
+                    o.perf.get(key) for o in cluster.osds.values())
+                assert total("short_gather_acks") == 0
+                assert total("gather_timeouts") == 0
+                assert all("op_queue_lat" not in o.perf.dump()
+                           for o in cluster.osds.values())
+
+                # one replica answers its sub-writes to nobody
+                pg, acting = next(iter(cluster.osds.values()))._acting(
+                    c.osdmap.pools[pool], "held")
+                primary = cluster.osds[acting[0]]._primary(
+                    c.osdmap.pools[pool], pg, acting)
+                mute = cluster.osds[next(a for a in acting if a != primary)]
+                real_send = mute.messenger.send
+
+                async def swallow(addr, msg, *a, **kw):
+                    if isinstance(msg, MECSubWriteReply):
+                        return
+                    await real_send(addr, msg, *a, **kw)
+                mute.messenger.send = swallow
+                await c.put(pool, "held", os.urandom(20_000))  # acked
+                assert cluster.osds[primary].perf.get(
+                    "short_gather_acks") == 1
+                assert cluster.osds[primary].perf.get("gather_timeouts") == 1
+                assert total("short_gather_acks") == 1
+                # and the loop set saw the cluster's work, layer by layer
+                loop = cluster.osds[primary].ctx.perf.dump()["loop"]
+                assert loop["self_osd"]["sum"] > 0
+                assert loop["self_store"]["avgcount"] > 0
+                assert loop is not None and "loop" in c.perf_dump()
+            finally:
+                await cluster.stop()
+        asyncio.run(asyncio.wait_for(go(), 90))
