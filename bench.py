@@ -1744,12 +1744,15 @@ def e2e_device_bench() -> int:
             blobs = {f"e{i}": rng.integers(0, 256, obj_size,
                                            dtype=np.uint8).tobytes()
                      for i in range(n_hot)}
-            # connection warmup outside the windows
-            await c.put(pool, "warm", b"x" * 4096)
+            # connection warmup outside the windows — at the window's
+            # object size: the fused install compiles per source
+            # geometry, at the first install of each (ops/slab.py)
+            await c.put(pool, "warm", bytes(obj_size))
 
-            # PUT window: encode + wire + install.  The slab kernels
-            # were pre-warmed at store build (osd_tier_slab_prewarm),
-            # so the compile-counter delta across the window is the
+            # PUT window: encode + wire + install.  The gather kernels
+            # were pre-warmed at store build (osd_tier_slab_prewarm)
+            # and the warm-up put compiled this size's install, so the
+            # compile-counter delta across the window is the
             # AOT-discipline evidence: 0 in-line XLA compiles.
             from ceph_tpu.ops.slab import SLAB_PERF
             prewarmed = bool(getattr(store, "prewarmed", False))

@@ -10,28 +10,49 @@ pages are rows of those arrays.  The idiom is Ragged Paged Attention
 jitted scatter updates with buffer donation, ragged tails handled by
 the page table above, host copies only at the true I/O boundary:
 
-- ``slab_install(slab, data, idx)`` scatters [n, page_words] page rows
-  into the sub-slab at row indices ``idx`` in ONE jitted
-  ``slab.at[idx].set(data)`` call (XLA lowers this to
-  dynamic-update-slice / scatter).  The slab argument is DONATED when
-  the backend supports it, so the update is genuinely in place — no
-  2x-slab copy per install.  Donation discipline: the CALLER must drop
-  its reference to the donated slab immediately (the pagestore swaps
-  ``_dev_slabs[s]`` under its lock before anyone can gather), and the
-  data argument is NEVER donated — resident-lane fan-out slices may
-  alias the batching queue's shared product (parallel/service.py).
+- ``slab_install(slab, src, cols, src_rows, dst_rows)`` lands pages of
+  ONE install in ONE sub-slab with ONE jitted program, the only device
+  program an install launches per touched sub-slab.  ``src`` is the
+  install's source exactly as its producer left it — the encode lane's
+  ``u32[rows, cols_full]`` plane words (a ``jax.Array``), or the
+  host-sourced page image after its one h2d copy.  Inside the program:
+  the trim to ``cols``, the row-major flatten, the zero pad of the
+  ragged tail, the view as ``[npages, page_words]``, the selection of
+  source page rows ``idx[0]`` and the scatter to sub-slab rows
+  ``idx[1]``.  STATIC (compile key): the source's shape, ``cols``,
+  ``page_words``, donate.  DYNAMIC: the slab, the source and the one
+  ``int32[2, npages]`` index array, built in numpy and passed as a
+  numpy argument — no eager jnp op, slice, reshape or index upload
+  runs on the calling (event-loop) thread.  The slab argument is
+  DONATED when the backend supports it, so the update is genuinely in
+  place — no 2x-slab copy per install.  Donation discipline: the
+  CALLER must drop its reference to the donated slab immediately (the
+  pagestore swaps ``_dev_slabs[s]`` under its lock before anyone can
+  gather), and the source is NEVER donated — resident-lane fan-out
+  slices may alias the batching queue's shared product
+  (parallel/service.py), and an install spread over sub-slabs feeds
+  the same source to each of its programs.
 - ``slab_gather(slab, idx)`` reads rows back as one jitted take; the
   result is a fresh device buffer (never a view of the slab), so a
   gather that raced a later donated install still holds the bytes it
   read.
 
-Both kernels compile per PAGE GEOMETRY — (page_words, pow2-bucketed row
-count, donate) — behind the same OrderedDict-LRU discipline as gf2's
-XOR-schedule cache, with the ``slab_kernels`` counter set mirroring
-SCHED_PERF.  Row-count bucketing pads ``idx`` by repeating the LAST
-index and ``data`` by repeating the last row: duplicate scatter updates
-with identical payloads are deterministic, and the pad rows write bytes
-that were being written anyway.
+The install compiles per SOURCE GEOMETRY only, never per group size: an
+install whose pages come off a fragmented free list lands a different
+number of pages in each sub-slab it touches, and a program first seen
+inside a served window compiles there.  So the index array always has
+``npages`` columns — every page of the install — and a group that owns
+fewer pads it by REPEATING its last (source row, destination row) pair:
+duplicate scatter updates with identical payloads are deterministic,
+and the redundant HBM writes cost nothing beside a host dispatch.  One
+program per (source shape, ``cols``, ``page_words``, donate) serves
+every group size, so there is nothing to enumerate ahead of time: the
+first install of a geometry compiles it (the served path's warm-up),
+and ``prewarm`` covers the gathers alone.  The gather compiles per
+(page_words, pow2-bucketed row count), padding ``idx`` by repeating the
+last index.  Both sit behind the same OrderedDict-LRU discipline as
+gf2's XOR-schedule cache, with the ``slab_kernels`` counter set
+mirroring SCHED_PERF.
 
 Donation resolution: ``CEPH_TPU_SLAB_DONATE=1`` forces it on (tests),
 ``=0`` forces it off, default = only when a real device backend is
@@ -134,45 +155,57 @@ def _kernel(key, build):
     return fn
 
 
-def _pad_rows(idx: np.ndarray, data, nb: int):
-    """Pad (idx, data) up to the bucketed row count by repeating the
-    last row: duplicate identical scatter updates are deterministic."""
-    n = int(idx.shape[0])
-    if n == nb:
-        return idx, data
-    idx = np.concatenate([idx, np.full(nb - n, idx[-1], dtype=idx.dtype)])
-    data = jnp.concatenate(
-        [data, jnp.broadcast_to(data[-1], (nb - n,) + data.shape[1:])])
-    return idx, data
+def install_pages(src_shape, cols: int, page_words: int) -> int:
+    """Pages the fused install makes of a ``src_shape`` u32 source
+    trimmed to ``cols`` words a row (ragged tail included)."""
+    return -(-(int(src_shape[0]) * int(cols)) // int(page_words))
 
 
-def slab_install(slab, data, idx: np.ndarray):
-    """Scatter [n, page_words] u32 page rows into the sub-slab at row
-    indices ``idx`` (int32 host array) — one jitted in-place update,
-    donation-annotated when the backend supports it.  Returns the NEW
-    slab array; the caller must forget the old one (it may be freed).
-    ``data`` is never donated (it may alias a shared batch product)."""
-    nb = bucket_rows(int(idx.shape[0]))
-    idx = np.asarray(idx, dtype=np.int32)
-    data = jnp.asarray(data, dtype=jnp.uint32)
-    idx, data = _pad_rows(idx, data, nb)
-    fn = install_fn(int(slab.shape[1]), nb, donate_enabled())
-    return fn(slab, data, jnp.asarray(idx))
+def slab_install(slab, src, cols: int, src_rows: np.ndarray,
+                 dst_rows: np.ndarray):
+    """Land page rows ``src_rows`` of the install's page view of ``src``
+    (u32 [rows, cols_full], trimmed to ``cols``, flattened, zero-padded
+    to whole pages) at rows ``dst_rows`` of the sub-slab — ONE jitted
+    in-place program, donation-annotated when the backend supports it,
+    and no other device call.  Returns the NEW slab array; the caller
+    must forget the old one (it may be freed).  ``src`` is never donated
+    (it may alias a shared batch product)."""
+    page_words = int(slab.shape[1])
+    shape = (int(src.shape[0]), int(src.shape[1]))
+    n = len(src_rows)
+    idx = np.empty((2, install_pages(shape, cols, page_words)),
+                   dtype=np.int32)
+    idx[0, :n] = src_rows
+    idx[1, :n] = dst_rows
+    idx[:, n:] = idx[:, n - 1:n]  # repeat one real pair: same bytes again
+    return install_fn(shape, int(cols), page_words,
+                      donate_enabled())(slab, src, idx)
 
 
-def install_fn(page_words: int, nb: int, donate: bool):
-    """The jitted (LRU-cached) install kernel for one page geometry."""
+def install_fn(src_shape, cols: int, page_words: int, donate: bool):
+    """The jitted (LRU-cached) fused install for one source geometry:
+    (slab, src u32[src_shape], idx int32[2, npages]) -> slab."""
+    rows, cols_full = src_shape
+    npages = install_pages(src_shape, cols, page_words)
+    pad = npages * page_words - rows * cols
 
     def build():
-        def _install(s, d, i):
+        def _install(s, src, idx):
             with jax.named_scope("slab_install"):
-                return s.at[i].set(d)
+                flat = (src[:, :cols] if cols < cols_full
+                        else src).reshape(-1)
+                if pad:
+                    flat = jnp.concatenate(
+                        [flat, jnp.zeros(pad, dtype=jnp.uint32)])
+                pages = flat.reshape(npages, page_words)
+                return s.at[idx[1]].set(pages[idx[0]])
 
         if donate:
             return jax.jit(_install, donate_argnums=(0,))
         return jax.jit(_install)
 
-    return _kernel(("install", page_words, nb, donate), build)
+    return _kernel(("install", rows, cols_full, cols, page_words, donate),
+                   build)
 
 
 def gather_fn(page_words: int, nb: int):
@@ -198,23 +231,21 @@ def slab_gather(slab, idx: np.ndarray):
 
 
 def prewarm(page_words: int, max_rows: int = 256) -> int:
-    """Compile the install/gather kernels for every pow2 row bucket up
-    to ``max_rows`` (one sub-slab's worth) at store build, OFF the put
-    path — the AOT discipline: the put window must never pay an in-line
-    XLA compile for a geometry the configured page size makes
-    inevitable.  Chained through one scratch sub-slab so donation stays
-    exercised exactly as the live path will.  Returns the number of
-    kernels compiled (0 when everything was already cached)."""
+    """Compile the gather kernel for every pow2 row bucket up to
+    ``max_rows`` (one sub-slab's worth) at store build, OFF the read
+    path — the AOT discipline: a served window must never pay an
+    in-line XLA compile for a geometry the configured page size makes
+    inevitable.  The install has nothing to enumerate here: it compiles
+    per source geometry, whatever the group size (module docstring).
+    Returns the number of kernels compiled (0 when everything was
+    already cached)."""
     before = SLAB_PERF.get("compile")
     slab = new_subslab(max_rows, page_words)
     nb = 1
     while nb <= max_rows:
-        idx = np.arange(nb, dtype=np.int32) % max_rows
-        data = jnp.zeros((nb, page_words), dtype=jnp.uint32)
-        slab = slab_install(slab, data, idx)
-        jax.block_until_ready(slab_gather(slab, idx))
+        jax.block_until_ready(
+            slab_gather(slab, np.arange(nb, dtype=np.int32) % max_rows))
         nb <<= 1
-    jax.block_until_ready(slab)
     return int(SLAB_PERF.get("compile") - before)
 
 

@@ -204,6 +204,11 @@ def build_pagestore_perf() -> PerfCounters:
         .add_u64_counter("device_installs",
                          "installs consumed device-native (queue-"
                          "produced residents; zero host copies)")
+        .add_u64_counter("install_programs",
+                         "device programs launched by installs: one per "
+                         "sub-slab an install touched, so over the two "
+                         "counters above it reads sub-slabs per install "
+                         "(more means an eager op crept back)")
         .add_u64_counter("d2h_gathers",
                          "device->host materializations of gathered "
                          "slab bytes at the declared exit boundaries")
@@ -269,9 +274,10 @@ class PagedResidentStore:
         self.perf.resync = self._resync_gauges
         self.prewarmed = False
         if prewarm and self.device_arm:
-            # compile the install/gather kernels for this page geometry
-            # (every pow2 row bucket) at store build — the put window
-            # must never pay an in-line XLA compile
+            # compile the gather kernels for this page geometry (every
+            # pow2 row bucket) at store build — a served window must
+            # never pay an in-line XLA compile.  The install compiles
+            # per source geometry, at the first install of each
             from ceph_tpu.ops.slab import prewarm as _slab_prewarm
 
             _slab_prewarm(self.page_words)
@@ -417,17 +423,19 @@ class PagedResidentStore:
             return min(cols, -(-int(trim) // 32))
         return min(cols, ((int(trim) + 3) // 4) * 4)
 
-    def _install_pages_locked(self, flat, total_words: int,
+    def _install_pages_locked(self, src, cols: int, total_words: int,
                               from_device: bool) -> List[Optional[int]]:
         """Device-arm install (lock held): allocate page ids, land the
-        flat word image as page rows via ONE scatter kernel per touched
-        sub-slab (ceph_tpu.ops.slab.slab_install, donation-annotated),
-        and swap the donated sub-slab references under the lock.  A
-        host-sourced image crosses h2d as ONE async copy of the whole
-        zero-padded page image; a device-native image never touches
-        host memory.  Returns the page-id list."""
-        import jax.numpy as jnp
-
+        source as page rows with ONE fused program per touched sub-slab
+        (ceph_tpu.ops.slab.slab_install: trim to ``cols``, flatten, zero
+        pad, page view, row selection and donated scatter all inside
+        it), and swap the donated sub-slab references under the lock.
+        Nothing else here touches the device: the index arrays are
+        numpy.  A device-native ``src`` (the queue's ``u32[rows,
+        cols_full]``) never touches host memory; a host-sourced one is
+        the flat word image, which crosses h2d as ONE copy of the whole
+        zero-padded page image and goes through the same program.
+        Returns the page-id list."""
         from ceph_tpu.ops.slab import slab_install
 
         npages = -(-total_words // self.page_words) if total_words else 0
@@ -438,40 +446,29 @@ class PagedResidentStore:
             pages.append(pid)
         if not npages:
             return pages
-        pad = npages * self.page_words - total_words
         if from_device:
-            buf = flat
-            if pad:
-                buf = jnp.concatenate(
-                    [buf, jnp.zeros(pad, dtype=jnp.uint32)])
             self.device_installs += 1
             self.perf.inc("device_installs")
         else:
-            host = np.zeros(npages * self.page_words, dtype=np.uint32)
-            host[:total_words] = flat
-            buf = jnp.asarray(host)  # the ONE h2d of the install
+            import jax
+
+            host = np.zeros((npages, self.page_words), dtype=np.uint32)
+            host.reshape(-1)[:total_words] = src
+            src, cols = jax.device_put(host), self.page_words  # the ONE h2d
             self.h2d_installs += 1
             self.perf.inc("h2d_installs")
-        rows = buf.reshape(npages, self.page_words)
-        mask = (1 << _SLAB_SHIFT) - 1
-        groups: "OrderedDict[int, List[int]]" = OrderedDict()
-        for i, pid in enumerate(pages):
-            groups.setdefault(pid >> _SLAB_SHIFT, []).append(i)
-        for s, order in groups.items():
-            idx = np.array([pages[i] & mask for i in order],
-                           dtype=np.int32)
-            if len(order) == npages:
-                data = rows
-            else:
-                data = jnp.take(rows,
-                                jnp.asarray(np.array(order,
-                                                     dtype=np.int32)),
-                                axis=0)
+        pids = np.array(pages, dtype=np.int32)
+        slab_of = pids >> _SLAB_SHIFT
+        dst = pids & ((1 << _SLAB_SHIFT) - 1)
+        touched = np.unique(slab_of).tolist()
+        for s in touched:
+            order = np.flatnonzero(slab_of == s)
             # the old sub-slab reference is dropped HERE, under the
             # lock, before any gather can observe it — the donation
             # safety contract (slab.py docstring)
-            self._dev_slabs[s] = slab_install(self._dev_slab(s), data,
-                                              idx)
+            self._dev_slabs[s] = slab_install(self._dev_slab(s), src, cols,
+                                              order, dst[order])
+        self.perf.inc("install_programs", len(touched))
         return pages
 
     @tracing.sectioned("store", "resident_install")
@@ -501,8 +498,9 @@ class PagedResidentStore:
         if from_device:
             # device-native install: a queue-produced resident (the
             # encode lane's packed-bit planes) never bounces through
-            # host numpy — trim/flatten are device ops and the scatter
-            # below consumes the same buffers
+            # host numpy, and nothing is dispatched here — the trim and
+            # the flatten happen inside the install's one program, which
+            # takes the array as the queue produced it
             rows, cols_full = int(bits.shape[0]), int(bits.shape[1])
             if n_rows is None:
                 n_rows = rows // w
@@ -510,8 +508,7 @@ class PagedResidentStore:
             dtype = np.dtype(np.uint32)
             mono_bytes = rows * cols_full * itemsize
             cols = self._trim_cols(dtype, cols_full, trim)
-            flat = (bits[:, :cols] if cols < cols_full else bits)
-            flat = flat.reshape(-1)
+            src = bits
         else:
             arr = np.asarray(bits)
             if n_rows is None:
@@ -531,9 +528,9 @@ class PagedResidentStore:
                 arr = np.pad(np.asarray(arr), ((0, 0), (0, pad)))
                 cols += pad
             dtype = np.dtype(arr.dtype)
-            flat = np.ascontiguousarray(arr).reshape(-1)
-            if flat.dtype != np.uint32:
-                flat = flat.view(np.uint32)  # rows % 4 == 0 (w >= 4)
+            src = np.ascontiguousarray(arr).reshape(-1)
+            if src.dtype != np.uint32:
+                src = src.view(np.uint32)  # rows % 4 == 0 (w >= 4)
         total_words = rows * cols * itemsize // 4
         npages = max(1, -(-total_words // self.page_words))
         with self.perf.time_avg("unpack_s"), self._lock:
@@ -558,8 +555,8 @@ class PagedResidentStore:
                 self.perf.inc("page_evictions", freed)
             e = _Entry()
             if self.device_arm:
-                e.pages = self._install_pages_locked(flat, total_words,
-                                                     from_device)
+                e.pages = self._install_pages_locked(
+                    src, cols, total_words, from_device)
             else:
                 e.pages = []
                 off = 0
@@ -567,7 +564,7 @@ class PagedResidentStore:
                     pid = self._alloc_page()
                     assert pid is not None  # _available_pages said so
                     n = min(self.page_words, total_words - off)
-                    self._page(pid)[:n] = flat[off:off + n]
+                    self._page(pid)[:n] = src[off:off + n]
                     e.pages.append(pid)
                     off += n
             e.dtype = dtype

@@ -153,18 +153,33 @@ def test_xor_packed_cauchy_rows(one_chip, mats):
              _spec((10 * 8, cols), np.uint8, one_chip))
 
 
-@pytest.mark.parametrize("rows", [1, 256])
-def test_slab_kernels(one_chip, rows):
-    from ceph_tpu.ops.slab import gather_fn, install_fn
+@pytest.mark.parametrize("src,cols", [
+    ((88, 16384), 16384),   # k=8 m=3, 4 MiB: one page a bit-row
+    ((48, 32768), 32768),   # k=4 m=2, 4 MiB: two pages a bit-row
+    ((88, 16384), 12289),   # a trimmed non-pow2 width, ragged tail
+])
+def test_slab_install(one_chip, src, cols):
+    """The fused install (trim, flatten, pad, page view, row selection,
+    donated scatter) as one program, keyed by the source's geometry."""
+    from ceph_tpu.ops.slab import install_fn, install_pages
 
     pw = (64 << 10) // 4  # osd_tier_page_bytes default, in u32 words
-    slab = _spec((256, pw), np.uint32, one_chip)
-    idx = _spec((rows,), np.int32, one_chip)
-    compiled = _compile(install_fn(pw, rows, True), slab,
-                        _spec((rows, pw), np.uint32, one_chip), idx)
+    compiled = _compile(
+        install_fn(src, cols, pw, True),
+        _spec((256, pw), np.uint32, one_chip),
+        _spec(src, np.uint32, one_chip),
+        _spec((2, install_pages(src, cols, pw)), np.int32, one_chip))
     # donated: the update must be in place, not a second slab
     assert "input_output_alias" in compiled.as_text()
-    _compile(gather_fn(pw, rows), slab, idx)
+
+
+@pytest.mark.parametrize("rows", [1, 256])
+def test_slab_gather(one_chip, rows):
+    from ceph_tpu.ops.slab import gather_fn
+
+    pw = (64 << 10) // 4
+    _compile(gather_fn(pw, rows), _spec((256, pw), np.uint32, one_chip),
+             _spec((rows,), np.int32, one_chip))
 
 
 def test_pallas_apply_bytes_w8(one_chip):

@@ -1108,6 +1108,129 @@ class TestDeviceArm:
         assert dev.stats()["h2d_installs"] == 0
         np.testing.assert_array_equal(dev.read("q"), rows)
 
+    # -- the fused install: one program per touched sub-slab ---------------
+
+    @staticmethod
+    def _pair(capacity, page_bytes):
+        _fresh_slab_cache()
+        return (PagedResidentStore(capacity_bytes=capacity,
+                                   page_bytes=page_bytes, device=False),
+                PagedResidentStore(capacity_bytes=capacity,
+                                   page_bytes=page_bytes, device=True))
+
+    @staticmethod
+    def _words(rows, cols, seed):
+        return np.random.default_rng(seed).integers(
+            0, 1 << 32, (rows, cols), dtype=np.uint32)
+
+    @staticmethod
+    def _subslabs(store, key):
+        return {pid >> 8 for pid in store._entries[key].pages}
+
+    def _fragment(self, host, dev):
+        """Fill three sub-slabs with one-page residents and free pages
+        of each in turn, so that the LIFO free list hands a later
+        install pages of all three, interleaved."""
+        import jax.numpy as jnp
+
+        for i in range(768):
+            one = self._words(1, 64, seed=1000 + i)
+            host.put_planar(f"f{i}", one, w=8, n_rows=1)
+            dev.put_planar(f"f{i}", jnp.asarray(one), w=8, n_rows=1)
+        for j in range(6):
+            for base in (10, 300, 600):
+                for st in (host, dev):
+                    assert st.drop(f"f{base + j}")
+
+    @pytest.mark.parametrize("case", [
+        "one_page_a_row", "two_pages_a_row", "trimmed_ragged_tail",
+        "three_subslabs", "host_image", "put_raw"])
+    def test_fused_install_matches_host_arm(self, case):
+        """The device arm's fused install leaves a store that reads
+        back byte-identical to the host arm's memcpy install, for every
+        shape the served paths hand it."""
+        import jax.numpy as jnp
+
+        if case == "put_raw":
+            host, dev = self._pair(1 << 20, 4096)
+            raw = _rows(1, 10001, seed=6).tobytes()
+            for st in (host, dev):
+                assert st.put_raw("o", raw)
+            assert dev.read_raw("o") == host.read_raw("o") == raw
+            assert dev.stats()["h2d_installs"] == 1
+            return
+        trim, native, neighbours = None, True, ()
+        if case == "one_page_a_row":      # k=8 m=3, 4 MiB: 88 x 64 KiB
+            host, dev = self._pair(8 << 20, 64 << 10)
+            bits = self._words(88, 16384, seed=1)
+        elif case == "two_pages_a_row":   # k=4 m=2, 4 MiB: 48 x 128 KiB
+            host, dev = self._pair(8 << 20, 64 << 10)
+            bits = self._words(48, 32768, seed=2)
+        elif case == "trimmed_ragged_tail":
+            host, dev = self._pair(1 << 20, 4096)
+            bits, trim = self._words(24, 128, seed=3), 3000  # 94 of 128
+        elif case == "three_subslabs":
+            host, dev = self._pair(256 << 10, 256)
+            self._fragment(host, dev)
+            bits, trim = self._words(8, 128, seed=4), 96 * 32
+            neighbours = ("f9", "f16", "f299", "f306", "f599", "f606",
+                          "f767")
+        else:                             # host_image: a promote's
+            host, dev = self._pair(1 << 20, 4096)   # numpy planes, one h2d
+            bits, trim, native = self._words(16, 200, seed=5), 6000, False
+        assert host.put_planar("o", bits, w=8, n_rows=bits.shape[0] // 8,
+                               trim=trim)
+        assert dev.put_planar("o", jnp.asarray(bits) if native else bits,
+                              w=8, n_rows=bits.shape[0] // 8, trim=trim)
+        assert dev.stats()["device_installs" if native
+                           else "h2d_installs"] >= 1
+        cols = host._entries["o"].cols
+        want = bits[:, :cols]
+        for st in (host, dev):
+            np.testing.assert_array_equal(
+                np.asarray(st.get_planar("o")[0]), want)
+            np.testing.assert_array_equal(
+                np.asarray(st.gather_rows("o", 3, 8)), want[3:8])
+        assert dev._entries["o"].pages == host._entries["o"].pages
+        if case == "three_subslabs":
+            assert len(self._subslabs(dev, "o")) >= 3
+        # the pad rows of a group repeat one of its own pages: nobody
+        # else's page may change
+        for key in neighbours:
+            np.testing.assert_array_equal(
+                np.asarray(dev.get_planar(key)[0]),
+                host.get_planar(key)[0])
+
+    def test_install_programs_counts_subslabs_one_compile_a_shape(self):
+        """`install_programs` moves by exactly the sub-slabs an install
+        touched (one launch each, nothing else), and installs of one
+        shape share one compiled program whatever their group sizes."""
+        import jax.numpy as jnp
+
+        from ceph_tpu.ops.slab import SLAB_PERF
+
+        host, dev = self._pair(256 << 10, 256)
+        self._fragment(host, dev)
+        assert dev.perf.get("install_programs") == 768  # one page each
+        compiles = []
+        for n, seed in enumerate((7, 8, 9)):
+            before = dev.perf.get("install_programs")
+            built = SLAB_PERF.get("compile")
+            bits = self._words(8, 128, seed=seed)
+            # the free list shrinks: 4 + 4 + 4 pages, then 2 + 2 + 2 + 6
+            # fresh ones, then 12 in one sub-slab
+            assert dev.put_planar(f"o{n}", jnp.asarray(bits), w=8,
+                                  n_rows=1, trim=96 * 32)
+            compiles.append(SLAB_PERF.get("compile") - built)
+            touched = len(self._subslabs(dev, f"o{n}"))
+            assert dev.perf.get("install_programs") - before == touched
+            np.testing.assert_array_equal(
+                np.asarray(dev.get_planar(f"o{n}")[0]), bits[:, :96])
+        assert len({len(self._subslabs(dev, f"o{n}")) for n in range(3)}) \
+            > 1  # the group sizes did differ
+        assert compiles == [1, 0, 0]
+        assert dev.perf.get("device_installs") == 768 + 3
+
     def test_env_override_pins_arms(self, monkeypatch):
         monkeypatch.setenv("CEPH_TPU_DEVICE_SLAB", "0")
         st = PagedResidentStore(capacity_bytes=1 << 20,
