@@ -304,16 +304,23 @@ class BitmatrixErasureCode(ErasureCode):
         self._decode_cache.put(chosen, inv)
         return inv
 
+    def decode_selection(self, want_to_read: Set[int],
+                         available: Set[int]):
+        """(chosen, inverted [k*w, k*w] bit-matrix) for reconstructing
+        from `available` — THE selection rule, shared by decode_chunks
+        and the batching queue's decode plan, as the matrix codecs'."""
+        plan = self.minimum_to_decode(
+            set(range(self.k)) | set(want_to_read), available)
+        chosen = tuple(sorted(plan))[: self.k]
+        return chosen, self._decode_bitmatrix(chosen)
+
     def decode_chunks(
         self, want_to_read: Set[int], chunks: Mapping[int, np.ndarray]
     ) -> Dict[int, np.ndarray]:
-        available = set(chunks)
-        plan = self.minimum_to_decode(set(range(self.k)) | set(want_to_read), available)
-        chosen = tuple(sorted(plan))[: self.k]
+        chosen, inv = self.decode_selection(set(want_to_read), set(chunks))
         src_rows = np.concatenate(
             [self._to_rows(np.asarray(chunks[c], dtype=np.uint8)[None, :]) for c in chosen]
         )
-        inv = self._decode_bitmatrix(chosen)
         data_rows = self._apply_rows(inv, src_rows)
         out: Dict[int, np.ndarray] = {}
         need_coding = [c for c in want_to_read if c >= self.k]

@@ -19,6 +19,16 @@ Batching: column counts are bucketed to powers of two (min 1024) to bound
 XLA recompilation; full cross-object stripe batching lives in
 ceph_tpu.parallel.service.BatchingQueue, which concatenates many
 encode_chunks calls into one device dispatch.
+
+Where the seams below run: the SERVED path (OSD writes, degraded reads,
+recovery) of every technique here plans through rados/ecutil.py onto the
+queue's lanes — byte-layout codes on "packedbit", the packet-layout five
+(cauchy_orig, cauchy_good, liberation, blaum_roth, liber8tion) on
+"packetrows" — and never calls `_apply`/`_apply_rows`.  The seams are the
+direct path: the benchmark CLI, the corpus tool, a daemon without a queue
+(CPU backend), mapped or ragged shapes no lane takes.  They dispatch on
+the caller's thread and tick `ec_plugin.apply`/`apply_rows`; the
+benchmark's `direct_dispatch_per_op.put` holds both at 0 per served op.
 """
 
 from __future__ import annotations

@@ -661,6 +661,49 @@ def encode_packedbit_resident_fn(bitmatrix: np.ndarray):
     return _compiled_schedule("resident", bitmatrix, build)
 
 
+def gf2_apply_packetrows(bitmatrix: np.ndarray, data, w: int,
+                         packetsize: int) -> "jnp.ndarray":
+    """[out_rows*w, n*w] GF(2) bit-matrix applied to [n, B] chunks in the
+    PACKET layout (cauchy/liberation family; B a whole number of
+    w*packetsize-byte blocks): ONE fused jitted call, the packed-bit
+    lane with another pair of layout stages.  A packet IS a bit-row
+    already, so no bit is moved: the rows-in stage is the block transpose
+    [n, nb, w, p] -> [n*w, nb*p], the same static XOR schedule runs over
+    those rows, and the rows-out stage transposes back to [out_rows, B].
+    `data` is uint8, or the same bytes viewed as uint32 words when
+    packetsize is a multiple of 4 (XOR is bitwise, so the word view
+    changes nothing but the element count of a packet).  Compatible,
+    byte for byte, with jerasure_bitmatrix_encode."""
+    return apply_packetrows_fn(bitmatrix, w, packetsize)(data)
+
+
+def apply_packetrows_fn(bitmatrix: np.ndarray, w: int, packetsize: int):
+    """The compiled (LRU-cached) jitted call behind gf2_apply_packetrows:
+    one per (matrix, w, packetsize)."""
+    out_rows = np.asarray(bitmatrix).shape[0] // w
+    C = np.asarray(bitmatrix).shape[1]
+
+    def build(ops, outs):
+        @jax.jit
+        def _run(x):
+            n, cols = x.shape
+            p = packetsize // x.dtype.itemsize  # elements in a packet
+            nb = cols // (w * p)
+            with jax.named_scope("to_packetrows"):
+                rows = (x.reshape(n, nb, w, p).transpose(0, 2, 1, 3)
+                        .reshape(n * w, nb * p))
+            with jax.named_scope("xor_apply"):
+                pouts = _schedule_apply(ops, outs, C, rows)
+            with jax.named_scope("from_packetrows"):
+                return (pouts.reshape(out_rows, w, nb, p)
+                        .transpose(0, 2, 1, 3).reshape(out_rows, cols))
+
+        return _run
+
+    return _compiled_schedule(f"packetrows.{w}.{packetsize}", bitmatrix,
+                              build)
+
+
 def pack_bitplanes_u32(data: np.ndarray, w: int = 8) -> np.ndarray:
     """Host-side packed-bit layout: [n, B] uint8 chunks -> [n*w, ceil(B/32)]
     uint32 words (bit b of word i = bit-plane value at column 32i+b) —

@@ -38,6 +38,19 @@ dispatch running the matrix as a static XOR schedule compiled per matrix
 Residents store at 1 HBM byte per data byte instead of 8, so the same
 store budget holds 8x the objects.
 
+PACKET-LAYOUT LANE (`submit_packetrows`, lane name "packetrows"): the
+bit-matrix codes (cauchy_orig/good, liberation, blaum_roth, liber8tion)
+lay a chunk out as w*packetsize-byte blocks of w packets, and a packet IS
+a bit-row.  Their lane is the packed-bit lane with another pair of layout
+stages, chosen by the codec's bit_layout, w and packetsize: the same
+launch/complete/mirror, the same static XOR schedule behind the same LRU,
+block transposes ([n, nb, w, p] <-> [n*w, nb*p]) on the device where the
+byte layout has bit transposes.  Requests coalesce by columns (a chunk is
+whole blocks); the width buckets to a power of two of blocks.  Encode
+generators and the inverted bit-matrices of decode signatures ride it
+(ecutil's plans), so no served op of such a pool dispatches from the
+event loop.  It keeps no residents.
+
 DEVICE-DISPATCH CIRCUIT BREAKER (the robustness layer): every lane owns a
 breaker with three states.  CLOSED: dispatches go to the device; one that
 RAISES is rescued host-side (the group's futures resolve with
@@ -90,9 +103,11 @@ from ceph_tpu.common.perf_counters import PerfCounters, PerfCountersBuilder
 
 log = logging.getLogger("ceph_tpu.ec.batch")
 
-#: the six dispatch lanes, in promotion order (int8 trio, packed-bit trio)
+#: the dispatch lanes, in promotion order (int8 trio, packed-bit trio, and
+#: the packed-bit lane's packet-layout form)
 LANES = ("packed", "planar", "resident",
-         "packedbit", "packedbit_resident", "packedbit_planes")
+         "packedbit", "packedbit_resident", "packedbit_planes",
+         "packetrows")
 
 
 def _build_ec_tpu_perf() -> PerfCounters:
@@ -235,12 +250,34 @@ def _np_words(bits: np.ndarray) -> np.ndarray:
                        bitorder="little").view(np.uint32)
 
 
+def _np_xor_rows(mb: np.ndarray, rows: np.ndarray) -> np.ndarray:
+    """out[r] = XOR of the rows that bit-matrix row r selects (mirror of
+    ops/gf2._schedule_apply; any element type)."""
+    out = np.zeros((mb.shape[0],) + rows.shape[1:], dtype=rows.dtype)
+    for r in range(mb.shape[0]):
+        cols = np.nonzero(mb[r])[0]
+        if len(cols):
+            out[r] = np.bitwise_xor.reduce(rows[cols], axis=0)
+    return out
+
+
 def _cpu_apply_request(kind: str, mbits: np.ndarray, regions, w: int,
-                       out_rows: int):
+                       out_rows: int, packetsize: int = 0):
     """Serve ONE lane request host-side; returns exactly what the device
     lane's fan-out would have resolved the request's future with (device
     buffers become numpy arrays — every consumer accepts both)."""
     mb = np.asarray(mbits, dtype=np.uint8)
+    if kind == "packetrows":
+        # mirror of ops/gf2.apply_packetrows_fn: block transpose in, XOR
+        # of whole packets, block transpose out
+        data = np.asarray(regions, dtype=np.uint8)
+        n, cols = data.shape
+        nb = cols // (w * packetsize)
+        rows = (data.reshape(n, nb, w, packetsize).transpose(0, 2, 1, 3)
+                .reshape(n * w, nb * packetsize))
+        return (_np_xor_rows(mb, rows)
+                .reshape(out_rows, w, nb, packetsize).transpose(0, 2, 1, 3)
+                .reshape(out_rows, cols))
     if kind in ("packed", "packedbit"):
         bits = _np_unpack_bits(np.asarray(regions, dtype=np.uint8), w)
         return _np_pack_bits(_np_matmul_gf2(mb, bits), w, out_rows)
@@ -257,13 +294,7 @@ def _cpu_apply_request(kind: str, mbits: np.ndarray, regions, w: int,
         return (_np_pack_bits(pbits, 8, out_rows),
                 np.concatenate([_np_words(bits), _np_words(pbits)], axis=0))
     if kind == "packedbit_planes":
-        pl = np.asarray(regions)
-        out = np.zeros((mb.shape[0], pl.shape[1]), dtype=pl.dtype)
-        for r in range(mb.shape[0]):
-            cols = np.nonzero(mb[r])[0]
-            if len(cols):
-                out[r] = np.bitwise_xor.reduce(pl[cols], axis=0)
-        return out
+        return _np_xor_rows(mb, np.asarray(regions))
     raise ValueError(f"unknown lane kind {kind!r}")
 
 
@@ -305,8 +336,11 @@ class _Group:
     # (packed in -> packed parity + planar rows out, the write path);
     # plus the packed-bit production trio mirroring them over u32 plane
     # words + static XOR schedules (ceph_tpu/ops/gf2.py lane promotion):
-    # "packedbit", "packedbit_planes", "packedbit_resident"
+    # "packedbit", "packedbit_planes", "packedbit_resident"; and
+    # "packetrows", the packed-bit lane for PACKET-layout codes (its
+    # layout stages are block transposes of `packetsize`-byte packets)
     kind: str = "packed"
+    packetsize: int = 0  # packet layout only
     requests: List[_Request] = field(default_factory=list)
     pending_bytes: int = 0
 
@@ -531,11 +565,33 @@ class BatchingQueue:
         return self._submit(mbits, planes, w, out_rows, "packedbit_planes",
                             span)
 
+    def submit_packetrows(
+        self, mbits: np.ndarray, regions: np.ndarray, w: int,
+        packetsize: int, out_rows: int, span=None,
+    ) -> "Future[np.ndarray]":
+        """The packed-bit lane for PACKET-layout codes (cauchy_orig/good,
+        liberation, blaum_roth, liber8tion): queue a [out_rows*w, n*w]
+        GF(2) bit-matrix over [n, B] uint8 chunks, B a whole number of
+        w*packetsize-byte blocks.  One fused device call per coalesced
+        group — block transpose to packet rows, the same static XOR
+        schedule, block transpose back (ops/gf2.apply_packetrows_fn) —
+        resolving to the [out_rows, B] parity or reconstruction buffer.
+        Requests coalesce by columns like the byte lanes': a chunk is
+        whole blocks, so the concatenation is a valid chunk set."""
+        if packetsize < 1 or regions.shape[1] % (w * packetsize):
+            # reject at SUBMISSION, as submit_packedbit_resident does
+            raise ValueError(
+                f"packetrows requests are whole w*packetsize={w}*"
+                f"{packetsize}-byte blocks, got width {regions.shape[1]}")
+        return self._submit(mbits, regions, w, out_rows, "packetrows", span,
+                            packetsize=packetsize)
+
     @tracing.sectioned("ecplan", "queue_submit")
     def submit_group(self, items, span=None) -> List[Future]:
         """Group-aware submit (the messenger/recovery whole-stripe-group
         handoff seam): queue a LIST of lane submissions — each item is
-        (mbits, regions, w, out_rows, kind) — under ONE lock acquisition
+        (mbits, regions, w, out_rows, kind), plus the packetsize on the
+        packet-layout lane — under ONE lock acquisition
         and ONE worker wakeup, so a coalesced group of objects reaches
         the EC tier as a single buffer-list submission instead of N
         contended submits.  Items sharing a dispatch signature land in
@@ -549,14 +605,15 @@ class BatchingQueue:
         with self._cv:
             if self._stop:
                 raise RuntimeError("BatchingQueue is closed")
-            for mbits, regions, w, out_rows, kind in items:
+            for mbits, regions, w, out_rows, kind, *packetsize in items:
                 fut: Future = Future()
                 futs.append(fut)
                 sizes.append(self._queue_locked(
-                    mbits, regions, w, out_rows, kind, fut, now, span))
+                    mbits, regions, w, out_rows, kind, fut, now, span,
+                    *packetsize))
             if items:
                 self._cv.notify()
-        for (_, _, _, _, kind), nbytes in zip(items, sizes):
+        for (_, _, _, _, kind, *_), nbytes in zip(items, sizes):
             self.perf.inc("submit")
             self.perf.inc(f"submit_{kind}")
             self.perf.inc(f"bytes_{kind}", nbytes)
@@ -566,17 +623,18 @@ class BatchingQueue:
         return futs
 
     def _queue_locked(self, mbits, regions, w, out_rows, kind, fut,
-                      now, span) -> int:
+                      now, span, packetsize: int = 0) -> int:
         """Insert one request into its dispatch group (caller holds the
         lock).  Returns the packed-equivalent byte size counted."""
         # the full dispatch signature: identical matrix BYTES under a
-        # different w or output arity is a different computation; the
-        # three lanes never share a dispatch (different layouts)
-        key = (w, out_rows, kind, mbits.shape, mbits.tobytes())
+        # different w, packet size or output arity is a different
+        # computation; the lanes never share a dispatch (different layouts)
+        key = (w, out_rows, kind, packetsize, mbits.shape, mbits.tobytes())
         group = self._groups.get(key)
         if group is None:
             group = self._groups[key] = _Group(
-                mbits=mbits, w=w, out_rows=out_rows, kind=kind)
+                mbits=mbits, w=w, out_rows=out_rows, kind=kind,
+                packetsize=packetsize)
         group.requests.append(_Request(regions, fut, now, span))
         # planar bit-plane submissions are 8x-expanded int8: count
         # their packed-equivalent size or the lane would flush at 1/8
@@ -590,7 +648,7 @@ class BatchingQueue:
 
     @tracing.sectioned("ecplan", "queue_submit")
     def _submit(self, mbits, regions, w, out_rows, kind,
-                span=None) -> Future:
+                span=None, packetsize: int = 0) -> Future:
         fut: Future = Future()
         now = time.monotonic()
         if span is not None:
@@ -599,7 +657,7 @@ class BatchingQueue:
             if self._stop:
                 raise RuntimeError("BatchingQueue is closed")
             nbytes = self._queue_locked(mbits, regions, w, out_rows, kind,
-                                        fut, now, span)
+                                        fut, now, span, packetsize)
             self._cv.notify()
         self.perf.inc("submit")
         self.perf.inc(f"submit_{kind}")
@@ -667,7 +725,7 @@ class BatchingQueue:
             # enough to saturate every round must not starve the other
             # (matrix, kind) lanes behind it (round-robin across lanes)
             part = _Group(mbits=g.mbits, w=g.w, out_rows=g.out_rows,
-                          kind=g.kind)
+                          kind=g.kind, packetsize=g.packetsize)
             while g.requests and (taken_bytes < budget
                                   or not part.requests):
                 req = g.requests.pop(0)
@@ -828,7 +886,7 @@ class BatchingQueue:
         try:
             results = [
                 _cpu_apply_request(g.kind, g.mbits, req.regions, g.w,
-                                   g.out_rows)
+                                   g.out_rows, g.packetsize)
                 for req in g.requests
             ]
         except Exception as e:
@@ -883,7 +941,7 @@ class BatchingQueue:
                         state = self._launch_planar(g)
                     elif g.kind == "resident":
                         state = self._launch_resident(g)
-                    elif g.kind == "packedbit":
+                    elif g.kind in ("packedbit", "packetrows"):
                         state = self._launch_packedbit(g)
                     elif g.kind == "packedbit_resident":
                         state = self._launch_packedbit_resident(g)
@@ -924,8 +982,8 @@ class BatchingQueue:
                 elif g.kind == "packedbit_planes":
                     self._complete_packedbit_planes(g, state)
                 else:
-                    # "packed" and "packedbit": both fan packed uint8
-                    # byte columns back out
+                    # "packed", "packedbit" and "packetrows": all fan
+                    # packed uint8 byte columns back out
                     self._complete_packed(g, state)
             except Exception as e:
                 # device completion failure: trip the breaker and rescue
@@ -1040,23 +1098,32 @@ class BatchingQueue:
                       "one device", batch.shape, exc_info=e)
             return batch, False
 
-    def _stage_packed_batch(self, g: _Group, align: int = 1):
+    def _stage_packed_batch(self, g: _Group, align: int = 1,
+                            words: bool = False):
         """The shared launch preamble for packed-byte request groups:
-        coalesce the requests column-wise, pow2-bucket the width (bounds
-        XLA recompiles), shard across the mesh when one is attached, and
-        otherwise start the H2D transfer NOW so it overlaps the previous
-        round's result fetch.  Returns (widths, batch, sharded, nbytes)."""
+        coalesce the requests column-wise, bucket the width to a power of
+        two of `align`-column units (bounds XLA recompiles; the packet
+        lane's unit is its w*packetsize block, the others' divides the
+        1024-column floor, so theirs is the plain pow2 width), shard
+        across the mesh when one is attached, and otherwise start the H2D
+        transfer NOW so it overlaps the previous round's result fetch.
+        `words` hands the device the same bytes as uint32 columns.
+        Returns (widths, batch, sharded, nbytes)."""
         import jax
 
         from ceph_tpu.ops.gf2 import bucket_columns as _bucket
 
         widths = [req.regions.shape[1] for req in g.requests]
         batch = np.concatenate([req.regions for req in g.requests], axis=1)
-        pad = _bucket(batch.shape[1]) - batch.shape[1]
+        cols = batch.shape[1]
+        pad = align * _bucket(-(-cols // align),
+                              lo=max(1, 1024 // align)) - cols
         if pad:
             batch = np.pad(batch, ((0, 0), (0, pad)))
         nbytes = batch.nbytes
         self.perf.inc("h2d_bytes", nbytes)
+        if words:
+            batch, align = batch.view(np.uint32), align // 4
         batch, sharded = self._maybe_shard(batch, pad_np=True, align=align)
         if not sharded:
             batch = jax.device_put(batch)  # async H2D staging
@@ -1087,7 +1154,7 @@ class BatchingQueue:
 
     def _complete_packed(self, g: _Group, state) -> None:
         widths, out, sharded, nbytes = state
-        out = self._fetch(out)
+        out = self._fetch(out).view(np.uint8)  # the packet lane's u32 words
         self._note_dispatch(nbytes, sharded)
         off = 0
         for width, req in zip(widths, g.requests):
@@ -1175,11 +1242,23 @@ class BatchingQueue:
         """One fused schedule call over the coalesced packed rows:
         unpack -> u32 words -> XOR schedule -> byte pack, compiled per
         matrix behind the gf2 LRU.  Fan-out is byte columns, so requests
-        of ANY width coalesce (pow2 bucketing keeps B % 32 == 0)."""
-        from ceph_tpu.ops.gf2 import gf2_apply_packedbit
+        of ANY width coalesce (pow2 bucketing keeps B % 32 == 0).
 
-        widths, batch, sharded, nbytes = self._stage_packed_batch(g, align=32)
-        out = gf2_apply_packedbit(g.mbits, batch)
+        The packet-layout form ("packetrows") is this lane with the other
+        pair of layout stages: whole w*packetsize blocks in, a block
+        transpose on the device where the byte layout has a bit
+        transpose, the same schedule.  A packet is XORed whole, so the
+        device gets it as u32 words when its size allows."""
+        from ceph_tpu.ops.gf2 import gf2_apply_packedbit, gf2_apply_packetrows
+
+        if g.kind == "packetrows":
+            widths, batch, sharded, nbytes = self._stage_packed_batch(
+                g, align=g.w * g.packetsize, words=g.packetsize % 4 == 0)
+            out = gf2_apply_packetrows(g.mbits, batch, g.w, g.packetsize)
+        else:
+            widths, batch, sharded, nbytes = self._stage_packed_batch(
+                g, align=32)
+            out = gf2_apply_packedbit(g.mbits, batch)
         return widths, out, sharded, nbytes
 
     # completion: _complete_packed (identical packed-byte fan-out)

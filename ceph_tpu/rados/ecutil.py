@@ -183,35 +183,68 @@ def _packedbit_route(codec) -> bool:
     return packedbit_enabled() and getattr(codec, "w", 8) == 8
 
 
+def _lane(codec, sinfo: StripeInfo):
+    """The queue lane this codec's plans ride, as (kind[, packetsize]):
+    the codec's bit_layout picks the layout stages, nothing else.
+    Packet-layout codes (cauchy/liberation family) ride "packetrows",
+    the packed-bit lane whose stages are block transposes; w=8
+    byte-layout codes "packedbit", the production lane (static XOR
+    schedule over u32 plane words); the rest the int8-plane "packed"
+    lane.  None when no lane takes the codec: a chunk remap, or chunks
+    that are not whole w*packetsize blocks."""
+    if codec.get_chunk_mapping():
+        return None
+    if getattr(codec, "bit_layout", "byte") == "packet":
+        if sinfo.chunk_size % (codec.w * codec.packetsize):
+            return None
+        return "packetrows", codec.packetsize
+    return ("packedbit",) if _packedbit_route(codec) else ("packed",)
+
+
+def _lane_item(lane, codec, bitmatrix, rows: np.ndarray, out_rows: int):
+    """The lane submission for applying `bitmatrix` (an encode generator
+    or an inverted decode signature) to `[n, n_stripes*chunk]` rows, as
+    BatchingQueue.submit_group takes it: (mbits, rows, w, out_rows,
+    kind[, packetsize])."""
+    dtype = np.int8 if lane[0] == "packed" else np.uint8
+    return (np.asarray(bitmatrix).astype(dtype), rows,
+            getattr(codec, "w", 8), out_rows, *lane)
+
+
+def _submit_lane(queue, item, span=None):
+    """One _lane_item alone, through the lane's own submit."""
+    mat, rows, w, out_rows, kind, *packetsize = item
+    if kind == "packetrows":
+        return queue.submit_packetrows(mat, rows, w, packetsize[0], out_rows,
+                                       span=span)
+    if kind == "packedbit":
+        return queue.submit_packedbit(mat, rows, w, out_rows, span=span)
+    return queue.submit(mat, rows, w, out_rows, span=span)
+
+
 @tracing.sectioned("ecplan", "encode_plan")
 def _encode_plan_parts(codec, sinfo: StripeInfo, arr: np.ndarray,
                        n_stripes: int):
     """The submit-free half of the queue encode plan: when the codec is
-    batchable (byte-layout bit seam, no chunk remap), returns
-    (kind, mbits, flat, w, m, reassemble) — the exact lane submission a
-    caller can hand to queue.submit/submit_packedbit, or (with several
-    buffers) to BatchingQueue.submit_group as one whole-stripe-group
-    handoff.  None when the queue path does not apply."""
+    batchable (a bit seam, no chunk remap), returns (item, reassemble) —
+    the lane submission (_lane_item) a caller hands to _submit_lane or
+    (with several buffers) to BatchingQueue.submit_group as one
+    whole-stripe-group handoff.  None when the queue path does not
+    apply."""
     mbits = codec.bit_generator()
-    if (mbits is None or getattr(codec, "bit_layout", "byte") != "byte"
-            or codec.get_chunk_mapping()):
+    lane = _lane(codec, sinfo) if mbits is not None else None
+    if lane is None:
         return None
     k = codec.get_data_chunk_count()
-    n = codec.get_chunk_count()
-    m = n - k
-    w = getattr(codec, "w", 8)
+    m = codec.get_chunk_count() - k
     ECPLAN_PERF.inc("plans")
     ECPLAN_PERF.inc("stripes", n_stripes)
-    # columns = stripes concatenated; one submit -> one device call
+    # columns = stripes concatenated; one submit -> one device call.  The
+    # layout stages run on the device: byte and packet layout alike hand
+    # the queue these [k, n_stripes*chunk] rows.
     flat = np.ascontiguousarray(
         arr.transpose(1, 0, 2).reshape(k, n_stripes * sinfo.chunk_size))
-    if _packedbit_route(codec):
-        # production lane: static XOR schedule over u32 plane words
-        kind = "packedbit"
-        mat = np.asarray(mbits).astype(np.uint8)
-    else:
-        kind = "packed"
-        mat = np.asarray(mbits).astype(np.int8)
+    item = _lane_item(lane, codec, mbits, flat, m)
 
     @tracing.sectioned("ecplan", "reassemble")
     def reassemble(parity: np.ndarray) -> List[np.ndarray]:
@@ -227,25 +260,21 @@ def _encode_plan_parts(codec, sinfo: StripeInfo, arr: np.ndarray,
             out.append(p[j])
         return out
 
-    return kind, mat, flat, w, m, reassemble
+    return item, reassemble
 
 
 def _queue_encode_plan(codec, sinfo: StripeInfo, arr: np.ndarray,
                        n_stripes: int, queue, span=None):
-    """When the codec/queue combination is batchable (byte-layout bit
-    seam, no chunk remap), submit the whole buffer as ONE queue request
-    and return (future, reassemble) — reassemble turns the parity rows
-    into the per-shard blob list.  None when the queue path does not
-    apply (packet-layout, mapped, or sub-chunk codecs)."""
+    """When the codec/queue combination is batchable (a bit seam, byte or
+    packet layout, no chunk remap), submit the whole buffer as ONE queue
+    request and return (future, reassemble) — reassemble turns the parity
+    rows into the per-shard blob list.  None when the queue path does
+    not apply (mapped or sub-chunk codecs, codecs without a bit seam)."""
     parts = _encode_plan_parts(codec, sinfo, arr, n_stripes)
     if parts is None:
         return None
-    kind, mat, flat, w, m, reassemble = parts
-    if kind == "packedbit":
-        fut = queue.submit_packedbit(mat, flat, w, m, span=span)
-    else:
-        fut = queue.submit(mat, flat, w, m, span=span)
-    return fut, reassemble
+    item, reassemble = parts
+    return _submit_lane(queue, item, span=span), reassemble
 
 
 def batched_encode(codec, sinfo: StripeInfo, data: bytes,
@@ -257,8 +286,10 @@ def batched_encode(codec, sinfo: StripeInfo, data: bytes,
     dispatch is the bottleneck, so here every stripe rides one batched
     call: the buffer is re-interleaved into per-shard rows
     (`[k, n_stripes*chunk]`) and the codec transforms all stripes at once
-    — through encode_chunks (one device dispatch for plugin=tpu) or
-    through the shared BatchingQueue when one is provided.  Byte-identical
+    — through the shared BatchingQueue when one is provided (byte- and
+    packet-layout codecs alike: _lane), else through encode_chunks (one
+    direct device dispatch for plugin=tpu, on the caller's thread).
+    Byte-identical
     to the per-stripe loop for every concat-safe codec (see concat_safe);
     CLAY takes the per-stripe path.  Returns one concatenated per-shard
     buffer each, `[n_shards][n_stripes*chunk]`, in physical shard order.
@@ -279,9 +310,9 @@ def batched_encode(codec, sinfo: StripeInfo, data: bytes,
                n_stripes, k, sinfo.chunk_size)
            if len(padded) else None)
     if queue is not None and arr is not None:
-        # the interface's bit seam drives ANY byte-layout codec through
-        # the one matmul kernel; packet-layout codecs (cauchy/liberation
-        # family) take the encode_chunks/per-stripe paths below.
+        # the interface's bit seam drives ANY byte- or packet-layout
+        # codec through the queue's lanes; mapped and sub-chunk codecs
+        # take the encode_chunks/per-stripe paths below.
         # Single-stripe objects ride the queue too — coalescing across
         # OBJECTS/ops is the point (SURVEY.md §7.5), and small concurrent
         # writes are exactly the dispatch-latency-bound workload.
@@ -342,7 +373,7 @@ async def batched_encode_group_async(codec, sinfo: StripeInfo, buffers,
     submits that only the delay window may happen to coalesce.
 
     Returns the per-buffer shard lists, index-aligned with ``buffers``.
-    Buffers the queue plan cannot take (packet-layout codecs, empty
+    Buffers the queue plan cannot take (mapped or sub-chunk codecs, empty
     objects, no queue) fall back to the plain batched_encode path."""
     import asyncio
 
@@ -358,9 +389,8 @@ async def batched_encode_group_async(codec, sinfo: StripeInfo, buffers,
                     n_stripes, sinfo.k, sinfo.chunk_size)
                 parts = _encode_plan_parts(codec, sinfo, arr, n_stripes)
                 if parts is not None:
-                    kind, mat, flat, w, m, reassemble = parts
-                    items.append((mat, flat, w, m, kind))
-                    metas.append((i, reassemble))
+                    items.append(parts[0])
+                    metas.append((i, parts[1]))
                     continue
         out[i] = batched_encode(codec, sinfo, data, queue=None)
     if items:
@@ -377,12 +407,14 @@ def _queue_decode_plan(codec, sinfo: StripeInfo,
     """Queue submission for a reconstructing decode: CPU picks/inverts
     the decode matrix via the codec's OWN selection rule (LRU-cached per
     erasure signature, the ISA table cache design), the device applies it
-    — so decode and recovery ride the same batched kernel as encode.
+    — so decode and recovery ride the same batched kernel as encode,
+    packet-layout pools on the same lane as their encode (the inverted
+    bit-matrix of an erasure signature is one more matrix for it).
     Returns (future, finish) with finish(rows) -> the reconstructed
     logical bytes trimmed to object_size, or None when the queue path
     does not apply."""
-    if (getattr(codec, "bit_layout", "byte") != "byte"
-            or codec.get_chunk_mapping() or not concat_safe(codec)
+    lane = _lane(codec, sinfo)
+    if (lane is None or not concat_safe(codec)
             or not hasattr(codec, "decode_selection")):
         return None
     blob_len = len(next(iter(arrays.values())))
@@ -399,25 +431,26 @@ def _queue_decode_plan(codec, sinfo: StripeInfo,
         return None
     if any(c not in arrays for c in chosen):
         return None
-    from ceph_tpu.ec.matrices import matrix_to_bitmatrix
-
     # dispatch ONLY the missing data rows (available ones pass through):
     # the matmul shrinks from k rows to n_lost — same trimming the codec
     # CPU path does, so queue and CPU decode stay work-equivalent
     missing = sorted(c for c in range(k) if c not in arrays)
-    inv_bm = matrix_to_bitmatrix(inv[missing], codec.w)
-    src = np.ascontiguousarray(np.stack([arrays[c] for c in chosen]))
-    if _packedbit_route(codec):
-        # decode rides the production packed-bit lane: the inverted
-        # signature matrix compiles to its own static XOR schedule
-        # behind the gf2 LRU (per-decode-signature compilation — the
-        # ErasureCodeIsaTableCache design at compile scope)
-        fut = queue.submit_packedbit(
-            inv_bm.astype(np.uint8), src, codec.w, len(missing), span=span)
+    w = codec.w
+    if lane[0] == "packetrows":
+        # the bitmatrix codecs invert at bit level: chunk c's w rows
+        inv_bm = np.vstack([inv[c * w:(c + 1) * w] for c in missing])
     else:
-        fut = queue.submit(inv_bm.astype(np.int8), src, codec.w,
-                           len(missing), span=span)
+        from ceph_tpu.ec.matrices import matrix_to_bitmatrix
 
+        inv_bm = matrix_to_bitmatrix(inv[missing], w)
+    src = np.ascontiguousarray(np.stack([arrays[c] for c in chosen]))
+    # on the schedule lanes the inverted signature matrix compiles to its
+    # own static XOR schedule behind the gf2 LRU (per-decode-signature
+    # compilation — the ErasureCodeIsaTableCache design at compile scope)
+    fut = _submit_lane(
+        queue, _lane_item(lane, codec, inv_bm, src, len(missing)), span=span)
+
+    @tracing.sectioned("ecplan", "decode_finish")
     def finish(rows: np.ndarray) -> bytes:
         rebuilt = np.asarray(rows)
         full = np.empty((k, n_stripes * cs), dtype=np.uint8)
@@ -567,6 +600,9 @@ async def decode_object_async(codec, sinfo: StripeInfo,
 
 
 def planar_eligible(codec) -> bool:
+    # packet-layout pools encode and decode on the queue (_lane) but keep
+    # no residents: an install of their rows is PERF.md section 7's
+    # open question
     return (getattr(codec, "bit_layout", "byte") == "byte"
             and not codec.get_chunk_mapping()
             and concat_safe(codec)

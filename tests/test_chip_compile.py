@@ -153,6 +153,22 @@ def test_xor_packed_cauchy_rows(one_chip, mats):
              _spec((10 * 8, cols), np.uint8, one_chip))
 
 
+@pytest.mark.parametrize("blocks", [32, 64, 128])
+def test_apply_packetrows_cauchy(one_chip, mats, blocks):
+    """The queue's packet-layout lane for cauchy_good k=10 m=4 w=8
+    packetsize=2048 at the widths the served path makes: one 4 MiB put's
+    28 blocks a row bucket to 32, groups of 2 to 64, of 3-4 to 128, as
+    u32 words.  Block transposes and schedule fuse: no temporaries
+    (sandbox compiler, PR 28; 1.5-4 s a width)."""
+    from ceph_tpu.ops.gf2 import apply_packetrows_fn
+
+    compiled = apply_packetrows_fn(mats["cauchy"], 8, 2048).lower(
+        _spec((10, blocks * 8 * 2048 // 4), np.uint32, one_chip)).compile()
+    ma = compiled.memory_analysis()
+    assert ma.output_size_in_bytes == 4 * blocks * 8 * 2048
+    assert ma.temp_size_in_bytes < ma.argument_size_in_bytes
+
+
 @pytest.mark.parametrize("src,cols", [
     ((88, 16384), 16384),   # k=8 m=3, 4 MiB: one page a bit-row
     ((48, 32768), 32768),   # k=4 m=2, 4 MiB: two pages a bit-row

@@ -98,6 +98,8 @@ class TestEcTpuCounters:
                 q.submit_packedbit(bmu, rows, W, M),
                 q.submit_packedbit_resident(bmu, rows, W, M),
                 q.submit_packedbit_planes(bmu, planes_u32, W, M),
+                # the packet-layout form: B is whole W*16-byte blocks
+                q.submit_packetrows(bmu, rows, W, 16, M),
             ]
             q.flush()
             for f in futs:
@@ -108,7 +110,7 @@ class TestEcTpuCounters:
                 # every lane counts PACKED-equivalent bytes: K rows x B
                 assert d[f"bytes_{lane}"] == K * B, lane
             assert d["submit"] == len(LANES)
-            # six distinct (matrix-dtype, lane) groups -> six dispatches
+            # distinct (matrix-dtype, lane) groups: a dispatch a lane
             assert d["dispatch"] == len(LANES)
             assert d["flush_forced"] == 1  # ONE flush() drained them all
             assert d["dispatch_dev"]["avgcount"] == len(LANES)
