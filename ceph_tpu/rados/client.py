@@ -45,6 +45,7 @@ from ceph_tpu.common.perf_counters import PerfCounters, PerfCountersBuilder
 from ceph_tpu.common import tracing
 from ceph_tpu.common.tracing import Tracer
 from ceph_tpu.rados.clog import ClogEntry, LogClient, decode_entries
+from ceph_tpu.rados.crush import CRUSH_PERF
 from ceph_tpu.rados.messenger import BufferList, Messenger
 from ceph_tpu.rados.monclient import MonTargets
 from ceph_tpu.rados.types import (
@@ -480,11 +481,13 @@ class RadosClient:
 
     def perf_dump(self) -> Dict[str, Dict]:
         """Client-side `perf dump` role: the `objecter` set plus the
-        messenger's `wire` set (clients own no admin socket — tools,
-        benches, and embedding daemons read this)."""
+        messenger's `wire` set and the process's `loop` and `crush`
+        sets (clients own no admin socket — tools, benches, and
+        embedding daemons read this)."""
         return {"objecter": self.perf.dump(),
                 "wire": self.messenger.perf.dump(),
-                "loop": tracing.LOOP_PERF.dump()}
+                "loop": tracing.LOOP_PERF.dump(),
+                "crush": CRUSH_PERF.dump()}
 
     @property
     def mon_addr(self) -> Tuple[str, int]:

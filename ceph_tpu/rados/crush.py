@@ -32,12 +32,38 @@ from __future__ import annotations
 
 import math
 import struct
+import threading
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Set, Tuple
+
+from ceph_tpu.common.perf_counters import PerfCountersBuilder
 
 CRUSH_ITEM_NONE = -1 << 30  # hole marker in indep mode (reference CRUSH_ITEM_NONE)
 
 CHOOSE_TRIES = 19  # bounded retries per position (reference choose_total_tries=50)
+
+# The placement memo.  A draw is a pure function of what `do_rule` reads:
+# the rule's steps, x, num_rep, the weight overlay and the map's tree
+# (`CrushMap._draw_inputs`), so it is keyed on all of that BY VALUE and
+# nothing has to invalidate it: a map edited in place (the mon's
+# handlers, `apply_incremental`, a test flipping an attribute with no
+# epoch) has another key, and equal maps share an entry whichever daemon
+# of the process holds them (the reference draws on map change,
+# OSDMap::_pg_to_up_acting_osds, and a PG keeps its acting set for the
+# interval).  It lives here and not on the map, so it is in no pickle, no
+# deep copy, no `sig()`.  Oldest entry out at _MEMO_MAX: a sweep
+# (CrushTester, the balancer) cannot grow it.
+_MEMO_MAX = 1024
+_memo: Dict[Tuple, Tuple[int, ...]] = {}
+_memo_lock = threading.Lock()  # misses only: insert + evict
+
+# the `crush` set: one per process, listed by every daemon's collection
+# like `gf2_sched`.  draws / lookups is the memo's miss share.
+CRUSH_PERF = (PerfCountersBuilder("crush")
+              .add_u64_counter("lookups", "placements asked of do_rule")
+              .add_u64_counter("draws", "of them, the straw2 draw ran "
+                                        "(placement memo misses)")
+              .create_perf_counters())
 
 
 def _mix(*vals: int) -> int:
@@ -134,6 +160,12 @@ class CrushMap:
     def move_item(self, item: int, to_bucket: int, weight: float = 1.0) -> None:
         self.remove_item(item)
         self.add_item(to_bucket, item, weight)
+
+    def remove_bucket(self, bucket_id: int) -> None:
+        """Unlink a bucket from its parent and drop it (its items go with
+        it: re-home them first)."""
+        self.remove_item(bucket_id)
+        self.buckets.pop(bucket_id, None)
 
     def set_weight(self, osd: int, weight: float) -> None:
         for b in self.buckets.values():
@@ -291,21 +323,48 @@ class CrushMap:
         return self._descend(self.buckets[bucket_id], x, r,
                              self.DEVICE_TYPE, exclude, overlay, memo)
 
+    def _draw_inputs(self, rule: dict) -> Tuple:
+        """Everything of this map that `_draw` reads, as a value: the
+        rule's program, the root, each bucket's type and ordered items,
+        the stored device weights (a bucket's own `weights` are not read:
+        its placement weight is its subtree's sum)."""
+        return (rule["mode"], tuple(map(tuple, rule["steps"])), self.root_id,
+                tuple([(bid, b.type, tuple(b.items))
+                       for bid, b in self.buckets.items()]),
+                tuple(self.device_weights.items()))
+
     def do_rule(self, rule_name: str, x: int, num_rep: int,
                 weights: Dict[int, float]) -> List[int]:
-        """Map input x (PG seed) to num_rep devices.
-
-        indep mode (EC): each position r draws independently with bounded
-        retries; an unplaceable position stays CRUSH_ITEM_NONE — holes are
-        holes (mapper.c:630 crush_choose_indep).
-        firstn mode (replication): forward-filled distinct choices
-        (mapper.c:438 crush_choose_firstn)."""
+        """Map input x (PG seed) to num_rep devices: `_draw`'s answer,
+        drawn once per value of its inputs (the placement memo above).
+        The list is the caller's own."""
         rule = self.rules.get(rule_name)
         if rule is None:
             rule = {"mode": "indep",
                     "steps": [("take", self.root_id),
                               ("choose", "indep", 0, self.DEVICE_TYPE),
                               ("emit",)]}
+        key = (x, num_rep, tuple(weights.items()), self._draw_inputs(rule))
+        CRUSH_PERF.inc("lookups")
+        out = _memo.get(key)
+        if out is None:
+            CRUSH_PERF.inc("draws")
+            out = tuple(self._draw(rule, x, num_rep, weights))
+            with _memo_lock:
+                if len(_memo) >= _MEMO_MAX:
+                    del _memo[next(iter(_memo))]
+                _memo[key] = out
+        return list(out)
+
+    def _draw(self, rule: dict, x: int, num_rep: int,
+              weights: Dict[int, float]) -> List[int]:
+        """The draw itself.
+
+        indep mode (EC): each position r draws independently with bounded
+        retries; an unplaceable position stays CRUSH_ITEM_NONE — holes are
+        holes (mapper.c:630 crush_choose_indep).
+        firstn mode (replication): forward-filled distinct choices
+        (mapper.c:438 crush_choose_firstn)."""
         overlay = dict(weights)
         memo: Dict[int, float] = {}
         working: List[int] = [self.root_id]

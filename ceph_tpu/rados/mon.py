@@ -2299,8 +2299,7 @@ class Monitor:
                 cw = (crush.device_weights.get(child, 1.0)
                       if child >= 0 else 0.0)
                 crush.move_item(child, parent, cw)
-            crush.remove_item(item)
-            del crush.buckets[item]
+            crush.remove_bucket(item)
             self.logm.log("cluster", CLOG_INFO,
                           f"crush rm bucket {msg.name}"
                           + (f" (forced, {len(rehomed)} re-homed)"

@@ -53,7 +53,7 @@ from ceph_tpu.common.context import Context
 from ceph_tpu.common.perf_counters import PerfCountersBuilder
 from ceph_tpu.ec.interface import ErasureCodeError
 from ceph_tpu.ec.registry import registry
-from ceph_tpu.rados.crush import CRUSH_ITEM_NONE
+from ceph_tpu.rados.crush import CRUSH_ITEM_NONE, CRUSH_PERF
 from ceph_tpu.rados.extent_cache import ExtentCache
 from ceph_tpu.utils.checksum import verify_any as crc_verify_any
 from ceph_tpu.rados.ecutil import (ECPLAN_PERF, HashInfo, StripeInfo,
@@ -549,10 +549,10 @@ class OSD:
         # (per-lane submits/bytes, queue-wait/dispatch latencies, flush
         # causes), the gf2 `gf2_sched` schedule-cache set, the tpu
         # plugin's `ec_plugin` seam set (device dispatches vs CPU
-        # fallbacks — the non-queue path), and the planar store's
-        # `planar_store` residency set.  The queue/store/sched/plugin
-        # sets are process-shared (as the resources are); every
-        # colocated OSD dumps the same numbers.
+        # fallbacks — the non-queue path), the `crush` placement-memo
+        # set, and the planar store's `planar_store` residency set.  The
+        # queue/store/sched/plugin/crush sets are process-shared (as the
+        # resources are); every colocated OSD dumps the same numbers.
         self.ctx.perf.add(self.messenger.perf)
         for worker in getattr(self.messenger.reactors, "workers", ()):
             meter = getattr(worker, "meter", None)  # thread-mode reactors
@@ -562,6 +562,7 @@ class OSD:
 
         self.ctx.perf.add(SCHED_PERF)
         self.ctx.perf.add(ECPLAN_PERF)
+        self.ctx.perf.add(CRUSH_PERF)
         try:
             from ceph_tpu.ec.plugins.tpu import PLUGIN_PERF
 

@@ -134,6 +134,34 @@ class TestHierarchy:
         m.move_item(0, h1)
         assert 0 in m.buckets[h1].items and 0 not in m.buckets[h0].items
 
+    def test_remove_bucket_unlinks_and_drops(self):
+        m = CrushMap.with_hosts(list(range(6)), 3)
+        m.add_simple_rule("r", failure_domain="host", mode="indep")
+        w = alive(range(6))
+        before = [m.do_rule("r", x, 3, w) for x in range(50)]
+        h2 = m.bucket_by_name("host2")
+        gone = list(h2.items)
+        m.remove_bucket(h2.id)
+        assert h2.id not in m.buckets and m.parent_of(h2.id) is None
+        assert h2.id not in m.buckets[m.root_id].items
+        after = [m.do_rule("r", x, 3, w) for x in range(50)]
+        assert after != before
+        assert not any(d in acting for acting in after for d in gone)
+        m.remove_bucket(h2.id)  # idempotent, like remove_item
+
+
+class TestTester:
+    def test_a_repeated_sweep_gives_the_same_statistics(self):
+        """The tester's second pass over a map is all lookups where it
+        fits the memo, all draws where it does not: same numbers."""
+        m = CrushMap.with_hosts(list(range(12)), 4)
+        m.add_simple_rule("r", failure_domain="host", mode="indep")
+        t = CrushTester(m)
+        for n in (64, 2048):
+            assert t.test("r", 4, n_inputs=n) == t.test("r", 4, n_inputs=n)
+        assert t.indep_stability("r", 4, kill=5) \
+            == t.indep_stability("r", 4, kill=5)
+
 
 class TestHostDomainCluster:
     def test_ec_pool_over_host_failure_domain(self):
