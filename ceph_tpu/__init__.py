@@ -1,6 +1,6 @@
 """ceph_tpu — a TPU-native distributed-storage framework with Ceph's capabilities.
 
-Built from scratch on JAX/XLA/Pallas (compute path) + C++ (native runtime), not a
+Built from scratch on JAX/XLA (compute path) + C++ (native runtime), not a
 port of the reference's C/C++ design.  The flagship subsystem is erasure coding:
 a ``plugin=tpu`` Reed-Solomon GF(2^8) backend whose parity math runs as a
 bit-plane GF(2) matmul on the TPU MXU, registered through the same pluggable
@@ -9,7 +9,7 @@ codec-registry architecture the reference uses (see
 
 Layout:
   ceph_tpu.ec        codec interface, registry, GF math, CPU codecs, tpu plugin
-  ceph_tpu.ops       JAX/Pallas kernels (bit-plane GF matmul and friends)
+  ceph_tpu.ops       JAX/XLA kernels (bit-plane GF matmul and friends)
   ceph_tpu.parallel  device mesh, shardings, distributed EC service
   ceph_tpu.rados     mini-RADOS: messenger, monitor, OSD, EC backend, stores
   ceph_tpu.utils     buffers, profiles, config, perf counters, logging

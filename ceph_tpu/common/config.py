@@ -274,7 +274,7 @@ DEFAULT_SCHEMA: Dict[str, Option] = _opts(
                 "trips the circuit breaker on a wedged device"),
     Option("osd_ec_planar_residency", OPT_BOOL, True,
            desc="keep encoded shard rows planar-resident on the device "
-                "(PlanarShardStore cache tier)"),
+                "(PagedResidentStore cache tier)"),
     Option("osd_ec_planar_bytes", OPT_SIZE, 0,
            desc="planar residency byte budget (0 = store default)"),
     # multi-tenant QoS (reference mClockScheduler client profiles; pool
@@ -363,10 +363,6 @@ DEFAULT_SCHEMA: Dict[str, Option] = _opts(
            desc="consecutive newest hit sets an object must appear in "
                 "before a write installs a resident (0 = always; the "
                 "r10 behavior was an unconditional install)"),
-    Option("osd_tier_pagestore", OPT_BOOL, True,
-           desc="back the residency tier with the paged store "
-                "(page table + ragged tails + dirty bits) instead of "
-                "monolithic per-object buffers"),
     Option("osd_tier_page_bytes", OPT_SIZE, 64 << 10,
            desc="page size of the paged resident store (u32-word "
                 "pages; eviction and dirty tracking are per page)"),

@@ -5,8 +5,8 @@ seam, BASELINE.json): profiles say ``plugin=tpu technique=reed_sol_van k=8
 m=3`` and the codec produces chunks byte-identical to the jerasure-equivalent
 CPU codec — same matrices, same padding/alignment rules (it *subclasses* the
 jerasure technique classes, so get_chunk_size et al. are literally shared) —
-while encode/decode/recovery run as one bit-plane GF(2) matmul on the device
-(ceph_tpu/ops/gf2.py, Pallas kernel in ceph_tpu/ops/pallas_gf2.py).
+while encode/decode/recovery run as one bit-plane GF(2) product on the device
+(ceph_tpu/ops/gf2.py).
 
 Failure semantics: the device is a new failure domain the in-process dlopen
 model never had (SURVEY.md §7 hard part 5).  Every dispatch falls back to
@@ -107,15 +107,6 @@ class _TpuDispatch:
             cache = self._bitmatrix_cache = {}
         return cache
 
-    def _use_pallas(self, cols: int) -> bool:
-        from ceph_tpu.ops.gf2 import pallas_enabled
-        from ceph_tpu.ops.pallas_gf2 import TILE_B
-        from ceph_tpu.utils.jaxdev import probe_backend
-
-        return (
-            pallas_enabled() and probe_backend() == "tpu" and cols % TILE_B == 0
-        )
-
     # seam override: GF(2^w) matrix applied to symbol regions
     def _apply(self, matrix: np.ndarray, regions: np.ndarray) -> np.ndarray:
         if not self._device_ok():
@@ -123,9 +114,8 @@ class _TpuDispatch:
             return super()._apply(matrix, regions)
         try:
             from ceph_tpu.ops.gf2 import bucket_columns as _bucket
-            from ceph_tpu.ops.gf2 import (gf2_apply_bytes,
-                                          gf2_apply_packedbit,
-                                          packedbit_enabled)
+            from ceph_tpu.ops.gf2 import gf2_apply_bytes, gf2_apply_packedbit
+            from ceph_tpu.rados.ecutil import lane_for
 
             cache = self._bm_cache()
             key = matrix.tobytes()
@@ -139,18 +129,16 @@ class _TpuDispatch:
             if padded != B:
                 buf = np.zeros((rows, padded), dtype=np.uint8)
                 buf[:, :B] = regions
-            use_pallas = self._use_pallas(padded)
+            # the same program the queue's lane of this codec would run
             with PLUGIN_PERF.time_avg("apply_s"):
-                if packedbit_enabled() and self.w == 8 and not use_pallas:
-                    # production lane: one fused static-XOR-schedule
-                    # call, compiled per matrix behind the gf2 LRU —
-                    # encode generators AND decode signature matrices
-                    # alike (pow2 bucketing keeps B a whole number of
-                    # u32 words)
+                if lane_for(self)[0] == "packedbit":
+                    # one fused static-XOR-schedule call, compiled per
+                    # matrix behind the gf2 LRU — encode generators AND
+                    # decode signature matrices alike (pow2 bucketing
+                    # keeps B a whole number of u32 words)
                     out = gf2_apply_packedbit(bm, buf)
                 else:
-                    out = gf2_apply_bytes(
-                        bm, buf, self.w, out_rows, use_pallas=use_pallas)
+                    out = gf2_apply_bytes(bm, buf, self.w, out_rows)
                 out = np.asarray(out)
             PLUGIN_PERF.inc("apply")
             return out[:, :B]
@@ -165,59 +153,25 @@ class _TpuDispatch:
             return super()._apply_rows(bm, rows)
         try:
             from ceph_tpu.ops.gf2 import bucket_columns as _bucket
-            from ceph_tpu.ops.gf2 import (gf2_apply_packets, gf2_xor_packed,
-                                          packedbit_enabled)
+            from ceph_tpu.ops.gf2 import gf2_xor_packed
 
-            if packedbit_enabled():
-                # production lane for the bitmatrix (cauchy/liberation)
-                # family: a packet-row combine IS a GF(2) XOR of whole
-                # rows, so the static XOR schedule applies DIRECTLY to
-                # the packet bytes — no 8x bit expansion at all (this is
-                # jerasure_schedule_encode's shape, compiled by XLA).
-                R, nb, p = rows.shape
-                flat = np.ascontiguousarray(rows.reshape(R, nb * p))
-                padded = _bucket(flat.shape[1])
-                if padded != flat.shape[1]:
-                    buf = np.zeros((R, padded), dtype=np.uint8)
-                    buf[:, :flat.shape[1]] = flat
-                    flat = buf
-                with PLUGIN_PERF.time_avg("apply_rows_s"):
-                    out = np.asarray(gf2_xor_packed(
-                        np.asarray(bm, dtype=np.uint8), flat))
-                PLUGIN_PERF.inc("apply_rows")
-                return out[:, :nb * p].reshape(bm.shape[0], nb, p)
-
-            w, p = self.w, self.packetsize
-            R, nb, _ = rows.shape
-            n = R // w
-            out_n = bm.shape[0] // w
-            # rows -> chunk layout; the fused op does the 8x bit expansion
-            # on-device instead of in host memory
-            chunks = (
-                rows.reshape(n, w, nb, p).transpose(0, 2, 1, 3).reshape(n, nb * w * p)
-            )
-            # pad the block axis to a power-of-two bucket to bound recompiles
-            nb_pad = _bucket(nb, lo=1)
-            if nb_pad != nb:
-                buf = np.zeros((n, nb_pad * w * p), dtype=np.uint8)
-                buf[:, : chunks.shape[1]] = chunks
-                chunks = buf
+            # a packet-row combine IS a GF(2) XOR of whole rows, so the
+            # static XOR schedule applies DIRECTLY to the packet bytes —
+            # the "packetrows" lane's middle stage, no bit expansion at
+            # all (this is jerasure_schedule_encode's shape, compiled by
+            # XLA)
+            R, nb, p = rows.shape
+            flat = np.ascontiguousarray(rows.reshape(R, nb * p))
+            padded = _bucket(flat.shape[1])
+            if padded != flat.shape[1]:
+                buf = np.zeros((R, padded), dtype=np.uint8)
+                buf[:, :flat.shape[1]] = flat
+                flat = buf
             with PLUGIN_PERF.time_avg("apply_rows_s"):
-                out = np.asarray(
-                    gf2_apply_packets(
-                        bm,
-                        chunks,
-                        w,
-                        p,
-                        out_n,
-                        use_pallas=self._use_pallas(nb_pad * p * 8),
-                    )
-                )
+                out = np.asarray(gf2_xor_packed(
+                    np.asarray(bm, dtype=np.uint8), flat))
             PLUGIN_PERF.inc("apply_rows")
-            out = out[:, : nb * w * p] if nb_pad != nb else out
-            return (
-                out.reshape(out_n, nb, w, p).transpose(0, 2, 1, 3).reshape(out_n * w, nb, p)
-            )
+            return out[:, :nb * p].reshape(bm.shape[0], nb, p)
         except Exception as e:
             self._mark_failed(e)
             return super()._apply_rows(bm, rows)

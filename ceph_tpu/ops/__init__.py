@@ -1,5 +1,5 @@
-"""JAX/XLA/Pallas compute kernels for the erasure-code data path."""
+"""JAX/XLA compute kernels for the erasure-code data path."""
 
-from ceph_tpu.ops.gf2 import gf2_apply_bytes, gf2_apply_packets, gf2_matmul
+from ceph_tpu.ops.gf2 import gf2_apply_bytes, gf2_matmul
 
-__all__ = ["gf2_matmul", "gf2_apply_bytes", "gf2_apply_packets"]
+__all__ = ["gf2_matmul", "gf2_apply_bytes"]

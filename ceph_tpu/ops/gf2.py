@@ -4,149 +4,53 @@ A GF(2^w) linear code is a GF(2) linear map on bit-planes, so the parity
 computation the reference dispatches per-stripe to CPU SIMD
 (jerasure_matrix_encode / jerasure_schedule_encode, reference
 src/erasure-code/jerasure/ErasureCodeJerasure.cc:105-138) becomes ONE batched
-MXU matmul here:
+device call here:
 
     out_bits[R, B] = (M_bits[R, C] @ data_bits[C, B]) & 1
 
-with int8 0/1 operands (int8 matmul maps natively onto the MXU) and the
-matrix as an *operand* — so the same compiled kernel serves encode (generator
-bit-matrix), decode (inverted signature matrix), and recovery, exactly the
-"one kernel" shape the north star asks for.
-
 Two data layouts feed it (see ceph_tpu/ec/codecs.py):
   * byte layout  (reed_sol codes): bit-row j*w+x = bit x of chunk j's bytes;
-  * packet layout (cauchy/liberation): bit-row j*w+l = packet l of chunk j,
-    further unpacked bit-columns-within-bytes to reach the MXU.
+  * packet layout (cauchy/liberation): bit-row j*w+l = packet l of chunk j.
 
-The pure-XLA path below is correct everywhere (CPU tests included); the
-Pallas kernel (ceph_tpu/ops/pallas_gf2.py) fuses unpack+matmul+pack in VMEM
-to avoid materializing the 8x-expanded bit arrays in HBM.
+THE FIVE LANES (ceph_tpu/parallel/service.py runs them, one row each in
+its LANES table; rados/ecutil.lane_for picks one per codec).  Every
+device program is plain XLA, correct on the CPU backend too.
 
-BIT-PLANAR RESIDENCY (measured, v5e, k=8 m=3, 8 MiB batches, 256 encodes
-per timed dispatch, dispatch RTT subtracted; round-4/5 records, since
-deleted — ROADMAP's table keeps their numbers):
+  int8-plane pair — the matrix is an OPERAND of an int8 MXU matmul, so one
+  compiled program serves every matrix; w=4/8/16.  Serves the w=16/w=4
+  pools and is the CEPH_TPU_PACKEDBIT=0 reference layout.
+    packed    gf2_apply_bytes: unpack -> matmul -> pack, bytes in and out.
+    resident  gf2_encode_resident: the same, and the int8 planes
+              (data ‖ parity) come back to stay in HBM.
+    Measured v5e, k=8 m=3, 16 MiB batches (round 5): 86.9 GB/s against an
+    HBM band of 69..95 GB/s (8-11 HBM bytes moved per data byte, 761 GB/s
+    streaming) — the layout is saturated; the output PACK (8 int32
+    plane-shifts + adds per byte) is its dominant VPU stage.
 
-    packed-resident (unpack+matmul+pack per dispatch) .... 48.6 GB/s
-    bit-planar resident (matmul only per dispatch) ....... 76.3 GB/s
-    planar input, packed output .......................... 47.1 GB/s
+  packed-bit trio — the matrix is baked at trace time as a STATIC XOR
+  SCHEDULE over rows (XLA prunes the zero terms: 465 XOR terms at the
+  k=8 m=3 Vandermonde density against 1536 dense), one compiled program
+  per matrix behind the LRU below (the ErasureCodeIsaTableCache design at
+  compile scope: encode generators and per-decode-signature matrices
+  alike).  The production lanes: 126.2 GB/s on the same rig, 1.45x the
+  int8 planes, byte-exact.  A matrix-as-operand mask-AND-XOR over the
+  same words measured 92.6 GB/s and was refuted.
+    packedbit           apply_packedbit_fn: w=8 byte layout; bytes ->
+                        u32 plane words (1 HBM byte per data byte) ->
+                        schedule -> bytes.
+    packedbit_resident  encode_packedbit_resident_fn: the same, and the
+                        u32 planes come back for the resident store
+                        (rados/pagestore.py) at 1/8th the int8 footprint.
+    packetrows          apply_packetrows_fn: packet layout; a packet IS a
+                        bit-row, so the layout stages are block
+                        transposes and no bit moves.
 
-(Those three used a full jnp.sum anti-DCE consumer; with the cheaper
-MXU-matvec consumer the bench records ~55 packed vs ~93 planar — same
-~1.6-1.7x conclusion, slightly higher absolutes.)
-
-Two pack-acceleration alternatives were tried and REFUTED (same rig):
-  * MXU pack (plane-major matrix rows so the output reshapes to
-    [8, M*B] and a pow2-weight dot packs it): 8.7 GB/s vs 49 — the
-    plane-major relayout plus a contraction dim of 8 starve the MXU
-    and the int32 plane materialization adds HBM traffic.
-  * uint8 shift-accumulate pack (narrower lanes than the int32 plane
-    sum): 48.5 vs 49 — XLA already narrows the existing pack.
-Planar residency (skip the output pack entirely) remains the only
-measured pack win.
-
-Keeping shards bit-planar in HBM across the pipeline — pack/unpack paid
-once at the host/wire boundary — is worth ~1.57x.  The middle row
-pinpoints WHERE: unpack fuses into the matmul almost for free, while the
-output PACK (8 int32 plane-shifts + adds per byte) is the dominant VPU
-stage; eliminating it is the entire win.
-
-ADOPTED (round 4): residency is now the production path —
-PlanarShardStore + BatchingQueue.submit_planar
-(ceph_tpu/parallel/service.py), ecutil.planar_encode_async/planar_rows/
-planar_object_bytes, and the OSD write/read/repair integration.  bench.py's
-headline is the resident pipeline (unpack once on entry, matmul per op,
-pack once on exit, both boundaries in the timed window): 83.9 GB/s vs
-52.8 packed-per-op on the same run (k=8 m=3, 16x1MiB stripe batches).
-
-The 8x HBM footprint DOES bite at large batches: a round-4 sweep of the
-resident pipeline found 64-stripe batches HBM-bound (4->89.5, 8->90.9,
-16->93.7, 32->89.9, 64->84.5 GB/s), so the batch default is 16 stripes
-(2 MiB of columns; BatchingQueue.max_pending_bytes=16 MiB matches).
-
-Pallas RE-TESTED under planar residency (round 4, v5e): the matmul-only
-kernel (pallas_gf2_matmul) reaches 24.7 GB/s vs XLA's 83.4 on the same
-resident loop — with pack/unpack gone the op is HBM-streaming-bound and
-XLA's pipelined fori_loop beats the per-call pallas grid by ~3.4x.  The
-kernel stays opt-in (CEPH_TPU_PALLAS=1); verdict recorded per VERDICT
-r03 #9.
-
-ROOFLINE OF THE INT8-PLANE LAYOUT (round 5, measured v5e, k=8 m=3 w=8,
-16 MiB batches, RTT-subtracted):
-
-    empirical HBM streaming bandwidth (chained adds) ...... 761 GB/s
-                                            (spec ~819; 93% achieved)
-    HBM bytes moved per DATA byte, int8-plane matmul loop:
-      read data planes    8     (k*w int8 rows / k bytes)
-      write parity planes 3     (m*w int8 rows / k bytes) — when the
-                                parity planes persist (residency);
-                                0 when the consumer fuses them in VMEM
-      => traffic 8–11 B/byte, roofline band 761/11..761/8
-                                          = 69.2 .. 95.1 GB/s data
-    measured int8-plane matmul loop ....................... 86.9 GB/s
-
-86.9 sits INSIDE the band — 91% of the fused-parity bound, 126% of the
-written-parity bound — i.e. the int8-plane layout is saturated; no
-constant-factor tuning of this layout buys another 2x.  (The r4
-headline's 76.3 used the heavier full-sum consumer; same conclusion.)
-
-PACKED-BIT PLANES EXPERIMENT (the traffic-cutting layout, r4 verdict
-ask; 1 bit/bit => 1.375 HBM B/byte, roofline 553 GB/s):
-  * matrix-as-OPERAND mask-AND-XOR over u32 words: 92.6 GB/s — only
-    1.07x.  The dense formulation does k*w AND+XOR per output row
-    regardless of matrix density (48 byte-ops per data byte): VPU-bound
-    at almost exactly the int8-MXU rate.  REFUTED as an operand-matrix
-    kernel.
-  * STATIC XOR SCHEDULE (matrix baked at trace time, XLA prunes zero
-    terms; 465 XOR terms at the Vandermonde density of 0.30 vs 1536
-    dense): **126.2 GB/s, 1.45x over int8-planes, byte-exact** vs the
-    oracle.  Still VPU/schedule-bound (23% of the packed roofline), so
-    a schedule-CSE pass (jerasure "smart scheduling" role) has more
-    headroom.
-ADOPTED (round 6): the packed-bit static-XOR-schedule lane IS the
-production lane for w=8 byte-layout codes.  Packed-bit residents (u32
-words) run end to end — BatchingQueue grew packedbit/packedbit_resident/
-packedbit_planes lanes mirroring the int8 packed/resident/planar trio,
-PlanarShardStore holds u32 residents (at 1/8th the int8-plane HBM
-footprint, so the same budget holds 8x the objects), and ecutil's
-encode/decode/resident plans plus the tpu plugin's _apply/_apply_rows
-seams route through the schedule cache.  Decode and recovery ride it
-too: per-decode-signature schedules compile behind the same LRU (the
-ErasureCodeIsaTableCache design at compile scope) — the signature set
-an OSD sees converges in a handful of erasure patterns, exactly the
-access pattern that cache was built for.  The int8-plane lanes remain
-as the w=16/w=4 path and the CEPH_TPU_PACKEDBIT=0 fallback: they serve
-every matrix without recompilation and the MXU does their reduction
-for free.
-
-SCHEDULE-CSE EXPERIMENT (jerasure "smart scheduling" role) — ADOPTED:
-xor_schedule_program's greedy pairwise pass factors the term pair
-co-occurring in the most output rows into a shared temp, repeatedly.
-Measured on the k=8 m=3 w=8 Vandermonde bit-matrix: 441 XOR ops naive
--> 230 with CSE (82 temps; -48%).  CPU wall time is IDENTICAL (12.0 vs
-12.1 ms on the 2 MiB-column batch): XLA fuses the whole schedule into
-one traffic-bound loop, so ALU count is invisible there — which is the
-point, the r5 measurement put the TPU lane at 23% of its roofline,
-VPU-ISSUE-bound, precisely where halving issued ops pays.  Default ON
-(CEPH_TPU_XOR_CSE=0 reverts); bench.py measures BOTH arms every run
-(ec_encode_packedbit_cse_GBps / ec_encode_packedbit_nocse_GBps) so the
-on-TPU verdict is re-recorded each round rather than frozen here.
-Risk noted: temps lengthen dependency chains; if a future TPU run
-shows nocse > cse, flip the env default and this paragraph.
-
-ROOFLINE RECONCILIATION (why r5 printed roofline_fraction_hi 1.13 —
-a physical impossibility): the r5 bench measured the HBM-bandwidth
-denominator (chained-adds loop) MINUTES before the headline matmul
-loop, on a shared chip; the bw probe caught a bad window (668 GB/s
-vs the 761 measured on the same rig in a clean window) while the
-headline loop caught a good one, so 94.8 / (668/8) = 1.13.  The r6 bench measures bw IMMEDIATELY before
-and after the headline loop (same run window) and takes the best of
-the two (timeit's min discipline, same as every other section), with
-one extra re-measure if the fraction still exceeds 1.0 — the
-denominator now shares the numerator's congestion conditions.  With
-the packed-bit lane as headline the margin is wide anyway: traffic is
-1 HBM byte per data byte when the parity planes are consumed fused
-(1.375 when they persist), so the roofline band is bw/1.375..bw and
-the measured 126.2 GB/s sits at ~23% of it — fraction well under 1.0.
+SCHEDULE CSE (jerasure "smart scheduling" role): xor_schedule_program's
+greedy pairwise pass factors the term pair co-occurring in the most
+output rows into a shared temp, repeatedly.  k=8 m=3 w=8 Vandermonde:
+441 XOR ops naive -> 230 (82 temps).  Every compiled schedule runs it;
+the `cse=` argument stays so tests/test_gf.py can hold it to the naive
+schedule.
 
 OBSERVABILITY — the `gf2_sched` counter set (COUNTER SCHEMA: name ->
 meaning -> kind), owned by this module because the schedule LRU is
@@ -160,13 +64,13 @@ carry it:
     compile        u64         schedules compiled (program build + trace)
     compile_s      longrunavg  seconds per schedule compile
     xor_ops_naive  u64         pre-CSE XOR op count, summed over compiles
-    xor_ops_final  u64         post-CSE (as-configured) XOR op count
+    xor_ops_final  u64         post-CSE XOR op count
     entries        u64         live LRU entries (gauge)
 
 xor_ops_final / xor_ops_naive is the realized CSE saving; compile_s
 times the Python program build + greedy CSE (the XLA trace happens
 lazily at first call).  `perf reset` (admin socket) zeroes the set so
-bench warmup/timed windows can isolate measurement intervals.
+warmup/timed windows can isolate measurement intervals.
 """
 
 from __future__ import annotations
@@ -198,29 +102,6 @@ SCHED_PERF = (
                      "XOR ops after the configured CSE pass")
     .add_u64("entries", "live compiled schedules (gauge)")
     .create_perf_counters())
-
-
-def pallas_enabled() -> bool:
-    """Whether dispatchers should route w=8 byte-layout ops to the Pallas
-    kernel.  Off by default — measured conclusion (v5e, k=8 m=3, 8 MiB
-    batches, 512 encodes per timed dispatch so dispatch RTT amortizes out):
-
-      old kernel (stack/reshape bit-plane unpack) .... 13 GB/s
-      tuned kernel (repeat + iota-shift unpack,
-        TILE_B 8192 -> 32768) ........................ ~40 GB/s
-      XLA fused unpack+matmul+pack ................... ~52 GB/s
-
-    The tuning round found the old kernel's cost was the [k,8,B] ->
-    [k*8,B] sublane-interleave relayout, not the matmul; replacing it
-    with elementwise repeat+shift tripled the kernel.  The remaining
-    ~1.3x gap is not HBM (both paths sit far below the bandwidth
-    roofline at ~1.4 bytes moved per data byte): the [m*8, k*8] x
-    [k*8, B] product leaves the 128x128 MXU ~90% idle, so the op is
-    VPU-bound on pack/unpack — exactly the stage XLA fuses across
-    surrounding ops while Pallas pays per-kernel boundaries.  XLA stays
-    the production path; set CEPH_TPU_PALLAS=1 to opt in when re-tuning
-    (a different generation or a wider m*k could flip the verdict)."""
-    return os.environ.get("CEPH_TPU_PALLAS", "") == "1"
 
 
 def bucket_columns(n: int, lo: int = 1024) -> int:
@@ -283,12 +164,10 @@ def pack_bits_bytes(bits: jnp.ndarray, w: int, out_rows: int) -> jnp.ndarray:
     return out.astype(jnp.uint8)
 
 
-# -- host-boundary converters for planar residency ---------------------------
+# -- host-boundary converters for int8-plane residents (w=16/w=4 pools) -----
 #
-# The EC service keeps shards BIT-PLANAR in HBM across encode -> decode ->
-# recovery (the measured ~1.6x win in the writeup above): these two jitted
-# entry points are the ONLY places bytes cross between packed host layout
-# and planar device layout.  Everything between them is gf2_matmul.
+# These two jitted entry points are the only places bytes cross between
+# the packed host layout and the int8-plane device layout.
 
 
 @functools.partial(jax.jit, static_argnames=("w",))
@@ -319,13 +198,9 @@ def gf2_encode_resident(mbits: jnp.ndarray, data: jnp.ndarray, w: int,
     return packed, jnp.concatenate([bits, pbits], axis=0)
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas",))
-def gf2_matmul(mbits: jnp.ndarray, bits: jnp.ndarray, use_pallas: bool = False) -> jnp.ndarray:
+@jax.jit
+def gf2_matmul(mbits: jnp.ndarray, bits: jnp.ndarray) -> jnp.ndarray:
     """(M @ bits) & 1 with int8 operands, int32 MXU accumulation."""
-    if use_pallas:
-        from ceph_tpu.ops.pallas_gf2 import pallas_gf2_matmul
-
-        return pallas_gf2_matmul(mbits, bits)
     acc = jax.lax.dot_general(
         mbits.astype(jnp.int8),
         bits.astype(jnp.int8),
@@ -335,9 +210,8 @@ def gf2_matmul(mbits: jnp.ndarray, bits: jnp.ndarray, use_pallas: bool = False) 
     return (acc & 1).astype(jnp.int8)
 
 
-# -- packed-bit static-schedule XOR: THE PRODUCTION LANE (measured 1.45x
-#    over int8 planes; see the writeup's packed-bit experiment and the
-#    lane-promotion note) ----------------------------------------------------
+# -- packed-bit static-schedule XOR: the production lanes (measured 1.45x
+#    over int8 planes; module docstring) -------------------------------------
 #
 # The resident EC pipeline keeps shards as u32-word bit-planes (1 bit/bit,
 # 1 HBM byte per data byte — 8x denser than the int8-plane layout) and
@@ -374,14 +248,7 @@ def packedbit_enabled() -> bool:
     return os.environ.get("CEPH_TPU_PACKEDBIT", "1") != "0"
 
 
-def xor_cse_enabled() -> bool:
-    """Whether XOR schedules run the common-subexpression pass (the
-    jerasure "smart scheduling" role; see the CSE writeup above).
-    Default ON; CEPH_TPU_XOR_CSE=0 pins the naive per-row schedules."""
-    return os.environ.get("CEPH_TPU_XOR_CSE", "1") != "0"
-
-
-def xor_schedule_program(bitmatrix: np.ndarray, cse: "bool | None" = None):
+def xor_schedule_program(bitmatrix: np.ndarray, cse: bool = True):
     """Compile a [R, C] GF(2) bit-matrix into a straight-line XOR program:
     returns (ops, outs, n_xors) where `ops` is a list of (a, b) pairs —
     op i computes temp C+i = term_a ^ term_b — and `outs[r]` is the term
@@ -394,8 +261,6 @@ def xor_schedule_program(bitmatrix: np.ndarray, cse: "bool | None" = None):
     per-operation SIMD XOR regions, this schedules the whole matrix as a
     DAG that XLA then fuses.  Deterministic (ties break to the smallest
     pair), so the compiled-schedule cache key stays stable."""
-    if cse is None:
-        cse = xor_cse_enabled()
     bm = np.asarray(bitmatrix, dtype=np.uint8)
     R, C = bm.shape
     sets = [set(np.nonzero(bm[r])[0].tolist()) for r in range(R)]
@@ -505,13 +370,11 @@ def _sched_cache_put(key, fn):
         SCHED_PERF.inc("evict", evicted)
 
 
-def _compiled_schedule(tag: str, bitmatrix, build, cse=None):
+def _compiled_schedule(tag: str, bitmatrix, build, cse: bool = True):
     """LRU-cached compiled function per (tag, matrix bytes, cse): the
     ErasureCodeIsaTableCache design at compile scope.  Thread-safe —
     the batching worker, OSD event loops, and tests all land here."""
     bm = np.asarray(bitmatrix, dtype=np.uint8)
-    if cse is None:
-        cse = xor_cse_enabled()
     key = (tag, bm.shape, bm.tobytes(), cse)
     fn = _sched_cache_get(key)
     if fn is None:
@@ -529,7 +392,8 @@ def _compiled_schedule(tag: str, bitmatrix, build, cse=None):
     return fn
 
 
-def gf2_xor_packed(bitmatrix: np.ndarray, planes, cse=None) -> "jnp.ndarray":
+def gf2_xor_packed(bitmatrix: np.ndarray, planes,
+                   cse: bool = True) -> "jnp.ndarray":
     """[R, C] GF(2) bit-matrix applied to C rows by a static XOR schedule
     (matrix baked at trace time; XLA prunes zero terms — 465 XOR terms
     instead of 1536 dense AND+XORs at the k=8 m=3 Vandermonde density,
@@ -542,7 +406,7 @@ def gf2_xor_packed(bitmatrix: np.ndarray, planes, cse=None) -> "jnp.ndarray":
     return xor_packed_fn(bitmatrix, cse=cse)(planes)
 
 
-def xor_packed_fn(bitmatrix: np.ndarray, cse=None):
+def xor_packed_fn(bitmatrix: np.ndarray, cse: bool = True):
     """The compiled (LRU-cached) jitted schedule behind gf2_xor_packed —
     split out so an AOT compile can lower it at a shape without data."""
     C = np.asarray(bitmatrix).shape[1]
@@ -731,47 +595,14 @@ def unpack_bitplanes_u32(planes: np.ndarray, w: int, out_rows: int,
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("w", "out_rows", "use_pallas"))
+@functools.partial(jax.jit, static_argnames=("w", "out_rows"))
 def gf2_apply_bytes(
     mbits: jnp.ndarray,
     data: jnp.ndarray,
     w: int,
     out_rows: int,
-    use_pallas: bool = False,
 ) -> jnp.ndarray:
     """Byte layout: apply a [out_rows*w, n*w] bit-matrix to [n, B] chunks."""
-    if use_pallas and w == 8:
-        from ceph_tpu.ops.pallas_gf2 import pallas_apply_bytes_w8
-
-        return pallas_apply_bytes_w8(mbits, data, out_rows)
     bits = unpack_bits_bytes(data, w)
     out = gf2_matmul(mbits, bits)
     return pack_bits_bytes(out, w, out_rows)
-
-
-@functools.partial(jax.jit, static_argnames=("w", "packetsize", "out_rows", "use_pallas"))
-def gf2_apply_packets(
-    mbits: jnp.ndarray,
-    data: jnp.ndarray,
-    w: int,
-    packetsize: int,
-    out_rows: int,
-    use_pallas: bool = False,
-) -> jnp.ndarray:
-    """Packet layout: [n, chunk] chunks, chunk = nb*w*packetsize, apply
-    [out_rows*w, n*w] bit-matrix over packet rows."""
-    n, chunk = data.shape
-    wp = w * packetsize
-    nb = chunk // wp
-    rows = data.reshape(n, nb, w, packetsize).transpose(0, 2, 1, 3).reshape(n * w, nb * packetsize)
-    # bytes -> bit columns so the combine is an MXU matmul
-    shifts = jnp.arange(8, dtype=jnp.uint8)
-    bits = ((rows[:, :, None] >> shifts[None, None, :]) & 1).reshape(n * w, nb * packetsize * 8)
-    out = gf2_matmul(mbits, bits, use_pallas=use_pallas)
-    out = out.reshape(out_rows * w, nb * packetsize, 8).astype(jnp.int32)
-    packed = jnp.sum(out << jnp.arange(8, dtype=jnp.int32)[None, None, :], axis=-1).astype(jnp.uint8)
-    return (
-        packed.reshape(out_rows, w, nb, packetsize)
-        .transpose(0, 2, 1, 3)
-        .reshape(out_rows, chunk)
-    )

@@ -6,8 +6,8 @@ ECUtil::encode (reference src/osd/ECUtil.cc:123-160) and per 1 MiB buffer in
 the benchmark; a TPU dispatch has fixed launch latency, so the >=10x target
 "lives or dies on the batching queue" (SURVEY.md §7 hard part 2).  This
 queue aggregates encode/decode requests from many objects/ops, concatenates
-them column-wise into one [rows, sum(B)] buffer per (matrix, layout) group,
-runs ONE bit-plane matmul, and fans completions back out — the same
+them column-wise into one [rows, sum(B)] buffer per (matrix, lane) group,
+runs ONE device program, and fans completions back out — the same
 submit -> aggregate -> dispatch -> completion-fan-out pipeline ECBackend's
 write path drives (submit_transaction -> ... -> try_reads_to_commit,
 ECBackend.cc:1525->1989).
@@ -15,41 +15,34 @@ ECBackend.cc:1525->1989).
 Threading model: submit() is non-blocking and returns a Future; a worker
 thread flushes when pending bytes cross `max_pending_bytes` or `max_delay`
 elapses, whichever first.  flush() forces a synchronous drain (used by
-tests and by the benchmark's timed sections).
+tests and by timed sections).
 
-BIT-PLANAR RESIDENCY (the measured ~1.6x win, ceph_tpu/ops/gf2.py
-writeup): `submit_planar` dispatches over shards that already live in HBM
-as int8 bit-planes — matmul only, no unpack/pack — and resolves to planar
-device buffers, so encode -> decode -> recovery chain on-device.
-`PlanarShardStore` is the residency manager: an LRU-bounded HBM cache of
-planar shard rows where bytes pay the pack/unpack boundary exactly once,
-when they enter or leave the device tier (the reference's analog is the
-buffer staying in L2/registers across ECUtil::encode's per-stripe loop,
-reference src/osd/ECUtil.cc:123-160; on a TPU the "stay resident" scope
-is HBM across whole pipeline stages).
+LANES: a request names its lane (`kind`), and LANES below holds one row
+per lane — the device program, its numpy mirror, the fan-out shape, the
+column alignment and the submit-time check.  Every lane takes packed
+[n, B] uint8 rows; they differ in the layout stages around the GF(2)
+product and in what comes back (ceph_tpu/ops/gf2.py has the kernels and
+their measurements; rados/ecutil.lane_for picks the lane for a codec):
 
-PACKED-BIT PRODUCTION LANE (the measured 1.45x over int8 planes,
-ceph_tpu/ops/gf2.py lane-promotion writeup): for w=8 byte-layout codes
-the resident trio has a u32-word mirror — `submit_packedbit` (bytes in,
-bytes out), `submit_packedbit_resident` (bytes in, parity bytes + u32
-planes out), `submit_packedbit_planes` (resident planes in/out) — each
-dispatch running the matrix as a static XOR schedule compiled per matrix
-(encode generators and decode signatures alike) behind the gf2 LRU.
-Residents store at 1 HBM byte per data byte instead of 8, so the same
-store budget holds 8x the objects.
+    packed              int8 bit-planes, matrix as a matmul operand (any
+                        matrix, no recompile; w=4/8/16): bytes out
+    resident            the same, and the int8 planes (data ‖ parity)
+                        come back as a device buffer for the resident store
+    packedbit           w=8 byte layout as u32 plane words under a static
+                        XOR schedule compiled per matrix: bytes out
+    packedbit_resident  the same, and the u32 planes come back (1 HBM
+                        byte per data byte) for the resident store
+                        (rados/pagestore.py)
+    packetrows          packet-layout codes (cauchy_orig/good, liberation,
+                        blaum_roth, liber8tion): a packet IS a bit-row, so
+                        the layout stages are block transposes
+                        ([n, nb, w, p] <-> [n*w, nb*p]) around the same
+                        schedule; whole w*packetsize blocks, the width
+                        buckets to a power of two of blocks; no residents
 
-PACKET-LAYOUT LANE (`submit_packetrows`, lane name "packetrows"): the
-bit-matrix codes (cauchy_orig/good, liberation, blaum_roth, liber8tion)
-lay a chunk out as w*packetsize-byte blocks of w packets, and a packet IS
-a bit-row.  Their lane is the packed-bit lane with another pair of layout
-stages, chosen by the codec's bit_layout, w and packetsize: the same
-launch/complete/mirror, the same static XOR schedule behind the same LRU,
-block transposes ([n, nb, w, p] <-> [n*w, nb*p]) on the device where the
-byte layout has bit transposes.  Requests coalesce by columns (a chunk is
-whole blocks); the width buckets to a power of two of blocks.  Encode
-generators and the inverted bit-matrices of decode signatures ride it
-(ecutil's plans), so no served op of such a pool dispatches from the
-event loop.  It keeps no residents.
+Encode generators and the inverted bit-matrices of decode signatures ride
+the same lanes (ecutil's plans), so no served op dispatches from the
+event loop.
 
 DEVICE-DISPATCH CIRCUIT BREAKER (the robustness layer): every lane owns a
 breaker with three states.  CLOSED: dispatches go to the device; one that
@@ -68,9 +61,9 @@ re-opens it.  ``inject_dispatch_delay`` (osd_debug_inject_dispatch_delay
 watchdog.  Counted in `ec_tpu`: breaker_trip / breaker_probe /
 breaker_recover / breaker_fallback + the breaker_open_lanes gauge.
 
-OBSERVABILITY (the `ec_tpu` + `planar_store` counter sets): the queue owns
-a PerfCounters set — name -> meaning -> kind in _build_ec_tpu_perf — with
-per-lane submit/byte counters (submit_<lane>/bytes_<lane>, u64), queue-wait
+OBSERVABILITY (the `ec_tpu` counter set): the queue owns a PerfCounters
+set — name -> meaning -> kind in _build_ec_tpu_perf — with per-lane
+submit/byte counters (submit_<lane>/bytes_<lane>, u64), queue-wait
 and device-dispatch longrunavg latencies (queue_wait, dispatch_dev), a
 coalesced-group-size histogram (group_size), and flush-cause counters
 (flush_bytes/flush_delay/flush_forced, u64).  Daemons add the set to their
@@ -79,22 +72,19 @@ backs the `dump_ec_batch_timeline` asok command with the last 128
 dispatches (lane, group size, bytes, wait, device seconds).  Trace spans
 ride submissions: a `span=` parent (the OSD's `ec write` trace) gets
 submit/coalesce/fan-out events plus a per-dispatch child span tagged with
-lane/group_size/bytes.  PlanarShardStore mirrors its residency stats into
-a `planar_store` set: admit/hit/miss/evict (u64), resident_bytes + entries
-(gauges), and pack_s/unpack_s longrunavg — the host<->device boundary
-seconds paid at admit()/read().
+lane/group_size/bytes.
 """
 
 from __future__ import annotations
 
 import collections
+import functools
 import logging
 import threading
 import time
-from collections import OrderedDict
 from concurrent.futures import Future, InvalidStateError
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -103,17 +93,10 @@ from ceph_tpu.common.perf_counters import PerfCounters, PerfCountersBuilder
 
 log = logging.getLogger("ceph_tpu.ec.batch")
 
-#: the dispatch lanes, in promotion order (int8 trio, packed-bit trio, and
-#: the packed-bit lane's packet-layout form)
-LANES = ("packed", "planar", "resident",
-         "packedbit", "packedbit_resident", "packedbit_planes",
-         "packetrows")
-
-
 def _build_ec_tpu_perf() -> PerfCounters:
     """The `ec_tpu` counter set (COUNTER SCHEMA below; dumped via `perf
     dump` on any daemon sharing the process queue, exported by the mgr
-    prometheus module, snapshotted into BENCH records):
+    prometheus module):
 
       submit               u64         requests accepted, all lanes
       submit_<lane>        u64         requests accepted per lane
@@ -261,41 +244,130 @@ def _np_xor_rows(mb: np.ndarray, rows: np.ndarray) -> np.ndarray:
     return out
 
 
+def _mirror_bytes(mb, data, w, out_rows, packetsize=0):
+    bits = _np_unpack_bits(data, w)
+    return _np_pack_bits(_np_matmul_gf2(mb, bits), w, out_rows)
+
+
+def _mirror_resident(mb, data, w, out_rows, packetsize=0, rows=lambda b: b):
+    bits = _np_unpack_bits(data, w)
+    pbits = _np_matmul_gf2(mb, bits)
+    return (_np_pack_bits(pbits, w, out_rows),
+            np.concatenate([rows(bits), rows(pbits)], axis=0))
+
+
+def _mirror_packetrows(mb, data, w, out_rows, packetsize):
+    # mirror of ops/gf2.apply_packetrows_fn: block transpose in, XOR of
+    # whole packets, block transpose out
+    n, cols = data.shape
+    nb = cols // (w * packetsize)
+    rows = (data.reshape(n, nb, w, packetsize).transpose(0, 2, 1, 3)
+            .reshape(n * w, nb * packetsize))
+    return (_np_xor_rows(mb, rows)
+            .reshape(out_rows, w, nb, packetsize).transpose(0, 2, 1, 3)
+            .reshape(out_rows, cols))
+
+
+# -- the lanes' device programs: (group, staged batch) -> device result ------
+# ops/gf2.py imports jax; this module stays importable without it.
+
+
+def _device_packed(g, batch):
+    from ceph_tpu.ops.gf2 import gf2_apply_bytes
+
+    return gf2_apply_bytes(g.mbits, batch, g.w, g.out_rows)
+
+
+def _device_resident(g, batch):
+    from ceph_tpu.ops.gf2 import gf2_encode_resident
+
+    return gf2_encode_resident(g.mbits, batch, g.w, g.out_rows)
+
+
+def _device_packedbit(g, batch):
+    from ceph_tpu.ops.gf2 import gf2_apply_packedbit
+
+    return gf2_apply_packedbit(g.mbits, batch)
+
+
+def _device_packedbit_resident(g, batch):
+    from ceph_tpu.ops.gf2 import gf2_encode_packedbit_resident
+
+    return gf2_encode_packedbit_resident(g.mbits, batch)
+
+
+def _device_packetrows(g, batch):
+    from ceph_tpu.ops.gf2 import gf2_apply_packetrows
+
+    return gf2_apply_packetrows(g.mbits, batch, g.w, g.packetsize)
+
+
+# -- submit-time checks: a request that cannot run is refused before it can
+#    coalesce, or its launch would fail every innocent request grouped with it
+
+
+def _check_packedbit(regions, w, packetsize=0):
+    if w != 8:
+        raise ValueError(f"the packed-bit lanes are the w=8 byte layout, "
+                         f"got w={w}")
+
+
+def _check_packedbit_resident(regions, w, packetsize=0):
+    _check_packedbit(regions, w, packetsize)
+    if regions.shape[1] % 32:
+        # the plane fan-out slices whole u32 words
+        raise ValueError("packedbit_resident requests must be 32-byte-column "
+                         f"aligned, got width {regions.shape[1]}")
+
+
+def _check_packetrows(regions, w, packetsize=0):
+    if packetsize < 1 or regions.shape[1] % (w * packetsize):
+        raise ValueError(
+            f"packetrows requests are whole w*packetsize={w}*{packetsize}"
+            f"-byte blocks, got width {regions.shape[1]}")
+
+
+class Lane(NamedTuple):
+    """All the queue knows about one lane.  A request is packed [n, B]
+    uint8 rows under a [out_rows*w, n*w] GF(2) bit-matrix."""
+
+    #: the lane's one fused program over a staged batch (async: returns a
+    #: device handle)
+    device: Callable
+    #: numpy mirror: (mbits u8, rows u8, w, out_rows, packetsize) -> exactly
+    #: what the device fan-out resolves a request's future with
+    mirror: Callable
+    #: fan-out shape: [out_rows, B] bytes, or (bytes, resident bit-rows
+    #: [(n+out_rows)*w, ...] that stay on the device)
+    resident: bool = False
+    #: column unit a staged batch pads to; None = the group's w*packetsize
+    align: Optional[int] = 1
+    check: Optional[Callable] = None
+
+
+LANES: Dict[str, Lane] = {
+    "packed": Lane(_device_packed, _mirror_bytes),
+    "resident": Lane(_device_resident, _mirror_resident, resident=True),
+    # pow2 bucketing of 32-column units keeps whole u32 words per plane row
+    "packedbit": Lane(_device_packedbit, _mirror_bytes, align=32,
+                      check=_check_packedbit),
+    "packedbit_resident": Lane(
+        _device_packedbit_resident,
+        functools.partial(_mirror_resident, rows=_np_words),
+        resident=True, align=32, check=_check_packedbit_resident),
+    "packetrows": Lane(_device_packetrows, _mirror_packetrows, align=None,
+                       check=_check_packetrows),
+}
+
+
 def _cpu_apply_request(kind: str, mbits: np.ndarray, regions, w: int,
                        out_rows: int, packetsize: int = 0):
     """Serve ONE lane request host-side; returns exactly what the device
     lane's fan-out would have resolved the request's future with (device
     buffers become numpy arrays — every consumer accepts both)."""
-    mb = np.asarray(mbits, dtype=np.uint8)
-    if kind == "packetrows":
-        # mirror of ops/gf2.apply_packetrows_fn: block transpose in, XOR
-        # of whole packets, block transpose out
-        data = np.asarray(regions, dtype=np.uint8)
-        n, cols = data.shape
-        nb = cols // (w * packetsize)
-        rows = (data.reshape(n, nb, w, packetsize).transpose(0, 2, 1, 3)
-                .reshape(n * w, nb * packetsize))
-        return (_np_xor_rows(mb, rows)
-                .reshape(out_rows, w, nb, packetsize).transpose(0, 2, 1, 3)
-                .reshape(out_rows, cols))
-    if kind in ("packed", "packedbit"):
-        bits = _np_unpack_bits(np.asarray(regions, dtype=np.uint8), w)
-        return _np_pack_bits(_np_matmul_gf2(mb, bits), w, out_rows)
-    if kind == "planar":
-        return _np_matmul_gf2(mb, np.asarray(regions))
-    if kind == "resident":
-        bits = _np_unpack_bits(np.asarray(regions, dtype=np.uint8), w)
-        pbits = _np_matmul_gf2(mb, bits)
-        return (_np_pack_bits(pbits, w, out_rows),
-                np.concatenate([bits, pbits], axis=0))
-    if kind == "packedbit_resident":
-        bits = _np_unpack_bits(np.asarray(regions, dtype=np.uint8), 8)
-        pbits = _np_matmul_gf2(mb, bits)
-        return (_np_pack_bits(pbits, 8, out_rows),
-                np.concatenate([_np_words(bits), _np_words(pbits)], axis=0))
-    if kind == "packedbit_planes":
-        return _np_xor_rows(mb, np.asarray(regions))
-    raise ValueError(f"unknown lane kind {kind!r}")
+    return LANES[kind].mirror(np.asarray(mbits, dtype=np.uint8),
+                              np.asarray(regions, dtype=np.uint8),
+                              w, out_rows, packetsize)
 
 
 class _LaneBreaker:
@@ -331,14 +403,7 @@ class _Group:
     mbits: np.ndarray
     w: int
     out_rows: int
-    # dispatch lane: "packed" (unpack+matmul+pack fused per dispatch),
-    # "planar" (matmul-only over resident int8 bit-planes), "resident"
-    # (packed in -> packed parity + planar rows out, the write path);
-    # plus the packed-bit production trio mirroring them over u32 plane
-    # words + static XOR schedules (ceph_tpu/ops/gf2.py lane promotion):
-    # "packedbit", "packedbit_planes", "packedbit_resident"; and
-    # "packetrows", the packed-bit lane for PACKET-layout codes (its
-    # layout stages are block transposes of `packetsize`-byte packets)
+    # the lane (a key of LANES) and, on the packet-layout lane, its packet size
     kind: str = "packed"
     packetsize: int = 0  # packet layout only
     requests: List[_Request] = field(default_factory=list)
@@ -360,12 +425,11 @@ class _Launched:
 class BatchingQueue:
     def __init__(
         self,
-        # 16 MiB/dispatch: the measured HBM sweet spot for the planar
-        # pipeline (bench.py r4 sweep — the 8x bit-plane expansion makes
-        # 64 MiB batches HBM-bound on v5e; 2 MiB of columns at k=8 wins)
+        # 16 MiB/dispatch: the measured HBM sweet spot of the int8-plane
+        # lanes (round-4 sweep on v5e — their 8x bit-plane expansion makes
+        # 64 MiB batches HBM-bound; 2 MiB of columns at k=8 wins)
         max_pending_bytes: int = 16 << 20,
         max_delay: Optional[float] = None,
-        use_pallas: Optional[bool] = None,
         mesh=None,
     ):
         import os as _os
@@ -383,14 +447,13 @@ class BatchingQueue:
             except ValueError:
                 max_delay = 0.002
         self.max_delay = max_delay
-        self._use_pallas = use_pallas
         # device-mesh execution (ceph_tpu/parallel/mesh.py): when a mesh
         # is attached (or auto-engages on a multi-chip backend), every
         # dispatch lane lays its batch out across the mesh's column axis
         # — the same compiled ops run SPMD over all devices, collectives
         # inserted by XLA where a consumer needs them.  mesh=None means
-        # auto-detect; mesh=False pins the queue single-device (bench
-        # arms and single-device comparisons that must not auto-engage).
+        # auto-detect; mesh=False pins the queue single-device
+        # (single-device comparisons that must not auto-engage).
         if mesh is None:
             from ceph_tpu.parallel.mesh import shared_mesh
 
@@ -405,7 +468,7 @@ class BatchingQueue:
         # PerfCountersCollection so `perf dump` carries the full breakdown.
         self.perf = _build_ec_tpu_perf()
         # optional per-daemon Tracer: dispatch spans with no submitter
-        # parent (e.g. bench traffic) root here; the OSD attaches its ctx
+        # parent (e.g. repair traffic) root here; the OSD attaches its ctx
         # tracer so spans land in its dump_traces ring
         self.tracer = None
         # bounded ring of recent dispatches for `dump_ec_batch_timeline`
@@ -487,145 +550,59 @@ class BatchingQueue:
 
     # -- client side ---------------------------------------------------------
 
-    def submit(
-        self, mbits: np.ndarray, regions: np.ndarray, w: int, out_rows: int,
-        span=None,
-    ) -> "Future[np.ndarray]":
-        """Queue (mbits @ regions) over the byte layout; resolves to the
-        [out_rows, B] parity/reconstruction buffer."""
-        return self._submit(mbits, regions, w, out_rows, "packed", span)
-
-    def submit_planar(
-        self, mbits: np.ndarray, bits, w: int, out_rows: int, span=None
-    ) -> "Future[object]":
-        """Queue (mbits @ bits) over ALREADY-PLANAR device bit-planes
-        ([rows*w, Bcols] int8); resolves to the [out_rows*w, Bcols] planar
-        device buffer — no pack, the result stays HBM-resident for the
-        next pipeline stage."""
-        return self._submit(mbits, bits, w, out_rows, "planar", span)
-
-    def submit_resident(
-        self, mbits: np.ndarray, rows: np.ndarray, w: int, out_rows: int,
-        span=None,
-    ) -> "Future[object]":
-        """The residency WRITE path: packed [n, B] uint8 rows in, ONE
-        fused batched device call (unpack + matmul + parity pack), and
-        the future resolves to (packed_parity np [out_rows, B],
-        all_bits planar [(n+out_rows)*w, Bc]) — parity bytes for
-        persistence, planar rows to keep HBM-resident.  Submission is
-        non-blocking (no device work on the caller's thread), so
-        concurrent ops coalesce exactly like the packed lane."""
-        return self._submit(mbits, rows, w, out_rows, "resident", span)
-
-    # -- packed-bit lanes (the production w=8 trio, ceph_tpu/ops/gf2.py
-    #    lane-promotion writeup: u32-word bit-planes + static XOR
-    #    schedules compiled per matrix behind the LRU) ----------------------
-
-    def submit_packedbit(
-        self, mbits: np.ndarray, regions: np.ndarray, w: int, out_rows: int,
-        span=None,
-    ) -> "Future[np.ndarray]":
-        """Queue a [out_rows*8, n*8] GF(2) bit-matrix over packed [n, B]
-        uint8 rows through the packed-bit XOR-schedule lane (one fused
-        unpack -> u32 words -> schedule -> byte pack device call per
-        coalesced group); resolves to the [out_rows, B] parity or
-        reconstruction buffer.  Encode generators AND per-decode-
-        signature matrices both land here — each matrix is its own
-        dispatch group and its own LRU-cached compiled schedule."""
-        assert w == 8, "packed-bit lane is the w=8 byte-layout lane"
-        return self._submit(mbits, regions, w, out_rows, "packedbit", span)
-
-    def submit_packedbit_resident(
-        self, mbits: np.ndarray, rows: np.ndarray, w: int, out_rows: int,
-        span=None,
-    ) -> "Future[object]":
-        """Packed-bit residency WRITE path: packed [n, B] uint8 rows in
-        (B % 32 == 0), resolves to (packed_parity np [out_rows, B],
-        all_planes u32 [(n+out_rows)*8, B//32]) — parity bytes for
-        persistence, u32 plane words to stay HBM-resident at 1/8th the
-        int8-plane footprint."""
-        assert w == 8, "packed-bit lane is the w=8 byte-layout lane"
-        if rows.shape[1] % 32:
-            # reject at SUBMISSION: a misaligned request that reached
-            # launch would fail every innocent request coalesced with it
-            raise ValueError(
-                "packedbit_resident requests must be 32-byte-column "
-                f"aligned, got width {rows.shape[1]}")
-        return self._submit(mbits, rows, w, out_rows, "packedbit_resident",
-                            span)
-
-    def submit_packedbit_planes(
-        self, mbits: np.ndarray, planes, w: int, out_rows: int, span=None
-    ) -> "Future[object]":
-        """Queue an XOR schedule over ALREADY-RESIDENT u32 plane words
-        ([rows*8, Wc] uint32); resolves to the [out_rows*8, Wc] device
-        buffer — no pack, the result stays resident for the next stage
-        (the packed-bit mirror of submit_planar)."""
-        assert w == 8, "packed-bit lane is the w=8 byte-layout lane"
-        return self._submit(mbits, planes, w, out_rows, "packedbit_planes",
-                            span)
-
-    def submit_packetrows(
-        self, mbits: np.ndarray, regions: np.ndarray, w: int,
-        packetsize: int, out_rows: int, span=None,
-    ) -> "Future[np.ndarray]":
-        """The packed-bit lane for PACKET-layout codes (cauchy_orig/good,
-        liberation, blaum_roth, liber8tion): queue a [out_rows*w, n*w]
-        GF(2) bit-matrix over [n, B] uint8 chunks, B a whole number of
-        w*packetsize-byte blocks.  One fused device call per coalesced
-        group — block transpose to packet rows, the same static XOR
-        schedule, block transpose back (ops/gf2.apply_packetrows_fn) —
-        resolving to the [out_rows, B] parity or reconstruction buffer.
-        Requests coalesce by columns like the byte lanes': a chunk is
-        whole blocks, so the concatenation is a valid chunk set."""
-        if packetsize < 1 or regions.shape[1] % (w * packetsize):
-            # reject at SUBMISSION, as submit_packedbit_resident does
-            raise ValueError(
-                f"packetrows requests are whole w*packetsize={w}*"
-                f"{packetsize}-byte blocks, got width {regions.shape[1]}")
-        return self._submit(mbits, regions, w, out_rows, "packetrows", span,
-                            packetsize=packetsize)
+    def submit(self, mbits: np.ndarray, regions: np.ndarray, w: int,
+               out_rows: int, kind: str = "packed", packetsize: int = 0,
+               *, span=None) -> Future:
+        """Queue ONE lane request: the [out_rows*w, n*w] bit-matrix `mbits`
+        over packed [n, B] uint8 `regions` on lane `kind` (LANES).  The
+        future resolves to the [out_rows, B] parity/reconstruction bytes,
+        or on a resident lane to (those bytes, the data ‖ parity bit-rows
+        as a device buffer).  Non-blocking: no device work on the caller's
+        thread, so concurrent ops coalesce.  Raises ValueError for a
+        request its lane cannot run."""
+        return self.submit_group(
+            [(mbits, regions, w, out_rows, kind, packetsize)], span=span)[0]
 
     @tracing.sectioned("ecplan", "queue_submit")
     def submit_group(self, items, span=None) -> List[Future]:
         """Group-aware submit (the messenger/recovery whole-stripe-group
-        handoff seam): queue a LIST of lane submissions — each item is
-        (mbits, regions, w, out_rows, kind), plus the packetsize on the
-        packet-layout lane — under ONE lock acquisition
-        and ONE worker wakeup, so a coalesced group of objects reaches
-        the EC tier as a single buffer-list submission instead of N
-        contended submits.  Items sharing a dispatch signature land in
-        the same _Group exactly as per-item submits would; returns the
-        per-item futures, index-aligned."""
-        futs: List[Future] = []
-        sizes: List[int] = []
+        handoff seam): queue a LIST of lane requests — each item is
+        (mbits, regions, w, out_rows, kind[, packetsize]), submit()'s
+        arguments — under ONE lock acquisition and ONE worker wakeup, so
+        a coalesced group of objects reaches the EC tier as a single
+        buffer-list submission instead of N contended submits.  Items
+        sharing a dispatch signature land in the same _Group; returns the
+        per-item futures, index-aligned.  A refused item (ValueError)
+        refuses the call before anything is queued."""
+        for _, regions, w, _, kind, *packetsize in items:
+            check = LANES[kind].check
+            if check is not None:
+                check(regions, w, *packetsize)
+        futs: List[Future] = [Future() for _ in items]
         now = time.monotonic()
         if span is not None:
-            span.event(f"ec submit group n={len(items)}")
+            span.event(f"ec submit lane={items[0][4]}" if len(items) == 1
+                       else f"ec submit group n={len(items)}")
         with self._cv:
             if self._stop:
                 raise RuntimeError("BatchingQueue is closed")
-            for mbits, regions, w, out_rows, kind, *packetsize in items:
-                fut: Future = Future()
-                futs.append(fut)
-                sizes.append(self._queue_locked(
-                    mbits, regions, w, out_rows, kind, fut, now, span,
-                    *packetsize))
+            for item, fut in zip(items, futs):
+                self._queue_locked(*item, fut=fut, now=now, span=span)
             if items:
                 self._cv.notify()
-        for (_, _, _, _, kind, *_), nbytes in zip(items, sizes):
+        for _, regions, _, _, kind, *_ in items:
             self.perf.inc("submit")
             self.perf.inc(f"submit_{kind}")
-            self.perf.inc(f"bytes_{kind}", nbytes)
+            self.perf.inc(f"bytes_{kind}", regions.nbytes)
         if len(items) > 1:
             self.perf.inc("submit_group")
             self.perf.hinc("group_submit_size", len(items))
         return futs
 
-    def _queue_locked(self, mbits, regions, w, out_rows, kind, fut,
-                      now, span, packetsize: int = 0) -> int:
+    def _queue_locked(self, mbits, regions, w, out_rows, kind,
+                      packetsize: int = 0, *, fut, now, span) -> None:
         """Insert one request into its dispatch group (caller holds the
-        lock).  Returns the packed-equivalent byte size counted."""
+        lock)."""
         # the full dispatch signature: identical matrix BYTES under a
         # different w, packet size or output arity is a different
         # computation; the lanes never share a dispatch (different layouts)
@@ -636,33 +613,10 @@ class BatchingQueue:
                 mbits=mbits, w=w, out_rows=out_rows, kind=kind,
                 packetsize=packetsize)
         group.requests.append(_Request(regions, fut, now, span))
-        # planar bit-plane submissions are 8x-expanded int8: count
-        # their packed-equivalent size or the lane would flush at 1/8
-        # the measured batch sweet spot
-        nbytes = self._req_bytes(kind, mbits, regions)
-        group.pending_bytes += nbytes
-        self._pending += nbytes
+        group.pending_bytes += regions.nbytes
+        self._pending += regions.nbytes
         if self._oldest is None:
             self._oldest = now
-        return nbytes
-
-    @tracing.sectioned("ecplan", "queue_submit")
-    def _submit(self, mbits, regions, w, out_rows, kind,
-                span=None, packetsize: int = 0) -> Future:
-        fut: Future = Future()
-        now = time.monotonic()
-        if span is not None:
-            span.event(f"ec submit lane={kind}")
-        with self._cv:
-            if self._stop:
-                raise RuntimeError("BatchingQueue is closed")
-            nbytes = self._queue_locked(mbits, regions, w, out_rows, kind,
-                                        fut, now, span, packetsize)
-            self._cv.notify()
-        self.perf.inc("submit")
-        self.perf.inc(f"submit_{kind}")
-        self.perf.inc(f"bytes_{kind}", nbytes)
-        return fut
 
     def flush(self) -> None:
         """Synchronously drain everything queued right now."""
@@ -680,17 +634,6 @@ class BatchingQueue:
         self.flush()
 
     # -- worker side ---------------------------------------------------------
-
-    @staticmethod
-    def _req_bytes(kind: str, mbits: np.ndarray, regions) -> int:
-        # flush thresholds are tuned in PACKED bytes (see _submit)
-        if kind == "planar":
-            return regions.shape[1] * mbits.shape[1] // 8
-        if kind == "packedbit_planes":
-            # u32 plane words carry exactly 1 bit/bit: total plane bytes
-            # == packed bytes (the layout's whole point)
-            return int(regions.shape[0]) * int(regions.shape[1]) * 4
-        return regions.nbytes
 
     @tracing.sectioned("queue", "group_build")
     def _take_locked(self, budget: Optional[int] = None) -> List[_Group]:
@@ -729,7 +672,7 @@ class BatchingQueue:
             while g.requests and (taken_bytes < budget
                                   or not part.requests):
                 req = g.requests.pop(0)
-                n = self._req_bytes(g.kind, g.mbits, req.regions)
+                n = req.regions.nbytes
                 part.requests.append(req)
                 part.pending_bytes += n
                 g.pending_bytes -= n
@@ -937,18 +880,7 @@ class BatchingQueue:
             try:
                 with tracing.section("devbound", "launch"), \
                         self.perf.time_avg("launch"):
-                    if g.kind == "planar":
-                        state = self._launch_planar(g)
-                    elif g.kind == "resident":
-                        state = self._launch_resident(g)
-                    elif g.kind in ("packedbit", "packetrows"):
-                        state = self._launch_packedbit(g)
-                    elif g.kind == "packedbit_resident":
-                        state = self._launch_packedbit_resident(g)
-                    elif g.kind == "packedbit_planes":
-                        state = self._launch_packedbit_planes(g)
-                    else:
-                        state = self._launch_packed(g)
+                    state = self._launch(g)
                 if sp is not None:
                     sp.event("launched")
                 launched.append(_Launched(g, state, now, sp, wait_s,
@@ -973,17 +905,9 @@ class BatchingQueue:
         for lc in launched:
             g, state = lc.group, lc.state
             try:
-                if g.kind == "planar":
-                    self._complete_planar(g, state)
-                elif g.kind == "resident":
+                if LANES[g.kind].resident:
                     self._complete_resident(g, state)
-                elif g.kind == "packedbit_resident":
-                    self._complete_packedbit_resident(g, state)
-                elif g.kind == "packedbit_planes":
-                    self._complete_packedbit_planes(g, state)
                 else:
-                    # "packed", "packedbit" and "packetrows": all fan
-                    # packed uint8 byte columns back out
                     self._complete_packed(g, state)
             except Exception as e:
                 # device completion failure: trip the breaker and rescue
@@ -1061,8 +985,7 @@ class BatchingQueue:
             self.perf.inc("sharded_dispatch")
         self.perf.inc("bytes", nbytes)
 
-
-    def _maybe_shard(self, batch, pad_np: bool, align: int = 1):
+    def _maybe_shard(self, batch, align: int = 1):
         """Lay a dispatch batch across the mesh when one is attached.
         Columns pad out to a device-grid multiple (bucket_columns gives
         powers of two, which a 6-device grid would never divide) — the
@@ -1082,13 +1005,8 @@ class BatchingQueue:
                        // math.gcd(align, self.mesh.n_devices))
                 want = -(-want // lcm) * lcm
             if want != batch.shape[1]:
-                extra = want - batch.shape[1]
-                if pad_np:
-                    batch = np.pad(batch, ((0, 0), (0, extra)))
-                else:
-                    import jax.numpy as jnp
-
-                    batch = jnp.pad(batch, ((0, 0), (0, extra)))
+                batch = np.pad(
+                    batch, ((0, 0), (0, want - batch.shape[1])))
             return self.mesh.shard_batch(batch), True
         except Exception as e:
             # sick mesh: single-device still serves, but never in silence
@@ -1098,21 +1016,22 @@ class BatchingQueue:
                       "one device", batch.shape, exc_info=e)
             return batch, False
 
-    def _stage_packed_batch(self, g: _Group, align: int = 1,
-                            words: bool = False):
-        """The shared launch preamble for packed-byte request groups:
-        coalesce the requests column-wise, bucket the width to a power of
-        two of `align`-column units (bounds XLA recompiles; the packet
-        lane's unit is its w*packetsize block, the others' divides the
-        1024-column floor, so theirs is the plain pow2 width), shard
-        across the mesh when one is attached, and otherwise start the H2D
-        transfer NOW so it overlaps the previous round's result fetch.
-        `words` hands the device the same bytes as uint32 columns.
-        Returns (widths, batch, sharded, nbytes)."""
+    def _launch(self, g: _Group):
+        """Launch one group on its lane: coalesce the requests
+        column-wise, bucket the width to a power of two of the lane's
+        column units (bounds XLA recompiles; the packet lane's unit is
+        its w*packetsize block, the others' divides the 1024-column
+        floor, so theirs is the plain pow2 width), shard across the mesh
+        when one is attached, and otherwise start the H2D transfer NOW so
+        it overlaps the previous round's result fetch; then enqueue the
+        lane's one fused program (async: a device handle comes back).
+        Returns (widths, out, sharded, nbytes)."""
         import jax
 
         from ceph_tpu.ops.gf2 import bucket_columns as _bucket
 
+        lane = LANES[g.kind]
+        align = lane.align or g.w * g.packetsize
         widths = [req.regions.shape[1] for req in g.requests]
         batch = np.concatenate([req.regions for req in g.requests], axis=1)
         cols = batch.shape[1]
@@ -1122,35 +1041,14 @@ class BatchingQueue:
             batch = np.pad(batch, ((0, 0), (0, pad)))
         nbytes = batch.nbytes
         self.perf.inc("h2d_bytes", nbytes)
-        if words:
+        if g.packetsize and g.packetsize % 4 == 0:
+            # a packet is XORed whole, so the device gets it as u32 words
+            # when its size allows
             batch, align = batch.view(np.uint32), align // 4
-        batch, sharded = self._maybe_shard(batch, pad_np=True, align=align)
+        batch, sharded = self._maybe_shard(batch, align=align)
         if not sharded:
             batch = jax.device_put(batch)  # async H2D staging
-        return widths, batch, sharded, nbytes
-
-    def _launch_packed(self, g: _Group):
-        from ceph_tpu.ops.gf2 import gf2_apply_bytes
-
-        widths, batch, sharded, nbytes = self._stage_packed_batch(g)
-        use_pallas = self._use_pallas and not sharded
-        if use_pallas is None:
-            from ceph_tpu.ops.gf2 import pallas_enabled
-            from ceph_tpu.ops.pallas_gf2 import TILE_B
-            from ceph_tpu.utils.jaxdev import probe_backend
-
-            # pallas_call does not run under GSPMD sharding (it would
-            # need a shard_map wrapper); sharded batches take XLA
-            use_pallas = (
-                not sharded
-                and pallas_enabled()
-                and probe_backend() == "tpu"
-                and batch.shape[1] % TILE_B == 0
-            )
-        # async launch: the jitted call returns a device handle
-        out = gf2_apply_bytes(g.mbits, batch, g.w, g.out_rows,
-                              use_pallas=use_pallas)
-        return widths, out, sharded, nbytes
+        return widths, lane.device(g, batch), sharded, nbytes
 
     def _complete_packed(self, g: _Group, state) -> None:
         widths, out, sharded, nbytes = state
@@ -1170,120 +1068,17 @@ class BatchingQueue:
                 pass  # cancelled in the check-to-set window
             off += width
 
-    def _launch_planar(self, g: _Group):
-        """Matmul-only dispatch over HBM-resident bit-planes: ONE batched
-        device call per (matrix) group; results are handed back as planar
-        device buffers so the next stage chains without a host bounce."""
-        import jax.numpy as jnp
-
-        from ceph_tpu.ops.gf2 import bucket_columns as _bucket
-        from ceph_tpu.ops.gf2 import gf2_matmul
-
-        widths = [req.regions.shape[1] for req in g.requests]
-        batch = (g.requests[0].regions if len(g.requests) == 1
-                 else jnp.concatenate([req.regions
-                                       for req in g.requests], axis=1))
-        # pow2 column bucketing, same as the other lanes: varying
-        # coalesced widths must not each compile a fresh gf2_matmul
-        pad = _bucket(batch.shape[1]) - batch.shape[1]
-        if pad:
-            batch = jnp.pad(batch, ((0, 0), (0, pad)))
-        batch, sharded = self._maybe_shard(batch, pad_np=False)
-        out = gf2_matmul(jnp.asarray(g.mbits), batch)
-        return widths, out, sharded
-
-    def _complete_planar(self, g: _Group, state) -> None:
-        widths, out, sharded = state
-        self._note_dispatch(
-            sum(w for w in widths) * g.mbits.shape[1] // 8, sharded)
-        off = 0
-        for width, req in zip(widths, g.requests):
-            try:
-                # device-side slice: stays planar-resident; no host copy
-                req.future.set_result(out[:, off : off + width])
-            except InvalidStateError:
-                pass
-            off += width
-
-    def _launch_resident(self, g: _Group):
-        """Residency write path: ONE fused batched call — unpack the
-        concatenated packed rows, matmul, pack the parity — and fan both
-        products out per request: (packed parity for persistence, planar
-        rows to stay HBM-resident)."""
-        from ceph_tpu.ops.gf2 import gf2_encode_resident
-
-        widths, batch, sharded, nbytes = self._stage_packed_batch(g)
-        # AFTER any mesh grid-padding: the planar fan-out factor must
-        # relate all_bits' columns to the columns the matmul actually saw
-        cols = batch.shape[1]
-        packed, all_bits = gf2_encode_resident(
-            g.mbits, batch, g.w, g.out_rows)
-        return widths, packed, all_bits, sharded, nbytes, cols
-
     def _complete_resident(self, g: _Group, state) -> None:
-        widths, packed, all_bits, sharded, nbytes, cols = state
-        packed = self._fetch(packed)
-        self._note_dispatch(nbytes, sharded)
-        # planar columns per packed byte-column depends on w (w=16: B//2)
-        cfac = all_bits.shape[1] / cols
-        off = 0
-        for width, req in zip(widths, g.requests):
-            try:
-                c0, c1 = int(off * cfac), int((off + width) * cfac)
-                req.future.set_result((packed[:, off : off + width].copy(),
-                                   all_bits[:, c0:c1]))
-            except InvalidStateError:
-                pass
-            off += width
-
-    # -- packed-bit lanes (u32 plane words + static XOR schedules) -----------
-
-    def _launch_packedbit(self, g: _Group):
-        """One fused schedule call over the coalesced packed rows:
-        unpack -> u32 words -> XOR schedule -> byte pack, compiled per
-        matrix behind the gf2 LRU.  Fan-out is byte columns, so requests
-        of ANY width coalesce (pow2 bucketing keeps B % 32 == 0).
-
-        The packet-layout form ("packetrows") is this lane with the other
-        pair of layout stages: whole w*packetsize blocks in, a block
-        transpose on the device where the byte layout has a bit
-        transpose, the same schedule.  A packet is XORed whole, so the
-        device gets it as u32 words when its size allows."""
-        from ceph_tpu.ops.gf2 import gf2_apply_packedbit, gf2_apply_packetrows
-
-        if g.kind == "packetrows":
-            widths, batch, sharded, nbytes = self._stage_packed_batch(
-                g, align=g.w * g.packetsize, words=g.packetsize % 4 == 0)
-            out = gf2_apply_packetrows(g.mbits, batch, g.w, g.packetsize)
-        else:
-            widths, batch, sharded, nbytes = self._stage_packed_batch(
-                g, align=32)
-            out = gf2_apply_packedbit(g.mbits, batch)
-        return widths, out, sharded, nbytes
-
-    # completion: _complete_packed (identical packed-byte fan-out)
-
-    def _launch_packedbit_resident(self, g: _Group):
-        """Packed-bit residency write path: one fused batched call, both
-        products fanned out per request — packed parity bytes for
-        persistence, u32 plane words to stay HBM-resident.  Request
-        widths must be whole u32 words (B % 32 == 0) so the plane
-        fan-out slices stay word-aligned; submit_packedbit_resident
-        rejects misaligned requests before they can coalesce."""
-        from ceph_tpu.ops.gf2 import gf2_encode_packedbit_resident
-
-        widths, batch, sharded, nbytes = self._stage_packed_batch(g, align=32)
-        packed, planes = gf2_encode_packedbit_resident(g.mbits, batch)
-        return widths, packed, planes, sharded, nbytes
-
-    def _complete_packedbit_resident(self, g: _Group, state) -> None:
+        """Fan out a resident lane's two products per request: packed
+        parity bytes for persistence, and the request's columns of the
+        data ‖ parity bit-rows, which stay on the device."""
         # DONATION SAFETY: every fan-out below is a device-side SLICE of
-        # the one batched `planes` product — consumers (the pagestore's
+        # the one batched `rows` product — consumers (the pagestore's
         # device-arm install, ceph_tpu/ops/slab.py) must never donate
         # the DATA argument of their kernels, because sibling requests
         # alias the same underlying buffer; only the slab argument,
         # which this plane never hands out, is donatable.
-        widths, packed, planes, sharded, nbytes = state
+        widths, (packed, rows), sharded, nbytes = state
         packed = self._fetch(packed)
         self._note_dispatch(nbytes, sharded)
         if len(g.requests) == 1 and packed.shape[1] == widths[0]:
@@ -1292,392 +1087,22 @@ class BatchingQueue:
             # graph, and the install's flatten sees one contiguous
             # buffer
             try:
-                g.requests[0].future.set_result((packed, planes))
+                g.requests[0].future.set_result((packed, rows))
             except InvalidStateError:
                 pass
             return
+        # resident columns per packed byte column, after any mesh
+        # grid-padding: 1/32 for u32 plane words (request widths are whole
+        # words, _check_packedbit_resident), 1/2, 1 or 2 for int8 planes
+        # of w=16, 8, 4
+        cols, rcols = packed.shape[1], rows.shape[1]
         off = 0
         for width, req in zip(widths, g.requests):
             try:
-                # 32 byte columns per u32 plane word (integer exact: the
-                # launch asserted width % 32 == 0)
-                req.future.set_result((packed[:, off : off + width].copy(),
-                                   planes[:, off // 32 : (off + width) // 32]))
+                req.future.set_result((
+                    packed[:, off : off + width].copy(),
+                    rows[:, off * rcols // cols
+                         : (off + width) * rcols // cols]))
             except InvalidStateError:
                 pass
             off += width
-
-    def _launch_packedbit_planes(self, g: _Group):
-        """Schedule-only dispatch over resident u32 plane words — the
-        packed-bit mirror of the planar lane: results stay device-side
-        plane buffers, chaining without a host bounce."""
-        import jax.numpy as jnp
-
-        from ceph_tpu.ops.gf2 import bucket_columns as _bucket
-        from ceph_tpu.ops.gf2 import gf2_xor_packed
-
-        widths = [req.regions.shape[1] for req in g.requests]  # u32 words
-        batch = (g.requests[0].regions if len(g.requests) == 1
-                 else jnp.concatenate([req.regions
-                                       for req in g.requests], axis=1))
-        # pow2 word bucketing (lo=32 words == the byte lanes' 1024 cols)
-        pad = _bucket(batch.shape[1], lo=32) - batch.shape[1]
-        if pad:
-            batch = jnp.pad(batch, ((0, 0), (0, pad)))
-        batch, sharded = self._maybe_shard(batch, pad_np=False)
-        out = gf2_xor_packed(g.mbits, batch)
-        return widths, out, sharded
-
-    def _complete_packedbit_planes(self, g: _Group, state) -> None:
-        widths, out, sharded = state
-        # u32 plane words carry 1 bit/bit, so plane bytes == packed-
-        # equivalent bytes (same arithmetic as _req_bytes: C rows x Wc
-        # words x 4 B/word; no 8x int8 expansion to divide back out)
-        self._note_dispatch(sum(widths) * 4 * g.mbits.shape[1], sharded)
-        off = 0
-        for width, req in zip(widths, g.requests):
-            try:
-                req.future.set_result(out[:, off : off + width])  # stays resident
-            except InvalidStateError:
-                pass
-            off += width
-
-
-class PlanarShardStore:
-    """HBM-resident planar shard cache — the residency manager behind the
-    measured ~1.6x pack-elimination win (ceph_tpu/ops/gf2.py writeup).
-
-    Rows of packed uint8 shard bytes are admitted ONCE (one on-device
-    unpack) and then live in HBM as int8 bit-planes; every subsequent EC
-    op on them — encode, decode-reconstruct, scrub re-encode, recovery —
-    is a pure GF(2) matmul chaining planar buffers, and bytes are packed
-    back exactly once, when they leave for the wire/store.  The
-    reference's analog is the stripe buffer staying cache-resident across
-    ECUtil::encode's loop (reference src/osd/ECUtil.cc:123-160); here the
-    residency scope is HBM across whole pipeline stages.
-
-    Capacity is a hard byte budget over the PLANAR footprint (w x the
-    packed bytes): least-recently-used entries are evicted, so the store
-    degrades to the packed path, never to an OOM.  Thread-safe — the OSD
-    event loop, the batching worker, and tests may touch it concurrently.
-    """
-
-    def __init__(self, capacity_bytes: int = 256 << 20,
-                 queue: Optional[BatchingQueue] = None):
-        from ceph_tpu.common.lockdep import make_mutex
-
-        self.capacity_bytes = capacity_bytes
-        self.queue = queue
-        self._lock = make_mutex("planar-store")
-        self._entries: "OrderedDict[Any, Any]" = OrderedDict()
-        self._bytes: Dict[Any, int] = {}
-        self._trim: Dict[Any, int] = {}  # packedbit admits: pre-pad width
-        # exit-boundary memo: key -> (version, packed host result).  The
-        # store's contract is "pack exactly once per resident lifetime",
-        # but a cache-tier resident is READ many times — without a memo
-        # every resident-hit read re-pays the device pack.  Lives and
-        # dies WITH the entry (cleared on put/drop/LRU-evict), so a
-        # memo can never outlive or contradict its resident.  Host RAM,
-        # not HBM — tracked separately (memo_bytes gauge) and capped at
-        # the store's capacity so the total footprint the operator
-        # budgets for is at most 2x capacity_bytes, never unbounded.
-        self._memo: Dict[Any, Tuple[Any, Any]] = {}
-        self.memo_bytes = 0
-        self.resident_bytes = 0
-        self.admits = 0
-        self.hits = 0
-        self.misses = 0
-        self.evictions = 0
-        # the `planar_store` perf set mirrors the bare ints above (kept:
-        # eviction logic and tests read them) and adds the boundary
-        # latencies the ints can't carry (module-docstring schema)
-        self.perf = (
-            PerfCountersBuilder("planar_store")
-            .add_u64_counter("admit", "packed rows admitted (one unpack)")
-            .add_u64_counter("hit", "resident lookups served")
-            .add_u64_counter("miss", "lookups that fell to the packed path")
-            .add_u64_counter("evict", "LRU evictions under the byte budget")
-            .add_u64("resident_bytes", "planar HBM footprint (gauge)")
-            .add_u64("entries", "resident objects (gauge)")
-            .add_u64("memo_bytes",
-                     "exit-boundary packed memo host footprint (gauge)")
-            .add_time_avg("pack_s",
-                          "device->host pack seconds at the exit boundary")
-            .add_time_avg("unpack_s",
-                          "host->device unpack seconds at admission")
-            .create_perf_counters())
-        # `perf reset` re-reads the live gauges instead of leaving the
-        # residency footprint misreported as 0 until the next admit
-        self.perf.resync = self._resync_gauges
-
-    def _resync_gauges(self) -> None:
-        # gauges are written INSIDE the store lock everywhere (here,
-        # put_planar, drop): an unlocked write could overwrite a newer
-        # value with a stale snapshot.  Lock order is store -> perf.
-        with self._lock:
-            self.perf.set("resident_bytes", self.resident_bytes)
-            self.perf.set("entries", len(self._entries))
-            self.perf.set("memo_bytes", self.memo_bytes)
-
-    # -- host boundary (pack/unpack paid here, once) -------------------------
-
-    def admit(self, key: Any, rows: np.ndarray, w: int = 8,
-              meta: Any = None, layout: str = "planes"):
-        """Unpack packed [n, B] uint8 rows onto the device and keep them
-        resident under `key`.  Returns the resident device buffer.
-        layout="planes" stores int8 bit-planes (any w); "packedbit"
-        stores u32 plane words (w=8 only, 1/8th the footprint — the
-        production lane), padding B out to whole words and trimming on
-        read."""
-        with self.perf.time_avg("unpack_s"):
-            if layout == "packedbit":
-                from ceph_tpu.ops.gf2 import to_packedbit
-
-                assert w == 8, "packed-bit residency is the w=8 byte layout"
-                B = rows.shape[1]
-                buf = np.ascontiguousarray(rows)
-                if B % 32:
-                    buf = np.pad(buf, ((0, 0), (0, 32 - B % 32)))
-                bits = to_packedbit(buf)
-                self.put_planar(key, bits, w=w, n_rows=rows.shape[0],
-                                meta=meta, trim=B)
-            else:
-                from ceph_tpu.ops.gf2 import to_planar
-
-                bits = to_planar(np.ascontiguousarray(rows), w)
-                self.put_planar(key, bits, w=w, n_rows=rows.shape[0],
-                                meta=meta)
-        self.admits += 1
-        self.perf.inc("admit")
-        return bits
-
-    def read(self, key: Any) -> Optional[np.ndarray]:
-        """Pack the resident rows back to [n, B] uint8 host bytes — the
-        EXIT boundary.  None when not resident.  Handles both layouts
-        (entry dtype tells them apart: uint32 words vs int8 planes)."""
-        got = self.get_planar(key)
-        if got is None:
-            return None
-        bits, w, n_rows, _meta = got
-        if np.dtype(bits.dtype) == np.uint32:
-            from ceph_tpu.ops.gf2 import from_packedbit
-
-            with self.perf.time_avg("pack_s"):
-                out = np.asarray(from_packedbit(bits, n_rows))
-            with self._lock:
-                trim = self._trim.get(key)
-            return out if trim is None else out[:, :trim]
-        from ceph_tpu.ops.gf2 import from_planar
-
-        with self.perf.time_avg("pack_s"):
-            return np.asarray(from_planar(bits, w, n_rows))
-
-    # -- resident side (no pack/unpack anywhere below) -----------------------
-
-    def put_planar(self, key: Any, bits, w: int = 8,
-                   n_rows: Optional[int] = None, meta: Any = None,
-                   trim: Optional[int] = None) -> None:
-        """`meta` is caller state carried with the entry (the OSD stores
-        the object VERSION there, so a read can reject a stale resident).
-        `trim` is the pre-pad byte width of a packed-bit admit, installed
-        under the same lock as the entry so a concurrent read never sees
-        the entry without its trim."""
-        if n_rows is None:
-            n_rows = bits.shape[0] // w
-        # HBM footprint by element width: int8 planes are 1 B/element
-        # (8x the packed bytes), u32 packed-bit words 4 B/element (1x)
-        nbytes = int(np.prod(bits.shape)) * np.dtype(bits.dtype).itemsize
-        with self._lock:
-            if key in self._entries:
-                self.resident_bytes -= self._bytes[key]
-            self._entries[key] = (bits, w, n_rows, meta)
-            self._entries.move_to_end(key)
-            self._bytes[key] = nbytes
-            self._memo_discard(key)  # new rows: stale packed memo dies
-            if trim is None:
-                self._trim.pop(key, None)  # re-put resets admit-time trim
-            else:
-                self._trim[key] = trim
-            self.resident_bytes += nbytes
-            evicted = 0
-            while self.resident_bytes > self.capacity_bytes and self._entries:
-                old_key, _ = self._entries.popitem(last=False)
-                self.resident_bytes -= self._bytes.pop(old_key)
-                self._trim.pop(old_key, None)
-                self._memo_discard(old_key)
-                self.evictions += 1
-                evicted += 1
-            # gauge writes stay under the store lock (see _resync_gauges)
-            self.perf.set("resident_bytes", self.resident_bytes)
-            self.perf.set("entries", len(self._entries))
-        if evicted:
-            self.perf.inc("evict", evicted)
-
-    def get_planar(self, key: Any):
-        """(bits, w, n_rows, meta) or None; refreshes LRU position."""
-        with self._lock:
-            ent = self._entries.get(key)
-            if ent is None:
-                self.misses += 1
-            else:
-                self._entries.move_to_end(key)
-                self.hits += 1
-        self.perf.inc("hit" if ent is not None else "miss")
-        return ent
-
-    # -- the residency protocol shared with PagedResidentStore ---------------
-    # (ceph_tpu/rados/pagestore.py): ecutil's planar_* helpers and the
-    # OSD tier paths speak these four shapes so either store can sit
-    # behind the cache tier.
-
-    def touch(self, key: Any):
-        """(w, n_rows, meta) with LRU refresh + hit/miss counting,
-        materializing nothing."""
-        ent = self.get_planar(key)
-        return None if ent is None else (ent[1], ent[2], ent[3])
-
-    def entry_info(self, key: Any):
-        """(w, n_rows, meta) without LRU/counter side effects."""
-        with self._lock:
-            ent = self._entries.get(key)
-        return None if ent is None else (ent[1], ent[2], ent[3])
-
-    def resident_meta(self, key: Any):
-        """The entry's caller meta, or None — the policy probe shape."""
-        info = self.entry_info(key)
-        return None if info is None else info[2]
-
-    def gather_rows(self, key: Any, r0: int, r1: int):
-        """The resident's bit-rows [r0, r1) (a device-buffer slice
-        here; the paged store gathers from its page table), or None.
-        No LRU side effects — ``touch`` owns those."""
-        with self._lock:
-            ent = self._entries.get(key)
-        if ent is None or r1 > ent[0].shape[0]:
-            return None
-        return ent[0][r0:r1]
-
-    def apply(self, key: Any, mbits: np.ndarray, out_rows: int,
-              out_key: Any = None):
-        """Apply a bit-matrix to the resident planar rows (encode with a
-        generator, reconstruct with an inverted signature matrix, scrub
-        re-encode, ...).  Pure matmul; the result stays planar, stored
-        under `out_key` when given.  Returns the planar device buffer, or
-        None when `key` is not resident.  Routes through the batching
-        queue when one is attached (cross-object coalescing)."""
-        got = self.get_planar(key)
-        if got is None:
-            return None
-        bits, w, _, _meta = got
-        if np.dtype(bits.dtype) == np.uint32:
-            # packed-bit resident: the matrix runs as a static XOR
-            # schedule over the u32 plane words (compiled per matrix
-            # behind the gf2 LRU — decode signatures included)
-            mb = np.asarray(mbits, dtype=np.uint8)
-            if self.queue is not None:
-                out = self.queue.submit_packedbit_planes(
-                    mb, bits, w, out_rows).result()
-            else:
-                from ceph_tpu.ops.gf2 import gf2_xor_packed
-
-                out = gf2_xor_packed(mb, bits)
-        elif self.queue is not None:
-            out = self.queue.submit_planar(
-                np.asarray(mbits), bits, w, out_rows).result()
-        else:
-            import jax.numpy as jnp
-
-            from ceph_tpu.ops.gf2 import gf2_matmul
-
-            out = gf2_matmul(jnp.asarray(np.asarray(mbits)), bits)
-        if out_key is not None:
-            self.put_planar(out_key, out, w=w, n_rows=out_rows)
-        return out
-
-    def drop(self, key: Any, force: bool = False) -> bool:
-        """Remove `key` if resident; True when an entry was actually
-        dropped.  Dropping an absent key is a supported no-op (the tier
-        agent races the LRU here: either side may have evicted first,
-        and the loser must count a no-op, not error).  ``force`` is the
-        paged store's dirty-override knob — a no-op here, where nothing
-        is ever dirty — accepted so callers can speak one surface."""
-        with self._lock:
-            dropped = key in self._entries
-            if dropped:
-                del self._entries[key]
-                self.resident_bytes -= self._bytes.pop(key)
-                self._trim.pop(key, None)
-            self._memo_discard(key)
-            self.perf.set("resident_bytes", self.resident_bytes)
-            self.perf.set("entries", len(self._entries))
-        return dropped
-
-    def peek(self, key: Any):
-        """(bits, w, n_rows, meta) or None WITHOUT touching LRU order or
-        the hit/miss counters — policy probes (the tier promotion gate
-        asking "already resident at this version?") must not make an
-        entry look recently used or pollute the hit ratio."""
-        with self._lock:
-            return self._entries.get(key)
-
-    def entries_snapshot(self) -> List[Tuple[Any, int]]:
-        """(key, planar nbytes) pairs in LRU order, oldest first — the
-        tier agent's eviction-candidate input.  A point-in-time copy:
-        the agent ranks against it and tolerates entries that vanish
-        before its drop lands (drop() reports the no-op)."""
-        with self._lock:
-            return [(k, self._bytes[k]) for k in self._entries]
-
-    def _memo_discard(self, key: Any) -> None:
-        """Drop a key's memo and its byte accounting.  Caller holds the
-        store lock."""
-        got = self._memo.pop(key, None)
-        if got is not None:
-            self.memo_bytes -= len(got[1])
-
-    def memo_get(self, key: Any, version: Any):
-        """The exit-boundary memo for `key` at `version`, or None.  Only
-        valid while the entry is RESIDENT (callers validate residency
-        via get_planar first); the memo is version-tagged so a re-put at
-        a newer version can never serve yesterday's bytes."""
-        with self._lock:
-            if key not in self._entries:
-                return None
-            got = self._memo.get(key)
-        if got is None or got[0] != version:
-            return None
-        return got[1]
-
-    def memo_put(self, key: Any, version: Any, value: Any) -> None:
-        """Record the packed host result of this resident at `version`
-        (one entry per key, latest version wins): subsequent resident
-        hits skip the device pack entirely — the 'pack once per
-        resident lifetime' contract made true under repeated reads.
-        Ignored when the entry is not resident (a drop/evict raced the
-        pack: the memo must not outlive the entry), and when the memo
-        pool is at its budget (capacity_bytes: host RAM stays the same
-        order as the HBM budget, so the operator's total footprint is
-        bounded by ~2x capacity — a refused memo only costs a re-pack
-        on the next read, never correctness)."""
-        nbytes = len(value)
-        with self._lock:
-            if key not in self._entries:
-                return
-            self._memo_discard(key)
-            if self.memo_bytes + nbytes > self.capacity_bytes:
-                self.perf.set("memo_bytes", self.memo_bytes)
-                return
-            self._memo[key] = (version, value)
-            self.memo_bytes += nbytes
-            self.perf.set("memo_bytes", self.memo_bytes)
-
-    def __contains__(self, key: Any) -> bool:
-        with self._lock:
-            return key in self._entries
-
-    def stats(self) -> Dict[str, int]:
-        return {"resident_bytes": self.resident_bytes,
-                "memo_bytes": self.memo_bytes,
-                "entries": len(self._entries), "admits": self.admits,
-                "hits": self.hits, "misses": self.misses,
-                "evictions": self.evictions}

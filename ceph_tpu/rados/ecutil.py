@@ -174,52 +174,51 @@ ECPLAN_PERF = (PerfCountersBuilder("ecplan")
                .create_perf_counters())
 
 
-def _packedbit_route(codec) -> bool:
-    """Whether this codec's queue plans ride the packed-bit XOR-schedule
-    lane (the w=8 production lane, ceph_tpu/ops/gf2.py lane-promotion
-    writeup) instead of the int8-plane lanes."""
+def lane_for(codec, resident: bool = False, cols: int = 0):
+    """THE rule for which BatchingQueue lane (a key of
+    parallel/service.LANES) applies a bit-matrix for this codec, and the
+    dtype that lane takes the matrix in: (kind, dtype), or None where no
+    lane does.  The codec's bit_layout and w decide, nothing else:
+    packet-layout codes (cauchy/liberation family) ride "packetrows", the
+    packed-bit lane whose layout stages are block transposes, and keep no
+    residents; w=8 byte-layout codes the packed-bit pair (static XOR
+    schedule over u32 plane words — a resident's `cols` must be whole
+    words); the rest (w=16/w=4, or CEPH_TPU_PACKEDBIT=0) the int8-plane
+    pair, whose matrix is a matmul operand.  The plans below, the
+    resident encode and the tpu plugin's direct seam all ask here."""
     from ceph_tpu.ops.gf2 import packedbit_enabled
 
-    return packedbit_enabled() and getattr(codec, "w", 8) == 8
+    if getattr(codec, "bit_layout", "byte") == "packet":
+        return None if resident else ("packetrows", np.uint8)
+    if (getattr(codec, "w", 8) == 8 and not (resident and cols % 32)
+            and packedbit_enabled()):
+        return ("packedbit_resident" if resident else "packedbit"), np.uint8
+    return ("resident" if resident else "packed"), np.int8
 
 
 def _lane(codec, sinfo: StripeInfo):
-    """The queue lane this codec's plans ride, as (kind[, packetsize]):
-    the codec's bit_layout picks the layout stages, nothing else.
-    Packet-layout codes (cauchy/liberation family) ride "packetrows",
-    the packed-bit lane whose stages are block transposes; w=8
-    byte-layout codes "packedbit", the production lane (static XOR
-    schedule over u32 plane words); the rest the int8-plane "packed"
-    lane.  None when no lane takes the codec: a chunk remap, or chunks
-    that are not whole w*packetsize blocks."""
+    """The lane this codec's encode/decode plans ride, as lane_for's
+    (kind, dtype) plus the packet size on the packet-layout lane.  None
+    when no lane takes the codec: a chunk remap, or chunks that are not
+    whole w*packetsize blocks."""
     if codec.get_chunk_mapping():
         return None
-    if getattr(codec, "bit_layout", "byte") == "packet":
+    lane = lane_for(codec)
+    if lane[0] == "packetrows":
         if sinfo.chunk_size % (codec.w * codec.packetsize):
             return None
-        return "packetrows", codec.packetsize
-    return ("packedbit",) if _packedbit_route(codec) else ("packed",)
+        return (*lane, codec.packetsize)
+    return lane
 
 
 def _lane_item(lane, codec, bitmatrix, rows: np.ndarray, out_rows: int):
-    """The lane submission for applying `bitmatrix` (an encode generator
+    """The lane request for applying `bitmatrix` (an encode generator
     or an inverted decode signature) to `[n, n_stripes*chunk]` rows, as
-    BatchingQueue.submit_group takes it: (mbits, rows, w, out_rows,
-    kind[, packetsize])."""
-    dtype = np.int8 if lane[0] == "packed" else np.uint8
+    BatchingQueue.submit / submit_group take it: (mbits, rows, w,
+    out_rows, kind[, packetsize])."""
+    kind, dtype, *packetsize = lane
     return (np.asarray(bitmatrix).astype(dtype), rows,
-            getattr(codec, "w", 8), out_rows, *lane)
-
-
-def _submit_lane(queue, item, span=None):
-    """One _lane_item alone, through the lane's own submit."""
-    mat, rows, w, out_rows, kind, *packetsize = item
-    if kind == "packetrows":
-        return queue.submit_packetrows(mat, rows, w, packetsize[0], out_rows,
-                                       span=span)
-    if kind == "packedbit":
-        return queue.submit_packedbit(mat, rows, w, out_rows, span=span)
-    return queue.submit(mat, rows, w, out_rows, span=span)
+            getattr(codec, "w", 8), out_rows, kind, *packetsize)
 
 
 @tracing.sectioned("ecplan", "encode_plan")
@@ -227,9 +226,9 @@ def _encode_plan_parts(codec, sinfo: StripeInfo, arr: np.ndarray,
                        n_stripes: int):
     """The submit-free half of the queue encode plan: when the codec is
     batchable (a bit seam, no chunk remap), returns (item, reassemble) —
-    the lane submission (_lane_item) a caller hands to _submit_lane or
-    (with several buffers) to BatchingQueue.submit_group as one
-    whole-stripe-group handoff.  None when the queue path does not
+    the lane request (_lane_item) a caller hands to BatchingQueue.submit
+    or (with several buffers) to submit_group as one whole-stripe-group
+    handoff.  None when the queue path does not
     apply."""
     mbits = codec.bit_generator()
     lane = _lane(codec, sinfo) if mbits is not None else None
@@ -274,7 +273,7 @@ def _queue_encode_plan(codec, sinfo: StripeInfo, arr: np.ndarray,
     if parts is None:
         return None
     item, reassemble = parts
-    return _submit_lane(queue, item, span=span), reassemble
+    return queue.submit(*item, span=span), reassemble
 
 
 def batched_encode(codec, sinfo: StripeInfo, data: bytes,
@@ -447,8 +446,8 @@ def _queue_decode_plan(codec, sinfo: StripeInfo,
     # on the schedule lanes the inverted signature matrix compiles to its
     # own static XOR schedule behind the gf2 LRU (per-decode-signature
     # compilation — the ErasureCodeIsaTableCache design at compile scope)
-    fut = _submit_lane(
-        queue, _lane_item(lane, codec, inv_bm, src, len(missing)), span=span)
+    fut = queue.submit(
+        *_lane_item(lane, codec, inv_bm, src, len(missing)), span=span)
 
     @tracing.sectioned("ecplan", "decode_finish")
     def finish(rows: np.ndarray) -> bytes:
@@ -584,14 +583,14 @@ async def decode_object_async(codec, sinfo: StripeInfo,
                          scatter=scatter)
 
 
-# -- bit-planar residency (ceph_tpu/parallel/service.py PlanarShardStore) ----
+# -- bit-planar residency (the resident store: ceph_tpu/rados/pagestore.py) --
 #
-# The measured ~1.6x win (ops/gf2.py writeup): shards stay in HBM as
-# bit-planes across encode -> decode -> recovery, and the pack/unpack
-# boundary is paid once, when bytes enter or leave the device tier.  The
-# reference's per-stripe hot loop (src/osd/ECUtil.cc:123-160) keeps its
-# buffer cache-resident for one stripe; residency here spans pipeline
-# stages.  Byte-layout, unmapped, concat-safe codecs only — the same
+# Shards stay in HBM as bit-planes across encode -> decode -> recovery,
+# and the pack/unpack boundary is paid once, when bytes enter or leave the
+# device tier.  The reference's per-stripe hot loop
+# (src/osd/ECUtil.cc:123-160) keeps its buffer cache-resident for one
+# stripe; residency here spans pipeline stages.  Byte-layout, unmapped,
+# concat-safe codecs only — the same
 # eligibility as the batching-queue encode plan.  For w=8 codecs the
 # resident layout is PACKED-BIT u32 words (the production lane, 1 HBM
 # byte per data byte and the measured 1.45x XOR-schedule kernel);
@@ -642,21 +641,13 @@ async def planar_encode_async(codec, sinfo: StripeInfo, data: bytes,
             .reshape(n_stripes, k, sinfo.chunk_size)
             .transpose(1, 0, 2).reshape(k, n_stripes * sinfo.chunk_size))
         L = flat.shape[1]
-        # the packed-bit production lane needs whole u32 words per plane row
-        # (w=8 byte codecs guarantee it: chunk_size is a multiple of w*4=32)
-        packedbit = _packedbit_route(codec) and L % 32 == 0
-        if packedbit:
-            mbits = np.asarray(codec.bit_generator()).astype(np.uint8)
-        else:
-            mbits = np.asarray(codec.bit_generator()).astype(np.int8)
+        # (w=8 byte codecs have whole u32 words per plane row: chunk_size
+        # is a multiple of w*4=32)
+        kind, dtype = lane_for(codec, resident=True, cols=L)
+        mbits = np.asarray(codec.bit_generator()).astype(dtype)
     if queue is not None:
-        if packedbit:
-            parity, all_bits = await asyncio.wrap_future(
-                queue.submit_packedbit_resident(mbits, flat, w, m,
-                                                span=span))
-        else:
-            parity, all_bits = await asyncio.wrap_future(
-                queue.submit_resident(mbits, flat, w, m, span=span))
+        parity, all_bits = await asyncio.wrap_future(
+            queue.submit(mbits, flat, w, m, kind, span=span))
     else:
         from ceph_tpu.ops.gf2 import (bucket_columns, gf2_encode_resident,
                                       gf2_encode_packedbit_resident)
@@ -666,7 +657,7 @@ async def planar_encode_async(codec, sinfo: StripeInfo, data: bytes,
         if Lb != L:
             buf = np.zeros((k, Lb), dtype=np.uint8)
             buf[:, :L] = flat
-        if packedbit:
+        if kind == "packedbit_resident":
             parity, all_bits = gf2_encode_packedbit_resident(mbits, buf)
         else:
             parity, all_bits = gf2_encode_resident(mbits, buf, w, m)
@@ -768,11 +759,9 @@ def planar_object_bytes(store, key, version, k: int, cs: int,
     w, _n_rows, meta = got
     if not meta or meta[0] != version:
         return None
-    memo_get = getattr(store, "memo_get", None)
-    if memo_get is not None:
-        cached = memo_get(key, version)
-        if cached is not None:
-            return cached
+    cached = store.memo_get(key, version)
+    if cached is not None:
+        return cached
     data_bits = store.gather_rows(key, 0, k * w)
     if data_bits is None:
         return None
@@ -781,6 +770,5 @@ def planar_object_bytes(store, key, version, k: int, cs: int,
     n_stripes = max(1, L // cs)
     out = rows.reshape(k, n_stripes, cs).transpose(1, 0, 2)
     result = out.reshape(-1)[:object_size].tobytes()
-    if memo_get is not None:
-        store.memo_put(key, version, result)
+    store.memo_put(key, version, result)
     return result

@@ -213,25 +213,18 @@ _PLANAR_STORE = None
 
 
 def shared_planar_store(capacity_bytes: int = 0, page_bytes: int = 0,
-                        paged: Optional[bool] = None,
                         device: Optional[bool] = None,
                         prewarm: bool = False):
-    """The process-wide resident store behind the cache tier.  Engages
-    under the same conditions as the batching queue — an accelerator
-    backend (or CEPH_TPU_FORCE_BATCH=1 for CPU tests); None otherwise.
-    All in-process OSDs share one HBM budget; keys are namespaced per
-    OSD.
+    """The process-wide resident store behind the cache tier: a
+    PagedResidentStore (ceph_tpu/rados/pagestore.py — page table, ragged
+    tails, per-page dirty bits).  Engages under the same conditions as
+    the batching queue — an accelerator backend (or
+    CEPH_TPU_FORCE_BATCH=1 for CPU tests); None otherwise.  All
+    in-process OSDs share one HBM budget; keys are namespaced per OSD.
+    The first caller creates it; later callers only ever raise the shared
+    byte budget.
 
-    Two flavors behind one surface (the residency protocol:
-    put_planar/touch/gather_rows/drop/memo): the PAGED store
-    (ceph_tpu/rados/pagestore.py — page table, ragged tails, per-page
-    dirty bits; the default, and the only flavor that can run
-    writeback) and the r10 monolithic PlanarShardStore
-    (osd_tier_pagestore=false or CEPH_TPU_PAGESTORE=0 — the bench A/B
-    arm).  The FIRST creator decides the flavor for the process; later
-    callers only ever raise the shared byte budget.
-
-    ``device`` gates the paged store's DEVICE arm (jax.Array sub-slabs,
+    ``device`` gates the store's DEVICE arm (jax.Array sub-slabs,
     jitted installs/gathers — ceph_tpu/ops/slab.py): None = auto
     (device arm iff a real backend is live), False = pinned host arm
     (osd_tier_device_slab=false); CEPH_TPU_DEVICE_SLAB=1/0 overrides
@@ -242,22 +235,12 @@ def shared_planar_store(capacity_bytes: int = 0, page_bytes: int = 0,
         return None
     with _BATCH_QUEUE_LOCK:
         if _PLANAR_STORE is None:
-            use_paged = True if paged is None else bool(paged)
-            if os.environ.get("CEPH_TPU_PAGESTORE", "") == "0":
-                use_paged = False
-            if use_paged:
-                from ceph_tpu.rados.pagestore import PagedResidentStore
+            from ceph_tpu.rados.pagestore import PagedResidentStore
 
-                _PLANAR_STORE = PagedResidentStore(
-                    capacity_bytes=capacity_bytes or (256 << 20),
-                    page_bytes=page_bytes or (64 << 10), queue=queue,
-                    device=device, prewarm=prewarm)
-            else:
-                from ceph_tpu.parallel.service import PlanarShardStore
-
-                _PLANAR_STORE = PlanarShardStore(
-                    capacity_bytes=capacity_bytes or (256 << 20),
-                    queue=queue)
+            _PLANAR_STORE = PagedResidentStore(
+                capacity_bytes=capacity_bytes or (256 << 20),
+                page_bytes=page_bytes or (64 << 10), queue=queue,
+                device=device, prewarm=prewarm)
         elif capacity_bytes and capacity_bytes > _PLANAR_STORE.capacity_bytes:
             # the budget is one shared HBM pool: any daemon asking for
             # more raises it (first-wins would silently drop the knob)
@@ -345,7 +328,6 @@ class OSD:
                              "shards reverted to rollback slots (unfound)")
             .add_u64_counter("recovery_errors", "repair rounds that errored")
             .add_u64_counter("op_queued", "ops entering the sharded queue")
-            .add_u64_counter("op_dequeued", "ops drained")
             .add_u64_counter("heartbeat_failures", "peer failures reported")
             .add_u64_counter("gather_timeouts",
                              "sub-op gathers that gave up waiting for a "
@@ -386,12 +368,6 @@ class OSD:
             .add_u64_counter("scrub_repaired",
                              "scrub-found shards repaired by re-encode "
                              "+ push")
-            .add_u64("ec_batch_ops",
-                     "requests submitted to the shared queue (gauge)")
-            .add_u64("ec_batch_dispatches",
-                     "device dispatches issued by the shared queue (gauge)")
-            .add_u64("ec_batch_bytes",
-                     "bytes pushed through the shared queue (gauge)")
             .create_perf_counters()
         )
         # the `osd_scheduler` set: per-class queue flow, the dmClock
@@ -509,7 +485,6 @@ class OSD:
                 int(self.conf.get("osd_ec_planar_bytes", 0) or 0),
                 page_bytes=int(
                     self.conf.get("osd_tier_page_bytes", 64 << 10) or 0),
-                paged=bool(self.conf.get("osd_tier_pagestore", True)),
                 # None = auto (device arm iff a real backend is live);
                 # an explicit false config pins the host arm
                 device=(None if self.conf.get("osd_tier_device_slab",
@@ -550,7 +525,7 @@ class OSD:
         # causes), the gf2 `gf2_sched` schedule-cache set, the tpu
         # plugin's `ec_plugin` seam set (device dispatches vs CPU
         # fallbacks — the non-queue path), the `crush` placement-memo
-        # set, and the planar store's `planar_store` residency set.  The
+        # set, and the resident store's `pagestore` residency set.  The
         # queue/store/sched/plugin/crush sets are process-shared (as the
         # resources are); every colocated OSD dumps the same numbers.
         self.ctx.perf.add(self.messenger.perf)
@@ -780,12 +755,10 @@ class OSD:
             # loses, so drop them (kill_osd honesty: a revived id must
             # re-earn its pages, and surviving replicas' copies are the
             # ONLY cache-tier copies of its acked writebacks)
-            snap = getattr(self._planar, "entries_snapshot", None)
-            if snap is not None:
-                for key, _nb in snap():
-                    if isinstance(key, tuple) and key \
-                            and key[0] == self.osd_id:
-                        self._planar.drop(key, force=True)
+            for key, _nb in self._planar.entries_snapshot():
+                if isinstance(key, tuple) and key \
+                        and key[0] == self.osd_id:
+                    self._planar.drop(key, force=True)
         close = getattr(self.store, "close", None)
         if close is not None:
             close()
@@ -1069,13 +1042,6 @@ class OSD:
             ticks += 1
             self._maybe_schedule_scrubs()
             self._maybe_schedule_tier_agent()
-            if self._ec_queue is not None:
-                # mirror the shared queue's stats into this daemon's
-                # counters (perf dump / prometheus visibility); submits
-                # vs dispatches is the coalescing ratio
-                self.perf.set("ec_batch_ops", self._ec_queue.submits)
-                self.perf.set("ec_batch_dispatches", self._ec_queue.dispatches)
-                self.perf.set("ec_batch_bytes", self._ec_queue.bytes_dispatched)
             if ticks % 3 == 0:
                 await self._report_to_mgr()
             if self.conf.get("auth_cephx", False):
@@ -2349,13 +2315,6 @@ class OSD:
         # namespaced per OSD: in-process clusters share one store/budget
         return (self.osd_id, pool_id, oid)
 
-    def _paged_store(self):
-        """The shared resident store WHEN it is the paged flavor (dirty
-        tracking / page table / writeback live only there); None under
-        the monolithic r10 store or no store at all."""
-        s = self._planar
-        return s if (s is not None and hasattr(s, "dirty_items")) else None
-
     def _purge_pool(self, pool_id: int) -> None:
         """Delete every locally stored object of a pool removed from the
         map (reference PG deletion): data shards, rollback slots, PG
@@ -3139,7 +3098,7 @@ class OSD:
             # the splice precondition (prior_version match) composes
             # with reality instead of degrading every RMW to a full
             # rewrite
-            _ps = self._paged_store()
+            _ps = self._planar
             if _ps is not None \
                     and _ps.is_dirty(self._planar_key(op.pool_id, op.oid)):
                 if await self._tier_flush_any(
@@ -3552,9 +3511,8 @@ class OSD:
                                 # bytes, no planar rows): the memo inside
                                 # planar_object_bytes missed — gather the
                                 # object straight off the page table
-                                rr = getattr(self._planar, "read_raw", None)
-                                data = rr(self._planar_key(
-                                    op.pool_id, op.oid)) if rr else None
+                                data = self._planar.read_raw(
+                                    self._planar_key(op.pool_id, op.oid))
                             if data is not None:
                                 self.perf.inc("planar_read_hits")
                                 self.tier_perf.inc("resident_hit")
@@ -4813,7 +4771,7 @@ class OSD:
                     self._extent_cache.drop((msg.pool_id, msg.oid))
                     _pkey = self._planar_key(msg.pool_id, msg.oid)
                     _spare = False
-                    _ps = self._paged_store()
+                    _ps = self._planar
                     if _ps is not None:
                         _snap = _ps.peek_dirty(_pkey)
                         if _snap is not None \
@@ -4875,7 +4833,7 @@ class OSD:
             # primary reconstructs from other shards (the behavior
             # qa/standalone/erasure-code/test-erasure-eio.sh exercises)
             got = None
-        _ps = self._paged_store()
+        _ps = self._planar
         if _ps is not None:
             _snap = _ps.peek_dirty(self._planar_key(msg.pool_id, msg.oid))
             if _snap is not None and isinstance(_snap[0], CacheDirtyRecord):
@@ -5342,7 +5300,7 @@ class OSD:
         dirty / oversized), the caller stays cold."""
         _, all_bits, n_rows, n_cols, pw = planar
         store = self._planar
-        if self._paged_store() is not None:
+        if self._planar is not None:
             return store.put_planar(
                 pkey, all_bits, w=pw, n_rows=n_rows,
                 meta=(version, n_cols, object_size),
@@ -5393,7 +5351,7 @@ class OSD:
             return None
         self.tier_perf.inc("write_installs")
         if self._tier_cache_mode(pool) == "writeback" \
-                and self._paged_store() is not None:
+                and self._planar is not None:
             return "writeback"
         return "clean"
 
@@ -5418,7 +5376,7 @@ class OSD:
         agent's flush cadence."""
         from ceph_tpu.rados.pagestore import WritebackRecord
 
-        store = self._paged_store()
+        store = self._planar
         _, all_bits, n_rows, n_cols, pw = planar
         # failsafe BEFORE any mutation, exactly like _apply_shard_write:
         # a write whose eventual flush could not land must refuse now,
@@ -5460,7 +5418,7 @@ class OSD:
         Generation-tokened: an overwrite that re-installed mid-flush
         keeps ITS dirt.  False leaves the entry dirty (ENOSPC, raced
         install) — eviction stays refused."""
-        store = self._paged_store()
+        store = self._planar
         if store is None:
             return True
         snap = store.peek_dirty(pkey)
@@ -5534,7 +5492,7 @@ class OSD:
         destage); deferred-apply WritebackRecords are purely local dirt.
         The mon's predicates refuse destroy/stop while a target is the
         last live holder of any key."""
-        store = self._paged_store()
+        store = self._planar
         if store is None:
             return []
         out: List[Tuple[str, List[int]]] = []
@@ -5579,7 +5537,7 @@ class OSD:
         blob.  Someone reading the backing store ends the deferral:
         flush the resident and serve the fresh store read — version,
         crc, and hinfo all land consistent in one move."""
-        store = self._paged_store()
+        store = self._planar
         if store is None:
             return got
         pkey = self._planar_key(msg.pool_id, msg.oid)
@@ -5608,7 +5566,7 @@ class OSD:
         acked data once primaryship moved: the new primary's sub-reads
         and recovery hit our BACKING store, so the deferred applies
         land before we stop answering for the PG."""
-        store = self._paged_store()
+        store = self._planar
         if store is None or not store.has_dirty() or self.osdmap is None:
             return
         for key, info, _gen, _since in self._my_dirty_items(store):
@@ -5650,7 +5608,7 @@ class OSD:
         ack at that quorum.  None = the quorum cannot form or the store
         refused — the caller falls back to synchronous write-through
         (the degradation contract, counted wb_quorum_short)."""
-        store = self._paged_store()
+        store = self._planar
         if store is None:
             return None
         cache_min = max(1, self._tier_opt(pool, "cache_min_size", 2, int))
@@ -5751,7 +5709,7 @@ class OSD:
         keeps its dirt.  An install landing on the PG's CURRENT primary
         from a non-primary sender is a recovery push: adopt, then
         complete the dead installer's deferred destage."""
-        store = self._paged_store()
+        store = self._planar
         pkey = self._planar_key(msg.pool_id, msg.oid)
         if msg.op == "clear":
             if store is not None:
@@ -5857,7 +5815,7 @@ class OSD:
         records destage through the async encode+fan-out path, legacy
         WritebackRecords replay synchronously.  The one entry point for
         the RMW / scrub fences (both async contexts)."""
-        store = self._paged_store()
+        store = self._planar
         if store is None:
             return True
         snap = store.peek_dirty(pkey)
@@ -5875,7 +5833,7 @@ class OSD:
         _tier_flush_key: an overwrite that re-installed mid-encode keeps
         ITS dirt (we simply stop owning the flush).  False leaves the
         entry dirty for the next pass."""
-        store = self._paged_store()
+        store = self._planar
         if store is None:
             return True
         if pkey in self._raw_flush_inflight:
@@ -6019,7 +5977,7 @@ class OSD:
         throttled as CLASS_FLUSH background work; adopted copies whose
         write our PG log shows superseded (a lost clear) are GC'd."""
         self._update_flush_backlog()
-        store = self._paged_store()
+        store = self._planar
         if store is None or not store.has_dirty() or self.osdmap is None:
             return
         ratio = self._tier_dirty_ratio()
@@ -6074,7 +6032,7 @@ class OSD:
         own adopted copy) completes the dead installer's deferred
         destage.  Steady state (the installer still leads the PG) is a
         no-op."""
-        store = self._paged_store()
+        store = self._planar
         if store is None or not store.has_dirty() or self.osdmap is None:
             return
         for key, rec, _gen, _since in self._my_dirty_items(store):
@@ -6102,7 +6060,7 @@ class OSD:
         """Push our raw dirty copy to ``target`` (the PG's new primary).
         Our copy stays dirty until the destaging primary's post-flush
         clear — the push hands over the bytes, not the custody."""
-        store = self._paged_store()
+        store = self._planar
         if store is None or self.osdmap is None \
                 or target not in self.osdmap.osds:
             return
@@ -6152,7 +6110,7 @@ class OSD:
                 return self.store.read((msg.pool_id, msg.oid, msg.shard))
             except IOError:
                 return got
-        store = self._paged_store()
+        store = self._planar
         if store is None:
             return got
         data = store.memo_get(pkey, rec.version)
@@ -6175,7 +6133,7 @@ class OSD:
         """flush_backlog_bytes gauge: acked-but-not-EC-durable raw
         dirty bytes this OSD currently holds (own records + adopted
         copies)."""
-        store = self._paged_store()
+        store = self._planar
         if store is None:
             return
         total = 0
@@ -6522,7 +6480,7 @@ class OSD:
         self.tier_perf.set("resident_target_bytes", target)
         if target <= 0:
             return
-        paged = self._paged_store()
+        paged = self._planar
         if paged is not None:
             self._tier_flush_pass(paged, target, forced=bool(full_state))
         high = int(target * self._tier_full_ratio())
@@ -6606,7 +6564,7 @@ class OSD:
     def tier_status(self) -> dict:
         """`tier status` admin-socket shape."""
         store = self._planar
-        paged = self._paged_store()
+        paged = self._planar
         out = {
             "enabled": bool(self.conf.get("osd_tier_enabled", True)),
             "device_residency": store is not None,
@@ -6742,7 +6700,7 @@ class OSD:
         # resident means our local shard's apply is still deferred —
         # flush first or every dirty object reads as a mismatch and
         # kicks a repair storm against bytes that were never wrong
-        ps = self._paged_store()
+        ps = self._planar
         if ps is not None and ps.has_dirty():
             for key, _info, _gen, _since in self._my_dirty_items(
                     ps, pool_id=pool.pool_id, pg=only_pg):

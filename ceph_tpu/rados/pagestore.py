@@ -3,10 +3,10 @@
 Role-equivalent of the KV-cache page pool in a production inference
 stack (the Ragged Paged Attention idiom, arXiv:2604.15464: fixed-size
 pages, a per-object page table, ragged last pages) applied to EC shard
-residency.  The r10 PlanarShardStore holds every resident as ONE
-monolithic device buffer whose width was pow2-bucketed for the encode
-lane — mixed object sizes fragment the budget (a 68 KiB stripe pays for
-128 KiB) and eviction is all-or-nothing per object.  Here the budget is
+residency.  A store of ONE monolithic device buffer per resident (the
+r10 design), its width pow2-bucketed for the encode lane, lets mixed
+object sizes fragment the budget (a 68 KiB stripe pays for 128 KiB) and
+makes eviction all-or-nothing per object.  Here the budget is
 ONE preallocated u32 slab carved into fixed-size pages
 (``osd_tier_page_bytes``): a resident's packed-bit plane words are
 TRIMMED to their true width and flattened row-major across a page table
@@ -46,7 +46,7 @@ touch) and has TWO arms behind one page table:
   rule).  Eviction, dirty bits, shed_parity and the memo are PAGE
   TABLE bookkeeping — identical across both arms by construction.
 
-Thread-safe under one mutex, same discipline as PlanarShardStore; the
+Thread-safe under one mutex; the
 OSD event loop, the batching worker, and tests may touch it
 concurrently.  Device kernel dispatches run under that mutex too — the
 lock sequences donated installs against gathers, which is what makes
@@ -221,9 +221,10 @@ def build_pagestore_perf() -> PerfCounters:
 
 
 class PagedResidentStore:
-    """Drop-in residency manager behind the tier (PlanarShardStore
-    surface: put_planar/get_planar/touch/gather_rows/drop/peek/memo),
-    backed by the page pool above instead of per-object buffers."""
+    """The residency manager behind the tier (the residency protocol
+    ecutil's planar_* helpers and the OSD tier paths speak:
+    put_planar/get_planar/touch/gather_rows/drop/peek/memo), backed by
+    the page pool above."""
 
     def __init__(self, capacity_bytes: int = 256 << 20,
                  page_bytes: int = 64 << 10, queue: Optional[Any] = None,
@@ -794,12 +795,15 @@ class PagedResidentStore:
             return [(k, e.live_pages * self.page_bytes)
                     for k, e in self._entries.items()]
 
-    # -- host boundary (test/bench parity with PlanarShardStore) -------------
+    # -- host boundary (tests and chip_smoke: bytes in, bytes out) -----------
 
     def admit(self, key: Any, rows: np.ndarray, w: int = 8,
               meta: Any = None, layout: str = "planes"):
         """Unpack packed [n, B] uint8 rows and keep them page-resident
-        (PlanarShardStore.admit contract)."""
+        under `key`; returns the resident bit-rows.  layout="planes"
+        stores int8 bit-planes (any w); "packedbit" stores u32 plane
+        words (w=8 only, 1/8th the footprint — the production layout),
+        padding B out to whole words and trimming on read."""
         if layout == "packedbit":
             from ceph_tpu.ops.gf2 import to_packedbit
 
@@ -982,10 +986,17 @@ class PagedResidentStore:
 
     @tracing.sectioned("store", "memo_put")
     def memo_put(self, key: Any, version: Any, value: Any) -> None:
-        """As PlanarShardStore.memo_put, but the cap accounting is in
-        PAGE units against the pool's byte size — the memo gauge can
-        never drift from the granularity actual residency is budgeted
-        in."""
+        """Record the packed host result of this resident at `version`
+        (one entry per key, latest version wins): later resident hits
+        skip the device pack — 'pack once per resident lifetime' held
+        under repeated reads.  Ignored when the entry is not resident (a
+        drop/evict raced the pack: the memo must not outlive the entry)
+        and when the memo pool is at its budget (capacity_bytes of host
+        RAM, so the operator's total footprint is bounded by ~2x
+        capacity; a refused memo only costs a re-pack on the next read).
+        The cap accounting is in PAGE units against the pool's byte
+        size — the memo gauge can never drift from the granularity
+        actual residency is budgeted in."""
         charge = self._memo_charge(len(value))
         with self._lock:
             if key not in self._entries:

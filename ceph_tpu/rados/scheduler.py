@@ -484,8 +484,6 @@ class ShardedOpQueue:
                     import traceback
 
                     traceback.print_exc()
-                if self.perf is not None:
-                    self.perf.inc("op_dequeued")
             finally:
                 if holds_slot:
                     slots.release()

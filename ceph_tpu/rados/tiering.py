@@ -6,7 +6,7 @@ src/common/bloom_filter.hpp; the tiering agent loop in
 src/osd/PrimaryLogPG.cc agent_work/agent_choose_mode; promotion throttles
 osd_tier_promote_max_objects_sec/_bytes_sec in OSD::promote_throttle).
 Here the "fast tier" is not a second pool but the device itself:
-PlanarShardStore HBM residents serve reads with zero shard reads and zero
+PagedResidentStore HBM residents serve reads with zero shard reads and zero
 decode, and this module supplies the POLICY for what deserves to stay
 resident — per-PG bloom-filter hit archives rotated on hit_set_period,
 a temperature estimator scored by which archived intervals contain an

@@ -909,172 +909,168 @@ def run_tier(args) -> int:
                 failures.append(
                     f"resident_bytes {store.resident_bytes} exceeds "
                     f"target {target_bytes} after settling")
-            # -- writeback legs (paged store only): put under
+            # -- writeback legs: put under
             # cache_mode=writeback -> dirty pages -> agent flush ->
             # evict -> re-read byte identity, with bounded dirty_pages
             # after settling as the failing gate
-            if hasattr(store, "dirty_items"):
-                await c.pool_set(pool, "cache_mode", "writeback")
-                for o in cluster.osds.values():
-                    # pool-opt propagation: poll each OSD's map
-                    for _ in range(100):
-                        p = (o.osdmap.pools.get(pool)
-                             if o.osdmap else None)
-                        if p is not None and (getattr(p, "opts", {})
-                                              or {}).get("cache_mode") \
-                                == "writeback":
-                            break
-                        await asyncio.sleep(0.02)
-                wb_blobs = {}
-                saw_dirty = False
-                pinned = {}
-                for i in range(6):
-                    oid = f"wb{i}"
-                    wb_blobs[oid] = _os.urandom(120_000 + 1024 * i)
-                    await c.put(pool, oid, wb_blobs[oid])
-                    # sample dirt per put: the agent (0.1s cadence,
-                    # 0.3s flush age) may legitimately drain earlier
-                    # puts' pages while later puts run on a slow host —
-                    # an after-the-loop snapshot would false-fail
-                    saw_dirty = saw_dirty or store.dirty_pages > 0
-                    for key, info, _g, _s in store.dirty_items():
-                        if info is not None:
-                            pinned[key] = info
-                pinned = sorted(pinned.items())
-                if not saw_dirty or not pinned:
-                    failures.append(
-                        "writeback puts left no dirty pages (writeback "
-                        "never engaged)")
-                for oid, want in wb_blobs.items():
-                    got = await c.get(pool, oid)
-                    if got != want:
-                        failures.append(
-                            f"writeback resident read mismatch on {oid}")
-                # agent settling: age-driven flush must bound dirty
+            await c.pool_set(pool, "cache_mode", "writeback")
+            for o in cluster.osds.values():
+                # pool-opt propagation: poll each OSD's map
                 for _ in range(100):
-                    if not store.has_dirty():
+                    p = (o.osdmap.pools.get(pool)
+                         if o.osdmap else None)
+                    if p is not None and (getattr(p, "opts", {})
+                                          or {}).get("cache_mode") \
+                            == "writeback":
                         break
-                    await asyncio.sleep(0.05)
-                if store.dirty_pages != 0:
+                    await asyncio.sleep(0.02)
+            wb_blobs = {}
+            saw_dirty = False
+            pinned = {}
+            for i in range(6):
+                oid = f"wb{i}"
+                wb_blobs[oid] = _os.urandom(120_000 + 1024 * i)
+                await c.put(pool, oid, wb_blobs[oid])
+                # sample dirt per put: the agent (0.1s cadence,
+                # 0.3s flush age) may legitimately drain earlier
+                # puts' pages while later puts run on a slow host —
+                # an after-the-loop snapshot would false-fail
+                saw_dirty = saw_dirty or store.dirty_pages > 0
+                for key, info, _g, _s in store.dirty_items():
+                    if info is not None:
+                        pinned[key] = info
+            pinned = sorted(pinned.items())
+            if not saw_dirty or not pinned:
+                failures.append(
+                    "writeback puts left no dirty pages (writeback "
+                    "never engaged)")
+            for oid, want in wb_blobs.items():
+                got = await c.get(pool, oid)
+                if got != want:
                     failures.append(
-                        f"dirty_pages {store.dirty_pages} not bounded "
-                        f"after agent settling (flush never drained)")
-                # the deferred local applies LANDED at their versions.
-                # A WritebackRecord pins its deferred local shards; a
-                # fast-ack CacheDirtyRecord defers the WHOLE k+m encode
-                # (the flush lands the installer's acting shards), and
-                # its ADOPTED copies on cache peers pin nothing locally.
-                for key, info in pinned:
-                    osd = cluster.osds.get(key[0])
-                    if osd is None:
-                        continue
-                    shards = getattr(info, "shards", None)
-                    if shards is None:
-                        if getattr(info, "primary", key[0]) != key[0]:
-                            continue  # adopted copy: owner destages
-                        p = osd.osdmap.pools[info.pool_id]
-                        acting = osd.osdmap.pg_to_acting(p, info.pg)
-                        shards = [s for s, o_id in enumerate(acting)
-                                  if o_id == key[0]]
-                    for shard in shards:
-                        got_s = osd._store_read(
-                            (info.pool_id, info.oid, shard))
-                        if got_s is None or got_s[1].version < info.version:
-                            failures.append(
-                                f"flush of {info.oid} shard {shard} on "
-                                f"osd.{key[0]} never reached the store")
-                # evict everything, then cold re-reads must serve the
-                # flushed bytes (flush-before-evict byte identity)
-                for oid in wb_blobs:
-                    drop_residents(oid)
-                for oid, want in wb_blobs.items():
-                    got = await c.get(pool, oid, fadvise="dontneed")
-                    if got != want:
+                        f"writeback resident read mismatch on {oid}")
+            # agent settling: age-driven flush must bound dirty
+            for _ in range(100):
+                if not store.has_dirty():
+                    break
+                await asyncio.sleep(0.05)
+            if store.dirty_pages != 0:
+                failures.append(
+                    f"dirty_pages {store.dirty_pages} not bounded "
+                    f"after agent settling (flush never drained)")
+            # the deferred local applies LANDED at their versions.
+            # A WritebackRecord pins its deferred local shards; a
+            # fast-ack CacheDirtyRecord defers the WHOLE k+m encode
+            # (the flush lands the installer's acting shards), and
+            # its ADOPTED copies on cache peers pin nothing locally.
+            for key, info in pinned:
+                osd = cluster.osds.get(key[0])
+                if osd is None:
+                    continue
+                shards = getattr(info, "shards", None)
+                if shards is None:
+                    if getattr(info, "primary", key[0]) != key[0]:
+                        continue  # adopted copy: owner destages
+                    p = osd.osdmap.pools[info.pool_id]
+                    acting = osd.osdmap.pg_to_acting(p, info.pg)
+                    shards = [s for s, o_id in enumerate(acting)
+                              if o_id == key[0]]
+                for shard in shards:
+                    got_s = osd._store_read(
+                        (info.pool_id, info.oid, shard))
+                    if got_s is None or got_s[1].version < info.version:
                         failures.append(
-                            f"post-flush cold read mismatch on {oid}")
-                wb_perf = store.perf.dump()
-                print(f"tier writeback: {len(wb_blobs)} puts, "
-                      f"flushes={wb_perf.get('flushes', 0)} "
-                      f"flush_bytes={wb_perf.get('flush_bytes', 0)} "
-                      f"dirty_pages={store.dirty_pages} "
-                      f"page_evictions={wb_perf.get('page_evictions', 0)} "
-                      f"frag_saved={wb_perf.get('frag_saved_bytes', 0)}")
-                # -- kill-primary-before-flush (the fast-ack durability
-                # gate): a put acked at the CACHE quorum, its primary
-                # SIGKILLed before any flush, must survive — a replica
-                # replays its raw dirty copy to the PG's new primary,
-                # who destages it; the cold re-read is byte-identical
-                for o in cluster.osds.values():
-                    o.conf["osd_tier_flush_age"] = 120.0  # park dirt
-                kp_blob = _os.urandom(130_000)
-                await c.put(pool, "wbkill", kp_blob)
-                owned = [(k, info) for k, info, _g, _s
-                         in store.dirty_items()
-                         if info is not None and info.oid == "wbkill"
-                         and getattr(info, "primary", None) == k[0]]
-                if not owned:
+                            f"flush of {info.oid} shard {shard} on "
+                            f"osd.{key[0]} never reached the store")
+            # evict everything, then cold re-reads must serve the
+            # flushed bytes (flush-before-evict byte identity)
+            for oid in wb_blobs:
+                drop_residents(oid)
+            for oid, want in wb_blobs.items():
+                got = await c.get(pool, oid, fadvise="dontneed")
+                if got != want:
                     failures.append(
-                        "kill-primary leg: fast-ack put left no owned "
-                        "raw dirty record (fast ack never engaged)")
-                else:
-                    (kp_key, kp_rec), = owned
-                    adopters = [p for p in kp_rec.peers
-                                if p != kp_key[0]
-                                and store.is_dirty((p, pool, "wbkill"))]
-                    if not adopters:
-                        failures.append(
-                            "kill-primary leg: no cache peer adopted "
-                            "the dirty copy before the kill")
-                    await cluster.kill_osd(kp_key[0])
-                    got_kp = None
-                    for _ in range(300):
-                        await asyncio.sleep(0.1)
-                        try:
-                            got_kp = await c.get(pool, "wbkill")
-                            if got_kp == kp_blob:
-                                break
-                        except Exception:
-                            continue
-                    if got_kp != kp_blob:
-                        failures.append(
-                            "kill-primary leg: acked write lost after "
-                            "primary SIGKILL before flush")
-                    # the survivors' replay destaged and released the
-                    # adopted copies
-                    for _ in range(100):
-                        if not any(info is not None
-                                   and info.oid == "wbkill"
-                                   for _k, info, _g, _s
-                                   in store.dirty_items()):
-                            break
-                        await asyncio.sleep(0.1)
-                    if any(info is not None and info.oid == "wbkill"
-                           for _k, info, _g, _s in store.dirty_items()):
-                        failures.append(
-                            "kill-primary leg: adopted dirty copies "
-                            "never destaged after the failover")
-                    drop_residents("wbkill")
-                    try:
-                        cold_kp = await c.get(pool, "wbkill",
-                                              fadvise="dontneed")
-                        if cold_kp != kp_blob:
-                            failures.append(
-                                "kill-primary leg: cold re-read after "
-                                "replay is not byte-identical")
-                    except Exception as e:
-                        failures.append(
-                            f"kill-primary leg: cold re-read failed: {e}")
-                    tier_enc = sum(o.tier_perf.get("flush_encodes")
-                                   for o in cluster.osds.values())
-                    print(f"tier kill-primary: victim osd.{kp_key[0]}, "
-                          f"{len(adopters)} adopter(s), replay "
-                          f"flush_encodes={tier_enc}, re-read "
-                          f"{'ok' if got_kp == kp_blob else 'LOST'}")
-                for o in cluster.osds.values():
-                    o.conf["osd_tier_flush_age"] = 0.3
+                        f"post-flush cold read mismatch on {oid}")
+            wb_perf = store.perf.dump()
+            print(f"tier writeback: {len(wb_blobs)} puts, "
+                  f"flushes={wb_perf.get('flushes', 0)} "
+                  f"flush_bytes={wb_perf.get('flush_bytes', 0)} "
+                  f"dirty_pages={store.dirty_pages} "
+                  f"page_evictions={wb_perf.get('page_evictions', 0)} "
+                  f"frag_saved={wb_perf.get('frag_saved_bytes', 0)}")
+            # -- kill-primary-before-flush (the fast-ack durability
+            # gate): a put acked at the CACHE quorum, its primary
+            # SIGKILLed before any flush, must survive — a replica
+            # replays its raw dirty copy to the PG's new primary,
+            # who destages it; the cold re-read is byte-identical
+            for o in cluster.osds.values():
+                o.conf["osd_tier_flush_age"] = 120.0  # park dirt
+            kp_blob = _os.urandom(130_000)
+            await c.put(pool, "wbkill", kp_blob)
+            owned = [(k, info) for k, info, _g, _s
+                     in store.dirty_items()
+                     if info is not None and info.oid == "wbkill"
+                     and getattr(info, "primary", None) == k[0]]
+            if not owned:
+                failures.append(
+                    "kill-primary leg: fast-ack put left no owned "
+                    "raw dirty record (fast ack never engaged)")
             else:
-                print("tier writeback: SKIPPED (monolithic resident "
-                      "store forced; writeback needs the pagestore)")
+                (kp_key, kp_rec), = owned
+                adopters = [p for p in kp_rec.peers
+                            if p != kp_key[0]
+                            and store.is_dirty((p, pool, "wbkill"))]
+                if not adopters:
+                    failures.append(
+                        "kill-primary leg: no cache peer adopted "
+                        "the dirty copy before the kill")
+                await cluster.kill_osd(kp_key[0])
+                got_kp = None
+                for _ in range(300):
+                    await asyncio.sleep(0.1)
+                    try:
+                        got_kp = await c.get(pool, "wbkill")
+                        if got_kp == kp_blob:
+                            break
+                    except Exception:
+                        continue
+                if got_kp != kp_blob:
+                    failures.append(
+                        "kill-primary leg: acked write lost after "
+                        "primary SIGKILL before flush")
+                # the survivors' replay destaged and released the
+                # adopted copies
+                for _ in range(100):
+                    if not any(info is not None
+                               and info.oid == "wbkill"
+                               for _k, info, _g, _s
+                               in store.dirty_items()):
+                        break
+                    await asyncio.sleep(0.1)
+                if any(info is not None and info.oid == "wbkill"
+                       for _k, info, _g, _s in store.dirty_items()):
+                    failures.append(
+                        "kill-primary leg: adopted dirty copies "
+                        "never destaged after the failover")
+                drop_residents("wbkill")
+                try:
+                    cold_kp = await c.get(pool, "wbkill",
+                                          fadvise="dontneed")
+                    if cold_kp != kp_blob:
+                        failures.append(
+                            "kill-primary leg: cold re-read after "
+                            "replay is not byte-identical")
+                except Exception as e:
+                    failures.append(
+                        f"kill-primary leg: cold re-read failed: {e}")
+                tier_enc = sum(o.tier_perf.get("flush_encodes")
+                               for o in cluster.osds.values())
+                print(f"tier kill-primary: victim osd.{kp_key[0]}, "
+                      f"{len(adopters)} adopter(s), replay "
+                      f"flush_encodes={tier_enc}, re-read "
+                      f"{'ok' if got_kp == kp_blob else 'LOST'}")
+            for o in cluster.osds.values():
+                o.conf["osd_tier_flush_age"] = 0.3
             tier = {}
             for o in cluster.osds.values():
                 for k, v in o.tier_perf.dump().items():
@@ -1940,8 +1936,8 @@ def run_device_parity_child(args) -> int:
                 "plugin": "jerasure", "technique": "reed_sol_van",
                 "k": "2", "m": "1"})
             store = osdmod.shared_planar_store()
-            if store is None or not hasattr(store, "dirty_items"):
-                print("FAIL paged planar store did not engage",
+            if store is None:
+                print("FAIL the resident store did not engage",
                       file=sys.stderr)
                 return 1
             await c.pool_set(pool, "cache_mode", "writeback")
@@ -1989,8 +1985,7 @@ def run_device_parity_child(args) -> int:
                     failures.append(
                         f"post-flush cold read mismatch on {oid}")
                 digests[oid] = hashlib.sha256(got).hexdigest()
-            if hasattr(store, "page_stats"):
-                snap = store.page_stats()
+            snap = store.page_stats()
             await c.stop()
         finally:
             await cluster.stop()

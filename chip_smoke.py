@@ -260,13 +260,11 @@ async def phase_cluster(seed: int, n_objects: int, object_bytes: int,
                 break
             if p != pg and victim in c.osdmap.pg_to_acting(pinfo, p):
                 degraded += by_pg[p][:n_degraded - len(degraded)]
-        decodes0 = queue.perf.get("submit_packedbit") \
-            + queue.perf.get("submit_packedbit_planes")
+        decodes0 = queue.perf.get("submit_packedbit")
         await cluster.kill_osd(victim)
         degraded_s = await _bounded(IN_FLIGHT, [
             (lambda o=o: verify(o)) for o in degraded])
-        decodes = queue.perf.get("submit_packedbit") \
-            + queue.perf.get("submit_packedbit_planes") - decodes0
+        decodes = queue.perf.get("submit_packedbit") - decodes0
         marks["degraded"] = meter.snapshot()
 
         async def outed():
@@ -374,9 +372,10 @@ def phase_multichip(seed: int, n_devices: int, object_bytes: int) -> None:
     from ceph_tpu.ec.gf import gf
     from ceph_tpu.ec.registry import registry
     from ceph_tpu.parallel.mesh import MeshDispatcher
-    from ceph_tpu.parallel.service import BatchingQueue, PlanarShardStore
+    from ceph_tpu.parallel.service import BatchingQueue
     from ceph_tpu.rados.ecutil import (StripeInfo, decode_object,
                                        planar_encode_async, planar_rows)
+    from ceph_tpu.rados.pagestore import PagedResidentStore
 
     codec = registry.factory("jerasure", "", {
         "plugin": "jerasure", "technique": "reed_sol_van",
@@ -395,7 +394,7 @@ def phase_multichip(seed: int, n_devices: int, object_bytes: int) -> None:
 
     def step(mesh):
         queue = BatchingQueue(max_delay=0.02, mesh=mesh if mesh else False)
-        store = PlanarShardStore(capacity_bytes=64 << 20, queue=queue)
+        store = PagedResidentStore(capacity_bytes=64 << 20, queue=queue)
         try:
             async def encodes():
                 return await asyncio.gather(*(

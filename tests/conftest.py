@@ -31,3 +31,26 @@ def pytest_configure(config):
     # (e.g. the sanitized native rebuild) don't warn as unknown
     config.addinivalue_line(
         "markers", "slow: excluded from the tier-1 fast suite")
+
+
+import pytest  # noqa: E402
+
+
+def _drop_shared_ec_service() -> None:
+    from ceph_tpu.rados import osd as osdmod
+
+    q, osdmod._BATCH_QUEUE, osdmod._PLANAR_STORE = osdmod._BATCH_QUEUE, None, None
+    if q is not None:
+        q.close()
+
+
+@pytest.fixture()
+def force_batching(monkeypatch):
+    """Engage the device EC service on the CPU backend, where the queue
+    normally stays off, and give the test the process-wide queue and
+    resident store to itself: whatever an earlier test left there is
+    closed on the way in, and what this test made on the way out."""
+    monkeypatch.setenv("CEPH_TPU_FORCE_BATCH", "1")
+    _drop_shared_ec_service()
+    yield
+    _drop_shared_ec_service()

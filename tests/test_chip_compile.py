@@ -13,7 +13,7 @@ back), and everything compiles in the test's own process.
 
 What the compiler said when these were written (PR 23; compiler seconds
 on the sandbox CPU and bytes from memory_analysis — not device metrics):
-every program is accepted, both Pallas kernels included.  The packed-bit
+every program is accepted.  The packed-bit
 boundary does NOT fuse: temp memory is 60x the data bytes for the encode
 apply (1.0 GB per 16 MiB dispatch), 160x for a decode matrix, 66x for
 to_packedbit and 220x for from_packedbit (3.7 GB for 11 rows x 2 MiB) —
@@ -196,26 +196,6 @@ def test_slab_gather(one_chip, rows):
     pw = (64 << 10) // 4
     _compile(gather_fn(pw, rows), _spec((256, pw), np.uint32, one_chip),
              _spec((rows,), np.int32, one_chip))
-
-
-def test_pallas_apply_bytes_w8(one_chip):
-    """Off by default (CEPH_TPU_PALLAS); interpret mode in the other tests.
-    TILE_B 32768: [k*8, TILE_B] int32 intermediates against scoped VMEM."""
-    from ceph_tpu.ops.pallas_gf2 import pallas_apply_bytes_w8
-
-    compiled = _compile(pallas_apply_bytes_w8,
-                        _spec((M * 8, K * 8), np.int8, one_chip),
-                        _spec((K, 2 << 20), np.uint8, one_chip), out_rows=M)
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_pallas_gf2_matmul(one_chip):
-    from ceph_tpu.ops.pallas_gf2 import pallas_gf2_matmul
-
-    compiled = _compile(pallas_gf2_matmul,
-                        _spec((M * 8, K * 8), np.int8, one_chip),
-                        _spec((K * 8, 2 << 20), np.int8, one_chip))
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("width", [

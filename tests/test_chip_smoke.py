@@ -86,15 +86,6 @@ def test_chip_smoke_without_a_chip_fails_with_ok_false():
     assert last["ok"] is False and "no TPU" in last["error"]
 
 
-def test_bench_without_a_chip_exits_nonzero(monkeypatch, capsys):
-    import bench
-
-    monkeypatch.setattr(os, "execve", lambda *a: pytest.fail(
-        "bench.py re-executed itself"))
-    assert bench.main() == 2
-    assert capsys.readouterr().out == ""  # no result line
-
-
 # -- start-up failures are loud ------------------------------------------------
 
 
@@ -139,17 +130,19 @@ def _bm_rows():
 
 def test_lane_first_launch_failure_is_logged_and_counted(
         monkeypatch, caplog):
+    from ceph_tpu.parallel import service
     from ceph_tpu.parallel.service import BatchingQueue, _cpu_apply_request
 
-    def refuse(self, g):
+    def refuse(g, batch):
         raise RuntimeError("RESOURCE_EXHAUSTED: out of HBM")
 
-    monkeypatch.setattr(BatchingQueue, "_launch_packedbit", refuse)
+    monkeypatch.setitem(service.LANES, "packedbit",
+                        service.LANES["packedbit"]._replace(device=refuse))
     bm, rows = _bm_rows()
     q = BatchingQueue(max_delay=60.0, mesh=False)
     try:
         with caplog.at_level(logging.ERROR, logger="ceph_tpu.ec.batch"):
-            fut = q.submit_packedbit(bm, rows, 8, 2)
+            fut = q.submit(bm, rows, 8, 2, "packedbit")
             q.flush()
             got = fut.result(timeout=60)
         assert np.array_equal(
@@ -182,12 +175,12 @@ def test_watchdog_does_not_count_compile_seconds(compile_s, trips):
     bm, rows = _bm_rows()
     q = BatchingQueue(max_delay=60.0, mesh=False)
     try:
-        q.submit_packedbit(bm, rows, 8, 2)
+        q.submit(bm, rows, 8, 2, "packedbit")
         q.flush()  # warm: the real compile happens here
         q._compiles = _FakeMeter(compile_s)
         q.dispatch_timeout = 0.05
         q.inject_dispatch_delay = 0.1  # every dispatch now "takes" > timeout
-        fut = q.submit_packedbit(bm, rows, 8, 2)
+        fut = q.submit(bm, rows, 8, 2, "packedbit")
         q.flush()
         fut.result(timeout=60)
         assert q.perf.get("breaker_trip") == trips
@@ -212,7 +205,7 @@ def test_mesh_layout_failure_is_counted_not_swallowed(caplog):
     q = BatchingQueue(max_delay=60.0, mesh=SickMesh())
     try:
         with caplog.at_level(logging.ERROR, logger="ceph_tpu.ec.batch"):
-            fut = q.submit_packedbit(bm, rows, 8, 2)
+            fut = q.submit(bm, rows, 8, 2, "packedbit")
             q.flush()
             got = fut.result(timeout=60)
         assert np.array_equal(

@@ -1,4 +1,4 @@
-"""EC data-plane observability (ISSUE 2): the `ec_tpu` / `planar_store` /
+"""EC data-plane observability (ISSUE 2): the `ec_tpu` / `pagestore` /
 `gf2_sched` / `wire` counter sets, the dispatch timeline admin command,
 trace-span propagation through the batching queue, the `perf reset`
 command, and the mgr prometheus histogram rendering."""
@@ -14,7 +14,8 @@ from ceph_tpu.common.perf_counters import (PerfCountersBuilder,
                                            PerfCountersCollection)
 from ceph_tpu.common.tracing import Tracer
 from ceph_tpu.ec.matrices import matrix_to_bitmatrix, vandermonde_coding_matrix
-from ceph_tpu.parallel.service import LANES, BatchingQueue, PlanarShardStore
+from ceph_tpu.parallel.service import LANES, BatchingQueue
+from ceph_tpu.rados.pagestore import PagedResidentStore
 
 K, M, W = 2, 1, 8
 B = 1024  # pow2, multiple of 32: every lane accepts it unmodified
@@ -83,31 +84,22 @@ class TestPerfCounterPrimitives:
 
 class TestEcTpuCounters:
     def test_every_lane_counts_submits_bytes_and_dispatches(self):
-        import jax.numpy as jnp
-
         q = BatchingQueue(max_delay=60.0)  # worker idle: flush() drives
         try:
-            bm8, bmu = _bm(np.int8), _bm(np.uint8)
             rows = _rows()
-            planes_i8 = jnp.zeros((K * W, B), jnp.int8)
-            planes_u32 = jnp.zeros((K * W, B // 32), jnp.uint32)
-            futs = [
-                q.submit(bm8, rows, W, M),
-                q.submit_planar(bm8, planes_i8, W, M),
-                q.submit_resident(bm8, rows, W, M),
-                q.submit_packedbit(bmu, rows, W, M),
-                q.submit_packedbit_resident(bmu, rows, W, M),
-                q.submit_packedbit_planes(bmu, planes_u32, W, M),
-                # the packet-layout form: B is whole W*16-byte blocks
-                q.submit_packetrows(bmu, rows, W, 16, M),
-            ]
+            # B is whole W*16-byte blocks for the packet-layout lane
+            futs = [q.submit(_bm(np.int8 if lane in ("packed", "resident")
+                                 else np.uint8),
+                             rows, W, M, lane,
+                             16 if lane == "packetrows" else 0)
+                    for lane in LANES]
             q.flush()
             for f in futs:
                 f.result(timeout=120)
             d = q.perf.dump()
             for lane in LANES:
                 assert d[f"submit_{lane}"] == 1, lane
-                # every lane counts PACKED-equivalent bytes: K rows x B
+                # every lane counts PACKED bytes: K rows x B
                 assert d[f"bytes_{lane}"] == K * B, lane
             assert d["submit"] == len(LANES)
             # distinct (matrix-dtype, lane) groups: a dispatch a lane
@@ -264,12 +256,12 @@ class TestScheduleCacheCounters:
         assert gf2.SCHED_PERF.get("entries") <= 2
 
 
-# -- planar_store: residency stats -------------------------------------------
+# -- pagestore: residency stats ----------------------------------------------
 
 
-class TestPlanarStoreCounters:
+class TestResidentStoreCounters:
     def test_admit_hit_miss_and_boundary_latencies(self):
-        store = PlanarShardStore(capacity_bytes=64 << 20)
+        store = PagedResidentStore(capacity_bytes=64 << 20)
         rows = _rows()
         store.admit("obj1", rows, w=W)
         assert store.read("obj1") is not None
@@ -284,7 +276,8 @@ class TestPlanarStoreCounters:
     def test_eviction_updates_counters_and_gauges(self):
         rows = _rows()
         planar_sz = K * W * B  # int8 planes: w bytes per packed byte
-        store = PlanarShardStore(capacity_bytes=planar_sz + planar_sz // 2)
+        store = PagedResidentStore(
+            capacity_bytes=planar_sz + planar_sz // 2, page_bytes=4096)
         store.admit("a", rows, w=W)
         store.admit("b", rows, w=W)  # over budget: "a" evicts
         d = store.perf.dump()
@@ -420,17 +413,12 @@ class TestPrometheusHistograms:
 
 class TestOsdPerfDumpEndToEnd:
     def test_perf_dump_carries_pipeline_sets_after_ec_traffic(
-            self, monkeypatch):
+            self, monkeypatch, force_batching):
         import os
 
-        from ceph_tpu.rados import osd as osdmod
         from ceph_tpu.rados.vstart import Cluster
 
-        # the queue normally stays off on the CPU backend: force it, as
-        # test_batching does, so the device tier engages
-        monkeypatch.setenv("CEPH_TPU_FORCE_BATCH", "1")
         monkeypatch.setenv("CEPH_TPU_BATCH_DELAY", "0.05")
-        monkeypatch.setattr(osdmod, "_BATCH_QUEUE", None)
 
         async def go():
             cluster = Cluster(n_osds=3, conf={
@@ -445,6 +433,13 @@ class TestOsdPerfDumpEndToEnd:
                 await c.put(pool, "o", blob)
                 assert await c.get(pool, "o") == blob
                 osd = next(iter(cluster.osds.values()))
+                # the queue's worker fans a dispatch out BEFORE it books
+                # it (dispatch_dev, group_size, the timeline, in that
+                # order), so the put can return first: wait for the books
+                for _ in range(200):
+                    if osd._ec_queue.timeline:
+                        break
+                    await asyncio.sleep(0.05)
                 d = osd.ctx.perf.dump()
                 # ONE dump carries the whole pipeline: queue lanes,
                 # schedule cache, residency store, wire split
@@ -453,10 +448,7 @@ class TestOsdPerfDumpEndToEnd:
                 assert d["ec_tpu"]["dispatch_dev"]["avgcount"] > 0
                 assert "gf2_sched" in d
                 assert "ec_plugin" in d
-                # residency set name tracks the store flavor: the paged
-                # store (default) registers `pagestore`, the monolithic
-                # r10 store `planar_store`
-                assert "pagestore" in d or "planar_store" in d
+                assert "pagestore" in d
                 wire = d["wire"]
                 assert wire["rx_msgs"] + wire["local_msgs"] > 0
                 tl = osd.ctx.asok.execute("dump_ec_batch_timeline")
@@ -466,43 +458,3 @@ class TestOsdPerfDumpEndToEnd:
                 await cluster.stop()
 
         asyncio.run(asyncio.wait_for(go(), 120))
-        q = osdmod._BATCH_QUEUE
-        if q is not None:
-            q.close()
-        monkeypatch.setattr(osdmod, "_BATCH_QUEUE", None)
-
-
-# -- bench snapshot helpers ---------------------------------------------------
-
-
-class TestBenchSnapshots:
-    def test_queue_perf_snapshot_carries_lane_breakdown(self):
-        import bench
-
-        q = BatchingQueue(max_delay=60.0)
-        try:
-            f = q.submit(_bm(), _rows(), W, M)
-            q.flush()
-            f.result(timeout=120)
-            snap = bench.queue_perf_snapshot(q)
-            assert snap["submits"] == 1 and snap["dispatches"] == 1
-            assert snap["lane_submits"] == {"packed": 1}
-            assert snap["lane_bytes"] == {"packed": K * B}
-            assert snap["flush_causes"]["forced"] == 1
-            assert snap["dispatch_dev_s_avg"] >= 0
-        finally:
-            q.close()
-
-    def test_sched_perf_snapshot_fields(self):
-        import bench
-
-        from ceph_tpu.ops.gf2 import gf2_xor_packed
-
-        rng = np.random.default_rng(5)
-        bm = rng.integers(0, 2, size=(8, 8), dtype=np.uint8) | np.eye(
-            8, dtype=np.uint8)
-        gf2_xor_packed(bm, np.zeros((8, 2), dtype=np.uint32))
-        snap = bench.sched_perf_snapshot()
-        assert snap["compiles"] >= 1
-        assert 0.0 <= snap["hit_rate"] <= 1.0
-        assert snap["xor_ops_final"] <= snap["xor_ops_naive"]

@@ -195,7 +195,7 @@ class TestPackedbitQueuePaths:
     XOR-schedule queue lanes, byte-identical to the CPU path, with the
     int8-plane lanes behind the CEPH_TPU_PACKEDBIT=0 kill switch."""
 
-    def test_encode_plan_routes_packedbit(self, monkeypatch):
+    def test_encode_plan_routes_packedbit(self):
         from ceph_tpu.parallel.service import BatchingQueue
 
         c = codec(k=4, m=2)
@@ -203,21 +203,17 @@ class TestPackedbitQueuePaths:
         data = os.urandom(16 * 4 * 2048 - 100)
         want = batched_encode(c, s, data, queue=None)
         q = BatchingQueue(max_delay=0.001)
-        calls = []
-        real = q.submit_packedbit
-        monkeypatch.setattr(
-            q, "submit_packedbit",
-            lambda *a, **kw: (calls.append(1), real(*a, **kw))[1])
         try:
             got = batched_encode(c, s, data, queue=q)
-            assert calls, "encode plan did not ride the packed-bit lane"
+            assert q.perf.get("submit_packedbit") == 1, \
+                "encode plan did not ride the packed-bit lane"
             assert q.dispatches == 1
         finally:
             q.close()
         for a, b in zip(got, want):
             assert np.array_equal(np.asarray(a), np.asarray(b))
 
-    def test_decode_plan_routes_packedbit(self, monkeypatch):
+    def test_decode_plan_routes_packedbit(self):
         from ceph_tpu.parallel.service import BatchingQueue
         from ceph_tpu.rados.ecutil import decode_object
 
@@ -228,14 +224,10 @@ class TestPackedbitQueuePaths:
         avail = {i: np.asarray(b) for i, b in enumerate(blobs)
                  if i not in (1, 3)}
         q = BatchingQueue(max_delay=0.001)
-        calls = []
-        real = q.submit_packedbit
-        monkeypatch.setattr(
-            q, "submit_packedbit",
-            lambda *a, **kw: (calls.append(1), real(*a, **kw))[1])
         try:
             got = decode_object(c, s, dict(avail), len(data), queue=q)
-            assert calls, "decode plan did not ride the packed-bit lane"
+            assert q.perf.get("submit_packedbit") == 1, \
+                "decode plan did not ride the packed-bit lane"
         finally:
             q.close()
         assert got == data
@@ -249,18 +241,17 @@ class TestPackedbitQueuePaths:
         data = os.urandom(4 * 4 * 2048)
         want = batched_encode(c, s, data, queue=None)
         q = BatchingQueue(max_delay=0.001)
-        monkeypatch.setattr(
-            q, "submit_packedbit",
-            lambda *a, **kw: (_ for _ in ()).throw(
-                AssertionError("packed-bit lane used while disabled")))
         try:
             got = batched_encode(c, s, data, queue=q)
+            assert q.perf.get("submit_packedbit") == 0, \
+                "packed-bit lane used while disabled"
+            assert q.perf.get("submit_packed") == 1
         finally:
             q.close()
         for a, b in zip(got, want):
             assert np.array_equal(np.asarray(a), np.asarray(b))
 
-    def test_w16_stays_off_the_packedbit_lane(self, monkeypatch):
+    def test_w16_stays_off_the_packedbit_lane(self):
         """Packed-bit is the w=8 byte-layout lane; w=16 pools must keep
         riding the int8-plane lanes."""
         from ceph_tpu.parallel.service import BatchingQueue
@@ -272,12 +263,11 @@ class TestPackedbitQueuePaths:
         data = os.urandom(4 * 3 * 2048)
         want = batched_encode(c, s, data, queue=None)
         q = BatchingQueue(max_delay=0.001)
-        monkeypatch.setattr(
-            q, "submit_packedbit",
-            lambda *a, **kw: (_ for _ in ()).throw(
-                AssertionError("w=16 dispatched on the packed-bit lane")))
         try:
             got = batched_encode(c, s, data, queue=q)
+            assert q.perf.get("submit_packedbit") == 0, \
+                "w=16 dispatched on the packed-bit lane"
+            assert q.perf.get("submit_packed") == 1
         finally:
             q.close()
         for a, b in zip(got, want):

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from ceph_tpu.parallel.mesh import MeshDispatcher
-from ceph_tpu.parallel.service import BatchingQueue, PlanarShardStore
+from ceph_tpu.parallel.service import BatchingQueue
 from ceph_tpu.rados import osd as osdmod
 from ceph_tpu.rados.vstart import Cluster
 
@@ -53,36 +53,39 @@ class TestMeshDispatcher:
 
 class TestQueueOnMesh:
     def test_all_lanes_dispatch_sharded_and_stay_byte_exact(self):
-        from ceph_tpu.ec.gf import gf
-        from ceph_tpu.ec.matrices import (matrix_to_bitmatrix,
-                                          vandermonde_coding_matrix)
-        from ceph_tpu.ops.gf2 import from_planar, to_planar
+        from tests.test_lanes import LANE_CASES, check_lane_result, lane_case
 
-        k, m, w = 4, 2, 8
-        mat = vandermonde_coding_matrix(k, m, w)
-        bm = matrix_to_bitmatrix(mat, w).astype(np.int8)
-        fgf = gf(w)
         mesh = _mesh()
         q = BatchingQueue(max_delay=0.05, mesh=mesh)
         try:
-            rng = np.random.default_rng(2)
-            d = rng.integers(0, 256, (k, 4096), dtype=np.uint8)
-            # packed lane
-            out = q.submit(bm, d, w, m).result(timeout=120)
-            assert np.array_equal(out, fgf.matmul(mat, d))
-            # resident lane
-            parity, all_bits = q.submit_resident(bm, d, w, m).result(
-                timeout=120)
-            assert np.array_equal(parity, fgf.matmul(mat, d))
-            # planar lane chains on the sharded resident bits
-            data_bits = all_bits[:k * w]
-            pb = q.submit_planar(bm, data_bits, w, m).result(timeout=120)
-            assert np.array_equal(np.asarray(from_planar(pb, w, m)),
-                                  fgf.matmul(mat, d))
-            assert q.sharded_dispatches >= 3, q.sharded_dispatches
-            assert mesh.shard_puts >= 3
+            # 1152 columns: no multiple of the 8-device grid's pad unit
+            for kind, w in LANE_CASES:
+                codec, item = lane_case(kind, w, cols=1152)
+                check_lane_result(codec, item,
+                                  q.submit(*item).result(timeout=120))
+            assert q.sharded_dispatches == q.dispatches == len(LANE_CASES)
+            assert mesh.shard_puts == len(LANE_CASES)
+            assert q.perf.get("mesh_shard_failed") == 0
+            assert q.perf.get("breaker_fallback") == 0
         finally:
             q.close()
+
+    def test_multichip_phase_step_on_the_virtual_mesh(self, capsys):
+        """chip_smoke.py --multichip's phase as the four-chip host runs
+        it — encodes on the mesh, residents through the paged store,
+        3-erasure decodes, the repair re-encode, the same again on one
+        device — with CPU devices for chips.  Its checks raise."""
+        import json
+
+        import chip_smoke
+
+        chip_smoke.phase_multichip(seed=3, n_devices=4,
+                                   object_bytes=64 << 10)
+        line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert line["phase"] == "multichip" and line["ok"] is True
+        assert line["mesh"]["sharded_dispatch"] == line["mesh"]["dispatch"] > 0
+        assert line["mesh"]["resident_device_sets"] == [4] * 4
+        assert line["single"]["sharded_dispatch"] == 0
 
 
 @pytest.fixture()

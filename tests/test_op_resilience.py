@@ -406,7 +406,7 @@ class TestDispatchBreaker:
             def boom(_g):
                 raise RuntimeError("device dead")
 
-            q._launch_packed = boom
+            q._launch = boom
             got = q.submit(bm, regions, 8, 2).result(timeout=60)
             assert np.array_equal(got, expect)
             assert q.perf.get("breaker_trip") == 1

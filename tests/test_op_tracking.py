@@ -667,21 +667,7 @@ class TestMgrHealthMetrics:
         assert 'check="MON_UNREACHABLE"' in t
 
 
-class TestBenchPercentiles:
-    def test_hist_percentiles(self):
-        import bench
-
-        buckets = [0] * 32
-        buckets[3] = 50   # values 4..7
-        buckets[10] = 49  # values 512..1023
-        buckets[20] = 1   # the tail
-        got = bench._hist_percentiles([buckets])
-        assert got["count"] == 100
-        assert got["p50_us"] == (1 << 3) - 1
-        assert got["p99_us"] == (1 << 10) - 1
-        assert got["p999_us"] == (1 << 20) - 1
-        assert bench._hist_percentiles([None])["count"] == 0
-
+class TestWireHistograms:
     def test_wire_io_histograms_populate(self):
         from ceph_tpu.rados.messenger import _build_wire_perf
 

@@ -105,15 +105,15 @@ def test_lane_stores_the_references_shards(queue, name, size):
 
 def test_lane_choice_follows_the_bit_layout():
     _, codec, sinfo = make("cauchy_good-k10m4")
-    assert ecutil._lane(codec, sinfo) == ("packetrows", 16)
+    assert ecutil._lane(codec, sinfo) == ("packetrows", np.uint8, 16)
     assert not ecutil.planar_eligible(codec)  # nothing of it is resident
     rs = registry.factory("tpu", "", {"plugin": "tpu", "k": "4", "m": "2",
                                       "technique": "reed_sol_van"})
-    assert ecutil._lane(rs, StripeInfo(4, 4 * 4096)) == ("packedbit",)
+    assert ecutil._lane(rs, StripeInfo(4, 4 * 4096)) == ("packedbit", np.uint8)
     rs16 = registry.factory("tpu", "", {"plugin": "tpu", "k": "4", "m": "2",
                                         "technique": "reed_sol_van",
                                         "w": "16"})
-    assert ecutil._lane(rs16, StripeInfo(4, 4 * 4096)) == ("packed",)
+    assert ecutil._lane(rs16, StripeInfo(4, 4 * 4096)) == ("packed", np.int8)
     # chunks that are not whole blocks: no lane, the codec's own path
     assert ecutil._lane(codec, StripeInfo(10, 10 * 4100)) is None
 
@@ -122,7 +122,7 @@ def test_ragged_width_is_refused_at_submission(queue):
     _, codec, _ = make("liberation-w7")
     bm = np.asarray(codec.bitmatrix, dtype=np.uint8)
     with pytest.raises(ValueError, match="whole w\\*packetsize"):
-        queue.submit_packetrows(bm, np.zeros((5, 4096), np.uint8), 7, 16, 2)
+        queue.submit(bm, np.zeros((5, 4096), np.uint8), 7, 2, "packetrows", 16)
 
 
 # -- (ii) coalescing -------------------------------------------------------------

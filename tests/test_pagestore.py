@@ -42,13 +42,17 @@ def _rows(n, B, seed=0):
 
 
 class TestPageTable:
-    def test_ragged_tail_roundtrip_non_page_multiple(self):
+    @pytest.mark.parametrize("widths", [
+        (3000, 4096, 4128, 12256),
+        # not whole u32 words either: admit pads to words, read trims
+        (100, 1024, 1000)])
+    def test_ragged_tail_roundtrip_non_page_multiple(self, widths):
         """Satellite pin: residents whose byte size is NOT a multiple of
         the page size round-trip byte-identically through the ragged
         last page, at several awkward widths."""
         store = PagedResidentStore(capacity_bytes=1 << 20,
                                    page_bytes=4096)
-        for i, B in enumerate((3000, 4096, 4128, 12256)):
+        for i, B in enumerate(widths):
             rows = _rows(3, B, seed=i)
             store.admit(f"o{i}", rows, w=8, layout="packedbit")
             got = store.read(f"o{i}")

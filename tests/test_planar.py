@@ -1,25 +1,25 @@
 """Bit-planar HBM residency (VERDICT r03 #1): shards stay on the device
-as int8 bit-planes across encode -> decode -> recovery, and the
-pack/unpack boundary is paid once at the host boundary — the measured
-~1.6x win recorded in ceph_tpu/ops/gf2.py.  These tests pin the planar
-paths byte-identical to the packed/CPU oracle paths and exercise the
-residency lifecycle (admission, version gating, eviction, invalidation)
-through both the service layer and the OSD data path."""
+as bit-planes (u32 plane words for w=8, int8 planes otherwise) across
+encode -> decode -> recovery, and the pack/unpack boundary is paid once
+at the host boundary.  These tests pin the planar paths byte-identical
+to the packed/CPU oracle paths and exercise the residency lifecycle
+(admission, version gating, eviction, invalidation) through both the
+resident store and the OSD data path."""
 
 import asyncio
 import os
 import time
 
 import numpy as np
-import pytest
 
 from ceph_tpu.ec.registry import registry
 from ceph_tpu.ops.gf2 import from_planar, gf2_matmul, to_planar
-from ceph_tpu.parallel.service import BatchingQueue, PlanarShardStore
+from ceph_tpu.parallel.service import BatchingQueue
 from ceph_tpu.rados import osd as osdmod
 from ceph_tpu.rados.ecutil import (StripeInfo, batched_encode,
                                    planar_encode_async, planar_object_bytes,
                                    planar_rows)
+from ceph_tpu.rados.pagestore import PagedResidentStore
 from ceph_tpu.rados.vstart import Cluster
 
 PROFILE = {"plugin": "jerasure", "technique": "reed_sol_van",
@@ -62,55 +62,31 @@ class TestPlanarBoundary:
 
 
 class TestPlanarQueueLane:
-    def test_submit_planar_coalesces_and_stays_device_side(self):
+    def test_packed_and_packedbit_groups_do_not_mix(self):
+        """One matrix and one buffer on two lanes are two dispatches
+        (the layouts differ), and the same bytes."""
         from ceph_tpu.ec.matrices import (matrix_to_bitmatrix,
                                           vandermonde_coding_matrix)
 
         k, m, w = 4, 2, 8
-        mat = vandermonde_coding_matrix(k, m, w)
-        bm = matrix_to_bitmatrix(mat, w).astype(np.int8)
-        rng = np.random.default_rng(7)
-        q = BatchingQueue(max_delay=0.05)
-        try:
-            datas = [rng.integers(0, 256, (k, 2048), dtype=np.uint8)
-                     for _ in range(6)]
-            bits = [to_planar(d, w) for d in datas]
-            before = q.dispatches
-            futs = [q.submit_planar(bm, b, w, m) for b in bits]
-            outs = [f.result(timeout=60) for f in futs]
-            # all six rode ONE matmul dispatch
-            assert q.dispatches - before == 1
-            from ceph_tpu.ec.gf import gf
-
-            for d, ob in zip(datas, outs):
-                packed = np.asarray(from_planar(ob, w, m))
-                assert np.array_equal(packed, gf(w).matmul(mat, d))
-        finally:
-            q.close()
-
-    def test_planar_and_packed_groups_do_not_mix(self):
-        from ceph_tpu.ec.matrices import (matrix_to_bitmatrix,
-                                          vandermonde_coding_matrix)
-
-        k, m, w = 4, 2, 8
-        bm = matrix_to_bitmatrix(
-            vandermonde_coding_matrix(k, m, w), w).astype(np.int8)
+        bm = matrix_to_bitmatrix(vandermonde_coding_matrix(k, m, w), w)
         rng = np.random.default_rng(9)
-        q = BatchingQueue(max_delay=0.05)
+        q = BatchingQueue(max_delay=60.0)
         try:
             d = rng.integers(0, 256, (k, 1024), dtype=np.uint8)
-            f1 = q.submit(bm, d, w, m)
-            f2 = q.submit_planar(bm, to_planar(d, w), w, m)
-            packed = f1.result(timeout=60)
-            planar = np.asarray(from_planar(f2.result(timeout=60), w, m))
-            assert np.array_equal(packed, planar)
+            f1 = q.submit(bm.astype(np.int8), d, w, m)
+            f2 = q.submit(bm.astype(np.uint8), d, w, m, "packedbit")
+            q.flush()
+            assert np.array_equal(f1.result(timeout=60),
+                                  f2.result(timeout=60))
+            assert q.dispatches == 2
         finally:
             q.close()
 
 
-class TestPlanarShardStore:
+class TestResidentStoreBoundary:
     def test_admit_read_roundtrip_and_stats(self):
-        store = PlanarShardStore(capacity_bytes=64 << 20)
+        store = PagedResidentStore(capacity_bytes=64 << 20, page_bytes=4096)
         rng = np.random.default_rng(11)
         rows = rng.integers(0, 256, (11, 4096), dtype=np.uint8)
         store.admit("obj1", rows)
@@ -124,7 +100,8 @@ class TestPlanarShardStore:
     def test_lru_eviction_under_byte_budget(self):
         rows = np.zeros((4, 1024), dtype=np.uint8)
         planar_sz = rows.size * 8
-        store = PlanarShardStore(capacity_bytes=planar_sz * 2)
+        store = PagedResidentStore(capacity_bytes=planar_sz * 2,
+                                   page_bytes=4096)
         store.admit("a", rows)
         store.admit("b", rows)
         assert "a" in store and "b" in store
@@ -133,37 +110,6 @@ class TestPlanarShardStore:
         assert "b" not in store and "a" in store and "c" in store
         assert store.evictions == 1
         assert store.resident_bytes <= store.capacity_bytes
-
-    def test_apply_chains_matmul_on_residents(self):
-        """encode -> reconstruct chain entirely on planar residents:
-        parity from a generator, then a lost data row from an inverted
-        signature matrix, byte-identical to the CPU oracle."""
-        from ceph_tpu.ec.gf import gf
-        from ceph_tpu.ec.matrices import (matrix_to_bitmatrix,
-                                          vandermonde_coding_matrix)
-
-        k, m, w = 4, 2, 8
-        fgf = gf(w)
-        mat = vandermonde_coding_matrix(k, m, w)
-        bm = matrix_to_bitmatrix(mat, w).astype(np.int8)
-        rng = np.random.default_rng(13)
-        data = rng.integers(0, 256, (k, 2048), dtype=np.uint8)
-        store = PlanarShardStore(capacity_bytes=64 << 20)
-        store.admit("d", data)
-        # encode on the resident: parity stays planar under its own key
-        store.apply("d", bm, m, out_key="p")
-        parity = store.read("p")
-        assert np.array_equal(parity, fgf.matmul(mat, data))
-        # lose data row 2: reconstruct from rows [0,1,3] + parity row 0
-        full = np.vstack([np.eye(k, dtype=np.int64), mat])
-        chosen = [0, 1, 3, k]  # survivors
-        inv = fgf.invert_matrix(full[chosen])
-        inv_bm = matrix_to_bitmatrix(inv[2:3], w).astype(np.int8)
-        surv = np.vstack([data[[0, 1, 3]], parity[0:1]])
-        store.admit("surv", surv)
-        rec_bits = store.apply("surv", inv_bm, 1)
-        rec = np.asarray(from_planar(rec_bits, w, 1))
-        assert np.array_equal(rec[0], data[2])
 
 
 class TestPlanarEcutil:
@@ -184,7 +130,7 @@ class TestPlanarEcutil:
             for a, b in zip(want, blobs):
                 assert np.array_equal(np.asarray(a), np.asarray(b)), size
             # the resident packs back to exactly the shard rows
-            store = PlanarShardStore(capacity_bytes=256 << 20)
+            store = PagedResidentStore(capacity_bytes=256 << 20)
             store.put_planar("k", all_bits, n_rows=n_rows,
                              meta=(7, n_cols))
             rows = planar_rows(store, "k", 7)
@@ -221,7 +167,7 @@ class TestPlanarEcutil:
         assert w == 16
         for a, b in zip(want, blobs):
             assert np.array_equal(np.asarray(a), np.asarray(b))
-        store = PlanarShardStore(capacity_bytes=256 << 20)
+        store = PagedResidentStore(capacity_bytes=256 << 20)
         store.put_planar("k16", all_bits, w=w, n_rows=n_rows,
                          meta=(3, n_cols))
         rows = planar_rows(store, "k16", 3)
@@ -231,11 +177,6 @@ class TestPlanarEcutil:
         obj = planar_object_bytes(store, "k16", 3, 4,
                                   sinfo.chunk_size, len(data))
         assert obj == data
-
-
-@pytest.fixture()
-def force_batching(monkeypatch):
-    monkeypatch.setenv("CEPH_TPU_FORCE_BATCH", "1")
 
 
 class TestOsdPlanarResidency:
@@ -449,67 +390,25 @@ class TestTransferOverlap:
 
 
 class TestPackedbitResidency:
-    """The packed-bit (u32-word) resident layout — the production lane
-    promoted in round 6 (ceph_tpu/ops/gf2.py lane-promotion writeup):
-    1/8th the int8-plane HBM footprint, static XOR schedules per matrix,
-    byte-identical to every oracle path."""
-
-    def test_admit_read_roundtrip_nonword_width(self):
-        """Arbitrary (non-multiple-of-32) chunk widths round-trip: the
-        admit boundary pads to whole u32 words, read trims back."""
-        rng = np.random.default_rng(41)
-        store = PlanarShardStore(capacity_bytes=8 << 20)
-        for B in (100, 1024, 1000):
-            rows = rng.integers(0, 256, size=(4, B), dtype=np.uint8)
-            store.admit(("pb", B), rows, w=8, layout="packedbit")
-            back = store.read(("pb", B))
-            assert back is not None and back.shape == (4, B)
-            assert np.array_equal(back, rows), B
+    """The packed-bit (u32-word) resident layout — the production layout
+    for w=8: 1/8th the int8-plane HBM footprint, static XOR schedules per
+    matrix, byte-identical to every oracle path.  (Its round trip at
+    widths that are not whole words is tests/test_pagestore.py's
+    ragged-tail case.)"""
 
     def test_packedbit_resident_is_8x_denser(self):
-        """The promotion's capacity win: a u32 resident accounts 1 byte
+        """The layout's capacity win: a u32 resident accounts 1 byte
         per data byte where int8 planes account 8 — same budget, 8x the
         objects."""
         rng = np.random.default_rng(43)
         rows = rng.integers(0, 256, size=(4, 1024), dtype=np.uint8)
-        s_planes = PlanarShardStore(capacity_bytes=8 << 20)
-        s_packed = PlanarShardStore(capacity_bytes=8 << 20)
+        s_planes = PagedResidentStore(capacity_bytes=8 << 20,
+                                      page_bytes=4096)
+        s_packed = PagedResidentStore(capacity_bytes=8 << 20,
+                                      page_bytes=4096)
         s_planes.admit("x", rows, w=8, layout="planes")
         s_packed.admit("x", rows, w=8, layout="packedbit")
         assert s_planes.resident_bytes == 8 * s_packed.resident_bytes
-
-    def test_apply_runs_schedule_on_packedbit_residents(self):
-        """store.apply over a u32 resident routes through the XOR
-        schedule (queue lane when attached, direct otherwise) and
-        reconstructs byte-exactly."""
-        from ceph_tpu.ec.gf import gf
-        from ceph_tpu.ec.matrices import (matrix_to_bitmatrix,
-                                          vandermonde_coding_matrix)
-        from ceph_tpu.ops.gf2 import from_packedbit
-
-        k, m, w = 4, 2, 8
-        f = gf(w)
-        mat = vandermonde_coding_matrix(k, m, w)
-        rng = np.random.default_rng(47)
-        data = rng.integers(0, 256, size=(k, 1024), dtype=np.uint8)
-        parity = f.matmul(mat, data)
-        full = np.vstack([np.eye(k, dtype=np.int64), mat])
-        chosen = [c for c in range(k + m) if c != 2][:k]
-        inv = f.invert_matrix(full[chosen])
-        inv_bm = matrix_to_bitmatrix(inv[2:3], w).astype(np.uint8)
-        surv = np.vstack([data[[0, 1, 3]], parity[0:1]])
-        for queue in (None, BatchingQueue(max_delay=0.001)):
-            try:
-                store = PlanarShardStore(capacity_bytes=8 << 20,
-                                         queue=queue)
-                store.admit("surv", surv, w=8, layout="packedbit")
-                rec_words = store.apply("surv", inv_bm, 1)
-                assert np.asarray(rec_words).dtype == np.uint32
-                rec = np.asarray(from_packedbit(np.asarray(rec_words), 1))
-                assert np.array_equal(rec[0], data[2])
-            finally:
-                if queue is not None:
-                    queue.close()
 
     def test_planar_encode_async_installs_packedbit_residents(self):
         """The w=8 write path admits u32 residents end-to-end: encode
@@ -535,7 +434,7 @@ class TestPackedbitResidency:
             "w=8 write path must install packed-bit residents"
         for a, b in zip(want, blobs):
             assert np.array_equal(np.asarray(a), np.asarray(b))
-        store = PlanarShardStore(capacity_bytes=256 << 20)
+        store = PagedResidentStore(capacity_bytes=256 << 20)
         store.put_planar("k", all_bits, n_rows=n_rows, meta=(7, n_cols))
         rows = planar_rows(store, "k", 7)
         assert rows is not None
@@ -544,33 +443,3 @@ class TestPackedbitResidency:
         obj = planar_object_bytes(store, "k", 7, 8, sinfo.chunk_size,
                                   len(data))
         assert obj == data
-
-    def test_packedbit_planes_lane_coalesces(self):
-        """Concurrent schedule-only dispatches over resident u32 planes
-        coalesce into one device call (the packed-bit mirror of the
-        planar lane) and the results stay resident (no host bounce)."""
-        from ceph_tpu.ec.gf import gf
-        from ceph_tpu.ec.matrices import (matrix_to_bitmatrix,
-                                          vandermonde_coding_matrix)
-        from ceph_tpu.ops.gf2 import from_packedbit, to_packedbit
-
-        k, m, w = 4, 2, 8
-        mat = vandermonde_coding_matrix(k, m, w)
-        bm = matrix_to_bitmatrix(mat, w).astype(np.uint8)
-        rng = np.random.default_rng(53)
-        q = BatchingQueue(max_pending_bytes=1 << 30, max_delay=60)
-        try:
-            reqs = [rng.integers(0, 256, size=(k, 2048), dtype=np.uint8)
-                    for _ in range(8)]
-            planes = [to_packedbit(r) for r in reqs]
-            futs = [q.submit_packedbit_planes(bm, p, w, m)
-                    for p in planes]
-            assert not any(f.done() for f in futs)
-            q.flush()
-            outs = [f.result(timeout=30) for f in futs]
-            assert q.dispatches == 1
-        finally:
-            q.close()
-        for r, out in zip(reqs, outs):
-            got = np.asarray(from_packedbit(np.asarray(out), m))
-            assert np.array_equal(got, gf(w).matmul(mat, r))

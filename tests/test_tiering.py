@@ -1,7 +1,7 @@
 """Cache-tier subsystem (ceph_tpu/rados/tiering.py + the OSD hooks):
 BloomHitSet statistics and binary encoding, HitSetArchive rotation /
 expiry / temperature, the promotion throttle, coldest-first eviction
-candidates, the PlanarShardStore agent/LRU race discipline, and the
+candidates, the resident store's agent/LRU race discipline, and the
 end-to-end promote -> resident-hit -> evict lifecycle — including the
 byte-identity gate (every resident-hit read equals the cold-path read)
 and bounded residency under a hot set larger than target_max_bytes."""
@@ -12,7 +12,7 @@ import os
 import numpy as np
 import pytest
 
-from ceph_tpu.parallel.service import PlanarShardStore
+from ceph_tpu.rados.pagestore import PagedResidentStore
 from ceph_tpu.rados import osd as osdmod
 from ceph_tpu.rados.tiering import (BloomHitSet, HitSetArchive,
                                     PromoteThrottle, build_tier_perf,
@@ -286,12 +286,13 @@ class TestEvictionCandidates:
         assert eviction_candidates([("a", 1)], lambda k: 0.0, 0) == []
 
 
-# -- PlanarShardStore agent discipline ---------------------------------------
+# -- resident store agent discipline -----------------------------------------
 
 
 class TestStoreAgentRace:
     def _store_with(self, keys, capacity=1 << 30):
-        store = PlanarShardStore(capacity_bytes=capacity)
+        # one 2 KiB page a resident: [8, 64] u32 plane words
+        store = PagedResidentStore(capacity_bytes=capacity, page_bytes=2048)
         for k in keys:
             store.put_planar(k, np.zeros((8, 64), dtype=np.uint32),
                              w=8, n_rows=8, meta=(1, 64, 64))
@@ -366,14 +367,14 @@ class TestStoreAgentRace:
         dropping an entry returns its bytes."""
         store = self._store_with(["a", "b"], capacity=10_000)
         store.memo_put("a", 1, b"x" * 6_000)
-        assert store.memo_bytes == 6_000
+        assert store.memo_bytes == 6_144  # charged in whole pages
         # over budget: refused, accounting unchanged
         store.memo_put("b", 1, b"y" * 6_000)
         assert store.memo_get("b", 1) is None
-        assert store.memo_bytes == 6_000
+        assert store.memo_bytes == 6_144
         # replacement returns the old bytes first
         store.memo_put("a", 2, b"z" * 2_000)
-        assert store.memo_bytes == 2_000
+        assert store.memo_bytes == 2_048
         store.drop("a")
         assert store.memo_bytes == 0
 

@@ -1,6 +1,6 @@
 """plugin=tpu tests: byte-equality vs the jerasure CPU oracle (the repo's
 non-regression contract, BASELINE.md), exhaustive-erasure decode through the
-device path, Pallas kernel in interpreter mode, CPU fallback semantics, and
+device path, CPU fallback semantics, and
 the stripe-batching queue."""
 
 import numpy as np
@@ -91,33 +91,6 @@ def test_tpu_cpu_fallback(monkeypatch):
         assert np.array_equal(enc[c], ej[c])
 
 
-def test_pallas_kernel_interpret():
-    """The fused Pallas kernel (interpreter mode) matches the CPU oracle."""
-    from ceph_tpu.ec.gf import gf
-    from ceph_tpu.ec.matrices import matrix_to_bitmatrix, vandermonde_coding_matrix
-    from ceph_tpu.ops.pallas_gf2 import TILE_B, pallas_apply_bytes_w8
-
-    k, m = 8, 3
-    mat = vandermonde_coding_matrix(k, m, 8)
-    bm = matrix_to_bitmatrix(mat, 8)
-    rng = np.random.default_rng(0)
-    data = rng.integers(0, 256, size=(k, TILE_B * 2), dtype=np.uint8)
-    out = np.asarray(pallas_apply_bytes_w8(bm, data, m, interpret=True))
-    want = gf(8).matmul(mat, data)
-    assert np.array_equal(out, want)
-
-
-def test_pallas_gf2_matmul_interpret():
-    from ceph_tpu.ops.pallas_gf2 import pallas_gf2_matmul
-
-    rng = np.random.default_rng(1)
-    M = rng.integers(0, 2, size=(16, 32), dtype=np.int8)
-    bits = rng.integers(0, 2, size=(32, 2048), dtype=np.int8)
-    out = np.asarray(pallas_gf2_matmul(M, bits, interpret=True))
-    want = (M.astype(np.int64) @ bits.astype(np.int64)) % 2
-    assert np.array_equal(out, want.astype(np.int8))
-
-
 def test_batching_queue():
     """Many small encodes -> few device dispatches, identical bytes."""
     from ceph_tpu.ec.matrices import matrix_to_bitmatrix, vandermonde_coding_matrix
@@ -127,7 +100,7 @@ def test_batching_queue():
     k, m = 4, 2
     mat = vandermonde_coding_matrix(k, m, 8)
     bm = matrix_to_bitmatrix(mat, 8)
-    q = BatchingQueue(max_pending_bytes=1 << 30, max_delay=60, use_pallas=False)
+    q = BatchingQueue(max_pending_bytes=1 << 30, max_delay=60)
     rng = np.random.default_rng(2)
     reqs = [rng.integers(0, 256, size=(k, 4096), dtype=np.uint8) for _ in range(32)]
     futs = [q.submit(bm, r, 8, m) for r in reqs]
@@ -145,7 +118,7 @@ def test_batching_queue_delay_flush():
     from ceph_tpu.parallel.service import BatchingQueue
 
     bm = matrix_to_bitmatrix(vandermonde_coding_matrix(2, 1, 8), 8)
-    q = BatchingQueue(max_delay=0.01, use_pallas=False)
+    q = BatchingQueue(max_delay=0.01)
     fut = q.submit(bm, np.zeros((2, 1024), dtype=np.uint8), 8, 1)
     # generous timeout: under full-suite load the worker's first dispatch
     # can sit behind a slow jit compile; the assertion is that the flush
@@ -155,30 +128,10 @@ def test_batching_queue_delay_flush():
     q.close()
 
 
-def test_pallas_small_batch_regression():
-    """B smaller than / not a multiple of TILE_B must not return unwritten
-    output (code-review regression: empty grid truncation)."""
-    from ceph_tpu.ec.gf import gf
-    from ceph_tpu.ec.matrices import matrix_to_bitmatrix, vandermonde_coding_matrix
-    from ceph_tpu.ops.pallas_gf2 import TILE_B, pallas_apply_bytes_w8, pallas_gf2_matmul
-
-    mat = vandermonde_coding_matrix(4, 2, 8)
-    bm = matrix_to_bitmatrix(mat, 8)
-    rng = np.random.default_rng(7)
-    for B in [256, TILE_B - 128, TILE_B + 512]:
-        data = rng.integers(0, 256, size=(4, B), dtype=np.uint8)
-        out = np.asarray(pallas_apply_bytes_w8(bm, data, 2, interpret=True))
-        assert np.array_equal(out, gf(8).matmul(mat, data)), f"B={B}"
-    M = rng.integers(0, 2, size=(8, 16), dtype=np.int8)
-    bits = rng.integers(0, 2, size=(16, TILE_B + 100), dtype=np.int8)
-    out = np.asarray(pallas_gf2_matmul(M, bits, interpret=True))
-    assert np.array_equal(out, ((M.astype(np.int64) @ bits.astype(np.int64)) % 2).astype(np.int8))
-
-
 def test_batching_queue_closed_submit():
     from ceph_tpu.parallel.service import BatchingQueue
 
-    q = BatchingQueue(use_pallas=False)
+    q = BatchingQueue()
     q.close()
     with pytest.raises(RuntimeError):
         q.submit(np.ones((8, 16), np.uint8), np.zeros((2, 64), np.uint8), 8, 1)
