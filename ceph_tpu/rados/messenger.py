@@ -164,10 +164,16 @@ def _build_wire_perf() -> PerfCounters:
       tx_framing           longrunavg  encode + frame-build seconds per send
       tx_io                longrunavg  socket write + drain seconds per
                                        write (messages, acks, replays)
-      rx_io                longrunavg  payload read seconds per frame
-                                       (clock starts AFTER the header
-                                       lands, so idle wait between
-                                       messages never pollutes it)
+      rx_io                longrunavg  payload read seconds per frame,
+                                       waits included (a FrameReceiver:
+                                       per burst, from the read that
+                                       brought a front to its frames
+                                       stashed)
+      rx_framed            u64         frames a FrameReceiver completed
+      rx_inline_acks       u64         of those, acks applied in place
+      rx_copied_bytes      u64         body bytes copied head ->
+                                       destination; over rx_bytes: what
+                                       did NOT land in place
       rx_framing           longrunavg  decode_message seconds per dispatch
       local_msgs           u64         colocated-fastpath handoffs (no
                                        framing or socket at all)
@@ -198,14 +204,15 @@ def _build_wire_perf() -> PerfCounters:
     per message): sum(tx_io)/tx_msgs is the per-message socket cost and
     drops as flush windows batch more frames.
 
-    tx_io and rx_io INCLUDE AWAITS (the drain; every readexactly of the
-    payload): on a busy loop they measure how long the connection waited
+    tx_io and rx_io INCLUDE WAITS (the drain; a readexactly chain's
+    awaits, or on a FrameReceiver the loop turns between a body's
+    reads): on a busy loop they measure how long the connection waited
     for the loop, not what the messenger did — 209 s of rx_io in a 30 s
-    window is parked readers (PERF.md, PR 26).  The messenger's own time
-    is the `loop` set's `self_messenger` (common/tracing.py): the
-    sections here (encode_frame with the blob's crc, sock_write, rx_drain
-    with its crc_verify, decode) plus asyncio's transport reads and
-    writes."""
+    window was parked readers (PERF.md, PR 26).  The messenger's own
+    time is the `loop` set's `self_messenger` (common/tracing.py): the
+    sections here (encode_frame with the blob's crc, sock_write,
+    rx_frame with its crc_verify, decode) plus asyncio's
+    transport reads and writes."""
     b = PerfCountersBuilder("wire")
     b.add_u64_counter("tx_msgs", "messages sent")
     b.add_u64_counter("tx_bytes", "frame bytes sent")
@@ -214,6 +221,10 @@ def _build_wire_perf() -> PerfCounters:
     b.add_time_avg("tx_framing", "encode + frame-build seconds per send")
     b.add_time_avg("tx_io", "socket write + drain seconds per flush window")
     b.add_time_avg("rx_io", "payload read seconds per frame (post-header)")
+    b.add_u64_counter("rx_framed", "frames the receiver completed")
+    b.add_u64_counter("rx_inline_acks", "acks applied where they arrived")
+    b.add_u64_counter("rx_copied_bytes",
+                      "bytes copied after the kernel delivered them")
     b.add_time_avg("rx_framing", "decode seconds per dispatched message")
     b.add_u64_counter("local_msgs", "colocated-fastpath handoffs")
     b.add_u64_counter("tx_flushes", "outbox flush windows written")
@@ -250,7 +261,7 @@ def _build_wire_perf() -> PerfCounters:
                       "(whole-window writev, batch blob crc)")
     b.add_u64_counter("native_rx_calls",
                       "released-GIL wirepath calls on the rx side "
-                      "(burst crc verify, fused copy+crc, scatter)")
+                      "(a burst's crc verify, a landed body's)")
     b.add_u64_counter("native_bytes",
                       "bytes touched by native wirepath passes (each "
                       "pass counts: a byte crc-verified then scattered "
@@ -289,6 +300,7 @@ _HDR = struct.Struct("<IHHBIQ")  # len, type, version, flags, crc, seq
 
 # blob-frame payload prefix: pickled length + blob checksum
 _BLOB_PFX = struct.Struct("<II")
+_ACK_SEQ = struct.Struct("<Q")  # an ACK_TYPE frame's payload
 
 FLAG_COMPRESSED = 1
 # FLAG_FIXED: the payload (or the header part of a blob frame) is the
@@ -811,45 +823,61 @@ class LocalConnection:
 
 
 class FrameReceiver(asyncio.BufferedProtocol):
-    """Zero-copy receive path: installed over the connection's transport
-    (transport.set_protocol) AFTER the handshake, replacing the
-    StreamReader chain whose kernel-copy -> feed_data-extend ->
-    readexactly-slice pipeline double-copies every byte.  BufferedProtocol
-    hands the transport OUR buffer: while a readexactly() is pending, the
-    destination frame buffer itself is exposed, so payload bytes land
-    exactly once.  Write-side flow control keeps working by forwarding
-    pause_writing/resume_writing to the original stream protocol (the
-    StreamWriter's drain() still consults it)."""
+    """The one place where a plaintext-TCP connection's bytes turn into
+    frames: installed over the transport (transport.set_protocol) AFTER
+    the handshake, in place of the StreamReader chain.
 
-    # small backlog cap: bytes that arrive before a readexactly() is
-    # waiting land in _pending and must be COPIED out, so the transport
-    # pauses early — the single-copy path is bytes landing directly in
-    # the registered destination buffer.  The native wirepath inverts
-    # the tradeoff (Connection._rx_drain_native verifies AND lands the
-    # whole backlog below the GIL), so enable_fast_read sizes the
-    # backlog UP when that arm is live: a burst of bulk frames must fit
-    # complete frames in _pending for the batch drain to engage at all.
-    _LIMIT = 128 << 10
-    _NATIVE_LIMIT = 1 << 20
-    _NATIVE_SCRATCH = 256 << 10
+    Between frames the transport reads into a small HEAD buffer, and
+    buffer_updated parses, in place, every frame that is whole in it.  A
+    frame whose body is not all in hand (a blob; a payload larger than
+    the head) gets its destination right away (_blob_dest), the body
+    bytes that came with the head are copied there ONCE, and from then
+    on the kernel is handed dest[pos:]: the rest lands where it will
+    live.  A finished burst is crc-verified in one pass
+    (wirepy_verify_regions on the native arm, the connection's crc_fn
+    otherwise), received acks are applied on the spot, the other frames
+    go on conn._rx_stash as read_frame's tuples, and the serve task is
+    woken once.  A bad frame keeps a per-frame reader's order: the good
+    frames before it are delivered, then read_frame raises BadFrame.
 
-    def __init__(self, transport, stream_protocol, leftover: bytes = b"",
-                 limit: Optional[int] = None, scratch: Optional[int] = None):
+    The transport's read is the only way bytes enter (feed() replays
+    what the StreamReader held at the swap by the same two calls, before
+    any later read); a body begins landing a loop turn after its front.
+
+    Receive-side backpressure is by bytes: with more than one frame
+    landed and not popped (the body in flight counts by what has landed
+    of it), above _LIMIT the transport is paused, and resumed below
+    half; one frame alone never pauses its own connection.  So a
+    connection holds about two frames (or _LIMIT of small ones) outside
+    the dispatch throttle: one popped or stashed, one landing.  Write-side
+    flow control keeps working by forwarding pause_writing /
+    resume_writing to the original stream protocol."""
+
+    _HEAD = 16 << 10
+    _LIMIT = 1 << 20
+
+    def __init__(self, conn: "Connection", transport, stream_protocol):
+        self._conn = conn
+        self._perf = conn.messenger.perf
         self._transport = transport
         self._stream_protocol = stream_protocol
-        self._pending = bytearray(leftover)
-        self._off = 0  # consumed prefix of _pending (O(1) front-consume)
-        self._dest = None  # memoryview being filled by get_buffer
-        self._dest_pos = 0
-        if limit is not None:
-            self._LIMIT = limit  # instance override of the class cap
-        self._scratch = bytearray(scratch or (64 * 1024))
-        self._scratch_view = memoryview(self._scratch)
+        self._head = bytearray(self._HEAD)
+        self._head_mv = memoryview(self._head)
+        self._pos = self._fill = 0  # the head's parsed prefix, its bytes
+        self._t0 = 0.0  # when the read that brought the newest front came
+        # the frame in flight: its destination (a memoryview), how much
+        # of it has landed, (read_frame's tuple, the body's crc or 0,
+        # compressed) and the front's bytes as the wire had them
+        self._body = None
+        self._body_pos = 0
+        self._frame: Optional[tuple] = None
+        self._front = b""
+        self._held = 0  # bytes of stashed frames nobody popped yet
         self._waiter: Optional[asyncio.Future] = None
         self._eof = False
         self._exc: Optional[BaseException] = None
         self._read_paused = False
-        self._via_scratch = True  # last get_buffer handed out scratch
+        self._dead = False  # after a bad frame nothing more is read
         # the connection's CorkedWriter, when one took over the tx side:
         # connection_lost must fail its drain waiters too
         self.corked = None
@@ -857,60 +885,275 @@ class FrameReceiver(asyncio.BufferedProtocol):
     # -- protocol side -------------------------------------------------------
 
     def get_buffer(self, sizehint: int):
-        if self._dest is not None and self._dest_pos < len(self._dest):
-            remaining = len(self._dest) - self._dest_pos
-            if remaining >= len(self._scratch):
-                # bulk destination (blob body): single-copy direct fill
-                self._via_scratch = False
-                return self._dest[self._dest_pos:]
-            # SMALL destination (frame header, short payload): read
-            # GREEDILY through scratch so one recv drains everything the
-            # kernel has — the surplus (trailing frames of a burst)
-            # lands in _pending, which is what the serve loop's rx
-            # batching predicate looks at.  A per-dest-sized recv here
-            # would hand frames over one at a time (two syscalls per
-            # tiny frame) and batching would never see a second frame.
-            self._via_scratch = True
-            return self._scratch_view
-        self._via_scratch = True
-        return self._scratch_view
+        if self._body is not None:
+            return self._body[self._body_pos:]
+        return self._head_mv if self._dead else self._head_mv[self._fill:]
 
     def buffer_updated(self, nbytes: int) -> None:
-        if self._dest is not None and self._dest_pos < len(self._dest):
-            if not self._via_scratch:
-                self._dest_pos += nbytes
-                # wake the reader only when its buffer is COMPLETE: a
-                # wake per network chunk would round-trip the event loop
-                # hundreds of times per blob, each competing with every
-                # other ready callback in a busy daemon
-                if self._dest_pos >= len(self._dest):
-                    self._wake()
-                return
-            # greedy scratch read: split between the waiting dest and
-            # the pending backlog
-            remaining = len(self._dest) - self._dest_pos
-            take = min(nbytes, remaining)
-            self._dest[self._dest_pos:self._dest_pos + take] = \
-                self._scratch_view[:take]
-            self._dest_pos += take
-            if nbytes > take:
-                self._pending += self._scratch_view[take:nbytes]
-                self._check_limit()
-            if self._dest_pos >= len(self._dest):
-                self._wake()
+        if self._dead:
+            return
+        body = self._body
+        if body is None:
+            self._fill += nbytes
+            self._t0 = time.monotonic()
         else:
-            self._pending += self._scratch_view[:nbytes]
-            self._check_limit()
+            self._body_pos += nbytes
+            if self._body_pos < len(body):
+                if self._held:
+                    self._backpressure()
+                return
+        with tracing.section("messenger", "rx_frame"):
+            done, error, copied = \
+                self._parse() if body is None else self._finish_body()
+            self._deliver(done, error, copied)
+
+    def feed(self, data) -> None:
+        """Bytes that did not come through the transport (what the
+        StreamReader had buffered at the swap), by the transport's own
+        two calls."""
+        mv = memoryview(data)
+        while len(mv) and not self._dead:
+            buf = self.get_buffer(-1)
+            n = min(len(buf), len(mv))
+            buf[:n] = mv[:n]
+            self.buffer_updated(n)
+            mv = mv[n:]
+
+    def _blob_dest(self, type_id: int, flags: int, seq: int,
+                   payload: bytes, blob_len: int):
+        """Where a blob's bytes will live, as (the view to fill, what the
+        message gets): a lane fragment's slice of its group's assembly
+        buffer, an uninitialised array for the classes whose consumers
+        take a view (no memset pass over bytes the socket is about to
+        overwrite), else a bytearray."""
+        conn = self._conn
+        cls = _MSG_TYPES.get(type_id)
+        if cls is MLaneSegment and conn.lane_group is not None \
+                and (flags & FLAG_FIXED) and blob_len \
+                and not (seq and seq <= conn.in_seq):
+            # the in_seq guard keeps a REPLAYED duplicate (acked but
+            # re-sent across a lane revival) from re-creating reassembly
+            # state the serve loop is about to drop
+            try:
+                dest = conn.lane_group.frag_view(
+                    _unpack_fixed(cls, payload, None), blob_len)
+            except Exception:
+                dest = None
+            if dest is not None:
+                if dest.ndim != 1 or dest.itemsize != 1:
+                    dest = dest.cast("B")
+                return dest, dest
+        if getattr(cls, "BLOB_VIEW_OK", False):
+            dest = memoryview(np.empty(blob_len, dtype=np.uint8)).cast("B")
+            return dest, dest
+        blob = bytearray(blob_len)
+        return memoryview(blob), blob
+
+    def _parse(self) -> tuple:
+        """Every frame that is whole in the head, verified: (what to
+        deliver, in order: an int is a received ack's seq, a tuple is
+        read_frame's; the BadFrame that ends the stream, or None; body
+        bytes copied head -> destination).  A frame whose body is not
+        all here goes in flight; a partial front stays in the head."""
+        head, mv = self._head, self._head_mv
+        pos, fill = self._pos, self._fill
+        crc_on = self._conn.crc_enabled
+        # crc regions of the head, and for each the index in `done` and
+        # the type it belongs to
+        offs, lens, wants, owner = [], [], [], []
+        done: list = []
+        zipped: list = []
+        error: Optional[BaseException] = None
+        copied = 0
+        need = _HDR.size  # bytes from pos before the parser can go on
+        while fill - pos >= _HDR.size:
+            length, type_id, version, flags, crc, seq = \
+                _HDR.unpack_from(head, pos)
+            start = pos + _HDR.size
+            if flags & FLAG_BLOB:
+                need = _HDR.size + _BLOB_PFX.size
+                if fill - pos < need:
+                    break
+                plen, blob_crc = _BLOB_PFX.unpack_from(head, start)
+                if _BLOB_PFX.size + plen > length:
+                    # a corrupt plen would desync the stream
+                    error = BadFrame(f"bad blob prefix on type {type_id}")
+                    break
+                need += plen
+                if fill - pos < need:
+                    break
+                front = pos + need
+                payload = bytes(mv[front - plen:front])
+                blob_len = length - _BLOB_PFX.size - plen
+                if crc and crc_on:
+                    # one region covers prefix+pickled: crc32c over the
+                    # contiguous span == the chained tx-side crc
+                    offs.append(start)
+                    lens.append(front - start)
+                    wants.append(crc)
+                    owner.append((len(done), type_id))
+                dest, blob = self._blob_dest(type_id, flags, seq, payload,
+                                             blob_len)
+                have = min(fill - front, blob_len)
+                dest[:have] = mv[front:front + have]
+                copied += have
+                want = blob_crc if crc_on else 0
+                frame = (type_id, version, seq, payload, length, blob,
+                         bool(flags & FLAG_FIXED), bool(want))
+                if have < blob_len:
+                    self._body, self._body_pos = dest, have
+                    self._frame = (frame, want, 0)
+                    self._front = bytes(mv[pos:front])
+                    pos = fill
+                    break
+                if want:
+                    offs.append(front)
+                    lens.append(blob_len)
+                    wants.append(want)
+                    owner.append((len(done), type_id))
+                done.append(frame)
+                pos = front + blob_len
+            else:
+                need = _HDR.size + length
+                if need > len(head):
+                    # a payload the head cannot hold lands like a body
+                    dest = memoryview(bytearray(length))
+                    have = fill - start
+                    dest[:have] = mv[start:fill]
+                    copied += have
+                    self._body, self._body_pos = dest, have
+                    self._frame = ((type_id, version, seq, dest.obj, length,
+                                    None, bool(flags & FLAG_FIXED), False),
+                                   crc if crc_on else 0,
+                                   flags & FLAG_COMPRESSED)
+                    self._front = bytes(mv[pos:start])
+                    pos = fill
+                    break
+                if fill - pos < need:
+                    break
+                if crc and crc_on:
+                    offs.append(start)
+                    lens.append(length)
+                    wants.append(crc)
+                    owner.append((len(done), type_id))
+                if type_id == ACK_TYPE and length == _ACK_SEQ.size:
+                    done.append(_ACK_SEQ.unpack_from(head, start)[0])
+                else:
+                    if flags & FLAG_COMPRESSED:
+                        zipped.append(len(done))
+                    done.append((type_id, version, seq,
+                                 bytes(mv[start:start + length]), length,
+                                 None, bool(flags & FLAG_FIXED), False))
+                pos += need
+            need = _HDR.size
+        if offs:
+            bad = self._verify(head, offs, lens, wants)
+            if bad >= 0:
+                # the first bad region is the first bad frame: what is
+                # before it is delivered, nothing after it is looked at
+                idx, type_id = owner[bad]
+                del done[idx:]
+                error = BadFrame(f"crc mismatch on frame type {type_id}")
+        if error is not None:
+            return done, error, copied
+        for i in zipped:
+            f = done[i]
+            done[i] = f[:3] + (zlib.decompress(f[3]),) + f[4:]
+        if pos == fill:
+            pos = fill = 0
+        elif pos + need > len(head):
+            part = bytes(mv[pos:fill])
+            if need > len(head):  # a blob frame's front: rare, and kept
+                self._head = head = bytearray(need)
+                self._head_mv = memoryview(head)
+            head[:len(part)] = part
+            pos, fill = 0, len(part)
+        self._pos, self._fill = pos, fill
+        return done, None, copied
+
+    def _finish_body(self) -> tuple:
+        """The frame in flight has all its bytes: _parse's triple."""
+        (frame, want, compressed), body = self._frame, self._body
+        self._frame = self._body = None
+        if want and self._verify(body, [0], [len(body)], [want]) >= 0:
+            return [], BadFrame(("blob crc" if frame[5] is not None
+                                 else "crc") + " mismatch on frame type "
+                                + str(frame[0])), 0
+        if compressed:
+            frame = frame[:3] + (zlib.decompress(frame[3]),) + frame[4:]
+        return [frame], None, 0
+
+    def _verify(self, buf, offs: list, lens: list, wants: list) -> int:
+        """Index of the first region of `buf` whose crc is not the one
+        stated, or -1: one released-GIL call on the native arm."""
+        conn = self._conn
+        with tracing.section("messenger", "crc_verify"):
+            if conn.wp is not None and conn.crc_fn is checksum:
+                self._perf.inc("native_rx_calls")
+                self._perf.inc("native_bytes", sum(lens))
+                return conn.wp.wirepy_verify_regions(buf, offs, lens, wants)
+            mv = memoryview(buf)
+            for i, off in enumerate(offs):
+                if conn.crc_fn(mv[off:off + lens[i]]) != wants[i]:
+                    return i
+        return -1
+
+    def _deliver(self, done: list, error, copied: int) -> None:
+        conn, perf = self._conn, self._perf
+        stash = conn._rx_stash
+        acks = held = 0
+        for f in done:
+            if type(f) is int:
+                conn.handle_ack(f)
+                acks += 1
+            else:
+                stash.append(f)
+                held += _HDR.size + f[4]
+        if done:
+            self._held += held
+            perf.inc("rx_framed", len(done))
+            perf.inc("rx_bytes", held + acks * (_HDR.size + _ACK_SEQ.size))
+            if acks:
+                perf.inc("rx_inline_acks", acks)
+            # as on the other readers: from a frame's front in hand to
+            # its payload in hand, the loop turns between a body's reads
+            # included (the framer's own time is the section rx_frame)
+            dt = time.monotonic() - self._t0
+            perf.tinc("rx_io", dt)
+            perf.hinc("rx_io_us", dt * 1e6)
+        if copied:
+            perf.inc("rx_copied_bytes", copied)
+        if error is not None:
+            conn._rx_error = error
+            self._dead = True
+            self._body = self._frame = None
+            self._pause(True)
+        elif self._held:
+            self._backpressure()
+        if stash or error is not None:
             self._wake()
 
-    def _check_limit(self) -> None:
-        if len(self._pending) - self._off > self._LIMIT \
-                and not self._read_paused:
-            self._read_paused = True
-            try:
+    def _backpressure(self) -> None:
+        landed = self._held
+        frames = len(self._conn._rx_stash)
+        if self._body is not None:
+            landed += self._body_pos
+            frames += 1
+        if self._read_paused:
+            if frames <= 1 or landed < self._LIMIT // 2:
+                self._pause(False)
+        elif frames > 1 and landed > self._LIMIT:
+            self._pause(True)
+
+    def _pause(self, on: bool) -> None:
+        self._read_paused = on
+        try:
+            if on:
                 self._transport.pause_reading()
-            except Exception:
-                pass
+            else:
+                self._transport.resume_reading()
+        except Exception:
+            pass
 
     def eof_received(self):
         self._eof = True
@@ -945,87 +1188,45 @@ class FrameReceiver(asyncio.BufferedProtocol):
 
     # -- reader side ---------------------------------------------------------
 
-    async def readexactly(self, n: int, uninit: bool = False, into=None):
-        """Read n bytes.  With ``uninit=True`` the destination is an
-        UNINITIALIZED buffer (np.empty) returned as a memoryview:
-        bytearray(n) memsets n zero bytes the socket is about to
-        overwrite, a full extra pass over the data volume on blob
-        frames.  Only blob fields whose consumers are buffer-safe
-        (BLOB_VIEW_OK types: store/decode lanes) opt in — everything
-        else keeps bytearray semantics (concat, decode, mutation).
-        With ``into=`` the bytes land DIRECTLY in the caller's buffer
-        (the lane-fragment reassembly seam: a striped blob's segments
-        fill their slice of the assembly buffer with zero extra
-        passes); the buffer is returned."""
-        pend = self._pending
-        avail = len(pend) - self._off
-        if into is not None:
-            buf = into if isinstance(into, memoryview) \
-                else memoryview(into)
-            if buf.ndim != 1 or buf.itemsize != 1:
-                buf = buf.cast("B")
-            mv = buf
-            if avail >= n:
-                mv[:n] = pend[self._off:self._off + n]
-                self._consume(n)
-                return buf
-        elif avail >= n:
-            out = bytes(pend[self._off:self._off + n])
-            self._consume(n)
-            return out
-        elif uninit:
-            buf = memoryview(np.empty(n, dtype=np.uint8)).cast("B")
-            mv = buf
-        else:
-            buf = bytearray(n)
-            mv = memoryview(buf)
-        pos = avail
-        if pos:
-            mv[:pos] = pend[self._off:]
-            self._off = 0
-            pend.clear()
-            self._maybe_resume()
-        self._dest = mv
-        self._dest_pos = pos
+    async def wait(self) -> None:
+        """Park read_frame until a burst leaves something to pop; at the
+        stream's end raise what a readexactly would have (the frame in
+        flight goes with its transport: replay delivers it)."""
+        if self._eof:
+            if self._exc is not None and not isinstance(
+                    self._exc, (ConnectionError, OSError)):
+                raise self._exc
+            raise asyncio.IncompleteReadError(b"", None)
+        self._waiter = asyncio.get_running_loop().create_future()
         try:
-            while self._dest_pos < n:
-                if self._eof:
-                    if self._exc is not None and not isinstance(
-                            self._exc, (ConnectionError, OSError)):
-                        raise self._exc
-                    raise asyncio.IncompleteReadError(
-                        bytes(mv[:self._dest_pos]), n)
-                self._waiter = asyncio.get_running_loop().create_future()
-                try:
-                    await self._waiter
-                finally:
-                    self._waiter = None
+            await self._waiter
         finally:
-            self._dest = None
-        return buf
+            self._waiter = None
 
-    def _consume(self, n: int) -> None:
-        """Advance the consumed-prefix pointer; compact only when the
-        dead prefix dominates (amortized O(1) — a del-from-front per
-        read is an O(len) memmove that dominated profiles)."""
-        self._off += n
-        pend = self._pending
-        if self._off == len(pend):
-            self._off = 0
-            pend.clear()
-        elif self._off > 1 << 16 and self._off * 2 > len(pend):
-            del pend[:self._off]
-            self._off = 0
-        self._maybe_resume()
+    def popped(self, cost: int) -> None:  # read_frame took a frame
+        self._held -= _HDR.size + cost
+        if self._read_paused:
+            self._backpressure()
 
-    def _maybe_resume(self) -> None:
-        if self._read_paused \
-                and len(self._pending) - self._off < self._LIMIT // 2:
-            self._read_paused = False
-            try:
-                self._transport.resume_reading()
-            except Exception:
-                pass
+    def unframed(self) -> bytes:
+        """The bytes received that are not a frame yet, as the wire had
+        them: what the worker process a delegated socket goes to has to
+        start from."""
+        if self._body is None:
+            return bytes(self._head_mv[self._pos:self._fill])
+        return self._front + bytes(self._body[:self._body_pos])
+
+
+def _writelines(writer, segs) -> None:
+    """writer.writelines(segs), refused on a transport that is closing:
+    asyncio's transport.writelines (3.12) has no _conn_lost guard (its
+    write() has), so on a connection already lost it registers a writer
+    that outlives the socket, and the fd's next owner can never add its
+    reader: its handshake waits forever (ROADMAP D0(a))."""
+    t = getattr(writer, "transport", None)
+    if t is not None and t.is_closing():
+        raise ConnectionResetError("transport is closing")
+    writer.writelines(segs)
 
 
 class CorkedWriter:
@@ -1276,18 +1477,18 @@ class Connection:
         # together with crc_fn — a zlib-negotiated connection keeps the
         # python arm so frame bytes stay identical either way
         self.wp = messenger.wirepath
-        # frames pre-verified + pre-scattered by _rx_drain_native,
-        # awaiting read_frame pops (each entry is read_frame's tuple);
-        # _rx_error raises once the stash drains (a bad frame mid-burst
-        # fails the connection AFTER its valid predecessors dispatch)
+        # frames the FrameReceiver completed and verified, awaiting
+        # read_frame pops (each entry is read_frame's tuple); _rx_error
+        # raises once the stash drains (a bad frame mid-burst fails the
+        # connection AFTER its valid predecessors dispatch)
         self._rx_stash: Deque = collections.deque()
         self._rx_error: Optional[BaseException] = None
 
     def enable_fast_read(self) -> None:
-        """Swap the StreamReader for the zero-copy FrameReceiver when the
-        transport allows it (plaintext TCP; not already swapped).  Called
-        at serve-loop start — the handshake has fully drained its reads,
-        and any bytes the stream already buffered carry over."""
+        """Hand the transport's rx side to a FrameReceiver when the
+        transport allows it (plaintext TCP; not already swapped).
+        Called at serve-loop start — the handshake has fully drained its
+        reads, and what the stream already buffered is framed first."""
         r = self.reader
         if not isinstance(r, asyncio.StreamReader):
             return  # SecureStream (AES-GCM) or already a FrameReceiver
@@ -1295,29 +1496,20 @@ class Connection:
             transport = r._transport  # the stream pair shares it
             if transport is None:
                 return
-            proto = transport.get_protocol()
+            receiver = FrameReceiver(self, transport,
+                                     transport.get_protocol())
             leftover = bytes(r._buffer)
             r._buffer.clear()
-            if self.wp is not None and (self.crc_fn is checksum
-                                        or not self.crc_enabled):
-                # native rx drain (same predicate read_frame gates the
-                # drain on — a zlib-negotiated connection stays on the
-                # python arm and must keep the small backlog): complete
-                # frames must BUFFER for the burst verify+scatter to
-                # batch, and the backlog-copy penalty the small default
-                # guards against runs below the GIL on this arm
-                receiver = FrameReceiver(
-                    transport, proto, leftover,
-                    limit=FrameReceiver._NATIVE_LIMIT,
-                    scratch=FrameReceiver._NATIVE_SCRATCH)
-            else:
-                receiver = FrameReceiver(transport, proto, leftover)
             if r.at_eof():
                 receiver._eof = True  # FIN landed before the swap
+            if isinstance(self.writer, CorkedWriter):
+                # corked before this serve loop started, under the stream
+                # protocol: it still has to hear of the connection's loss
+                receiver.corked = self.writer
             transport.set_protocol(receiver)
             # the StreamReader may have left the transport paused (its
             # own flow control); the receiver starts unpaused, so resume
-            # or reads would hang forever once the leftover drains
+            # or reads would hang forever once the leftover is framed
             try:
                 transport.resume_reading()
             except Exception:
@@ -1325,6 +1517,7 @@ class Connection:
         except Exception:
             return
         self.reader = receiver
+        receiver.feed(leftover)
 
     # -- frame IO ------------------------------------------------------------
 
@@ -1471,7 +1664,7 @@ class Connection:
                     try:
                         with perf.time_avg("tx_io"):
                             with tracing.section("messenger", "sock_write"):
-                                self.writer.writelines(segs)
+                                _writelines(self.writer, segs)
                             await self.writer.drain()
                     except (ConnectionError, OSError,
                             asyncio.TimeoutError) as e:
@@ -1684,9 +1877,9 @@ class Connection:
 
     def buffered_frame_len(self) -> Optional[int]:
         """Payload length of the next COMPLETE frame in hand: a frame
-        pre-verified into the rx stash by the native drain first, else
-        whatever is fully buffered on the reader — the serve loop's rx
-        batching predicate (batch only what needs no network wait).
+        the FrameReceiver stashed first, else whatever is fully buffered
+        on a reader of another kind — the serve loop's rx batching
+        predicate (batch only what needs no network wait).
         Delegated connections peek the shm ring instead: a fully
         buffered record needs no worker round-trip."""
         if self._rx_stash:
@@ -1698,199 +1891,41 @@ class Connection:
             return max(0, n - _SHM_FRAME_HDR.size)
         return Messenger._buffered_frame_len(self.reader)
 
-    def _rx_drain_native(self) -> None:
-        """Native rx burst: parse every COMPLETE frame already buffered
-        in the FrameReceiver backlog, verify ALL their crc sections in
-        ONE released-GIL call (wirepy_verify_regions — the geometry
-        rides plain int lists, walked in C), land every verified
-        frame's blob bytes with ONE more released-GIL scatter call
-        (wirepy_scatter_from) — lane fragments straight into their
-        slice of the group assembly buffer (frag_view) — and stash
-        read_frame-ready tuples.  The python arm pays 2-4 awaits plus
-        1-2 ctypes crc round-trips plus an interpreter copy per frame;
-        this pays two foreign calls per BURST, and the GIL is released
-        while the burst's bytes are checksummed and moved.
-
-        A crc-failing frame mid-burst stashes its valid predecessors,
-        consumes through the bad frame, and parks the BadFrame in
-        _rx_error — read_frame raises it once the stash drains, exactly
-        the slow path's fail-after-the-good-frames order."""
-        r = self.reader
-        pend = r._pending
-        base = r._off
-        end = len(pend)
-        if end - base < _HDR.size or self._rx_error is not None:
-            return
-        crc_on = self.crc_enabled
-        t0 = time.monotonic()
-        voffs: list = []    # crc regions: offsets/lengths INTO pend
-        vlens: list = []
-        vwants: list = []
-        expect: list = []   # (frame_index, is_blob) per crc region
-        frames: list = []   # [type_id, version, seq, payload, length,
-        #                      blob, fixed, verified, flags, src_off]
-        pos = base
-        error: Optional[BaseException] = None
-        error_end = pos
-        # one export for the whole drain: bytes(mv[a:b]) is a single
-        # copy, where bytes(pend[a:b]) would copy twice (bytearray
-        # slice, then bytes).  Released before _consume — a live export
-        # blocks the bytearray resize.
-        mv = memoryview(pend)
-        try:
-            while end - pos >= _HDR.size:
-                length, type_id, version, flags, crc, seq = \
-                    _HDR.unpack_from(pend, pos)
-                if end - pos - _HDR.size < length:
-                    break
-                fstart = pos + _HDR.size
-                fend = fstart + length
-                blob = None
-                verified = False
-                src_off = -1
-                if flags & FLAG_BLOB:
-                    if _BLOB_PFX.size > length:
-                        error = BadFrame(f"bad blob prefix on type {type_id}")
-                        error_end = fend
-                        break
-                    plen, blob_crc = _BLOB_PFX.unpack_from(pend, fstart)
-                    if _BLOB_PFX.size + plen > length:
-                        # a corrupt plen would desync the stream — reject
-                        # (the slow path refuses before any read; either
-                        # way the frame is consumed and the session dies)
-                        error = BadFrame(f"bad blob prefix on type {type_id}")
-                        error_end = fend
-                        break
-                    hdr_end = fstart + _BLOB_PFX.size + plen
-                    payload = bytes(mv[fstart + _BLOB_PFX.size:hdr_end])
-                    blob_len = length - _BLOB_PFX.size - plen
-                    if crc and crc_on:
-                        # one region covers prefix+pickled: crc32c over the
-                        # contiguous span == the chained tx-side crc
-                        voffs.append(fstart)
-                        vlens.append(hdr_end - fstart)
-                        vwants.append(crc)
-                        expect.append((len(frames), False))
-                    cls = _MSG_TYPES.get(type_id)
-                    dest = None
-                    if cls is MLaneSegment and self.lane_group is not None \
-                            and (flags & FLAG_FIXED) and blob_len \
-                            and not (seq and seq <= self.in_seq):
-                        # the in_seq guard: see the slow path — a replayed
-                        # duplicate must not re-open reassembly state
-                        try:
-                            seg = _unpack_fixed(cls, payload, None)
-                            dest = self.lane_group.frag_view(seg, blob_len)
-                        except Exception:
-                            dest = None
-                    if dest is not None:
-                        blob = dest
-                    elif getattr(cls, "BLOB_VIEW_OK", False):
-                        blob = memoryview(
-                            np.empty(blob_len, dtype=np.uint8)).cast("B")
-                    else:
-                        blob = bytearray(blob_len)
-                    src_off = hdr_end
-                    if blob_crc and crc_on:
-                        voffs.append(hdr_end)
-                        vlens.append(blob_len)
-                        vwants.append(blob_crc)
-                        expect.append((len(frames), True))
-                        verified = True
-                else:
-                    payload = bytes(mv[fstart:fend])
-                    if crc and crc_on:
-                        voffs.append(fstart)
-                        vlens.append(length)
-                        vwants.append(crc)
-                        expect.append((len(frames), False))
-                frames.append([type_id, version, seq, payload, length, blob,
-                               bool(flags & FLAG_FIXED), verified, flags,
-                               src_off])
-                pos = fend
-            if not frames and error is None:
-                return
-            perf = self.messenger.perf
-            bad_idx = len(frames)
-            if voffs:
-                with tracing.section("messenger", "crc_verify"):
-                    bad_region = self.wp.wirepy_verify_regions(
-                        pend, voffs, vlens, vwants)
-                perf.inc("native_rx_calls")
-                perf.inc("native_bytes", sum(vlens))
-                if bad_region >= 0:
-                    fidx, is_blob = expect[bad_region]
-                    if fidx < bad_idx:
-                        bad_idx = fidx
-                        error = BadFrame(
-                            ("blob crc mismatch on type {}" if is_blob
-                             else "crc mismatch on frame type {}").format(
-                                frames[fidx][0]))
-                        error_end = base + sum(
-                            _HDR.size + f[4] for f in frames[:fidx + 1])
-            consumed = pos - base
-            soffs: list = []
-            dsts: list = []
-            for f in frames[:bad_idx]:
-                if f[9] >= 0:
-                    # verified-then-copied: a crc-refused frame never lands
-                    # a byte (the slow path lands then kills; the failure
-                    # surface — BadFrame, session death — is identical, the
-                    # assembly buffer just stays cleaner)
-                    soffs.append(f[9])
-                    dsts.append(f[5])
-                flags = f[8]
-                payload = f[3]
-                if flags & FLAG_COMPRESSED and not (flags & FLAG_BLOB):
-                    payload = zlib.decompress(payload)
-                self._rx_stash.append((f[0], f[1], f[2], payload, f[4],
-                                       f[5], f[6], f[7]))
-            if soffs:
-                copied = self.wp.wirepy_scatter_from(pend, soffs, dsts)
-                perf.inc("native_rx_calls")
-                perf.inc("native_bytes", copied)
-            if error is not None:
-                self._rx_error = error
-                consumed = error_end - base
-        finally:
-            mv.release()
-        r._consume(consumed)
-        rx_dt = time.monotonic() - t0
-        perf.tinc("rx_io", rx_dt)
-        perf.hinc("rx_io_us", rx_dt * 1e6)
-
     async def read_frame(self) -> Tuple[int, int, int, bytes, int, Any,
                                         bool, bool]:
         """Returns (type_id, version, seq, payload, cost, blob, fixed,
         blob_verified).  The dispatch throttle is charged `cost` bytes
-        BEFORE the payload is read (receive-side backpressure, reference
-        DispatchQueue throttle); the caller must put() cost back when
-        done with the payload.  Blob frames (FLAG_BLOB) return the bulk
-        bytes separately, checked against their own crc32c —
-        ``blob_verified`` says that check actually ran (crc enabled and
-        present), so handlers holding an app-level crc of the same bytes
+        before the frame is handed over (receive-side backpressure,
+        reference DispatchQueue throttle; a FrameReceiver bounds what
+        lands ahead of it); the caller must put() cost back when done
+        with the payload.  The reader's type picks the path: a
+        FrameReceiver has framed the stream already and this pops its
+        stash; a delegated connection reads its shm ring; a SecureStream
+        (bytes are decrypted before they can land) or a plain
+        StreamReader takes the readexactly chain below.  Blob frames (FLAG_BLOB) return the bulk bytes
+        separately, checked against their own crc32c — ``blob_verified``
+        says that check actually ran (crc enabled and present), so
+        handlers holding an app-level crc of the same bytes
         (MECSubWrite.chunk_crc) can skip their own verify pass."""
         stash = self._rx_stash
-        if not stash and self.wp is not None \
-                and isinstance(self.reader, FrameReceiver) \
-                and (self.crc_fn is checksum or not self.crc_enabled):
-            # native burst drain: every fully-buffered frame verifies in
-            # one released-GIL call and lands pre-scattered in the stash
-            r = self.reader
-            if len(r._pending) - r._off >= _HDR.size:
-                with tracing.section("messenger", "rx_drain"):
-                    self._rx_drain_native()
+        r = self.reader
+        framed = isinstance(r, FrameReceiver)
+        while framed and not stash:
+            # the receiver frames the stream (and counted the bytes):
+            # park until a burst leaves something to pop
+            if self._rx_error is not None:
+                err, self._rx_error = self._rx_error, None
+                raise err
+            await r.wait()
+            if self.reader is not r:
+                raise ConnectionResetError("transport replaced")
         if stash:
-            (type_id, version, seq, payload, cost, blob, fixed,
-             verified) = stash.popleft()
-            await self.throttle.get(cost)
-            self.messenger.perf.inc("rx_bytes", _HDR.size + cost)
-            return (type_id, version, seq, payload, cost, blob, fixed,
-                    verified)
-        if self._rx_error is not None:
-            err, self._rx_error = self._rx_error, None
-            raise err
-        if isinstance(self.reader, ShmConnEndpoint):
+            frame = stash.popleft()
+            if framed:
+                r.popped(frame[4])
+            await self.throttle.get(frame[4])
+            return frame
+        if isinstance(r, ShmConnEndpoint):
             return await self._read_frame_shm()
         hdr = await self.reader.readexactly(_HDR.size)
         length, type_id, version, flags, crc, seq = _HDR.unpack(hdr)
@@ -1904,8 +1939,6 @@ class Connection:
         try:
             blob = None
             if flags & FLAG_BLOB:
-                # the blob reads into ITS OWN buffer (FrameReceiver lands
-                # bytes there directly — no giant payload slice)
                 head = await self.reader.readexactly(_BLOB_PFX.size)
                 plen, blob_crc = _BLOB_PFX.unpack_from(head)
                 if _BLOB_PFX.size + plen > length:
@@ -1913,40 +1946,8 @@ class Connection:
                     # and desync the stream — reject before any read
                     raise BadFrame(f"bad blob prefix on type {type_id}")
                 pickled = await self.reader.readexactly(plen)
-                blob_len = length - _BLOB_PFX.size - plen
-                cls = _MSG_TYPES.get(type_id)
-                if getattr(cls, "BLOB_VIEW_OK", False) \
-                        and isinstance(self.reader, FrameReceiver):
-                    # lane-fragment reassembly seam: a striped segment's
-                    # chunk lands DIRECTLY in its slice of the group's
-                    # assembly buffer — no per-fragment staging buffer,
-                    # no gather copy at reassembly time
-                    dest = None
-                    if cls is MLaneSegment and self.lane_group is not None \
-                            and (flags & FLAG_FIXED) and blob_len \
-                            and not (seq and seq <= self.in_seq):
-                        # the in_seq guard keeps a REPLAYED duplicate
-                        # (acked but re-sent across a lane revival) from
-                        # re-creating reassembly state the serve loop is
-                        # about to drop — that would leak one assembly
-                        # buffer per replayed fragment
-                        try:
-                            seg = _unpack_fixed(cls, bytes(pickled), None)
-                            dest = self.lane_group.frag_view(
-                                seg, blob_len)
-                        except Exception:
-                            dest = None
-                    if dest is not None:
-                        blob = await self.reader.readexactly(blob_len,
-                                                             into=dest)
-                    else:
-                        # store/decode-lane blob: land in an
-                        # uninitialized buffer (no memset pass over the
-                        # data volume)
-                        blob = await self.reader.readexactly(blob_len,
-                                                             uninit=True)
-                else:
-                    blob = await self.reader.readexactly(blob_len)
+                blob = await self.reader.readexactly(
+                    length - _BLOB_PFX.size - plen)
                 with tracing.section("messenger", "crc_verify"):
                     if crc and self.crc_enabled and self.crc_fn(
                             pickled, self.crc_fn(head)) != crc:
@@ -2071,7 +2072,7 @@ class Connection:
             with self.messenger.perf.time_avg("tx_io"):
                 for _, data in list(self.unacked):
                     if isinstance(data, list):
-                        self.writer.writelines(data)
+                        _writelines(self.writer, data)
                         replayed += sum(len(p) for p in data)
                     else:
                         self.writer.write(data)
@@ -2869,18 +2870,16 @@ class Messenger:
                 return None  # buffered tx would race the worker's writes
         except Exception:
             return None
-        # leftover rx bytes: captured only after the ctrl handoff
-        # succeeds, so a failed delegation leaves the reader intact
-        if isinstance(reader, FrameReceiver):
-            leftover = bytes(memoryview(reader._pending)[reader._off:])
-        elif isinstance(reader, asyncio.StreamReader):
-            leftover = bytes(reader._buffer)
-        else:
+        if not isinstance(reader, (FrameReceiver, asyncio.StreamReader)):
             return None
         try:
             transport.pause_reading()
         except Exception:
             pass
+        # leftover rx bytes: the reader lets go of them only after the
+        # ctrl handoff succeeds, so a failed delegation leaves it intact
+        leftover = reader.unframed() if isinstance(reader, FrameReceiver) \
+            else bytes(reader._buffer)
         conn_id = next(self._conn_ids)
         try:
             ep = delegate_socket(worker, conn_id, sock.fileno(), leftover,
@@ -2898,8 +2897,7 @@ class Messenger:
         # handoff complete: the worker owns a dup of the fd.  Clear the
         # captured bytes from the parent reader and close our copy.
         if isinstance(reader, FrameReceiver):
-            reader._pending.clear()
-            reader._off = 0
+            reader._dead = True
         else:
             reader._buffer.clear()
         if isinstance(writer, CorkedWriter):
@@ -3437,8 +3435,8 @@ class Messenger:
         frame never stalls dispatch of messages already in hand."""
         try:
             if isinstance(reader, FrameReceiver):
-                buf, off = reader._pending, reader._off
-            elif isinstance(reader, asyncio.StreamReader):
+                return None  # its complete frames are on the stash
+            if isinstance(reader, asyncio.StreamReader):
                 buf, off = reader._buffer, 0
             else:  # SecureStream
                 buf, off = reader._buf, 0

@@ -596,7 +596,7 @@ class TestBlobCrcReuse:
         run(go())
 
 
-# -- native wirepath (ISSUE 12): drain semantics + arm parity ----------------
+# -- the receiver frames the stream (ISSUE 32): FrameReceiver alone ----------
 
 def _wirepath_native() -> bool:
     from ceph_tpu.utils import wirepath
@@ -604,52 +604,154 @@ def _wirepath_native() -> bool:
     return wirepath.kind() == "native"
 
 
-def _drain_conn(raw: bytes):
-    """A minimal Connection wired to a detached FrameReceiver holding
-    ``raw`` as its buffered backlog — the unit under test is
-    _rx_drain_native alone (parse + one-call verify + one-call scatter),
-    with no transport or serve loop underneath."""
+class _FakeTransport:
+    """Records what the receiver asks of its transport."""
+
+    def __init__(self):
+        self.calls = []
+
+    def pause_reading(self):
+        self.calls.append("pause")
+
+    def resume_reading(self):
+        self.calls.append("resume")
+
+
+def _framer_conn(native: bool = True):
+    """A minimal Connection whose reader is a FrameReceiver over a fake
+    transport: the unit under test is the framer alone (parse, one-pass
+    verify, land, stash), driven through the transport's own two calls,
+    with no socket or serve loop underneath."""
     import collections
 
-    from ceph_tpu.native import bridge
     from ceph_tpu.rados.messenger import (Connection, FrameReceiver,
                                           _build_wire_perf)
+    from ceph_tpu.utils import wirepath
+    from ceph_tpu.utils.checksum import checksum
 
     class _Msgr:
         perf = _build_wire_perf()
 
+    class _Throttle:
+        async def get(self, cost):
+            pass
+
     conn = object.__new__(Connection)
-    conn.reader = FrameReceiver(None, None, leftover=raw)
     conn.messenger = _Msgr()
+    conn.throttle = _Throttle()
     conn.crc_enabled = True
-    conn.wp = bridge
+    conn.crc_fn = checksum
+    conn.wp = wirepath.impl() if native else None
     conn.lane_group = None
     conn.in_seq = 0
+    conn.unacked = collections.deque()
     conn._rx_stash = collections.deque()
     conn._rx_error = None
+    conn.reader = FrameReceiver(conn, _FakeTransport(), None)
     return conn
 
 
-def _mk_frame(msg, seq: int) -> bytes:
+def _feed(conn, raw: bytes, chunk=None) -> None:
+    """What the transport does with a stream: get_buffer, recv_into of at
+    most `chunk` bytes, buffer_updated."""
+    r = conn.reader
+    if chunk is None:
+        return r.feed(raw)
+    mv = memoryview(raw)
+    while len(mv) and not r._dead:
+        buf = r.get_buffer(-1)
+        assert len(buf) > 0, "the transport must always get room"
+        n = min(len(buf), len(mv), chunk)
+        buf[:n] = mv[:n]
+        r.buffer_updated(n)
+        mv = mv[n:]
+
+
+def _mk_frame(msg, seq: int, type_id: int = 900, compress: bool = False
+              ) -> bytes:
+    from ceph_tpu.rados.messenger import FLAG_COMPRESSED
     from ceph_tpu.utils.checksum import checksum
 
     payload = encode_payload(msg)
+    flags = 0
+    if compress:
+        payload, flags = zlib.compress(payload, 1), FLAG_COMPRESSED
     crc = checksum(payload) & 0xFFFFFFFF
-    return _HDR.pack(len(payload), 900, 1, 0, crc, seq) + payload
+    return _HDR.pack(len(payload), type_id, 1, flags, crc, seq) + payload
+
+
+def _mk_blob_frame(blob: bytes, seq: int, type_id: int = 910,
+                   blob_crc=None) -> bytes:
+    import pickle
+
+    from ceph_tpu.rados.messenger import FLAG_BLOB, _BLOB_PFX
+    from ceph_tpu.utils.checksum import checksum
+
+    crc = checksum(blob) & 0xFFFFFFFF if blob_crc is None else blob_crc
+    pickled = pickle.dumps({"chunk_crc": crc})
+    head = _BLOB_PFX.pack(len(pickled), crc) + pickled
+    return _HDR.pack(len(head) + len(blob), type_id, 1, FLAG_BLOB,
+                     checksum(head) & 0xFFFFFFFF, seq) + head + blob
+
+
+def _mk_ack(seq: int) -> bytes:
+    from ceph_tpu.utils.checksum import checksum
+
+    payload = struct.pack("<Q", seq)
+    return _HDR.pack(8, ACK_TYPE, 1, 0, checksum(payload), 0) + payload
+
+
+def _plain(stash) -> list:
+    """The stash with every buffer as bytes, for comparisons."""
+    return [tuple(bytes(x) if isinstance(x, (bytearray, memoryview)) else x
+                  for x in f) for f in stash]
 
 
 from ceph_tpu.rados.messenger import encode_payload  # noqa: E402
 
 
+@message(912)
+class MViewBlob:
+    chunk: bytes = b""
+    chunk_crc: int = 0
+
+
+MViewBlob.BLOB_ATTR = "chunk"
+MViewBlob.BLOB_VIEW_OK = True
+
+
+def _mixed_stream():
+    """Small frames, acks, blobs of both destination kinds, a payload
+    larger than the head and a compressed one."""
+    import os
+
+    rnd = os.urandom
+    parts = [
+        _mk_frame(MTest(text="a", seqno=1), 1),
+        _mk_ack(7),
+        _mk_blob_frame(rnd(75 * 1024), 2),             # bytearray dest
+        _mk_frame(MTest(text="b" * 300, seqno=2), 3),
+        _mk_blob_frame(rnd(300 * 1024 + 13), 4, 912),  # np.empty dest
+        _mk_ack(9),
+        _mk_frame(MTest(text="big", blob=rnd(40000)), 5),  # > the head
+        _mk_frame(MTest(text="z" * 5000), 6, compress=True),
+        _mk_blob_frame(rnd(16 * 1024), 7, 912),
+        _mk_frame(MTest(text="tail"), 8),
+    ]
+    return b"".join(parts)
+
+
 @pytest.mark.skipif(not _wirepath_native(), reason="native wirepath absent")
-class TestNativeRxDrain:
+class TestFramerBursts:
+    """TestNativeRxDrain's four cases (ISSUE 12), on the framer."""
+
     def test_burst_stashes_every_complete_frame(self):
         frames = [MTest(text=f"t{i}", seqno=i) for i in range(5)]
         raw = b"".join(_mk_frame(m, i + 1) for i, m in enumerate(frames))
-        # a trailing HALF frame must stay buffered, not parse
-        raw += _mk_frame(MTest(text="partial"), 9)[:-7]
-        conn = _drain_conn(raw)
-        conn._rx_drain_native()
+        # a trailing HALF frame must stay in the head, not parse
+        half = _mk_frame(MTest(text="partial"), 9)[:-7]
+        conn = _framer_conn()
+        _feed(conn, raw + half)
         assert len(conn._rx_stash) == 5
         assert conn._rx_error is None
         for i, (type_id, version, seq, payload, cost, blob, fixed,
@@ -659,88 +761,332 @@ class TestNativeRxDrain:
 
             m = decode_message(type_id, version, payload, blob, fixed)
             assert m.text == f"t{i}" and m.seqno == i
-        # the half frame is still pending for the slow path
-        r = conn.reader
-        assert len(r._pending) - r._off == len(_mk_frame(
-            MTest(text="partial"), 9)) - 7
+        assert conn.reader.unframed() == half
+        perf = conn.messenger.perf.dump()
+        assert perf["rx_framed"] == 5
+        assert perf["native_rx_calls"] == 1  # one verify for the burst
 
     def test_corrupt_mid_burst_fails_after_the_good_frames(self):
-        """The slow path dispatches every frame before the corrupt one,
-        then kills the session — the native burst must keep exactly
-        that order: predecessors stash, the BadFrame parks, nothing
-        after the corrupt frame is touched."""
-        from ceph_tpu.rados.messenger import BadFrame
-
+        """A per-frame reader dispatches every frame before the corrupt
+        one, then kills the session — the framer keeps exactly that
+        order: predecessors stash, the BadFrame parks, nothing after
+        the corrupt frame is looked at."""
         good0 = _mk_frame(MTest(text="ok0"), 1)
         bad = bytearray(_mk_frame(MTest(text="dead"), 2))
         bad[-1] ^= 0xFF  # corrupt the payload tail: crc must catch it
         good1 = _mk_frame(MTest(text="ok1"), 3)
-        conn = _drain_conn(good0 + bytes(bad) + good1)
-        conn._rx_drain_native()
+        conn = _framer_conn()
+        _feed(conn, good0 + bytes(bad) + good1)
         assert len(conn._rx_stash) == 1  # only the pre-corruption frame
         assert isinstance(conn._rx_error, BadFrame)
-        # consumed THROUGH the bad frame; the trailing good frame stays
-        # unconsumed (the session dies before it would be read)
-        r = conn.reader
-        assert len(r._pending) - r._off == len(good1)
-        # a second drain is a no-op while the error is parked
-        conn._rx_drain_native()
+        assert conn.reader._transport.calls == ["pause"]
+        # nothing more is framed on a stream that lost its framing
+        _feed(conn, good1)
         assert len(conn._rx_stash) == 1
+
+        async def go():
+            first = await conn.read_frame()
+            assert first[2] == 1
+            with pytest.raises(BadFrame):
+                await conn.read_frame()
+
+        run(go())
 
     def test_blob_frame_lands_and_verifies(self):
         from ceph_tpu.rados.messenger import decode_message
-        from ceph_tpu.utils.checksum import checksum
 
         blob = bytes(range(256)) * 300  # 75 KiB
-        crc = checksum(blob) & 0xFFFFFFFF
-        raw = b"".join(_mk_frame(MTest(text=f"x{i}"), i + 1)
-                       for i in range(2))
-        conn0 = _drain_conn(raw)
-        conn0._rx_drain_native()
-        base = conn0.messenger.perf.dump()["native_rx_calls"]
-        assert base >= 1  # the verify call ran
-        # now a blob frame: prefix + pickled + raw blob, blob crc in
-        # the prefix (the scatter call must land it byte-identical)
-        import pickle
-
-        from ceph_tpu.rados.messenger import FLAG_BLOB, _BLOB_PFX
-
-        pickled = pickle.dumps({"chunk_crc": crc})
-        prefix = _BLOB_PFX.pack(len(pickled), crc)
-        head = prefix + pickled
-        hcrc = checksum(head) & 0xFFFFFFFF
-        frame = _HDR.pack(len(head) + len(blob), 910, 1, FLAG_BLOB,
-                          hcrc, 1) + head + blob
-        conn = _drain_conn(frame)
-        conn._rx_drain_native()
+        conn = _framer_conn()
+        _feed(conn, _mk_blob_frame(blob, 1))
         assert conn._rx_error is None
         assert len(conn._rx_stash) == 1
         (type_id, version, seq, payload, cost, got_blob, fixed,
          verified) = conn._rx_stash[0]
-        assert verified  # the blob crc section was checked natively
+        assert verified  # the blob crc section was checked
         out = decode_message(type_id, version, payload, got_blob, fixed)
         assert bytes(out.chunk) == blob
+        perf = conn.messenger.perf.dump()
+        assert perf["native_rx_calls"] == 2  # the front, then the body
+        # what came with the head was copied, the rest landed in place
+        assert 0 < perf["rx_copied_bytes"] <= conn.reader._HEAD
 
-    def test_corrupt_blob_never_lands_a_byte(self):
-        """crc runs over the backlog BEFORE the scatter: a corrupt blob
-        frame must park the error without copying anything."""
-        import pickle
-
-        from ceph_tpu.rados.messenger import (BadFrame, FLAG_BLOB,
-                                              _BLOB_PFX)
+    def test_corrupt_blob_is_never_handed_over(self):
+        """The body lands (in a buffer nobody sees) and fails its crc:
+        the error parks, nothing reaches the stash."""
         from ceph_tpu.utils.checksum import checksum
 
         blob = b"Q" * 70000
-        pickled = pickle.dumps({"chunk_crc": 0})
         wrong = (checksum(blob) ^ 1) & 0xFFFFFFFF
-        prefix = _BLOB_PFX.pack(len(pickled), wrong)
-        head = prefix + pickled
-        frame = _HDR.pack(len(head) + len(blob), 910, 1, FLAG_BLOB,
-                          checksum(head) & 0xFFFFFFFF, 1) + head + blob
-        conn = _drain_conn(frame)
-        conn._rx_drain_native()
+        conn = _framer_conn()
+        _feed(conn, _mk_blob_frame(blob, 1, blob_crc=wrong))
         assert isinstance(conn._rx_error, BadFrame)
         assert not conn._rx_stash
+
+        async def go():
+            with pytest.raises(BadFrame):
+                await conn.read_frame()
+
+        run(go())
+
+
+class TestFramer:
+    @pytest.mark.parametrize("chunk", [1, 29, 4096, 256 * 1024, None])
+    def test_any_chunking_gives_the_same_frames(self, chunk):
+        """The same byte stream through get_buffer/buffer_updated in any
+        chunking: same frames, order and bytes; acks applied, not
+        stashed; at most one head buffer copied a frame with a body."""
+        raw = _mixed_stream()
+        want = _framer_conn()
+        _feed(want, raw)
+        conn = _framer_conn()
+        conn.unacked.extend((s, b"") for s in range(1, 12))
+        _feed(conn, raw, chunk)
+        assert conn._rx_error is None
+        assert _plain(conn._rx_stash) == _plain(want._rx_stash)
+        assert [f[2] for f in conn._rx_stash] == list(range(1, 9))
+        assert [f[7] for f in conn._rx_stash] == [
+            False, True, False, True, False, False, True, False]
+        # a blob is what its class's consumers expect: a bytearray, or
+        # (BLOB_VIEW_OK) a view of an uninitialised array
+        assert [type(f[5]) for f in conn._rx_stash if f[5] is not None] \
+            == [bytearray, memoryview, memoryview]
+        # the compressed frame arrived decompressed, the large one whole
+        from ceph_tpu.rados.messenger import decode_message
+
+        msgs = [decode_message(f[0], f[1], f[3], f[5], f[6])
+                for f in conn._rx_stash]
+        assert msgs[5].text == "z" * 5000 and len(msgs[4].blob) == 40000
+        assert [s for s, _ in conn.unacked] == [10, 11]  # acks 7 and 9
+        r = conn.reader
+        assert r.unframed() == b"" and r._body is None
+        perf = conn.messenger.perf.dump()
+        assert perf["rx_bytes"] == len(raw)
+        assert perf["rx_framed"] == 10 and perf["rx_inline_acks"] == 2
+        # four frames had a body (three blobs, one large payload)
+        assert perf["rx_copied_bytes"] <= 4 * r._HEAD
+        if chunk == 1:
+            assert perf["rx_copied_bytes"] == 0  # every body landed
+
+    def test_eof_inside_a_body_drops_the_partial_frame(self):
+        conn = _framer_conn()
+        frame = _mk_blob_frame(b"E" * 200_000, 2)
+        _feed(conn, _mk_frame(MTest(text="whole"), 1) + frame[:90_000])
+        r = conn.reader
+        assert r._body is not None and len(conn._rx_stash) == 1
+        assert r.unframed() == frame[:90_000]
+        r.eof_received()
+
+        async def go():
+            assert (await conn.read_frame())[2] == 1
+            with pytest.raises(asyncio.IncompleteReadError):
+                await conn.read_frame()
+
+        run(go())
+        assert not conn._rx_stash  # the half blob went with its transport
+
+    def test_pause_above_the_limit_and_resume_below_half(self):
+        conn = _framer_conn()
+        r = conn.reader
+        r._LIMIT = 8192
+        calls = r._transport.calls
+        one = _mk_frame(MTest(text="x" * 1000), 1)
+        # one frame alone never pauses its own connection
+        _feed(conn, _mk_frame(MTest(text="y" * 12000), 1))
+        assert calls == [] and r._held > r._LIMIT
+        conn._rx_stash.clear()
+        r._held = 0
+        n = 0
+        while not calls:
+            n += 1
+            _feed(conn, one)
+            assert n < 20
+        assert calls == ["pause"] and r._held > r._LIMIT
+
+        async def pop_until_resumed():
+            popped = 0
+            while calls == ["pause"]:
+                # nothing resumes it but a pop that brings it below half
+                assert r._held >= r._LIMIT // 2
+                await conn.read_frame()
+                popped += 1
+            return popped
+
+        popped = run(pop_until_resumed())
+        assert calls == ["pause", "resume"]
+        assert 1 < popped < n and r._held < r._LIMIT // 2
+
+    def test_body_in_flight_counts_and_cannot_deadlock(self):
+        conn = _framer_conn()
+        r = conn.reader
+        r._LIMIT = 8192
+        big = _mk_blob_frame(b"B" * 100_000, 2)
+        _feed(conn, _mk_frame(MTest(text="first"), 1) + big[:50_000])
+        assert r._transport.calls == ["pause"]  # one stashed + the body
+
+        async def go():
+            await conn.read_frame()
+
+        run(go())
+        # the body alone resumes: nobody could pop what has not landed
+        assert r._transport.calls == ["pause", "resume"]
+        _feed(conn, big[50_000:])
+        assert len(conn._rx_stash) == 1 and conn._rx_error is None
+
+    @pytest.mark.parametrize("crc", [True, False], ids=["crc", "nocrc"])
+    @pytest.mark.parametrize("left", [1000, 40_000, 99_000])
+    def test_leftover_with_a_blob_front_then_the_socket_keeps_order(
+            self, crc, left):
+        """What the StreamReader held at the swap (after a reconnect: the
+        front of a replayed blob frame, more than one head of it) is fed
+        whole before any byte the socket still holds: the transport's
+        read is the only other way in, so the body's bytes stay in
+        stream order, crc checked or not."""
+        data = bytes(range(256)) * 400  # 100 KiB
+        frame = _mk_blob_frame(data, 1) + _mk_frame(MTest(text="next"), 2)
+        conn = _framer_conn()
+        conn.crc_enabled = crc
+        r = conn.reader
+        r.feed(frame[:left])  # 40 000 and 99 000 are over one head
+        assert r._body is not None and not conn._rx_stash
+        assert r.unframed() == frame[:left]
+        _feed(conn, frame[left:], 64 << 10)  # the transport's reads
+        assert conn._rx_error is None and len(conn._rx_stash) == 2
+        blob_frame, nxt = conn._rx_stash
+        assert bytes(blob_frame[5]) == data and blob_frame[7] is crc
+        assert nxt[2] == 2
+        assert conn.messenger.perf.dump()["rx_bytes"] == len(frame)
+
+    def test_swap_with_a_backlog_on_a_real_socket_keeps_the_stream(self):
+        """enable_fast_read over a live TCP transport whose StreamReader
+        already holds more than a head of a 1 MiB blob frame while the
+        kernel holds the rest (a reconnect's replay right behind the
+        handshake): read_frame gives the same bytes in the same order."""
+        import os
+
+        data = os.urandom(1 << 20)
+        raw = _mk_blob_frame(data, 1) + _mk_frame(MTest(text="after"), 2)
+        out = []
+
+        async def go():
+            served = asyncio.Event()
+
+            async def on_conn(reader, writer):
+                while len(reader._buffer) <= head:
+                    await asyncio.sleep(0.01)
+                conn = _framer_conn()
+                conn.reader, conn.writer = reader, writer
+                conn.enable_fast_read()
+                assert conn.reader is not reader
+                for _ in range(2):
+                    out.append(await conn.read_frame())
+                served.set()
+
+            server = await asyncio.start_server(on_conn, "127.0.0.1", 0)
+            port = server.sockets[0].getsockname()[1]
+            _, w = await asyncio.open_connection("127.0.0.1", port)
+            w.write(raw)
+            await w.drain()
+            await asyncio.wait_for(served.wait(), 20)
+            w.close()
+            server.close()
+            await server.wait_closed()
+
+        from ceph_tpu.rados.messenger import FrameReceiver
+
+        head = FrameReceiver._HEAD
+        run(go())
+        assert [f[2] for f in out] == [1, 2]
+        assert bytes(out[0][5]) == data and out[0][7] is True
+
+    def test_received_ack_trims_unacked_without_waking_the_serve_task(self):
+        conn = _framer_conn()
+        conn.unacked.extend([(1, b"a"), (2, b"b"), (3, b"c")])
+
+        async def go():
+            task = asyncio.ensure_future(conn.read_frame())
+            await asyncio.sleep(0)
+            waiter = conn.reader._waiter
+            assert waiter is not None
+            _feed(conn, _mk_ack(2))
+            await asyncio.sleep(0)
+            assert list(conn.unacked) == [(3, b"c")]
+            assert not conn._rx_stash
+            assert not waiter.done() and not task.done()
+            _feed(conn, _mk_frame(MTest(text="data"), 4))
+            frame = await asyncio.wait_for(task, 2)
+            assert frame[2] == 4
+
+        run(go())
+        perf = conn.messenger.perf.dump()
+        assert perf["rx_inline_acks"] == 1 and perf["rx_framed"] == 2
+
+    @pytest.mark.skipif(not _wirepath_native(),
+                        reason="native wirepath absent")
+    @pytest.mark.parametrize("chunk", [29, None])
+    def test_both_crc_arms_give_identical_tuples(self, chunk, monkeypatch):
+        """The arm is what utils.wirepath resolves (CEPH_TPU_WIREPATH=0:
+        python), nothing is set on the framer: same tuples either way,
+        and the same refusal of a corrupt stream."""
+        from ceph_tpu.utils import wirepath
+
+        raw = _mixed_stream()
+        bad = bytearray(raw)
+        bad[len(raw) // 2] ^= 0x01  # inside the 300 KiB blob
+        native = _framer_conn()
+        assert native.wp is not None
+        monkeypatch.setenv("CEPH_TPU_WIREPATH", "0")
+        wirepath._reset_for_tests()
+        try:
+            python = _framer_conn()
+            assert python.wp is None
+            python_bad = _framer_conn()
+        finally:
+            monkeypatch.delenv("CEPH_TPU_WIREPATH")
+            wirepath._reset_for_tests()
+        native_bad = _framer_conn()
+        _feed(native, raw, chunk)
+        _feed(python, raw, chunk)
+        assert _plain(native._rx_stash) == _plain(python._rx_stash)
+        assert len(python._rx_stash) == 8
+        assert python.messenger.perf.dump()["native_rx_calls"] == 0
+        _feed(native_bad, bytes(bad), chunk)
+        _feed(python_bad, bytes(bad), chunk)
+        assert _plain(native_bad._rx_stash) == _plain(python_bad._rx_stash)
+        assert len(python_bad._rx_stash) == 3
+        assert isinstance(native_bad._rx_error, BadFrame)
+        assert isinstance(python_bad._rx_error, BadFrame)
+
+
+@pytest.mark.parametrize("name, cells, moves", [
+    ("rx_copy_share.put",
+     ["k8m3.write4m", "k4m2.write4m", "k10m4c.write4m"], "put_MBps"),
+    ("rx_copy_share.get", ["k8m3.randread4m"], "get_MBps"),
+])
+def test_the_engagement_metric_reads_copied_over_received(name, cells, moves):
+    """The benchmark's data files (ISSUE 32): bytes copied after the
+    kernel delivered them over bytes received, in percent, from a
+    window's counter delta; a program without the counter (the parent)
+    reports nothing."""
+    import json
+    import os
+
+    from benchmarks import layers
+
+    def read(counters):
+        return layers.read(name, {"counters": counters})
+
+    assert read({"wire.rx_bytes": 5_000_000}) is None
+    assert read({"wire.rx_bytes": 5_000_000,
+                 "wire.rx_copied_bytes": 0}) == 0.0
+    assert read({"wire.rx_bytes": 5_000_000,
+                 "wire.rx_copied_bytes": 200_000}) == 4.0
+    assert read({"wire.rx_bytes": 0, "wire.rx_copied_bytes": 0}) is None
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        entry = [e for e in json.load(f)["per_layer"] if e["name"] == name]
+    assert len(entry) == 1 and entry[0]["workloads"] == cells
+    assert entry[0]["layer"] == "messenger" and entry[0]["moves"] == moves
+    assert entry[0]["source"] == "program_counter"
 
 
 class TestWirepathParity:

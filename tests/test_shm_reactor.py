@@ -406,11 +406,21 @@ class TestWholePlanePerf:
             got = []
 
             async def disp(conn, msg):
+                if msg.seq < 0:
+                    return
                 got.append(msg.seq)
                 if len(got) >= 16:
                     done.set()
 
             b.dispatcher = disp
+            # open the lanes first and wait until b's workers own them:
+            # what reaches b's parent before its lane is delegated is
+            # framed there (FrameReceiver), not by a worker
+            await a.send(addr_b, MProc(seq=-1))
+            deadline = asyncio.get_running_loop().time() + 20
+            while b.perf.dump()["proc_delegated_conns"] < 2 \
+                    and asyncio.get_running_loop().time() < deadline:
+                await asyncio.sleep(0.05)
             for i in range(16):
                 await a.send(addr_b, MProc(seq=i, data=b"p" * 8192))
             await asyncio.wait_for(done.wait(), 20)
