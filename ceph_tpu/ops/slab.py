@@ -32,10 +32,19 @@ the page table above, host copies only at the true I/O boundary:
   slices may alias the batching queue's shared product
   (parallel/service.py), and an install spread over sub-slabs feeds
   the same source to each of its programs.
-- ``slab_gather(slab, idx)`` reads rows back as one jitted take; the
-  result is a fresh device buffer (never a view of the slab), so a
-  gather that raced a later donated install still holds the bytes it
-  read.
+- ``slab_gather(slab_at, slab_of, rows)`` reads a span's pages back
+  into ONE ``[bucket, page_words]`` buffer in span order, one jitted
+  program per touched sub-slab: the first sub-slab's take fills every
+  position (row 0 where a page lies elsewhere), each further sub-slab's
+  program overwrites the positions it owns.  ``span_rows`` then cuts the
+  bit-rows out of that buffer (flatten, slice, bitcast, reshape) as one
+  more program.  Nothing here is an eager jnp op: in a store that stays
+  at its evict line a span's pages come off a scattered free list, split
+  over sub-slabs another way every time, and an eager slice or
+  concatenate compiles once per split, on the event loop, inside a
+  served window (PERF.md, PR 33).  The result is a fresh device buffer
+  (never a view of the slab), so a gather that raced a later donated
+  install still holds the bytes it read.
 
 The install compiles per SOURCE GEOMETRY only, never per group size: an
 install whose pages come off a fragmented free list lands a different
@@ -48,9 +57,10 @@ and the redundant HBM writes cost nothing beside a host dispatch.  One
 program per (source shape, ``cols``, ``page_words``, donate) serves
 every group size, so there is nothing to enumerate ahead of time: the
 first install of a geometry compiles it (the served path's warm-up),
-and ``prewarm`` covers the gathers alone.  The gather compiles per
-(page_words, pow2-bucketed row count), padding ``idx`` by repeating the
-last index.  Both sit behind the same OrderedDict-LRU discipline as
+and ``prewarm`` covers the gathers alone.  The two gather programs
+compile per (page_words, pow2-bucketed page count) and never per split;
+``span_rows`` per (bucket, row range, resident geometry), at the first
+device read of a geometry.  All sit behind the same OrderedDict-LRU discipline as
 gf2's XOR-schedule cache, with the ``slab_kernels`` counter set
 mirroring SCHED_PERF.
 
@@ -209,7 +219,8 @@ def install_fn(src_shape, cols: int, page_words: int, donate: bool):
 
 
 def gather_fn(page_words: int, nb: int):
-    """The jitted (LRU-cached) gather kernel for one page geometry."""
+    """The jitted (LRU-cached) take for one page geometry: a span's first
+    sub-slab."""
     def _gather(s, i):
         with jax.named_scope("slab_gather"):
             return s[i]
@@ -217,21 +228,70 @@ def gather_fn(page_words: int, nb: int):
     return _kernel(("gather", page_words, nb), lambda: jax.jit(_gather))
 
 
-def slab_gather(slab, idx: np.ndarray):
-    """Gather rows ``idx`` from the sub-slab as a fresh [n, page_words]
-    device array (never a view — safe across later donated installs)."""
-    n = int(idx.shape[0])
+def gather_into_fn(page_words: int, nb: int):
+    """The jitted (LRU-cached) take of a span's FURTHER sub-slab: rows
+    ``i`` of ``s`` where ``own``, what ``acc`` holds elsewhere."""
+    def _gather_into(acc, s, i, own):
+        with jax.named_scope("slab_gather"):
+            return jnp.where(own[:, None], s[i], acc)
+
+    return _kernel(("gather_into", page_words, nb),
+                   lambda: jax.jit(_gather_into))
+
+
+def slab_gather(slab_at, slab_of: np.ndarray, rows: np.ndarray):
+    """The pages (sub-slab ``slab_of[j]``, row ``rows[j]``) as ONE fresh
+    ``[bucket_rows(n), page_words]`` device buffer in that order (rows
+    past ``n`` are filler): one jitted program per touched sub-slab,
+    whatever the split, and no other device call.  ``slab_at(s)`` hands
+    over sub-slab ``s``; the index and mask arrays are numpy arguments of
+    the programs."""
+    n = len(rows)
     nb = bucket_rows(n)
-    idx = np.asarray(idx, dtype=np.int32)
-    if nb != n:
-        idx = np.concatenate(
-            [idx, np.full(nb - n, idx[-1], dtype=idx.dtype)])
-    out = gather_fn(int(slab.shape[1]), nb)(slab, jnp.asarray(idx))
-    return out if nb == n else out[:n]
+    acc = None
+    for s in np.unique(slab_of).tolist():
+        own = np.zeros(nb, dtype=bool)
+        own[:n] = slab_of == s
+        idx = np.zeros(nb, dtype=np.int32)
+        idx[:n] = np.where(own[:n], rows, 0)
+        slab = slab_at(s)
+        if acc is None:
+            acc = gather_fn(int(slab.shape[1]), nb)(slab, idx)
+        else:
+            acc = gather_into_fn(int(slab.shape[1]), nb)(acc, slab, idx, own)
+    return acc
+
+
+def span_rows_fn(nb: int, page_words: int, start: int, length: int,
+                 n_rows: int, cols: int, planes8: bool):
+    """The jitted (LRU-cached) cut of bit-rows out of gathered pages, for
+    one (bucket, range, resident geometry)."""
+    def build():
+        def _rows(p):
+            with jax.named_scope("slab_gather"):
+                out = p.reshape(-1)[start:start + length]
+                if planes8:
+                    out = jax.lax.bitcast_convert_type(out, jnp.int8)
+                return out.reshape(n_rows, cols)
+
+        return jax.jit(_rows)
+
+    return _kernel(("rows", nb, page_words, start, length, n_rows, cols,
+                    planes8), build)
+
+
+def span_rows(pages, start: int, length: int, n_rows: int, cols: int,
+              planes8: bool):
+    """Words ``[start, start + length)`` of the gathered pages' flat image
+    as the resident's ``[n_rows, cols]`` bit-rows (``planes8``: the int8
+    plane layout, each u32 word four bytes, LSB first as numpy's view on
+    the little-endian hosts this runs on) — ONE jitted program."""
+    return span_rows_fn(int(pages.shape[0]), int(pages.shape[1]), start,
+                        length, n_rows, cols, planes8)(pages)
 
 
 def prewarm(page_words: int, max_rows: int = 256) -> int:
-    """Compile the gather kernel for every pow2 row bucket up to
+    """Compile the two gather programs for every pow2 page bucket up to
     ``max_rows`` (one sub-slab's worth) at store build, OFF the read
     path — the AOT discipline: a served window must never pay an
     in-line XLA compile for a geometry the configured page size makes
@@ -243,8 +303,10 @@ def prewarm(page_words: int, max_rows: int = 256) -> int:
     slab = new_subslab(max_rows, page_words)
     nb = 1
     while nb <= max_rows:
-        jax.block_until_ready(
-            slab_gather(slab, np.arange(nb, dtype=np.int32) % max_rows))
+        # every other page "in a second sub-slab": both programs run
+        jax.block_until_ready(slab_gather(
+            lambda _s: slab, np.arange(nb) % 2,
+            np.arange(nb, dtype=np.int32) % max_rows))
         nb <<= 1
     return int(SLAB_PERF.get("compile") - before)
 

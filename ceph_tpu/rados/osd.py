@@ -3523,64 +3523,69 @@ class OSD:
                                     t.mark_event("resident_hit")
                                 return MOSDOpReply(ok=True, data=data,
                                                    version=ent.object_version)
-        available = {
-            shard: osd for shard, osd in enumerate(acting)
-            if osd != CRUSH_ITEM_NONE and shard not in exclude_shards
-        }
-        # ask the codec which shards suffice (subchunk-aware plan); the
-        # wanted shards are the codec's DATA positions, which mapped codecs
-        # (lrc) place at chunk_index(i), not at 0..k-1
-        mapping = codec.get_chunk_mapping()
-        want = {mapping[i] if mapping else i for i in range(k)}
-        try:
-            plan = codec.minimum_to_decode(want, set(available))
-        except ErasureCodeError:
-            # fewer than k live ACTING members (e.g. a pg_temp override
-            # whose members died): the data may still exist on past
-            # holders — fall through to the shard hunt instead of failing
-            plan = []
-        tid = uuid.uuid4().hex
-        chunks: Dict[int, bytes] = {}
-        versions: Dict[int, int] = {}
-        sizes: Dict[int, int] = {}
-        remote = []
-        for shard in plan:
-            osd = available[shard]
-            if osd == self.osd_id:
-                got = self._store_read((op.pool_id, op.oid, shard))
-                if got is not None:
-                    chunks[shard] = got[0]
-                    versions[shard] = got[1].version
-                    sizes[shard] = got[1].object_size
-            else:
-                remote.append((shard, osd))
-        q = self._collector(tid)
+        # the shard read's own work is one section in two parts (plan and
+        # requests; replies and the version cut): the sends and the gather
+        # between them are awaits, and waits stay out of self time
+        with tracing.section("osd", "read_shards"):
+            available = {
+                shard: osd for shard, osd in enumerate(acting)
+                if osd != CRUSH_ITEM_NONE and shard not in exclude_shards
+            }
+            # ask the codec which shards suffice (subchunk-aware plan); the
+            # wanted shards are the codec's DATA positions, which mapped
+            # codecs (lrc) place at chunk_index(i), not at 0..k-1
+            mapping = codec.get_chunk_mapping()
+            want = {mapping[i] if mapping else i for i in range(k)}
+            try:
+                plan = codec.minimum_to_decode(want, set(available))
+            except ErasureCodeError:
+                # fewer than k live ACTING members (e.g. a pg_temp override
+                # whose members died): the data may still exist on past
+                # holders — fall through to the shard hunt instead of failing
+                plan = []
+            tid = uuid.uuid4().hex
+            chunks: Dict[int, bytes] = {}
+            versions: Dict[int, int] = {}
+            sizes: Dict[int, int] = {}
+            requests = []
+            for shard in plan:
+                osd = available[shard]
+                if osd == self.osd_id:
+                    got = self._store_read((op.pool_id, op.oid, shard))
+                    if got is not None:
+                        chunks[shard] = got[0]
+                        versions[shard] = got[1].version
+                        sizes[shard] = got[1].object_size
+                else:
+                    requests.append((osd, MECSubRead(
+                        pool_id=op.pool_id, pg=pg, oid=op.oid, shard=shard,
+                        tid=tid, reply_to=self.addr)))
+            q = self._collector(tid)
         tracked = getattr(op, "_tracked", None)
         if tracked is not None:
             tracked.mark_event("sub_reads_sent")
         sent = 0
-        for shard, osd in remote:
-            msg = MECSubRead(
-                pool_id=op.pool_id, pg=pg, oid=op.oid, shard=shard, tid=tid,
-                reply_to=self.addr,
-            )
+        for osd, msg in requests:
             try:
                 await self.messenger.send(self.osdmap.addr_of(osd), msg)
                 sent += 1
             except TRANSPORT_ERRORS:
                 pass
-        for r in await self._gather(tid, q, sent):
-            if r.ok:
-                chunks[r.shard] = r.chunk
-                versions[r.shard] = r.version
-                sizes[r.shard] = r.object_size
-        # consistent-version cut: only shards at ONE version may mix in a
-        # decode.  Prefer the newest version that is COMPLETE (>= k
-        # shards): a failed overwrite can leave a partial newer version
-        # that must not poison reads of the intact older one (the
-        # reference's last_complete / rollback semantics).
-        newest = max(versions.values()) if versions else -1
-        complete = {s: c for s, c in chunks.items() if versions[s] == newest}
+        replies = await self._gather(tid, q, sent)
+        with tracing.section("osd", "read_shards"):
+            for r in replies:
+                if r.ok:
+                    chunks[r.shard] = r.chunk
+                    versions[r.shard] = r.version
+                    sizes[r.shard] = r.object_size
+            # consistent-version cut: only shards at ONE version may mix in
+            # a decode.  Prefer the newest version that is COMPLETE (>= k
+            # shards): a failed overwrite can leave a partial newer version
+            # that must not poison reads of the intact older one (the
+            # reference's last_complete / rollback semantics).
+            newest = max(versions.values()) if versions else -1
+            complete = {s: c for s, c in chunks.items()
+                        if versions[s] == newest}
         if len(complete) < k:
             # shard hunt: shards carry their id, so a degraded read
             # survives placement drift between failure and recovery
@@ -6235,51 +6240,16 @@ class OSD:
                                     data: bytes, version: int) -> None:
         tracked = self.ctx.op_tracker.create(
             f"tier_promote({pool.pool_id} {oid})")
+        t0 = time.monotonic()
         try:
             tracked.mark_event("encode_dispatched")
             planar = await planar_encode_async(
                 self._codec(pool), self._sinfo(pool), data,
                 queue=self._ec_queue)
-            if planar is None:
-                # codec not planar-eligible (mapped/bit-layout plugins)
-                self.tier_perf.inc("promote_skipped")
-                tracked.mark_event("skipped")
-                return
-            # staleness gate: between the read and this install a write
-            # may have landed.  The log check and the install below are
-            # synchronous (no await between them), so a write appending
-            # a newer entry either already moved the head (we skip) or
-            # will install its own newer resident after ours.  A TRIMMED
-            # log (latest_entry None — long-lived objects outlive the
-            # per-PG log window) is NOT stale: no entry means no recent
-            # write, and the serving paths re-validate the resident's
-            # version on every read anyway, so a mis-install can never
-            # be served.
-            pg = self.osdmap.object_to_pg(pool, oid)
-            ent = self._pglog(pool.pool_id, pg).latest_entry(oid)
-            if ent is not None and (ent.op != "write"
-                                    or ent.object_version != version):
-                self.tier_perf.inc("promote_stale")
-                tracked.mark_event("stale")
-                return
-            pkey = self._planar_key(pool.pool_id, oid)
-            if not self._install_resident(
-                    pkey, planar, version, len(data),
-                    self._codec(pool).get_data_chunk_count()):
-                # paged pool full of dirty / oversized resident: the
-                # promotion stays cold and retries on a later read
-                self.tier_perf.inc("promote_skipped")
-                tracked.mark_event("refused")
-                return
-            # the promoted bytes ARE the pack of the resident's data
-            # rows at this version: seed the exit-boundary memo so the
-            # first resident hit serves host bytes with zero device
-            # work (the pack is already paid — it happened as part of
-            # this promote's encode)
-            self._planar.memo_put(pkey, version, data)
-            self.tier_perf.inc("promote")
-            self.tier_perf.inc("promote_bytes", len(data))
-            tracked.mark_event("installed")
+            event = self._promote_install(pool, oid, data, version, planar)
+            tracked.mark_event(event)
+            if event == "installed":
+                self.tier_perf.tinc("promote_lat", time.monotonic() - t0)
         except (asyncio.CancelledError, GeneratorExit):
             raise
         except Exception as e:
@@ -6288,6 +6258,48 @@ class OSD:
                 "osd", f"tier promote {oid}: {type(e).__name__}: {e}")
         finally:
             tracked.finish()
+
+    @tracing.sectioned("osd", "tier_promote")
+    def _promote_install(self, pool: PoolInfo, oid: str, data: bytes,
+                         version: int, planar) -> str:
+        """The synchronous half of a promotion, after its encode: the
+        staleness gate, the install and the memo, with no await between
+        them.  Returns the op tracker's event: installed, skipped, stale
+        or refused."""
+        if planar is None:
+            # codec not planar-eligible (mapped/bit-layout plugins)
+            self.tier_perf.inc("promote_skipped")
+            return "skipped"
+        # staleness gate: between the read and this install a write may
+        # have landed.  The log check and the install are synchronous, so
+        # a write appending a newer entry either already moved the head
+        # (we skip) or will install its own newer resident after ours.  A
+        # TRIMMED log (latest_entry None — long-lived objects outlive the
+        # per-PG log window) is NOT stale: no entry means no recent
+        # write, and the serving paths re-validate the resident's version
+        # on every read anyway, so a mis-install can never be served.
+        pg = self.osdmap.object_to_pg(pool, oid)
+        ent = self._pglog(pool.pool_id, pg).latest_entry(oid)
+        if ent is not None and (ent.op != "write"
+                                or ent.object_version != version):
+            self.tier_perf.inc("promote_stale")
+            return "stale"
+        pkey = self._planar_key(pool.pool_id, oid)
+        if not self._install_resident(
+                pkey, planar, version, len(data),
+                self._codec(pool).get_data_chunk_count()):
+            # paged pool full of dirty / oversized resident: the
+            # promotion stays cold and retries on a later read
+            self.tier_perf.inc("promote_skipped")
+            return "refused"
+        # the promoted bytes ARE the pack of the resident's data rows at
+        # this version: seed the exit-boundary memo so the first resident
+        # hit serves host bytes with zero device work (the pack is
+        # already paid — it happened as part of this promote's encode)
+        self._planar.memo_put(pkey, version, data)
+        self.tier_perf.inc("promote")
+        self.tier_perf.inc("promote_bytes", len(data))
+        return "installed"
 
     def _replicate_hit_set(self, pool: PoolInfo, pg: int,
                            acting: List[int], arch: HitSetArchive) -> None:

@@ -678,39 +678,22 @@ class PagedResidentStore:
     def _gather_device_locked(self, e: _Entry, r0: int, r1: int,
                               w0: int, w1: int, p0: int,
                               span: List[int]):
-        """Device-arm gather (lock held): one take kernel per touched
-        sub-slab run, concatenated and sliced ON DEVICE.  The result is
-        a fresh device buffer (never a slab view) — it stays valid
+        """Device-arm gather (lock held): one jitted program per touched
+        sub-slab lands the span's pages in one buffer, one more cuts the
+        bit-rows out of it (ceph_tpu.ops.slab) — no eager op, so a span
+        split over sub-slabs in a new way compiles nothing.  The result
+        is a fresh device buffer (never a slab view) — it stays valid
         across later donated installs and feeds the jitted decode path
         without leaving HBM; the host exit is read()/ecutil's
         ``_pack_rows`` (counted as ``d2h_gathers`` via note_d2h)."""
-        import jax
-        import jax.numpy as jnp
+        from ceph_tpu.ops.slab import slab_gather, span_rows
 
-        from ceph_tpu.ops.slab import slab_gather
-
-        mask = (1 << _SLAB_SHIFT) - 1
-        parts = []
-        i = 0
-        while i < len(span):
-            s = span[i] >> _SLAB_SHIFT
-            idx = []
-            while i < len(span) and (span[i] >> _SLAB_SHIFT) == s:
-                idx.append(span[i] & mask)
-                i += 1
-            parts.append(slab_gather(self._dev_slab(s),
-                                     np.array(idx, dtype=np.int32)))
-        block = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-        flat = block.reshape(-1)
-        start = w0 - p0 * self.page_words
-        out = flat[start:start + (w1 - w0)]
-        if np.dtype(e.dtype) != np.uint32:
-            # little-endian u32 -> byte planes: bitcast appends a
-            # trailing dim of 4 (LSB first), matching numpy .view on
-            # the LE hosts this runs on (itemsize is 1 here — the
-            # planes layout)
-            out = jax.lax.bitcast_convert_type(out, jnp.int8)
-        return out.reshape(r1 - r0, e.cols)
+        pids = np.array(span, dtype=np.int32)
+        pages = slab_gather(self._dev_slab, pids >> _SLAB_SHIFT,
+                            pids & ((1 << _SLAB_SHIFT) - 1))
+        return span_rows(pages, w0 - p0 * self.page_words, w1 - w0,
+                         r1 - r0, e.cols,
+                         bool(np.dtype(e.dtype) != np.uint32))
 
     @tracing.sectioned("store", "resident_gather")
     def gather_rows(self, key: Any, r0: int, r1: int):

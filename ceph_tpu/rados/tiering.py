@@ -535,6 +535,10 @@ def build_tier_perf() -> PerfCounters:
                          "deferred k+m EC encodes performed by the "
                          "flush path (one per raw dirty object destaged)")
         .add_time_avg("agent_pass_s", "agent pass wall seconds")
+        .add_time_avg("promote_lat",
+                      "seconds from a promotion's start (the read's "
+                      "bytes in hand) to its pages installed: queue "
+                      "wait, H2D, resident encode, install")
         .add_u64("flush_backlog_bytes",
                  "acked-but-not-EC-durable raw dirty bytes awaiting "
                  "flush on this OSD (gauge)")

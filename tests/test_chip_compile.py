@@ -198,6 +198,27 @@ def test_slab_gather(one_chip, rows):
              _spec((rows,), np.int32, one_chip))
 
 
+@pytest.mark.parametrize("pages,n_rows", [
+    (64, 64),    # k=8 m=3, 4 MiB: the data rows a served read packs
+    (128, 88),   # the whole resident (planar_rows): 88 pages, bucket 128
+    (8, 8),      # one shard's rows (planar_shard_bytes)
+])
+def test_slab_gather_span(one_chip, pages, n_rows):
+    """A span's further sub-slabs and the cut of its bit-rows: the two
+    programs PR 33 put where the read kept an eager concatenate, slice
+    and reshape, at the served read's shapes."""
+    from ceph_tpu.ops.slab import gather_into_fn, span_rows_fn
+
+    pw = (64 << 10) // 4
+    _compile(gather_into_fn(pw, pages),
+             _spec((pages, pw), np.uint32, one_chip),
+             _spec((256, pw), np.uint32, one_chip),
+             _spec((pages,), np.int32, one_chip),
+             _spec((pages,), np.bool_, one_chip))
+    _compile(span_rows_fn(pages, pw, 0, n_rows * pw, n_rows, pw, False),
+             _spec((pages, pw), np.uint32, one_chip))
+
+
 @pytest.mark.parametrize("width", [
     "object4MiB", pytest.param("dispatch16MiB", marks=pytest.mark.slow)])
 def test_mesh_encode_resident_four_chips(topo, mats, width):

@@ -1060,7 +1060,8 @@ class TestFramer:
 @pytest.mark.parametrize("name, cells, moves", [
     ("rx_copy_share.put",
      ["k8m3.write4m", "k4m2.write4m", "k10m4c.write4m"], "put_MBps"),
-    ("rx_copy_share.get", ["k8m3.randread4m"], "get_MBps"),
+    ("rx_copy_share.get", ["k8m3.randread4m", "k8m3.randread4m-cold"],
+     "get_MBps"),
 ])
 def test_the_engagement_metric_reads_copied_over_received(name, cells, moves):
     """The benchmark's data files (ISSUE 32): bytes copied after the

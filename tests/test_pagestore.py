@@ -1235,6 +1235,51 @@ class TestDeviceArm:
         assert compiles == [1, 0, 0]
         assert dev.perf.get("device_installs") == 768 + 3
 
+    def test_fused_gather_compiles_per_bucket_never_per_split(self):
+        """Residents of one shape whose pages lie in three, four and one
+        sub-slabs read back byte-identical to the host arm, and only the
+        first read compiles: the gather's programs are keyed by the page
+        bucket and the row range, not by how the free list split the
+        span (an eager slice or concatenate compiled once per split, on
+        the event loop, inside a served window: PERF.md, PR 33)."""
+        import jax
+        import jax.numpy as jnp
+
+        from ceph_tpu.ops.slab import SLAB_PERF
+        from ceph_tpu.utils.jaxdev import compile_meter
+
+        host, dev = self._pair(256 << 10, 256)
+        self._fragment(host, dev)
+        meter = compile_meter()
+        splits, compiles, kernels = [], [], []
+        for n, seed in enumerate((7, 8, 9)):
+            bits = self._words(8, 128, seed=seed)
+            for st, src in ((host, bits), (dev, jnp.asarray(bits))):
+                assert st.put_planar(f"o{n}", src, w=8, n_rows=1,
+                                     trim=96 * 32)
+            splits.append(len(self._subslabs(dev, f"o{n}")))
+            before, built = meter.count, SLAB_PERF.get("compile")
+            for r0, r1 in ((0, 8), (0, 5)):
+                got = dev.gather_rows(f"o{n}", r0, r1)
+                assert isinstance(got, jax.Array)
+                np.testing.assert_array_equal(
+                    np.asarray(got), host.gather_rows(f"o{n}", r0, r1))
+            compiles.append(meter.count - before)
+            kernels.append(SLAB_PERF.get("compile") - built)
+        assert len(set(splits)) > 1 and max(splits) >= 3, splits
+        # first reads: rows 0-8 span 12 pages (bucket 16), rows 0-5 span
+        # 8: the two gather programs of each bucket and one program a row
+        # range; afterwards nothing, whatever the split
+        assert kernels == [6, 0, 0], kernels
+        assert compiles[1:] == [0, 0], compiles
+
+    def test_prewarm_compiles_both_gather_programs_of_every_bucket(self):
+        from ceph_tpu.ops import slab
+
+        _fresh_slab_cache()
+        assert slab.prewarm(64, max_rows=8) == 4 + 3  # 1,2,4,8 and 2,4,8
+        assert slab.prewarm(64, max_rows=8) == 0
+
     def test_env_override_pins_arms(self, monkeypatch):
         monkeypatch.setenv("CEPH_TPU_DEVICE_SLAB", "0")
         st = PagedResidentStore(capacity_bytes=1 << 20,

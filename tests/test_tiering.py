@@ -437,11 +437,14 @@ class TestTierEndToEnd:
     def test_recency_gate_and_fadvise(self, force_batching):
         """min_read_recency_for_promote=2 defers promotion to the
         second interval; dontneed reads never record or promote;
-        willneed promotes immediately."""
+        willneed promotes immediately.  The interval is one the test
+        cannot outlast and ends when the test says so: under a loaded
+        host a put and a get 0.3 s apart fell into two intervals and
+        the first get promoted."""
         async def go():
             cluster = Cluster(n_osds=3, conf={
                 "osd_auto_repair": False, "client_op_timeout": 60.0,
-                "osd_hit_set_period": 0.3,
+                "osd_hit_set_period": 3600.0,
                 "osd_min_read_recency_for_promote": 2})
             await cluster.start()
             try:
@@ -478,7 +481,9 @@ class TestTierEndToEnd:
                 assert counters("read_hits_recorded") == 1
                 assert not resident()
                 # next interval: recency reaches 2 -> promoted
-                await asyncio.sleep(0.35)
+                for o in cluster.osds.values():
+                    for arch in o._hit_sets.values():
+                        arch.rotate()
                 assert await c.get(pool, "obj") == blob
                 for _ in range(200):
                     if resident():
