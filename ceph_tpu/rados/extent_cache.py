@@ -14,6 +14,12 @@ moved under us — failover, recovery push, concurrent interval), and any
 put at a newer version drops the stale extents.  Whole-object entries are
 extents covering [0, size) with `full=True`, preserving the previous
 whole-object behavior for reads and full writes.
+
+A whole-object put copies nothing it can keep (`put_full`): the put path
+pays for no reader that may never come.  So a cached run is a BUFFER —
+`bytes`, or a read-only `memoryview` of a payload as the wire delivered
+it — and a reader that needs `bytes` semantics (concatenation, hashing)
+normalises where it reads.
 """
 
 from __future__ import annotations
@@ -22,6 +28,22 @@ from collections import OrderedDict
 from typing import List, Optional, Tuple
 
 Key = Tuple[int, str]  # (pool_id, oid)
+
+
+def _keepable(data) -> bool:
+    """True when caching `data` itself is as good as caching a copy:
+    `bytes`, or a read-only flat byte view of the WHOLE of a buffer that
+    owns its memory.  A writable view can change under the cache; a view
+    of part of something larger (a lane fragment of its group's assembly
+    buffer) would pin the rest of it."""
+    if isinstance(data, bytes):
+        return True
+    if not (isinstance(data, memoryview) and data.readonly
+            and data.format == "B" and data.ndim == 1 and data.contiguous):
+        return False
+    owner = data.obj
+    return getattr(owner, "base", None) is None \
+        and memoryview(owner).nbytes == data.nbytes
 
 
 class _Entry:
@@ -48,7 +70,7 @@ class _Entry:
             lo = min(s, new_start)
             pre = b[: max(0, new_start - s)]
             post = b[max(0, new_start + len(new_data) - s):]
-            new_data = pre + new_data + post
+            new_data = b"".join((pre, new_data, post))
             new_start = lo
         for i, (s, _b) in enumerate(merged):
             if s > new_start:
@@ -95,13 +117,20 @@ class ExtentCache:
             self._entries.popitem(last=False)
         return ent
 
-    def put_full(self, key: Key, version: int, data: bytes) -> None:
+    def put_full(self, key: Key, version: int, data) -> bool:
+        """Cache the whole object.  Returns False when that cost a copy
+        of `data`, True when it did not: `data` itself is cached (see
+        `_keepable`; the caller writes into it no more), or the put was
+        stale and nothing is."""
         ent = self._entry_for_put(key, version)
         if ent is None:
-            return
-        ent.extents = [(0, bytes(data))]
+            return True
+        kept = _keepable(data)
+        run = data if kept else bytes(data)
+        ent.extents = [(0, run)]
         ent.full = True
-        ent.size = len(data)
+        ent.size = len(run)
+        return kept
 
     def put_extent(self, key: Key, version: int, start: int,
                    data: bytes, size_hint: int = 0,

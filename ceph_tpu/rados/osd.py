@@ -316,6 +316,14 @@ class OSD:
             .add_u64_counter("rmw_partial", "stripe-scoped partial overwrites")
             .add_u64_counter("rmw_extent_hits",
                              "RMW reads served from the extent cache")
+            .add_u64_counter("write_adopted_bytes",
+                             "EC write payload bytes the extent cache "
+                             "keeps by reference (no copy on the put "
+                             "path)")
+            .add_u64_counter("write_copied_bytes",
+                             "EC write payload bytes copied for the "
+                             "extent cache (a writable view, or a view "
+                             "of part of a larger buffer)")
             .add_u64_counter("planar_read_hits",
                              "reads served from planar HBM residents "
                              "with zero shard reads")
@@ -2296,8 +2304,8 @@ class OSD:
     # -- extent cache (primary-side RMW pinning) ------------------------------
 
     def _cache_put(self, pool_id: int, oid: str, version: int,
-                   data: bytes) -> None:
-        self._extent_cache.put_full((pool_id, oid), version, data)
+                   data) -> bool:
+        return self._extent_cache.put_full((pool_id, oid), version, data)
 
     def _cache_get(self, pool_id: int, oid: str) -> Optional[Tuple[int, bytes]]:
         return self._extent_cache.get_full((pool_id, oid))
@@ -3088,7 +3096,15 @@ class OSD:
             shard_size = 0
             base_version = 0
             object_size = len(op.data)
-            full_for_cache: Optional[bytes] = bytes(op.data)
+            # what a LATER partial overwrite splices against: the payload
+            # as it was received, kept by reference — the put path copies
+            # no payload.  Nothing writes into it from here on: the
+            # receiver is done with a frame's buffer once it is delivered,
+            # the encode only reads it, and on the in-process paths a
+            # message is immutable once sent (LocalConnection's contract)
+            full_for_cache = (op.data.toreadonly()
+                              if isinstance(op.data, memoryview)
+                              else op.data)
         if op.offset >= 0:
             span.event("rmw read")
             mark("rmw_read")
@@ -3360,7 +3376,11 @@ class OSD:
                     if isinstance(data, bytes) and len(data) == object_size:
                         self._planar.memo_put(pkey, version, data)
             if full_for_cache is not None:
-                self._cache_put(op.pool_id, op.oid, version, full_for_cache)
+                kept = self._cache_put(op.pool_id, op.oid, version,
+                                       full_for_cache)
+                self.perf.inc("write_adopted_bytes" if kept
+                              else "write_copied_bytes",
+                              len(full_for_cache))
             elif chunk_off >= 0:
                 # segment RMW: pin the freshly-written stripes at the NEW
                 # version; carry_from upgrades the entry in place (nothing
@@ -5267,6 +5287,20 @@ class OSD:
             arch.retune(period, count, target, fpp)
         return arch
 
+    def _tier_rotated(self, pool: PoolInfo, pg: int, acting: List[int],
+                      arch: HitSetArchive) -> None:
+        """A record() rotated the PG's archive: refresh the fpp gauge
+        over every archive this OSD holds (its own PGs' and the ones
+        peers pushed) and push the rotated one.  On an op's path: each
+        filter answers from its running count, no bits are walked
+        (`hitset_bits_scanned` is where a walk that comes back counts
+        itself)."""
+        self.tier_perf.inc("hitset_rotations")
+        worst = max((a.estimated_fpp()
+                     for a in self._hit_sets.values()), default=0.0)
+        self.tier_perf.set("hitset_fpp_ppm", int(worst * 1e6))
+        self._replicate_hit_set(pool, pg, acting, arch)
+
     def _tier_cache_mode(self, pool: PoolInfo) -> str:
         """The pool's cache mode (mon-validated pool opt `cache_mode`
         over the osd_tier_cache_mode default).  writeback engages only
@@ -5336,11 +5370,7 @@ class OSD:
         rotated = arch.record(op.oid)
         self.tier_perf.inc("write_hits_recorded")
         if rotated:
-            self.tier_perf.inc("hitset_rotations")
-            worst = max((a.estimated_fpp()
-                         for a in self._hit_sets.values()), default=0.0)
-            self.tier_perf.set("hitset_fpp_ppm", int(worst * 1e6))
-            self._replicate_hit_set(pool, pg, acting, arch)
+            self._tier_rotated(pool, pg, acting, arch)
         if not full or self._planar is None or not nbytes:
             return None
         recency_min = self._tier_opt(
@@ -6182,11 +6212,7 @@ class OSD:
         rotated = arch.record(op.oid)
         self.tier_perf.inc("read_hits_recorded")
         if rotated:
-            self.tier_perf.inc("hitset_rotations")
-            worst = max((a.estimated_fpp()
-                         for a in self._hit_sets.values()), default=0.0)
-            self.tier_perf.set("hitset_fpp_ppm", int(worst * 1e6))
-            self._replicate_hit_set(pool, pg, acting, arch)
+            self._tier_rotated(pool, pg, acting, arch)
         if self._planar is None:
             return
         # already resident at this version?  resident_meta: a policy

@@ -51,10 +51,16 @@ class BloomHitSet:
     false-positive rate.  The encoding is a pinned binary layout (struct
     header + raw bit bytes) checked by the wire corpus, so archives
     written by one version keep decoding in the next.
+
+    The filter keeps the number of its set bits (``_ones``): insert
+    counts each bit it flips, decode counts the bits it is handed once,
+    so the fill ratio and the estimated fpp are O(1) however often the
+    owner's gauge asks (every rotation, over every archive it holds).
+    The count is not on the wire.
     """
 
     __slots__ = ("seed", "fpp", "target_size", "nbits", "nhash",
-                 "inserted", "_bits")
+                 "inserted", "_bits", "_ones")
 
     def __init__(self, target_size: int = 128, fpp: float = 0.05,
                  seed: int = 0):
@@ -72,6 +78,7 @@ class BloomHitSet:
         self.target_size = target_size
         self.inserted = 0
         self._bits = bytearray((self.nbits + 7) // 8)
+        self._ones = 0
 
     # -- hashing -------------------------------------------------------------
 
@@ -90,9 +97,13 @@ class BloomHitSet:
 
     def insert(self, oid: str) -> None:
         h1, h2 = self._digests(oid)
+        bits = self._bits
         for i in range(self.nhash):
             bit = (h1 + i * h2) % self.nbits
-            self._bits[bit >> 3] |= 1 << (bit & 7)
+            byte, mask = bit >> 3, 1 << (bit & 7)
+            if not bits[byte] & mask:
+                bits[byte] |= mask
+                self._ones += 1
         self.inserted += 1
 
     def __contains__(self, oid: str) -> bool:
@@ -108,8 +119,7 @@ class BloomHitSet:
     # -- introspection -------------------------------------------------------
 
     def fill_ratio(self) -> float:
-        ones = sum(bin(b).count("1") for b in self._bits)
-        return ones / self.nbits
+        return self._ones / self.nbits
 
     def estimated_fpp(self) -> float:
         """The CURRENT false-positive probability from the observed fill
@@ -158,6 +168,7 @@ class BloomHitSet:
         hs.nhash = nhash
         hs.inserted = inserted
         hs._bits = bytearray(blob[off:off + nbytes])
+        hs._ones = int.from_bytes(hs._bits, "little").bit_count()
         return hs, off + nbytes
 
 
@@ -472,6 +483,10 @@ def build_tier_perf() -> PerfCounters:
                          "write installs refused by the promote "
                          "throttle")
         .add_u64_counter("hitset_rotations", "hit-set intervals archived")
+        .add_u64_counter("hitset_bits_scanned",
+                         "filter bits walked on an op's path to produce "
+                         "a fill ratio (a filter keeps a running count: "
+                         "0 unless a walk comes back)")
         .add_u64_counter("resident_hit",
                          "reads served from a device resident "
                          "(zero shard reads, zero decode)")

@@ -473,6 +473,121 @@ class TestExtentCache:
         c.drop(key)
         assert c.get_full(key) is None
 
+    @pytest.mark.parametrize("case", [
+        "bytes", "whole_readonly_view", "whole_view_of_bytearray",
+        "slice_of_larger", "window_on_larger_array", "writable_view",
+        "bytearray", "wide_items"])
+    def test_put_full_keeps_what_it_can_and_copies_the_rest(self, case):
+        """ISSUE 34: a whole-object put keeps the buffer it is handed
+        when that is bytes or a read-only view of all of a buffer that
+        owns its memory; a writable view can change under the cache and
+        a view of part of something larger would pin the rest: those
+        are copied."""
+        from ceph_tpu.rados.extent_cache import ExtentCache
+
+        arr = np.arange(4096, dtype=np.uint8)
+        keeps = True
+        if case == "bytes":
+            data = arr.tobytes()
+        elif case == "whole_readonly_view":
+            # how the wire delivers a blob (FrameReceiver._blob_dest)
+            data = memoryview(arr).cast("B").toreadonly()
+        elif case == "whole_view_of_bytearray":
+            data = memoryview(bytearray(arr.tobytes())).toreadonly()
+        elif case == "slice_of_larger":
+            # a lane fragment of its group's assembly buffer
+            data = memoryview(arr).cast("B")[1024:2048].toreadonly()
+            keeps = False
+        elif case == "window_on_larger_array":
+            data = memoryview(arr[:1024]).toreadonly()
+            keeps = False
+        elif case == "writable_view":
+            data = memoryview(arr).cast("B")
+            keeps = False
+        elif case == "bytearray":
+            data = bytearray(arr.tobytes())
+            keeps = False
+        else:
+            data = memoryview(np.arange(64, dtype=np.uint16)).toreadonly()
+            keeps = False
+        want = bytes(data)
+        c = ExtentCache()
+        key = (1, "o")
+        assert c.put_full(key, 3, data) is keeps
+        version, got = c.get_full(key)
+        assert version == 3 and bytes(got) == want
+        if keeps:
+            assert got is data
+            if isinstance(data, memoryview):
+                assert got.obj is data.obj and got.readonly
+        else:
+            assert type(got) is bytes
+            if case != "wide_items":
+                # the caller's later write does not reach the cache
+                if case == "bytearray":
+                    data[:] = bytes(len(data))
+                else:
+                    arr[:] = 0
+                assert c.get_full(key)[1] == want
+        # readers take any buffer: ranges, and a run spliced at the
+        # same version (bytes semantics are made where they are needed)
+        assert bytes(c.get_range(key, 8, 16)[1]) == want[8:24]
+        assert c.get_range(key, len(want), 4)[1] == b""
+        c.put_extent(key, 3, 4, b"zz")
+        assert bytes(c.get_full(key)[1]) == want[:4] + b"zz" + want[6:]
+        # a stale put costs nothing and caches nothing
+        assert c.put_full(key, 2, bytearray(b"old")) is True
+        assert c.get_full(key)[0] == 3
+
+    def test_wire_full_write_is_kept_not_copied_and_splices(self):
+        """Over the wire messenger (not the fast path) a put's payload
+        lands as a view of a buffer of its own: the primary caches THAT
+        (no copy), a partial overwrite splices against exactly those
+        bytes, and a read returns the spliced object."""
+        async def go():
+            cluster = Cluster(n_osds=4, conf={**CONF,
+                                              "ms_local_fastpath": False})
+            await cluster.start()
+            try:
+                c = await cluster.client()
+                pool = await c.create_pool("wire", profile=dict(PROFILE))
+                data = payload(512 * 1024, seed=5)
+
+                def total(key):
+                    return sum(o.perf.get(key)
+                               for o in cluster.osds.values())
+
+                await c.put(pool, "obj", data)
+                assert total("write_adopted_bytes") == len(data)
+                assert total("write_copied_bytes") == 0
+                _p, _pg, _acting, primary = _primary_of(
+                    cluster, c, pool, "obj")
+                _v, cached = primary._cache_get(pool, "obj")
+                assert isinstance(cached, memoryview) and cached.readonly
+                assert isinstance(cached.obj, np.ndarray) \
+                    and cached.obj.base is None \
+                    and cached.obj.nbytes == len(data)
+                assert cached == data
+                with pytest.raises(TypeError):
+                    cached[0] = 0
+                patch = payload(5000, seed=6)
+                off = 70_001
+                reads0 = total("rmw_read_bytes")
+                await c.put(pool, "obj", patch, offset=off)
+                want = data[:off] + patch + data[off + len(patch):]
+                assert total("rmw_read_bytes") == reads0  # cache served
+                assert total("write_adopted_bytes") == 2 * len(data)
+                assert total("write_copied_bytes") == 0
+                assert await c.get(pool, "obj") == want
+                for o in cluster.osds.values():
+                    o._extent_cache.clear()
+                assert await c.get(pool, "obj") == want  # from the shards
+                await c.stop()
+            finally:
+                await cluster.stop()
+
+        run(go(), timeout=90)
+
     def test_rmw_pipeline_hits_extent_cache(self):
         """Back-to-back partial overwrites to one region: the second+
         RMW must serve its read from the pinned extents (reference
@@ -509,3 +624,84 @@ class TestExtentCache:
                 await cluster.stop()
 
         _a.run(_a.wait_for(go(), 90))
+
+
+# -- the benchmark's engagement metrics (ISSUE 34) ---------------------------
+
+WRITE_CELLS = ["k8m3.write4m", "k4m2.write4m", "k10m4c.write4m"]
+READ_CELLS = ["k8m3.randread4m", "k8m3.randread4m-cold"]
+
+
+@pytest.mark.parametrize("name, cells, moves, full, empty, gone", [
+    ("write_copy_share.put", WRITE_CELLS, "put_MBps",
+     ({"osd.write_copied_bytes": 1 << 20,
+       "osd.write_adopted_bytes": 3 << 20}, 25.0),
+     {"osd.write_copied_bytes": 0, "osd.write_adopted_bytes": 1 << 22},
+     {"osd.write_copied_bytes": 0, "osd.write_adopted_bytes": 0}),
+    ("hitset_scan_bits_per_op.put", WRITE_CELLS, "put_MBps",
+     ({"tier.hitset_bits_scanned": 1_000_000, "objecter.op": 500}, 2000.0),
+     {"tier.hitset_bits_scanned": 0, "objecter.op": 500},
+     {"tier.hitset_bits_scanned": 0, "objecter.op": 0}),
+    ("hitset_scan_bits_per_op.get", READ_CELLS, "get_MBps",
+     ({"tier.hitset_bits_scanned": 1_000_000, "objecter.op": 500}, 2000.0),
+     {"tier.hitset_bits_scanned": 0, "objecter.op": 500},
+     {"tier.hitset_bits_scanned": 0, "objecter.op": 0}),
+])
+def test_the_engagement_metrics_resolve_and_read_their_counters(
+        name, cells, moves, full, empty, gone):
+    """The benchmark's data files: a window's counter delta gives the
+    share / the bits per op; a window in which the mechanism holds reads
+    0.0 and not nothing; a program without the counters (the parent)
+    reports nothing."""
+    import json
+    import os
+
+    from benchmarks import layers, manifest
+
+    def read(counters):
+        return layers.read(name, {"counters": counters})
+
+    assert read(full[0]) == full[1]
+    assert read(empty) == 0.0
+    assert read(gone) is None
+    assert read({"objecter.op": 500, "osd.op_w": 500}) is None
+    man = manifest.load()
+    entry = [e for e in man["per_layer"] if e["name"] == name]
+    assert len(entry) == 1 and entry[0]["workloads"] == cells
+    assert entry[0]["layer"] == "OSD op path" and entry[0]["moves"] == moves
+    assert entry[0]["source"] == "program_counter"
+    for cell in man["workloads"]:
+        reported = {m["name"] for m in
+                    manifest.metrics_of(man, cell["name"])[1]}
+        assert (name in reported) == (cell["name"] in cells)
+    with open(os.path.join(layers.DIR, name + ".json")) as f:
+        assert json.load(f)["source"] == "perf_counter"
+
+
+def test_a_rehearsed_put_cell_copies_no_payload_and_walks_no_filter():
+    """benchmarks/run.py --rehearse (the cell's own path at a tiny size,
+    over TCP): every put's payload is kept, not copied, and no hit-set
+    bit is walked; both metrics are in the traced line, at 0."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("CEPH_TPU_FORCE_BATCH", None)
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, "benchmarks", "run.py"),
+         "--workload", "k4m2.write4m", "--seed", "3000000019",
+         "--seconds", "2", "--trace", "1", "--rehearse"],
+        cwd=root, env=env, capture_output=True, text=True, timeout=300)
+    lines = [json.loads(ln) for ln in proc.stdout.splitlines() if ln.strip()]
+    assert lines, proc.stderr[-2000:]
+    last = lines[-1]
+    assert proc.returncode == 3 and last["rehearsal"]
+    assert last["would_be_correct"] is True, lines
+    assert last["attempted"] > 0 and last["failed"] == 0
+    assert last["metrics"]["write_copy_share.put"] == \
+        {"value": 0.0, "unit": "%"}
+    assert last["metrics"]["hitset_scan_bits_per_op.put"] == \
+        {"value": 0.0, "unit": "bits/op"}

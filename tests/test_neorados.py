@@ -120,9 +120,17 @@ class TestWriteOp:
                 assert not primary._meta_repl_pending
                 assert fail["n"] == 0  # injection actually fired
                 # every acting peer now holds the replicated metadata
+                # (the pump's sends are fire-and-forget: the queue is
+                # empty when the last one is SENT, the peer applies it
+                # a loop turn or a few later)
                 key = (ioc.pool_id, "robj", 0)
                 for peer_id in peers:
                     peer = cluster.osds[peer_id]
+                    for _ in range(100):
+                        if peer.store.omap_get(key).get("idx") \
+                                and peer.store.getattr(key, "who"):
+                            break
+                        await asyncio.sleep(0.05)
                     assert peer.store.omap_get(key).get("idx") == b"entry"
                     assert peer.store.getattr(key, "who") == b"x"
             finally:

@@ -102,6 +102,124 @@ class TestBloomHitSet:
         assert a.encode() != b.encode()
 
 
+def _popcount(hs):
+    """The set bits of a filter, walked afresh: what the parent's
+    fill_ratio summed on every call."""
+    return sum(bin(b).count("1") for b in hs._bits)
+
+
+def _old_fpp(hs):
+    return (_popcount(hs) / hs.nbits) ** hs.nhash
+
+
+def _filters_after_inserts():
+    hs = BloomHitSet(128, 0.05, seed=11)
+    for i in range(300):
+        hs.insert(f"obj-{i % 90}")  # repeats flip nothing twice
+    return [hs]
+
+
+def _filters_after_roundtrip():
+    hs = _filters_after_inserts()[0]
+    back, _ = BloomHitSet.decode(hs.encode())
+    assert back.encode() == hs.encode()  # the count is not on the wire
+    back.insert("one-more")  # a decoded filter keeps counting
+    # a foreign blob's stray bits past nbits count as the parent's
+    # per-byte sum counted them
+    odd = BloomHitSet(5, 0.3, seed=2)
+    assert odd.nbits % 8
+    blob = bytearray(odd.encode())
+    blob[-1] |= 0x80
+    stray, _ = BloomHitSet.decode(bytes(blob))
+    return [back, stray]
+
+
+def _filters_after_retune_and_rotation():
+    arch = HitSetArchive(period=1.0, count=4, target_size=32, fpp=0.05,
+                         seed=7, now=0.0)
+    for t in range(6):
+        for i in range(20 + t):
+            arch.record(f"o{i}", now=t * 1.1)
+    arch.retune(2.0, 2, 64, 0.01)
+    assert len(arch.archived) == 2
+    arch.record("after-retune", now=20.0)  # rotates: a fresh, resized set
+    assert arch.current.target_size == 64
+    back = HitSetArchive.decode(arch.encode(now=21.0), now=5.0)
+    assert back.estimated_fpp() == arch.estimated_fpp()
+    assert back.dump()["archived"] == [
+        {**d, "start": b["start"], "end": b["end"]}
+        for d, b in zip(arch.dump()["archived"], back.dump()["archived"])]
+    sets = [arch.current] + [h for _, _, h in arch.archived]
+    # the admin dump prints the parent's numbers
+    assert [(d["fill_ratio"], d["estimated_fpp"])
+            for d in [arch.dump()["current"]] + arch.dump()["archived"]] \
+        == [(round(_popcount(h) / h.nbits, 4), round(_old_fpp(h), 6))
+            for h in sets]
+    return sets + [back.current] + [h for _, _, h in back.archived]
+
+
+@pytest.mark.parametrize("build", [
+    _filters_after_inserts, _filters_after_roundtrip,
+    _filters_after_retune_and_rotation], ids=lambda f: f.__name__[9:])
+def test_running_count_equals_a_fresh_popcount(build):
+    """ISSUE 34: a filter's running count of set bits is what a walk of
+    the bits gives, however the bits came to be, and the fill ratio and
+    the estimated fpp are the parent's values to the last bit."""
+    filters = build()
+    assert any(hs._ones for hs in filters)
+    for hs in filters:
+        assert hs._ones == _popcount(hs)
+        assert hs.fill_ratio() == _popcount(hs) / hs.nbits
+        assert hs.estimated_fpp() == _old_fpp(hs)
+
+
+def test_a_rotation_with_30_archives_present_walks_no_filter():
+    """The gauge after a rotation asks every archive the OSD holds
+    (~29 x 9 filters on a k=8 m=3 OSD) for its fpp: no filter's bits are
+    walked for it, and `hitset_fpp_ppm` reads what the walk gave."""
+    from types import SimpleNamespace
+
+    walked = [0]
+
+    class Walked(bytearray):
+        def __iter__(self):
+            walked[0] += len(self)
+            return super().__iter__()
+
+        def __getitem__(self, i):
+            walked[0] += 1
+            return super().__getitem__(i)
+
+    archives = {}
+    for pg in range(30):
+        arch = HitSetArchive(period=1.0, count=8, seed=pg, now=0.0)
+        for t in range(10):
+            for i in range(3 * pg + t):
+                arch.record(f"pg{pg}/o{i}", now=float(t) * 1.01)
+        assert len(arch.archived) == 8
+        archives[(1, pg)] = arch
+    sets = [h for a in archives.values()
+            for h in [a.current] + [h for _, _, h in a.archived]]
+    assert len(sets) == 270
+    want = int(max(_old_fpp(h) for h in sets) * 1e6)
+    assert want > 0
+    for h in sets:
+        h._bits = Walked(h._bits)
+    pushed = []
+    osd = SimpleNamespace(
+        tier_perf=build_tier_perf(), _hit_sets=archives,
+        _replicate_hit_set=lambda *a: pushed.append(a))
+    arch = archives[(1, 3)]
+    assert arch.record("rotates", now=100.0)
+    osdmod.OSD._tier_rotated(osd, None, 3, [0, 1, 2], arch)
+    assert pushed == [(None, 3, [0, 1, 2], arch)]
+    assert osd.tier_perf.get("hitset_rotations") == 1
+    assert osd.tier_perf.get("hitset_fpp_ppm") == want
+    assert osd.tier_perf.get("hitset_bits_scanned") == 0
+    assert walked[0] == 0
+    assert _popcount(sets[0]) and walked[0] == len(sets[0]._bits)  # it sees
+
+
 # -- HitSetArchive -----------------------------------------------------------
 
 
