@@ -19,11 +19,20 @@ the page table above, host copies only at the true I/O boundary:
   the trim to ``cols``, the row-major flatten, the zero pad of the
   ragged tail, the view as ``[npages, page_words]``, the selection of
   source page rows ``idx[0]`` and the scatter to sub-slab rows
-  ``idx[1]``.  STATIC (compile key): the source's shape, ``cols``,
+  ``idx[1]``.  STATIC (compile key): the source's shape,
   ``page_words``, donate.  DYNAMIC: the slab, the source and the one
-  ``int32[2, npages]`` index array, built in numpy and passed as a
-  numpy argument — no eager jnp op, slice, reshape or index upload
-  runs on the calling (event-loop) thread.  The slab argument is
+  ``int32[2, npages + 1]`` index array, built in numpy and passed as a
+  numpy argument, whose last column is ``cols`` — no eager jnp op,
+  slice, reshape or index upload runs on the calling (event-loop)
+  thread.  A source used at its full width takes the flatten as a
+  reshape, a narrower one is compacted row by row: two branches of the
+  one program (``lax.cond``), so a pool of one object size pays nothing
+  for the others.  The source's shape is the
+  encode lane's pow2 column bucket, so a pool has one install program
+  per bucket however many object sizes it holds (an object store's
+  sizes are heavy-tailed: keyed by ``cols``, 4 KiB-4 MiB objects on a
+  32 KiB stripe would be 128 programs against an LRU of 64, each
+  rebuilt on the event loop when it came round again).  The slab argument is
   DONATED when the backend supports it, so the update is genuinely in
   place — no 2x-slab copy per install.  Donation discipline: the
   CALLER must drop its reference to the donated slab immediately (the
@@ -50,17 +59,20 @@ The install compiles per SOURCE GEOMETRY only, never per group size: an
 install whose pages come off a fragmented free list lands a different
 number of pages in each sub-slab it touches, and a program first seen
 inside a served window compiles there.  So the index array always has
-``npages`` columns — every page of the install — and a group that owns
-fewer pads it by REPEATING its last (source row, destination row) pair:
+``npages`` columns — every page the untrimmed source could fill — and
+a group that owns fewer pads it by REPEATING its last (source row,
+destination row) pair:
 duplicate scatter updates with identical payloads are deterministic,
 and the redundant HBM writes cost nothing beside a host dispatch.  One
-program per (source shape, ``cols``, ``page_words``, donate) serves
-every group size, so there is nothing to enumerate ahead of time: the
+program per (source shape, ``page_words``, donate) serves
+every group size and trim width, so there is nothing to enumerate ahead of time: the
 first install of a geometry compiles it (the served path's warm-up),
 and ``prewarm`` covers the gathers alone.  The two gather programs
 compile per (page_words, pow2-bucketed page count) and never per split;
-``span_rows`` per (bucket, row range, resident geometry), at the first
-device read of a geometry.  All sit behind the same OrderedDict-LRU discipline as
+``span_rows`` per (page bucket, row count, pow2 column bucket), told
+the range's start and the row width at run time; ``plane_window`` (a
+request's columns out of a coalesced group's product, for the queue's
+fan-out) per (product shape, column bucket).  All sit behind the same OrderedDict-LRU discipline as
 gf2's XOR-schedule cache, with the ``slab_kernels`` counter set
 mirroring SCHED_PERF.
 
@@ -93,7 +105,11 @@ SLAB_PERF = (
     .add_u64("entries", "live compiled slab kernels (gauge)")
     .create_perf_counters())
 
-_KERNEL_CAPACITY = 64
+# what a pool of mixed sizes can need, with room: ten source shapes of
+# the install, two gathers a page bucket, a row cut per (page bucket, row
+# count, column bucket), and the queue's plane windows, one per (product
+# width, request bucket): ~50 of those alone (PERF.md, PR 38)
+_KERNEL_CAPACITY = 256
 _KERNELS: "OrderedDict" = OrderedDict()
 _LOCK = threading.Lock()
 
@@ -177,45 +193,71 @@ def slab_install(slab, src, cols: int, src_rows: np.ndarray,
     (u32 [rows, cols_full], trimmed to ``cols``, flattened, zero-padded
     to whole pages) at rows ``dst_rows`` of the sub-slab — ONE jitted
     in-place program, donation-annotated when the backend supports it,
-    and no other device call.  Returns the NEW slab array; the caller
-    must forget the old one (it may be freed).  ``src`` is never donated
-    (it may alias a shared batch product)."""
+    and no other device call.  ``cols`` is told to the program at run
+    time: one program serves every width a source shape can carry.
+    Returns the NEW slab array; the caller must forget the old one (it
+    may be freed).  ``src`` is never donated (it may alias a shared
+    batch product)."""
     page_words = int(slab.shape[1])
     shape = (int(src.shape[0]), int(src.shape[1]))
     n = len(src_rows)
-    idx = np.empty((2, install_pages(shape, cols, page_words)),
-                   dtype=np.int32)
+    npages = install_pages(shape, shape[1], page_words)
+    idx = np.empty((2, npages + 1), dtype=np.int32)
     idx[0, :n] = src_rows
     idx[1, :n] = dst_rows
-    idx[:, n:] = idx[:, n - 1:n]  # repeat one real pair: same bytes again
-    return install_fn(shape, int(cols), page_words,
-                      donate_enabled())(slab, src, idx)
+    idx[:, n:npages] = idx[:, n - 1:n]  # repeat one real pair: same bytes
+    idx[:, npages] = cols  # the trim width rides along: ONE host argument
+    return install_fn(shape, page_words, donate_enabled())(slab, src, idx)
 
 
-def install_fn(src_shape, cols: int, page_words: int, donate: bool):
-    """The jitted (LRU-cached) fused install for one source geometry:
-    (slab, src u32[src_shape], idx int32[2, npages]) -> slab."""
+def install_fn(src_shape, page_words: int, donate: bool):
+    """The jitted (LRU-cached) fused install for one source SHAPE:
+    (slab, src u32[src_shape], idx int32[2, npages + 1]) -> slab.  The
+    trim width is data, not part of the key: it is the index array's
+    last column (one host argument a call, as before it was told).  A
+    source used at its full width (a 4 MiB object fills its bucket) takes
+    the plain flatten, a reshape; a narrower one is compacted: row r of
+    the source written, whole, at word ``r * cols`` of the flat page
+    image, rows in order, so each row overwrites the pad columns of the
+    one before, and what the last row leaves past ``rows * cols`` is
+    zeroed.  Both are branches of ONE program (``lax.cond``: only the
+    branch taken runs).  ``npages`` is what the untrimmed source would
+    fill; an install of fewer pages repeats its last index pair (module
+    docstring)."""
     rows, cols_full = src_shape
-    npages = install_pages(src_shape, cols, page_words)
-    pad = npages * page_words - rows * cols
+    npages = install_pages(src_shape, cols_full, page_words)
+    words = npages * page_words
 
     def build():
+        def _whole(src, cols):
+            flat = src.reshape(-1)
+            if words > rows * cols_full:
+                flat = jnp.concatenate([flat, jnp.zeros(
+                    words - rows * cols_full, dtype=jnp.uint32)])
+            return flat
+
+        def _compact(src, cols):
+            def place(r, flat):
+                row = jax.lax.dynamic_index_in_dim(src, r, 0, False)
+                return jax.lax.dynamic_update_slice(flat, row, (r * cols,))
+
+            flat = jax.lax.fori_loop(
+                0, rows, place, jnp.zeros(words, dtype=jnp.uint32))
+            live = jnp.arange(words, dtype=jnp.int32) < rows * cols
+            return jnp.where(live, flat, jnp.uint32(0))
+
         def _install(s, src, idx):
             with jax.named_scope("slab_install"):
-                flat = (src[:, :cols] if cols < cols_full
-                        else src).reshape(-1)
-                if pad:
-                    flat = jnp.concatenate(
-                        [flat, jnp.zeros(pad, dtype=jnp.uint32)])
-                pages = flat.reshape(npages, page_words)
-                return s.at[idx[1]].set(pages[idx[0]])
+                cols = idx[0, npages]
+                pages = jax.lax.cond(cols == cols_full, _whole, _compact,
+                                     src, cols).reshape(npages, page_words)
+                return s.at[idx[1, :npages]].set(pages[idx[0, :npages]])
 
         if donate:
             return jax.jit(_install, donate_argnums=(0,))
         return jax.jit(_install)
 
-    return _kernel(("install", rows, cols_full, cols, page_words, donate),
-                   build)
+    return _kernel(("install", rows, cols_full, page_words, donate), build)
 
 
 def gather_fn(page_words: int, nb: int):
@@ -262,32 +304,100 @@ def slab_gather(slab_at, slab_of: np.ndarray, rows: np.ndarray):
     return acc
 
 
-def span_rows_fn(nb: int, page_words: int, start: int, length: int,
-                 n_rows: int, cols: int, planes8: bool):
+def span_rows_fn(nb: int, page_words: int, n_rows: int, cols_b: int,
+                 planes8: bool):
     """The jitted (LRU-cached) cut of bit-rows out of gathered pages, for
-    one (bucket, range, resident geometry)."""
+    one (page bucket, row count, pow2 column bucket): where the range
+    starts and how wide a row is are data (``at`` = int32[2]).  Rows as
+    wide as their bucket (a 4 MiB object's) are one contiguous slice
+    and a reshape; narrower ones are cut row by row and masked — two
+    branches of ONE program."""
     def build():
-        def _rows(p):
+        def _whole(p, start, cols):
+            return jax.lax.dynamic_slice(
+                p.reshape(-1), (start,),
+                (n_rows * cols_b,)).reshape(n_rows, cols_b)
+
+        def _ragged(p, start, cols):
+            # cols_b words of slack: a slice never clamps its start
+            flat = jnp.concatenate(
+                [p.reshape(-1), jnp.zeros(cols_b, dtype=p.dtype)])
+            offs = start + jnp.arange(n_rows, dtype=jnp.int32) * cols
+            out = jax.vmap(lambda o: jax.lax.dynamic_slice(
+                flat, (o,), (cols_b,)))(offs)
+            return jnp.where(
+                jnp.arange(cols_b, dtype=jnp.int32)[None, :] < cols,
+                out, jnp.uint32(0))
+
+        def _rows(p, at):
             with jax.named_scope("slab_gather"):
-                out = p.reshape(-1)[start:start + length]
+                start, cols = at[0], at[1]
+                if n_rows * cols_b <= nb * page_words:
+                    out = jax.lax.cond(cols == cols_b, _whole, _ragged,
+                                       p, start, cols)
+                else:  # the pages cannot hold full-width rows
+                    out = _ragged(p, start, cols)
                 if planes8:
-                    out = jax.lax.bitcast_convert_type(out, jnp.int8)
-                return out.reshape(n_rows, cols)
+                    out = jax.lax.bitcast_convert_type(
+                        out, jnp.int8).reshape(n_rows, cols_b * 4)
+                return out
 
         return jax.jit(_rows)
 
-    return _kernel(("rows", nb, page_words, start, length, n_rows, cols,
-                    planes8), build)
+    return _kernel(("rows", nb, page_words, n_rows, cols_b, planes8), build)
 
 
-def span_rows(pages, start: int, length: int, n_rows: int, cols: int,
-              planes8: bool):
-    """Words ``[start, start + length)`` of the gathered pages' flat image
-    as the resident's ``[n_rows, cols]`` bit-rows (``planes8``: the int8
-    plane layout, each u32 word four bytes, LSB first as numpy's view on
-    the little-endian hosts this runs on) — ONE jitted program."""
-    return span_rows_fn(int(pages.shape[0]), int(pages.shape[1]), start,
-                        length, n_rows, cols, planes8)(pages)
+def span_rows(pages, start: int, n_rows: int, cols: int, planes8: bool):
+    """``n_rows`` bit-rows of ``cols`` u32 words each, read from word
+    ``start`` of the gathered pages' flat image, as ONE jitted program.
+    The result is ``[n_rows, bucket_rows(cols)]`` (``planes8``: the int8
+    plane layout, four columns a word, LSB first as numpy's view on the
+    little-endian hosts this runs on), zero past the true width: the
+    program is keyed by the pow2 bucket and told ``start`` and ``cols``
+    at run time, so residents of every width share a handful of
+    programs, and so do the unpack programs downstream.  Callers trim to
+    the width they know."""
+    return span_rows_fn(int(pages.shape[0]), int(pages.shape[1]), n_rows,
+                        bucket_rows(cols), planes8)(
+        pages, np.array([start, cols], dtype=np.int32))
+
+
+def plane_window_fn(rows: int, cols_full: int, cols_b: int):
+    """The jitted (LRU-cached) cut of ONE request's columns out of a
+    coalesced group's plane rows: (product [rows, cols_full], at =
+    int32[3]: start, shift, cols) -> [rows, cols_b], zero past ``cols``."""
+    def build():
+        def _window(product, at):
+            with jax.named_scope("plane_window"):
+                start, shift, cols = at[0], at[1], at[2]
+                block = jax.lax.dynamic_slice(product, (0, start),
+                                              (rows, cols_b))
+                # the block began `shift` columns early where the window
+                # would have run off the product's edge: rotate it back
+                block = jax.lax.dynamic_slice(
+                    jnp.concatenate([block, block], axis=1), (0, shift),
+                    (rows, cols_b))
+                return jnp.where(
+                    jnp.arange(cols_b, dtype=jnp.int32)[None, :] < cols,
+                    block, jnp.zeros((), dtype=product.dtype))
+
+        return jax.jit(_window)
+
+    return _kernel(("window", rows, cols_full, cols_b), build)
+
+
+def plane_window(product, off: int, cols: int, cols_b: int):
+    """Columns ``[off, off + cols)`` of a group's plane rows as a fresh
+    ``[rows, cols_b]`` device buffer (``cols <= cols_b <= cols_full``),
+    zero past ``cols`` — with ``cols_b`` the width a dispatch of the
+    request alone would have bucketed to, exactly what that dispatch
+    hands back.  ONE jitted program per (product shape, ``cols_b``):
+    offset and width are data (an eager slice compiles per offset
+    and width, on the queue's thread, inside a served window)."""
+    rows, cols_full = int(product.shape[0]), int(product.shape[1])
+    start = min(int(off), cols_full - cols_b)
+    return plane_window_fn(rows, cols_full, cols_b)(
+        product, np.array([start, int(off) - start, cols], dtype=np.int32))
 
 
 def prewarm(page_words: int, max_rows: int = 256) -> int:

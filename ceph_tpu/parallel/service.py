@@ -105,6 +105,7 @@ def _build_ec_tpu_perf() -> PerfCounters:
       sharded_dispatch     u64         dispatches laid across the mesh
       overlapped_rounds    u64         rounds whose launch overlapped a fetch
       bytes                u64         bytes dispatched (incl. bucket padding)
+      pad_bytes            u64         of `bytes`, the bucket padding
       queue_wait           longrunavg  submit -> launch wait per request
       dispatch_dev         longrunavg  launch -> fan-out device seconds per dispatch
       dispatch_compile     longrunavg  XLA compile seconds inside a dispatch
@@ -132,6 +133,9 @@ def _build_ec_tpu_perf() -> PerfCounters:
                       "rounds whose launch overlapped the previous fetch")
     b.add_u64_counter("bytes",
                       "bytes dispatched to the device (incl. padding)")
+    b.add_u64_counter("pad_bytes",
+                      "of those, zeros that pad a batch up to its pow2 "
+                      "column bucket")
     for lane in LANES:
         b.add_u64_counter(f"submit_{lane}", f"requests on the {lane} lane")
         b.add_u64_counter(f"bytes_{lane}",
@@ -360,14 +364,37 @@ LANES: Dict[str, Lane] = {
 }
 
 
+def staged_cols(kind: str, w: int, packetsize: int, cols: int) -> int:
+    """The width a batch of `cols` byte columns is staged at on lane
+    `kind`: a power of two of the lane's column units (the packet lane's
+    unit is its w*packetsize block, the others' divides the 1024-column
+    floor, so theirs is the plain pow2 width; ops/gf2.bucket_columns is
+    the same policy) — what bounds XLA recompiles across object sizes."""
+    align = LANES[kind].align or w * packetsize
+    units, bucket = -(-cols // align), max(1, 1024 // align)
+    while bucket < units:
+        bucket <<= 1
+    return align * bucket
+
+
 def _cpu_apply_request(kind: str, mbits: np.ndarray, regions, w: int,
                        out_rows: int, packetsize: int = 0):
     """Serve ONE lane request host-side; returns exactly what the device
     lane's fan-out would have resolved the request's future with (device
-    buffers become numpy arrays — every consumer accepts both)."""
-    return LANES[kind].mirror(np.asarray(mbits, dtype=np.uint8),
-                              np.asarray(regions, dtype=np.uint8),
-                              w, out_rows, packetsize)
+    buffers become numpy arrays — every consumer accepts both; a resident
+    lane's plane rows zero-padded to the request's staged width, as
+    _complete_resident hands them out)."""
+    regions = np.asarray(regions, dtype=np.uint8)
+    out = LANES[kind].mirror(np.asarray(mbits, dtype=np.uint8), regions,
+                             w, out_rows, packetsize)
+    if LANES[kind].resident:
+        packed, rows = out
+        cols = regions.shape[1]
+        pad = (rows.shape[1] * staged_cols(kind, w, packetsize, cols)
+               // cols - rows.shape[1])
+        if pad:
+            out = packed, np.pad(rows, ((0, 0), (0, pad)))
+    return out
 
 
 class _LaneBreaker:
@@ -1018,27 +1045,23 @@ class BatchingQueue:
 
     def _launch(self, g: _Group):
         """Launch one group on its lane: coalesce the requests
-        column-wise, bucket the width to a power of two of the lane's
-        column units (bounds XLA recompiles; the packet lane's unit is
-        its w*packetsize block, the others' divides the 1024-column
-        floor, so theirs is the plain pow2 width), shard across the mesh
+        column-wise, bucket the width (staged_cols: bounds XLA
+        recompiles), shard across the mesh
         when one is attached, and otherwise start the H2D transfer NOW so
         it overlaps the previous round's result fetch; then enqueue the
         lane's one fused program (async: a device handle comes back).
         Returns (widths, out, sharded, nbytes)."""
         import jax
 
-        from ceph_tpu.ops.gf2 import bucket_columns as _bucket
-
         lane = LANES[g.kind]
         align = lane.align or g.w * g.packetsize
         widths = [req.regions.shape[1] for req in g.requests]
         batch = np.concatenate([req.regions for req in g.requests], axis=1)
-        cols = batch.shape[1]
-        pad = align * _bucket(-(-cols // align),
-                              lo=max(1, 1024 // align)) - cols
+        pad = staged_cols(g.kind, g.w, g.packetsize,
+                          batch.shape[1]) - batch.shape[1]
         if pad:
             batch = np.pad(batch, ((0, 0), (0, pad)))
+            self.perf.inc("pad_bytes", pad * batch.shape[0])
         nbytes = batch.nbytes
         self.perf.inc("h2d_bytes", nbytes)
         if g.packetsize and g.packetsize % 4 == 0:
@@ -1081,16 +1104,25 @@ class BatchingQueue:
         widths, (packed, rows), sharded, nbytes = state
         packed = self._fetch(packed)
         self._note_dispatch(nbytes, sharded)
-        if len(g.requests) == 1 and packed.shape[1] == widths[0]:
-            # single-request group covering the full (unpadded) batch:
-            # hand the whole product back — no slice op on the device
-            # graph, and the install's flatten sees one contiguous
-            # buffer
+        # THE CONTRACT of the resident half: a request's plane rows come
+        # back as wide as a dispatch of the request alone stages them —
+        # its columns, then zeros up to the lane's pow2 bucket — so what
+        # a consumer compiles for (the store's install) is keyed by the
+        # bucket and told the width, never by the width (rados/
+        # pagestore.py put_planar's `trim`).
+        if len(g.requests) == 1:
+            # the product IS that: no op on the device graph, and the
+            # install's flatten sees one contiguous buffer
+            width = widths[0]
             try:
-                g.requests[0].future.set_result((packed, rows))
+                g.requests[0].future.set_result((
+                    packed if packed.shape[1] == width
+                    else packed[:, :width].copy(), rows))
             except InvalidStateError:
                 pass
             return
+        from ceph_tpu.ops.slab import plane_window
+
         # resident columns per packed byte column, after any mesh
         # grid-padding: 1/32 for u32 plane words (request widths are whole
         # words, _check_packedbit_resident), 1/2, 1 or 2 for int8 planes
@@ -1098,11 +1130,18 @@ class BatchingQueue:
         cols, rcols = packed.shape[1], rows.shape[1]
         off = 0
         for width, req in zip(widths, g.requests):
+            # one jitted program per (product shape, bucket), told the
+            # offset and the width: requests of unequal widths land at
+            # another offset in every group, and an eager slice compiles
+            # for each, here, inside a served window
+            lo = off * rcols // cols
             try:
                 req.future.set_result((
                     packed[:, off : off + width].copy(),
-                    rows[:, off * rcols // cols
-                         : (off + width) * rcols // cols]))
+                    plane_window(
+                        rows, lo, (off + width) * rcols // cols - lo,
+                        min(rcols, staged_cols(g.kind, g.w, g.packetsize,
+                                               width) * rcols // cols))))
             except InvalidStateError:
                 pass
             off += width

@@ -103,6 +103,10 @@ class RadosError(Exception):
         self.code = code
 
 
+# the data ops the objecter counts apart (upstream's op_r / op_w)
+OP_KINDS = {"read": ("op_r", "reads"), "write": ("op_w", "writes"),
+            "delete": ("op_d", "deletes")}
+
 # reply codes that are ANSWERS, not failures: the primary executed the op
 # and the result is "no" — retrying would turn every expected miss into a
 # multi-second epoch-barrier stall (reference: definitive errno from
@@ -170,6 +174,9 @@ def _build_objecter_perf() -> PerfCounters:
     (name -> meaning -> kind):
 
       op                 u64         logical data ops submitted
+      op_r, op_w, op_d   u64         of those, reads / writes / deletes
+      op_r_lat, op_w_lat, op_d_lat
+                         longrunavg  submit -> answer of each kind
       resends            u64         op sends beyond the first (map change,
                                      timeout, transport death, backoff)
       timeouts           u64         per-attempt reply timeouts
@@ -184,6 +191,10 @@ def _build_objecter_perf() -> PerfCounters:
     """
     b = PerfCountersBuilder("objecter")
     b.add_u64_counter("op", "logical data ops submitted")
+    for kind, what in OP_KINDS.values():
+        b.add_u64_counter(kind, f"of those, {what}")
+        b.add_time_avg(kind + "_lat", f"submit -> answer (or failure) "
+                                      f"of {what}")
     b.add_u64_counter("resends", "op sends beyond the first")
     b.add_u64_counter("timeouts", "per-attempt reply timeouts")
     b.add_u64_counter("backoffs_received", "MOSDBackoff blocks received")
@@ -979,6 +990,10 @@ class RadosClient:
                 span.tag("reqid", op.reqid).tag("pool", op.pool_id)
                 op.trace_id, op.span_id = span.context()
             self.perf.inc("op")
+            kind = OP_KINDS.get(op.op, (None,))[0]
+            if kind is not None:
+                self.perf.inc(kind)
+            t0 = time.monotonic()
             self._inflight[op.reqid] = rec
             self.perf.set("inflight", len(self._inflight))
         try:
@@ -991,6 +1006,8 @@ class RadosClient:
                 span.tag("ok", False).tag("error", type(e).__name__)
             raise
         finally:
+            if kind is not None:
+                self.perf.tinc(kind + "_lat", time.monotonic() - t0)
             if span is not None:
                 span.finish()
             self._inflight.pop(op.reqid, None)

@@ -308,7 +308,11 @@ class OSD:
             .add_u64_counter("op", "client ops")
             .add_u64_counter("op_w", "client writes")
             .add_u64_counter("op_r", "client reads")
+            .add_u64_counter("op_d", "client deletes")
             .add_time_avg("op_lat", "client op latency")
+            .add_time_avg("op_r_lat", "client read latency")
+            .add_time_avg("op_w_lat", "client write latency")
+            .add_time_avg("op_d_lat", "client delete latency")
             .add_u64_counter("subop_w", "EC sub-writes applied")
             .add_u64_counter("subop_r", "EC sub-reads served")
             .add_u64_counter("pools_purged",
@@ -533,7 +537,8 @@ class OSD:
         # causes), the gf2 `gf2_sched` schedule-cache set, the tpu
         # plugin's `ec_plugin` seam set (device dispatches vs CPU
         # fallbacks — the non-queue path), the `crush` placement-memo
-        # set, and the resident store's `pagestore` residency set.  The
+        # set, the resident store's `pagestore` residency set and its
+        # device programs' `slab_kernels` LRU set.  The
         # queue/store/sched/plugin/crush sets are process-shared (as the
         # resources are); every colocated OSD dumps the same numbers.
         self.ctx.perf.add(self.messenger.perf)
@@ -543,7 +548,10 @@ class OSD:
                 self.ctx.perf.add(meter.perf)
         from ceph_tpu.ops.gf2 import SCHED_PERF
 
+        from ceph_tpu.ops.slab import SLAB_PERF
+
         self.ctx.perf.add(SCHED_PERF)
+        self.ctx.perf.add(SLAB_PERF)
         self.ctx.perf.add(ECPLAN_PERF)
         self.ctx.perf.add(CRUSH_PERF)
         try:
@@ -2456,18 +2464,25 @@ class OSD:
             tracked = self._track_client_op(op)
         t0 = time.monotonic()
         self.perf.inc("op")
-        if op.op == "write":
-            self.perf.inc("op_w")
-        elif op.op == "read":
-            self.perf.inc("op_r")
+        # reads, writes and deletes apart, as upstream's op_r / op_w
+        # (and their latencies) are: one average over a mixed window
+        # says nothing of any of them
+        kind = self._OP_KIND.get(op.op)
+        if kind is not None:
+            self.perf.inc(kind)
         try:
             await self._handle_client_op_inner(conn, op, tracked)
         finally:
-            self.perf.tinc("op_lat", time.monotonic() - t0)
+            took = time.monotonic() - t0
+            self.perf.tinc("op_lat", took)
+            if kind is not None:
+                self.perf.tinc(kind + "_lat", took)
             if tracked.trace is not None:
                 tracked.trace.finish()
             tracked.mark_event("done")
             tracked.finish()
+
+    _OP_KIND = {"write": "op_w", "read": "op_r", "delete": "op_d"}
 
     # ops the backoff gate may drop-and-block (client data plane; admin
     # fan-outs like repair/deep-scrub/pgls answer normally)
@@ -4555,7 +4570,10 @@ class OSD:
                 await self._save_snapset(pool, pg, acting, op.oid, ss)
                 return MOSDOpReply(ok=True)
         tid = uuid.uuid4().hex
-        self._cache_drop(op.pool_id, op.oid)
+        with tracing.section("osd", "delete_drop"):
+            # the name's decoded bytes, device pages and memo go first:
+            # no read may be served from them once the delete is logged
+            self._cache_drop(op.pool_id, op.oid)
         entry = LogEntry(version=log.next_version(self.osdmap.epoch),
                          op="delete", oid=op.oid, prior_version=log.head,
                          reqid=op.reqid)
@@ -4563,7 +4581,13 @@ class OSD:
         # local: drop any shard we hold (rollback slots included); the
         # delete is a PG log event
         txn = Transaction()
-        for oid, shard in list(self.store.list_objects(op.pool_id)):
+        # no list() around the walk: every store lists from a snapshot of
+        # its keys, and nothing is applied before the loop ends.  A list
+        # of one fresh tuple per stored shard (45 000 an OSD at 4096
+        # names) lives through the collector's young generations and
+        # ends in a full collection a few deletes later: 0.3-0.4 s of
+        # the loop each (PERF.md, PR 38)
+        for oid, shard in self.store.list_objects(op.pool_id):
             if oid == op.oid:
                 txn.delete((op.pool_id, op.oid, shard))
         self._log_in_txn(txn, op.pool_id, pg, entry)
@@ -4907,7 +4931,8 @@ class OSD:
     async def _handle_sub_delete(self, msg: MECSubDelete) -> None:
         txn = Transaction()
         if msg.shard < 0:  # whole-object delete (rollback slots included)
-            for oid, shard in list(self.store.list_objects(msg.pool_id)):
+            # (no list() around the walk, as in _do_delete)
+            for oid, shard in self.store.list_objects(msg.pool_id):
                 if oid == msg.oid:
                     txn.delete((msg.pool_id, msg.oid, shard))
         else:
@@ -6799,6 +6824,16 @@ class OSD:
             replies = local_results + await self._gather(tid, q, sent,
                                                          timeout=2.0)
             by_shard = {r.shard: r for r in replies}
+            ent = self._pglog(pool.pool_id, pg).latest_entry(oid)
+            if ent is not None and ent.op == "delete":
+                # deleted since the listing above, or being deleted now
+                # (every await in this loop lets client ops through, and
+                # a delete is logged before its sub-deletes are sent):
+                # shards that are gone, or going, are not inconsistent.
+                # Only a LOGGED delete says so: a listed name with no
+                # log entry and no shard is a loss, and is reported
+                scrubbed -= 1
+                continue
             x_bad: List[Tuple[int, int]] = []
             xcheck = (self._hinfo_cross_check(pool.pool_id, oid, acting)
                       if pool.pool_type == "ec" else None)

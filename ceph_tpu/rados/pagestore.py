@@ -209,6 +209,9 @@ def build_pagestore_perf() -> PerfCounters:
                          "sub-slab an install touched, so over the two "
                          "counters above it reads sub-slabs per install "
                          "(more means an eager op crept back)")
+        .add_u64_counter("install_page_bytes",
+                         "page bytes those programs landed (pages x page "
+                         "size: what an install has to move at least)")
         .add_u64_counter("d2h_gathers",
                          "device->host materializations of gathered "
                          "slab bytes at the declared exit boundaries")
@@ -437,7 +440,7 @@ class PagedResidentStore:
         the flat word image, which crosses h2d as ONE copy of the whole
         zero-padded page image and goes through the same program.
         Returns the page-id list."""
-        from ceph_tpu.ops.slab import slab_install
+        from ceph_tpu.ops.slab import bucket_rows, slab_install
 
         npages = -(-total_words // self.page_words) if total_words else 0
         pages: List[Optional[int]] = []
@@ -453,7 +456,9 @@ class PagedResidentStore:
         else:
             import jax
 
-            host = np.zeros((npages, self.page_words), dtype=np.uint32)
+            # pow2 rows: the image's shape is the install's compile key
+            host = np.zeros((bucket_rows(npages), self.page_words),
+                            dtype=np.uint32)
             host.reshape(-1)[:total_words] = src
             src, cols = jax.device_put(host), self.page_words  # the ONE h2d
             self.h2d_installs += 1
@@ -470,6 +475,7 @@ class PagedResidentStore:
             self._dev_slabs[s] = slab_install(self._dev_slab(s), src, cols,
                                               order, dst[order])
         self.perf.inc("install_programs", len(touched))
+        self.perf.inc("install_page_bytes", npages * self.page_bytes)
         return pages
 
     @tracing.sectioned("store", "resident_install")
@@ -659,8 +665,7 @@ class PagedResidentStore:
         if any(p is None for p in span):
             return None
         if self.device_arm:
-            return self._gather_device_locked(e, r0, r1, w0, w1, p0,
-                                              span)
+            return self._gather_device_locked(e, r0, r1, w0, p0, span)
         out = np.empty(w1 - w0, dtype=np.uint32)
         pos = 0
         for i, pid in enumerate(span):
@@ -676,12 +681,15 @@ class PagedResidentStore:
         return out.reshape(r1 - r0, e.cols)
 
     def _gather_device_locked(self, e: _Entry, r0: int, r1: int,
-                              w0: int, w1: int, p0: int,
-                              span: List[int]):
+                              w0: int, p0: int, span: List[int]):
         """Device-arm gather (lock held): one jitted program per touched
         sub-slab lands the span's pages in one buffer, one more cuts the
         bit-rows out of it (ceph_tpu.ops.slab) — no eager op, so a span
-        split over sub-slabs in a new way compiles nothing.  The result
+        split over sub-slabs in a new way compiles nothing, and neither
+        does a resident of a new width: the cut is keyed by the pow2
+        bucket of the row width and comes back THAT wide, zero past the
+        resident's own columns.  Every reader packs the rows and trims
+        the bytes on the host, to the width it recorded.  The result
         is a fresh device buffer (never a slab view) — it stays valid
         across later donated installs and feeds the jitted decode path
         without leaving HBM; the host exit is read()/ecutil's
@@ -691,13 +699,16 @@ class PagedResidentStore:
         pids = np.array(span, dtype=np.int32)
         pages = slab_gather(self._dev_slab, pids >> _SLAB_SHIFT,
                             pids & ((1 << _SLAB_SHIFT) - 1))
-        return span_rows(pages, w0 - p0 * self.page_words, w1 - w0,
-                         r1 - r0, e.cols,
+        return span_rows(pages, w0 - p0 * self.page_words, r1 - r0,
+                         e.cols * e.itemsize // 4,
                          bool(np.dtype(e.dtype) != np.uint32))
 
     @tracing.sectioned("store", "resident_gather")
     def gather_rows(self, key: Any, r0: int, r1: int):
-        """[r1-r0, cols] array gathered from the page table, or None
+        """[r1-r0, cols] array gathered from the page table — on the
+        device arm a device array whose columns are zero-padded to their
+        pow2 bucket (a new width compiles nothing; the reader trims after
+        its pack) — or None
         when the entry is absent or any needed page was evicted (a
         partial resident can still serve any fully-covered row range —
         the data-row prefix after a parity shed).  No LRU side effects
@@ -736,7 +747,8 @@ class PagedResidentStore:
 
     def get_planar(self, key: Any):
         """(bits, w, n_rows, meta) or None; refreshes LRU position.
-        Gathers the WHOLE resident — None when partial (parity shed)."""
+        Gathers the WHOLE resident — None when partial (parity shed);
+        bucket-wide on the device arm, as gather_rows."""
         got = self.touch(key)
         if got is None:
             return None
@@ -826,6 +838,7 @@ class PagedResidentStore:
         with self._lock:
             e = self._entries.get(key)
             trim = e.trim if e is not None else None
+            cols = e.cols if e is not None else bits.shape[1]
         if w == 0:
             # raw whole-object entry (put_raw): no planar decode exists;
             # the single uint8 bit-row IS the bytes
@@ -843,7 +856,9 @@ class PagedResidentStore:
             with self.perf.time_avg("pack_s"):
                 out = np.asarray(from_planar(bits, w, n_rows))
         self.note_d2h()
-        return out if trim is None else out[:, :trim]
+        if trim is None:  # the device arm gathers bucket-wide
+            trim = out.shape[1] * cols // bits.shape[1]
+        return out[:, :trim]
 
     # -- eviction ------------------------------------------------------------
 

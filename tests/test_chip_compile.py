@@ -169,24 +169,39 @@ def test_apply_packetrows_cauchy(one_chip, mats, blocks):
     assert ma.temp_size_in_bytes < ma.argument_size_in_bytes
 
 
-@pytest.mark.parametrize("src,cols", [
-    ((88, 16384), 16384),   # k=8 m=3, 4 MiB: one page a bit-row
-    ((48, 32768), 32768),   # k=4 m=2, 4 MiB: two pages a bit-row
-    ((88, 16384), 12289),   # a trimmed non-pow2 width, ragged tail
+@pytest.mark.parametrize("src", [
+    (88, 16384),   # k=8 m=3, 4 MiB: one page a bit-row
+    (48, 32768),   # k=4 m=2, 4 MiB: two pages a bit-row
+    (88, 128),     # k=8 m=3, one 4 KiB stripe (and every width up to it)
 ])
-def test_slab_install(one_chip, src, cols):
-    """The fused install (trim, flatten, pad, page view, row selection,
-    donated scatter) as one program, keyed by the source's geometry."""
+def test_slab_install(one_chip, src):
+    """The fused install (compaction to the trim width it is told, pad,
+    page view, row selection, donated scatter) as one program, keyed by
+    the source's shape."""
     from ceph_tpu.ops.slab import install_fn, install_pages
 
     pw = (64 << 10) // 4  # osd_tier_page_bytes default, in u32 words
     compiled = _compile(
-        install_fn(src, cols, pw, True),
+        install_fn(src, pw, True),
         _spec((256, pw), np.uint32, one_chip),
         _spec(src, np.uint32, one_chip),
-        _spec((2, install_pages(src, cols, pw)), np.int32, one_chip))
+        _spec((2, install_pages(src, src[1], pw) + 1), np.int32, one_chip))
     # donated: the update must be in place, not a second slab
     assert "input_output_alias" in compiled.as_text()
+
+
+@pytest.mark.parametrize("cols_full,cols_b", [
+    (65536, 16384),  # a 4 MiB put's rows out of a 16 MiB round
+    (32768, 128),    # one stripe's out of a round a 4 MiB put leads
+])
+def test_plane_window(one_chip, cols_full, cols_b):
+    """A request's columns out of a coalesced group's plane rows (the
+    queue's resident fan-out), told the offset and the width."""
+    from ceph_tpu.ops.slab import plane_window_fn
+
+    _compile(plane_window_fn(88, cols_full, cols_b),
+             _spec((88, cols_full), np.uint32, one_chip),
+             _spec((3,), np.int32, one_chip))
 
 
 @pytest.mark.parametrize("rows", [1, 256])
@@ -215,8 +230,9 @@ def test_slab_gather_span(one_chip, pages, n_rows):
              _spec((256, pw), np.uint32, one_chip),
              _spec((pages,), np.int32, one_chip),
              _spec((pages,), np.bool_, one_chip))
-    _compile(span_rows_fn(pages, pw, 0, n_rows * pw, n_rows, pw, False),
-             _spec((pages, pw), np.uint32, one_chip))
+    _compile(span_rows_fn(pages, pw, n_rows, pw, False),
+             _spec((pages, pw), np.uint32, one_chip),
+             _spec((2,), np.int32, one_chip))
 
 
 @pytest.mark.parametrize("width", [
