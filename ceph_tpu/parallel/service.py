@@ -22,7 +22,12 @@ per lane — the device program, its numpy mirror, the fan-out shape, the
 column alignment and the submit-time check.  Every lane takes packed
 [n, B] uint8 rows; they differ in the layout stages around the GF(2)
 product and in what comes back (ceph_tpu/ops/gf2.py has the kernels and
-their measurements; rados/ecutil.lane_for picks the lane for a codec):
+their measurements; rados/ecutil.lane_for picks the lane for a codec).
+A request hands its rows over as that [n, B] array, or NAMES them in
+stripe order (StripeRows: the object's own buffer, nothing copied yet);
+_launch writes every request once, into the staging buffer device_put
+reads — pad to whole stripes, stripe-major to shard rows and pad to the
+bucket are that one pass, on the queue's thread:
 
     packed              int8 bit-planes, matrix as a matmul operand (any
                         matrix, no recompile; w=4/8/16): bytes out
@@ -106,6 +111,8 @@ def _build_ec_tpu_perf() -> PerfCounters:
       overlapped_rounds    u64         rounds whose launch overlapped a fetch
       bytes                u64         bytes dispatched (incl. bucket padding)
       pad_bytes            u64         of `bytes`, the bucket padding
+      staged_layout_bytes  u64         row bytes the queue's thread laid out
+                                       from stripe-order sources (StripeRows)
       queue_wait           longrunavg  submit -> launch wait per request
       dispatch_dev         longrunavg  launch -> fan-out device seconds per dispatch
       dispatch_compile     longrunavg  XLA compile seconds inside a dispatch
@@ -136,6 +143,9 @@ def _build_ec_tpu_perf() -> PerfCounters:
     b.add_u64_counter("pad_bytes",
                       "of those, zeros that pad a batch up to its pow2 "
                       "column bucket")
+    b.add_u64_counter("staged_layout_bytes",
+                      "row bytes laid out of stripe-order sources, on the "
+                      "queue's thread, straight into a staging buffer")
     for lane in LANES:
         b.add_u64_counter(f"submit_{lane}", f"requests on the {lane} lane")
         b.add_u64_counter(f"bytes_{lane}",
@@ -306,6 +316,69 @@ def _device_packetrows(g, batch):
     return gf2_apply_packetrows(g.mbits, batch, g.w, g.packetsize)
 
 
+# -- a request's rows, named before they are laid out -------------------------
+
+
+class StripeRows:
+    """A request's [n, n_stripes x chunk] shard rows NAMED in stripe
+    order: `buf` is the object's own flat uint8 buffer, stripe after
+    stripe of n chunks of `chunk` bytes, the last stripe as short as the
+    object left it.  Row i is chunk i of every stripe, zeros past the
+    buffer's end.  Nothing is copied until `lay_into` writes the rows
+    where they are wanted — for a queued request that is _launch, on the
+    queue's thread, into the staging buffer.  `shape` and `nbytes` are
+    the rows', so every check, counter and grouping rule reads a request
+    of either form alike.  Whoever submits one hands over a buffer that
+    nobody writes until the future resolves (rados/ecutil._stripe_rows
+    decides), and the request keeps it referenced until then."""
+
+    __slots__ = ("buf", "n", "chunk", "n_stripes")
+
+    def __init__(self, buf: np.ndarray, n: int, chunk: int):
+        self.buf, self.n, self.chunk = buf, n, chunk
+        self.n_stripes = max(1, -(-len(buf) // (n * chunk)))
+
+    @property
+    def shape(self) -> Tuple[int, int]:
+        return self.n, self.n_stripes * self.chunk
+
+    @property
+    def nbytes(self) -> int:
+        return self.n * self.n_stripes * self.chunk
+
+    def lay_into(self, dst: np.ndarray) -> None:
+        """Write the rows into `dst`, [n, n_stripes x chunk] uint8 whose
+        rows are contiguous (a column range of a staging buffer): one
+        pass over the whole stripes, then the ragged last stripe — its
+        whole chunks, the chunk the object ends in, and the zeros.  A
+        handful of numpy calls whatever n is: each may hand the GIL to
+        a busy event loop and wait to get it back."""
+        n, chunk = self.n, self.chunk
+        dst = dst.reshape(n, self.n_stripes, chunk)  # a view: rows split
+        whole = len(self.buf) // (n * chunk)
+        if whole:
+            np.copyto(dst[:, :whole],
+                      self.buf[:whole * n * chunk]
+                      .reshape(whole, n, chunk).transpose(1, 0, 2))
+        if whole < self.n_stripes:
+            tail = self.buf[whole * n * chunk:]
+            last = dst[:, whole]
+            full, rest = divmod(len(tail), chunk)
+            if full:
+                last[:full] = tail[:full * chunk].reshape(full, chunk)
+            if rest:
+                last[full, :rest] = tail[full * chunk:]
+                last[full, rest:] = 0
+                full += 1
+            last[full:] = 0
+
+    def rows(self) -> np.ndarray:
+        """The rows laid out now, on the caller's thread."""
+        out = np.empty(self.shape, dtype=np.uint8)
+        self.lay_into(out)
+        return out
+
+
 # -- submit-time checks: a request that cannot run is refused before it can
 #    coalesce, or its launch would fail every innocent request grouped with it
 
@@ -333,7 +406,8 @@ def _check_packetrows(regions, w, packetsize=0):
 
 class Lane(NamedTuple):
     """All the queue knows about one lane.  A request is packed [n, B]
-    uint8 rows under a [out_rows*w, n*w] GF(2) bit-matrix."""
+    uint8 rows (or a StripeRows that names them) under a [out_rows*w,
+    n*w] GF(2) bit-matrix."""
 
     #: the lane's one fused program over a staged batch (async: returns a
     #: device handle)
@@ -383,8 +457,11 @@ def _cpu_apply_request(kind: str, mbits: np.ndarray, regions, w: int,
     lane's fan-out would have resolved the request's future with (device
     buffers become numpy arrays — every consumer accepts both; a resident
     lane's plane rows zero-padded to the request's staged width, as
-    _complete_resident hands them out)."""
-    regions = np.asarray(regions, dtype=np.uint8)
+    _complete_resident hands them out; a stripe-order request laid out
+    here, its data rows last)."""
+    named = isinstance(regions, StripeRows)
+    regions = (regions.rows() if named
+               else np.asarray(regions, dtype=np.uint8))
     out = LANES[kind].mirror(np.asarray(mbits, dtype=np.uint8), regions,
                              w, out_rows, packetsize)
     if LANES[kind].resident:
@@ -394,7 +471,14 @@ def _cpu_apply_request(kind: str, mbits: np.ndarray, regions, w: int,
                // cols - rows.shape[1])
         if pad:
             out = packed, np.pad(rows, ((0, 0), (0, pad)))
-    return out
+    return _with_data_rows(out, regions) if named else out
+
+
+def _with_data_rows(result, rows: np.ndarray):
+    """What a stripe-order request's future resolves to: what a request
+    of rows gets, then its data rows as the staging pass laid them out
+    ([n, B], every row C-contiguous) — the plan never had them."""
+    return (*result, rows) if isinstance(result, tuple) else (result, rows)
 
 
 class _LaneBreaker:
@@ -415,7 +499,9 @@ class _LaneBreaker:
 
 
 class _Request(NamedTuple):
-    """One queued lane submission.  t_submit feeds the queue_wait
+    """One queued lane submission: `regions` is the [n, B] rows, or the
+    StripeRows that names them (and keeps the source buffer referenced
+    until the future resolves).  t_submit feeds the queue_wait
     latency; span threads the submitter's trace (the OSD's `ec write`)
     through coalesce -> dispatch -> fan-out."""
 
@@ -584,9 +670,13 @@ class BatchingQueue:
         over packed [n, B] uint8 `regions` on lane `kind` (LANES).  The
         future resolves to the [out_rows, B] parity/reconstruction bytes,
         or on a resident lane to (those bytes, the data ‖ parity bit-rows
-        as a device buffer).  Non-blocking: no device work on the caller's
-        thread, so concurrent ops coalesce.  Raises ValueError for a
-        request its lane cannot run."""
+        as a device buffer).  `regions` may be a StripeRows instead: the
+        rows are then laid out by the queue's thread, and the future
+        resolves to the same with the [n, B] data rows appended (views of
+        the staging buffer for a request alone in its dispatch, copied
+        out of it otherwise).  Non-blocking: no device work and no copy
+        on the caller's thread, so concurrent ops coalesce.  Raises
+        ValueError for a request its lane cannot run."""
         return self.submit_group(
             [(mbits, regions, w, out_rows, kind, packetsize)], span=span)[0]
 
@@ -862,6 +952,9 @@ class BatchingQueue:
         except Exception as e:
             self._fail_group(g, e)
             return
+        self.perf.inc("staged_layout_bytes",
+                      sum(req.regions.nbytes for req in g.requests
+                          if isinstance(req.regions, StripeRows)))
         for req, res in zip(g.requests, results):
             try:
                 req.future.set_result(res)
@@ -1044,25 +1137,42 @@ class BatchingQueue:
             return batch, False
 
     def _launch(self, g: _Group):
-        """Launch one group on its lane: coalesce the requests
-        column-wise, bucket the width (staged_cols: bounds XLA
-        recompiles), shard across the mesh
+        """Launch one group on its lane: stage the requests column-wise
+        in ONE buffer of the bucketed width (staged_cols: bounds XLA
+        recompiles), each written once — a request of rows by a slice
+        copy, a stripe-order one laid out in place, then the bucket's
+        zeros — shard across the mesh
         when one is attached, and otherwise start the H2D transfer NOW so
         it overlaps the previous round's result fetch; then enqueue the
         lane's one fused program (async: a device handle comes back).
-        Returns (widths, out, sharded, nbytes)."""
+        Returns (widths, out, sharded, nbytes, staged): `staged` is the
+        host buffer, whose column ranges are the data rows a stripe-order
+        request gets back."""
         import jax
 
         lane = LANES[g.kind]
         align = lane.align or g.w * g.packetsize
         widths = [req.regions.shape[1] for req in g.requests]
-        batch = np.concatenate([req.regions for req in g.requests], axis=1)
-        pad = staged_cols(g.kind, g.w, g.packetsize,
-                          batch.shape[1]) - batch.shape[1]
-        if pad:
-            batch = np.pad(batch, ((0, 0), (0, pad)))
-            self.perf.inc("pad_bytes", pad * batch.shape[0])
-        nbytes = batch.nbytes
+        cols = sum(widths)
+        staged = np.empty(
+            (g.requests[0].regions.shape[0],
+             staged_cols(g.kind, g.w, g.packetsize, cols)), dtype=np.uint8)
+        off = laid_out = 0
+        for width, req in zip(widths, g.requests):
+            dst = staged[:, off : off + width]
+            if isinstance(req.regions, StripeRows):
+                req.regions.lay_into(dst)
+                laid_out += req.regions.nbytes
+            else:
+                np.copyto(dst, req.regions)
+            off += width
+        if laid_out:
+            self.perf.inc("staged_layout_bytes", laid_out)
+        if cols != staged.shape[1]:
+            staged[:, cols:] = 0
+            self.perf.inc("pad_bytes",
+                          (staged.shape[1] - cols) * staged.shape[0])
+        batch, nbytes = staged, staged.nbytes
         self.perf.inc("h2d_bytes", nbytes)
         if g.packetsize and g.packetsize % 4 == 0:
             # a packet is XORed whole, so the device gets it as u32 words
@@ -1071,24 +1181,39 @@ class BatchingQueue:
         batch, sharded = self._maybe_shard(batch, align=align)
         if not sharded:
             batch = jax.device_put(batch)  # async H2D staging
-        return widths, lane.device(g, batch), sharded, nbytes
+        return widths, lane.device(g, batch), sharded, nbytes, staged
+
+    @staticmethod
+    def _resolve(g: _Group, req: _Request, result, staged: np.ndarray,
+                 off: int, width: int) -> None:
+        """Resolve one request's future; a stripe-order request also gets
+        its data rows, the columns _launch laid out for it."""
+        if isinstance(req.regions, StripeRows):
+            rows = staged[:, off : off + width]
+            # alone in its dispatch, the rows are views: what they pin is
+            # the request's own bytes and its bucket's zeros.  Out of a
+            # shared buffer they are copied, as every output is: a view
+            # would pin the whole batch for as long as one object lives
+            result = _with_data_rows(
+                result, rows if len(g.requests) == 1 else rows.copy())
+        # a submitter may have been CANCELLED while waiting (an async op
+        # torn down mid-flight propagates cancellation into the future
+        # via asyncio.wrap_future): its slice is simply dropped
+        try:
+            req.future.set_result(result)
+        except InvalidStateError:
+            pass  # cancelled in the check-to-set window
 
     def _complete_packed(self, g: _Group, state) -> None:
-        widths, out, sharded, nbytes = state
+        widths, out, sharded, nbytes, staged = state
         out = self._fetch(out).view(np.uint8)  # the packet lane's u32 words
         self._note_dispatch(nbytes, sharded)
         off = 0
         for width, req in zip(widths, g.requests):
-            # a submitter may have been CANCELLED while waiting (an
-            # async op torn down mid-flight propagates cancellation
-            # into the future via asyncio.wrap_future): its slice is
-            # simply dropped
-            try:
-                # copy: a view would pin the whole batch buffer for as
-                # long as any single result stays alive
-                req.future.set_result(out[:, off : off + width].copy())
-            except InvalidStateError:
-                pass  # cancelled in the check-to-set window
+            # copy: a view would pin the whole batch buffer for as
+            # long as any single result stays alive
+            self._resolve(g, req, out[:, off : off + width].copy(),
+                          staged, off, width)
             off += width
 
     def _complete_resident(self, g: _Group, state) -> None:
@@ -1101,7 +1226,7 @@ class BatchingQueue:
         # the DATA argument of their kernels, because sibling requests
         # alias the same underlying buffer; only the slab argument,
         # which this plane never hands out, is donatable.
-        widths, (packed, rows), sharded, nbytes = state
+        widths, (packed, rows), sharded, nbytes, staged = state
         packed = self._fetch(packed)
         self._note_dispatch(nbytes, sharded)
         # THE CONTRACT of the resident half: a request's plane rows come
@@ -1114,12 +1239,10 @@ class BatchingQueue:
             # the product IS that: no op on the device graph, and the
             # install's flatten sees one contiguous buffer
             width = widths[0]
-            try:
-                g.requests[0].future.set_result((
-                    packed if packed.shape[1] == width
-                    else packed[:, :width].copy(), rows))
-            except InvalidStateError:
-                pass
+            self._resolve(g, g.requests[0],
+                          (packed if packed.shape[1] == width
+                           else packed[:, :width].copy(), rows),
+                          staged, 0, width)
             return
         from ceph_tpu.ops.slab import plane_window
 
@@ -1135,13 +1258,11 @@ class BatchingQueue:
             # another offset in every group, and an eager slice compiles
             # for each, here, inside a served window
             lo = off * rcols // cols
-            try:
-                req.future.set_result((
-                    packed[:, off : off + width].copy(),
-                    plane_window(
-                        rows, lo, (off + width) * rcols // cols - lo,
-                        min(rcols, staged_cols(g.kind, g.w, g.packetsize,
-                                               width) * rcols // cols))))
-            except InvalidStateError:
-                pass
+            self._resolve(g, req, (
+                packed[:, off : off + width].copy(),
+                plane_window(
+                    rows, lo, (off + width) * rcols // cols - lo,
+                    min(rcols, staged_cols(g.kind, g.w, g.packetsize,
+                                           width) * rcols // cols))),
+                staged, off, width)
             off += width

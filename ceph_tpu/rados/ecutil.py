@@ -31,6 +31,8 @@ import numpy as np
 
 from ceph_tpu.common import tracing
 from ceph_tpu.common.perf_counters import PerfCountersBuilder
+from ceph_tpu.parallel.service import StripeRows
+from ceph_tpu.rados.extent_cache import keepable
 
 
 @dataclass(frozen=True)
@@ -171,6 +173,11 @@ ECPLAN_PERF = (PerfCountersBuilder("ecplan")
                .add_u64_counter("stripes", "stripes those plans covered")
                .add_u64_counter("packs", "resident bit-rows packed to "
                                          "bytes (_pack_rows)")
+               .add_u64_counter("loop_layout_bytes",
+                                "row bytes an encode plan laid out on its "
+                                "caller's thread (no queue, or a source "
+                                "that may change): the rest is "
+                                "ec_tpu.staged_layout_bytes")
                .create_perf_counters())
 
 
@@ -211,65 +218,81 @@ def _lane(codec, sinfo: StripeInfo):
     return lane
 
 
-def _lane_item(lane, codec, bitmatrix, rows: np.ndarray, out_rows: int):
+def _lane_item(lane, codec, bitmatrix, rows, out_rows: int):
     """The lane request for applying `bitmatrix` (an encode generator
-    or an inverted decode signature) to `[n, n_stripes*chunk]` rows, as
-    BatchingQueue.submit / submit_group take it: (mbits, rows, w,
-    out_rows, kind[, packetsize])."""
+    or an inverted decode signature) to `[n, n_stripes*chunk]` rows (or
+    the StripeRows that names them), as BatchingQueue.submit /
+    submit_group take it: (mbits, rows, w, out_rows, kind[, packetsize])."""
     kind, dtype, *packetsize = lane
     return (np.asarray(bitmatrix).astype(dtype), rows,
             getattr(codec, "w", 8), out_rows, kind, *packetsize)
 
 
+def _stripe_rows(sinfo: StripeInfo, data, queued: bool = True):
+    """The `[k, n_stripes*chunk]` data rows of the non-empty buffer
+    `data` as an encode request's source.  A queued request over a source
+    that cannot change (extent_cache.keepable: `bytes`, or a read-only
+    view of the whole of a buffer that owns its memory — what the wire
+    delivers and _do_write passes on) only NAMES the rows (StripeRows):
+    the queue's thread writes pad and stripe order straight into its
+    staging buffer, and no byte of the object is copied here.  Anything
+    else — a writable view somebody may reuse, a slice of a larger
+    buffer, no queue to stage it — is laid out now, on the caller's
+    thread, in one pass."""
+    src = StripeRows(np.frombuffer(data, dtype=np.uint8), sinfo.k,
+                     sinfo.chunk_size)
+    if queued and keepable(data):
+        return src
+    ECPLAN_PERF.inc("loop_layout_bytes", src.nbytes)
+    return src.rows()
+
+
 @tracing.sectioned("ecplan", "encode_plan")
-def _encode_plan_parts(codec, sinfo: StripeInfo, arr: np.ndarray,
-                       n_stripes: int):
-    """The submit-free half of the queue encode plan: when the codec is
-    batchable (a bit seam, no chunk remap), returns (item, reassemble) —
-    the lane request (_lane_item) a caller hands to BatchingQueue.submit
-    or (with several buffers) to submit_group as one whole-stripe-group
-    handoff.  None when the queue path does not
-    apply."""
+def _encode_plan_parts(codec, sinfo: StripeInfo, data):
+    """The submit-free half of the queue encode plan for the non-empty
+    buffer `data`: when the codec is batchable (a bit seam, no chunk
+    remap), returns (item, reassemble) — the lane request (_lane_item) a
+    caller hands to BatchingQueue.submit or (with several buffers) to
+    submit_group as one whole-stripe-group handoff, and what turns that
+    request's result into the per-shard blob list.  None when the queue
+    path does not apply."""
     mbits = codec.bit_generator()
     lane = _lane(codec, sinfo) if mbits is not None else None
     if lane is None:
         return None
     k = codec.get_data_chunk_count()
     m = codec.get_chunk_count() - k
-    ECPLAN_PERF.inc("plans")
-    ECPLAN_PERF.inc("stripes", n_stripes)
     # columns = stripes concatenated; one submit -> one device call.  The
-    # layout stages run on the device: byte and packet layout alike hand
-    # the queue these [k, n_stripes*chunk] rows.
-    flat = np.ascontiguousarray(
-        arr.transpose(1, 0, 2).reshape(k, n_stripes * sinfo.chunk_size))
-    item = _lane_item(lane, codec, mbits, flat, m)
+    # layout stages run on the device: byte and packet layout alike give
+    # the queue [k, n_stripes*chunk] rows, here mostly by name
+    src = _stripe_rows(sinfo, data)
+    ECPLAN_PERF.inc("plans")
+    ECPLAN_PERF.inc("stripes", src.shape[1] // sinfo.chunk_size)
+    item = _lane_item(lane, codec, mbits, src, m)
 
     @tracing.sectioned("ecplan", "reassemble")
-    def reassemble(parity: np.ndarray) -> List[np.ndarray]:
-        p = np.asarray(parity).reshape(m, n_stripes * sinfo.chunk_size)
-        out: List[np.ndarray] = []
-        for i in range(k):
-            # the flat rows ARE the per-shard data blobs, already
-            # contiguous — handing back arr[:, i, :] views here would
-            # make every consumer (store write, sub-write framing) pay
-            # an ascontiguousarray copy per shard
-            out.append(flat[i])
-        for j in range(m):
-            out.append(p[j])
-        return out
+    def reassemble(result) -> List[np.ndarray]:
+        # the data rows ARE the per-shard data blobs, each contiguous:
+        # the staging buffer's own rows for a source the queue laid out
+        # (its result carries them), else the rows laid out above —
+        # stripe-major views of `data` would make every consumer (store
+        # write, sub-write framing) pay an ascontiguousarray copy a shard
+        parity, rows = (result if isinstance(src, StripeRows)
+                        else (result, src))
+        p = np.asarray(parity).reshape(m, src.shape[1])
+        return [rows[i] for i in range(k)] + [p[j] for j in range(m)]
 
     return item, reassemble
 
 
-def _queue_encode_plan(codec, sinfo: StripeInfo, arr: np.ndarray,
-                       n_stripes: int, queue, span=None):
+def _queue_encode_plan(codec, sinfo: StripeInfo, data, queue, span=None):
     """When the codec/queue combination is batchable (a bit seam, byte or
-    packet layout, no chunk remap), submit the whole buffer as ONE queue
-    request and return (future, reassemble) — reassemble turns the parity
-    rows into the per-shard blob list.  None when the queue path does
-    not apply (mapped or sub-chunk codecs, codecs without a bit seam)."""
-    parts = _encode_plan_parts(codec, sinfo, arr, n_stripes)
+    packet layout, no chunk remap), submit the whole non-empty buffer as
+    ONE queue request and return (future, reassemble) — reassemble turns
+    the future's result into the per-shard blob list.  None when the
+    queue path does not apply (mapped or sub-chunk codecs, codecs without
+    a bit seam)."""
+    parts = _encode_plan_parts(codec, sinfo, data)
     if parts is None:
         return None
     item, reassemble = parts
@@ -300,26 +323,26 @@ def batched_encode(codec, sinfo: StripeInfo, data: bytes,
     k = codec.get_data_chunk_count()
     n = codec.get_chunk_count()
     assert sinfo.k == k
-    padded = sinfo.pad_to_stripe(data)
-    n_stripes = max(1, len(padded) // sinfo.stripe_width)
-    # stripe-major view (no copy): [n_stripes, k, chunk].  Empty objects
-    # (len 0) cannot take the queue path — the codec's own encode handles
-    # the degenerate padding rules.
-    arr = (np.frombuffer(padded, dtype=np.uint8).reshape(
-               n_stripes, k, sinfo.chunk_size)
-           if len(padded) else None)
-    if queue is not None and arr is not None:
+    if queue is not None and len(data):
         # the interface's bit seam drives ANY byte- or packet-layout
         # codec through the queue's lanes; mapped and sub-chunk codecs
         # take the encode_chunks/per-stripe paths below.
         # Single-stripe objects ride the queue too — coalescing across
         # OBJECTS/ops is the point (SURVEY.md §7.5), and small concurrent
         # writes are exactly the dispatch-latency-bound workload.
-        planned = _queue_encode_plan(codec, sinfo, arr, n_stripes, queue,
-                                     span=span)
+        # Empty objects (len 0) cannot take the queue path — the codec's
+        # own encode handles the degenerate padding rules.
+        planned = _queue_encode_plan(codec, sinfo, data, queue, span=span)
         if planned is not None:
             fut, reassemble = planned
             return reassemble(fut.result())
+    # no queue to stage it: the caller's thread pads and lays out
+    padded = sinfo.pad_to_stripe(data)
+    n_stripes = max(1, len(padded) // sinfo.stripe_width)
+    # stripe-major view (no copy): [n_stripes, k, chunk]
+    arr = (np.frombuffer(padded, dtype=np.uint8).reshape(
+               n_stripes, k, sinfo.chunk_size)
+           if len(padded) else None)
     if n_stripes <= 1 or arr is None:
         # one stripe IS one dispatch: the codec encodes the whole buffer
         enc = codec.encode(set(range(n)), padded)
@@ -344,21 +367,17 @@ async def batched_encode_async(codec, sinfo: StripeInfo, data: bytes,
                                queue=None, span=None) -> List[np.ndarray]:
     """Event-loop-friendly batched_encode: the queue future is AWAITED,
     so concurrent ops keep submitting while this one waits — that
-    concurrency is what the queue coalesces into one device dispatch."""
-    if queue is not None:
+    concurrency is what the queue coalesces into one device dispatch.
+    The loop copies nothing of a stable `data` (_stripe_rows): the queue's
+    thread reads it after this returns to the loop, so the caller leaves
+    it unwritten until the encode is back."""
+    if queue is not None and len(data):
         import asyncio
 
-        k = codec.get_data_chunk_count()
-        padded = sinfo.pad_to_stripe(data)
-        if len(padded):
-            n_stripes = max(1, len(padded) // sinfo.stripe_width)
-            arr = np.frombuffer(padded, dtype=np.uint8).reshape(
-                n_stripes, k, sinfo.chunk_size)
-            planned = _queue_encode_plan(codec, sinfo, arr, n_stripes, queue,
-                                         span=span)
-            if planned is not None:
-                fut, reassemble = planned
-                return reassemble(await asyncio.wrap_future(fut))
+        planned = _queue_encode_plan(codec, sinfo, data, queue, span=span)
+        if planned is not None:
+            fut, reassemble = planned
+            return reassemble(await asyncio.wrap_future(fut))
     return batched_encode(codec, sinfo, data, queue=None)
 
 
@@ -380,17 +399,12 @@ async def batched_encode_group_async(codec, sinfo: StripeInfo, buffers,
     items = []
     metas = []
     for i, data in enumerate(buffers):
-        if queue is not None:
-            padded = sinfo.pad_to_stripe(data)
-            if len(padded):
-                n_stripes = max(1, len(padded) // sinfo.stripe_width)
-                arr = np.frombuffer(padded, dtype=np.uint8).reshape(
-                    n_stripes, sinfo.k, sinfo.chunk_size)
-                parts = _encode_plan_parts(codec, sinfo, arr, n_stripes)
-                if parts is not None:
-                    items.append(parts[0])
-                    metas.append((i, parts[1]))
-                    continue
+        if queue is not None and len(data):
+            parts = _encode_plan_parts(codec, sinfo, data)
+            if parts is not None:
+                items.append(parts[0])
+                metas.append((i, parts[1]))
+                continue
         out[i] = batched_encode(codec, sinfo, data, queue=None)
     if items:
         futs = queue.submit_group(items, span=span)
@@ -620,34 +634,30 @@ async def planar_encode_async(codec, sinfo: StripeInfo, data: bytes,
     as batched_encode); w MUST be recorded with the resident (w=16/w=4
     pools unpack to different plane layouts) — or None when the codec is
     not planar-eligible."""
-    with tracing.section("ecplan", "planar_pad"):
-        if not planar_eligible(codec):
-            return None
-        padded = sinfo.pad_to_stripe(data)
-    if not len(padded):
-        return None
     import asyncio
 
     with tracing.section("ecplan", "planar_plan"):
+        if not planar_eligible(codec) or not len(data):
+            return None
         k = codec.get_data_chunk_count()
         n = codec.get_chunk_count()
         m = n - k
         w = getattr(codec, "w", 8)
-        n_stripes = max(1, len(padded) // sinfo.stripe_width)
-        ECPLAN_PERF.inc("plans")
-        ECPLAN_PERF.inc("stripes", n_stripes)
-        flat = np.ascontiguousarray(
-            np.frombuffer(padded, dtype=np.uint8)
-            .reshape(n_stripes, k, sinfo.chunk_size)
-            .transpose(1, 0, 2).reshape(k, n_stripes * sinfo.chunk_size))
+        # the data rows: by name where the queue's thread can lay them
+        # out (no copy here), laid out now otherwise
+        flat = _stripe_rows(sinfo, data, queued=queue is not None)
         L = flat.shape[1]
+        ECPLAN_PERF.inc("plans")
+        ECPLAN_PERF.inc("stripes", L // sinfo.chunk_size)
         # (w=8 byte codecs have whole u32 words per plane row: chunk_size
         # is a multiple of w*4=32)
         kind, dtype = lane_for(codec, resident=True, cols=L)
         mbits = np.asarray(codec.bit_generator()).astype(dtype)
     if queue is not None:
-        parity, all_bits = await asyncio.wrap_future(
+        parity, all_bits, *rows = await asyncio.wrap_future(
             queue.submit(mbits, flat, w, m, kind, span=span))
+        if rows:
+            flat, = rows  # the staging buffer's own rows
     else:
         from ceph_tpu.ops.gf2 import (bucket_columns, gf2_encode_resident,
                                       gf2_encode_packedbit_resident)

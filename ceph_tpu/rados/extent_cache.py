@@ -30,12 +30,14 @@ from typing import List, Optional, Tuple
 Key = Tuple[int, str]  # (pool_id, oid)
 
 
-def _keepable(data) -> bool:
-    """True when caching `data` itself is as good as caching a copy:
+def keepable(data) -> bool:
+    """True when keeping `data` itself is as good as keeping a copy:
     `bytes`, or a read-only flat byte view of the WHOLE of a buffer that
-    owns its memory.  A writable view can change under the cache; a view
+    owns its memory.  A writable view can change under its keeper; a view
     of part of something larger (a lane fragment of its group's assembly
-    buffer) would pin the rest of it."""
+    buffer) would pin the rest of it.  The cache below asks it for a put
+    it keeps, the EC plan (ecutil._stripe_rows) for one it lets another
+    thread read later."""
     if isinstance(data, bytes):
         return True
     if not (isinstance(data, memoryview) and data.readonly
@@ -120,12 +122,12 @@ class ExtentCache:
     def put_full(self, key: Key, version: int, data) -> bool:
         """Cache the whole object.  Returns False when that cost a copy
         of `data`, True when it did not: `data` itself is cached (see
-        `_keepable`; the caller writes into it no more), or the put was
+        `keepable`; the caller writes into it no more), or the put was
         stale and nothing is."""
         ent = self._entry_for_put(key, version)
         if ent is None:
             return True
-        kept = _keepable(data)
+        kept = keepable(data)
         run = data if kept else bytes(data)
         ent.extents = [(0, run)]
         ent.full = True

@@ -3106,7 +3106,6 @@ class OSD:
             # splice plan: chunk_off >= 0 means each shard splices `blobs[shard]`
             # into its stored blob at chunk_off (per-stripe RMW, the reference's
             # write plan ECTransaction.cc:37-95); -1 replaces the whole blob
-            data = op.data
             chunk_off = -1
             shard_size = 0
             base_version = 0
@@ -3120,6 +3119,11 @@ class OSD:
             full_for_cache = (op.data.toreadonly()
                               if isinstance(op.data, memoryview)
                               else op.data)
+            # the encode reads the same read-only view, and for the same
+            # reason may read it LATER: the EC plan copies nothing on
+            # this loop, the queue's thread lays the stripes out
+            # (ecutil._stripe_rows)
+            data = full_for_cache
         if op.offset >= 0:
             span.event("rmw read")
             mark("rmw_read")

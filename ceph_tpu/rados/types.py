@@ -1668,8 +1668,8 @@ MECSubReadReply.BLOB_VIEW_OK = True
 # MCacheDirty.data: consumers are put_raw (np.frombuffer) and bytes()
 # normalization on the adopt path — buffer-safe end to end
 MCacheDirty.BLOB_VIEW_OK = True
-# MOSDOp.data: the WRITE path is buffer-safe end to end (pad_to_stripe,
-# splice slicing, np.frombuffer encode, bytes() cache copy); the OSD
+# MOSDOp.data: the WRITE path is buffer-safe end to end (splice slicing,
+# np.frombuffer encode, the extent cache's read-only view); the OSD
 # dispatcher normalizes data to bytes for every OTHER op (multi/call/...)
 # whose handlers — object classes especially — expect bytes semantics
 MOSDOp.BLOB_VIEW_OK = True
