@@ -44,6 +44,12 @@ Two instruments say where a loop thread's wall time goes (PERF.md §3):
   ``self_unnamed``.  ``mark(layer)`` re-labels the rest of the current
   step (the messenger calls it where it hands a message to its daemon,
   and a send where the daemon calls into the messenger).
+  ``charge(key)`` books the same busy seconds a second time, by CAUSE
+  beside by layer: the messenger, where it knows a frame's type, says
+  whose message a stretch of a step served, and the meter keeps it as
+  ``msg_<Type>`` and ``for_<family>`` (``FAMILIES``; ``for_none`` is what
+  nobody charged).  The ``for_*`` keys sum to ``busy`` as the ``self_*``
+  keys do.  A charge is a counter, never an event of the host plane.
 
 ``jax`` is never imported here: the profiler annotation is used only when
 the process already imported ``jax.profiler``.  Span times are integer
@@ -225,6 +231,11 @@ _LAYER_OF_TASK = {
     "tiering": "background", "logclient": "background", "vstart": "background",
     "admin_socket": "background", "log": "background",
 }
+# whose message a stretch of the loop's time served (PERF.md section 3): a
+# client op's own, the cluster's liveness, the tier's hit sets, recovery and
+# scrub, the control plane, standalone acks; `none` is in no message's hand.
+# Each is a `for_<family>` time-avg of the `loop` set from the start
+FAMILIES = ("op", "liveness", "tier", "recovery", "control", "ack", "none")
 _LAG_PROBE_S = 0.02
 
 
@@ -249,6 +260,11 @@ def build_loop_perf(name: str = "loop") -> PerfCounters:
                        f"loop-thread self seconds of layer {layer}: its "
                        f"sections, and the uncovered part of the steps "
                        f"whose kind belongs to it")
+    for family in FAMILIES:
+        b.add_time_avg("for_" + family,
+                       f"loop-thread seconds charged to messages of family "
+                       f"{family} (tracing.charge): the same busy seconds as "
+                       f"self_*, cut by cause; each type is a msg_<Type>")
     return b.create_perf_counters()
 
 
@@ -264,7 +280,8 @@ class _State:
     once."""
 
     __slots__ = ("depth", "child", "covered", "meter", "skip", "null",
-                 "cursor", "base", "acc", "marked", "loose")
+                 "cursor", "base", "acc", "marked", "loose",
+                 "charged", "charge_from", "claimable")
 
     def __init__(self) -> None:
         self.depth = 0        # open sections
@@ -281,6 +298,9 @@ class _State:
         self.acc = [0.0]      # the sum that stretch goes to: base, or
         self.marked: Optional[str] = None  # the layer mark() named
         self.loose = 0.0      # uncovered seconds of the step already booked
+        self.charged: Any = None  # whom the step's time is charged to now
+        self.charge_from = 0.0    # since when
+        self.claimable = False    # no charge was made in this step yet
 
 
 _tls = threading.local()
@@ -413,6 +433,58 @@ def mark(layer: Optional[str]) -> Optional[str]:
     return was
 
 
+def charge(key: Optional[Tuple[str, str]], claim: bool = True):
+    """From here to the end of the current loop step, or to the next
+    charge, the step's time (sections and uncovered stretches alike) is
+    booked to `key`, a (family, type) pair: `loop.for_<family>` and
+    `loop.msg_<type>`; None is nobody's (`for_none`, what a step is until
+    somebody charges it).  Returns what it was, for a caller that puts it
+    back, as mark does.  The FIRST charge of a step claims the step from
+    its start (a receive step's recv_into ran inside asyncio before
+    anybody could read a type) unless `claim` is false (a send made from
+    a continuation: what the step did before is not the message's).  Like
+    a mark, a charge ends with its step.  Off a metered loop and in the
+    turns the meter does not sample this returns None after one
+    thread-local read: no clock, no allocation."""
+    try:
+        st = _tls.state
+    except AttributeError:
+        return None
+    meter = st.meter
+    if meter is None:
+        return None
+    if not (st.claimable and claim):
+        now = time.perf_counter()
+        meter._book(st.charged, now - st.charge_from)
+        st.charge_from = now
+    st.claimable = False
+    was, st.charged = st.charged, key
+    return was
+
+
+def charge_many(weights: Dict[Tuple[str, str], float], claim: bool = True):
+    """charge(), the stretch divided among several keys by weight: a burst
+    of frames by the bytes each landed, a flush window by its bytes by
+    type.  `weights` is kept, not copied."""
+    return charge(weights or None, claim)
+
+
+def metered() -> bool:
+    """In a sampled turn of a metered loop: a charge made now is booked.
+    For a caller whose KEY costs something to work out."""
+    try:
+        return _tls.state.meter is not None
+    except AttributeError:
+        return False
+
+
+def last_lag() -> Optional[float]:
+    """Seconds the running loop's newest lag probe ran after it was due
+    (at most 20 ms old); None off a metered loop."""
+    meter = getattr(asyncio._get_running_loop(), "_ceph_meter", None)
+    return None if meter is None else meter.last_lag
+
+
 def _task_label(task) -> str:
     """`<daemon>[.<id>]/<role>` if the task was given such a name, else
     `<module>/<coroutine>` of the code it runs (`osd/OSD._run_op`)."""
@@ -456,9 +528,11 @@ def _metered_run(handle):
     st.base = st.acc = kind.acc
     st.marked = None
     st.covered = st.loose = 0.0
-    t0 = st.cursor = meter._t_mark
+    st.charged, st.claimable = None, True
+    t0 = st.cursor = st.charge_from = meter._t_mark
     _handle_run(handle)  # logs what the callback raises; raises nothing
     meter._t_mark = t1 = time.perf_counter()
+    meter._book(st.charged, t1 - st.charge_from)
     if ta:
         ta.__exit__(None, None, None)
     loose = (t1 - st.cursor) - st.covered
@@ -520,10 +594,13 @@ class LoopMeter:
         self._layers: Dict[str, List[float]] = {}  # layer -> [seconds]
         self._sections: Dict[str, list] = {}  # layer -> [self seconds, n]
         self._kinds: Dict[Any, _Kind] = {}  # callback or task label -> kind
+        # (family, type) or None -> [seconds charged, stretches]
+        self._charges: Dict[Any, list] = {}
         # the loop's own time between its last handle and the select
         self._loop_itself = self._kind("kind_loop_itself", "unnamed")
         self._probe = None
         self._due = 0.0
+        self.last_lag = 0.0  # the newest probe's delay, seconds
         self._selector = None
 
     # -- install / remove ----------------------------------------------------
@@ -579,6 +656,7 @@ class LoopMeter:
             itself = self._loop_itself
             itself.seconds += now - self._t_mark
             itself.acc[0] += now - self._t_mark
+            self._book(None, now - self._t_mark)
 
     def _timed_select(self, select):
         def timed(timeout=None):
@@ -629,7 +707,7 @@ class LoopMeter:
             pass
 
     def _lag_probe(self) -> None:
-        late = max(0.0, self.loop.time() - self._due)
+        late = self.last_lag = max(0.0, self.loop.time() - self._due)
         self._lag += late
         self._lags += 1
         self._lag_us[min(31, int(late * 1e6).bit_length())] += 1
@@ -644,6 +722,22 @@ class LoopMeter:
         if acc is None:
             acc = self._layers[layer] = [0.0]
         return acc
+
+    def _book(self, whom, seconds: float) -> None:
+        """`seconds` of a sampled step to whom they were charged: a key,
+        None, or charge_many's weights."""
+        if type(whom) is dict:
+            total = sum(whom.values())
+            if total > 0:
+                for key, weight in whom.items():
+                    self._book(key, seconds * weight / total)
+                return
+            whom = None
+        slot = self._charges.get(whom)
+        if slot is None:
+            slot = self._charges[whom] = [0.0, 0]
+        slot[0] += seconds
+        slot[1] += 1
 
     def _kind(self, key: str, layer: str) -> _Kind:
         kind = self._kinds.get(key)
@@ -693,7 +787,7 @@ class LoopMeter:
         set's presample hook: every dump sees the loop up to now).  What
         the sampled turns summed is scaled to the whole busy time; until a
         turn was sampled, busy waits with it, so that the `self_*` keys
-        always sum to `busy`."""
+        and the `for_*` keys each always sum to `busy`."""
         perf = self.perf
         lag, self._lag = self._lag, 0.0
         lags, self._lags = self._lags, 0
@@ -711,6 +805,8 @@ class LoopMeter:
             st.acc[0] += loose
             st.loose += loose
             st.cursor, st.covered = now, 0.0
+            self._book(st.charged, now - st.charge_from)
+            st.charge_from = now
             c_now = time.thread_time()
             self._cpu += c_now - self._c_mark
             self._sampled += now - self._t_turn
@@ -746,6 +842,16 @@ class LoopMeter:
                 _tally(perf, kind.key, kind.seconds * scale,
                        round(kind.steps * scale))
                 kind.seconds, kind.steps = 0.0, 0
+        for key, slot in self._charges.items():
+            (seconds, count), slot[:] = slot, (0.0, 0)
+            if not (seconds or count):
+                continue
+            family, name = key or ("none", "")
+            _tally(perf, "for_" + family, seconds * scale,
+                   round(count * scale))
+            if name:
+                _tally(perf, "msg_" + name, seconds * scale,
+                       round(count * scale))
 
 
 _METERS: "weakref.WeakSet[LoopMeter]" = weakref.WeakSet()

@@ -183,6 +183,11 @@ def _build_wire_perf() -> PerfCounters:
       tx_flush_bytes       histogram   bytes per flush window
       tx_flush_data        u64         windows cut carrying data frames
       tx_flush_ack         u64         ack-only windows (no data pending)
+      tx_flush_mixed       u64         windows whose frames were of more
+                                       than one family (MSG_FAMILY; the
+                                       ack frame counts): there the loop
+                                       meter's charge is split by bytes, a
+                                       rule; elsewhere it is a measurement
       tx_acks              u64         ack frames written
       tx_acks_coalesced    u64         acks absorbed into a pending ack
                                        (would have been standalone frames)
@@ -212,7 +217,10 @@ def _build_wire_perf() -> PerfCounters:
     time is the `loop` set's `self_messenger` (common/tracing.py): the
     sections here (encode_frame with the blob's crc, sock_write,
     rx_frame with its crc_verify, decode) plus asyncio's
-    transport reads and writes."""
+    transport reads and writes.  WHOSE message that time served is the
+    same set's `msg_<Type>` and `for_<family>` (tracing.charge): the
+    receiver charges a burst to its frames, a send to its message, the
+    serve loop a decode and a dispatch, the flusher a window."""
     b = PerfCountersBuilder("wire")
     b.add_u64_counter("tx_msgs", "messages sent")
     b.add_u64_counter("tx_bytes", "frame bytes sent")
@@ -232,6 +240,8 @@ def _build_wire_perf() -> PerfCounters:
     b.add_histogram("tx_flush_bytes", "bytes per flush window")
     b.add_u64_counter("tx_flush_data", "flush windows carrying data frames")
     b.add_u64_counter("tx_flush_ack", "ack-only flush windows")
+    b.add_u64_counter("tx_flush_mixed", "flush windows holding frames of "
+                                        "more than one family")
     b.add_u64_counter("tx_acks", "ack frames written")
     b.add_u64_counter("tx_acks_coalesced",
                       "acks absorbed into a pending cumulative ack")
@@ -351,6 +361,52 @@ def message(type_id: int, version: int = 1):
         return cls
 
     return deco
+
+
+# Whose work a message is (common/tracing.py FAMILIES; PERF.md section 3):
+# the loop meter books the time a message costs the loop to its type and to
+# its type's family.  `op` is what a client op causes, `liveness` what the
+# cluster says to stay a cluster, `tier` the hit sets, `recovery` peering,
+# backfill and scrub, `control` the rest.  EVERY registered class is listed
+# (tests/test_messenger.py); one that is not runs as `control`.
+MSG_FAMILY: Dict[str, str] = {
+    name: family for family, names in {
+        "op": """MOSDOp MOSDOpReply MOSDBackoff MECSubWrite MECSubWriteReply
+            MECSubRead MECSubReadReply MECSubDelete MECSubRollback
+            MFetchShards MFetchShardsReply MListShards MListShardsReply
+            MCacheDirty MCacheDirtyAck MSetXattrs MSetOmap MWatchNotify
+            MNotifyAck""",
+        "liveness": """MOSDPing MPing MOSDFailure MOsdMembership MMonElection
+            MMonPaxos""",
+        "tier": "MOSDPGHitSet",
+        "recovery": """MPushShard MPGInfoReq MPGInfoReply MPGLogReq
+            MPGLogReply MBackfillReserve MBackfillReserveReply MScrubShard
+            MScrubShardReply MOSDPGTemp""",
+        "control": """MGetMap MMapReply MOsdBoot MBootReply MCreatePool
+            MCreatePoolReply MDeletePool MPoolSet MMarkDown MForward
+            MForwardReply MConfigSet MConfigGet MConfigReply MAuthTicket
+            MAuthTicketReply MAuthRotating MAuthRotatingReply MSetUpmap
+            MSnapOp MSnapOpReply MOSDSetFlag MSetFullRatio MGetHealth
+            MHealthReply MHealthMute MLog MLogAck MLogSubscribe MLogReply
+            MCrashReport MCrashReportAck MCrashQuery MCrashQueryReply
+            MCommand MCommandReply MCrushOp MCrushOpReply MOsdPredicate
+            MOsdPredicateReply MMgrReport MLaneHello MLaneSegment""",
+    }.items() for name in names.split()}
+ACK_CHARGE = ("ack", "ack")  # an ACK_TYPE frame, an ack-only flush window
+
+
+class _TypeSlot:
+    """What a messenger keeps per message type, found by type id with one
+    dict read a message (Messenger._type_slot): the loop meter's charge
+    key and the names of the type's `wire` counters (made in its set when
+    the direction is first used)."""
+
+    __slots__ = ("name", "charge", "tx", "tx_bytes", "rx", "rx_bytes")
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+        self.charge = (MSG_FAMILY.get(name, "control"), name)
+        self.tx = self.tx_bytes = self.rx = self.rx_bytes = None
 
 
 # -- lane negotiation / fragmentation wire types -----------------------------
@@ -897,15 +953,41 @@ class FrameReceiver(asyncio.BufferedProtocol):
             self._fill += nbytes
             self._t0 = time.monotonic()
         else:
+            # the step (recv_into included) is the frame's in flight
+            if tracing.metered():
+                tracing.charge(self._conn.messenger._type_slot(
+                    self._frame[0][0]).charge)
             self._body_pos += nbytes
             if self._body_pos < len(body):
                 if self._held:
                     self._backpressure()
                 return
         with tracing.section("messenger", "rx_frame"):
-            done, error, copied = \
-                self._parse() if body is None else self._finish_body()
+            if body is None:
+                done, error, copied = self._parse()
+                if tracing.metered():
+                    self._charge_burst(done)
+            else:
+                done, error, copied = self._finish_body()
             self._deliver(done, error, copied)
+
+    def _charge_burst(self, done: list) -> None:
+        """The step that read and parsed a head is its frames', by the
+        bytes each landed: those complete, the one put in flight (by its
+        front and what came with it), received acks as acks."""
+        slot_of = self._conn.messenger._type_slot
+        whose: Dict[tuple, int] = {}
+        for f in done:
+            if type(f) is int:
+                key, n = ACK_CHARGE, _HDR.size + _ACK_SEQ.size
+            else:
+                key, n = slot_of(f[0]).charge, _HDR.size + f[4]
+            whose[key] = whose.get(key, 0) + n
+        if self._frame is not None:
+            key = slot_of(self._frame[0][0]).charge
+            whose[key] = whose.get(key, 0) + len(self._front) \
+                + self._body_pos
+        tracing.charge_many(whose)
 
     def feed(self, data) -> None:
         """Bytes that did not come through the transport (what the
@@ -1458,6 +1540,9 @@ class Connection:
         self._outbox: list = []
         self._outbox_frames = 0
         self._outbox_bytes = 0
+        # the same bytes by the loop meter's charge key: whose window the
+        # flusher's step and its socket write are (tracing.charge_many)
+        self._outbox_by: Dict[tuple, int] = {}
         self._ack_pending = -1  # highest seq owed an ack; -1 = none
         self._flush_fut: Optional[asyncio.Future] = None
         self._flusher: Optional[asyncio.Task] = None
@@ -1580,23 +1665,33 @@ class Connection:
     def _seg_len(self, s) -> int:
         return s.nbytes if isinstance(s, memoryview) else len(s)
 
-    async def _enqueue(self, data) -> None:
-        """Append one framed message to the outbox and await the flush
-        window that carries it.  Concurrent senders in the same window
-        share ONE writelines + ONE drain; a transport failure fails the
-        whole window (each sender sees ConnectionResetError)."""
+    def _enqueue(self, data, nbytes: int, charge: tuple,
+                 was) -> asyncio.Future:
+        """Append one framed message of `nbytes` to the outbox; the
+        future returned is the flush window's that carries it.
+        Concurrent senders in the same window share ONE writelines + ONE
+        drain; a transport failure fails the whole window (each sender
+        sees ConnectionResetError).  The sender's step was the message's
+        up to here (`charge`, the key its bytes are kept under for the
+        flusher); `was` is the caller's charge, put back now: a charge
+        ends with its step, and the sender awaits next."""
+        tracing.charge(was, claim=False)
         if self.closed:
             raise ConnectionResetError("connection closed")
-        segs = data if isinstance(data, list) else [data]
-        self._outbox.extend(segs)
+        if isinstance(data, list):
+            self._outbox.extend(data)
+        else:
+            self._outbox.append(data)
         self._outbox_frames += 1
-        self._outbox_bytes += sum(self._seg_len(s) for s in segs)
+        self._outbox_bytes += nbytes
+        by = self._outbox_by
+        by[charge] = by.get(charge, 0) + nbytes
         fut = self._flush_fut
         if fut is None:
             fut = self._flush_fut = \
                 asyncio.get_running_loop().create_future()
         self._kick_flusher()
-        await fut
+        return fut
 
     def queue_ack(self, seq: int) -> None:
         """Queue a cumulative ack for ``seq`` (acks are cumulative: the
@@ -1645,6 +1740,7 @@ class Connection:
                     self._outbox_frames = 0
                     nbytes = self._outbox_bytes
                     self._outbox_bytes = 0
+                    whose, self._outbox_by = self._outbox_by, {}
                     fut, self._flush_fut = self._flush_fut, None
                     had_data = bool(segs)
                     if self._ack_pending >= 0:
@@ -1652,9 +1748,16 @@ class Connection:
                         segs.append(ack)
                         frames += 1
                         nbytes += len(ack)
+                        whose[ACK_CHARGE] = len(ack)
                         perf.inc("tx_acks")
                     if not segs:
                         break
+                    # this step and the socket write are the window's, by
+                    # its bytes by type (an ack-only window: the ack's)
+                    tracing.charge_many(whose)
+                    if len(whose) > 1 \
+                            and len({family for family, _ in whose}) > 1:
+                        perf.inc("tx_flush_mixed")
                     perf.inc("tx_flush_data" if had_data else "tx_flush_ack")
                     perf.inc("tx_flushes")
                     perf.hinc("tx_flush_frames", frames)
@@ -1690,6 +1793,9 @@ class Connection:
                             fut.exception()
                         await self.close(gen)
                         raise
+                    # a drain that waited resumes in a step of its own,
+                    # still this window's
+                    tracing.charge_many(whose)
                     perf.inc("tx_bytes", nbytes)
                     perf.hinc("tx_io_us",
                               (time.monotonic() - t_io) * 1e6)
@@ -1723,6 +1829,7 @@ class Connection:
         self._outbox = []
         self._outbox_frames = 0
         self._outbox_bytes = 0
+        self._outbox_by = {}
         self._ack_pending = -1
         if fut is not None and not fut.done():
             fut.set_exception(exc)
@@ -1797,6 +1904,10 @@ class Connection:
                      and random.randrange(dup_inj) == 0)
         self.out_seq += 1
         seq = self.out_seq
+        slot = self.messenger._type_slot(msg.TYPE_ID)
+        # from here to the enqueue the step is this message's; what it
+        # did before (a handler, an op's continuation) is not, so no claim
+        was = tracing.charge(slot.charge, claim=False)
         t_frame = time.monotonic()
         with tracing.section("messenger", "encode_frame"):
             pickled, blob, fixed = encode_payload_parts(msg)
@@ -1821,10 +1932,9 @@ class Connection:
                 pre_crc = None
                 data = self._frame(msg.TYPE_ID, msg.VERSION, pickled, seq,
                                    flags)
-        self.messenger._note_tx(type(msg).__name__,
-                                sum(self._seg_len(p) for p in data)
-                                if isinstance(data, list) else len(data),
-                                time.monotonic() - t_frame)
+        nbytes = (sum(self._seg_len(p) for p in data)
+                  if isinstance(data, list) else len(data))
+        self.messenger._note_tx(slot, nbytes, time.monotonic() - t_frame)
         if self.policy.replay:
             # lossless send never fails: the frame joins the session queue
             # and reconnect+replay delivers it exactly once (reference
@@ -1841,11 +1951,11 @@ class Connection:
                 await self.close()
                 return
             try:
-                await self._enqueue(data)
+                await self._enqueue(data, nbytes, slot.charge, was)
             except (ConnectionError, OSError):
                 await self.close()
         else:
-            await self._enqueue(data)
+            await self._enqueue(data, nbytes, slot.charge, was)
         if duplicate and not self.closed:
             # the duplicate frame is best-effort: the knob exists to
             # exercise dedup, and a transport error here already has the
@@ -1862,7 +1972,7 @@ class Connection:
             if self.policy.replay:
                 self.unacked.append((dseq, ddata))
             try:
-                await self._enqueue(ddata)
+                await self._enqueue(ddata, nbytes, slot.charge, None)
             except (ConnectionError, OSError):
                 pass
 
@@ -2614,6 +2724,7 @@ class Messenger:
         # it, the service-plane gauge discipline
         self.perf.resync = lambda: self.perf.set(
             "wirepath_kind", 1 if self.wirepath is not None else 0)
+        self._slots: Dict[int, _TypeSlot] = {}  # by wire type id
         # per-daemon log (debug_ms levels): daemons attach their
         # Context's Log; raw messengers stay silent.  Per-frame douts are
         # call-site guarded with log.wants("ms", 20) so a disabled level
@@ -2999,26 +3110,47 @@ class Messenger:
 
     # -- wire accounting -----------------------------------------------------
 
-    def _note_tx(self, type_name: str, nbytes: int, framing_s: float) -> None:
+    def conn_backlog(self, addr: Tuple[str, int]) -> Tuple[int, int]:
+        """(frames sent to `addr` and not acked yet, bytes in the outbox
+        awaiting a flush window) of the session with that peer, its lanes
+        together: what a daemon notes down when the peer did not answer."""
+        conn = self._conns.get(tuple(addr))
+        lanes = getattr(conn, "lanes", None) or [conn]
+        # (a colocated ring or an absent session holds neither)
+        return (sum(len(getattr(c, "unacked", ())) for c in lanes),
+                sum(getattr(c, "_outbox_bytes", 0) for c in lanes))
+
+    def _type_slot(self, type_id: int) -> _TypeSlot:
+        slot = self._slots.get(type_id)
+        if slot is None:
+            cls = _MSG_TYPES.get(type_id)
+            slot = self._slots[type_id] = _TypeSlot(
+                cls.__name__ if cls is not None else f"type{type_id}")
+        return slot
+
+    def _note_tx(self, slot: _TypeSlot, nbytes: int, framing_s: float) -> None:
         # tx_bytes is NOT counted here: _write_raw owns it, so acks and
         # session replays land in the socket totals too
         p = self.perf
         p.inc("tx_msgs")
         p.tinc("tx_framing", framing_s)
-        p.ensure(f"tx_{type_name}", desc=f"{type_name} messages sent")
-        p.ensure(f"tx_bytes_{type_name}", desc=f"{type_name} bytes sent")
-        p.inc(f"tx_{type_name}")
-        p.inc(f"tx_bytes_{type_name}", nbytes)
+        if slot.tx is None:
+            slot.tx, slot.tx_bytes = f"tx_{slot.name}", f"tx_bytes_{slot.name}"
+            p.ensure(slot.tx, desc=f"{slot.name} messages sent")
+            p.ensure(slot.tx_bytes, desc=f"{slot.name} bytes sent")
+        p.inc(slot.tx)
+        p.inc(slot.tx_bytes, nbytes)
 
-    def _note_rx(self, type_name: str, nbytes: int, framing_s: float) -> None:
+    def _note_rx(self, slot: _TypeSlot, nbytes: int, framing_s: float) -> None:
         p = self.perf
         p.inc("rx_msgs")
         p.tinc("rx_framing", framing_s)
-        p.ensure(f"rx_{type_name}", desc=f"{type_name} messages dispatched")
-        p.ensure(f"rx_bytes_{type_name}",
-                 desc=f"{type_name} bytes received")
-        p.inc(f"rx_{type_name}")
-        p.inc(f"rx_bytes_{type_name}", nbytes)
+        if slot.rx is None:
+            slot.rx, slot.rx_bytes = f"rx_{slot.name}", f"rx_bytes_{slot.name}"
+            p.ensure(slot.rx, desc=f"{slot.name} messages dispatched")
+            p.ensure(slot.rx_bytes, desc=f"{slot.name} bytes received")
+        p.inc(slot.rx)
+        p.inc(slot.rx_bytes, nbytes)
 
     # -- handshake -----------------------------------------------------------
 
@@ -3478,6 +3610,7 @@ class Messenger:
                             conn.throttle.put(cost)
                             return  # transport replaced while suspended
                         if type_id == ACK_TYPE:
+                            tracing.charge(ACK_CHARGE)
                             conn.handle_ack(struct.unpack("<Q", payload)[0])
                             conn.throttle.put(cost)
                             continue
@@ -3488,6 +3621,8 @@ class Messenger:
                             conn.throttle.put(cost)
                             continue
                         try:
+                            slot = self._type_slot(type_id)
+                            tracing.charge(slot.charge)
                             t_dec = time.monotonic()
                             with tracing.section("messenger", "decode"):
                                 msg = decode_message(type_id, version,
@@ -3497,8 +3632,7 @@ class Messenger:
                                 # handlers holding an app-level crc of the
                                 # same bytes skip their own pass
                                 msg._wire_verified = True
-                            self._note_rx(type(msg).__name__,
-                                          _HDR.size + cost,
+                            self._note_rx(slot, _HDR.size + cost,
                                           time.monotonic() - t_dec)
                             if conn.reactor is not None:
                                 conn.reactor.rx_msgs += 1
@@ -3570,10 +3704,23 @@ class Messenger:
                             # dispatcher is installed — a group-only
                             # daemon must not have isolated frames
                             # consumed-and-acked undispatched.
+                            if len(batch) > 1 and tracing.metered():
+                                # the daemon walks the batch itself: its
+                                # synchronous head is the batch's types',
+                                # by their counts
+                                whose: Dict[tuple, int] = {}
+                                for _, msg in batch:
+                                    key = self._type_slot(msg.TYPE_ID).charge
+                                    whose[key] = whose.get(key, 0) + 1
+                                tracing.charge_many(whose, claim=False)
                             await self._dispatch_group_home(
                                 conn, [m for _, m in batch])
                         elif self.dispatcher is not None:
+                            many = len(batch) > 1  # else decode's holds
                             for _, msg in batch:
+                                if many:
+                                    tracing.charge(self._type_slot(
+                                        msg.TYPE_ID).charge, claim=False)
                                 try:
                                     await self._dispatch_home(conn, msg)
                                 except (asyncio.CancelledError,

@@ -2258,6 +2258,26 @@ class OSD:
             self._collectors.pop(tid, None)
         return out
 
+    def _gather_gave_up(self, tid: str, remote, replies) -> str:
+        """The tracked-op event of a put whose gather timed out
+        (`gather_timeouts`): the tid, each OSD that did not answer with
+        what this end still holds for it (frames sent and not acked,
+        outbox bytes not flushed) and the loop's newest lag probe: was
+        the sub-write never delivered, the reply never sent, or this
+        loop too late to see it (ROADMAP S6a)."""
+        answered = {r.shard for r in replies}
+        silent = []
+        for shard, osd in remote:
+            if shard not in answered:
+                unacked, outbox = self.messenger.conn_backlog(
+                    self.osdmap.addr_of(osd))
+                silent.append(f"osd.{osd}(shard={shard} unacked={unacked} "
+                              f"outbox_bytes={outbox})")
+        lag = tracing.last_lag()
+        return (f"gather_timeout tid={tid} no_reply={','.join(silent)} "
+                f"loop_lag_ms="
+                + ("unmetered" if lag is None else f"{lag * 1e3:.1f}"))
+
     # -- client ops (primary) ------------------------------------------------
 
     def _store_read(self, key):
@@ -3361,6 +3381,8 @@ class OSD:
         with tracing.section("osd", "write_finish"):
             span.event("commit gathered")
             mark("commit_gathered")
+            if len(replies) < sent:
+                mark(self._gather_gave_up(tid, remote, replies))
             span.finish()
             acks = local_ok + sum(1 for r in replies if r.ok)  # self + remote
             if acks < pool.min_size:

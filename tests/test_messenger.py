@@ -1157,3 +1157,115 @@ class TestWirepathParity:
         m = Messenger("off", {"ms_wirepath_native": False})
         assert m.wirepath is None
         assert m.perf.dump()["wirepath_kind"] == 0
+
+
+class TestLoopCharges:
+    """What a message costs the loop (ISSUE 40): where the messenger knows
+    a frame's type it charges the loop meter, which keeps the step's time
+    as `loop.msg_<Type>` and `loop.for_<family>`."""
+
+    def test_every_registered_message_class_has_a_family(self):
+        """A class without a family fails HERE; in a run it is booked as
+        `control`.  (Classes tests register themselves are not the
+        program's.)"""
+        import importlib
+        import pkgutil
+
+        import ceph_tpu
+        from ceph_tpu.common.tracing import FAMILIES
+        from ceph_tpu.rados.messenger import _MSG_TYPES, MSG_FAMILY
+
+        for mod in pkgutil.walk_packages(ceph_tpu.__path__, "ceph_tpu."):
+            if mod.name.endswith("__main__"):
+                continue
+            try:
+                importlib.import_module(mod.name)
+            except Exception:
+                pass  # an optional dependency; its messages cannot run
+        ours = {cls.__name__ for cls in _MSG_TYPES.values()
+                if cls.__module__.startswith("ceph_tpu.")}
+        assert len(ours) >= 79
+        assert sorted(ours - set(MSG_FAMILY)) == []
+        assert sorted(set(MSG_FAMILY) - ours) == []  # no class that is gone
+        assert set(MSG_FAMILY.values()) == set(FAMILIES) - {"ack", "none"}
+        for name, family in (("MECSubWriteReply", "op"), ("MPing", "liveness"),
+                             ("MOSDPGHitSet", "tier"), ("MPushShard", "recovery"),
+                             ("MScrubShard", "recovery"), ("MMapReply", "control")):
+            assert MSG_FAMILY[name] == family
+
+    def test_pings_sub_writes_and_acks_are_booked_to_their_families(self):
+        from ceph_tpu.common import tracing
+        from ceph_tpu.rados.types import (MECSubWrite, MECSubWriteReply,
+                                          MOSDPing)
+
+        async def go():
+            meter = tracing.install_loop_meter()
+            meter.sample_every = 1
+            server, client, addr = await _pair()
+            replies = asyncio.Queue()
+
+            async def serve(conn, msg):
+                if isinstance(msg, MECSubWrite):
+                    assert bytes(msg.chunk[:4]) == b"\x07" * 4
+                    await conn.send(MECSubWriteReply(tid=msg.tid, ok=True))
+
+            async def collect(conn, msg):
+                await replies.put(msg)
+            server.dispatcher, client.dispatcher = serve, collect
+            conn = await client.connect(addr)
+            await conn.send(MOSDPing(from_osd=1))  # the cork swap's flush
+            await asyncio.sleep(0.05)
+            before = tracing.LOOP_PERF.dump()
+            wire0 = client.perf.dump()
+            chunk = memoryview(bytes([7]) * (512 << 10))
+            for i in range(4):
+                # a ping and a blob-carrying sub-write in ONE flush window
+                await asyncio.gather(
+                    conn.send(MOSDPing(from_osd=1, stamp=float(i))),
+                    conn.send(MECSubWrite(oid="o", shard=i, chunk=chunk,
+                                          tid=f"t{i}")))
+                assert (await asyncio.wait_for(replies.get(), 5)).tid \
+                    == f"t{i}"
+            for _ in range(100):  # the reply's ack rides a window alone
+                if not conn.unacked and not any(
+                        c.unacked for c in server._conns.values()
+                        if hasattr(c, "unacked")):
+                    break
+                await asyncio.sleep(0.02)
+            after = tracing.LOOP_PERF.dump()
+            wire1 = client.perf.dump()
+            srv = server.perf.dump()
+            meter.remove()
+            await client.shutdown()
+            await server.shutdown()
+            return before, after, wire0, wire1, srv
+        before, after, wire0, wire1, srv = run(go())
+
+        def moved(key, part="sum"):
+            return after[key][part] - before.get(key, {}).get(part, 0)
+        for key in ("for_liveness", "for_op", "for_ack", "msg_MOSDPing",
+                    "msg_MECSubWrite", "msg_MECSubWriteReply", "msg_ack"):
+            assert moved(key) > 0 and moved(key, "avgcount") > 0, key
+        # a type is in one family, a family is its types
+        assert moved("for_liveness") == pytest.approx(moved("msg_MOSDPing"))
+        assert moved("for_op") == pytest.approx(
+            moved("msg_MECSubWrite") + moved("msg_MECSubWriteReply"))
+        assert moved("for_ack") == pytest.approx(moved("msg_ack"))
+        assert moved("for_tier") == moved("for_recovery") == 0
+        # 2 MiB of sub-writes cost the loop more than four pings
+        assert moved("msg_MECSubWrite") > moved("msg_MOSDPing")
+        # both cuts of the same seconds
+        families = sum(moved(k) for k in after if k.startswith("for_"))
+        layers = sum(moved(k) for k in after if k.startswith("self_"))
+        assert families == pytest.approx(moved("busy"), rel=1e-6)
+        assert layers == pytest.approx(moved("busy"), rel=1e-6)
+        # the windows that held a ping beside a sub-write were split by a
+        # rule (bytes) and say so; per-type wire counters read as before
+        assert wire1["tx_flush_mixed"] - wire0["tx_flush_mixed"] >= 1
+        assert wire1["tx_MOSDPing"] - wire0["tx_MOSDPing"] == 4
+        assert wire1["tx_MECSubWrite"] == 4
+        assert wire1["tx_bytes_MECSubWrite"] > 4 * (512 << 10)
+        assert wire1["rx_MECSubWriteReply"] == 4
+        assert srv["rx_MECSubWrite"] == 4 and srv["rx_MOSDPing"] == 5
+        assert srv["rx_bytes_MECSubWrite"] == wire1["tx_bytes_MECSubWrite"]
+        assert srv["tx_flush_ack"] >= 1
