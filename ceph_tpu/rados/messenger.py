@@ -60,12 +60,26 @@ swaps the StreamWriter for a CorkedWriter that ``sendmsg``-writevs the
 frame segments STRAIGHT FROM their owning buffers (encode outputs, store
 blobs, BufferList pieces) — zero copies between codec and kernel.
 
-Acks are PIGGYBACKED: dispatching a frame queues a cumulative ack
-(highest contiguous seq) on the connection instead of writing a
-standalone ACK_TYPE frame; the next flush carries one ack frame for the
-whole window (acks are cumulative, so the latest seq covers every
-earlier one).  An ack-only flush is still written promptly when no data
-frames are outbound.  The rx side mirrors the batching: the serve loop
+Acks WAIT FOR COMPANY: dispatching a frame records a debt on the
+connection (``queue_ack``: the highest seq owed — acks are cumulative, so
+the latest seq covers every earlier one — the payload bytes it covers,
+when the debt began) and wakes nobody.  An ack only trims the sender's
+replay queue; no send, op or throttle waits for it, so what a later ack
+costs is the sender's memory held a little longer and a longer replay
+after a reconnect.  The debt is settled by whichever comes first:
+(1) a DATA WINDOW on that connection — the flusher appends the ack to any
+window that has data (a ping's ack beside its reply, an op's beside
+MOSDOpReply); (2) the BOUND on what the sender must hold — once the
+frames owed cover ``Messenger.ACK_OWED_BYTES`` (4 MiB, one put's worth)
+the ack leaves at once in a window of its own; (3) the messenger's SWEEP
+— one timer a messenger per loop (``_AckSweep``), armed only while a
+connection of it owes, whose tick writes in ONE loop step the ack of
+every connection whose debt is ``Messenger.ACK_DELAY_S`` old (500 ms),
+through the connection's own write path and accounting.  Most sockets here carry data one way only
+(``Messenger.send`` uses the sender's OUTBOUND session, so a pair of OSDs
+has two sockets): an ack written the moment it was owed rode alone, one
+frame, one writev and one read step on the far side for nearly every
+message.  The rx side mirrors the batching: the serve loop
 drains every frame ALREADY BUFFERED on the transport into one batch,
 dispatches the batch (through ``group_dispatcher`` when the daemon
 installs one — the whole-stripe group handoff seam), and acks once.
@@ -188,7 +202,15 @@ def _build_wire_perf() -> PerfCounters:
                                        ack frame counts): there the loop
                                        meter's charge is split by bytes, a
                                        rule; elsewhere it is a measurement
-      tx_acks              u64         ack frames written
+      tx_acks              u64         ack frames written; each left one of
+                                       three ways, which sum to it:
+      tx_acks_rode         u64         in a window that had data
+      tx_acks_bound        u64         alone, the frames owed covering
+                                       Messenger.ACK_OWED_BYTES
+      tx_acks_swept        u64         alone, the debt ACK_DELAY_S old (the
+                                       messenger's sweep)
+      ack_frames_covered   u64         sequenced frames the ack frames newly
+                                       covered; over tx_acks: frames an ack
       tx_acks_coalesced    u64         acks absorbed into a pending ack
                                        (would have been standalone frames)
       tx_crc_reused        u64         blob frames whose wire crc reused an
@@ -243,6 +265,13 @@ def _build_wire_perf() -> PerfCounters:
     b.add_u64_counter("tx_flush_mixed", "flush windows holding frames of "
                                         "more than one family")
     b.add_u64_counter("tx_acks", "ack frames written")
+    b.add_u64_counter("tx_acks_rode", "ack frames in a window with data")
+    b.add_u64_counter("tx_acks_bound",
+                      "ack frames sent alone at the bound on bytes owed")
+    b.add_u64_counter("tx_acks_swept",
+                      "ack frames sent alone by the messenger's sweep")
+    b.add_u64_counter("ack_frames_covered",
+                      "sequenced frames newly covered by ack frames")
     b.add_u64_counter("tx_acks_coalesced",
                       "acks absorbed into a pending cumulative ack")
     b.add_u64_counter("tx_crc_reused",
@@ -1481,6 +1510,65 @@ class CorkedWriter:
         self._wake()
 
 
+class _AckSweep:
+    """One messenger's owed acks on one event loop (module docstring
+    "Acks WAIT FOR COMPANY"): the connections that owe, and the ONE timer
+    that settles what no data window and no bound has.  A tick writes, in
+    its own loop step, the ack of every connection whose debt would pass
+    ACK_DELAY_S before a further tick could come (a quarter of the
+    deadline ahead: at most four ticks a deadline, none while nothing is
+    owed), then re-arms for the oldest debt left."""
+
+    __slots__ = ("messenger", "loop", "owing", "timer", "ticks")
+
+    def __init__(self, messenger: "Messenger", loop) -> None:
+        self.messenger = messenger
+        self.loop = loop
+        # connections whose debt the sweep has yet to look at; one that
+        # was settled another way, or went with its transport, leaves at
+        # the next tick
+        self.owing: Dict["Connection", None] = {}
+        self.timer: Optional[asyncio.TimerHandle] = None
+        self.ticks = 0
+
+    def owe(self, conn: "Connection") -> None:
+        """`conn` began to owe an ack (its _ack_since says when)."""
+        self.owing[conn] = None
+        if self.timer is None and not self.messenger._shutdown:
+            # on the connection's own loop (its serve task owes)
+            self.timer = asyncio.get_running_loop().call_later(
+                self.messenger.ACK_DELAY_S, self._tick)
+
+    def _tick(self) -> None:
+        self.timer = None
+        self.ticks += 1
+        # a timer's step is nobody's until it says so: the messenger's
+        # time, and every ack's (tracing.mark, tracing.charge)
+        tracing.mark("messenger")
+        tracing.charge(ACK_CHARGE)
+        delay = self.messenger.ACK_DELAY_S
+        now = time.monotonic()
+        due = now - 0.75 * delay
+        oldest = None
+        for conn in list(self.owing):
+            if conn._ack_pending < 0:
+                del self.owing[conn]
+            elif conn._ack_since <= due:
+                del self.owing[conn]
+                conn.sweep_ack()
+            elif oldest is None or conn._ack_since < oldest:
+                oldest = conn._ack_since
+        if oldest is not None and not self.messenger._shutdown:
+            self.timer = asyncio.get_running_loop().call_later(
+                oldest + delay - now, self._tick)
+
+    def cancel(self) -> None:
+        if self.timer is not None:
+            self.timer.cancel()
+            self.timer = None
+        self.owing.clear()
+
+
 class Connection:
     """One ordered session with a peer.  For lossless sessions this object
     outlives TCP transports: seqs, the unacked queue, and the dedupe floor
@@ -1543,7 +1631,19 @@ class Connection:
         # the same bytes by the loop meter's charge key: whose window the
         # flusher's step and its socket write are (tracing.charge_many)
         self._outbox_by: Dict[tuple, int] = {}
-        self._ack_pending = -1  # highest seq owed an ack; -1 = none
+        # the ack this side owes (module docstring "Acks WAIT FOR
+        # COMPANY"): the highest seq owed (-1 = none), the payload bytes
+        # the debt covers, when it began (time.monotonic), the
+        # counter of the way it leaves ALONE once it is due (tx_acks_bound
+        # / tx_acks_swept; None = not due: only a data window takes it),
+        # the highest seq an ack frame has carried, and the messenger's
+        # sweep on this connection's loop
+        self._ack_pending = -1
+        self._ack_bytes = 0
+        self._ack_since = 0.0
+        self._ack_due: Optional[str] = None
+        self._ack_sent = 0
+        self._ack_sweep: Optional["_AckSweep"] = None
         self._flush_fut: Optional[asyncio.Future] = None
         self._flusher: Optional[asyncio.Task] = None
         self._corked_ok = bool(_cget(messenger.conf, "ms_corked_writev",
@@ -1693,18 +1793,32 @@ class Connection:
         self._kick_flusher()
         return fut
 
-    def queue_ack(self, seq: int) -> None:
-        """Queue a cumulative ack for ``seq`` (acks are cumulative: the
-        receiver pops every unacked frame <= seq, so only the highest
-        pending seq ever needs a frame).  The ack piggybacks on the next
-        flush window — one ack frame per window instead of one per
-        dispatched message."""
+    def queue_ack(self, seq: int, nbytes: int = 0) -> None:
+        """Owe the peer a cumulative ack up to ``seq``, for ``nbytes``
+        more of payload (acks are cumulative: the peer pops every unacked
+        frame <= seq, so only the highest seq owed ever needs a frame).
+        Nobody is woken: the debt leaves with this connection's next data
+        window, or alone once it covers ACK_OWED_BYTES (now) or is
+        ACK_DELAY_S old (the messenger's sweep) — module docstring "Acks
+        WAIT FOR COMPANY"."""
         if self.closed:
             return
+        m = self.messenger
         if self._ack_pending >= 0:
-            self.messenger.perf.inc("tx_acks_coalesced")
-        self._ack_pending = max(self._ack_pending, seq)
-        self._kick_flusher()
+            m.perf.inc("tx_acks_coalesced")
+            if seq > self._ack_pending:
+                self._ack_pending = seq
+        else:
+            self._ack_pending = seq
+            sweep = self._ack_sweep
+            if sweep is None:
+                sweep = self._ack_sweep = m._ack_sweep_here()
+            self._ack_since = time.monotonic()
+            sweep.owe(self)
+        self._ack_bytes += nbytes
+        if self._ack_bytes >= m.ACK_OWED_BYTES and self._ack_due is None:
+            self._ack_due = "tx_acks_bound"
+            self._kick_flusher()
 
     def _kick_flusher(self) -> None:
         if self._flusher is None or self._flusher.done():
@@ -1715,53 +1829,101 @@ class Connection:
             self._flusher.add_done_callback(m._tasks.discard)
 
     def _ack_frame(self) -> bytes:
-        payload = struct.pack("<Q", self._ack_pending)
-        self._ack_pending = -1
+        """The frame that settles the debt (seqs are consecutive, so what
+        it newly covers is its distance from the last one written)."""
+        seq = self._ack_pending
+        if seq > self._ack_sent:
+            self.messenger.perf.inc("ack_frames_covered",
+                                    seq - self._ack_sent)
+            self._ack_sent = seq
+        self._ack_pending, self._ack_bytes, self._ack_due = -1, 0, None
+        payload = _ACK_SEQ.pack(seq)
         return _HDR.pack(8, ACK_TYPE, 1, 0, self.crc_fn(payload), 0) + payload
+
+    def _cut_window(self):
+        """Take what is queued as ONE flush window and count it: the
+        outbox, and the owed ack when the window has data for it to ride
+        or the debt is due.  Returns (segs, nbytes, whose, fut): the
+        segments to write (none: nothing to do), their bytes, the same
+        bytes by the loop meter's charge key, the future the window's
+        senders await.  The caller's step is the window's from here."""
+        perf = self.messenger.perf
+        segs, self._outbox = self._outbox, []
+        frames, self._outbox_frames = self._outbox_frames, 0
+        nbytes, self._outbox_bytes = self._outbox_bytes, 0
+        whose, self._outbox_by = self._outbox_by, {}
+        fut, self._flush_fut = self._flush_fut, None
+        had_data = bool(segs)
+        if self._ack_pending >= 0 and (had_data or self._ack_due):
+            perf.inc("tx_acks_rode" if had_data else self._ack_due)
+            ack = self._ack_frame()
+            segs.append(ack)
+            frames += 1
+            nbytes += len(ack)
+            whose[ACK_CHARGE] = len(ack)
+            perf.inc("tx_acks")
+        if segs:
+            # this step and the socket write are the window's, by its
+            # bytes by type (an ack-only window: the ack's)
+            tracing.charge_many(whose)
+            if len(whose) > 1 \
+                    and len({family for family, _ in whose}) > 1:
+                perf.inc("tx_flush_mixed")
+            perf.inc("tx_flush_data" if had_data else "tx_flush_ack")
+            perf.inc("tx_flushes")
+            perf.hinc("tx_flush_frames", frames)
+            perf.hinc("tx_flush_bytes", nbytes)
+        return segs, nbytes, whose, fut
+
+    def sweep_ack(self) -> None:
+        """The messenger's sweep found this debt at its deadline: the ack
+        leaves alone.  Where the ordinary window can be written here and
+        now — nothing queued or being written, and a writer whose write
+        is the socket's (CorkedWriter: plaintext TCP) with no backlog — it
+        is, in the sweep's own loop step; else the flusher takes it (the
+        ack rides, or follows, the window in hand)."""
+        if self._ack_due is None:
+            self._ack_due = "tx_acks_swept"
+        if not (self._outbox or self._send_lock.locked()
+                or (self._flusher is not None and not self._flusher.done())):
+            self._maybe_cork()
+            w = self.writer
+            if isinstance(w, CorkedWriter) and not w._buffered:
+                perf = self.messenger.perf
+                segs, nbytes, _, _ = self._cut_window()  # no sender waits
+                t_io = time.monotonic()
+                try:
+                    with perf.time_avg("tx_io"):
+                        with tracing.section("messenger", "sock_write"):
+                            _writelines(w, segs)
+                except (ConnectionError, OSError):
+                    # the transport is going and its reader's end closes
+                    # the session: the peer replays, the dedupe path re-acks
+                    return
+                perf.inc("tx_bytes", nbytes)
+                perf.hinc("tx_io_us", (time.monotonic() - t_io) * 1e6)
+                return
+        self._kick_flusher()
 
     async def _flush_loop(self) -> None:
         """The per-connection flusher: drains flush windows until the
-        outbox and pending ack are empty.  tx accounting lives HERE so
+        outbox is empty and no ack is due (an ack that is only owed waits
+        for a data window, the bound or the sweep: queue_ack).  tx
+        accounting lives in the window (_cut_window) and HERE so
         every socket write — messages, acks — lands in tx_io/tx_bytes;
         per-message framing cost and per-type counts are send()'s
         (_note_tx).  The tx_io timer starts INSIDE the lock: queueing
         behind an adopt_transport replay is not socket time."""
         perf = self.messenger.perf
         try:
-            while (self._outbox or self._ack_pending >= 0) \
-                    and not self.closed:
+            while (self._outbox or self._ack_due) and not self.closed:
                 async with self._send_lock:
                     if self.closed:
                         break
                     self._maybe_cork()
-                    segs = self._outbox
-                    self._outbox = []
-                    frames = self._outbox_frames
-                    self._outbox_frames = 0
-                    nbytes = self._outbox_bytes
-                    self._outbox_bytes = 0
-                    whose, self._outbox_by = self._outbox_by, {}
-                    fut, self._flush_fut = self._flush_fut, None
-                    had_data = bool(segs)
-                    if self._ack_pending >= 0:
-                        ack = self._ack_frame()
-                        segs.append(ack)
-                        frames += 1
-                        nbytes += len(ack)
-                        whose[ACK_CHARGE] = len(ack)
-                        perf.inc("tx_acks")
+                    segs, nbytes, whose, fut = self._cut_window()
                     if not segs:
                         break
-                    # this step and the socket write are the window's, by
-                    # its bytes by type (an ack-only window: the ack's)
-                    tracing.charge_many(whose)
-                    if len(whose) > 1 \
-                            and len({family for family, _ in whose}) > 1:
-                        perf.inc("tx_flush_mixed")
-                    perf.inc("tx_flush_data" if had_data else "tx_flush_ack")
-                    perf.inc("tx_flushes")
-                    perf.hinc("tx_flush_frames", frames)
-                    perf.hinc("tx_flush_bytes", nbytes)
                     gen = self.transport_gen
                     t_io = time.monotonic()
                     try:
@@ -1830,7 +1992,7 @@ class Connection:
         self._outbox_frames = 0
         self._outbox_bytes = 0
         self._outbox_by = {}
-        self._ack_pending = -1
+        self._ack_pending, self._ack_bytes, self._ack_due = -1, 0, None
         if fut is not None and not fut.done():
             fut.set_exception(exc)
             fut.exception()  # mark retrieved: ok if every sender left
@@ -1975,11 +2137,6 @@ class Connection:
                 await self._enqueue(ddata, nbytes, slot.charge, None)
             except (ConnectionError, OSError):
                 pass
-
-    async def send_ack(self, seq: int) -> None:
-        """Compat shim: queue a cumulative ack (piggybacked on the next
-        flush window; see queue_ack)."""
-        self.queue_ack(seq)
 
     def handle_ack(self, seq: int) -> None:
         while self.unacked and self.unacked[0][0] <= seq:
@@ -2830,6 +2987,9 @@ class Messenger:
         # per-reactor-loop dispatch throttles (Throttle futures are
         # loop-bound; backpressure is per shard)
         self._loop_throttles: Dict[Any, Throttle] = {}
+        # the sweep of owed acks, one per loop that owns connections of
+        # this messenger (the home loop; each reactor worker's)
+        self._ack_sweeps: Dict[Any, _AckSweep] = {}
 
     def policy_for(self, peer_type: str) -> Policy:
         return self.policies.get(peer_type, Policy.lossy_client())
@@ -2858,6 +3018,12 @@ class Messenger:
                 f"{self.name}-dispatch-shard",
                 _cget(self.conf, "ms_dispatch_throttle_bytes", 100 << 20))
         return t
+
+    def _ack_sweep_here(self) -> "_AckSweep":
+        """The sweep of owed acks for the CURRENT loop (a connection's
+        serve loop asks once)."""
+        loop = asyncio.get_running_loop()
+        return self._ack_sweeps.setdefault(loop, _AckSweep(self, loop))
 
     def _throttle_put(self, conn, cost: int) -> None:
         """Return dispatch-throttle budget to ``conn``'s shard, from any
@@ -3558,6 +3724,15 @@ class Messenger:
     # the throttle bytes held across a group dispatch)
     RX_BATCH_MSGS = 32
     RX_BATCH_BYTES = 32 << 20
+    # what an owed ack may wait for (module docstring "Acks WAIT FOR
+    # COMPANY"; Connection.queue_ack): its age before the sweep sends it
+    # alone — of 50 / 200 / 500 ms the one that left fewest ack-only
+    # windows and returned most (PERF.md section 6, PR 41; 200 ms is the
+    # ceiling of Linux's delayed ack, 500 ms RFC 1122's) — and the payload
+    # bytes a sender must hold for it, one put's worth.  Constants, not
+    # options: the rule reads the connection's own state and nothing else.
+    ACK_DELAY_S = 0.5
+    ACK_OWED_BYTES = 4 << 20
 
     @staticmethod
     def _buffered_frame_len(reader) -> Optional[int]:
@@ -3617,7 +3792,7 @@ class Messenger:
                         if seq and seq <= conn.in_seq:
                             # replayed duplicate: re-ack (the original ack
                             # may have been lost) but don't re-dispatch
-                            conn.queue_ack(seq)
+                            conn.queue_ack(seq, cost)
                             conn.throttle.put(cost)
                             continue
                         try:
@@ -3654,7 +3829,7 @@ class Messenger:
                                   f"v={version}: {e}")
                             if seq:
                                 conn.in_seq = seq
-                                conn.queue_ack(seq)
+                                conn.queue_ack(seq, cost)
                             conn.throttle.put(cost)
                             continue
                         if isinstance(msg, MLaneHello):
@@ -3664,7 +3839,7 @@ class Messenger:
                             self._bind_lane(conn, msg)
                             if seq:
                                 conn.in_seq = max(conn.in_seq, seq)
-                                conn.queue_ack(seq)
+                                conn.queue_ack(seq, cost)
                             conn.throttle.put(cost)
                             if msg.lane >= 1 and self._delegatable():
                                 # process mode: a freshly bound DATA
@@ -3677,11 +3852,11 @@ class Messenger:
                         if conn.lane_group is not None:
                             # striped session: the LaneGroup restores
                             # gseq order, reassembles fragments, and
-                            # dispatches through its single pump — ack
-                            # per frame (the flush window coalesces)
+                            # dispatches through its single pump — a
+                            # debt per frame (queue_ack coalesces)
                             if seq:
                                 conn.in_seq = max(conn.in_seq, seq)
-                                conn.queue_ack(seq)
+                                conn.queue_ack(seq, cost)
                             conn.lane_group.rx_push(conn, msg, cost)
                             continue
                         batch.append((seq, msg))
@@ -3738,7 +3913,7 @@ class Messenger:
                     # frame; one cumulative ack covers the whole batch
                     if top_seq:
                         conn.in_seq = max(conn.in_seq, top_seq)
-                        conn.queue_ack(top_seq)
+                        conn.queue_ack(top_seq, sum(costs))
                 finally:
                     for c in costs:
                         conn.throttle.put(c)
@@ -3989,7 +4164,7 @@ class Messenger:
                     # Replayed frames may re-dispatch there (at-least-once
                     # across an acceptor restart, as in the reference — PG
                     # reqid dedupe above absorbs it).
-                    conn.in_seq = 0
+                    conn.in_seq = conn._ack_sent = 0
                 conn.crc_fn = crc_fn
                 await conn.adopt_transport(reader, writer)
                 task = asyncio.get_running_loop().create_task(
@@ -4141,6 +4316,14 @@ class Messenger:
         # Tasks living on reactor loops must be cancelled FROM their own
         # loop (Task.cancel is not thread-safe across loops).
         here = asyncio.get_running_loop()
+        for sweep in list(self._ack_sweeps.values()):
+            if sweep.loop is here:
+                sweep.cancel()
+            elif not sweep.loop.is_closed():
+                try:
+                    sweep.loop.call_soon_threadsafe(sweep.cancel)
+                except RuntimeError:
+                    pass  # loop shut down under us
         for t in list(self._tasks):
             t_loop = t.get_loop()
             if t_loop is here:

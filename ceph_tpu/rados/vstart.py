@@ -115,12 +115,24 @@ class Cluster:
             await self.add_osd()
 
     async def wait_for_quorum(self, timeout: float = 10.0) -> None:
-        deadline = asyncio.get_running_loop().time() + timeout
-        while asyncio.get_running_loop().time() < deadline:
-            if any(m.is_leader for m in self.mons):
+        """Until the leader's quorum follows it.  A leader that has only
+        DECLARED itself is not a quorum yet: its victory is still on the
+        way, and the first OSD's boot holds this loop for seconds (codec
+        and device set-up), by which time the peers have timed out and
+        elected among themselves, leaving the first mon of every client's
+        list outside the quorum, serving its boot map (PERF.md section 6,
+        PR 41)."""
+        loop = asyncio.get_running_loop()
+        deadline = loop.time() + timeout
+        while loop.time() < deadline:
+            leaders = [m.logic for m in self.mons if m.is_leader]
+            if len(leaders) == 1 and all(
+                    m.logic.leader == leaders[0].rank for m in self.mons
+                    if m.rank in leaders[0].quorum):
                 return
             await asyncio.sleep(0.05)
-        raise TimeoutError("mon quorum did not form")
+        if not any(m.is_leader for m in self.mons):
+            raise TimeoutError("mon quorum did not form")
 
     async def add_osd(self) -> OSD:
         # capacity seeding (the fullness plane's byte ceiling): BlueStore

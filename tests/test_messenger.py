@@ -4,6 +4,7 @@ replay with exactly-once dispatch, dispatch throttle, fault injection
 
 import asyncio
 import struct
+import time
 import zlib
 
 import pytest
@@ -1269,3 +1270,262 @@ class TestLoopCharges:
         assert srv["rx_MECSubWrite"] == 4 and srv["rx_MOSDPing"] == 5
         assert srv["rx_bytes_MECSubWrite"] == wire1["tx_bytes_MECSubWrite"]
         assert srv["tx_flush_ack"] >= 1
+
+
+async def _until(cond, seconds=3.0, step=0.01):
+    """Poll `cond` for at most `seconds`; says whether it came true."""
+    for _ in range(int(seconds / step)):
+        if cond():
+            return True
+        await asyncio.sleep(step)
+    return cond()
+
+
+def _sweep_timers(loop, messenger):
+    """The loop's live timer handles that are `messenger`'s ack sweep."""
+    from ceph_tpu.rados.messenger import _AckSweep
+
+    return [h for h in loop._scheduled if not h.cancelled()
+            and getattr(h._callback, "__func__", None) is _AckSweep._tick
+            and h._callback.__self__.messenger is messenger]
+
+
+class TestAcksWaitForCompany:
+    """An owed ack wakes nobody: it rides the connection's next data
+    window, leaves alone at the bound on the bytes owed, or is written by
+    the messenger's one sweep at the deadline (module docstring "Acks WAIT
+    FOR COMPANY")."""
+
+    @pytest.mark.parametrize("server_conf,inline", [
+        ({}, True), ({"ms_corked_writev": False}, False)])
+    def test_idle_reverse_direction_acks_within_the_deadline(
+            self, server_conf, inline):
+        from ceph_tpu.rados.messenger import CorkedWriter
+
+        async def go():
+            server, client, addr = await _pair(server_conf=server_conf)
+            got = asyncio.Queue()
+
+            async def dispatch(conn, msg):
+                await got.put(conn)
+            server.dispatcher = dispatch
+            conn = await client.connect(addr)
+            t0 = time.monotonic()
+            await conn.send(MTest(seqno=1))
+            sconn = await asyncio.wait_for(got.get(), 5)
+            assert await _until(lambda: not conn.unacked)
+            waited = time.monotonic() - t0
+            # a timer cannot fire early: no ack before the sweep's first
+            # tick, three quarters of the deadline after the debt began
+            assert waited >= 0.75 * server.ACK_DELAY_S - 0.02, waited
+            d = server.perf.dump()
+            assert d["tx_acks"] == d["tx_acks_swept"] == 1, d
+            assert d["tx_acks_rode"] == d["tx_acks_bound"] == 0, d
+            assert d["tx_flush_ack"] == d["tx_flushes"] == 1, d
+            assert d["ack_frames_covered"] == 1
+            assert d["tx_io"]["avgcount"] == 1 and d["tx_bytes"] == 29
+            # plaintext TCP: the sweep's own step wrote the window; a
+            # writer whose write is not the socket's hands it to a task
+            assert isinstance(sconn.writer, CorkedWriter) is inline
+            assert (sconn._flusher is None) is inline
+            await client.shutdown()
+            await server.shutdown()
+
+        run(go())
+
+    def test_frames_inside_one_deadline_share_one_ack(self):
+        async def go():
+            server, client, addr = await _pair()
+            server.ACK_DELAY_S = 1.0  # room for a slow host's seven sends
+            got = asyncio.Queue()
+
+            async def dispatch(conn, msg):
+                await got.put(msg)
+            server.dispatcher = dispatch
+            conn = await client.connect(addr)
+            n = 7
+            for i in range(n):  # one rx batch, one debt entry each
+                await conn.send(MTest(seqno=i))
+                await asyncio.wait_for(got.get(), 5)
+            assert len(conn.unacked) == n
+            assert await _until(lambda: not conn.unacked, 4.0)
+            d = server.perf.dump()
+            assert d["tx_acks"] == d["tx_acks_swept"] == 1, d
+            assert d["ack_frames_covered"] / d["tx_acks"] == n
+            assert d["tx_acks_coalesced"] == n - 1
+            await client.shutdown()
+            await server.shutdown()
+
+        run(go())
+
+    def test_ack_rides_a_data_window_and_the_sweep_writes_nothing(self):
+        async def go():
+            server, client, addr = await _pair()
+            server.ACK_DELAY_S = 0.4
+            got = asyncio.Queue()
+
+            async def dispatch(conn, msg):
+                await got.put(conn)
+            server.dispatcher = dispatch
+            client.dispatcher = _swallow
+            conn = await client.connect(addr)
+            await conn.send(MTest(seqno=1))
+            sconn = await asyncio.wait_for(got.get(), 5)
+            assert conn.unacked
+            await sconn.send(MTest(text="data"))  # before the deadline
+            assert await _until(lambda: not conn.unacked)
+            d = server.perf.dump()
+            assert d["tx_acks"] == d["tx_acks_rode"] == 1, d
+            assert d["tx_flush_mixed"] == d["tx_flush_data"] == 1, d
+            await asyncio.sleep(server.ACK_DELAY_S + 0.1)  # the tick came
+            sweep, = server._ack_sweeps.values()
+            d = server.perf.dump()
+            assert sweep.ticks == 1 and sweep.timer is None
+            assert not sweep.owing
+            assert d["tx_acks"] == 1 and d["tx_acks_swept"] == 0, d
+            assert d["tx_flush_ack"] == 0 and d["tx_flushes"] == 1, d
+            await client.shutdown()
+            await server.shutdown()
+
+        run(go())
+
+    def test_bytes_owed_at_the_bound_are_acked_without_waiting(self):
+        async def go():
+            server, client, addr = await _pair()
+            server.ACK_DELAY_S = 30.0  # only the bound can send this ack
+            got = asyncio.Queue()
+
+            async def dispatch(conn, msg):
+                await got.put(msg)
+            server.dispatcher = dispatch
+            conn = await client.connect(addr)
+            blob = bytes(3 << 19)  # 1.5 MiB: the third passes 4 MiB
+            for i in range(2):
+                await conn.send(MTest(seqno=i, blob=blob))
+                await asyncio.wait_for(got.get(), 5)
+            await asyncio.sleep(0.05)
+            assert len(conn.unacked) == 2
+            assert server.perf.dump()["tx_acks"] == 0
+            await conn.send(MTest(seqno=2, blob=blob))
+            assert await _until(lambda: not conn.unacked)
+            d = server.perf.dump()
+            assert d["tx_acks"] == d["tx_acks_bound"] == 1, d
+            assert d["ack_frames_covered"] == 3
+            assert d["tx_flush_ack"] == 1
+            sweep, = server._ack_sweeps.values()
+            assert sweep.ticks == 0
+            await client.shutdown()
+            await server.shutdown()
+
+        run(go())
+
+    def test_one_timer_a_messenger_however_many_connections_owe(self):
+        async def go():
+            server = Messenger("server", {}, entity_type="osd")
+            addr = await server.bind()
+            server.dispatcher = _swallow
+            clients = [Messenger(f"client{i}", {}, entity_type="osd")
+                       for i in range(5)]
+            conns = [await c.connect(addr) for c in clients]
+            loop = asyncio.get_running_loop()
+            for conn in conns:
+                await conn.send(MTest(seqno=1))
+            assert await _until(lambda: server.perf.dump()["rx_msgs"] == 5)
+            sweep, = server._ack_sweeps.values()
+            assert len(sweep.owing) == 5
+            assert len(_sweep_timers(loop, server)) == 1
+            assert await _until(
+                lambda: not any(conn.unacked for conn in conns))
+            d = server.perf.dump()
+            assert d["tx_acks"] == d["tx_acks_swept"] == 5, d
+            # debts a few ms apart share a tick: far fewer steps than acks
+            assert 1 <= sweep.ticks <= 2, sweep.ticks
+            assert await _until(lambda: sweep.timer is None)
+            assert not _sweep_timers(loop, server) and not sweep.owing
+            for c in clients:
+                await c.shutdown()
+            await server.shutdown()
+
+        run(go())
+
+    def test_transport_dropped_under_a_debt_replays_exactly_once(self):
+        async def go():
+            server, client, addr = await _pair()
+            server.ACK_DELAY_S = 0.4
+            received = []
+
+            async def dispatch(conn, msg):
+                received.append(msg.seqno)
+            server.dispatcher = dispatch
+            conn = await client.connect(addr)
+            for i in range(3):
+                await conn.send(MTest(seqno=i))
+            assert await _until(lambda: len(received) == 3)
+            sconn, = server._sessions.values()
+            assert sconn._ack_pending == 3 and len(conn.unacked) == 3
+            await conn.close()  # the debt goes with the server's transport
+            # the initiator redials and replays all three; the dedupe floor
+            # keeps them from the dispatcher and owes their ack again
+            assert await _until(lambda: not conn.closed)
+            assert await _until(lambda: not conn.unacked)
+            assert received == [0, 1, 2]
+            for i in range(3, 5):
+                await client.send(addr, MTest(seqno=i))
+            assert await _until(lambda: len(received) == 5
+                                and not conn.unacked)
+            assert received == [0, 1, 2, 3, 4]
+            d = server.perf.dump()
+            assert d["tx_acks"] == d["tx_acks_swept"] >= 1, d
+            # a re-ack covers nothing new: five frames, each counted once
+            assert d["ack_frames_covered"] == 5
+            await client.shutdown()
+            await server.shutdown()
+
+        run(go())
+
+    def test_injected_drops_under_standing_debts_deliver_exactly_once(self):
+        async def go():
+            server, client, addr = await _pair(
+                client_conf={"ms_inject_socket_failures": 6})
+            received = []
+
+            async def dispatch(conn, msg):
+                received.append(msg.seqno)
+            server.dispatcher = dispatch
+            n = 60
+            for i in range(n):  # nearly every drop finds an ack owed
+                await client.send(addr, MTest(seqno=i), retries=8)
+            assert await _until(lambda: len(set(received)) == n, 10.0, 0.05)
+            assert received == list(range(n)), "loss, duplicate or reorder"
+            conn = client._conns[tuple(addr)]
+            assert await _until(lambda: not conn.unacked, 10.0, 0.05)
+            d = server.perf.dump()
+            assert d["tx_acks"] < n, d
+            assert d["tx_acks"] == d["tx_acks_rode"] + d["tx_acks_bound"] \
+                + d["tx_acks_swept"]
+            await client.shutdown()
+            await server.shutdown()
+
+        run(go())
+
+    def test_shutdown_leaves_no_pending_timer(self):
+        async def go():
+            server, client, addr = await _pair()
+            server.ACK_DELAY_S = 30.0
+            server.dispatcher = _swallow
+            conn = await client.connect(addr)
+            await conn.send(MTest(seqno=1))
+            loop = asyncio.get_running_loop()
+            assert await _until(lambda: _sweep_timers(loop, server))
+            sweep, = server._ack_sweeps.values()
+            sconn, = server._sessions.values()
+            await server.shutdown()
+            assert sweep.timer is None and not sweep.owing
+            assert not _sweep_timers(loop, server)
+            sconn.closed = False  # a straggler's debt re-arms nothing
+            sconn.queue_ack(2)
+            assert sweep.timer is None
+            assert not _sweep_timers(loop, server)
+            await client.shutdown()
+
+        run(go())

@@ -211,6 +211,32 @@ class TestMonQuorum:
 
         run(go())
 
+    def test_start_waits_until_the_quorum_follows_its_leader(self):
+        """A leader that has only declared itself is not a quorum: the
+        victory has to have reached the peers before the OSDs' boot holds
+        the loop (vstart.wait_for_quorum; PERF.md section 6, PR 41)."""
+        class _Mon:
+            def __init__(self, rank):
+                self.rank, self.logic = rank, ElectionLogic(rank, 3)
+                self.is_leader = False
+
+        async def go():
+            cluster = Cluster(n_osds=0, conf=dict(FAST), n_mons=3)
+            cluster.mons = mons = [_Mon(r) for r in range(3)]
+            waiter = asyncio.ensure_future(cluster.wait_for_quorum(5.0))
+            mons[0].logic.leader, mons[0].logic.quorum = 0, {0, 1}
+            mons[0].is_leader = True  # declared; victory on its way
+            await asyncio.sleep(0.2)
+            assert not waiter.done()
+            mons[1].logic.receive_victory(0, mons[0].logic.epoch, {0, 1})
+            # mon.2 acked too late to be in the quorum: nobody waits for it
+            await asyncio.wait_for(waiter, 1.0)
+            with pytest.raises(TimeoutError):
+                mons[0].is_leader = False
+                await cluster.wait_for_quorum(0.2)
+
+        run(go())
+
     def test_write_through_peon_is_forwarded(self):
         async def go():
             cluster = Cluster(n_osds=4, conf=dict(FAST), n_mons=3)
