@@ -299,6 +299,16 @@ class Rados:
         self._client = RadosClient(mon_addr, conf)
         self.connected = False
 
+    @classmethod
+    def from_client(cls, client: RadosClient) -> "Rados":
+        """A handle over an engine that is already started (a harness
+        that holds a RadosClient and wants IoCtxs on it: their ops, and
+        its `objecter` counters, are that client's)."""
+        rados = cls.__new__(cls)
+        rados._client = client
+        rados.connected = True
+        return rados
+
     async def connect(self) -> "Rados":
         await self._client.start()
         await self._client.refresh_map()

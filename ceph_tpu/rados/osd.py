@@ -320,6 +320,38 @@ class OSD:
             .add_u64_counter("rmw_partial", "stripe-scoped partial overwrites")
             .add_u64_counter("rmw_extent_hits",
                              "RMW reads served from the extent cache")
+            # an offset write's base comes from one of four arms: the
+            # whole object cached (rmw_base_cached), its stripes in the
+            # extent cache (rmw_extent_hits), k shards' extents read and
+            # decoded (rmw_base_shards), a whole-object read
+            # (rmw_base_full_read); the four sum to the offset writes
+            .add_u64_counter("rmw_base_cached",
+                             "offset writes whose base was the primary's "
+                             "cached whole object")
+            .add_u64_counter("rmw_base_shards",
+                             "offset writes whose base was read from k "
+                             "shards' extents and decoded")
+            .add_u64_counter("rmw_base_full_read",
+                             "offset writes that found no consistent cut "
+                             "and read the whole object")
+            .add_u64_counter("rmw_full_rewrite",
+                             "offset writes that went out as a rewrite of "
+                             "the whole object, not as splices")
+            .add_time_avg("rmw_read_lat",
+                          "offset write: 'rmw read' -> base in hand (a "
+                          "wait, like op_lat)")
+            .add_u64_counter("rmw_copied_bytes",
+                             "bytes the primary copied to build an offset "
+                             "write's base and segment")
+            .add_u64_counter("splice_copied_bytes",
+                             "bytes copied by shards splicing a chunk "
+                             "range into their stored blob")
+            .add_u64_counter("splice_crc_bytes",
+                             "bytes checksummed by shards after a splice "
+                             "(blob crc and hinfo entry)")
+            .add_u64_counter("splice_refused",
+                             "splices refused: the stored shard was not at "
+                             "the version the primary read")
             .add_u64_counter("write_adopted_bytes",
                              "EC write payload bytes the extent cache "
                              "keeps by reference (no copy on the put "
@@ -3168,56 +3200,85 @@ class OSD:
             s0, slen = sinfo.offset_len_to_stripe_bounds(
                 op.offset, len(op.data))
             seg: Optional[bytes] = None
-            cached = self._cache_get(op.pool_id, op.oid)
-            if cached is not None:
-                base_version, cached_data = cached
-                base = bytearray(cached_data)
-                if len(base) < op.offset:
-                    base.extend(b"\x00" * (op.offset - len(base)))
-                base[op.offset:op.offset + len(op.data)] = op.data
-                full = bytes(base)
-                object_size = len(full)
-                seg = full[s0:s0 + slen]
-                full_for_cache = full
-            else:
-                # extent-granular hit (reference ExtentCache pinning): a
-                # prior RMW on an overlapping range left its decoded
-                # stripes here — no shard reads at all
-                ranged = self._extent_cache.get_range(
-                    (op.pool_id, op.oid), s0, slen)
+            # which of four arms hands this write its base (each counted;
+            # their sum is the offset writes), how long the write waited
+            # for it (rmw_read_lat), and what the primary copied to
+            # build base and segment (rmw_copied_bytes)
+            t_base = time.monotonic()
+            copied = 0
+            with tracing.section("osd", "rmw_base"):
+                cached = self._cache_get(op.pool_id, op.oid)
+                if cached is not None:
+                    # (a) the whole object is cached
+                    self.perf.inc("rmw_base_cached")
+                    base_version, cached_data = cached
+                    base = bytearray(cached_data)
+                    if len(base) < op.offset:
+                        base.extend(b"\x00" * (op.offset - len(base)))
+                    base[op.offset:op.offset + len(op.data)] = op.data
+                    full = bytes(base)
+                    object_size = len(full)
+                    seg = full[s0:s0 + slen]
+                    copied = 2 * len(full) + len(seg)
+                    full_for_cache = full
+                    ranged = None
+                else:
+                    # extent-granular hit (reference ExtentCache pinning):
+                    # a prior RMW on an overlapping range left its decoded
+                    # stripes here — no shard reads at all
+                    ranged = self._extent_cache.get_range(
+                        (op.pool_id, op.oid), s0, slen)
+            if cached is None:
                 got = None
-                if ranged is not None and ranged[2] > 0                         and len(ranged[1]) == slen:
+                if ranged is not None and ranged[2] > 0 \
+                        and len(ranged[1]) == slen:
+                    # (b) the extent cache holds the stripes
                     base_version, stripes, old_size = ranged
                     self.perf.inc("rmw_extent_hits")
+                    copied = slen  # get_range cut them out of their run
                     got = (old_size, stripes, base_version)
                 else:
                     got = await self._read_stripe_range(
                         op, pool, codec, sinfo, s0, slen)
+                    if got is not None:
+                        # (c) k shards' extents, read and decoded
+                        self.perf.inc("rmw_base_shards")
                 if got is not None:
-                    old_size, stripes, base_version = got
-                    seg_buf = bytearray(stripes)
-                    lo = op.offset - s0
-                    seg_buf[lo:lo + len(op.data)] = op.data
-                    seg = bytes(seg_buf)
-                    object_size = max(old_size, op.offset + len(op.data))
-                    full_for_cache = None  # only the segment is in hand
+                    with tracing.section("osd", "rmw_base"):
+                        old_size, stripes, base_version = got
+                        seg_buf = bytearray(stripes)
+                        lo = op.offset - s0
+                        seg_buf[lo:lo + len(op.data)] = op.data
+                        seg = bytes(seg_buf)
+                        copied += 2 * len(seg)
+                        object_size = max(old_size,
+                                          op.offset + len(op.data))
+                        full_for_cache = None  # only the segment is in hand
                 else:
-                    # degraded / inconsistent / absent: whole-object path
+                    # (d) degraded / inconsistent / absent: whole-object
+                    # read, and the whole object written again below
+                    self.perf.inc("rmw_base_full_read")
                     read = await self._do_read(
                         MOSDOp(op="read", pool_id=op.pool_id, oid=op.oid))
-                    base = bytearray(as_bytes(read.data)) \
-                        if read.ok else bytearray()
-                    if len(base) < op.offset:
-                        base.extend(b"\x00" * (op.offset - len(base)))
-                    base[op.offset:op.offset + len(op.data)] = op.data
-                    data = bytes(base)
-                    object_size = len(data)
-                    full_for_cache = data
+                    with tracing.section("osd", "rmw_base"):
+                        base = bytearray(as_bytes(read.data)) \
+                            if read.ok else bytearray()
+                        if len(base) < op.offset:
+                            base.extend(b"\x00" * (op.offset - len(base)))
+                        base[op.offset:op.offset + len(op.data)] = op.data
+                        data = bytes(base)
+                        copied = 2 * len(data)
+                        object_size = len(data)
+                        full_for_cache = data
+            self.perf.tinc("rmw_read_lat", time.monotonic() - t_base)
+            self.perf.inc("rmw_copied_bytes", copied)
             if seg is not None:
                 self.perf.inc("rmw_partial")
                 data = seg
                 chunk_off = sinfo.aligned_logical_offset_to_chunk_offset(s0)
                 shard_size = sinfo.logical_to_next_chunk_offset(object_size)
+            else:
+                self.perf.inc("rmw_full_rewrite")
         # encode BEFORE allocating the PG-log eversion: the batched encode
         # awaits the device queue, and the version->local-apply window
         # below must stay SYNCHRONOUS — a concurrent log merge (repair
@@ -4677,24 +4738,29 @@ class OSD:
             # self-consistent crc.  Refusal costs one ack; recovery
             # re-pushes the full blob.
             if old is None or old[1].version != prior_version:
+                self.perf.inc("splice_refused")
                 return False
             # splice the chunk range into the stored blob (per-stripe RMW);
             # zero-extension to shard_size covers gap stripes — zero chunks
             # ARE the parity of zero stripes for these linear codes
-            base = bytearray(old[0])
-            appended = chunk_off == len(base)
-            want = max(shard_size, chunk_off + len(chunk), len(base))
-            if len(base) < want:
-                base.extend(b"\x00" * (want - len(base)))
-            base[chunk_off:chunk_off + len(chunk)] = chunk
-            blob = bytes(base)
-            chunk_crc = None  # splice: the shipped crc covered the delta
+            with tracing.section("osd", "rmw_splice"):
+                base = bytearray(old[0])
+                appended = chunk_off == len(base)
+                want = max(shard_size, chunk_off + len(chunk), len(base))
+                if len(base) < want:
+                    base.extend(b"\x00" * (want - len(base)))
+                base[chunk_off:chunk_off + len(chunk)] = chunk
+                blob = bytes(base)
+                # splice: the shipped crc covered the delta
+                crc = shard_crc(blob)
+            self.perf.inc("splice_copied_bytes", 2 * len(blob))
+            self.perf.inc("splice_crc_bytes", len(blob))
         else:
             blob = chunk
-        # one crc per shard per write: reuse the crc the primary already
-        # computed (or the receiver already VERIFIED the frame against)
-        # instead of a third pass over the same bytes
-        crc = shard_crc(blob) if chunk_crc is None else chunk_crc
+            # one crc per shard per write: reuse the crc the primary
+            # already computed (or the receiver already VERIFIED the
+            # frame against) instead of a third pass over the same bytes
+            crc = shard_crc(blob) if chunk_crc is None else chunk_crc
         txn.write(
             (pool_id, oid, shard),
             # a non-bytes full-write blob is an encode-output (or
@@ -4759,7 +4825,9 @@ class OSD:
 
                 h.crcs[shard] = checksum(chunk, h.crcs[shard]) & 0xFFFFFFFF
             else:
-                h.crcs[shard] = shard_crc(blob)
+                with tracing.section("osd", "rmw_splice"):
+                    h.crcs[shard] = shard_crc(blob)
+                self.perf.inc("splice_crc_bytes", len(blob))
             h.total_chunk_size = len(blob)
             h.dirty = True
             self.store.setattr(key, HashInfo.XATTR_KEY, h.encode())

@@ -7,6 +7,23 @@ byte extents onto data objects; unwritten extents read as zeros (sparse).
 The object map (which blocks exist, reference object-map feature) lives in
 the header and makes sparse reads and fast remove possible without listing.
 
+An image may have a DATA POOL (reference `rbd create --data-pool`, the
+librbd data_ctx beside its md_ctx): ``RBD.create(..., data_pool=<IoCtx>)``
+records the pool in the header, and from then on the image's own pool (a
+replicated one) holds the header, the object map, the snapshots'
+bookkeeping, the clone registry, the journal and every ``rbd`` class call,
+while the ``rbd_data.<id>.<n>`` objects, and they alone, are read, written,
+written in full and removed in the data pool.  That is how a block image
+lives on an erasure-coded pool (``allow_ec_overwrites``): class calls answer
+EOPNOTSUPP there, so an image whose ONE pool is erasure-coded falls back to
+client-side whole-header rewrites, and one with a data pool does not.  Snap
+ids are allocated in the pool that holds the data objects, since that pool's
+primaries clone and trim them.  ``RBD.open`` finds the data pool through the
+header; an image without one behaves as it always has.
+
+Every image counts its writes in a perf set ``rbd`` under librbd's names
+(``wr``, ``wr_bytes``, time average ``wr_lat``; ``Image.perf``).
+
 Snapshots sit on RADOS self-managed snaps exactly as the reference's
 librbd sits on librados (IoCtxImpl selfmanaged snap ops): snap_create
 allocates a pool-unique snap id from the mon and records the object map;
@@ -43,6 +60,8 @@ import time
 import uuid
 from typing import Dict, List, Optional
 
+from ceph_tpu.common import tracing
+from ceph_tpu.common.perf_counters import PerfCounters, PerfCountersBuilder
 from ceph_tpu.rados.client import RadosError
 from ceph_tpu.rados.librados import IoCtx
 
@@ -53,11 +72,49 @@ class RbdError(Exception):
     pass
 
 
+def _build_rbd_perf() -> PerfCounters:
+    """The `rbd` set of one open image, under librbd's names (reference
+    src/librbd/internal.h l_librbd_wr, _wr_bytes, _wr_latency)."""
+    return (PerfCountersBuilder("rbd")
+            .add_u64_counter("wr", "image writes acknowledged")
+            .add_u64_counter("wr_bytes", "bytes of those writes")
+            .add_time_avg("wr_lat", "Image.write: call -> every data "
+                                    "object and the object map written")
+            .create_perf_counters())
+
+
+async def _data_ioctx(ioctx: IoCtx, header: Dict) -> IoCtx:
+    """The IoCtx an image's data objects live in: its own pool's, or
+    one on the data pool its header names (same cluster handle, same
+    namespace: reference librbd opens data_ctx from the header's
+    data_pool_id and gives it md_ctx's namespace)."""
+    pool = header.get("data_pool")
+    if not pool or pool == ioctx.pool_name:
+        return ioctx
+    try:
+        data = await ioctx._rados.open_ioctx(pool)
+    except RadosError as e:
+        raise RbdError(f"data pool {pool!r}: {e}") from None
+    data.set_namespace(ioctx.get_namespace())
+    return data
+
+
+async def _open_image(ioctx: IoCtx, name: str, header: Dict) -> "Image":
+    return Image(ioctx, name, header, await _data_ioctx(ioctx, header))
+
+
 class Image:
-    def __init__(self, ioctx: IoCtx, name: str, header: Dict):
+    def __init__(self, ioctx: IoCtx, name: str, header: Dict,
+                 data_ioctx: Optional[IoCtx] = None):
+        # `ioctx`: header, object map, snapshots' bookkeeping, class
+        # calls.  `data_ioctx`: the rbd_data.* objects and the snap ids
+        # they are cloned under; the same pool unless the header names
+        # a data pool
         self.ioctx = ioctx
+        self.data_ioctx = data_ioctx if data_ioctx is not None else ioctx
         self.name = name
         self._hdr = header
+        self.perf = _build_rbd_perf()
 
     # -- layout --------------------------------------------------------------
 
@@ -122,7 +179,7 @@ class Image:
         if not p:
             return None
         raw = await self.ioctx.read(self._header_oid(p["image"]))
-        return Image(self.ioctx, p["image"], json.loads(raw))
+        return await _open_image(self.ioctx, p["image"], json.loads(raw))
 
     async def _read_from_parent(self, idx: int,
                                 parent: Optional["Image"] = None) -> bytes:
@@ -163,7 +220,7 @@ class Image:
 
         async def fetch(idx: int):
             if idx in objmap:
-                return await self.ioctx.read(self._data_oid(idx))
+                return await self.data_ioctx.read(self._data_oid(idx))
             if layered:
                 return await self._read_from_parent(idx, parent)
             return None
@@ -179,19 +236,28 @@ class Image:
         return bytes(out)
 
     async def write(self, offset: int, data: bytes) -> None:
-        if offset + len(data) > self.size:
-            raise RbdError("write beyond image size (resize first)")
-        objmap = set(self._hdr["object_map"])
-        layered = bool(self._hdr.get("parent"))
-        snapc = self._image_snapc()  # every data write carries the context:
-        # the OSD primary clones a block before its first post-snap write
-        pos = 0
+        t0 = time.monotonic()
+        with tracing.section("client", "rbd_write"):
+            # extent mapping and object-map look-up: the image layer's
+            # own work on a write (the ops below are the objecter's)
+            if offset + len(data) > self.size:
+                raise RbdError("write beyond image size (resize first)")
+            objmap = set(self._hdr["object_map"])
+            layered = bool(self._hdr.get("parent"))
+            # every data write carries the context: the OSD primary
+            # clones a block before its first post-snap write
+            snapc = self._image_snapc()
+            extents = []  # (object, offset in it, offset in data, bytes)
+            pos = 0
+            while pos < len(data):
+                lofs = offset + pos
+                off_in = lofs % self.object_size
+                n = min(self.object_size - off_in, len(data) - pos)
+                extents.append((lofs // self.object_size, off_in, pos, n))
+                pos += n
+        io = self.data_ioctx
         dirty_map = False
-        while pos < len(data):
-            lofs = offset + pos
-            idx = lofs // self.object_size
-            off_in = lofs % self.object_size
-            n = min(self.object_size - off_in, len(data) - pos)
+        for idx, off_in, pos, n in extents:
             piece = data[pos:pos + n]
             if (layered and idx not in objmap
                     and (off_in or n < self.object_size)):
@@ -201,28 +267,30 @@ class Image:
                 # in the child first, then overwrite part of it
                 base = await self._read_from_parent(idx)
                 if base:
-                    await self.ioctx.write_full(self._data_oid(idx), base,
-                                                snapc=snapc)
+                    await io.write_full(self._data_oid(idx), base,
+                                        snapc=snapc)
                     objmap.add(idx)
                     dirty_map = True
             if idx in objmap and (off_in or n < self.object_size):
                 # partial overwrite rides the OSD's RMW path
-                await self.ioctx.write(self._data_oid(idx), piece,
-                                       offset=off_in, snapc=snapc)
+                await io.write(self._data_oid(idx), piece,
+                               offset=off_in, snapc=snapc)
             elif off_in or n < self.object_size:
                 # sparse partial write into a fresh object: pad the head
-                await self.ioctx.write_full(self._data_oid(idx),
-                                            b"\x00" * off_in + piece,
-                                            snapc=snapc)
+                await io.write_full(self._data_oid(idx),
+                                    b"\x00" * off_in + piece,
+                                    snapc=snapc)
             else:
-                await self.ioctx.write_full(self._data_oid(idx), piece,
-                                            snapc=snapc)
+                await io.write_full(self._data_oid(idx), piece,
+                                    snapc=snapc)
             if idx not in objmap:
                 objmap.add(idx)
                 dirty_map = True
-            pos += n
         if dirty_map:
             await self._merge_object_map(objmap)
+        self.perf.inc("wr")
+        self.perf.inc("wr_bytes", len(data))
+        self.perf.tinc("wr_lat", time.monotonic() - t0)
 
     async def _merge_object_map(self, objmap) -> None:
         """Record newly-materialized blocks.  In-OSD merge (cls_rbd
@@ -254,8 +322,8 @@ class Image:
                     try:
                         # under a snap context the OSD clones first and
                         # whiteouts, so snapshots keep their blocks
-                        await self.ioctx.remove(self._data_oid(idx),
-                                                snapc=snapc)
+                        await self.data_ioctx.remove(self._data_oid(idx),
+                                                     snapc=snapc)
                     except RadosError:
                         pass
                     objmap.discard(idx)
@@ -266,9 +334,9 @@ class Image:
             bidx = new_size // self.object_size
             if tail and bidx in objmap:
                 try:
-                    blob = await self.ioctx.read(self._data_oid(bidx))
-                    await self.ioctx.write_full(self._data_oid(bidx),
-                                                blob[:tail], snapc=snapc)
+                    blob = await self.data_ioctx.read(self._data_oid(bidx))
+                    await self.data_ioctx.write_full(
+                        self._data_oid(bidx), blob[:tail], snapc=snapc)
                 except RadosError:
                     pass
             self._hdr["object_map"] = sorted(objmap)
@@ -326,7 +394,8 @@ class Image:
         object-map merges."""
         if name in self._snaps():
             raise RbdError(f"snapshot {name!r} exists")
-        snap_id = await self.ioctx.allocate_snap_id()
+        # in the pool whose primaries clone and trim the data objects
+        snap_id = await self.data_ioctx.allocate_snap_id()
         got = await self._hdr_cls("snap_create",
                                   {"name": name, "snap_id": snap_id})
         if got is not None:
@@ -334,7 +403,7 @@ class Image:
             if ret != 0:
                 # ANY failure releases the freshly-allocated id — a
                 # leaked id keeps its clones untrimmable forever
-                await self.ioctx.release_snap_id(snap_id)
+                await self.data_ioctx.release_snap_id(snap_id)
                 if ret == -17:
                     raise RbdError(f"snapshot {name!r} exists")
                 raise RbdError(f"snap_create failed ({ret})")
@@ -382,8 +451,8 @@ class Image:
                     return await self._read_from_parent(idx, parent)
                 return None
             try:
-                return await self.ioctx.read(self._data_oid(idx),
-                                             snap=snap["id"])
+                return await self.data_ioctx.read(self._data_oid(idx),
+                                                  snap=snap["id"])
             except RadosError as e:
                 if e.code != -errno.ENOENT:
                     raise
@@ -455,7 +524,7 @@ class Image:
                 continue
             blob = await self._read_from_parent(idx, parent)
             if blob and blob.strip(b"\x00"):
-                await self.ioctx.write_full(self._data_oid(idx), blob)
+                await self.data_ioctx.write_full(self._data_oid(idx), blob)
                 objmap.add(idx)
         self._hdr["object_map"] = sorted(objmap)
         parent_ref = f"{p['image']}@{p['snap']}"
@@ -471,7 +540,7 @@ class Image:
         Returns the number of blocks recovered into the map."""
         prefix = f"rbd_data.{self._hdr['id']}."
         found = set()
-        for oid in await self.ioctx.list_objects():
+        for oid in await self.data_ioctx.list_objects():
             if not oid.startswith(prefix):
                 continue
             try:
@@ -516,9 +585,9 @@ class Image:
                 raise RbdError(f"snap_remove failed ({ret})")
             if ret == 0:
                 self._hdr = json.loads(out)
-            await self.ioctx.release_snap_id(snap["id"])
+            await self.data_ioctx.release_snap_id(snap["id"])
             return
-        await self.ioctx.release_snap_id(snap["id"])
+        await self.data_ioctx.release_snap_id(snap["id"])
         snaps.pop(name, None)
         await self._save_header()
 
@@ -529,11 +598,35 @@ class RBD:
     def __init__(self, ioctx: IoCtx):
         self.ioctx = ioctx
 
+    @staticmethod
+    def _with_data_pool(header: Dict, ioctx: IoCtx,
+                        data_pool: Optional[IoCtx]) -> Optional[IoCtx]:
+        """Record `data_pool` in a new image's header (by name, as `rbd
+        info` shows it); the IoCtx its data objects take, in the header
+        pool's namespace, or None for an image in one pool."""
+        if data_pool is None or data_pool.pool_id == ioctx.pool_id:
+            return None
+        header["data_pool"] = data_pool.pool_name
+        if data_pool.get_namespace() == ioctx.get_namespace():
+            return data_pool
+        data = IoCtx(data_pool._rados, data_pool.pool_id,
+                     data_pool.pool_name)
+        data.set_namespace(ioctx.get_namespace())
+        return data
+
     async def create(self, name: str, size: int,
-                     order: int = DEFAULT_ORDER) -> Image:
+                     order: int = DEFAULT_ORDER,
+                     data_pool: Optional[IoCtx] = None,
+                     image_id: Optional[str] = None) -> Image:
+        """``data_pool``: an IoCtx of the pool the image's data objects
+        go to (`rbd create --data-pool`); this RBD's own pool keeps the
+        header and every class call.  ``image_id`` names the data
+        objects (`rbd_data.<id>.<n>`) and so places them; left out, it
+        is drawn at random, as librbd draws it."""
         hdr_oid = Image._header_oid(name)
-        header = {"id": uuid.uuid4().hex[:12], "size": size, "order": order,
-                  "object_map": []}
+        header = {"id": image_id or uuid.uuid4().hex[:12], "size": size,
+                  "order": order, "object_map": []}
+        data = self._with_data_pool(header, self.ioctx, data_pool)
         # single in-OSD call (cls_rbd create role): exclusive creation —
         # two racing create()s cannot both win the check-then-write
         try:
@@ -544,7 +637,7 @@ class RBD:
                 raise RbdError(f"image {name!r} exists")
             if ret != 0:
                 raise RbdError(f"create failed ({ret})")
-            return Image(self.ioctx, name, header)
+            return Image(self.ioctx, name, header, data)
         except RadosError as e:
             if e.code != -errno.EOPNOTSUPP:
                 raise
@@ -558,7 +651,7 @@ class RBD:
             if e.code != -errno.ENOENT:
                 raise
         await self.ioctx.write_full(hdr_oid, json.dumps(header).encode())
-        return Image(self.ioctx, name, header)
+        return Image(self.ioctx, name, header, data)
 
     async def open(self, name: str) -> Image:
         try:
@@ -570,7 +663,7 @@ class RBD:
             if e.code == -errno.ENOENT:
                 raise RbdError(f"image {name!r} does not exist")
             raise
-        return Image(self.ioctx, name, json.loads(raw))
+        return await _open_image(self.ioctx, name, json.loads(raw))
 
     CHILDREN_OID = "rbd_children"  # pool-level clone registry (cls_rbd role)
 
@@ -603,10 +696,12 @@ class RBD:
         return sorted((await self._children_map()).get(f"{image}@{snap}", []))
 
     async def clone(self, parent: str, snap: str, child: str,
-                    order: Optional[int] = None) -> Image:
+                    order: Optional[int] = None,
+                    data_pool: Optional[IoCtx] = None) -> Image:
         """Create a copy-on-write child of a protected parent snapshot
         (reference librbd clone v2).  The child starts with no objects of
-        its own: reads fall through to the parent snap, writes copy-up."""
+        its own: reads fall through to the parent snap, writes copy-up.
+        ``data_pool`` is the CHILD's (a parent's is its own affair)."""
         pimg = await self.open(parent)
         psnap = pimg._snaps().get(snap)
         if psnap is None:
@@ -626,9 +721,10 @@ class RBD:
             "object_map": [],
             "parent": {"image": parent, "snap": snap, "size": psnap["size"]},
         }
+        data = self._with_data_pool(header, self.ioctx, data_pool)
         await self.ioctx.write_full(hdr_oid, json.dumps(header).encode())
         await self._register_child(f"{parent}@{snap}", child)
-        return Image(self.ioctx, child, header)
+        return Image(self.ioctx, child, header, data)
 
     # -- consistency groups (reference src/librbd/api/Group.cc) -------------
     #
@@ -774,7 +870,7 @@ class RBD:
             raise RbdError(f"image {name!r} has snapshots; purge them first")
         for idx in img._hdr["object_map"]:
             try:
-                await self.ioctx.remove(img._data_oid(idx))
+                await img.data_ioctx.remove(img._data_oid(idx))
             except RadosError:
                 pass
         p = img._hdr.get("parent")
@@ -861,7 +957,7 @@ class RBD:
         if p:
             await self._register_child(f"{p['image']}@{p['snap']}", name)
         await self.ioctx.remove(self._trash_oid(image_id))
-        return Image(self.ioctx, name, rec["header"])
+        return await _open_image(self.ioctx, name, rec["header"])
 
     async def trash_purge(self, now: Optional[float] = None,
                           force: bool = False) -> int:
@@ -874,10 +970,10 @@ class RBD:
                 continue
             rec = await self._trash_rec(entry["id"])
             hdr = rec["header"]
-            img = Image(self.ioctx, rec["name"], hdr)
+            img = await _open_image(self.ioctx, rec["name"], hdr)
             for idx in hdr["object_map"]:
                 try:
-                    await self.ioctx.remove(img._data_oid(idx))
+                    await img.data_ioctx.remove(img._data_oid(idx))
                 except RadosError:
                     pass
             await self.ioctx.remove(self._trash_oid(entry["id"]))
@@ -1172,7 +1268,7 @@ class ImageMigrator:
         snapc = dst._image_snapc()
         for idx in extra:
             try:
-                await dst.ioctx.remove(dst._data_oid(idx), snapc=snapc)
+                await dst.data_ioctx.remove(dst._data_oid(idx), snapc=snapc)
             except RadosError:
                 pass
         dst._hdr["object_map"] = sorted(

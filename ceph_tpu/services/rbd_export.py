@@ -173,8 +173,8 @@ async def apply_diff(img: Image, inp: BinaryIO) -> dict:
                     partial.append((max(off, b_start), min(end, b_end)))
             for i in drop:
                 try:
-                    await img.ioctx.remove(img._data_oid(i),
-                                           snapc=img._image_snapc())
+                    await img.data_ioctx.remove(img._data_oid(i),
+                                                snapc=img._image_snapc())
                 except Exception:
                     pass
             if drop:
