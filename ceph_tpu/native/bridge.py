@@ -160,6 +160,8 @@ def _configure(_lib: ctypes.CDLL) -> None:
     _lib.ceph_tpu_crc32c.restype = ctypes.c_uint32
     _lib.ceph_tpu_crc32c.argtypes = [
         ctypes.c_uint32, ctypes.c_char_p, ctypes.c_size_t]
+    _lib.ceph_tpu_crc32c_shift.restype = ctypes.c_uint32
+    _lib.ceph_tpu_crc32c_shift.argtypes = [ctypes.c_uint32, ctypes.c_size_t]
     _lib.ceph_tpu_crc32c_kind.restype = ctypes.c_char_p
     _lib.ceph_tpu_crc32c_kind.argtypes = []
     _lib.ceph_tpu_rs_decode.restype = ctypes.c_int
@@ -320,6 +322,12 @@ def crc32c(data, seed: int = 0) -> int:
         # row/element count and would silently checksum a prefix
         n = memoryview(data).nbytes
     return lib().ceph_tpu_crc32c(seed, _buf_arg(data), n)
+
+
+def crc32c_shift(state: int, n: int) -> int:
+    """The raw crc32c register `state` advanced over `n` zero bytes
+    (native/crc32c.cc ZerosOp; linear, no inversion on either side)."""
+    return lib().ceph_tpu_crc32c_shift(state & 0xFFFFFFFF, n)
 
 
 def crc32c_kind() -> str:

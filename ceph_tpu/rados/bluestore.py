@@ -278,6 +278,7 @@ class BlueStore(ObjectStore):
         with register_on_commit semantics)."""
         prefer_deferred = int(self.conf.get("bluestore_prefer_deferred_size",
                                             32768) or 0)
+        self._ranged_as_whole(txn)
         # failsafe BEFORE any mutation (KV batch, allocator, block file):
         # a refused transaction leaves the store byte-identical.  The
         # common no-ceiling config skips both sums (the free-list walk

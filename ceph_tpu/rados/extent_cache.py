@@ -20,6 +20,15 @@ pays for no reader that may never come.  So a cached run is a BUFFER —
 `bytes`, or a read-only `memoryview` of a payload as the wire delivered
 it — and a reader that needs `bytes` semantics (concatenation, hashing)
 normalises where it reads.
+
+A write at an offset into a cached whole object costs its stripes
+(`patch_full`): the entry's runs are split around them (views of the old
+runs on both sides, the new stripes between) and nothing of the rest is
+copied, the first time or ever.  Every run stays immutable.  `get_full`
+still returns the whole current object in one buffer: it joins a split
+entry when somebody asks (one whole-object copy, kept as the single run
+again), and the offset-write path, which needs the stripes only, asks
+`get_whole` instead.
 """
 
 from __future__ import annotations
@@ -84,22 +93,29 @@ class _Entry:
         self.extents = merged
 
     def read(self, start: int, length: int) -> Optional[bytes]:
-        """The bytes of [start, start+length) iff FULLY covered."""
+        """The bytes of [start, start+length) iff FULLY covered, by one
+        run or by runs that touch."""
         end = start + length
         if self.full and start >= self.size:
             return b""  # past EOF on a fully-known object reads as empty
+        parts = []
+        pos = start
         for s, b in self.extents:
             e = s + len(b)
-            if s <= start < e:
-                if end <= e:
-                    return b[start - s: end - s]
-                if self.full and e == self.size:
-                    # short tail of a fully-known object: zero-extend is
-                    # NOT valid for RMW reads (stripes past EOF are
-                    # synthesized by the caller) — return what exists
-                    return b[start - s:]
-                return None
-        return None
+            if e <= pos:
+                continue
+            if s > pos:
+                break  # a hole
+            parts.append(b[pos - s: min(end, e) - s])
+            pos = min(end, e)
+            if pos == end:
+                break
+        if pos < end and not (parts and self.full and pos == self.size):
+            # (short tail of a fully-known object: zero-extend is NOT
+            # valid for RMW reads — stripes past EOF are synthesized by
+            # the caller — so what exists is returned)
+            return None
+        return parts[0] if len(parts) == 1 else b"".join(parts)
 
 
 class ExtentCache:
@@ -159,12 +175,65 @@ class ExtentCache:
         elif size_hint:
             ent.size = max(ent.size, size_hint)
 
+    def patch_full(self, key: Key, base_version: int, version: int,
+                   start: int, data: bytes) -> bool:
+        """Bring a cached WHOLE object from `base_version` to `version`
+        by laying `data` over [start, start + len(data)): valid only
+        when the caller knows the version step changed nothing else (the
+        primary's own offset write, serialized per PG).  The runs are
+        split around the extent, nothing is copied; `data` is kept as it
+        is (the caller writes into it no more).  False where there is no
+        such entry (evicted, moved on, not whole): nothing was done."""
+        ent = self._entries.get(key)
+        if ent is None or not ent.full or ent.version != base_version \
+                or version <= base_version:
+            return False
+        end = start + len(data)
+        runs: List[Tuple[int, bytes]] = []
+        for s, b in ent.extents:
+            e = s + len(b)
+            if e <= start or s >= end:
+                runs.append((s, b))
+                continue
+            flat = memoryview(b)
+            if s < start:
+                runs.append((s, flat[:start - s]))
+            if e > end:
+                runs.append((end, flat[end - s:]))
+        if start > ent.size:
+            runs.append((ent.size, bytes(start - ent.size)))
+        runs.append((start, data))
+        runs.sort(key=lambda run: run[0])
+        ent.extents = runs
+        ent.size = max(ent.size, end)
+        ent.version = version
+        self._entries.move_to_end(key)
+        return True
+
     def get_full(self, key: Key) -> Optional[Tuple[int, bytes]]:
         ent = self._entries.get(key)
         if ent is None or not ent.full:
             return None
         self._entries.move_to_end(key)
+        if len(ent.extents) > 1:
+            # split by offset writes: joined for the first reader who
+            # wants it whole, and kept so
+            ent.extents = [(0, b"".join(run for _s, run in ent.extents))]
         return ent.version, ent.extents[0][1] if ent.extents else b""
+
+    def get_whole(self, key: Key, start: int,
+                  length: int) -> Optional[Tuple[int, bytes, int]]:
+        """(version, bytes, object size) of [start, start+length), cut
+        short at the object's end, iff the WHOLE object is cached: what
+        an offset write needs of it, at the cost of the range."""
+        ent = self._entries.get(key)
+        if ent is None or not ent.full:
+            return None
+        got = ent.read(start, length)
+        if got is None:
+            return None
+        self._entries.move_to_end(key)
+        return ent.version, got, ent.size
 
     def get_range(self, key: Key, start: int,
                   length: int) -> Optional[Tuple[int, bytes, int]]:

@@ -55,7 +55,8 @@ from ceph_tpu.ec.interface import ErasureCodeError
 from ceph_tpu.ec.registry import registry
 from ceph_tpu.rados.crush import CRUSH_ITEM_NONE, CRUSH_PERF
 from ceph_tpu.rados.extent_cache import ExtentCache
-from ceph_tpu.utils.checksum import verify_any as crc_verify_any
+from ceph_tpu.utils.checksum import (checksum, spliced as checksum_spliced,
+                                     verify_any as crc_verify_any)
 from ceph_tpu.rados.ecutil import (ECPLAN_PERF, HashInfo, StripeInfo,
                                    batched_encode_async,
                                    batched_encode_group_async,
@@ -97,7 +98,8 @@ from ceph_tpu.rados.scheduler import (
 )
 from ceph_tpu.rados.store import (ENOSPCError, MemStore, ObjectStore,
                                   ShardMeta, Transaction, shard_crc,
-                                  Owned as StoreOwned)
+                                  Owned as StoreOwned, live as store_live,
+                                  unwrap as store_unwrap)
 from ceph_tpu.rados.tiering import (HitSetArchive, PromoteThrottle,
                                     build_tier_perf, eviction_candidates)
 from ceph_tpu.rados.auth import TicketKeyring
@@ -349,6 +351,16 @@ class OSD:
             .add_u64_counter("splice_crc_bytes",
                              "bytes checksummed by shards after a splice "
                              "(blob crc and hinfo entry)")
+            .add_u64_counter("splice_in_place",
+                             "splices applied as a write at an offset in "
+                             "the store, the shard's crc made from the "
+                             "bytes that changed")
+            .add_u64_counter("splice_rebuilt",
+                             "splices that cost a pass over the whole "
+                             "shard: the store copied it (first splice of "
+                             "a shard stored as it arrived, a reader's "
+                             "view out, no write at an offset) or the crc "
+                             "was made over all of it")
             .add_u64_counter("splice_refused",
                              "splices refused: the stored shard was not at "
                              "the version the primary read")
@@ -3161,6 +3173,7 @@ class OSD:
             chunk_off = -1
             shard_size = 0
             base_version = 0
+            patch = False  # arm (a) of an offset write, below
             object_size = len(op.data)
             # what a LATER partial overwrite splices against: the payload
             # as it was received, kept by reference — the put path copies
@@ -3207,20 +3220,25 @@ class OSD:
             t_base = time.monotonic()
             copied = 0
             with tracing.section("osd", "rmw_base"):
-                cached = self._cache_get(op.pool_id, op.oid)
-                if cached is not None:
-                    # (a) the whole object is cached
+                whole = self._extent_cache.get_whole(
+                    (op.pool_id, op.oid), s0, slen)
+                patch = whole is not None
+                if patch:
+                    # (a) the whole object is cached: its stripes are cut
+                    # out of it and the write laid in; after the commit
+                    # the cached object gets the new stripes in place of
+                    # the old (patch_full below), and nothing else of it
+                    # is touched
                     self.perf.inc("rmw_base_cached")
-                    base_version, cached_data = cached
-                    base = bytearray(cached_data)
-                    if len(base) < op.offset:
-                        base.extend(b"\x00" * (op.offset - len(base)))
-                    base[op.offset:op.offset + len(op.data)] = op.data
-                    full = bytes(base)
-                    object_size = len(full)
-                    seg = full[s0:s0 + slen]
-                    copied = 2 * len(full) + len(seg)
-                    full_for_cache = full
+                    base_version, piece, old_size = whole
+                    seg_buf = bytearray(piece)
+                    seg_buf.extend(bytes(slen - len(seg_buf)))
+                    lo = op.offset - s0
+                    seg_buf[lo:lo + len(op.data)] = op.data
+                    seg = bytes(seg_buf)
+                    copied = 2 * slen
+                    object_size = max(old_size, op.offset + len(op.data))
+                    full_for_cache = None  # only the segment is in hand
                     ranged = None
                 else:
                     # extent-granular hit (reference ExtentCache pinning):
@@ -3228,7 +3246,7 @@ class OSD:
                     # stripes here — no shard reads at all
                     ranged = self._extent_cache.get_range(
                         (op.pool_id, op.oid), s0, slen)
-            if cached is None:
+            if not patch:
                 got = None
                 if ranged is not None and ranged[2] > 0 \
                         and len(ranged[1]) == slen:
@@ -3484,13 +3502,23 @@ class OSD:
                               else "write_copied_bytes",
                               len(full_for_cache))
             elif chunk_off >= 0:
-                # segment RMW: pin the freshly-written stripes at the NEW
-                # version; carry_from upgrades the entry in place (nothing
-                # outside this extent changed — our write made the version)
-                self._extent_cache.put_extent(
-                    (op.pool_id, op.oid), version,
-                    sinfo.aligned_chunk_offset_to_logical_offset(chunk_off),
-                    data, size_hint=object_size, carry_from=base_version)
+                # arm (a): the cached whole object moves to the NEW
+                # version by the stripes just written, as far as the
+                # object reaches (nothing outside them changed — our
+                # write made the version); no copy: `data` is the
+                # segment's own bytes
+                if not (patch and self._extent_cache.patch_full(
+                        (op.pool_id, op.oid), base_version, version, s0,
+                        data[:object_size - s0])):
+                    # segment RMW (or the whole object left the cache
+                    # meanwhile): pin the freshly-written stripes at the
+                    # NEW version; carry_from upgrades the entry in place
+                    self._extent_cache.put_extent(
+                        (op.pool_id, op.oid), version,
+                        sinfo.aligned_chunk_offset_to_logical_offset(
+                            chunk_off),
+                        data, size_hint=object_size,
+                        carry_from=base_version)
             else:
                 self._cache_drop(op.pool_id, op.oid)
             return MOSDOpReply(ok=True)
@@ -4719,6 +4747,10 @@ class OSD:
             raise ENOSPCError(
                 f"osd.{self.osd_id} failsafe full: refusing "
                 f"{len(chunk)}-byte shard write")
+        if chunk_off >= 0:
+            return self._apply_shard_splice(
+                (pool_id, oid, shard), chunk, version, object_size, pg,
+                entry, chunk_off, shard_size, prior_version)
         txn = Transaction()
         # retain the outgoing version in the rollback slot (same txn):
         # reads fall back to it when a newer write never completed
@@ -4729,46 +4761,20 @@ class OSD:
             txn.write((pool_id, oid, shard + PREV_SLOT),
                       old[0] if isinstance(old[0], bytes)
                       else StoreOwned(old[0]), old[1])
-        appended = False
-        if chunk_off >= 0:
-            # splice precondition: the delta only composes with the exact
-            # base the primary read.  A shard that missed an intermediate
-            # write (or lost the object) must refuse — splicing into a
-            # stale blob would stamp corrupt bytes as newest with a
-            # self-consistent crc.  Refusal costs one ack; recovery
-            # re-pushes the full blob.
-            if old is None or old[1].version != prior_version:
-                self.perf.inc("splice_refused")
-                return False
-            # splice the chunk range into the stored blob (per-stripe RMW);
-            # zero-extension to shard_size covers gap stripes — zero chunks
-            # ARE the parity of zero stripes for these linear codes
-            with tracing.section("osd", "rmw_splice"):
-                base = bytearray(old[0])
-                appended = chunk_off == len(base)
-                want = max(shard_size, chunk_off + len(chunk), len(base))
-                if len(base) < want:
-                    base.extend(b"\x00" * (want - len(base)))
-                base[chunk_off:chunk_off + len(chunk)] = chunk
-                blob = bytes(base)
-                # splice: the shipped crc covered the delta
-                crc = shard_crc(blob)
-            self.perf.inc("splice_copied_bytes", 2 * len(blob))
-            self.perf.inc("splice_crc_bytes", len(blob))
-        else:
-            blob = chunk
-            # one crc per shard per write: reuse the crc the primary
-            # already computed (or the receiver already VERIFIED the
-            # frame against) instead of a third pass over the same bytes
-            crc = shard_crc(blob) if chunk_crc is None else chunk_crc
+        blob = chunk
+        # one crc per shard per write: reuse the crc the primary
+        # already computed (or the receiver already VERIFIED the
+        # frame against) instead of a third pass over the same bytes
+        crc = shard_crc(blob) if chunk_crc is None else chunk_crc
         txn.write(
             (pool_id, oid, shard),
             # a non-bytes full-write blob is an encode-output (or
             # fetched-shard) buffer whose ownership transfers to the
             # store here: mark it Owned so the RAM store keeps the view
-            # instead of a 16 MiB defensive copy per shard (stored
-            # buffers are never mutated in place — overwrites replace
-            # entries)
+            # instead of a 16 MiB defensive copy per shard (a buffer the
+            # store was handed is never written in place: overwrites
+            # replace entries, and a write at an offset copies it first,
+            # MemStore._write_at)
             blob if isinstance(blob, bytes) else StoreOwned(blob),
             ShardMeta(version=version, object_size=object_size,
                       chunk_crc=crc),
@@ -4777,58 +4783,123 @@ class OSD:
             self._log_in_txn(txn, pool_id, pg, entry)
         with tracing.section("store", "commit"):
             self.store.queue_transaction(txn)
-            self._update_hinfo(pool_id, oid, shard, blob, chunk, hinfo,
-                               chunk_off, appended)
+            self._update_hinfo(pool_id, oid, shard, len(blob), crc, hinfo,
+                               chunk_off)
         return True
 
-    def _update_hinfo(self, pool_id: int, oid: str, shard: int, blob: bytes,
-                      chunk: bytes, hinfo: bytes, chunk_off: int,
-                      appended: bool) -> None:
+    def _apply_shard_splice(self, key, chunk, version: int,
+                            object_size: int, pg: Optional[int],
+                            entry: Optional[LogEntry], chunk_off: int,
+                            shard_size: int, prior_version: int) -> bool:
+        """One stripe's chunk into the stored shard at `chunk_off` (the
+        per-stripe RMW): a write at an offset in the store, the shard's
+        crc made from the crc it had and the bytes that changed, the
+        outgoing version left to the store to keep at the rollback slot.
+        Costs the extent, not the shard, where the store writes in place
+        (`splice_in_place`); `splice_rebuilt` counts the ones that cost
+        a pass over the whole shard: the store copied it (`txn.copied`:
+        the first splice of a shard stored as it arrived, a reader's view
+        out, a store with no write at an offset) or the crc had to be
+        made over all of it (no native shift; stored crcs of another
+        build's kind)."""
+        # splice precondition: the delta only composes with the exact
+        # base the primary read.  A shard that missed an intermediate
+        # write (or lost the object) must refuse — splicing into a
+        # stale blob would stamp corrupt bytes as newest with a
+        # self-consistent crc.  Refusal costs one ack; recovery
+        # re-pushes the full blob.
+        try:
+            have = self.store.stat(key)
+        except IOError:
+            have = None
+        if have is None or have[1].version != prior_version:
+            self.perf.inc("splice_refused")
+            return False
+        with tracing.section("osd", "rmw_splice"):
+            size, was_meta = have
+            # zero-extension to shard_size covers gap stripes — zero
+            # chunks ARE the parity of zero stripes for these linear codes
+            new_size = max(shard_size, chunk_off + len(chunk), size)
+            crc = None
+            if isinstance(self.store, MemStore):
+                # its crcs were made by THIS process's resolver (the
+                # sub-read reply's rule): the old one composes
+                was = self.store.read_range(key, chunk_off, len(chunk))
+                crc = checksum_spliced(was_meta.chunk_crc, size, new_size,
+                                       chunk_off, was, chunk)
+                crc_bytes = len(was) + len(chunk)
+            delta = crc is not None
+            if not delta:
+                crc = self._splice_crc_whole(key, size, new_size, chunk_off,
+                                             chunk)
+                crc_bytes = new_size
+        txn = Transaction()
+        txn.write_at(
+            key, chunk_off, chunk, new_size,
+            ShardMeta(version=version, object_size=object_size,
+                      chunk_crc=crc),
+            # the outgoing version to the rollback slot (same txn): reads
+            # fall back to it when a newer write never completed
+            prev=((key[0], key[1], key[2] + PREV_SLOT)
+                  if was_meta.version != version else None))
+        if entry is not None and pg is not None:
+            self._log_in_txn(txn, key[0], pg, entry)
+        with tracing.section("store", "commit"):
+            self.store.queue_transaction(txn)
+            self._update_hinfo(*key, new_size, crc, b"", chunk_off)
+        self.perf.inc("splice_in_place" if delta and not txn.copied
+                      else "splice_rebuilt")
+        # the extent out (here for the crc, in the store for the slot)
+        # and in, and whatever whole shards the store had to copy
+        self.perf.inc("splice_copied_bytes", txn.copied + 3 * len(chunk))
+        self.perf.inc("splice_crc_bytes", crc_bytes)
+        return True
+
+    def _splice_crc_whole(self, key, size: int, new_size: int, off: int,
+                          chunk) -> int:
+        """The spliced shard's crc by a pass over all of it, chained
+        through the stored bytes around the extent (no copy of them)."""
+        old = memoryview(store_unwrap(self.store.read(key)[0]))
+        end = off + len(chunk)
+        crc = checksum(old[:min(off, size)])
+        if off > size:
+            crc = checksum(bytes(off - size), crc)
+        crc = checksum(chunk, crc)
+        if end < size:
+            crc = checksum(old[end:], crc)
+        if new_size > max(end, size):
+            crc = checksum(bytes(new_size - max(end, size)), crc)
+        return crc & 0xFFFFFFFF
+
+    def _update_hinfo(self, pool_id: int, oid: str, shard: int, size: int,
+                      crc: int, hinfo: bytes, chunk_off: int) -> None:
         """Maintain the hinfo_key xattr (cumulative shard crcs, reference
         ECUtil.h:101-160): full writes store the primary-computed record;
-        splices refresh our OWN entry — by crc32 chaining when the splice
-        is a pure append (no re-read of prior bytes), by recompute
-        otherwise — and mark the record dirty (other entries went stale)."""
+        splices refresh our OWN entry with the crc the shard's meta just
+        got (`size` bytes, `crc`: never a second pass over them) and mark
+        the record dirty (other entries went stale)."""
         pool = self.osdmap.pools.get(pool_id) if self.osdmap else None
         if pool is not None and pool.pool_type != "ec":
             return  # replicated pools carry no hinfo; skip the xattr I/O
         key = (pool_id, oid, shard)
         try:
-            if chunk_off < 0:
-                if hinfo:
-                    self.store.setattr(key, HashInfo.XATTR_KEY, hinfo)
-                else:
-                    # full-blob write without a primary-computed record
-                    # (e.g. a sub-chunk recovery push whose helper record
-                    # was dirty): an existing record is now stale for this
-                    # shard — refresh our own entry and mark it dirty so
-                    # scrub trusts the self crc and skips the cross-shard
-                    # comparison, instead of flagging fresh data as bad
-                    raw0 = self.store.getattr(key, HashInfo.XATTR_KEY)
-                    if raw0 is not None:
-                        h0 = HashInfo.decode(raw0)
-                        if shard < len(h0.crcs):
-                            h0.crcs[shard] = shard_crc(blob)
-                            h0.total_chunk_size = len(blob)
-                            h0.dirty = True
-                            self.store.setattr(key, HashInfo.XATTR_KEY,
-                                               h0.encode())
+            if chunk_off < 0 and hinfo:
+                self.store.setattr(key, HashInfo.XATTR_KEY, hinfo)
                 return
+            # a splice, or a full-blob write without a primary-computed
+            # record (e.g. a sub-chunk recovery push whose helper record
+            # was dirty): an existing record is now stale for this
+            # shard — refresh our own entry and mark it dirty so scrub
+            # trusts the self crc and skips the cross-shard comparison,
+            # instead of flagging fresh data as bad
             raw = self.store.getattr(key, HashInfo.XATTR_KEY)
             if raw is None:
                 return
             h = HashInfo.decode(raw)
             if shard >= len(h.crcs):
                 return
-            if appended and h.total_chunk_size == chunk_off:
-                from ceph_tpu.utils.checksum import checksum
-
-                h.crcs[shard] = checksum(chunk, h.crcs[shard]) & 0xFFFFFFFF
-            else:
-                with tracing.section("osd", "rmw_splice"):
-                    h.crcs[shard] = shard_crc(blob)
-                self.perf.inc("splice_crc_bytes", len(blob))
-            h.total_chunk_size = len(blob)
+            h.crcs[shard] = crc
+            h.total_chunk_size = size
             h.dirty = True
             self.store.setattr(key, HashInfo.XATTR_KEY, h.encode())
         except NotImplementedError:
@@ -4981,46 +5052,59 @@ class OSD:
             _snap = _ps.peek_dirty(self._planar_key(msg.pool_id, msg.oid))
             if _snap is not None and isinstance(_snap[0], CacheDirtyRecord):
                 got = await self._raw_subread_fence(msg, _snap[0], got)
-        got = self._dirty_subread_fence(msg, got)
-        if got is None:
-            reply = MECSubReadReply(tid=msg.tid, shard=msg.shard, ok=False)
-        else:
-            chunk, meta = got
-            stored_crc = 0
-            if msg.extents:
-                # fragmented read: only the requested blob ranges cross
-                # the wire, as a BufferList of extent VIEWS — no join
-                # copy (stripe-RMW + sub-chunk recovery, ECMsgTypes.h:105)
-                payload = BufferList(
-                    [memoryview(chunk)[o:o + l] for o, l in msg.extents])
-            else:
-                payload = chunk
-                # whole-blob reply: the stored meta crc IS the crc of
-                # these bytes — the messenger reuses it as the frame's
-                # blob crc (BLOB_CRC_ATTR), skipping the checksum pass.
-                # MemStore only: its contents were written by THIS
-                # process, so the crc kind is the current resolver's; a
-                # persistent store may hold crcs from another build/kind
-                # (the crc_verify_any discipline), and shipping one as
-                # the wire crc would fail every frame at the receiver
-                if isinstance(self.store, MemStore):
-                    stored_crc = meta.chunk_crc
-            hraw = None
-            if getattr(msg, "want_hinfo", False):
-                try:
-                    hraw = self.store.getattr(
-                        (msg.pool_id, msg.oid, msg.shard), HashInfo.XATTR_KEY)
-                except NotImplementedError:
-                    pass
-            reply = MECSubReadReply(
-                tid=msg.tid, shard=msg.shard, ok=True, chunk=payload,
-                version=meta.version, object_size=meta.object_size,
-                hinfo=hraw or b"", chunk_crc=stored_crc,
-            )
+        # (the reply is built in a call of its own so that no local of
+        # this coroutine holds the stored buffer across the send: a view
+        # held costs the shard's next splice a whole copy)
+        reply = self._sub_read_reply(msg, self._dirty_subread_fence(msg, got))
+        del got
         try:
             await self.messenger.send(tuple(msg.reply_to), reply)
         except TRANSPORT_ERRORS:
             pass
+
+    def _sub_read_reply(self, msg: MECSubRead, got) -> MECSubReadReply:
+        if got is None:
+            return MECSubReadReply(tid=msg.tid, shard=msg.shard, ok=False)
+        chunk, meta = got
+        stored_crc = 0
+        if msg.extents:
+            # fragmented read: only the requested blob ranges cross
+            # the wire, as a BufferList of extent VIEWS — no join
+            # copy (stripe-RMW + sub-chunk recovery, ECMsgTypes.h:105).
+            # Of a shard the store may write in place, COPIES: the
+            # reply sits in the outbox and the replay queue until it is
+            # acked, the splice this read was made for comes before
+            # that, and it would find the views out and copy the whole
+            # shard (MemStore._write_at); the extents are the smaller
+            flat = memoryview(chunk)
+            live = store_live(chunk)
+            payload = BufferList(
+                [bytes(flat[o:o + l]) if live else flat[o:o + l]
+                 for o, l in msg.extents])
+        else:
+            payload = chunk
+            # whole-blob reply: the stored meta crc IS the crc of
+            # these bytes — the messenger reuses it as the frame's
+            # blob crc (BLOB_CRC_ATTR), skipping the checksum pass.
+            # MemStore only: its contents were written by THIS
+            # process, so the crc kind is the current resolver's; a
+            # persistent store may hold crcs from another build/kind
+            # (the crc_verify_any discipline), and shipping one as
+            # the wire crc would fail every frame at the receiver
+            if isinstance(self.store, MemStore):
+                stored_crc = meta.chunk_crc
+        hraw = None
+        if getattr(msg, "want_hinfo", False):
+            try:
+                hraw = self.store.getattr(
+                    (msg.pool_id, msg.oid, msg.shard), HashInfo.XATTR_KEY)
+            except NotImplementedError:
+                pass
+        return MECSubReadReply(
+            tid=msg.tid, shard=msg.shard, ok=True, chunk=payload,
+            version=meta.version, object_size=meta.object_size,
+            hinfo=hraw or b"", chunk_crc=stored_crc,
+        )
 
     async def _handle_sub_delete(self, msg: MECSubDelete) -> None:
         txn = Transaction()

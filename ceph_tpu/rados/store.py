@@ -66,12 +66,29 @@ class Transaction:
     log_operation + queue_transactions coupling)."""
 
     writes: List[Tuple[Key, bytes, ShardMeta]] = field(default_factory=list)
+    # writes at an offset: (key, off, data, size, meta, prev)
+    ranged: List[tuple] = field(default_factory=list)
     deletes: List[Key] = field(default_factory=list)
     omap_sets: List[Tuple[Key, Dict[str, bytes]]] = field(default_factory=list)
     omap_rms: List[Tuple[Key, List[str]]] = field(default_factory=list)
+    # the store's answer, set when it applies `ranged`: bytes of whole
+    # objects it had to copy for them (0: every one landed in place)
+    copied: int = 0
 
     def write(self, key: Key, chunk: bytes, meta: ShardMeta) -> None:
         self.writes.append((key, chunk, meta))
+
+    def write_at(self, key: Key, off: int, data: bytes, size: int,
+                 meta: ShardMeta, prev: Optional[Key] = None) -> None:
+        """`data` over [off, off + len(data)) of the object at `key`
+        (reference ObjectStore::Transaction::write(oid, off, len, bl)),
+        the object zero-extended to `size` and to the extent's end, its
+        meta replaced.  With `prev`, the object as it was before this
+        write, bytes and meta, reads back at `prev` afterwards (the
+        reference clones the outgoing extent into a rollback object in
+        the same transaction, ECTransaction rollback_extents); what the
+        store keeps there is its own business."""
+        self.ranged.append((key, off, data, size, meta, prev))
 
     def delete(self, key: Key) -> None:
         self.deletes.append(key)
@@ -120,8 +137,32 @@ class ObjectStore:
                 f"{incoming_bytes} > {ceiling} "
                 f"({self.failsafe_ratio:g} of {cap})")
 
+    def _ranged_as_whole(self, txn: Transaction) -> None:
+        """A store with no write at an offset applies one as it always
+        has: read, rebuild, write whole, the outgoing object whole at
+        `prev`.  Turns `txn.ranged` into `txn.writes` before anything
+        mutates."""
+        pending: Dict[Key, Tuple[bytes, ShardMeta]] = {}
+        for key, off, data, size, meta, prev in txn.ranged:
+            got = pending.get(key) or self.read(key)
+            old = bytes(unwrap(got[0])) if got is not None else b""
+            if got is not None and prev is not None:
+                txn.writes.append((prev, old, got[1]))
+            blob = splice(old, off, data, size)
+            txn.writes.append((key, blob, meta))
+            pending[key] = (blob, meta)
+            txn.copied += len(old) + len(blob)
+        txn.ranged = []
+
     def read(self, key: Key) -> Optional[Tuple[bytes, ShardMeta]]:
         raise NotImplementedError
+
+    def stat(self, key: Key) -> Optional[Tuple[int, ShardMeta]]:
+        """(length, meta) of the object, without handing its bytes out."""
+        got = self.read(key)
+        if got is None:
+            return None
+        return memoryview(unwrap(got[0])).nbytes, got[1]
 
     def list_objects(self, pool_id: int) -> Iterable[Tuple[str, int]]:
         """Yield (oid, shard) pairs stored for a pool."""
@@ -154,6 +195,90 @@ class ObjectStore:
         return {}
 
 
+def splice(old, off: int, data, size: int = 0) -> bytes:
+    """`old` zero-extended to `size` and to the extent's end, with `data`
+    over [off, off + len(data)): the whole-buffer form of a write at an
+    offset (what a store without one does, and what tests hold one to)."""
+    buf = bytearray(old)
+    want = max(size, off + len(data), len(buf))
+    if len(buf) < want:
+        buf.extend(bytes(want - len(buf)))
+    buf[off:off + len(data)] = data
+    return bytes(buf)
+
+
+class _Spliced(bytearray):
+    """A shard the store has written at an offset and may write again IN
+    PLACE.  The store allocated it, so nobody else holds the object; what
+    others hold are the read-only views `read` hands out, and a bytearray
+    knows while one is alive (`viewed`): then, and only then, the next
+    write at an offset copies first and leaves this buffer to its
+    viewers.  `undo_at` is the key whose `_Undo` reads through this
+    buffer, if any."""
+
+    __slots__ = ("undo_at",)
+
+
+def live(chunk) -> bool:
+    """True when `chunk`, as a store's `read` gave it, is a view of a
+    buffer the store may write in place again.  It will not while the
+    view is alive (it copies first), so the view reads what it read; a
+    reader who keeps a SMALL part across an await copies the part instead
+    and spares the store the whole."""
+    return isinstance(chunk, memoryview) and type(chunk.obj) is _Spliced
+
+
+def viewed(buf: bytearray) -> bool:
+    """True while a memoryview of `buf` is alive anywhere (a sub-read
+    reply in a connection's outbox or replay queue, a recovery push, a
+    reader between two awaits).  A bytearray refuses to change its
+    length while it is exported, and that is the one way Python answers
+    the question.  The probe needs a spare byte of allocation, or it
+    reallocates (and may copy) the whole buffer once: `private` makes
+    buffers that have one."""
+    try:
+        buf.append(0)
+    except BufferError:
+        return True
+    buf.pop()
+    return False
+
+
+def private(src) -> "_Spliced":
+    """A copy of `src` to write in place and to ask `viewed` about:
+    allocated one byte longer than it is, in one pass over the bytes."""
+    n = memoryview(src).nbytes
+    buf = _Spliced(n + 1)
+    buf[:n] = src
+    buf.pop()
+    buf.undo_at = None
+    return buf
+
+
+class _Undo:
+    """A spliced shard's previous version, kept as what the splice
+    overwrote: the shard as it is now with `was` back at `off`, cut to
+    its old `length`.  O(extent) to keep; `whole` costs the shard and is
+    paid by whoever reads the rollback slot (a rollback, recovery, a
+    shard hunt).  It reads through `buf`, so it holds as long as no
+    later write at an offset changes `buf` without replacing it
+    (`MemStore._write_at` sees to that)."""
+
+    __slots__ = ("buf", "off", "was", "length")
+
+    def __init__(self, buf: bytearray, off: int, was: bytes, length: int):
+        self.buf, self.off, self.was, self.length = buf, off, was, length
+
+    def __len__(self) -> int:
+        return len(self.was)  # the bytes it holds, for the store's books
+
+    def whole(self) -> bytes:
+        now = memoryview(self.buf)
+        end = self.off + len(self.was)
+        return b"".join((now[:min(self.off, self.length)], self.was,
+                         now[end:self.length]))
+
+
 class MemStore(ObjectStore):
     def __init__(self, capacity_bytes: int = 0,
                  failsafe_ratio: float = 0.97) -> None:
@@ -170,7 +295,8 @@ class MemStore(ObjectStore):
         # the disk stores: the unlimited config skips even the cheap sum.
         if self.capacity_bytes:
             self._check_failsafe(
-                sum(len(unwrap(c)) for _k, c, _m in txn.writes),
+                sum(len(unwrap(c)) for _k, c, _m in txn.writes)
+                + sum(len(r[2]) for r in txn.ranged),
                 self._used_bytes)
         for key in txn.deletes:
             old = self._data.pop(key, None)
@@ -193,6 +319,8 @@ class MemStore(ObjectStore):
                 self._used_bytes -= len(prev[0])
             self._used_bytes += len(chunk)
             self._data[key] = (chunk, meta)
+        for ranged in txn.ranged:
+            self._write_at(txn, *ranged)
         for key, entries in txn.omap_sets:
             self._omap.setdefault(key, {}).update(entries)
         for key, keys in txn.omap_rms:
@@ -202,6 +330,42 @@ class MemStore(ObjectStore):
                     table.pop(k, None)
         if on_commit is not None:
             on_commit()
+
+    def _write_at(self, txn: Transaction, key: Key, off: int, data,
+                  size: int, meta: ShardMeta, prev: Optional[Key]) -> None:
+        """A write at an offset, in place where the store may: the object
+        is a `_Spliced` nobody views and no `_Undo` but `prev`'s reads
+        through.  Else one private copy first (`txn.copied`): the first
+        such write to an object stored as it arrived (bytes, an adopted
+        view: those are shared with the sender on the in-process paths
+        and are never written), a view out, a missing object (created).
+        The outgoing version goes to `prev` as an `_Undo`, replacing
+        whatever was there: the extent, not the shard."""
+        got = self._data.get(key)
+        cur = got[0] if got is not None else b""
+        held = len(cur)
+        if type(cur) is _Spliced and cur.undo_at in (None, prev) \
+                and not viewed(cur):
+            buf = cur
+        else:
+            buf = private(cur.whole() if type(cur) is _Undo else cur)
+            txn.copied += len(buf)
+        length = len(buf)
+        end = off + len(data)
+        was = bytes(memoryview(buf)[off:end])
+        if max(size, end) > length:
+            buf.extend(bytes(max(size, end) - length))
+        buf[off:end] = data
+        self._used_bytes += len(buf) - held
+        self._data[key] = (buf, meta)
+        if prev is not None and got is not None:
+            slot = self._data.get(prev)
+            if slot is not None:
+                self._used_bytes -= len(slot[0])
+            undo = _Undo(buf, off, was, length)
+            self._used_bytes += len(undo)
+            self._data[prev] = (undo, got[1])
+            buf.undo_at = prev
 
     def omap_get(self, key: Key) -> Dict[str, bytes]:
         return dict(self._omap.get(key, {}))
@@ -228,7 +392,39 @@ class MemStore(ObjectStore):
         return dict(self._xattrs.get(key, {}))
 
     def read(self, key: Key) -> Optional[Tuple[bytes, ShardMeta]]:
-        return self._data.get(key)
+        got = self._data.get(key)
+        if got is not None:
+            kind = type(got[0])
+            if kind is _Spliced:
+                # the one door to a buffer that may be written in place:
+                # a view, so that `viewed` knows while it is held
+                return memoryview(got[0]).toreadonly(), got[1]
+            if kind is _Undo:
+                return got[0].whole(), got[1]
+        return got
+
+    def stat(self, key: Key) -> Optional[Tuple[int, ShardMeta]]:
+        got = self._data.get(key)
+        if got is None:
+            return None
+        chunk = got[0]
+        return (chunk.length if type(chunk) is _Undo
+                else memoryview(chunk).nbytes), got[1]
+
+    def read_range(self, key: Key, off: int, length: int):
+        """The object's bytes in [off, off + length), cut short where it
+        ends: a buffer that stays as it is whatever is written to the
+        object later."""
+        got = self._data.get(key)
+        if got is None:
+            return b""
+        chunk = got[0]
+        if type(chunk) is _Undo:
+            return chunk.whole()[off:off + length]
+        cut = memoryview(chunk)[off:off + length]
+        # of a buffer that may be written in place, a copy: a view held
+        # would cost the next write there a copy of the whole
+        return bytes(cut) if type(chunk) is _Spliced else cut
 
     def list_objects(self, pool_id: int):
         for (pid, oid, shard) in list(self._data):
@@ -264,6 +460,7 @@ class DirStore(ObjectStore):
         return os.path.join(self.path, f"{pid}__{oid.encode().hex()}__{shard}")
 
     def queue_transaction(self, txn: Transaction, on_commit=None) -> None:
+        self._ranged_as_whole(txn)
         if self.capacity_bytes:
             # _used_bytes is a directory sweep: only pay it when a
             # ceiling is actually configured

@@ -15,15 +15,17 @@ import zlib
 
 _IMPL = None
 _KIND = None
+_SHIFT = None  # the crc32c kind's zeros operator (bridge.crc32c_shift)
 
 
 def _resolve() -> None:
-    global _IMPL, _KIND
+    global _IMPL, _KIND, _SHIFT
     try:
         from ceph_tpu.native import bridge
 
         bridge.crc32c(b"probe")
         _IMPL = bridge.crc32c
+        _SHIFT = bridge.crc32c_shift
         _KIND = "crc32c"
     except Exception:
         import logging
@@ -39,6 +41,30 @@ def checksum(data, seed: int = 0) -> int:
     if _IMPL is None:
         _resolve()
     return _IMPL(data, seed)
+
+
+def spliced(crc: int, size: int, new_size: int, off: int, was, now):
+    """The checksum of a buffer after a splice, made from the one before
+    it and the bytes that changed: `crc` covered `size` bytes; the buffer
+    is zero-extended to `new_size` and `now` laid over [off, off +
+    len(now)), where it held `was` (shorter than `now` where the old
+    buffer ended inside the extent, empty for an append).  A crc is
+    linear, so crc(new) = crc(old) ^ shift(raw(was ^ now), bytes after
+    the extent), `raw` the register run from zero and `shift` its advance
+    over that many zero bytes (native/crc32c.cc ZerosOp): two passes over
+    the extent, none over the buffer.  None where this process's checksum
+    has no shift (the zlib kind): the caller makes the whole pass."""
+    if _IMPL is None:
+        _resolve()
+    if _KIND != "crc32c":
+        return None
+    shift, m = _SHIFT, 0xFFFFFFFF
+    if new_size > size:
+        crc = ~shift(~crc & m, new_size - size) & m
+    # raw(0, x) == ~crc(x, seed=~0); the two inversions cancel in the xor
+    delta = shift(~_IMPL(was, m) & m, len(now) - len(was)) \
+        ^ (~_IMPL(now, m) & m)
+    return (crc ^ shift(delta, new_size - off - len(now))) & m
 
 
 def checksum_kind() -> str:

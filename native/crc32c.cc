@@ -14,6 +14,7 @@
 
 #include <cstddef>
 #include <cstdint>
+#include <unordered_map>
 
 #if defined(__x86_64__)
 #include <cpuid.h>
@@ -43,13 +44,6 @@ uint32_t crc32c_table(uint32_t crc, const uint8_t* p, size_t n) {
   for (size_t i = 0; i < n; ++i)
     crc = t[(crc ^ p[i]) & 0xFF] ^ (crc >> 8);
   return ~crc;
-}
-
-#if defined(__x86_64__)
-bool have_sse42() {
-  unsigned a, b, c, d;
-  if (!__get_cpuid(1, &a, &b, &c, &d)) return false;
-  return (c & bit_SSE4_2) != 0;
 }
 
 // GF(2) matrix ops for crc stream combination (zeros operator): the
@@ -119,6 +113,13 @@ struct ZerosOp {
   uint32_t shift(uint32_t crc) const { return gf2_matrix_times(mat, crc); }
 };
 
+#if defined(__x86_64__)
+bool have_sse42() {
+  unsigned a, b, c, d;
+  if (!__get_cpuid(1, &a, &b, &c, &d)) return false;
+  return (c & bit_SSE4_2) != 0;
+}
+
 constexpr size_t kLong = 8192;  // bytes per stream in the 3-way stride
 
 __attribute__((target("sse4.2")))
@@ -171,6 +172,25 @@ uint32_t ceph_tpu_crc32c(uint32_t seed, const uint8_t* data, size_t len) {
   if (hw) return crc32c_hw(seed, data, len);
 #endif
   return crc32c_table(seed, data, len);
+}
+
+// The crc register advanced over `len` zero bytes: ZerosOp(len) applied to
+// `state`, the raw register (no pre- or post-inversion; the operator is
+// linear, so shift(a ^ b) == shift(a) ^ shift(b)).  It is what lets a
+// caller who changed a few bytes of a long buffer make the new crc from
+// the old one: crc(new) = crc(old) ^ shift(raw_crc(old_extent ^
+// new_extent), bytes after the extent).  An operator costs ~log2(len)
+// matrix squarings to build, so each thread keeps the ones it built (a
+// 4 KiB-aligned splice of a 512 KiB shard has 128 tail lengths).
+uint32_t ceph_tpu_crc32c_shift(uint32_t state, size_t len) {
+  if (len == 0 || state == 0) return state;
+  thread_local std::unordered_map<size_t, ZerosOp> ops;
+  auto it = ops.find(len);
+  if (it == ops.end()) {
+    if (ops.size() >= 4096) ops.clear();
+    it = ops.emplace(len, ZerosOp(len)).first;
+  }
+  return it->second.shift(state);
 }
 
 // which dispatch the crc took ("sse4.2" | "table") — audit hook

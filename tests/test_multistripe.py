@@ -576,8 +576,13 @@ class TestExtentCache:
                 await c.put(pool, "obj", patch, offset=off)
                 want = data[:off] + patch + data[off + len(patch):]
                 assert total("rmw_read_bytes") == reads0  # cache served
-                assert total("write_adopted_bytes") == 2 * len(data)
+                # the offset write hands the cache no new object: it
+                # splits the cached one around the stripes it wrote
+                assert total("write_adopted_bytes") == len(data)
                 assert total("write_copied_bytes") == 0
+                v2, patched = primary._cache_get(pool, "obj")
+                assert v2 > _v and patched == want
+                assert cached == data  # the payload itself is untouched
                 assert await c.get(pool, "obj") == want
                 for o in cluster.osds.values():
                     o._extent_cache.clear()

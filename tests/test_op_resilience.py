@@ -376,6 +376,11 @@ class TestDispatchBreaker:
             q.inject_dispatch_delay = 0.12
             assert np.array_equal(
                 q.submit(bm, regions, 8, 2).result(timeout=60), expect)
+            # the queue's thread sets the result BEFORE it trips the lane
+            deadline = time.monotonic() + 5
+            while not q.perf.get("breaker_trip") \
+                    and time.monotonic() < deadline:
+                time.sleep(0.01)
             assert q.perf.get("breaker_trip") == 1
             assert q.perf.get("breaker_open_lanes") == 1
             # while open: the CPU path serves, byte-identical

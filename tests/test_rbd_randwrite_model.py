@@ -47,7 +47,8 @@ def osd_counters(cluster) -> dict:
     return {key: sum(o.perf.get(key) for o in cluster.osds.values())
             for key in ARMS + ["rmw_partial", "rmw_full_rewrite",
                                "rmw_copied_bytes", "splice_copied_bytes",
-                               "splice_crc_bytes", "splice_refused"]}
+                               "splice_crc_bytes", "splice_refused",
+                               "splice_in_place", "splice_rebuilt"]}
 
 
 async def _scenario():
@@ -238,18 +239,27 @@ def test_the_arms_sum_to_the_offset_writes(seen):
 
 
 def test_copies_and_crcs_of_a_write_are_counted(seen):
-    """On a 1 MiB object a shard is 128 KiB: every one of the k+m
-    splices copies it twice and checksums it twice (blob and hinfo
-    entry); the primary copies the object twice on the cached arm, the
-    32 KiB segment twice otherwise."""
+    """A write costs its stripe, not its object (PR 44).  Every one of
+    the k+m splices moves its 4 KiB extent three times (out for the crc,
+    out for the rollback slot, in) and checksums it twice (as it was, as
+    it is); a shard is copied whole (128 KiB of a 1 MiB object) only at
+    its first splice, when the store makes the buffer it writes in place
+    from then on.  The primary copies the 32 KiB segment twice on every
+    arm (and cuts it out of its run on the extent arm), and nothing of
+    the rest of a cached object: its runs are split around the stripe."""
     during, n = seen["during"], seen["offset_writes"]
     shard = (1 << ORDER) // K
-    assert during["splice_copied_bytes"] == n * (K + M) * 2 * shard
-    assert during["splice_crc_bytes"] == n * (K + M) * 2 * shard
-    cached = during["rmw_base_cached"]
+    splices = n * (K + M)
+    rebuilt = during["splice_rebuilt"]
+    assert during["splice_in_place"] + rebuilt == splices
+    # a first touch a shard of the 8 objects, and no more
+    assert K + M <= rebuilt <= 8 * (K + M)
+    assert during["splice_copied_bytes"] == \
+        rebuilt * shard + splices * 3 * UNIT
+    assert during["splice_crc_bytes"] == splices * 2 * UNIT
     stripe = K * UNIT
     assert during["rmw_copied_bytes"] == (
-        cached * (2 * (1 << ORDER) + stripe)
+        during["rmw_base_cached"] * 2 * stripe
         + during["rmw_extent_hits"] * 3 * stripe
         + during["rmw_base_shards"] * 2 * stripe)
 
