@@ -451,6 +451,15 @@ DEFAULT_SCHEMA: Dict[str, Option] = _opts(
                 "in CI without writing gigabytes "
                 "(CEPH_TPU_INJECT_FULL env equivalent)"),
     # objectstore
+    Option("osd_objectstore", OPT_STR, "memstore",
+           enum_values=("memstore", "bluestore"),
+           desc="the object store vstart gives each OSD (upstream's "
+                "default is bluestore; a caller's data_dir means "
+                "bluestore there)"),
+    Option("osd_data", OPT_STR, "",
+           desc="where a cluster makes the directory of its OSDs' disk "
+                "stores, which it removes when it stops (empty = the "
+                "system's temporary directory)"),
     Option("bluestore_csum_type", OPT_STR, "crc32c",
            enum_values=("none", "crc32c")),
     Option("bluestore_debug_inject_read_err", OPT_BOOL, False, level=LEVEL_DEV),

@@ -10,7 +10,11 @@ metadata batch hits the KV WAL.  Small writes are DEFERRED
 is flushed to the block file after commit, saving the block-file sync on
 the latency path; large writes go to freshly allocated extents first
 (copy-on-write — crash before KV commit leaves the old object intact),
-then the metadata flips atomically.
+the block file is SYNCED, and only then the metadata flips atomically
+(reference _kv_sync_thread: bdev->flush() before
+db->submit_transaction_sync): an onode never names bytes a power cut
+could take.  The order of a commit is block write, block sync, WAL
+write, WAL sync, on_commit; the caller acks after on_commit.
 
 Checksums: per-extent, algorithm selected by bluestore_csum_type
 (crc32c default, zlib, none — reference csum_type per blob), verified
@@ -39,13 +43,17 @@ from __future__ import annotations
 import os
 import pickle
 import random
+import time
 import zlib
 
+from ceph_tpu.common import tracing
+from ceph_tpu.common.perf_counters import PerfCounters, PerfCountersBuilder
 from ceph_tpu.utils.checksum import checksum
 from dataclasses import dataclass, field
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
-from ceph_tpu.rados.kv import KeyValueDB, MemDB, WalDB, WriteBatch
+from ceph_tpu.rados.kv import (KeyValueDB, MemDB, SyncedFile, WalDB,
+                               WriteBatch, timed_sync)
 from ceph_tpu.rados.store import (ENOSPCError,  # noqa: F401 (re-export)
                                   Key, ObjectStore, ShardMeta, Transaction,
                                   unwrap as store_unwrap)
@@ -54,6 +62,46 @@ PREFIX_OBJ = "O"  # object metadata (extents, csums, ShardMeta, xattrs)
 PREFIX_DEFERRED = "D"  # deferred write payloads awaiting block flush
 PREFIX_OMAP = "M"  # per-object sorted key/value (PG log lives here)
 PREFIX_SUPER = "S"  # store-wide state (size watermark)
+
+
+def build_bluestore_perf(name: str = "bluestore") -> PerfCounters:
+    b = PerfCountersBuilder(name)
+    b.add_u64_counter("txns", "transactions committed")
+    b.add_time_avg("commit_lat", "queue_transaction entered -> on_commit "
+                                 "run")
+    b.add_u64_counter("block_write_bytes", "bytes written to block files")
+    b.add_u64_counter("wal_bytes", "bytes appended to KV write-ahead logs")
+    b.add_u64_counter("deferred_bytes", "payload bytes that rode a WAL "
+                                        "record (deferred writes)")
+    b.add_u64_counter("deferred_writes", "writes at or under "
+                                         "bluestore_prefer_deferred_size")
+    b.add_u64_counter("big_writes", "writes that went to fresh extents "
+                                    "before their commit")
+    b.add_u64_counter("block_syncs", "syncs of a block file")
+    b.add_u64_counter("wal_syncs", "syncs of a KV write-ahead log")
+    b.add_time_avg("sync_s", "seconds inside fsync/fdatasync, whichever "
+                             "thread")
+    b.add_time_avg("loop_sync_s", "the part of sync_s made on a thread "
+                                  "that runs an event loop")
+    b.add_time_avg("group_txns", "transactions a WAL sync committed, over "
+                                 "the syncs that committed any")
+    b.add_u64_counter("compactions", "KV snapshots written (WalDB.compact)")
+    b.add_time_avg("compact_s", "seconds inside KV compactions")
+    b.add_u64_counter("csum_bytes", "bytes checksummed for extents "
+                                    "written")
+    b.add_u64_counter("alloc_extents", "extents allocated")
+    b.add_u64_counter("commit_under_sync",
+                      "transactions whose on_commit found every block "
+                      "byte and the WAL record they made under a sync")
+    b.add_u64_counter("commit_unsynced",
+                      "transactions whose on_commit ran ahead of a sync "
+                      "that has to cover them: the broken guarantee")
+    return b.create_perf_counters()
+
+
+# ONE set per process, listed by every OSD whose store is a BlueStore, as
+# the resident store's set is: the stores of a vstart cluster share a disk
+BS_PERF = build_bluestore_perf()
 
 
 class EIOError(IOError):
@@ -178,19 +226,23 @@ class Allocator:
 
 
 class BlueStore(ObjectStore):
+    perf = BS_PERF
+
     def __init__(self, path: Optional[str] = None,
                  conf: Optional[dict] = None,
-                 db: Optional[KeyValueDB] = None):
+                 db: Optional[KeyValueDB] = None,
+                 files=SyncedFile):
         self.conf = conf or {}
         self.path = path
         if path is not None:
             os.makedirs(path, exist_ok=True)
-            self.db: KeyValueDB = db or WalDB(os.path.join(path, "db"))
+            self.db: KeyValueDB = db or WalDB(
+                os.path.join(path, "db"), perf=self.perf, files=files)
             self._block_path = os.path.join(path, "block")
             if not os.path.exists(self._block_path):
                 open(self._block_path, "wb").close()
             # r+b: positioned writes (a+b would append regardless of seek)
-            self._block = open(self._block_path, "r+b")
+            self._block = files(self._block_path, "r+b")
         else:
             self.db = db or MemDB()
             self._block = None
@@ -212,6 +264,7 @@ class BlueStore(ObjectStore):
         # the commit latency path (bluestore deferred_batch semantics)
         self._deferred_pending: List[Tuple[Key, _Onode, bytes]] = []
         self._deferred_batch_max = 16
+        self._block_dirty = False  # extents written since the last sync
         self._load()
         self._flush_deferred()
 
@@ -233,6 +286,7 @@ class BlueStore(ObjectStore):
             onode = self._onodes.get(key)
             if onode is not None and onode.deferred:
                 self._write_extents(onode.extents, v)
+                self._sync_block()
                 onode.deferred = False
                 batch = WriteBatch()
                 batch.set(PREFIX_OBJ, _okey(key),
@@ -247,26 +301,40 @@ class BlueStore(ObjectStore):
     # -- block IO ------------------------------------------------------------
 
     def _write_extents(self, extents: List[Tuple[int, int]], data: bytes) -> None:
-        pos = 0
-        for off, length in extents:
-            piece = data[pos:pos + length]
-            if self._block is not None:
-                self._block.seek(off)
-                self._block.write(piece)
-            else:
-                self._blob[off] = piece
-            pos += length
+        """The bytes to their extents; `_sync_block` has to follow before
+        a KV batch that names them."""
+        with tracing.section("store", "bs_block_write"):
+            pos = 0
+            for off, length in extents:
+                piece = data[pos:pos + length]
+                if self._block is not None:
+                    self._block.seek(off)
+                    self._block.write(piece)
+                else:
+                    self._blob[off] = piece
+                pos += length
+        self.perf.inc("block_write_bytes", pos)
+        self._block_dirty = True
+
+    def _sync_block(self) -> None:
+        """Every extent written since the last sync, onto the disk."""
+        if not self._block_dirty:
+            return
         if self._block is not None:
-            self._block.flush()
+            timed_sync(self._block, self.perf, "bs_block_sync",
+                       data_only=True)
+            self.perf.inc("block_syncs")
+        self._block_dirty = False
 
     def _read_extents(self, extents: List[Tuple[int, int]]) -> bytes:
         out = []
-        for off, length in extents:
-            if self._block is not None:
-                self._block.seek(off)
-                out.append(self._block.read(length))
-            else:
-                out.append(self._blob.get(off, b"")[:length])
+        with tracing.section("store", "bs_read"):
+            for off, length in extents:
+                if self._block is not None:
+                    self._block.seek(off)
+                    out.append(self._block.read(length))
+                else:
+                    out.append(self._blob.get(off, b"")[:length])
         return b"".join(out)
 
     # -- ObjectStore interface -----------------------------------------------
@@ -276,6 +344,7 @@ class BlueStore(ObjectStore):
         """Apply atomically: ONE KV batch is the commit point for every
         write/delete in the transaction (ObjectStore::queue_transactions
         with register_on_commit semantics)."""
+        t_enter = time.perf_counter()
         prefer_deferred = int(self.conf.get("bluestore_prefer_deferred_size",
                                             32768) or 0)
         self._ranged_as_whole(txn)
@@ -354,9 +423,13 @@ class BlueStore(ObjectStore):
                         onode.raw_len = raw_len
             onode.csum_type = str(self.conf.get("bluestore_csum_type",
                                                 "crc32c") or "crc32c")
-            off = self.alloc.allocate(max(1, len(chunk)))
+            with tracing.section("store", "bs_alloc"):
+                off = self.alloc.allocate(max(1, len(chunk)))
             onode.extents = [(off, len(chunk))]
-            onode.csums = [self._csum(onode.csum_type, chunk)]
+            with tracing.section("store", "bs_csum"):
+                onode.csums = [self._csum(onode.csum_type, chunk)]
+            self.perf.inc("alloc_extents")
+            self.perf.inc("csum_bytes", len(chunk))
             if len(chunk) <= prefer_deferred:
                 # deferred: payload rides the KV WAL (pickled) — needs
                 # real bytes, a memoryview cannot serialize
@@ -365,14 +438,30 @@ class BlueStore(ObjectStore):
                 onode.deferred = True
                 batch.set(PREFIX_DEFERRED, _okey(key), chunk)
                 deferred_flush.append((key, onode, chunk))
+                self.perf.inc("deferred_writes")
+                self.perf.inc("deferred_bytes", len(chunk))
             else:
                 # large write: data to fresh extents BEFORE commit (COW)
                 self._write_extents(onode.extents, chunk)
+                self.perf.inc("big_writes")
             self._onodes[key] = onode
             batch.set(PREFIX_OBJ, _okey(key), pickle.dumps(onode, protocol=5))
+        # the extents' bytes onto the disk before the batch that names
+        # them: a power cut between the two leaves the old object
+        self._sync_block()
+        wal_seq = getattr(self.db, "wal_seq", None)  # a RAM KV has none
         self.db.submit(batch)  # <- THE commit point
+        self.perf.inc("txns")
+        self.perf.tinc("group_txns", 1)
+        # the guarantee, looked at where the caller is told: no extent of
+        # this store waits for a sync, and the WAL was synced after this
+        # batch was handed over
+        self.perf.inc("commit_unsynced" if self._block_dirty
+                      or (wal_seq is not None and self.db.wal_seq <= wal_seq)
+                      else "commit_under_sync")
         if on_commit is not None:
             on_commit()
+        self.perf.tinc("commit_lat", time.perf_counter() - t_enter)
         # post-commit: deferred payloads drain in batches so a small write
         # costs ONE fsync on the latency path (the open-time replay covers
         # anything pending at a crash)
@@ -395,6 +484,9 @@ class BlueStore(ObjectStore):
             b2.set(PREFIX_OBJ, _okey(key), pickle.dumps(onode, protocol=5))
             b2.rm(PREFIX_DEFERRED, _okey(key))
         if b2.ops:
+            # as a commit's: the payloads leave the WAL only once the
+            # block file holds them
+            self._sync_block()
             self.db.submit(b2)
 
     @staticmethod
@@ -451,6 +543,17 @@ class BlueStore(ObjectStore):
                     f"decompressed length mismatch on {key}: "
                     f"{len(data)} != {raw_len}")
         return data, onode.meta
+
+    def stat(self, key: Key) -> Optional[Tuple[int, ShardMeta]]:
+        """From the onode alone: no byte is read or checksummed."""
+        onode = self._onodes.get(key)
+        if onode is None:
+            return None
+        size = sum(length for _, length in onode.extents)
+        if getattr(onode, "compression", None) \
+                and getattr(onode, "raw_len", -1) >= 0:
+            size = onode.raw_len  # the extents hold the compressed blob
+        return size, onode.meta
 
     def list_objects(self, pool_id: int) -> Iterable[Tuple[str, int]]:
         for (pid, oid, shard) in list(self._onodes):
@@ -517,8 +620,25 @@ class BlueStore(ObjectStore):
                 "num_objects": len(self._onodes),
                 "size": self.alloc.size, "free": free}
 
+    def synced_lengths(self) -> Dict[str, int]:
+        """Bytes of each of the store's files that a sync has covered, by
+        path under `self.path`: a copy of the directory cut to them is
+        what a power cut now would leave."""
+        out = {"block": self._block.synced} if self._block is not None \
+            else {}
+        lengths = getattr(self.db, "synced_lengths", None)
+        if lengths is not None:
+            out.update(("db/" + name, n) for name, n in lengths().items())
+        return out
+
     def close(self) -> None:
         self.flush_deferred_batch()
+        self.abandon()
+
+    def abandon(self) -> None:
+        """Let go of the files as they are, as a killed daemon does:
+        deferred payloads that were not flushed stay in the WAL for the
+        next open to replay."""
         self.db.close()
         if self._block is not None:
             self._block.close()

@@ -9,19 +9,77 @@ record is discarded), sized for metadata volumes, not a general LSM.
 
 Record format in the WAL: [u32 len][u32 crc][pickled batch].  Compaction
 writes a full snapshot and truncates the log.
+
+Every file of a store is a `SyncedFile`: it knows how many of its bytes
+a sync has covered, which is what a power cut leaves of it.
 """
 
 from __future__ import annotations
 
+import asyncio
 import os
 import pickle
 import struct
-import zlib
+import time
 
+from ceph_tpu.common import tracing
 from ceph_tpu.utils.checksum import checksum
-from typing import Dict, Iterable, Iterator, List, Optional, Tuple
+from typing import Dict, Iterator, List, Optional, Tuple
 
 _REC = struct.Struct("<II")
+
+
+class SyncedFile:
+    """A store's file and the two lengths that matter at a power cut:
+    `end`, the bytes written, and `synced`, the bytes that were there at
+    the last `sync` (what was on the disk at `open` counts as synced).
+    The tests' crash layer subclasses it to keep the synced image."""
+
+    def __init__(self, path: str, mode: str):
+        self.path = path
+        self.f = open(path, mode)
+        self.end = self.synced = os.path.getsize(path)
+
+    def seek(self, off: int) -> None:
+        self.f.seek(off)
+
+    def read(self, n: int) -> bytes:
+        return self.f.read(n)
+
+    def write(self, data) -> None:
+        self.f.write(data)
+        self.end = max(self.end, self.f.tell())
+
+    def flush(self) -> None:
+        self.f.flush()
+
+    def sync(self, data_only: bool = False) -> None:
+        self.f.flush()
+        (os.fdatasync if data_only else os.fsync)(self.f.fileno())
+        self.synced = self.end
+
+    def close(self) -> None:
+        self.f.close()
+
+    @staticmethod
+    def replace(src: str, dst: str) -> None:
+        os.replace(src, dst)
+
+
+def timed_sync(f: SyncedFile, perf, name: str,
+               data_only: bool = False) -> None:
+    """`f.sync()` as the section `name` of the store layer, its seconds
+    in the `bluestore` set: `sync_s` whichever thread made it,
+    `loop_sync_s` when that thread runs an event loop (every daemon of
+    an in-process cluster waits behind it)."""
+    with tracing.section("store", name):
+        t0 = time.perf_counter()
+        f.sync(data_only)
+        took = time.perf_counter() - t0
+    if perf is not None:
+        perf.tinc("sync_s", took)
+        if asyncio._get_running_loop() is not None:
+            perf.tinc("loop_sync_s", took)
 
 
 class WriteBatch:
@@ -80,17 +138,23 @@ class MemDB(KeyValueDB):
 
 class WalDB(MemDB):
     """Durable MemDB: every batch is WAL-appended before apply; snapshot +
-    log truncation when the log grows past `compact_bytes`."""
+    log truncation when the log grows past `compact_bytes`.  `perf` is
+    the owning store's counter set (BlueStore's `bluestore`), `files`
+    the class its files are opened with."""
 
-    def __init__(self, path: str, compact_bytes: int = 4 << 20):
+    def __init__(self, path: str, compact_bytes: int = 4 << 20,
+                 perf=None, files=SyncedFile):
         super().__init__()
         self.path = path
         self.compact_bytes = compact_bytes
+        self.perf = perf
+        self._files = files
+        self.wal_seq = 0  # the number of the log's last sync
         os.makedirs(path, exist_ok=True)
         self._snap_path = os.path.join(path, "snapshot.db")
         self._log_path = os.path.join(path, "wal.log")
         self._recover()
-        self._log = open(self._log_path, "ab")
+        self._log = files(self._log_path, "ab")
 
     # -- recovery ------------------------------------------------------------
 
@@ -130,23 +194,43 @@ class WalDB(MemDB):
     # -- commits -------------------------------------------------------------
 
     def submit(self, batch: WriteBatch) -> None:
-        blob = pickle.dumps(batch.ops, protocol=5)
-        self._log.write(_REC.pack(len(blob), checksum(blob)) + blob)
-        self._log.flush()
-        os.fsync(self._log.fileno())
+        with tracing.section("store", "bs_wal_submit"):
+            blob = pickle.dumps(batch.ops, protocol=5)
+            self._log.write(_REC.pack(len(blob), checksum(blob)) + blob)
+        timed_sync(self._log, self.perf, "bs_wal_sync")
+        self.wal_seq += 1
+        if self.perf is not None:
+            self.perf.inc("wal_bytes", _REC.size + len(blob))
+            self.perf.inc("wal_syncs")
         self._apply(batch)
-        if self._log.tell() >= self.compact_bytes:
+        if self._log.end >= self.compact_bytes:
             self.compact()
 
     def compact(self) -> None:
-        tmp = self._snap_path + ".tmp"
-        with open(tmp, "wb") as f:
-            pickle.dump(self._tables, f, protocol=5)
-            f.flush()
-            os.fsync(f.fileno())
-        os.replace(tmp, self._snap_path)
-        self._log.close()
-        self._log = open(self._log_path, "wb")
+        """The whole table set to a new snapshot, then an empty log.  On
+        the caller's thread: every key the store holds is pickled there,
+        and `compact_s` says for how long."""
+        t0 = time.perf_counter()
+        with tracing.section("store", "bs_compact"):
+            tmp = self._snap_path + ".tmp"
+            snap = self._files(tmp, "wb")
+            pickle.dump(self._tables, snap, protocol=5)
+            timed_sync(snap, self.perf, "bs_wal_sync")
+            snap.close()
+            self._files.replace(tmp, self._snap_path)
+            self._log.close()
+            self._log = self._files(self._log_path, "wb")
+        if self.perf is not None:
+            self.perf.inc("compactions")
+            self.perf.tinc("compact_s", time.perf_counter() - t0)
+
+    def synced_lengths(self) -> Dict[str, int]:
+        """Bytes of each file a sync has covered, by name under `path`
+        (the snapshot is replaced whole, after its own sync)."""
+        out = {"wal.log": self._log.synced}
+        if os.path.exists(self._snap_path):
+            out["snapshot.db"] = os.path.getsize(self._snap_path)
+        return out
 
     def close(self) -> None:
         try:

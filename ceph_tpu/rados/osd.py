@@ -446,6 +446,7 @@ class OSD:
         # re-send while the peer stays silent (evidence at the mon expires)
         self._hb_last: Dict[int, float] = {}
         self._hb_reported: Dict[int, float] = {}
+        self._booted_at = time.monotonic()  # set again when the mon answers
         # per-PG logs (src/osd/PGLog.cc role), lazily loaded from omap
         self._pglogs: Dict[Tuple[int, int], PGLog] = {}
         # reqids whose write failed min_size: a resend must RE-EXECUTE,
@@ -612,6 +613,9 @@ class OSD:
                 self._ec_queue.tracer = self.ctx.tracer
         if self._planar is not None:
             self.ctx.perf.add(self._planar.perf)
+        store_perf = getattr(self.store, "perf", None)
+        if store_perf is not None:  # BlueStore's `bluestore` set
+            self.ctx.perf.add(store_perf)
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -657,6 +661,7 @@ class OSD:
         # next epoch that happens to touch them leaves them driverless
         # while the old holders keep failing
         self._on_map(reply.osdmap)
+        self._booted_at = time.monotonic()
         interval = self.conf.get("osd_heartbeat_interval", 0.3)
         loop = asyncio.get_running_loop()
         # the driver loops run under the daemon crash guard: an
@@ -795,9 +800,13 @@ class OSD:
         self._inject_crash = True
         return {"injected": True, "osd": self.osd_id}
 
-    async def stop(self) -> None:
+    def halt(self) -> None:
+        """The daemon's own loops end here, with nothing awaited: no ping,
+        no failure report and no peering step after this call.  `stop`
+        does it on its way; who kills several daemons as one (a power
+        cut) halts them all before stopping any, or one not yet stopped
+        finds a stopped peer's address refusing and reports it."""
         self._stopped = True
-        await self.clog.stop()
         for t in (self._ping_task, self._hb_task, self._repair_task,
                   self._meta_repl_task, self._scrub_task):
             if t:
@@ -805,6 +814,14 @@ class OSD:
         for m in self._pg_machines.values():
             if m.task is not None:
                 m.task.cancel()
+
+    async def stop(self, abandon_store: bool = False) -> None:
+        """`abandon_store`: leave the store as a killed daemon does, its
+        files let go of without a flush (a store with no `abandon` has
+        nothing to flush and is closed as ever)."""
+        self._stopped = True
+        await self.clog.stop()
+        self.halt()
         await self.op_queue.stop()
         await self.ctx.shutdown()
         await self.messenger.shutdown()
@@ -819,7 +836,8 @@ class OSD:
                 if isinstance(key, tuple) and key \
                         and key[0] == self.osd_id:
                     self._planar.drop(key, force=True)
-        close = getattr(self.store, "close", None)
+        close = (abandon_store and getattr(self.store, "abandon", None)) \
+            or getattr(self.store, "close", None)
         if close is not None:
             close()
 
@@ -1157,8 +1175,14 @@ class OSD:
                     # instead of burning the grace window (the reference
                     # reports connection faults ahead of ping timeouts).
                     # A restarting OSD re-boots and re-registers, so a
-                    # false positive costs one re-peer, not data.
-                    if now - self._hb_reported.get(o.osd_id, -1e9) > 1.0:
+                    # false positive costs one re-peer, not data.  Not in
+                    # this daemon's own first grace: the map it booted
+                    # with may name peers that are coming back as it did
+                    # (a whole cluster restarted at once), and a report
+                    # then marks live daemons down and starts recovery
+                    # where nothing was lost.
+                    if now - self._booted_at > grace and \
+                            now - self._hb_reported.get(o.osd_id, -1e9) > 1.0:
                         self._hb_reported[o.osd_id] = now
                         self.perf.inc("heartbeat_failures")
                         try:

@@ -12,6 +12,8 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import shutil
+import tempfile
 from typing import Dict, List, Optional
 
 from ceph_tpu.common import tracing
@@ -57,6 +59,10 @@ class Cluster:
         self.n_mons = n_mons
         self.with_mgr = with_mgr
         self.data_dir = data_dir
+        # where the OSDs' stores live when the conf, not the caller,
+        # asks for a disk store (osd_objectstore): a fresh directory this
+        # cluster makes at start and removes at stop
+        self._own_data_dir: Optional[str] = None
         self.mons: List[Monitor] = []
         self.mgr = None
         self.osds: Dict[int, OSD] = {}
@@ -86,6 +92,42 @@ class Cluster:
         return ports
 
     async def start(self) -> None:
+        try:
+            await self._start()
+        except BaseException:
+            self._remove_own_data_dir()
+            raise
+
+    def _remove_own_data_dir(self) -> None:
+        if self._own_data_dir is not None:
+            shutil.rmtree(self._own_data_dir, ignore_errors=True)
+            self._own_data_dir = None
+
+    def _osd_store(self, n: int):
+        """The object store of the n-th OSD started: what the caller's
+        `data_dir` says, else what the conf's `osd_objectstore` says (as
+        upstream; `memstore` unless it names `bluestore`)."""
+        kind = str(self.conf.get("osd_objectstore", "") or "memstore")
+        if kind not in ("memstore", "bluestore"):
+            raise ValueError(f"osd_objectstore: {kind!r} is neither "
+                             f"'memstore' nor 'bluestore'")
+        root = self.data_dir or self._own_data_dir
+        if root is None and kind == "bluestore":
+            root = self._own_data_dir = tempfile.mkdtemp(
+                prefix="ceph_tpu-osd-data-",
+                dir=self.conf.get("osd_data") or tempfile.gettempdir())
+        if root is not None:
+            return BlueStore(f"{root}/osd.{n}", self.conf)
+        # capacity seeding (the fullness plane's byte ceiling): BlueStore
+        # reads osd_store_capacity_bytes from the conf itself; the RAM
+        # store gets it passed explicitly.  0 = unlimited (default).
+        return MemStore(
+            capacity_bytes=int(
+                self.conf.get("osd_store_capacity_bytes", 0) or 0),
+            failsafe_ratio=float(
+                self.conf.get("osd_failsafe_full_ratio", 0.97) or 0.97))
+
+    async def _start(self) -> None:
         tracing.install_loop_meter()
         if self.n_mons == 1:
             mon = Monitor(self.conf,
@@ -135,22 +177,33 @@ class Cluster:
             raise TimeoutError("mon quorum did not form")
 
     async def add_osd(self) -> OSD:
-        # capacity seeding (the fullness plane's byte ceiling): BlueStore
-        # reads osd_store_capacity_bytes from the conf itself; the RAM
-        # store gets it passed explicitly.  0 = unlimited (default).
-        capacity = int(self.conf.get("osd_store_capacity_bytes", 0) or 0)
-        failsafe = float(self.conf.get("osd_failsafe_full_ratio", 0.97)
-                         or 0.97)
-        store = (
-            BlueStore(f"{self.data_dir}/osd.{self._next_store}", self.conf)
-            if self.data_dir
-            else MemStore(capacity_bytes=capacity, failsafe_ratio=failsafe)
-        )
+        store = self._osd_store(self._next_store)
         self._next_store += 1
         osd = OSD(self.mon_addrs, store=store, conf=self.conf)
         osd_id = await osd.start()
         self.osds[osd_id] = osd
         return osd
+
+    async def restart_osds(self, osd_ids=None) -> None:
+        """Kill these OSDs (all of them unless told) together, as a power
+        cut does, and start one with each id on the directory its store
+        left: no flush, no goodbye to the store (what was synced is what
+        there is), the new store replays it."""
+        ids = sorted(self.osds) if osd_ids is None else sorted(osd_ids)
+        killed = {osd_id: self.osds.pop(osd_id) for osd_id in ids}
+        # in one instant: none of them outlives another to report it
+        for old in killed.values():
+            old.halt()
+        paths = {}
+        for osd_id, old in killed.items():
+            paths[osd_id] = old.store.path
+            await old.stop(abandon_store=True)
+        for osd_id in ids:
+            osd = OSD(self.mon_addrs,
+                      store=BlueStore(paths[osd_id], self.conf),
+                      conf=self.conf, osd_id=osd_id)
+            await osd.start()
+            self.osds[osd_id] = osd
 
     async def kill_osd(self, osd_id: int) -> None:
         """Hard-stop an OSD (no goodbye) — the thrasher primitive."""
@@ -173,12 +226,15 @@ class Cluster:
         return c
 
     async def stop(self) -> None:
-        for osd in list(self.osds.values()):
-            await osd.stop()
-        if self.mgr is not None:
-            await self.mgr.stop()
-        for mon in self.mons:
-            await mon.stop()
+        try:
+            for osd in list(self.osds.values()):
+                await osd.stop()
+            if self.mgr is not None:
+                await self.mgr.stop()
+            for mon in self.mons:
+                await mon.stop()
+        finally:
+            self._remove_own_data_dir()
 
 
 def _write_addr_file(path: str, cluster: Cluster, n_osds: int) -> None:

@@ -1061,7 +1061,8 @@ class TestFramer:
 @pytest.mark.parametrize("name, cells, moves", [
     ("rx_copy_share.put",
      ["k8m3.write4m", "k4m2.write4m", "k10m4c.write4m",
-      "k8m3.mixed-small", "k8m3.rbd-randwrite4k"], "put_MBps"),
+      "k8m3.mixed-small", "k8m3.rbd-randwrite4k",
+      "k8m3.write4m-bluestore"], "put_MBps"),
     ("rx_copy_share.get", ["k8m3.randread4m", "k8m3.randread4m-cold"],
      "get_MBps"),
 ])

@@ -634,7 +634,8 @@ class TestExtentCache:
 # -- the benchmark's engagement metrics (ISSUE 34) ---------------------------
 
 WRITE_CELLS = ["k8m3.write4m", "k4m2.write4m", "k10m4c.write4m",
-               "k8m3.mixed-small"]  # puts beside gets and deletes (PR 38)
+               "k8m3.mixed-small",  # puts beside gets and deletes (PR 38)
+               "k8m3.write4m-bluestore"]  # the same puts on a disk store
 READ_CELLS = ["k8m3.randread4m", "k8m3.randread4m-cold"]
 
 
