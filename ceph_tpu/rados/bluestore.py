@@ -13,8 +13,44 @@ the latency path; large writes go to freshly allocated extents first
 the block file is SYNCED, and only then the metadata flips atomically
 (reference _kv_sync_thread: bdev->flush() before
 db->submit_transaction_sync): an onode never names bytes a power cut
-could take.  The order of a commit is block write, block sync, WAL
-write, WAL sync, on_commit; the caller acks after on_commit.
+could take.
+
+A commit has two halves.  PREPARE, on the caller's thread, is what a
+reader needs: the failsafe check, compression, the extents allocated,
+each extent's checksum, the onode into `_onodes` and the KV batch into
+the KV's tables, and a big write's bytes reachable from its key until
+they are on the disk (`_inflight`: a read of an object whose commit is
+under way is served from them, never from an extent not yet written).
+COMMIT is, in this order and one transaction at a time: block write,
+block sync, WAL append, WAL sync, the `commit_under_sync` look; then
+FINISH, on the caller's thread again: `on_commit`, and the extents the
+transaction freed go back to the allocator (only now: until the WAL
+sync the old onode is what a power cut leaves).  The caller acks after
+on_commit.
+
+Which thread commits: a store on a path (`commit_blocks`) has a thread
+of its own, started at the first transaction that comes with an
+`on_commit` from a running event loop.  Such a transaction is prepared,
+queued and the call returns; the thread commits what it is handed in
+the order it was handed, and the loop runs `on_commit` (one
+`call_soon_threadsafe` for however many commits of however many stores
+finished since the loop last looked).  The thread is handed immutable
+bytes only (the chunks, the batch's operations, a copy of the tables
+where a compaction is due): it reads no onode and no table, and holds
+no lock.  That is why the checksum is still made in prepare: the
+onode's record has to be whole when it enters the tables.  Every other
+call (no `on_commit`, no loop, `setattr`, `omap_set`, a deferred flush,
+`synced_lengths`) first waits until the thread has nothing left
+(`_drain`), then commits on its caller as ever: committed when it
+returns, and in the order of the calls.  A commit that fails on the
+thread (the disk, a closed file) fails the store: that transaction and
+the ones queued behind it are not committed, as a power cut would leave
+them, and no `on_commit` of theirs runs; the store's owner is told once,
+on its loop (`on_failure`), and every later call raises.  The OSD answers
+whoever waits for a commit with a refusal and dies (the reference aborts
+the daemon there).  The block file is read and written with positioned
+I/O alone (`SyncedFile.pread` / `pwrite`): a reader on the loop and the
+writer on the thread share no cursor.
 
 Checksums: per-extent, algorithm selected by bluestore_csum_type
 (crc32c default, zlib, none — reference csum_type per blob), verified
@@ -40,11 +76,17 @@ rebuilds its free map from the extent metadata.
 
 from __future__ import annotations
 
+import asyncio
+import logging
 import os
 import pickle
+import queue
 import random
+import threading
 import time
+import weakref
 import zlib
+from collections import deque
 
 from ceph_tpu.common import tracing
 from ceph_tpu.common.perf_counters import PerfCounters, PerfCountersBuilder
@@ -57,6 +99,8 @@ from ceph_tpu.rados.kv import (KeyValueDB, MemDB, SyncedFile, WalDB,
 from ceph_tpu.rados.store import (ENOSPCError,  # noqa: F401 (re-export)
                                   Key, ObjectStore, ShardMeta, Transaction,
                                   unwrap as store_unwrap)
+
+log = logging.getLogger("ceph_tpu.bluestore")
 
 PREFIX_OBJ = "O"  # object metadata (extents, csums, ShardMeta, xattrs)
 PREFIX_DEFERRED = "D"  # deferred write payloads awaiting block flush
@@ -96,6 +140,14 @@ def build_bluestore_perf(name: str = "bluestore") -> PerfCounters:
     b.add_u64_counter("commit_unsynced",
                       "transactions whose on_commit ran ahead of a sync "
                       "that has to cover them: the broken guarantee")
+    b.add_u64_counter("offloop_commits",
+                      "transactions committed on a store's own thread "
+                      "(of txns: the share that left the caller's loop)")
+    b.add_time_avg("commit_queue_wait", "handed to the store's thread -> "
+                                        "the thread takes it")
+    b.add_time_avg("commit_queue_depth",
+                   "transactions of the store not yet finished, as each "
+                   "one handed to its thread found them (sum: of depths)")
     return b.create_perf_counters()
 
 
@@ -225,6 +277,67 @@ class Allocator:
         self.size = max(self.size, off + length)
 
 
+class _Commit:
+    """One prepared transaction on its way to the disk.  What the
+    committing thread reads of it is immutable: `blocks` (extents and
+    the bytes for them), `ops` (the KV batch's operations) and
+    `snapshot` (a copy of the KV's tables where a compaction is due
+    after this record, else None)."""
+
+    __slots__ = ("blocks", "ops", "snapshot", "freed", "deferred",
+                 "inflight", "on_commit", "error", "t_enter", "t_queued",
+                 "waker")
+
+    def __init__(self, on_commit, t_enter: float) -> None:
+        self.blocks: List[Tuple[List[Tuple[int, int]], bytes]] = []
+        self.ops: list = []
+        self.snapshot = None
+        self.freed: List[Tuple[int, int]] = []
+        self.deferred: List[Tuple[Key, "_Onode", bytes]] = []
+        self.inflight: List[Tuple[Key, "_Onode"]] = []
+        self.on_commit = on_commit
+        self.error: Optional[BaseException] = None  # why it never committed
+        self.t_enter = t_enter
+
+
+class _Waker:
+    """An event loop's one wake-up for the commits its stores' threads
+    have finished: a thread that finds it armed adds its store and
+    leaves the loop alone."""
+
+    def __init__(self, loop) -> None:
+        self.loop = loop
+        self.stores: deque = deque()
+        self.armed = False
+
+    def notify(self, store: "BlueStore") -> None:
+        """Any thread: `store` has commits to finish on the loop."""
+        self.stores.append(store)
+        if not self.armed:
+            self.armed = True
+            try:
+                self.loop.call_soon_threadsafe(self.run)
+            except RuntimeError:
+                pass  # the loop is closed: nobody is left to tell
+
+    def run(self) -> None:
+        # disarm BEFORE looking: a store added after the last look arms
+        # a wake-up of its own
+        self.armed = False
+        while self.stores:
+            self.stores.popleft()._finish_done()
+
+
+_WAKERS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+
+
+def _waker_of(loop) -> _Waker:
+    waker = _WAKERS.get(loop)
+    if waker is None:
+        waker = _WAKERS[loop] = _Waker(loop)
+    return waker
+
+
 class BlueStore(ObjectStore):
     perf = BS_PERF
 
@@ -241,7 +354,8 @@ class BlueStore(ObjectStore):
             self._block_path = os.path.join(path, "block")
             if not os.path.exists(self._block_path):
                 open(self._block_path, "wb").close()
-            # r+b: positioned writes (a+b would append regardless of seek)
+            # r+b: positioned writes (a+b would append whatever the
+            # offset); read and written through pread / pwrite alone
             self._block = files(self._block_path, "r+b")
         else:
             self.db = db or MemDB()
@@ -265,6 +379,17 @@ class BlueStore(ObjectStore):
         self._deferred_pending: List[Tuple[Key, _Onode, bytes]] = []
         self._deferred_batch_max = 16
         self._block_dirty = False  # extents written since the last sync
+        # the commit pipeline (module docstring).  `_inflight`: big
+        # writes whose bytes may not be in their extents yet, by key
+        self.commit_blocks = path is not None
+        self.failed: Optional[BaseException] = None
+        self._failure_told = False
+        self._inflight: Dict[Key, Tuple[_Onode, bytes]] = {}
+        self._thread: Optional[threading.Thread] = None
+        self._queue: "queue.SimpleQueue" = queue.SimpleQueue()
+        self._done: deque = deque()  # committed, to finish on the caller
+        self._unfinished = 0  # handed to the thread and not finished yet
+        self._dropping = False  # abandon: what is queued is not committed
         self._load()
         self._flush_deferred()
 
@@ -308,8 +433,7 @@ class BlueStore(ObjectStore):
             for off, length in extents:
                 piece = data[pos:pos + length]
                 if self._block is not None:
-                    self._block.seek(off)
-                    self._block.write(piece)
+                    self._block.pwrite(off, piece)
                 else:
                     self._blob[off] = piece
                 pos += length
@@ -331,8 +455,7 @@ class BlueStore(ObjectStore):
         with tracing.section("store", "bs_read"):
             for off, length in extents:
                 if self._block is not None:
-                    self._block.seek(off)
-                    out.append(self._block.read(length))
+                    out.append(self._block.pread(off, length))
                 else:
                     out.append(self._blob.get(off, b"")[:length])
         return b"".join(out)
@@ -342,9 +465,53 @@ class BlueStore(ObjectStore):
     def queue_transaction(self, txn: Transaction,
                           on_commit: Optional[Callable[[], None]] = None) -> None:
         """Apply atomically: ONE KV batch is the commit point for every
-        write/delete in the transaction (ObjectStore::queue_transactions
-        with register_on_commit semantics)."""
+        write, delete, omap and xattr change in the transaction
+        (ObjectStore::queue_transactions with register_on_commit
+        semantics).  Committed at return, or at `on_commit` where the
+        store's thread commits it (module docstring; rados/store.py)."""
         t_enter = time.perf_counter()
+        loop = (asyncio._get_running_loop()
+                if on_commit is not None and self.commit_blocks else None)
+        if loop is None:
+            self._drain()
+        self._check_alive()
+        item = self._prepare(txn, on_commit, t_enter,
+                             hand_over=loop is not None)
+        if loop is not None:
+            self._hand_over(item, loop)
+            return
+        try:
+            self._commit(item)
+            if self.db.log_full():
+                self.db.compact()
+            self._finish(item)
+        except BaseException as e:
+            self._fail(e)
+            raise
+
+    def _fail(self, error: BaseException) -> None:
+        """What was prepared is in the tables and may not be on the disk:
+        the store stops where a power cut would have stopped it."""
+        if self.failed is None:
+            self.failed = error
+
+    def _tell_failure(self) -> None:
+        """On the owner's loop, once: commits it waits for will not come
+        (`ObjectStore.on_failure`)."""
+        if self.on_failure is not None and not self._failure_told:
+            self._failure_told = True
+            self.on_failure(self.failed)
+
+    def _check_alive(self) -> None:
+        if self.failed is not None:
+            raise IOError(f"bluestore {self.path}: a commit failed "
+                          f"({self.failed!r}); the store takes no more")
+
+    def _prepare(self, txn: Transaction, on_commit, t_enter: float,
+                 hand_over: bool = False) -> _Commit:
+        """The caller's half of a commit: everything a reader finds, and
+        a `_Commit` that holds what is left to do."""
+        item = _Commit(on_commit, t_enter)
         prefer_deferred = int(self.conf.get("bluestore_prefer_deferred_size",
                                             32768) or 0)
         self._ranged_as_whole(txn)
@@ -357,7 +524,7 @@ class BlueStore(ObjectStore):
                 sum(len(store_unwrap(c)) for _k, c, _m in txn.writes),
                 self.alloc.size - sum(l for _, l in self.alloc.free))
         batch = WriteBatch()
-        freed: List[Tuple[int, int]] = []
+        freed = item.freed
         for key in txn.deletes:
             onode = self._onodes.pop(key, None)
             if onode is not None:
@@ -371,7 +538,7 @@ class BlueStore(ObjectStore):
         for key, keys in txn.omap_rms:
             for k in keys:
                 batch.rm(PREFIX_OMAP + _okey(key), k)
-        deferred_flush: List[Tuple[Key, _Onode, bytes]] = []
+        touched: Dict[Key, _Onode] = {}  # onodes whose record the batch sets
         for key, chunk, meta in txn.writes:
             chunk = store_unwrap(chunk)  # disk store copies to media anyway
             old = self._onodes.get(key)
@@ -437,43 +604,165 @@ class BlueStore(ObjectStore):
                     chunk = bytes(chunk)
                 onode.deferred = True
                 batch.set(PREFIX_DEFERRED, _okey(key), chunk)
-                deferred_flush.append((key, onode, chunk))
+                item.deferred.append((key, onode, chunk))
                 self.perf.inc("deferred_writes")
                 self.perf.inc("deferred_bytes", len(chunk))
             else:
-                # large write: data to fresh extents BEFORE commit (COW)
-                self._write_extents(onode.extents, chunk)
+                # large write: data to fresh extents BEFORE commit (COW);
+                # until it is there a read finds it here
+                item.blocks.append((onode.extents, chunk))
+                item.inflight.append((key, onode))
+                self._inflight[key] = (onode, chunk)
                 self.perf.inc("big_writes")
             self._onodes[key] = onode
+            touched[key] = onode
+        for key, name, value in txn.xattr_sets:
+            onode = self._onodes.get(key)
+            if onode is None:
+                onode = self._onodes[key] = _Onode()
+            onode.xattrs[name] = value
+            touched[key] = onode
+        for key, onode in touched.items():
             batch.set(PREFIX_OBJ, _okey(key), pickle.dumps(onode, protocol=5))
-        # the extents' bytes onto the disk before the batch that names
-        # them: a power cut between the two leaves the old object
+        item.ops = batch.ops
+        self.db.apply(batch)
+        if hand_over and self.db.log_full():
+            item.snapshot = self.db.snapshot()
+        return item
+
+    def _commit(self, item: _Commit) -> None:
+        """The disk's half, on the store's thread or, with nothing left
+        there, on the caller: the extents' bytes onto the disk before the
+        batch that names them (a power cut between the two leaves the old
+        object), then the batch: THE commit point."""
+        for extents, chunk in item.blocks:
+            self._write_extents(extents, chunk)
         self._sync_block()
         wal_seq = getattr(self.db, "wal_seq", None)  # a RAM KV has none
-        self.db.submit(batch)  # <- THE commit point
+        self.db.log(item.ops)
         self.perf.inc("txns")
         self.perf.tinc("group_txns", 1)
-        # the guarantee, looked at where the caller is told: no extent of
-        # this store waits for a sync, and the WAL was synced after this
-        # batch was handed over
+        # the guarantee, looked at where the caller is about to be told:
+        # no extent of this store waits for a sync, and the WAL was
+        # synced after this batch was handed over
         self.perf.inc("commit_unsynced" if self._block_dirty
                       or (wal_seq is not None and self.db.wal_seq <= wal_seq)
                       else "commit_under_sync")
-        if on_commit is not None:
-            on_commit()
-        self.perf.tinc("commit_lat", time.perf_counter() - t_enter)
+        if item.snapshot is not None:
+            self.db.compact(item.snapshot)
+
+    def _finish(self, item: _Commit) -> None:
+        """After the commit, on the caller's thread: the caller is told,
+        and what the transaction replaced is let go of."""
+        for key, onode in item.inflight:
+            got = self._inflight.get(key)
+            if got is not None and got[0] is onode:
+                del self._inflight[key]
+        if item.on_commit is not None:
+            item.on_commit()
+        self.perf.tinc("commit_lat", time.perf_counter() - item.t_enter)
         # post-commit: deferred payloads drain in batches so a small write
         # costs ONE fsync on the latency path (the open-time replay covers
         # anything pending at a crash)
-        self._deferred_pending.extend(deferred_flush)
+        self._deferred_pending.extend(item.deferred)
         if len(self._deferred_pending) >= self._deferred_batch_max:
             self.flush_deferred_batch()
-        for off, length in freed:
+        for off, length in item.freed:
             self.alloc.release(off, length)
+
+    # -- the store's own thread ----------------------------------------------
+
+    def _hand_over(self, item: _Commit, loop) -> None:
+        if self._thread is None:
+            self._thread = threading.Thread(
+                target=self._commit_loop, daemon=True,
+                name=f"bluestore-commit-{os.path.basename(self.path)}")
+            self._thread.start()
+        item.waker = _waker_of(loop)
+        self.perf.tinc("commit_queue_depth", self._unfinished)
+        self._unfinished += 1
+        item.t_queued = time.perf_counter()
+        self._queue.put(item)
+
+    def _commit_loop(self) -> None:
+        """The thread: one transaction at a time, in the order handed."""
+        take = self._queue.get
+        while True:
+            item = take()
+            if item is None:
+                return
+            if isinstance(item, threading.Event):
+                item.set()  # `_drain`: everything before it is done with
+                continue
+            if self._dropping:
+                continue  # as a power cut: neither committed nor told
+            waited = time.perf_counter() - item.t_queued
+            if self.failed is None:
+                try:
+                    self._commit(item)
+                except BaseException as e:
+                    self._fail(e)
+                    log.error("bluestore %s: commit failed, the store "
+                              "takes no more: %r", self.path, e)
+            # a failed store commits nothing more; whoever waits is told
+            item.error = self.failed
+            if item.error is None:
+                self.perf.inc("offloop_commits")
+                self.perf.tinc("commit_queue_wait", waited)
+            self._done.append(item)
+            item.waker.notify(self)
+
+    def _finish_done(self) -> None:
+        """On the caller's thread: whatever the store's thread has
+        committed since the last look is finished, in order; at the first
+        one it could not commit the owner is told (`on_failure`).  A flush
+        that fails or a callback that raises fails the store too; the
+        loop's wake-up goes on to the other stores."""
+        while self._done:
+            item = self._done.popleft()
+            self._unfinished -= 1
+            if item.error is None:
+                try:
+                    self._finish(item)
+                    continue
+                except Exception as e:
+                    # the ones behind it are on the disk all the same:
+                    # their waiters are still told
+                    self._fail(e)
+                    log.exception("bluestore %s: after a commit", self.path)
+            self._tell_failure()
+
+    def _drain(self) -> None:
+        """Wait until the thread has nothing queued or in hand, and finish
+        what it committed: after this the files, the books and `every
+        call that has returned` describe one instant, and the caller may
+        commit on its own thread."""
+        if self._unfinished:
+            if self._thread is not None:
+                reached = threading.Event()
+                self._queue.put(reached)
+                reached.wait()
+            self._finish_done()
+
+    def _stop_thread(self, drop: bool) -> None:
+        """`drop`: what is still queued is neither committed nor told, as
+        at a power cut; the transaction in hand ends as it ends."""
+        thread, self._thread = self._thread, None
+        if thread is not None:
+            self._dropping = drop
+            self._queue.put(None)
+            thread.join()
+        if drop:
+            self._done.clear()
+            self._unfinished = 0
+        else:
+            self._finish_done()
 
     def flush_deferred_batch(self) -> None:
         if not self._deferred_pending:
             return
+        self._drain()
+        self._check_alive()
         pending, self._deferred_pending = self._deferred_pending, []
         b2 = WriteBatch()
         for key, onode, chunk in pending:
@@ -510,7 +799,12 @@ class BlueStore(ObjectStore):
             return None
         if self.conf.get("bluestore_debug_inject_read_err", False):
             raise EIOError(f"injected read error on {key}")
-        if onode.deferred:
+        flying = self._inflight.get(key)
+        if flying is not None and flying[0] is onode:
+            # its commit is under way: the extents may not hold it yet
+            data = flying[1] if isinstance(flying[1], bytes) \
+                else bytes(flying[1])
+        elif onode.deferred:
             data = self.db.get(PREFIX_DEFERRED, _okey(key)) or b""
         else:
             data = self._read_extents(onode.extents)
@@ -566,6 +860,8 @@ class BlueStore(ObjectStore):
     # -- xattrs / omap (HashInfo + PG log substrate) -------------------------
 
     def setattr(self, key: Key, name: str, value: bytes) -> None:
+        self._drain()
+        self._check_alive()
         onode = self._onodes.get(key)
         if onode is None:
             onode = _Onode()
@@ -583,6 +879,8 @@ class BlueStore(ObjectStore):
         onode = self._onodes.get(key)
         if onode is None or name not in onode.xattrs:
             return
+        self._drain()
+        self._check_alive()
         del onode.xattrs[name]
         batch = WriteBatch()
         batch.set(PREFIX_OBJ, _okey(key), pickle.dumps(onode, protocol=5))
@@ -593,6 +891,8 @@ class BlueStore(ObjectStore):
         return dict(onode.xattrs) if onode else {}
 
     def omap_set(self, key: Key, entries: Dict[str, bytes]) -> None:
+        self._drain()
+        self._check_alive()
         batch = WriteBatch()
         for k, v in entries.items():
             batch.set(PREFIX_OMAP + _okey(key), k, v)
@@ -602,6 +902,8 @@ class BlueStore(ObjectStore):
         return dict(self.db.iterate(PREFIX_OMAP + _okey(key)))
 
     def omap_rm(self, key: Key, keys: List[str]) -> None:
+        self._drain()
+        self._check_alive()
         batch = WriteBatch()
         for k in keys:
             batch.rm(PREFIX_OMAP + _okey(key), k)
@@ -623,7 +925,11 @@ class BlueStore(ObjectStore):
     def synced_lengths(self) -> Dict[str, int]:
         """Bytes of each of the store's files that a sync has covered, by
         path under `self.path`: a copy of the directory cut to them is
-        what a power cut now would leave."""
+        what a power cut now would leave.  The store's thread is waited
+        for first: the lengths are those of an instant at which every
+        call that has returned, with a callback or without, is on the
+        disk."""
+        self._drain()
         out = {"block": self._block.synced} if self._block is not None \
             else {}
         lengths = getattr(self.db, "synced_lengths", None)
@@ -632,13 +938,25 @@ class BlueStore(ObjectStore):
         return out
 
     def close(self) -> None:
-        self.flush_deferred_batch()
-        self.abandon()
+        """Everything handed over is committed and told, the deferred
+        payloads are flushed, the thread ends; then the files close."""
+        self._drain()
+        if self.failed is None:
+            self.flush_deferred_batch()
+        self._stop_thread(drop=False)
+        self._close_files()
 
     def abandon(self) -> None:
         """Let go of the files as they are, as a killed daemon does:
         deferred payloads that were not flushed stay in the WAL for the
-        next open to replay."""
+        next open to replay.  The thread takes no more work: the commit
+        it has in hand ends as it ends, what is queued behind it is
+        dropped as a power cut would drop it (no callback runs), the
+        thread is joined, and only then are the files closed."""
+        self._stop_thread(drop=True)
+        self._close_files()
+
+    def _close_files(self) -> None:
         self.db.close()
         if self._block is not None:
             self._block.close()

@@ -12,6 +12,12 @@ writes a full snapshot and truncates the log.
 
 Every file of a store is a `SyncedFile`: it knows how many of its bytes
 a sync has covered, which is what a power cut leaves of it.
+
+A batch has two halves, and a store that commits on a thread of its own
+(BlueStore on a path) calls them apart: `apply` puts it into the tables,
+where `get` and `iterate` find it, and is the SUBMITTING thread's;
+`log` makes it durable, and is the committing thread's, which sees
+nothing but the batch's own bytes.  `submit` is both on one thread.
 """
 
 from __future__ import annotations
@@ -52,6 +58,28 @@ class SyncedFile:
 
     def flush(self) -> None:
         self.f.flush()
+
+    def pwrite(self, off: int, data) -> None:
+        """`data` at `off`, whatever any other thread reads or writes of
+        the file meanwhile: the descriptor's cursor is neither read nor
+        moved (a file written here is not written through `write`)."""
+        fd, view = self.f.fileno(), memoryview(data).cast("B")
+        done = 0
+        while done < len(view):
+            done += os.pwrite(fd, view[done:], off + done)
+        self.end = max(self.end, off + done)
+
+    def pread(self, off: int, n: int) -> bytes:
+        """Up to `n` bytes from `off`, short where the file ends."""
+        fd, out = self.f.fileno(), []
+        while n > 0:
+            piece = os.pread(fd, n, off)
+            if not piece:
+                break
+            out.append(piece)
+            off += len(piece)
+            n -= len(piece)
+        return out[0] if len(out) == 1 else b"".join(out)
 
     def sync(self, data_only: bool = False) -> None:
         self.f.flush()
@@ -100,7 +128,21 @@ class WriteBatch:
 
 class KeyValueDB:
     def submit(self, batch: WriteBatch) -> None:
+        """`log` then `apply`, on the caller's thread."""
         raise NotImplementedError
+
+    def apply(self, batch: WriteBatch) -> None:
+        """The batch into the tables `get` and `iterate` read."""
+        raise NotImplementedError
+
+    def log(self, ops: list) -> None:
+        """A batch's operations made durable (a RAM table has nothing to
+        do)."""
+
+    def log_full(self) -> bool:
+        """True when a commit should end in `compact` (of a `snapshot`,
+        where another thread commits): never, without a log."""
+        return False
 
     def get(self, prefix: str, key: str) -> Optional[bytes]:
         raise NotImplementedError
@@ -126,6 +168,8 @@ class MemDB(KeyValueDB):
             elif op == "rmpfx":
                 table.clear()
 
+    apply = _apply
+
     def submit(self, batch: WriteBatch) -> None:
         self._apply(batch)
 
@@ -150,6 +194,7 @@ class WalDB(MemDB):
         self.perf = perf
         self._files = files
         self.wal_seq = 0  # the number of the log's last sync
+        self._snapshot_out = False  # a `snapshot` waits for its `compact`
         os.makedirs(path, exist_ok=True)
         self._snap_path = os.path.join(path, "snapshot.db")
         self._log_path = os.path.join(path, "wal.log")
@@ -193,33 +238,59 @@ class WalDB(MemDB):
 
     # -- commits -------------------------------------------------------------
 
-    def submit(self, batch: WriteBatch) -> None:
+    def log(self, ops: list) -> None:
+        """One record appended and synced.  Touches the log file and
+        nothing else of this object, so the thread that commits may
+        call it while another reads the tables."""
         with tracing.section("store", "bs_wal_submit"):
-            blob = pickle.dumps(batch.ops, protocol=5)
+            blob = pickle.dumps(ops, protocol=5)
             self._log.write(_REC.pack(len(blob), checksum(blob)) + blob)
         timed_sync(self._log, self.perf, "bs_wal_sync")
         self.wal_seq += 1
         if self.perf is not None:
             self.perf.inc("wal_bytes", _REC.size + len(blob))
             self.perf.inc("wal_syncs")
+
+    def submit(self, batch: WriteBatch) -> None:
+        self.log(batch.ops)
         self._apply(batch)
-        if self._log.end >= self.compact_bytes:
+        if self.log_full():
             self.compact()
 
-    def compact(self) -> None:
-        """The whole table set to a new snapshot, then an empty log.  On
-        the caller's thread: every key the store holds is pickled there,
-        and `compact_s` says for how long."""
+    def log_full(self) -> bool:
+        return self._log.end >= self.compact_bytes \
+            and not self._snapshot_out
+
+    def snapshot(self) -> Dict[str, Dict[str, bytes]]:
+        """The tables as they are now, for a `compact` on another thread:
+        a copy of each dict (the values are bytes nobody changes), so the
+        thread pickles what no batch applied later can touch.  Whoever
+        takes one owes the `compact`; until then `log_full` is False."""
+        self._snapshot_out = True
+        return {prefix: dict(table)
+                for prefix, table in self._tables.items()}
+
+    def compact(self, tables=None) -> None:
+        """The whole table set to a new snapshot, then an empty log, on
+        the thread that commits: every key the store holds is pickled
+        there, and `compact_s` says for how long.  Where that thread is
+        not the one that applies batches, the applying thread hands it a
+        consistent copy (`snapshot`, taken right after the batch whose
+        record is the log's last when this runs: the committing thread
+        takes its work in order); no lock is shared.  Without `tables`
+        the caller is the applying thread itself."""
         t0 = time.perf_counter()
         with tracing.section("store", "bs_compact"):
             tmp = self._snap_path + ".tmp"
             snap = self._files(tmp, "wb")
-            pickle.dump(self._tables, snap, protocol=5)
+            pickle.dump(self._tables if tables is None else tables, snap,
+                        protocol=5)
             timed_sync(snap, self.perf, "bs_wal_sync")
             snap.close()
             self._files.replace(tmp, self._snap_path)
             self._log.close()
             self._log = self._files(self._log_path, "wb")
+        self._snapshot_out = False
         if self.perf is not None:
             self.perf.inc("compactions")
             self.perf.tinc("compact_s", time.perf_counter() - t0)

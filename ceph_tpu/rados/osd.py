@@ -44,6 +44,7 @@ import time
 import uuid
 import zlib
 from collections import deque
+from functools import partial
 from typing import Any, Dict, List, Optional, Set, Tuple
 
 import numpy as np
@@ -179,6 +180,7 @@ PGMETA_PREFIX = "__pgmeta_"  # per-PG metadata object carrying the PG log
 # version of the object
 PREV_SLOT = 1 << 20
 
+
 # ONE stripe-batching queue per process, shared by every OSD instance in
 # it: the device is a process-level resource, and cross-daemon coalescing
 # (a vstart cluster runs many OSDs in one process) only helps — more
@@ -262,6 +264,10 @@ class OSD:
         # one mon addr or a monmap list; RPCs rotate on mon failure
         self.mons = MonTargets(mon_addr)
         self.store = store or MemStore()
+        # shard writes that wait for the store's `on_commit`
+        # (`_commit_shard`; none on a store that does not block)
+        self._shard_commits: Set["asyncio.Future"] = set()
+        self.store.on_failure = self._store_failed
         self.osd_id = osd_id
         self.messenger = Messenger(f"osd.{osd_id}", self.conf, entity_type="osd")
         self.osdmap: Optional[OSDMap] = None
@@ -3385,6 +3391,7 @@ class OSD:
             entry_blob = entry.encode()
             tid = uuid.uuid4().hex
             local_ok = 0
+            local_commits: list = []
             wb_shards: set = set()
             if chunk_off < 0 and planar is None and self._planar is not None:
                 # gated / ineligible / empty full write: it supersedes any
@@ -3430,7 +3437,7 @@ class OSD:
                         # memoryview, not bytes(): ownership of the fresh
                         # encode-output row passes to the store (Owned
                         # marking in _apply_shard_write) — no per-shard copy
-                        if self._apply_shard_write(
+                        applied = self._apply_shard_write(
                             op.pool_id, op.oid, shard,
                             memoryview(np.ascontiguousarray(blobs[shard])),
                             version,
@@ -3440,8 +3447,14 @@ class OSD:
                             prior_version=base_version,
                             chunk_crc=(shard_crcs[shard]
                                        if shard_crcs is not None else None),
-                        ):
+                            defer=True,
+                        )
+                        if applied is True:
                             local_ok += 1
+                        elif applied:
+                            # on the store's own thread, while the
+                            # sub-writes travel: counted after the gather
+                            local_commits.append(applied)
                 else:
                     remote.append((shard, osd))
             q = self._collector(tid)
@@ -3481,6 +3494,8 @@ class OSD:
         mark("sub_writes_sent")
         mark("waiting_for_subops")
         replies = await self._gather(tid, q, sent)
+        for applied in local_commits:
+            local_ok += await applied
         with tracing.section("osd", "write_finish"):
             span.event("commit gathered")
             mark("commit_gathered")
@@ -4761,8 +4776,12 @@ class OSD:
         object_size: int, pg: Optional[int] = None,
         entry: Optional[LogEntry] = None, chunk_off: int = -1,
         shard_size: int = 0, hinfo: bytes = b"", prior_version: int = 0,
-        chunk_crc: Optional[int] = None,
-    ) -> bool:
+        chunk_crc: Optional[int] = None, defer: bool = False,
+    ):
+        """The shard, its log entry and its hinfo record as ONE store
+        transaction.  Returns False (refused), True (committed) or, for
+        a caller that can wait (`defer`) on a store whose commit blocks,
+        a future that becomes True once it is: `_commit_shard`."""
         # failsafe FIRST — before the rollback-slot read, the in-memory
         # PG-log append, and the store transaction: a refused write must
         # leave both the store AND the in-memory log byte-identical
@@ -4774,7 +4793,7 @@ class OSD:
         if chunk_off >= 0:
             return self._apply_shard_splice(
                 (pool_id, oid, shard), chunk, version, object_size, pg,
-                entry, chunk_off, shard_size, prior_version)
+                entry, chunk_off, shard_size, prior_version, defer)
         txn = Transaction()
         # retain the outgoing version in the rollback slot (same txn):
         # reads fall back to it when a newer write never completed
@@ -4806,15 +4825,56 @@ class OSD:
         if entry is not None and pg is not None:
             self._log_in_txn(txn, pool_id, pg, entry)
         with tracing.section("store", "commit"):
+            self._hinfo_in_txn(txn, pool_id, oid, shard, len(blob), crc,
+                               hinfo, chunk_off)
+            return self._commit_shard(txn, defer)
+
+    def _commit_shard(self, txn: Transaction, defer: bool):
+        """A shard write's transaction to the store.  True: committed.
+        A store whose commit blocks (what the store says of itself:
+        `commit_blocks`) commits on a thread of its own for a caller who
+        can wait (`defer`: the sub-write handler, the primary's own
+        shard): then a future of this loop that becomes True at the
+        store's `on_commit`, and nothing is acknowledged before it.  A
+        store that commits in microseconds is called as ever: no future,
+        no callback, no step of the loop."""
+        if not (defer and self.store.commit_blocks):
             self.store.queue_transaction(txn)
-            self._update_hinfo(pool_id, oid, shard, len(blob), crc, hinfo,
-                               chunk_off)
-        return True
+            return True
+        done = asyncio.get_running_loop().create_future()
+        self.store.queue_transaction(
+            txn, on_commit=partial(self._shard_committed, done))
+        if done.done():
+            return True
+        self._shard_commits.add(done)
+        return done
+
+    def _shard_committed(self, done: "asyncio.Future") -> None:
+        """The store's `on_commit` for a shard write that waits for it;
+        the waiter may have been cancelled since."""
+        self._shard_commits.discard(done)
+        if not done.done():
+            done.set_result(True)
+
+    def _store_failed(self, why: BaseException) -> None:
+        """The store's `on_failure`: its thread could not commit, and the
+        shard writes that wait for it never will be.  Each waiter gets a
+        refusal (one ack fewer at the primary, never a hang), and a
+        daemon whose disk failed dies as on any fatal error (the
+        reference aborts in _kv_sync_thread)."""
+        waiting, self._shard_commits = self._shard_commits, set()
+        for done in waiting:
+            if not done.done():
+                done.set_result(False)
+        if not self._stopped and self._fatal_task is None:
+            self._fatal_task = asyncio.get_running_loop().create_task(
+                self._on_fatal(why))
 
     def _apply_shard_splice(self, key, chunk, version: int,
                             object_size: int, pg: Optional[int],
                             entry: Optional[LogEntry], chunk_off: int,
-                            shard_size: int, prior_version: int) -> bool:
+                            shard_size: int, prior_version: int,
+                            defer: bool = False):
         """One stripe's chunk into the stored shard at `chunk_off` (the
         per-stripe RMW): a write at an offset in the store, the shard's
         crc made from the crc it had and the bytes that changed, the
@@ -4869,15 +4929,17 @@ class OSD:
         if entry is not None and pg is not None:
             self._log_in_txn(txn, key[0], pg, entry)
         with tracing.section("store", "commit"):
-            self.store.queue_transaction(txn)
-            self._update_hinfo(*key, new_size, crc, b"", chunk_off)
+            self._hinfo_in_txn(txn, *key, new_size, crc, b"", chunk_off)
+            done = self._commit_shard(txn, defer)
+        # `txn.copied` is the store's answer at the call's return, also
+        # where its commit comes later
         self.perf.inc("splice_in_place" if delta and not txn.copied
                       else "splice_rebuilt")
         # the extent out (here for the crc, in the store for the slot)
         # and in, and whatever whole shards the store had to copy
         self.perf.inc("splice_copied_bytes", txn.copied + 3 * len(chunk))
         self.perf.inc("splice_crc_bytes", crc_bytes)
-        return True
+        return done
 
     def _splice_crc_whole(self, key, size: int, new_size: int, off: int,
                           chunk) -> int:
@@ -4895,44 +4957,50 @@ class OSD:
             crc = checksum(bytes(new_size - max(end, size)), crc)
         return crc & 0xFFFFFFFF
 
-    def _update_hinfo(self, pool_id: int, oid: str, shard: int, size: int,
-                      crc: int, hinfo: bytes, chunk_off: int) -> None:
-        """Maintain the hinfo_key xattr (cumulative shard crcs, reference
-        ECUtil.h:101-160): full writes store the primary-computed record;
-        splices refresh our OWN entry with the crc the shard's meta just
-        got (`size` bytes, `crc`: never a second pass over them) and mark
-        the record dirty (other entries went stale)."""
+    def _hinfo_in_txn(self, txn: Transaction, pool_id: int, oid: str,
+                      shard: int, size: int, crc: int, hinfo: bytes,
+                      chunk_off: int) -> None:
+        """The hinfo_key xattr (cumulative shard crcs, reference
+        ECUtil.h:101-160) set in the shard write's own transaction, as
+        the reference's ECTransaction does: a power cut leaves the shard
+        with its record or neither.  Full writes store the
+        primary-computed record; splices refresh our OWN entry with the
+        crc the shard's meta just got (`size` bytes, `crc`: never a
+        second pass over them) and mark the record dirty (other entries
+        went stale)."""
         pool = self.osdmap.pools.get(pool_id) if self.osdmap else None
         if pool is not None and pool.pool_type != "ec":
             return  # replicated pools carry no hinfo; skip the xattr I/O
         key = (pool_id, oid, shard)
-        try:
-            if chunk_off < 0 and hinfo:
-                self.store.setattr(key, HashInfo.XATTR_KEY, hinfo)
-                return
-            # a splice, or a full-blob write without a primary-computed
-            # record (e.g. a sub-chunk recovery push whose helper record
-            # was dirty): an existing record is now stale for this
-            # shard — refresh our own entry and mark it dirty so scrub
-            # trusts the self crc and skips the cross-shard comparison,
-            # instead of flagging fresh data as bad
-            raw = self.store.getattr(key, HashInfo.XATTR_KEY)
-            if raw is None:
-                return
-            h = HashInfo.decode(raw)
-            if shard >= len(h.crcs):
-                return
-            h.crcs[shard] = crc
-            h.total_chunk_size = size
-            h.dirty = True
-            self.store.setattr(key, HashInfo.XATTR_KEY, h.encode())
-        except NotImplementedError:
-            pass  # store without xattr support
+        if chunk_off < 0 and hinfo:
+            txn.setattr(key, HashInfo.XATTR_KEY, hinfo)
+            return
+        # a splice, or a full-blob write without a primary-computed
+        # record (e.g. a sub-chunk recovery push whose helper record
+        # was dirty): an existing record is now stale for this
+        # shard — refresh our own entry and mark it dirty so scrub
+        # trusts the self crc and skips the cross-shard comparison,
+        # instead of flagging fresh data as bad
+        raw = self.store.getattr(key, HashInfo.XATTR_KEY)
+        if raw is None:
+            return
+        h = HashInfo.decode(raw)
+        if shard >= len(h.crcs):
+            return
+        h.crcs[shard] = crc
+        h.total_chunk_size = size
+        h.dirty = True
+        txn.setattr(key, HashInfo.XATTR_KEY, h.encode())
 
-    async def _apply_sub_write(self, msg: MECSubWrite) -> MECSubWriteReply:
-        """Validate + apply one sub-write; the reply is the CALLER's to
-        send (the group path batches a whole run of them so the replies
-        coalesce into one flush window on the primary's connection)."""
+    async def _apply_sub_write(self, msg: MECSubWrite):
+        """Validate + apply one sub-write.  Returns the reply, which is
+        the CALLER's to send (the group path batches a whole run of them
+        so the replies coalesce into one flush window on the primary's
+        connection), or, where the store commits on a thread of its own,
+        the coroutine that waits for that commit and then gives the
+        reply: the caller applies what else it has first, so the store's
+        thread finds the next transaction when it is done with this
+        one."""
         # every sub-write is a first-class tracked op with a span that
         # joins the primary's propagated `ec write` context — this is
         # the peer leg of the client->primary->k+m stitched trace
@@ -4991,7 +5059,7 @@ class OSD:
                             hinfo=msg.hinfo, prior_version=msg.prior_version,
                             # just verified against the frame: reuse, don't
                             # re-crc
-                            chunk_crc=msg.chunk_crc or None,
+                            chunk_crc=msg.chunk_crc or None, defer=True,
                         )
                     except ENOSPCError:
                         # this shard's store is failsafe-full: refuse (one
@@ -5025,17 +5093,38 @@ class OSD:
                                        else "refused_splice")
                     if ok:
                         self.perf.inc("subop_w")
+        except BaseException:
+            self._sub_write_reply(msg, False, span, tracked)
+            raise
+        if ok is True or ok is False:
+            return self._sub_write_reply(msg, ok, span, tracked)
+        # a store whose commit blocks has it on its own thread: the reply
+        # is built after its on_commit, not before
+        return self._sub_write_committed(msg, ok, span, tracked)
+
+    async def _sub_write_committed(self, msg: MECSubWrite, done, span,
+                                   tracked) -> MECSubWriteReply:
+        ok = False
+        try:
+            ok = await done
         finally:
-            if span is not None:
-                span.tag("ok", ok)
-                span.finish()
-            tracked.finish()
+            reply = self._sub_write_reply(msg, ok, span, tracked)
+        return reply
+
+    def _sub_write_reply(self, msg: MECSubWrite, ok: bool, span,
+                         tracked) -> MECSubWriteReply:
+        if span is not None:
+            span.tag("ok", ok)
+            span.finish()
+        tracked.finish()
         return MECSubWriteReply(tid=msg.tid, shard=msg.shard, ok=ok,
-                                trace_id=t_tid,
+                                trace_id=getattr(msg, "trace_id", ""),
                                 span_id=getattr(msg, "span_id", ""))
 
     async def _handle_sub_write(self, msg: MECSubWrite) -> None:
         reply = await self._apply_sub_write(msg)
+        if not isinstance(reply, MECSubWriteReply):
+            reply = await reply
         try:
             await self.messenger.send(tuple(msg.reply_to), reply)
         except TRANSPORT_ERRORS:
@@ -5047,9 +5136,20 @@ class OSD:
         same primary land in the same outbox flush window (one writev +
         one piggybacked ack instead of a write+drain per sub-write)."""
         replies = []
-        for msg in msgs:
-            replies.append((tuple(msg.reply_to),
-                            await self._apply_sub_write(msg)))
+        try:
+            for msg in msgs:
+                replies.append((tuple(msg.reply_to),
+                                await self._apply_sub_write(msg)))
+            # every one is applied and handed over; now wait, in that
+            # order, for those a store's thread is still committing
+            for i, (addr, reply) in enumerate(replies):
+                if not isinstance(reply, MECSubWriteReply):
+                    replies[i] = (addr, await reply)
+        except BaseException:
+            for _addr, reply in replies:  # nobody waits for these now
+                if not isinstance(reply, MECSubWriteReply):
+                    reply.close()
+            raise
 
         async def _send_one(addr, reply):
             try:

@@ -6,6 +6,40 @@ src/os/ObjectStore.h:229 queue_transactions): atomic transactions over
 the RAM store the reference also ships for testing (src/os/memstore/);
 DirStore persists shards as files (a minimal filestore) so OSD restart
 tests survive process death.
+
+`queue_transaction(txn, on_commit)`, the contract every store keeps:
+
+    without `on_commit`   the transaction is committed when the call
+                          returns: applied, and on a disk store on the
+                          disk.  Whoever goes on to acknowledge after
+                          the call relies on that.
+    with `on_commit`      it is APPLIED when the call returns (a read, a
+                          stat, an omap_get find it) and COMMITTED when
+                          `on_commit()` runs, on the caller's thread: for
+                          a store whose commit does not block, before the
+                          call returns; for one whose commit does
+                          (`commit_blocks`: a BlueStore on a path) and
+                          whose caller runs an event loop, later, from
+                          that loop, after the store's own thread has
+                          made it durable.  Commits are made in the order
+                          of the calls, whatever their kind; nothing is
+                          acknowledged before its callback.  Where that
+                          thread cannot commit (the disk fails under it)
+                          the store takes no more: no `on_commit` of that
+                          transaction or of one queued behind it runs, and
+                          `on_failure(why)`, which the store's owner sets,
+                          runs once on that loop: whoever waits for a
+                          callback of this store stops waiting there.
+
+`commit_blocks` is what a store says of itself, and the one thing the OSD
+reads to decide whether a shard write waits for a callback or for the
+call (rados/osd.py `_commit_shard`): MemStore False, BlueStore on a path
+True, in RAM False.
+
+A transaction carries xattr sets too (`Transaction.setattr`; the
+reference's ECTransaction sets hinfo_key in the shard's own
+ObjectStore::Transaction): applied after its writes, so a shard and its
+hinfo record are one commit, and a power cut leaves both or neither.
 """
 
 from __future__ import annotations
@@ -15,7 +49,7 @@ import json
 import os
 import zlib
 from dataclasses import dataclass, field
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
 Key = Tuple[int, str, int]  # (pool_id, oid, shard)
 
@@ -71,6 +105,8 @@ class Transaction:
     deletes: List[Key] = field(default_factory=list)
     omap_sets: List[Tuple[Key, Dict[str, bytes]]] = field(default_factory=list)
     omap_rms: List[Tuple[Key, List[str]]] = field(default_factory=list)
+    # xattrs set after the writes: (key, name, value)
+    xattr_sets: List[Tuple[Key, str, bytes]] = field(default_factory=list)
     # the store's answer, set when it applies `ranged`: bytes of whole
     # objects it had to copy for them (0: every one landed in place)
     copied: int = 0
@@ -99,8 +135,22 @@ class Transaction:
     def omap_rm(self, key: Key, keys: List[str]) -> None:
         self.omap_rms.append((key, list(keys)))
 
+    def setattr(self, key: Key, name: str, value: bytes) -> None:
+        """An xattr of the object at `key`, set with the transaction's
+        writes (reference ObjectStore::Transaction::setattr); a store
+        with no xattrs leaves it out, as its `setattr` refuses."""
+        self.xattr_sets.append((key, name, value))
+
 
 class ObjectStore:
+    # True where a commit waits for a disk: such a store commits on a
+    # thread of its own for a caller that passes `on_commit` from an
+    # event loop (module docstring)
+    commit_blocks: bool = False
+    # set by the owner of a store whose commit blocks: called once, on
+    # the owner's loop, with the error that failed the store's thread
+    on_failure: Optional[Callable[[BaseException], None]] = None
+
     # byte ceiling (0 = unlimited) + the last-resort guard protecting the
     # store itself (reference osd_failsafe_full_ratio): a transaction
     # whose writes would push used bytes past failsafe_ratio * capacity
@@ -328,6 +378,8 @@ class MemStore(ObjectStore):
             if table:
                 for k in keys:
                     table.pop(k, None)
+        for key, name, value in txn.xattr_sets:
+            self._xattrs.setdefault(key, {})[name] = value
         if on_commit is not None:
             on_commit()
 

@@ -10,8 +10,11 @@ absent.  The same stream on a store that does not sync its block file
 before the KV batch (the order before PR 46) has to fail the case.
 """
 
+import asyncio
 import os
 import shutil
+import threading
+from functools import partial
 
 import numpy as np
 import pytest
@@ -82,6 +85,10 @@ class _File(SyncedFile):
         self.fs.tick("write", self.path)
         super().write(data)
 
+    def pwrite(self, off, data):
+        self.fs.tick("write", self.path)
+        super().pwrite(off, data)
+
     def sync(self, data_only=False):
         self.fs.tick("sync", self.path)
         super().sync(data_only)
@@ -139,11 +146,13 @@ def stream(seed, n=28):
     return out
 
 
-def apply(store, ops):
-    if ops[0][0] == "setattr":
-        return store.setattr(*ops[0][1:])
-    if ops[0][0] == "rmattr":
-        return store.rmattr(*ops[0][1:])
+def apply(store, ops, on_commit=None):
+    """A transaction of one `setattr` or `rmattr` is the store's call of
+    that name (committed when it returns); a `setattr` among other
+    operations is set in their transaction."""
+    if ops[0][0] in ("setattr", "rmattr"):
+        getattr(store, ops[0][0])(*ops[0][1:])
+        return on_commit() if on_commit is not None else None
     txn = Transaction()
     for op in ops:
         kind = op[0]
@@ -156,9 +165,20 @@ def apply(store, ops):
             txn.delete(op[1])
         elif kind == "omap_set":
             txn.omap_set(op[1], op[2])
+        elif kind == "setattr":
+            txn.setattr(*op[1:])
         else:
             txn.omap_rm(op[1], op[2])
-    store.queue_transaction(txn)
+    store.queue_transaction(txn, on_commit)
+
+
+def with_hinfo(txns):
+    """Every write carries the shard's hinfo record in its transaction,
+    as `OSD._apply_shard_write` sends it."""
+    return [ops + [("setattr", op[1], "hinfo_key",
+                    bytes([op[-2 if op[0] == "write_at" else -1][0] % 256])
+                    * 40) for op in ops if op[0] in ("write", "write_at")]
+            for ops in txns]
 
 
 def state_of(store, omap_keys):
@@ -219,6 +239,56 @@ def admissible(state, ref):
     return any(state == s.as_dicts() for s in ref.admissible_after_crash())
 
 
+WAVE = 3  # transactions handed over before the loop waits for them
+
+
+def run_on_the_thread(tmp, txns, cut_at=None, torn=False):
+    """As `run`, from a running loop with callbacks: the store's thread
+    commits, up to WAVE transactions in flight, and the cut falls where
+    the thread is (or on the loop, in a call that commits on its
+    caller).  Returns (events, reopened state, reference, the threads
+    that made events)."""
+    live, left = os.path.join(tmp, "live"), os.path.join(tmp, "left")
+    for d in (live, left):
+        shutil.rmtree(d, ignore_errors=True)
+    fs, ref = CrashFS(live, cut_at), DurableStore()
+    path = os.path.join(live, "osd.0")
+    store = BlueStore(path, dict(CONF), files=fs)
+    threads = set()
+    tick = fs.tick
+    fs.tick = lambda kind, p: (threads.add(threading.current_thread().name),
+                               tick(kind, p))[1]
+
+    async def go():
+        for i, ops in enumerate(txns):
+            # several in flight: the reference's own `submit` allows one
+            ref.log.append(list(ops))
+            try:
+                apply(store, ops, partial(ref.commit_reported, i))
+            except (PowerCut, IOError):
+                return
+            if i % WAVE == WAVE - 1 or i == len(txns) - 1:
+                while ref.reported <= i and store.failed is None:
+                    await asyncio.sleep(0.001)
+            if store.failed is not None:
+                return
+
+    asyncio.run(go())
+    try:
+        store._finish_done()  # what the thread committed before the cut
+    except PowerCut:
+        pass
+    fs.crash(left, torn)
+    store.abandon()
+    assert not [t for t in threading.enumerate()
+                if t.name.startswith("bluestore-commit")]
+    again = BlueStore(os.path.join(left, "osd.0"), dict(CONF))
+    try:
+        return fs.events, state_of(again, omap_keys_of(txns)), ref, threads
+    finally:
+        again.abandon()
+
+
 # -- the cases ---------------------------------------------------------------
 
 @pytest.mark.parametrize("compact", [False, True],
@@ -239,6 +309,50 @@ def test_cut_before_every_event_reopens_to_the_reference(tmp_path, seed,
             f"cut before event {cut} {events[cut - 1]}: the reopened store "
             f"holds neither the {ref.reported} reported transactions nor "
             f"those and the one in flight")
+
+
+@pytest.mark.parametrize("torn", [False, True], ids=["dropped", "torn"])
+@pytest.mark.parametrize("seed", [4801, 4802])
+def test_cut_inside_the_threads_sequence_reopens_to_the_reference(
+        tmp_path, seed, torn):
+    """The store's thread commits, transactions wait behind each other,
+    and the power goes before every write and sync it makes: what is left
+    is the transactions whose callback ran and, at most, the one the
+    thread had in hand, whole; none of those queued behind it."""
+    txns = with_hinfo(stream(seed))
+    events, state, ref, threads = run_on_the_thread(str(tmp_path), txns)
+    assert state == ref.crash().as_dicts() == ref.now().as_dicts()
+    assert "bluestore-commit-osd.0" in threads and "MainThread" in threads
+    assert any("hinfo_key" in attrs for attrs in state[1].values())
+    for cut in range(1, len(events) + 1):
+        _, state, ref, _ = run_on_the_thread(str(tmp_path), txns, cut, torn)
+        assert ref.reported < len(ref.log)
+        assert admissible(state, ref), (
+            f"cut before event {cut} {events[cut - 1]}: the reopened store "
+            f"holds neither the {ref.reported} reported transactions nor "
+            f"those and the one in hand")
+
+
+@pytest.mark.parametrize("cut,point,version", [
+    (5, "before the block write", 1), (6, "before the block sync", 1),
+    (7, "before the WAL write", 1), (8, "before the WAL sync", 1),
+    (9, "after the WAL sync, before anybody is told", 2)])
+def test_a_shard_and_its_hinfo_are_one_commit(tmp_path, cut, point,
+                                              version):
+    """The thread's five stops in a shard write's commit: at each the
+    reopened store has the old shard with the old record or the new
+    shard with the new one."""
+    key = (1, "obj", 0)
+    txns = with_hinfo([big_write(version=1),
+                       big_write(size=30000, version=2),
+                       big_write(key=(1, "next", 0))])
+    events, state, ref, _ = run_on_the_thread(str(tmp_path), txns,
+                                              cut_at=cut)
+    objects, xattrs, _ = state
+    assert objects[key][1][0] == version, point
+    assert xattrs[key] == {"hinfo_key": bytes([version]) * 40}, point
+    assert (1, "next", 0) not in objects
+    assert admissible(state, ref)
 
 
 @pytest.mark.parametrize("seed", [4601, 4602, 4603])
@@ -392,9 +506,7 @@ def test_synced_lengths_are_what_a_sync_covered(tmp_path):
     assert lengths["block"] == os.path.getsize(os.path.join(path, "block"))
     assert lengths["db/wal.log"] == os.path.getsize(
         os.path.join(path, "db", "wal.log"))
-    store._block.seek(lengths["block"])
-    store._block.write(b"unsynced")
-    store._block.flush()
+    store._block.pwrite(lengths["block"], b"unsynced")
     assert store.synced_lengths()["block"] == lengths["block"]
     assert os.path.getsize(os.path.join(path, "block")) \
         == lengths["block"] + 8

@@ -228,3 +228,329 @@ class TestAClusterOnBlueStores:
                 await cluster.stop()
 
         asyncio.run(go())
+
+
+# -- PR 48: the OSD waits for a disk store's callback, and for nothing on
+#    a store that commits in microseconds --------------------------------------
+
+import threading  # noqa: E402
+
+from ceph_tpu.rados.ecutil import HashInfo  # noqa: E402
+from ceph_tpu.rados.osd import OSD  # noqa: E402
+
+
+def _watch_commit_shard(monkeypatch, seen):
+    """Every `OSD._commit_shard` call: what it returned, whether the store
+    was handed a callback, how many futures the loop made inside it."""
+    inner = OSD._commit_shard
+
+    def watched(self, txn, defer):
+        loop = asyncio.get_running_loop()
+        made, create = [], loop.create_future
+        loop.create_future = lambda: (made.append(1), create())[1]
+        queue, callbacks = self.store.queue_transaction, []
+
+        def queue_transaction(txn, on_commit=None):
+            # the signature the benchmark's tap and controls wrap it with
+            callbacks.append(on_commit)
+            return queue(txn, on_commit)
+
+        self.store.queue_transaction = queue_transaction
+        try:
+            out = inner(self, txn, defer)
+        finally:
+            del loop.create_future
+            self.store.queue_transaction = queue
+        seen.append((out, callbacks, len(made), defer,
+                     [x[:2] for x in txn.xattr_sets]))
+        return out
+
+    monkeypatch.setattr(OSD, "_commit_shard", watched)
+
+
+def _bs_counters():
+    dump = BS_PERF.dump()
+    return {k: (v["sum"] if isinstance(v, dict) else v)
+            for k, v in dump.items()}
+
+
+class TestTheOsdWaitsForTheCallbackNotTheCall:
+    def test_a_memstore_shard_write_makes_no_future_and_no_loop_step(
+            self, monkeypatch):
+        seen = []
+        _watch_commit_shard(monkeypatch, seen)
+
+        async def go():
+            cluster = Cluster(n_osds=4, conf=dict(FAST), n_mons=1)
+            await cluster.start()
+            try:
+                client = await cluster.client()
+                pool = await client.create_pool("p", pg_num=8,
+                                                profile=dict(K2M1))
+                before = _bs_counters()
+                for i in range(6):
+                    await client.put(pool, f"obj{i}", os.urandom(40000))
+                assert _bs_counters() == before
+                for osd in cluster.osds.values():
+                    assert osd.store.commit_blocks is False
+                    for oid, shard in osd.store.list_objects(pool):
+                        if shard < 3:
+                            assert HashInfo.decode(osd.store.getattr(
+                                (pool, oid, shard), HashInfo.XATTR_KEY))
+                await client.stop()
+            finally:
+                await cluster.stop()
+
+        asyncio.run(go())
+        served = [s for s in seen if s[3]]
+        assert len(served) >= 18  # 6 puts x (k + m)
+        for out, callbacks, futures, _defer, xattrs in served:
+            # committed at the call's return: nothing to await, no
+            # callback, no future, and the hinfo record inside the one
+            # transaction (no store call of its own)
+            assert out is True and callbacks == [None] and futures == 0
+            assert [name for _key, name in xattrs] == [HashInfo.XATTR_KEY]
+
+    def test_a_bluestore_shard_write_is_acked_after_its_callback(
+            self, tmp_path, monkeypatch):
+        seen = []
+        _watch_commit_shard(monkeypatch, seen)
+
+        async def go():
+            conf = dict(FAST, osd_objectstore="bluestore",
+                        osd_data=str(tmp_path), ms_local_fastpath=False)
+            cluster = Cluster(n_osds=4, conf=conf, n_mons=1)
+            await cluster.start()
+            try:
+                client = await cluster.client()
+                pool = await client.create_pool("p", pg_num=8,
+                                                profile=dict(K2M1))
+                await client.put(pool, "warm", os.urandom(150000))
+                before = _bs_counters()
+                data = {f"obj{i}": os.urandom(150000) for i in range(8)}
+                await asyncio.gather(*[client.put(pool, oid, blob)
+                                       for oid, blob in data.items()])
+                moved = {k: v - before[k]
+                         for k, v in _bs_counters().items()}
+                # 8 puts x 3 shards, each ONE transaction under one block
+                # sync and one WAL sync, all on the stores' threads: the
+                # loop slept in none of them
+                assert moved["txns"] == moved["offloop_commits"] == 24
+                assert moved["block_syncs"] == moved["wal_syncs"] == 24
+                assert moved["commit_under_sync"] == 24
+                assert moved["commit_unsynced"] == 0
+                assert moved["loop_sync_s"] == 0
+                assert moved["commit_queue_wait"] > 0
+                held = 0
+                for osd in cluster.osds.values():
+                    assert osd.store.commit_blocks is True
+                    for oid, shard in osd.store.list_objects(pool):
+                        if oid in data and shard < 3:
+                            held += 1
+                            rec = HashInfo.decode(osd.store.getattr(
+                                (pool, oid, shard), HashInfo.XATTR_KEY))
+                            assert rec.total_chunk_size > 0
+                assert held == 24
+                for oid, blob in data.items():
+                    assert bytes(await client.get(pool, oid)) == blob
+                await client.stop()
+            finally:
+                await cluster.stop()
+
+        asyncio.run(go())
+        served = [s for s in seen if s[3]]
+        assert len(served) >= 27
+        for out, callbacks, futures, _defer, _xattrs in served:
+            # handed over with a callback; what the handler awaited is a
+            # future, made here and nowhere on a MemStore
+            assert isinstance(out, asyncio.Future) and futures == 1
+            assert len(callbacks) == 1 and callbacks[0] is not None
+        assert not [t for t in threading.enumerate()
+                    if t.name.startswith("bluestore-commit")]
+
+    def test_killed_with_commits_in_flight_no_thread_is_left(self, tmp_path):
+        """Writers are running when every OSD is halted and its store
+        abandoned: the kill awaits no commit, no thread of an abandoned
+        store lives on, the directories open again, and what had been
+        acknowledged before the kill reads back."""
+        async def go():
+            conf = dict(FAST, osd_objectstore="bluestore",
+                        osd_data=str(tmp_path))
+            cluster = Cluster(n_osds=4, conf=conf, n_mons=1)
+            await cluster.start()
+            try:
+                client = await cluster.client()
+                pool = await client.create_pool("p", pg_num=8,
+                                                profile=dict(K2M1))
+                acked = {}
+
+                async def writer(w):
+                    for i in range(200):
+                        oid, blob = f"w{w}.{i}", os.urandom(120000)
+                        try:
+                            await client.put(pool, oid, blob)
+                        except Exception:
+                            return
+                        acked[oid] = blob
+
+                writers = [asyncio.ensure_future(writer(w))
+                           for w in range(4)]
+                while len(acked) < 8:
+                    await asyncio.sleep(0.01)
+                snapshot = dict(acked)
+                old = [o.store for o in cluster.osds.values()]
+                assert any(s._thread is not None for s in old)
+                await cluster.restart_osds()
+                assert all(s._thread is None for s in old)
+                live = {o.store._thread for o in cluster.osds.values()}
+                assert all(t in live for t in threading.enumerate()
+                           if t.name.startswith("bluestore-commit"))
+                for w in writers:
+                    w.cancel()
+                await asyncio.gather(*writers, return_exceptions=True)
+                for _ in range(200):
+                    await client.refresh_map()
+                    health = await client.get_health()
+                    if not health.get("checks"):
+                        break
+                    await asyncio.sleep(0.1)
+                for oid, blob in snapshot.items():
+                    assert bytes(await client.get(pool, oid)) == blob, oid
+                await client.stop()
+            finally:
+                await cluster.stop()
+
+        asyncio.run(go())
+        assert not [t for t in threading.enumerate()
+                    if t.name.startswith("bluestore-commit")]
+
+
+class TestAGroupOfSubWritesAndAStoreThatFails:
+    def test_a_group_is_handed_over_whole_before_any_commit_is_awaited(
+            self, tmp_path):
+        """A run of sub-writes from one rx batch: every one is applied and
+        on the store's queue while the first is still inside its block
+        sync, so the thread goes from one commit to the next with no turn
+        of the loop between them; the replies go out together, in order,
+        each after its own commit."""
+        from ceph_tpu.rados.types import MECSubWrite
+
+        async def go():
+            conf = dict(FAST, osd_objectstore="bluestore",
+                        osd_data=str(tmp_path))
+            cluster = Cluster(n_osds=4, conf=conf, n_mons=1)
+            await cluster.start()
+            try:
+                c = await cluster.client()
+                pool = await c.create_pool("p", pg_num=8, profile=dict(K2M1))
+                await c.put(pool, "obj", os.urandom(150000))
+                p = c.osdmap.pools[pool]
+                pg = c.osdmap.object_to_pg(p, "obj")
+                acting = c.osdmap.pg_to_acting(p, pg)
+                primary = c.osdmap.primary_of(acting,
+                                              seed=(pool << 20) | pg)
+                rid = next(a for a in acting if a >= 0 and a != primary)
+                replica, shard = cluster.osds[rid], acting.index(rid)
+                chunk, meta = replica.store.read((pool, "obj", shard))
+                msgs = [MECSubWrite(
+                    pool_id=pool, pg=pg, oid="obj", shard=shard,
+                    chunk=bytes([n]) * len(chunk), version=meta.version + n,
+                    object_size=meta.object_size, tid=f"t{n}",
+                    reply_to=("127.0.0.1", 1), from_osd=primary,
+                    epoch=replica.osdmap.epoch) for n in (1, 2, 3)]
+                gate, sync = threading.Event(), replica.store._block.sync
+                synced, sent = [], []
+
+                def held(data_only=False):
+                    assert gate.wait(20)
+                    synced.append(replica.store._unfinished)
+                    sync(data_only)
+
+                async def send(addr, reply):
+                    sent.append((reply.tid, reply.ok, len(synced)))
+
+                replica.store._block.sync = held
+                replica.messenger.send = send
+                before = _bs_counters()
+                group = asyncio.ensure_future(
+                    replica._handle_sub_write_group(msgs))
+                for _ in range(2000):
+                    if replica.store._unfinished == 3:
+                        break
+                    await asyncio.sleep(0.005)
+                # all three with the thread, none synced, nobody told
+                assert replica.store._unfinished == 3
+                assert synced == [] and sent == [] and not group.done()
+                assert replica.store.stat((pool, "obj", shard))[1].version \
+                    == meta.version + 3
+                gate.set()
+                await asyncio.wait_for(group, 20)
+                del replica.store._block.sync, replica.messenger.send
+                assert synced[0] == 3
+                assert sent == [("t1", True, 3), ("t2", True, 3),
+                                ("t3", True, 3)]
+                moved = {k: v - before[k] for k, v in _bs_counters().items()}
+                assert moved["offloop_commits"] == moved["txns"] == 3
+                assert moved["commit_unsynced"] == 0
+                got, now = replica.store.read((pool, "obj", shard))
+                assert bytes(got) == bytes([3]) * len(chunk)
+                assert now.version == meta.version + 3
+                await c.stop()
+            finally:
+                await cluster.stop()
+
+        asyncio.run(asyncio.wait_for(go(), 120))
+
+    def test_an_osd_whose_disk_fails_refuses_its_waiters_and_dies(
+            self, tmp_path):
+        """One OSD's WAL sync starts to fail with shard writes queued on
+        its store's thread: every waiter is answered (a refusal, so no put
+        hangs on it), the store takes no more, and the daemon stops as on
+        any fatal error; its thread is gone and the mon marks it down."""
+        async def go():
+            conf = dict(FAST, osd_objectstore="bluestore",
+                        osd_data=str(tmp_path), mon_osd_report_grace=0.8,
+                        osd_heartbeat_interval=0.2, osd_heartbeat_grace=1.0,
+                        client_op_timeout=3.0)
+            cluster = Cluster(n_osds=4, conf=conf, n_mons=1)
+            await cluster.start()
+            try:
+                c = await cluster.client()
+                pool = await c.create_pool("p", pg_num=8, profile=dict(K2M1))
+                await c.put(pool, "warm", os.urandom(150000))
+                victim = cluster.osds[1]
+                store = victim.store
+
+                def broken(data_only=False):
+                    raise OSError(5, "Input/output error")
+
+                store.db._log.sync = broken
+                puts = [asyncio.ensure_future(
+                    c.put(pool, f"obj{i}", os.urandom(150000)))
+                    for i in range(8)]
+                done, hanging = await asyncio.wait(puts, timeout=60)
+                assert not hanging
+                for t in puts:
+                    t.exception()  # acked or failed: either, but answered
+                for _ in range(400):
+                    if victim._stopped and store._thread is None:
+                        break
+                    await asyncio.sleep(0.05)
+                assert isinstance(store.failed, OSError)
+                assert victim._stopped and store._unfinished == 0
+                assert store._thread is None
+                with pytest.raises(IOError, match="takes no more"):
+                    store.setattr((pool, "warm", 0), "k", b"v")
+                # the rest of the cluster learns it as of any dead daemon
+                for _ in range(400):
+                    await c.refresh_map()
+                    if not c.osdmap.osds[1].up:
+                        break
+                    await asyncio.sleep(0.05)
+                assert not c.osdmap.osds[1].up
+                assert len(bytes(await c.get(pool, "warm"))) == 150000
+                await c.stop()
+            finally:
+                await cluster.stop()
+
+        asyncio.run(asyncio.wait_for(go(), 180))
