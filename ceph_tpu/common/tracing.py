@@ -26,7 +26,8 @@ Two instruments say where a loop thread's wall time goes (PERF.md §3):
   the open sections form a stack, and on exit a section's SELF time —
   its duration minus its child sections — goes to the time-avg
   ``self_<layer>`` of the loop's counter set (``thread_<layer>`` off a
-  loop, so loop time and thread time never mix).  While a profiler
+  loop, so loop time and thread time never mix; a NATIVE thread's time
+  comes in there through ``thread_source``).  While a profiler
   session records, the section is also a ``ceph.<layer>.<name>`` event
   in the profiler's host plane, beside the device's "XLA Ops" line and
   on its clock.  Op-level ``Span``s cross awaits and stay out of that
@@ -863,7 +864,32 @@ def _flush_meters(perf: PerfCounters) -> None:
             meter.flush()
 
 
-LOOP_PERF.presample = lambda: _flush_meters(LOOP_PERF)
+# (layer, read, [seconds, calls] folded in so far): what a NATIVE thread
+# did for a layer, kept by its library (the messenger's sender thread:
+# seconds inside writev)
+_THREAD_SOURCES: List[list] = []
+
+
+def thread_source(layer: str, read) -> None:
+    """`read()` gives (seconds, calls) a thread that runs no Python has
+    worked for `layer` so far; every dump of the `loop` set folds what is
+    new into `thread_<layer>`, beside the sections Python threads time."""
+    _THREAD_SOURCES.append([layer, read, [0.0, 0]])
+
+
+def _presample() -> None:
+    _flush_meters(LOOP_PERF)
+    for layer, read, seen in _THREAD_SOURCES:
+        seconds, calls = read()
+        if seconds < seen[0] or calls < seen[1]:
+            seen[:] = 0.0, 0  # the library began anew (a fork's child)
+        if calls > seen[1]:
+            _tally(LOOP_PERF, "thread_" + layer, seconds - seen[0],
+                   calls - seen[1])
+            seen[:] = seconds, calls
+
+
+LOOP_PERF.presample = _presample
 
 
 def install_loop_meter(loop=None, name: str = "") -> LoopMeter:

@@ -200,6 +200,24 @@ def _configure(_lib: ctypes.CDLL) -> None:
         ctypes.POINTER(ctypes.c_int64), _sp, _up, ctypes.c_int32]
     _lib.ceph_tpu_wirepath_selftest.restype = ctypes.c_int32
     _lib.ceph_tpu_wirepath_selftest.argtypes = []
+    # the off-loop sender (native/wirepath.h)
+    _u64p = ctypes.POINTER(ctypes.c_uint64)
+    _lib.ceph_tpu_wire_sender_submit.restype = ctypes.c_int32
+    _lib.ceph_tpu_wire_sender_submit.argtypes = [
+        ctypes.c_int, ctypes.c_int, ctypes.c_uint64, _pp, _sp,
+        ctypes.c_int32]
+    _lib.ceph_tpu_wire_sender_reap.restype = ctypes.c_int32
+    _lib.ceph_tpu_wire_sender_reap.argtypes = [
+        ctypes.c_int, _u64p, ctypes.POINTER(ctypes.c_int64), _up,
+        ctypes.c_int32]
+    for _fn in (_lib.ceph_tpu_wire_sender_cancel,
+                _lib.ceph_tpu_wire_sender_close_chan):
+        _fn.restype = ctypes.c_int32
+        _fn.argtypes = [ctypes.c_int]
+    _lib.ceph_tpu_wire_sender_stop.restype = ctypes.c_int32
+    _lib.ceph_tpu_wire_sender_stop.argtypes = []
+    _lib.ceph_tpu_wire_sender_stats.restype = None
+    _lib.ceph_tpu_wire_sender_stats.argtypes = [_u64p]
 
 
 def gf_mul(a: int, b: int) -> int:
@@ -488,6 +506,75 @@ def wire_scatter(srcs, offs, dst, want_crcs=None) -> tuple:
     return int(rc), int(bad.value)
 
 
+# -- the off-loop sender (native/wirepath.h): ONE native thread a process ----
+# writes the flush windows handed to it, off every event loop and without the
+# GIL.  The raw entry points take addresses: the caller keeps every segment
+# alive and unchanged until the job's completion was reaped.  The messenger
+# hands over through the PyDLL shim below (wirepy_sender_submit / _reap),
+# which pins the segments' buffers itself.
+
+SENDER_STATS = ("submitted", "completed", "failed", "cancelled", "bytes",
+                "writev_calls", "eagains", "writev_ns", "starts", "signals")
+
+
+def wire_sender_submit(fd: int, chan: int, token: int, segs) -> int:
+    """Queue `segs` for `fd`; the completion goes to the eventfd `chan`.
+    Returns the jobs the thread had unfinished; raises OSError (EINVAL:
+    bad geometry, nothing queued)."""
+    ptrs, lens, _ = _seg_arrays(segs)
+    rc = lib().ceph_tpu_wire_sender_submit(fd, chan, token, ptrs, lens,
+                                           len(segs))
+    if rc < 0:
+        raise OSError(-rc, os.strerror(-rc))
+    return int(rc)
+
+
+def wire_sender_reap(chan: int) -> list:
+    """[(token, bytes written or -errno, EAGAINs)] of the jobs of `chan`
+    that ended since the last reap; resets the eventfd."""
+    cap = 64
+    tokens = (ctypes.c_uint64 * cap)()
+    results = (ctypes.c_int64 * cap)()
+    eagains = (ctypes.c_uint32 * cap)()
+    out: list = []
+    while True:
+        n = lib().ceph_tpu_wire_sender_reap(chan, tokens, results, eagains,
+                                            cap)
+        if n < 0:
+            raise OSError(-n, os.strerror(-n))
+        out.extend((tokens[i], results[i], eagains[i]) for i in range(n))
+        if n < cap:
+            return out
+
+
+def wire_sender_cancel(fd: int) -> int:
+    """Drop every job of `fd`; returns, with the jobs dropped, only when
+    the thread is in no system call on it (the fd may be closed then).
+    Each dropped job completes with -ECANCELED on its channel."""
+    return int(lib().ceph_tpu_wire_sender_cancel(fd))
+
+
+def wire_sender_close_chan(chan: int) -> int:
+    """Drop every job that would complete on `chan`; the channel is
+    forgotten once nothing of it waits to be reaped (reap, then call
+    again).  Call BEFORE closing the eventfd."""
+    return int(lib().ceph_tpu_wire_sender_close_chan(chan))
+
+
+def wire_sender_stop() -> int:
+    """Stop the sender thread (joined on return); queued jobs complete
+    with -ECANCELED.  The next submit starts a new one."""
+    return int(lib().ceph_tpu_wire_sender_stop())
+
+
+def wire_sender_stats() -> dict:
+    """The sender's counters since load (SENDER_STATS); unfinished jobs =
+    submitted - completed - failed - cancelled."""
+    out = (ctypes.c_uint64 * len(SENDER_STATS))()
+    lib().ceph_tpu_wire_sender_stats(out)
+    return dict(zip(SENDER_STATS, out))
+
+
 # -- wirepy: the PyDLL shim (native/wirepath_py.cc) --------------------------
 # Separate .so because it needs Python headers; loaded via ctypes.PyDLL
 # so the C side parses the SEGMENT LIST itself (PyObject_GetBuffer walk,
@@ -519,7 +606,7 @@ def build_wirepy(force: bool = False) -> Optional[str]:
     os.makedirs(os.path.dirname(_PYLIB), exist_ok=True)
     cmd = [
         "g++", "-std=c++17", "-O3", "-march=native", "-fPIC", "-shared",
-        *WARN_FLAGS, f"-I{inc}", "-o", _PYLIB, *srcs,
+        *WARN_FLAGS, f"-I{inc}", "-o", _PYLIB, *srcs, "-pthread",
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True)
@@ -560,6 +647,25 @@ def pylib() -> Optional[ctypes.PyDLL]:
             _l.ceph_tpu_wirepy_scatter_from.restype = ctypes.c_longlong
             _l.ceph_tpu_wirepy_scatter_from.argtypes = [
                 ctypes.py_object, ctypes.py_object, ctypes.py_object]
+            _l.ceph_tpu_wirepy_sender_submit.restype = ctypes.c_longlong
+            _l.ceph_tpu_wirepy_sender_submit.argtypes = [
+                ctypes.c_int, ctypes.c_int, ctypes.c_ulonglong,
+                ctypes.py_object]
+            _l.ceph_tpu_wirepy_sender_reap.restype = ctypes.c_longlong
+            _l.ceph_tpu_wirepy_sender_reap.argtypes = [
+                ctypes.c_int, ctypes.py_object]
+            # the sender thread is the BASE library's, one a process: the
+            # shim (which carries a copy of wirepath.cc that never runs)
+            # reaches it through these two addresses
+            _l.ceph_tpu_wirepy_sender_bind.restype = None
+            _l.ceph_tpu_wirepy_sender_bind.argtypes = [
+                ctypes.c_void_p, ctypes.c_void_p]
+            base = lib()
+            _l.ceph_tpu_wirepy_sender_bind(
+                ctypes.cast(base.ceph_tpu_wire_sender_submit,
+                            ctypes.c_void_p),
+                ctypes.cast(base.ceph_tpu_wire_sender_reap,
+                            ctypes.c_void_p))
             _pylib = _l
         except Exception:
             _pylib_failed = True
@@ -591,6 +697,28 @@ def wirepy_writev(fd: int, segs, skip: int = 0) -> int:
         err = int(-rc)
         raise OSError(err, os.strerror(err))
     return int(rc)
+
+
+def wirepy_sender_submit(fd: int, chan: int, token: int, segs) -> int:
+    """Hand the segment LIST to the sender thread in one PyDLL call: the
+    buffers are pinned in C under the held GIL and stay pinned until
+    wirepy_sender_reap saw the job end; nothing here lets go of the GIL.
+    Returns the jobs the thread had unfinished; raises OSError."""
+    rc = _pyl().ceph_tpu_wirepy_sender_submit(fd, chan, token, segs)
+    if rc < 0:
+        raise OSError(int(-rc), os.strerror(int(-rc)))
+    return int(rc)
+
+
+def wirepy_sender_reap(chan: int) -> list:
+    """[(token, bytes written or -errno, EAGAINs)] of `chan`'s jobs that
+    ended since the last reap, their buffers released (on this thread);
+    resets the eventfd."""
+    out: list = []
+    rc = _pyl().ceph_tpu_wirepy_sender_reap(chan, out)
+    if rc < 0:
+        raise OSError(int(-rc), os.strerror(int(-rc)))
+    return out
 
 
 def wirepy_crc_chain(segs, seed: int = 0) -> int:

@@ -58,7 +58,12 @@ drains, new frames pile into the next — no added latency for an isolated
 send, automatic batching under load.  On plaintext TCP the flusher also
 swaps the StreamWriter for a CorkedWriter that ``sendmsg``-writevs the
 frame segments STRAIGHT FROM their owning buffers (encode outputs, store
-blobs, BufferList pieces) — zero copies between codec and kernel.
+blobs, BufferList pieces) — zero copies between codec and kernel.  A
+window big enough for it to pay (``CorkedWriter.OFFLOOP_MIN_BYTES``)
+leaves on the process's SENDER THREAD: a native thread that runs no
+Python and never takes the GIL copies it into the kernel while the loop
+goes on, and the loop hears of the windows that finished in one callback
+(``_Offloop``; CorkedWriter "Off the loop").
 
 Acks WAIT FOR COMPANY: dispatching a frame records a debt on the
 connection (``queue_ack``: the highest seq owed — acks are cumulative, so
@@ -128,7 +133,9 @@ Sharded multi-reactor wire plane (reactor.py + the lane layer here):
 from __future__ import annotations
 
 import asyncio
+import atexit
 import collections
+import errno
 import hashlib
 import hmac
 import itertools
@@ -141,6 +148,7 @@ import struct
 import threading
 import time
 import traceback
+import weakref
 import zlib
 from dataclasses import dataclass, field
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
@@ -219,6 +227,25 @@ def _build_wire_perf() -> PerfCounters:
       rx_batch_msgs        histogram   messages per rx dispatch batch
       wirepath_kind        u64 gauge   1 = native wirepath, 0 = python arm
       native_tx_calls      u64         released-GIL tx wirepath calls
+                                       (a window on the sender thread:
+                                       one, and one more each time it
+                                       found the socket full)
+      tx_offloop_windows   u64         flush windows handed to the
+                                       process's sender thread instead of
+                                       written in the loop's step
+      tx_offloop_bytes     u64         their bytes; over tx_bytes: how
+                                       often the hand-over engages
+      tx_offloop_behind    u64         of those windows, the ones under
+                                       CorkedWriter.OFFLOOP_MIN_BYTES that
+                                       followed a job of their fd (order)
+      tx_offloop_eagain    u64         times the thread found a socket
+                                       full and left the fd to its epoll
+      tx_offloop_lat       longrunavg  hand-over -> completion seen by the
+                                       loop, seconds per window (a WAIT:
+                                       the thread's copy and the loop's
+                                       own delay in looking)
+      tx_offloop_depth     longrunavg  jobs the thread had unfinished at a
+                                       hand-over (sum / count)
       native_rx_calls      u64         released-GIL rx wirepath calls
       native_bytes         u64         bytes touched by native wirepath
                                        passes (counted once per pass)
@@ -298,6 +325,18 @@ def _build_wire_perf() -> PerfCounters:
     b.add_u64_counter("native_tx_calls",
                       "released-GIL wirepath calls on the tx side "
                       "(whole-window writev, batch blob crc)")
+    b.add_u64_counter("tx_offloop_windows",
+                      "flush windows written by the sender thread")
+    b.add_u64_counter("tx_offloop_bytes", "bytes of those windows")
+    b.add_u64_counter("tx_offloop_behind",
+                      "small windows that followed a job of their fd")
+    b.add_u64_counter("tx_offloop_eagain",
+                      "full sockets the sender thread left to its epoll")
+    b.add_time_avg("tx_offloop_lat",
+                   "seconds from a hand-over to its completion seen by "
+                   "the loop")
+    b.add_time_avg("tx_offloop_depth",
+                   "jobs unfinished on the sender thread at a hand-over")
     b.add_u64_counter("native_rx_calls",
                       "released-GIL wirepath calls on the rx side "
                       "(a burst's crc verify, a landed body's)")
@@ -1360,9 +1399,29 @@ class CorkedWriter:
 
     Failure: a send error (or the transport's connection_lost, forwarded
     by FrameReceiver) fails queued segments and drain waiters with the
-    transport error — the same surface StreamWriter.drain() has."""
+    transport error — the same surface StreamWriter.drain() has.
+
+    Off the loop (PR 49): on the native arm a window of OFFLOOP_MIN_BYTES
+    or more is not written in the loop's step.  Its segments are pinned
+    and queued for the process's sender thread (`_Offloop`;
+    native/wirepath.h), which copies them into the kernel without ever
+    holding the GIL while the loop goes on; while the fd has a job there,
+    every later window follows it, whatever its size (order on an fd is
+    the order handed).  ``drain()`` keeps its contract: the completion
+    the thread posts is what takes the bytes off ``_buffered``.  Before
+    the socket may close, `_detach` takes the fd off the thread and waits
+    until the thread is in no system call on it: the fd's next owner
+    finds nothing of this one.  Only a writer whose transport's loss is
+    forwarded to it hands over (`hears_loss`): no other path could call
+    `_detach` in time."""
 
     IOV_MAX = 512  # segments per sendmsg call (conservative vs UIO_MAXIOV)
+    # the window size from which the hand-over and its share of a completion
+    # step cost the loop less than the writev they save: on the chip host
+    # 64 KiB reads 33-41 us inline against 44-46 handed over, 512 KiB 143-150
+    # against 68-72, the lines crossing near 107 KiB (tools/offloop_table.py;
+    # PERF.md section 6, PR 49)
+    OFFLOOP_MIN_BYTES = 128 << 10
 
     def __init__(self, transport, sock, stream_writer, wp=None, perf=None):
         self._transport = transport
@@ -1387,6 +1446,31 @@ class CorkedWriter:
         self._writer_on = False  # add_writer registered
         self._waiters: list = []
         self._exc: Optional[BaseException] = None
+        # the loop's end of the sender thread once hears_loss gave it, the
+        # jobs this fd has there, and whose the window being written is
+        # (the flusher says, for the completion step's charge)
+        self._off: Optional["_Offloop"] = None
+        self._off_jobs = 0
+        self.whose: Any = None
+
+    def hears_loss(self, offloop: Optional["_Offloop"]) -> None:
+        """The transport's protocol forwards connection_lost to this
+        writer from now on (FrameReceiver.corked): its windows may go to
+        the sender thread, `offloop` being the running loop's end of it
+        (None: no native arm).  The fd number's last owner may have died
+        without a word (a loop closed under its sockets): what the thread
+        still holds for the number goes first."""
+        if offloop is not None and self._off is None:
+            offloop.cancel(self._fd)
+            self._off = offloop
+
+    def offloop_takes(self, nbytes: int) -> bool:
+        """A window of `nbytes` written now would go to the sender thread:
+        it is big enough for the hand-over to pay, or the fd has a job
+        there that it has to follow."""
+        off = self._off
+        return off is not None and not off.closed and (
+            self._off_jobs > 0 or nbytes >= self.OFFLOOP_MIN_BYTES)
 
     # -- StreamWriter surface -------------------------------------------------
 
@@ -1397,10 +1481,60 @@ class CorkedWriter:
         if self._exc is not None:
             return  # error surfaces at drain(), like StreamWriter
         segs, total = _norm_segments(segments)
+        if total and self.offloop_takes(total):
+            self._hand_over(segs, total)
+            return
         self._segs.extend(segs)
         self._buffered += total
         if not self._writer_on:
             self._do_send()
+
+    def _hand_over(self, segs, total: int) -> None:
+        """Queue the window for the sender thread.  What the loop's own
+        writer still waits to write (small windows a full socket refused:
+        nothing is on the thread then) leaves first, in the same job."""
+        nbytes = total
+        if self._segs:
+            segs = [*self._segs, *segs]
+            nbytes += self._buffered
+            self._segs.clear()
+            self._buffered = 0
+            self._writer_off()
+        whose, self.whose = self.whose, None
+        try:
+            depth = self._off.submit(self, segs, nbytes, whose)
+        except OSError as e:
+            self._on_lost(e)
+            return
+        self._buffered += nbytes
+        self._off_jobs += 1
+        perf = self._perf
+        if perf is not None:
+            perf.inc("tx_offloop_windows")
+            perf.inc("tx_offloop_bytes", nbytes)
+            perf.tinc("tx_offloop_depth", depth)
+            if total < self.OFFLOOP_MIN_BYTES:
+                perf.inc("tx_offloop_behind")
+
+    def _job_done(self, nbytes: int, result: int, eagains: int,
+                  waited: float) -> None:
+        """The sender thread ended a job of this fd (`_Offloop._reap`, on
+        the loop): `result` is its bytes, all of them, or -errno."""
+        self._off_jobs -= 1
+        if self._exc is not None or result == -errno.ECANCELED:
+            return  # lost or detached meanwhile: nobody waits for these
+        if result < 0:
+            self._on_lost(OSError(-result, os.strerror(-result)))
+            return
+        perf = self._perf
+        if perf is not None:
+            perf.inc("native_tx_calls", 1 + eagains)
+            perf.inc("native_bytes", nbytes)
+            perf.tinc("tx_offloop_lat", waited)
+            if eagains:
+                perf.inc("tx_offloop_eagain", eagains)
+        self._buffered -= nbytes
+        self._wake()
 
     async def drain(self) -> None:
         while self._exc is None and self._buffered > 0:
@@ -1466,12 +1600,7 @@ class CorkedWriter:
         except OSError as e:
             self._on_lost(e)
             return
-        if self._writer_on:
-            self._writer_on = False
-            try:
-                self._remove_writer(self._fd)
-            except Exception:
-                pass
+        self._writer_off()
         self._wake()
 
     def _advance(self, n: int) -> None:
@@ -1492,13 +1621,21 @@ class CorkedWriter:
                 if not w.done():
                     w.set_result(None)
 
-    def _detach(self) -> None:
+    def _writer_off(self) -> None:
         if self._writer_on:
             self._writer_on = False
             try:
                 self._remove_writer(self._fd)
             except Exception:
                 pass
+
+    def _detach(self) -> None:
+        """Nothing of this writer touches the fd after this: the loop's
+        writer registration goes, and the sender thread's jobs with it
+        (returns with the thread in no system call on the fd)."""
+        self._writer_off()
+        if self._off_jobs > 0:
+            self._off.cancel(self._fd)
 
     def _on_lost(self, exc) -> None:
         if self._exc is None:
@@ -1508,6 +1645,161 @@ class CorkedWriter:
         self._segs.clear()
         self._buffered = 0
         self._wake()
+
+
+class _Offloop:
+    """One event loop's end of the process's sender thread
+    (native/wirepath.h; CorkedWriter "Off the loop").  It owns the eventfd
+    the thread writes when jobs of this loop ended (once per batch: not
+    again until the loop looked) and the ONE reader callback that takes
+    them, however many windows finished: each job's buffers are released,
+    its writer's `_buffered` advances and its drain() waiters wake, an
+    error goes to the writer's `_on_lost`.  The step is the messenger's
+    layer by its kind, and is charged to the finished windows' bytes by
+    key as the flusher's own steps are (PERF.md section 3)."""
+
+    def __init__(self, loop, wp) -> None:
+        self.loop = loop
+        self.wp = wp
+        self.efd = os.eventfd(0, os.EFD_NONBLOCK | os.EFD_CLOEXEC)
+        # token -> (writer, bytes, whose, handed over at)
+        self.jobs: Dict[int, tuple] = {}
+        # the messengers with connections on this loop; the last one's
+        # shutdown closes this
+        self.users: "weakref.WeakSet[Messenger]" = weakref.WeakSet()
+        self.closed = False
+        loop.add_reader(self.efd, self._on_done)
+
+    def submit(self, writer: "CorkedWriter", segs, nbytes: int,
+               whose) -> int:
+        """Pin `segs` and queue them for `writer`'s fd; returns the jobs
+        the thread had unfinished."""
+        token = next(_OFFLOOP_TOKENS)
+        depth = self.wp.wirepy_sender_submit(writer._fd, self.efd, token,
+                                             segs)
+        self.jobs[token] = (writer, nbytes, whose, time.perf_counter())
+        return depth
+
+    def cancel(self, fd: int) -> None:
+        """Take `fd` off the thread; returns with the thread in no system
+        call on it.  The buffers of the jobs dropped, and of one that
+        failed on the thread a moment before, are released here."""
+        self.wp.wire_sender_cancel(fd)
+        self._reap(None)
+
+    def _on_done(self) -> None:
+        weights: Optional[dict] = {} if tracing.metered() else None
+        self._reap(weights)
+        if weights:
+            tracing.charge_many(weights)
+
+    def _reap(self, weights: Optional[dict]) -> None:
+        jobs = self.jobs
+        now = time.perf_counter()
+        for token, result, eagains in self.wp.wirepy_sender_reap(self.efd):
+            job = jobs.pop(token, None)
+            if job is None:
+                continue
+            writer, nbytes, whose, t0 = job
+            if weights is not None:
+                if type(whose) is dict:
+                    for key, n in whose.items():
+                        weights[key] = weights.get(key, 0) + n
+                else:
+                    weights[whose] = weights.get(whose, 0) + nbytes
+            writer._job_done(nbytes, result, eagains, now - t0)
+
+    def release(self, messenger: "Messenger") -> None:
+        """`messenger` shut down (any thread's loop): without users this
+        closes, on its own loop."""
+        with _OFFLOOP_LOCK:
+            self.users.discard(messenger)
+            if self.users or self.closed:
+                return
+        try:
+            here = asyncio.get_running_loop()
+        except RuntimeError:
+            here = None
+        if here is self.loop or self.loop.is_closed():
+            self.close()
+        else:
+            try:
+                self.loop.call_soon_threadsafe(self.close)
+            except RuntimeError:
+                self.close()  # the loop shut down under us
+
+    def close(self) -> None:
+        with _OFFLOOP_LOCK:
+            if self.closed or self.users:
+                return
+            self.closed = True
+        if not self.loop.is_closed():
+            self.loop.remove_reader(self.efd)
+        # what is still on the thread for this loop is dropped (never
+        # another loop's jobs on an fd number handed out again), its
+        # buffers released, then the thread forgets the eventfd
+        self.wp.wire_sender_close_chan(self.efd)
+        self._reap(None)
+        self.wp.wire_sender_close_chan(self.efd)
+        os.close(self.efd)
+        # under the lock no loop gets a new end while the thread stops
+        with _OFFLOOP_LOCK:
+            if all(off.closed for off in _OFFLOOPS.values()):
+                self.wp.wire_sender_stop()  # the next hand-over starts it
+
+    def abandon(self) -> None:
+        """The loop was closed under its messengers (no shutdown): what
+        the thread holds for it is dropped and the eventfd closed."""
+        self.users.clear()
+        self.close()
+
+
+# the loops' ends of the sender thread; a job's token is the process's.
+# Loops run on several threads under ms_reactor_mode = thread: the lock
+# guards the table, the one-time hooks and each end's users / closed
+_OFFLOOPS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+_OFFLOOP_TOKENS = itertools.count(1)
+_OFFLOOP_HOOKS = False
+_OFFLOOP_LOCK = threading.RLock()
+
+
+def _offloops_forked() -> None:
+    """In a fork's child: none of the parent's loops, and a lock that no
+    thread of the parent's holds."""
+    global _OFFLOOP_LOCK
+    _OFFLOOP_LOCK = threading.RLock()
+    _OFFLOOPS.clear()
+
+
+def _offloop_of(loop, wp, user: Optional["Messenger"] = None
+                ) -> Optional[_Offloop]:
+    """`loop`'s end of the sender thread, made at first use, `user` among
+    its users; None where the native arm or the platform cannot give one."""
+    global _OFFLOOP_HOOKS
+    if wp is None or not hasattr(os, "eventfd"):
+        return None
+    with _OFFLOOP_LOCK:
+        off = _OFFLOOPS.get(loop)
+        if off is None or off.closed:
+            if not _OFFLOOP_HOOKS:
+                _OFFLOOP_HOOKS = True
+                # the thread ends with the interpreter; a fork's child has
+                # no thread and none of these loops (the library forgets
+                # its own half, native/wirepath.cc atfork_child)
+                atexit.register(wp.wire_sender_stop)
+                os.register_at_fork(after_in_child=_offloops_forked)
+
+                def writev_seconds():
+                    st = wp.wire_sender_stats()
+                    return st["writev_ns"] * 1e-9, st["writev_calls"]
+                tracing.thread_source("messenger", writev_seconds)
+            for other in list(_OFFLOOPS.values()):
+                if other.loop.is_closed():
+                    other.abandon()
+            off = _OFFLOOPS[loop] = _Offloop(loop, wp)
+        if user is not None:
+            off.users.add(user)
+        return off
 
 
 class _AckSweep:
@@ -1691,6 +1983,7 @@ class Connection:
                 # corked before this serve loop started, under the stream
                 # protocol: it still has to hear of the connection's loss
                 receiver.corked = self.writer
+                self.writer.hears_loss(self.messenger._offloop_here())
             transport.set_protocol(receiver)
             # the StreamReader may have left the transport paused (its
             # own flow control); the receiver starts unpaused, so resume
@@ -1926,11 +2219,19 @@ class Connection:
                         break
                     gen = self.transport_gen
                     t_io = time.monotonic()
+                    w = self.writer
+                    # the inline arm's write, or the hand-over to the
+                    # sender thread (whose completion step is charged to
+                    # the window as this one is)
+                    section = "sock_write"
+                    if isinstance(w, CorkedWriter) \
+                            and w.offloop_takes(nbytes):
+                        section, w.whose = "sock_handoff", whose
                     try:
                         with perf.time_avg("tx_io"):
-                            with tracing.section("messenger", "sock_write"):
-                                _writelines(self.writer, segs)
-                            await self.writer.drain()
+                            with tracing.section("messenger", section):
+                                _writelines(w, segs)
+                            await w.drain()
                     except (ConnectionError, OSError,
                             asyncio.TimeoutError) as e:
                         if fut is not None and not fut.done():
@@ -2028,6 +2329,7 @@ class Connection:
             proto = transport.get_protocol()
             if isinstance(proto, FrameReceiver):
                 proto.corked = corked  # connection_lost fails its waiters
+                corked.hears_loss(self.messenger._offloop_here())
         except Exception:
             return
         self.writer = corked
@@ -3018,6 +3320,14 @@ class Messenger:
                 f"{self.name}-dispatch-shard",
                 _cget(self.conf, "ms_dispatch_throttle_bytes", 100 << 20))
         return t
+
+    def _offloop_here(self) -> Optional[_Offloop]:
+        """The CURRENT loop's end of the process's sender thread, this
+        messenger among its users until it shuts down; None on the python
+        arm."""
+        if self.wirepath is None or self._shutdown:
+            return None
+        return _offloop_of(asyncio.get_running_loop(), self.wirepath, self)
 
     def _ack_sweep_here(self) -> "_AckSweep":
         """The sweep of owed acks for the CURRENT loop (a connection's
@@ -4359,6 +4669,13 @@ class Messenger:
                 pass
         if self.reactors is not None:
             self.reactors.shutdown()
+        # every connection is closed, so nothing of this messenger is on
+        # the sender thread: the last messenger of a loop closes the
+        # loop's end of it, the last loop's stops the thread
+        with _OFFLOOP_LOCK:
+            mine = [off for off in _OFFLOOPS.values() if self in off.users]
+        for off in mine:
+            off.release(self)
 
     # -- wire-plane introspection --------------------------------------------
 

@@ -90,6 +90,68 @@ int32_t ceph_tpu_wire_scatter(const uint8_t* const* src_ptrs,
                               size_t dst_len, const uint32_t* want_crcs,
                               int32_t check_crc, int32_t* bad_idx);
 
+// ---- the off-loop sender (ISSUE 49) ---------------------------------------
+//
+// ONE native thread a process takes over the kernel's half of a big
+// send: a flush window's writev runs here, with no GIL anywhere near
+// it, while the event loop that handed it over goes on.  The thread
+// runs no Python and never takes the GIL; it owns an epoll of its own
+// and a FIFO of jobs per fd.  A job is written with the loop of
+// ceph_tpu_wire_writev (partial writes, EINTR, IOV_MAX); on EAGAIN the
+// fd is armed for EPOLLOUT and the thread goes on with the other fds,
+// so a receiver that pauses holds back its own connection only.  Order
+// on an fd is the order handed.
+//
+// A job's completion is posted to its CHANNEL, an eventfd of the
+// caller's (one per event loop): (token, bytes written or -errno).  The
+// eventfd is written once per batch: a channel that was signalled and
+// not yet reaped is not written again.  The segments' memory belongs to
+// the caller and stays as it is until the job's completion was reaped.
+//
+// The thread starts at the first submit and ends at
+// ceph_tpu_wire_sender_stop; a fork's child finds no thread, no queue
+// and no fd of the parent's sender (pthread_atfork) and starts its own
+// at its first submit.
+
+// Queue one job: nseg segments to `fd` (nonblocking), completion to
+// `chan`.  Returns the jobs the thread had unfinished before this one
+// (>= 0), or -EINVAL (bad geometry: nothing queued), -EAGAIN (no thread
+// could be started).
+int32_t ceph_tpu_wire_sender_submit(int fd, int chan, uint64_t token,
+                                    const uint8_t* const* ptrs,
+                                    const size_t* lens, int32_t nseg);
+
+// Take up to `cap` completions of `chan` and reset its eventfd.  Returns
+// how many were copied; a caller that got `cap` asks again.  eagains[i]
+// is how often job i found its socket full.
+int32_t ceph_tpu_wire_sender_reap(int chan, uint64_t* tokens,
+                                  int64_t* results, uint32_t* eagains,
+                                  int32_t cap);
+
+// Drop every job of `fd` and return only when the thread is in no
+// system call on it: after this the fd may be closed and its number
+// reused.  Each dropped job completes with -ECANCELED on its channel.
+// Returns the jobs dropped (0: the fd had none).
+int32_t ceph_tpu_wire_sender_cancel(int fd);
+
+// Drop every job whose completion goes to `chan` (as cancel does, fd by
+// fd), and forget the channel if nothing of it waits to be reaped: a
+// caller that keeps memory for its jobs reaps what this posted, then
+// calls again.  Returns the jobs dropped.
+int32_t ceph_tpu_wire_sender_close_chan(int chan);
+
+// Stop the thread (joined before this returns); jobs still queued
+// complete with -ECANCELED.  The next submit starts a new thread.
+// Returns the jobs dropped.
+int32_t ceph_tpu_wire_sender_stop();
+
+// Counters since the library was loaded (a fork's child: since the
+// fork), out[0..10): jobs submitted, completed, failed, cancelled, bytes
+// written, writev calls, EAGAINs, nanoseconds inside writev, thread
+// starts, eventfd writes to channels.  Jobs unfinished
+// = submitted - completed - failed - cancelled.
+void ceph_tpu_wire_sender_stats(uint64_t out[10]);
+
 // Adversarial self-battery: truncated, overlapping, corrupt-offset and
 // oversize fragment geometries against the scatter/gather/crc entry
 // points above.  Returns 0 when every hostile case is refused and every

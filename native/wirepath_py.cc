@@ -28,6 +28,7 @@
 #include <cerrno>
 #include <cstdint>
 #include <cstring>
+#include <unordered_map>
 #include <vector>
 
 // the pure entry points this file fans into (wirepath.cc / crc32c.cc,
@@ -71,6 +72,24 @@ void release_segments(std::vector<Py_buffer>& bufs, Py_ssize_t got) {
   for (Py_ssize_t i = 0; i < got; ++i) PyBuffer_Release(&bufs[i]);
 }
 
+// -- the off-loop sender's Python half (wirepath.h) ---------------------------
+// The sender thread lives in libceph_tpu_ec.so, ONE a process; this shim
+// reaches it through the two entry points the bridge binds at load.  What
+// is here runs on the caller's thread with the GIL held from end to end:
+// the buffers of a job are acquired at the hand-over and released at the
+// reap, both on the event loop's thread; the sender thread sees addresses
+// and lengths, never an object.
+typedef int32_t (*sender_submit_fn)(int, int, uint64_t,
+                                    const uint8_t* const*, const size_t*,
+                                    int32_t);
+typedef int32_t (*sender_reap_fn)(int, uint64_t*, int64_t*, uint32_t*,
+                                  int32_t);
+sender_submit_fn g_sender_submit = nullptr;
+sender_reap_fn g_sender_reap = nullptr;
+
+// the views a job on the thread keeps alive, by token (the GIL is the lock)
+std::unordered_map<uint64_t, std::vector<Py_buffer>>* g_pins = nullptr;
+
 }  // namespace
 
 extern "C" {
@@ -100,6 +119,79 @@ long long ceph_tpu_wirepy_writev(int fd, PyObject* segs,
   release_segments(bufs, got);
   Py_DECREF(fast);
   return rc;
+}
+
+void ceph_tpu_wirepy_sender_bind(void* submit, void* reap) {
+  g_sender_submit = reinterpret_cast<sender_submit_fn>(submit);
+  g_sender_reap = reinterpret_cast<sender_reap_fn>(reap);
+  if (g_pins == nullptr)
+    g_pins = new std::unordered_map<uint64_t, std::vector<Py_buffer>>();
+}
+
+// Hand a flush window to the sender thread: the segments' buffers are
+// pinned here and stay pinned until ceph_tpu_wirepy_sender_reap saw the
+// job end.  Returns the jobs the thread had unfinished (>= 0) or -errno
+// (nothing queued, nothing pinned).
+long long ceph_tpu_wirepy_sender_submit(int fd, int chan,
+                                        unsigned long long token,
+                                        PyObject* segs) {
+  if (g_sender_submit == nullptr) return -ENOSYS;
+  PyObject* fast = PySequence_Fast(segs, "wirepy_sender_submit segments");
+  if (fast == nullptr) {
+    PyErr_Clear();
+    return -EINVAL;
+  }
+  std::vector<Py_buffer> bufs;
+  std::vector<const uint8_t*> ptrs;
+  std::vector<size_t> lens;
+  long long rc = 0;
+  Py_ssize_t got = acquire_segments(fast, bufs, ptrs, lens, &rc);
+  if (rc == 0 && g_pins->count(token)) rc = -EEXIST;
+  if (rc == 0)
+    rc = g_sender_submit(fd, chan, token, ptrs.data(), lens.data(),
+                         static_cast<int32_t>(got));
+  if (rc < 0)
+    release_segments(bufs, got);
+  else
+    (*g_pins)[token] = std::move(bufs);
+  Py_DECREF(fast);
+  return rc;
+}
+
+// The jobs of `chan` that ended since the last reap: their buffers are
+// released and (token, bytes written or -errno, EAGAINs) is appended to
+// the list `out` for each.  Returns how many, or -errno.
+long long ceph_tpu_wirepy_sender_reap(int chan, PyObject* out) {
+  if (g_sender_reap == nullptr) return -ENOSYS;
+  constexpr int32_t kCap = 64;
+  uint64_t tokens[kCap];
+  int64_t results[kCap];
+  uint32_t eagains[kCap];
+  long long total = 0;
+  for (;;) {
+    int32_t n = g_sender_reap(chan, tokens, results, eagains, kCap);
+    if (n < 0) return n;
+    for (int32_t i = 0; i < n; ++i) {
+      auto it = g_pins->find(tokens[i]);
+      if (it != g_pins->end()) {
+        release_segments(it->second,
+                         static_cast<Py_ssize_t>(it->second.size()));
+        g_pins->erase(it);
+      }
+      PyObject* row = Py_BuildValue(
+          "(KLI)", static_cast<unsigned long long>(tokens[i]),
+          static_cast<long long>(results[i]),
+          static_cast<unsigned int>(eagains[i]));
+      if (row == nullptr || PyList_Append(out, row) != 0) {
+        Py_XDECREF(row);
+        PyErr_Clear();
+        return -ENOMEM;
+      }
+      Py_DECREF(row);
+    }
+    total += n;
+    if (n < kCap) return total;
+  }
 }
 
 // chained crc32c over a list of buffers (a BufferList's pieces, a

@@ -1530,3 +1530,534 @@ class TestAcksWaitForCompany:
             await client.shutdown()
 
         run(go())
+
+
+# -- a window's bytes leave on the sender thread (PR 49) ---------------------
+
+_ARMS = ("native", "python")
+
+
+def _arm_conf(arm: str) -> dict:
+    return {} if arm == "native" else {"ms_wirepath_native": False}
+
+
+def _needs_native():
+    from ceph_tpu.utils import wirepath
+    if wirepath.impl() is None:
+        pytest.skip("no native wirepath arm on this host")
+    return wirepath.impl()
+
+
+async def _corked(conn, server_got=None):
+    """Send small messages until `conn` writes through a CorkedWriter (the
+    swap happens at a flush that finds the transport's own buffer empty)
+    and its receiver is in place, so that the writer hears of a loss."""
+    from ceph_tpu.rados.messenger import CorkedWriter
+    for _ in range(20):
+        await conn.send(MTest(text="prime", seqno=-1))
+        await asyncio.sleep(0.01)
+        w = conn.writer
+        if isinstance(w, CorkedWriter) \
+                and (conn.wp is None or w._off is not None):
+            return w
+    raise AssertionError("the connection never corked")
+
+
+class _Gate:
+    """A dispatcher that parks its first message until released, so the
+    receiver's backpressure pauses the socket under a sender."""
+
+    def __init__(self):
+        self.got = []
+        self.parked = asyncio.Event()
+        self.release = asyncio.Event()
+
+    async def __call__(self, conn, msg):
+        if msg.seqno >= 0 and not self.release.is_set():
+            self.parked.set()
+            await self.release.wait()
+        if msg.seqno >= 0:
+            self.got.append((msg.seqno, bytes(msg.blob)))
+
+
+def _blob(i: int, n: int) -> bytes:
+    return bytes([i % 251]) * n
+
+
+class TestOffloopSend:
+    """CorkedWriter "Off the loop": which windows leave on the process's
+    sender thread, that order and bytes are what they were, and that the
+    thread never outlives its fd.  Each case runs on the native arm and,
+    where it applies, on the python arm, where nothing changed."""
+
+    @pytest.mark.parametrize("arm", _ARMS)
+    def test_big_and_small_windows_interleaved_keep_seq_order(self, arm):
+        async def go():
+            from ceph_tpu.rados.messenger import CorkedWriter
+            if arm == "native":
+                _needs_native()
+            server, client, addr = await _pair(client_conf=_arm_conf(arm))
+            got = []
+
+            async def dispatch(conn, msg):
+                if msg.seqno >= 0:
+                    got.append((msg.seqno, bytes(msg.blob)))
+            server.dispatcher = dispatch
+            conn = await client.connect(addr)
+            await _corked(conn)
+            sizes = [256 << 10, 10, 1 << 20, 0, 64, 300 << 10, 7, 2 << 20,
+                     33, 128 << 10, 5, 5]
+            msgs = [MTest(seqno=i, blob=_blob(i, n))
+                    for i, n in enumerate(sizes)]
+            # three waves of concurrent senders: windows of every mix
+            for lo in range(0, len(msgs), 4):
+                await asyncio.gather(*(conn.send(m)
+                                       for m in msgs[lo:lo + 4]))
+            assert await _until(lambda: len(got) == len(msgs), 10.0)
+            assert [s for s, _ in got] == list(range(len(msgs)))
+            assert all(b == _blob(i, sizes[i]) for i, b in got)
+            d = client.perf.dump()
+            if arm == "native":
+                assert d["tx_offloop_windows"] >= 3, d
+                assert d["tx_offloop_bytes"] >= sum(
+                    n for n in sizes if n >= CorkedWriter.OFFLOOP_MIN_BYTES)
+                assert d["tx_offloop_lat"]["avgcount"] \
+                    == d["tx_offloop_windows"]
+            else:
+                assert d["tx_offloop_windows"] == 0
+                assert d["tx_offloop_bytes"] == 0
+            assert d["tx_bytes"] > sum(sizes)
+            await client.shutdown()
+            await server.shutdown()
+
+        run(go())
+
+    @pytest.mark.parametrize("arm", _ARMS)
+    def test_a_full_socket_holds_back_its_own_connection_only(self, arm):
+        async def go():
+            import socket
+            if arm == "native":
+                _needs_native()
+            slow, client, slow_addr = await _pair(
+                client_conf=_arm_conf(arm))
+            fast = Messenger("fast", {}, entity_type="osd")
+            fast_addr = await fast.bind()
+            gate, fast_got = _Gate(), []
+
+            async def fast_dispatch(conn, msg):
+                if msg.seqno >= 0:
+                    fast_got.append(msg.seqno)
+            slow.dispatcher, fast.dispatcher = gate, fast_dispatch
+            a = await client.connect(slow_addr)
+            b = await client.connect(fast_addr)
+            wa = await _corked(a)
+            await _corked(b)
+            wa._sock.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 16384)
+            # 24 MiB at a reader that parks on the first message: more
+            # than the receiver's 1 MiB and any socket buffer take
+            n = 24
+            sends = [asyncio.ensure_future(
+                a.send(MTest(seqno=i, blob=_blob(i, 1 << 20))))
+                for i in range(n)]
+            await asyncio.wait_for(gate.parked.wait(), 10)
+            assert await _until(lambda: wa._buffered > 0 and (
+                wa._off_jobs > 0 or wa._writer_on), 10.0)
+            await asyncio.sleep(0.2)
+            assert not all(s.done() for s in sends), \
+                "the paused reader took 24 MiB"
+            # the other connection keeps sending meanwhile, big windows too
+            for i in range(6):
+                await asyncio.wait_for(
+                    b.send(MTest(seqno=i, blob=_blob(i, 512 << 10))), 5)
+            assert await _until(lambda: len(fast_got) == 6, 10.0)
+            assert not all(s.done() for s in sends)
+            gate.release.set()
+            await asyncio.wait_for(asyncio.gather(*sends), 20)
+            assert await _until(lambda: len(gate.got) == n, 20.0)
+            assert [s for s, _ in gate.got] == list(range(n))
+            assert all(blob == _blob(i, 1 << 20) for i, blob in gate.got)
+            d = client.perf.dump()
+            if arm == "native":
+                assert d["tx_offloop_eagain"] >= 1, d
+                assert d["tx_offloop_windows"] >= 4
+            else:
+                assert d["tx_offloop_windows"] == 0
+            assert wa._buffered == 0 and wa._off_jobs == 0
+            for m in (client, slow, fast):
+                await m.shutdown()
+
+        run(go())
+
+    @pytest.mark.parametrize("arm", _ARMS)
+    def test_a_reset_mid_window_surfaces_at_drain_and_replays_once(
+            self, arm):
+        async def go():
+            import socket
+            if arm == "native":
+                _needs_native()
+            server, client, addr = await _pair(client_conf=_arm_conf(arm))
+            gate = _Gate()
+            server_conns = []
+
+            async def dispatch(conn, msg):
+                server_conns.append(conn)
+                await gate(conn, msg)
+            server.dispatcher = dispatch
+            conn = await client.connect(addr)
+            w = await _corked(conn)
+            w._sock.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 16384)
+            # seq 0 parks the reader; the 4 MiB windows behind it cannot
+            # all leave: one of them is in the middle when the peer resets
+            first = asyncio.ensure_future(
+                client.send(addr, MTest(seqno=0, blob=b"a"), retries=8))
+            await asyncio.wait_for(gate.parked.wait(), 10)
+            big = [asyncio.ensure_future(client.send(
+                addr, MTest(seqno=i, blob=_blob(i, 4 << 20)), retries=8))
+                for i in (1, 2, 3)]
+            assert await _until(lambda: w._buffered > 0 and (
+                w._off_jobs > 0 or w._writer_on), 10.0)
+            await asyncio.sleep(0.1)
+            unacked_before = len(conn.unacked)
+            assert unacked_before >= 1
+            sconn = server_conns[0]
+            sconn.writer.transport.abort()  # RST under the window
+            with pytest.raises((ConnectionError, OSError)):
+                await asyncio.wait_for(w.drain(), 10)
+            assert w._exc is not None and w._off_jobs == 0
+            gate.release.set()
+            await asyncio.wait_for(asyncio.gather(first, *big), 30)
+            assert await _until(lambda: len(gate.got) == 4, 20.0)
+            assert [s for s, _ in gate.got] == [0, 1, 2, 3], \
+                "loss, duplicate or reorder across the replay"
+            assert all(blob == _blob(i, 4 << 20)
+                       for i, blob in gate.got[1:])
+            await client.shutdown()
+            await server.shutdown()
+
+        run(go())
+
+    def test_close_with_a_job_pending_leaves_the_fd_number_clean(self):
+        async def go():
+            import socket
+            wp = _needs_native()
+            server, client, addr = await _pair(client_type="client")
+            other = Messenger("other", {}, entity_type="osd")
+            other_addr = await other.bind()
+            gate, other_got = _Gate(), []
+
+            async def other_dispatch(conn, msg):
+                other_got.append((msg.seqno, bytes(msg.blob)))
+            server.dispatcher, other.dispatcher = gate, other_dispatch
+            conn = await client.connect(addr, peer_type="client")
+            w = await _corked(conn)
+            fd = w._fd
+            w._sock.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 16384)
+            sends = [asyncio.ensure_future(
+                conn.send(MTest(seqno=i, blob=_blob(i, 4 << 20))))
+                for i in range(3)]
+            await asyncio.wait_for(gate.parked.wait(), 10)
+            assert await _until(lambda: w._off_jobs > 0, 10.0)
+            before = wp.wire_sender_stats()
+            await conn.close()
+            after = wp.wire_sender_stats()
+            assert after["cancelled"] > before["cancelled"]
+            assert w._off_jobs == 0
+            # nothing of the fd is left on the thread: a cancel finds none
+            assert wp.wire_sender_cancel(fd) == 0
+            for s in sends:
+                with pytest.raises((ConnectionError, OSError)):
+                    await s
+            # the number's next owner: a connection that gets the same fd
+            conn2 = await client.connect(other_addr)
+            w2 = await _corked(conn2)
+            if w2._fd != fd:
+                pytest.skip(f"fd {fd} was not handed out again "
+                            f"(got {w2._fd})")
+            for i in range(4):
+                await conn2.send(MTest(seqno=i, blob=_blob(i + 7, 1 << 20)))
+            assert await _until(
+                lambda: len([s for s, _ in other_got if s >= 0]) == 4, 10.0)
+            assert [(s, b) for s, b in other_got if s >= 0] == [
+                (i, _blob(i + 7, 1 << 20)) for i in range(4)]
+            assert not conn2.closed
+            gate.release.set()
+            for m in (client, server, other):
+                await m.shutdown()
+
+        run(go())
+
+    @pytest.mark.parametrize("case", [
+        (TestLosslessReplay, "test_exactly_once_under_injected_failures"),
+        (TestLosslessReplay,
+         "test_bidirectional_rpc_exactly_once_under_failures"),
+        (TestCorkedOutbox,
+         "test_burst_exactly_once_in_order_under_failures"),
+        (None, "test_injected_drops_under_standing_debts_deliver_"
+               "exactly_once"),
+    ], ids=lambda c: c[1][5:45])
+    def test_injected_failures_with_every_window_on_the_thread(
+            self, case, monkeypatch):
+        from ceph_tpu.rados.messenger import CorkedWriter
+        wp = _needs_native()
+        cls, name = case
+        cls = cls or TestAcksWaitForCompany
+        monkeypatch.setattr(CorkedWriter, "OFFLOOP_MIN_BYTES", 0)
+        before = wp.wire_sender_stats()
+        getattr(cls(), name)()
+        after = wp.wire_sender_stats()
+        assert after["submitted"] - before["submitted"] >= 10
+        assert after["submitted"] == after["completed"] + after["failed"] \
+            + after["cancelled"], "a job outlived its messengers"
+
+    def test_the_engagement_metric_reads_offloop_over_sent(self):
+        async def go():
+            import json
+            import os
+            from benchmarks import layers
+            _needs_native()
+            spec_path = os.path.join(layers.DIR, "tx_offloop_share.put.json")
+            with open(spec_path) as f:
+                spec = json.load(f)
+            with open(spec_path.replace(".put.", ".get.")) as f:
+                assert json.load(f) == spec
+            assert spec["num"] == ["wire.tx_offloop_bytes"]
+            assert spec["den"] == ["wire.tx_bytes"]
+            server, client, addr = await _pair()
+            server.dispatcher = _swallow
+            conn = await client.connect(addr)
+            await _corked(conn)
+
+            def snap():
+                d = client.perf.dump()
+                return {"wire." + k: d[k]
+                        for k in ("tx_offloop_bytes", "tx_bytes")}
+
+            def share(a, b):
+                return layers._perf_counter(
+                    spec, {"counters": {k: b[k] - a[k] for k in a}})
+            s0 = snap()
+            for i in range(8):  # a 4 KiB write's windows: all under the line
+                await conn.send(MTest(seqno=i, blob=_blob(i, 4096)))
+            s1 = snap()
+            assert share(s0, s1) == 0.0
+            for i in range(4):
+                await conn.send(MTest(seqno=i, blob=_blob(i, 1 << 20)))
+            s2 = snap()
+            moved = s2["wire.tx_offloop_bytes"] - s1["wire.tx_offloop_bytes"]
+            assert moved >= 4 << 20
+            assert share(s1, s2) == pytest.approx(
+                100.0 * moved / (s2["wire.tx_bytes"] - s1["wire.tx_bytes"]))
+            assert share(s1, s2) > 99.0
+            # a program without the counter reports nothing
+            assert layers._perf_counter(
+                spec, {"counters": {"wire.tx_bytes": 5}}) is None
+            await client.shutdown()
+            await server.shutdown()
+
+        run(go())
+
+    def test_only_a_writer_that_hears_of_a_loss_hands_over(self):
+        async def go():
+            from ceph_tpu.rados.messenger import CorkedWriter, _offloop_of
+            wp = _needs_native()
+            loop = asyncio.get_running_loop()
+            got = bytearray()
+
+            async def sink(reader, writer):
+                while chunk := await reader.read(1 << 20):
+                    got.extend(chunk)
+            srv = await asyncio.start_server(sink, "127.0.0.1", 0)
+            port = srv.sockets[0].getsockname()[1]
+            _, sw = await asyncio.open_connection("127.0.0.1", port)
+            sock = sw.transport.get_extra_info("socket")
+            w = CorkedWriter(sw.transport, getattr(sock, "_sock", sock),
+                             sw, wp=wp)
+            blob = _blob(3, 1 << 20)
+            assert not w.offloop_takes(len(blob))
+            w.writelines([blob])  # nobody forwards a loss: the inline arm
+            await w.drain()
+            assert w._off_jobs == 0
+            off = _offloop_of(loop, wp)
+            w.hears_loss(off)
+            assert w.offloop_takes(len(blob)) and not w.offloop_takes(100)
+            w.writelines([blob])
+            assert w._off_jobs == 1 and w.offloop_takes(100)  # order
+            w.writelines([b"tail"])
+            assert w._off_jobs == 2
+            await w.drain()
+            assert w._off_jobs == 0 and w._buffered == 0
+            assert await _until(lambda: len(got) == 2 * len(blob) + 4, 5.0)
+            assert bytes(got) == blob + blob + b"tail"
+            w.close()
+            srv.close()
+            off.close()
+            assert off.closed
+
+        run(go())
+
+    def test_a_loop_closed_under_its_jobs_is_abandoned_by_the_next(self):
+        """No shutdown, no close: the loop ends with a window parked on
+        the thread.  The next loop's first hand-over drops what the dead
+        loop left, through its channel, and its own jobs are untouched."""
+        import socket
+        from ceph_tpu.rados.messenger import (CorkedWriter, _OFFLOOPS,
+                                              _offloop_of)
+        wp = _needs_native()
+        before = wp.wire_sender_stats()
+        keep = []  # the dead loop's sockets stay open: their numbers too
+
+        async def writer_to(port):
+            _, sw = await asyncio.open_connection("127.0.0.1", port)
+            sock = sw.transport.get_extra_info("socket")
+            sock = getattr(sock, "_sock", sock)
+            w = CorkedWriter(sw.transport, sock, sw, wp=wp)
+            w.hears_loss(_offloop_of(asyncio.get_running_loop(), wp))
+            return w
+
+        async def first():
+            # a socket pair whose far end nobody reads and nobody closes
+            a, b = socket.socketpair()
+            a.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 16384)
+            keep.extend((b, a.dup()))
+            _, sw = await asyncio.open_connection(sock=a)
+            sock = sw.transport.get_extra_info("socket")
+            w = CorkedWriter(sw.transport, getattr(sock, "_sock", sock),
+                             sw, wp=wp)
+            w.hears_loss(_offloop_of(asyncio.get_running_loop(), wp))
+            w.writelines([_blob(1, 8 << 20)])
+            assert w._off_jobs == 1
+            await asyncio.sleep(0.1)
+            assert w._buffered > 0, "8 MiB went into a socket nobody reads"
+            keep.append(w)
+            return w._off
+
+        old = asyncio.run(asyncio.wait_for(first(), 30))
+        assert not old.closed and old.jobs and old.loop.is_closed()
+
+        async def second():
+            got = bytearray()
+
+            async def reading(reader, writer):
+                while chunk := await reader.read(1 << 20):
+                    got.extend(chunk)
+            srv = await asyncio.start_server(reading, "127.0.0.1", 0)
+            w = await writer_to(srv.sockets[0].getsockname()[1])
+            assert old.closed and not old.jobs  # abandoned on the way
+            assert w._off is not old and not w._off.closed
+            blob = _blob(2, 2 << 20)
+            w.writelines([blob])
+            await asyncio.wait_for(w.drain(), 10)
+            assert await _until(lambda: len(got) == len(blob), 5.0)
+            assert bytes(got) == blob
+            w.close()
+            srv.close()
+            w._off.close()
+
+        run(second())
+        after = wp.wire_sender_stats()
+        assert after["cancelled"] - before["cancelled"] == 1
+        assert after["submitted"] - before["submitted"] == 2
+        assert after["submitted"] == after["completed"] + after["failed"] \
+            + after["cancelled"]
+        assert all(off.closed for off in _OFFLOOPS.values())
+
+    def test_the_last_shutdown_stops_the_thread_and_the_next_use_starts_it(
+            self):
+        async def go():
+            from ceph_tpu.rados.messenger import _OFFLOOPS
+            wp = _needs_native()
+            loop = asyncio.get_running_loop()
+            for round_ in range(2):
+                server, client, addr = await _pair()
+                server.dispatcher = _swallow
+                conn = await client.connect(addr)
+                await _corked(conn)
+                starts = wp.wire_sender_stats()["starts"]
+                await conn.send(MTest(seqno=1, blob=_blob(1, 1 << 20)))
+                assert wp.wire_sender_stats()["starts"] == starts + 1
+                off = _OFFLOOPS[loop]
+                assert not off.closed and client in off.users
+                await client.shutdown()
+                # a messenger whose connections hand over keeps it open
+                assert off.closed == (server not in off.users)
+                await server.shutdown()
+                assert off.closed and not off.jobs
+                st = wp.wire_sender_stats()
+                assert st["submitted"] == st["completed"] + st["failed"] \
+                    + st["cancelled"]
+                # stopped: stopping again finds no thread
+                assert wp.wire_sender_stop() == 0
+
+        run(go())
+
+    def test_loops_on_threads_get_their_ends_under_one_lock(self):
+        """ms_reactor_mode = thread: loops that start together on several
+        threads each get an end of their own, the table is never walked
+        while it grows, the hooks are registered once (a second
+        thread_source would count loop.thread_messenger twice) and the
+        last end to close stops the thread."""
+        import sys
+        import threading
+        from ceph_tpu.common import tracing
+        from ceph_tpu.rados import messenger as msgr
+        wp = _needs_native()
+        n, rounds = 8, 12
+        gate = threading.Barrier(n)
+        errors, ends = [], []
+
+        def one():
+            loop = asyncio.new_event_loop()
+            try:
+                for _ in range(rounds):
+                    gate.wait(10)
+                    off = msgr._offloop_of(loop, wp)
+                    assert not off.closed and off.loop is loop
+                    ends.append(off)
+                    gate.wait(10)
+                    off.close()
+            except Exception as e:  # reported on the test's thread
+                errors.append(e)
+                gate.abort()
+            finally:
+                loop.close()
+
+        was = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        threads = [threading.Thread(target=one) for _ in range(n)]
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+        finally:
+            sys.setswitchinterval(was)
+        assert not errors, errors
+        assert not any(t.is_alive() for t in threads)
+        assert len(ends) == n * rounds and all(off.closed for off in ends)
+        assert len({id(off) for off in ends}) == n * rounds
+        assert sum(1 for layer, _, _ in tracing._THREAD_SOURCES
+                   if layer == "messenger") == 1
+        assert wp.wire_sender_stop() == 0  # the last close stopped it
+
+    def test_a_secure_stream_and_the_python_arm_never_hand_over(self):
+        async def go():
+            from ceph_tpu.rados.messenger import CorkedWriter
+            conf = {"ms_auth_secret": "s3", "ms_secure_mode": True}
+            server, client, addr = await _pair(dict(conf), dict(conf))
+            got = []
+
+            async def dispatch(conn, msg):
+                got.append(bytes(msg.blob))
+            server.dispatcher = dispatch
+            conn = await client.connect(addr)
+            for i in range(3):
+                await conn.send(MTest(seqno=i, blob=_blob(i, 1 << 20)))
+            assert await _until(lambda: len(got) == 3, 10.0)
+            assert got == [_blob(i, 1 << 20) for i in range(3)]
+            assert client.perf.dump()["tx_offloop_windows"] == 0
+            if isinstance(conn.writer, CorkedWriter):
+                assert conn.writer._off is None or not conn.writer._off_jobs
+            await client.shutdown()
+            await server.shutdown()
+
+        run(go())

@@ -274,7 +274,7 @@ def test_sanitized_native_build_runs_clean(tmp_path):
         ["g++", "-std=c++17", "-O1", *bridge.WARN_FLAGS,
          *bridge.SANITIZE_FLAGS, "-o", str(sdir / "wirepath_selftest"),
          str(wrapper), os.path.join(NATIVE, "wirepath.cc"),
-         os.path.join(NATIVE, "crc32c.cc")],
+         os.path.join(NATIVE, "crc32c.cc"), "-pthread"],
         check=True, capture_output=True)
     out = subprocess.run([str(sdir / "wirepath_selftest")],
                          capture_output=True, timeout=300)
@@ -428,3 +428,395 @@ def test_wirepath_hostile_geometry_refused():
         with pytest.raises(ValueError):
             bridge.wirepy_scatter_from(data, [len(data) - 8],
                                        [bytearray(64)])
+
+
+# -- the off-loop sender (ISSUE 49): native/wirepath.h, through ctypes --------
+
+
+@pytest.fixture
+def sender():
+    """The bridge with the process's sender thread idle before and after:
+    a socket pair whose far end nobody reads unless the test does, and an
+    eventfd as the completion channel."""
+    import socket
+
+    from ceph_tpu.native import bridge
+
+    try:
+        bridge.build()
+    except Exception as e:
+        pytest.skip(f"native wirepath unavailable: {e}")
+    if not hasattr(os, "eventfd"):
+        pytest.skip("no eventfd on this platform")
+    a, b = socket.socketpair()
+    a.setblocking(False)
+    b.setblocking(False)
+    chan = os.eventfd(0, os.EFD_NONBLOCK | os.EFD_CLOEXEC)
+    try:
+        yield bridge, a, b, chan
+    finally:
+        bridge.wire_sender_cancel(a.fileno())
+        bridge.wire_sender_reap(chan)  # or the channel is kept for them
+        bridge.wire_sender_close_chan(chan)
+        os.close(chan)
+        a.close()
+        b.close()
+
+
+def _reaped(bridge, chan, want, seconds=10.0, reap=None):
+    import time
+    out = []
+    deadline = time.monotonic() + seconds
+    while len(out) < want and time.monotonic() < deadline:
+        out.extend((reap or bridge.wire_sender_reap)(chan))
+        if len(out) < want:
+            time.sleep(0.002)
+    return out
+
+
+def _unfinished(st):
+    return st["submitted"] - st["completed"] - st["failed"] - st["cancelled"]
+
+
+def _recv_all(sock, n, seconds=10.0):
+    import time
+    got = bytearray()
+    deadline = time.monotonic() + seconds
+    while len(got) < n and time.monotonic() < deadline:
+        try:
+            chunk = sock.recv(1 << 20)
+        except BlockingIOError:
+            time.sleep(0.001)
+            continue
+        if not chunk:
+            break
+        got += chunk
+    return bytes(got)
+
+
+def test_sender_writes_jobs_of_one_fd_in_the_order_handed(sender):
+    bridge, a, b, chan = sender
+    before = bridge.wire_sender_stats()
+    rng = np.random.default_rng(49)
+    jobs = []
+    for token in range(1, 9):
+        segs = [rng.integers(0, 256, n, dtype=np.uint8).tobytes()
+                for n in (26, 8, 100 + token, 70000 * (token % 3))]
+        depth = bridge.wire_sender_submit(a.fileno(), chan, token, segs)
+        assert 0 <= depth < token
+        jobs.append((token, segs))
+    want = b"".join(b"".join(segs) for _, segs in jobs)
+    assert _recv_all(b, len(want)) == want
+    done = _reaped(bridge, chan, len(jobs))
+    assert [t for t, _, _ in done] == [t for t, _ in jobs]
+    assert [r for _, r, _ in done] == [sum(map(len, segs))
+                                       for _, segs in jobs]
+    # the eventfd was reset by the reap; nothing is left to take
+    with pytest.raises(BlockingIOError):
+        os.eventfd_read(chan)
+    assert bridge.wire_sender_reap(chan) == []
+    after = bridge.wire_sender_stats()
+    assert after["submitted"] - before["submitted"] == len(jobs)
+    assert after["completed"] - before["completed"] == len(jobs)
+    assert after["bytes"] - before["bytes"] == len(want)
+    assert after["writev_calls"] - before["writev_calls"] >= len(jobs)
+    assert after["writev_ns"] > before["writev_ns"]
+    assert after["signals"] - before["signals"] <= len(jobs)  # per batch
+    assert _unfinished(after) == _unfinished(before)
+
+
+def test_sender_a_reap_resets_an_eventfd_written_late(sender):
+    """The thread writes a channel's eventfd after it let go of its
+    mutex, so the write may land after the reap that took its
+    completions: the next reap finds nothing and still resets the
+    eventfd, or an event loop's reader would spin on it."""
+    import select
+    bridge, a, b, chan = sender
+    for token in range(1, 201):
+        bridge.wire_sender_submit(a.fileno(), chan, token, [b"x" * 64])
+        assert [t for t, _, _ in _reaped(bridge, chan, 1)] == [token]
+    assert len(_recv_all(b, 200 * 64)) == 200 * 64
+    st = bridge.wire_sender_stats()
+    assert _unfinished(st) == 0
+    os.eventfd_write(chan, 1)  # the late write, made by hand
+    assert bridge.wire_sender_reap(chan) == []
+    assert not select.select([chan], [], [], 0.05)[0]
+    # and whatever the 200 real ones left behind goes with one reap too
+    assert bridge.wire_sender_reap(chan) == []
+    assert not select.select([chan], [], [], 0.05)[0]
+
+
+def test_sender_refuses_bad_geometry_and_queues_nothing(sender):
+    import ctypes
+    bridge, a, b, chan = sender
+    before = bridge.wire_sender_stats()
+    for fd, ch, segs in ((-1, chan, [b"x"]), (a.fileno(), -1, [b"x"]),
+                         (a.fileno(), chan, []),
+                         (a.fileno(), chan, [b"", b""])):
+        with pytest.raises(OSError) as e:
+            bridge.wire_sender_submit(fd, ch, 7, segs)
+        assert e.value.errno == 22
+    lib = bridge.lib()
+    ptrs = (ctypes.c_void_p * 1)(None)
+    lens = (ctypes.c_size_t * 1)(64)  # a null segment that claims bytes
+    assert lib.ceph_tpu_wire_sender_submit(
+        a.fileno(), chan, 7, ptrs, lens, 1) == -22
+    assert lib.ceph_tpu_wire_sender_submit(
+        a.fileno(), chan, 7, None, None, 1) == -22
+    assert lib.ceph_tpu_wire_sender_reap(chan, None, None, None, 4) == -22
+    assert bridge.wire_sender_cancel(a.fileno()) == 0
+    after = bridge.wire_sender_stats()
+    assert after["submitted"] == before["submitted"]
+    assert bridge.wire_sender_reap(chan) == []
+    if bridge.has_wirepy():
+        with pytest.raises(OSError):
+            bridge.wirepy_sender_submit(a.fileno(), chan, 7, [b"x", 5])
+        with pytest.raises(OSError):
+            bridge.wirepy_sender_submit(a.fileno(), chan, 7, [])
+        assert bridge.wire_sender_stats()["submitted"] \
+            == before["submitted"]
+
+
+def test_sender_cancel_while_a_job_is_half_written(sender):
+    import socket
+    import time
+    bridge, a, b, chan = sender
+    a.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 16384)
+    before = bridge.wire_sender_stats()
+    big = bytes(8 << 20)
+    bridge.wire_sender_submit(a.fileno(), chan, 1, [b"head", big])
+    bridge.wire_sender_submit(a.fileno(), chan, 2, [b"behind"])
+    deadline = time.monotonic() + 10
+    while bridge.wire_sender_stats()["eagains"] == before["eagains"] \
+            and time.monotonic() < deadline:
+        time.sleep(0.002)
+    mid = bridge.wire_sender_stats()
+    assert mid["eagains"] > before["eagains"], "the socket never filled"
+    assert 0 < mid["bytes"] - before["bytes"] < len(big)
+    assert bridge.wire_sender_reap(chan) == []  # nothing ended yet
+    # the cancel returns with the thread off the fd: both jobs end
+    # -ECANCELED, in order, and the fd has nothing left
+    assert bridge.wire_sender_cancel(a.fileno()) == 2
+    done = _reaped(bridge, chan, 2)
+    assert [(t, r) for t, r, _ in done] == [(1, -125), (2, -125)]
+    assert done[0][2] >= 1  # the first found the socket full
+    assert bridge.wire_sender_cancel(a.fileno()) == 0
+    # what the far end gets is a prefix of the first job, then nothing
+    sent = bridge.wire_sender_stats()["bytes"] - before["bytes"]
+    got = _recv_all(b, sent)
+    assert got == (b"head" + big)[:sent]
+    time.sleep(0.05)
+    with pytest.raises(BlockingIOError):
+        b.recv(1)
+    after = bridge.wire_sender_stats()
+    assert after["cancelled"] - before["cancelled"] == 2
+    assert _unfinished(after) == _unfinished(before)
+
+
+def test_sender_a_full_socket_holds_back_its_own_fd_only(sender):
+    import socket
+    import time
+    bridge, a, b, chan = sender
+    c, d = socket.socketpair()
+    c.setblocking(False)
+    d.setblocking(False)
+    try:
+        a.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 16384)
+        before = bridge.wire_sender_stats()
+        big = bytes([7]) * (4 << 20)
+        bridge.wire_sender_submit(a.fileno(), chan, 1, [big])
+        deadline = time.monotonic() + 10
+        while bridge.wire_sender_stats()["eagains"] == before["eagains"] \
+                and time.monotonic() < deadline:
+            time.sleep(0.002)
+        # the parked fd waits in the thread's epoll; another fd's jobs pass
+        for token in (10, 11, 12):
+            bridge.wire_sender_submit(c.fileno(), chan, token,
+                                      [b"x" * 1000, bytes([token]) * 50000])
+        done = _reaped(bridge, chan, 3)
+        assert [t for t, _, _ in done] == [10, 11, 12]
+        assert len(_recv_all(d, 3 * 51000)) == 3 * 51000
+        # the reader resumes: EPOLLOUT wakes the job, it ends whole
+        assert _recv_all(b, len(big)) == big
+        done = _reaped(bridge, chan, 1)
+        assert [(t, r) for t, r, _ in done] == [(1, len(big))]
+        assert done[0][2] >= 1
+        after = bridge.wire_sender_stats()
+        assert after["completed"] - before["completed"] == 4
+        assert _unfinished(after) == _unfinished(before)
+    finally:
+        bridge.wire_sender_cancel(c.fileno())
+        c.close()
+        d.close()
+
+
+def test_sender_stop_with_jobs_queued_then_a_new_thread(sender):
+    import socket
+    import time
+    bridge, a, b, chan = sender
+    a.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 16384)
+    before = bridge.wire_sender_stats()
+    big = bytes(8 << 20)
+    bridge.wire_sender_submit(a.fileno(), chan, 1, [big])
+    bridge.wire_sender_submit(a.fileno(), chan, 2, [b"two"])
+    bridge.wire_sender_submit(a.fileno(), chan, 3, [b"three"])
+    deadline = time.monotonic() + 10
+    while bridge.wire_sender_stats()["eagains"] == before["eagains"] \
+            and time.monotonic() < deadline:
+        time.sleep(0.002)
+    assert bridge.wire_sender_stop() == 3
+    done = _reaped(bridge, chan, 3)
+    assert [(t, r) for t, r, _ in done] == [(1, -125), (2, -125), (3, -125)]
+    assert bridge.wire_sender_stop() == 0  # no thread to stop
+    mid = bridge.wire_sender_stats()
+    assert _unfinished(mid) == _unfinished(before)
+    # drain what the first job got out, then hand over again
+    sent = mid["bytes"] - before["bytes"]
+    assert _recv_all(b, sent) == big[:sent]
+    bridge.wire_sender_submit(a.fileno(), chan, 4, [b"after the stop"])
+    assert [(t, r) for t, r, _ in _reaped(bridge, chan, 1)] == [(4, 14)]
+    assert _recv_all(b, 14) == b"after the stop"
+    after = bridge.wire_sender_stats()
+    assert after["starts"] == mid["starts"] + 1
+    assert after["cancelled"] - before["cancelled"] == 3
+
+
+def test_sender_keeps_a_jobs_buffers_pinned_until_the_reap(sender):
+    import socket
+    import time
+    bridge, a, b, chan = sender
+    if not bridge.has_wirepy():
+        pytest.skip("wirepy shim unavailable")
+    a.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 16384)
+    before = bridge.wire_sender_stats()
+    buf = bytearray(b"p" * (4 << 20))
+    bridge.wirepy_sender_submit(a.fileno(), chan, 1, [b"hdr", buf])
+    with pytest.raises(OSError) as e:  # a token in flight is not reused
+        bridge.wirepy_sender_submit(a.fileno(), chan, 1, [b"again"])
+    assert e.value.errno == 17
+    # pinned: a bytearray with an exported view cannot change size
+    with pytest.raises(BufferError):
+        buf.extend(b"x")
+    want = b"hdr" + bytes(buf)
+    assert _recv_all(b, len(want)) == want
+    deadline = time.monotonic() + 10
+    while bridge.wire_sender_stats()["completed"] == before["completed"] \
+            and time.monotonic() < deadline:
+        time.sleep(0.002)
+    with pytest.raises(BufferError):  # ended on the thread, not yet reaped
+        buf.extend(b"x")
+    done = _reaped(bridge, chan, 1, reap=bridge.wirepy_sender_reap)
+    assert [(t, r) for t, r, _ in done] == [(1, len(want))]
+    buf.extend(b"x")  # released by the reap, on this thread
+    assert bridge.wirepy_sender_reap(chan) == []
+
+
+def test_sender_is_not_inherited_across_a_fork(sender):
+    bridge, a, b, chan = sender
+    bridge.wire_sender_submit(a.fileno(), chan, 1, [b"parent"])
+    assert [(t, r) for t, r, _ in _reaped(bridge, chan, 1)] == [(1, 6)]
+    assert bridge.wire_sender_stats()["starts"] >= 1
+    pid = os.fork()
+    if pid == 0:
+        code = 1
+        try:
+            st = bridge.wire_sender_stats()
+            if any(st.values()):
+                code = 2  # the child starts from nothing
+            else:
+                bridge.wire_sender_submit(a.fileno(), chan, 2, [b"child"])
+                done = _reaped(bridge, chan, 1)
+                st = bridge.wire_sender_stats()
+                ok = [(t, r) for t, r, _ in done] == [(2, 5)] \
+                    and st["starts"] == 1 and st["completed"] == 1
+                code = 0 if ok else 3
+                bridge.wire_sender_stop()
+        finally:
+            os._exit(code)
+    _, status = os.waitpid(pid, 0)
+    assert os.waitstatus_to_exitcode(status) == 0
+    assert _recv_all(b, 11) == b"parentchild"
+    # the parent's thread is the parent's still
+    bridge.wire_sender_submit(a.fileno(), chan, 3, [b"parent again"])
+    assert [(t, r) for t, r, _ in _reaped(bridge, chan, 1)] == [(3, 12)]
+
+
+def test_sender_many_threads_hand_over_at_once(sender):
+    """More submitting threads than cores, a short switch interval: every
+    job ends once, each fd's bytes are its own jobs' in the order handed,
+    and the counters close."""
+    import socket
+    import sys
+    import threading
+    bridge, _a, _b, chan = sender
+    workers, jobs_each = 2 * (os.cpu_count() or 4), 60
+    before = bridge.wire_sender_stats()
+    pairs = [socket.socketpair() for _ in range(workers)]
+    for x, y in pairs:
+        x.setblocking(False)
+        y.setblocking(False)
+    errors, got = [], [bytearray() for _ in range(workers)]
+    stop = threading.Event()
+    # the raw entry point pins nothing: the caller keeps every segment
+    # alive until its job was reaped
+    bodies = [[bytes([w]) * (50 + 997 * (j % 7)) + bytes([j])
+               for j in range(jobs_each)] for w in range(workers)]
+
+    def submit(w):
+        try:
+            fd = pairs[w][0].fileno()
+            for j in range(jobs_each):
+                bridge.wire_sender_submit(fd, chan, w * 1000 + j + 1,
+                                          [b"<", bodies[w][j], b">"])
+        except Exception as e:  # reported below, on the test's thread
+            errors.append(e)
+
+    def drain():
+        while not stop.is_set():
+            for w, (_, y) in enumerate(pairs):
+                try:
+                    got[w] += y.recv(1 << 20)
+                except BlockingIOError:
+                    pass
+            stop.wait(0.001)
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    reader = threading.Thread(target=drain)
+    threads = [threading.Thread(target=submit, args=(w,))
+               for w in range(workers)]
+    try:
+        reader.start()
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30)
+        assert not any(t.is_alive() for t in threads)
+        assert not errors, errors
+        done = _reaped(bridge, chan, workers * jobs_each, 30.0)
+    finally:
+        sys.setswitchinterval(was)
+        stop.set()
+        reader.join(10)
+        for x, y in pairs:
+            bridge.wire_sender_cancel(x.fileno())
+    assert not reader.is_alive()
+    assert sorted(t for t, _, _ in done) == sorted(
+        w * 1000 + j + 1 for w in range(workers) for j in range(jobs_each))
+    assert all(r > 0 for _, r, _ in done)
+    for w in range(workers):
+        mine = [t for t, _, _ in done if t // 1000 == w]
+        assert mine == sorted(mine), "an fd's jobs ended out of order"
+        for _, y in pairs[w:w + 1]:
+            try:
+                got[w] += y.recv(1 << 20)
+            except BlockingIOError:
+                pass
+        want = b"".join(b"<" + body + b">" for body in bodies[w])
+        assert bytes(got[w]) == want
+    for x, y in pairs:
+        x.close()
+        y.close()
+    after = bridge.wire_sender_stats()
+    assert after["completed"] - before["completed"] == workers * jobs_each
+    assert _unfinished(after) == _unfinished(before)
