@@ -126,7 +126,7 @@ DEFAULT_SCHEMA: Dict[str, Option] = _opts(
     Option("ms_crc_data", OPT_BOOL, True),
     Option("ms_local_fastpath", OPT_BOOL, False,
            desc="colocated vstart daemons skip the wire for same-process "
-                "peers (implies ms_colocated_ring unless set explicitly)"),
+                "peers"),
     Option("ms_compress_min_size", OPT_SIZE, 0,
            desc="compress frames >= this size; 0 disables on-wire compression"),
     Option("ms_dispatch_throttle_bytes", OPT_SIZE, 100 << 20),
@@ -135,23 +135,7 @@ DEFAULT_SCHEMA: Dict[str, Option] = _opts(
                 "messages so cross-daemon spans stitch into one tree"),
     Option("ms_auth_secret", OPT_STR, "",
            desc="shared cluster secret; non-empty enables cephx-style frames"),
-    # sharded multi-reactor wire plane (reference AsyncMessenger worker
-    # pool, src/msg/async/AsyncMessenger.h ms_async_op_threads)
-    Option("ms_async_op_threads", OPT_INT, 0, flags=(FLAG_STARTUP,),
-           desc="reactor workers per messenger, each its own event loop "
-                "owning a socket shard (0 = single-loop legacy path; "
-                "in ms_reactor_mode=process, 0 defaults to 2 workers)"),
-    Option("ms_reactor_mode", OPT_STR, "thread", flags=(FLAG_STARTUP,),
-           desc="reactor worker substrate: 'thread' (N event-loop "
-                "threads sharing the interpreter, the r13 plane) or "
-                "'process' (forked wire workers, each owning its socket "
-                "shard + its own wirepath arm; frames cross via "
-                "shared-memory rings into the home-loop dispatch pump). "
-                "The CEPH_TPU_REACTOR env overrides process-wide."),
-    Option("ms_shm_ring_bytes", OPT_SIZE, 4 << 20, flags=(FLAG_STARTUP,),
-           desc="per-direction shared-memory ring capacity of one "
-                "process-delegated connection; oversized frames stream "
-                "through in bounded pieces instead of deadlocking"),
+    # multi-lane peer sessions (messenger.py LaneGroup)
     Option("ms_lanes_per_peer", OPT_INT, 1, flags=(FLAG_STARTUP,), min=1,
            desc="parallel lanes per peer session (negotiated; lane 0 is "
                 "control-only, data stripes across the rest; 1 = single "
@@ -159,9 +143,6 @@ DEFAULT_SCHEMA: Dict[str, Option] = _opts(
     Option("ms_lane_stripe_min", OPT_SIZE, 1 << 20,
            desc="blobs at least this large fragment across ALL data "
                 "lanes concurrently (0 disables fragmentation)"),
-    Option("ms_colocated_ring", OPT_BOOL, False,
-           desc="negotiate a zero-serialization in-process ring with "
-                "colocated peers at connect time (falls back to TCP)"),
     Option("ms_wirepath_native", OPT_BOOL, True, flags=(FLAG_STARTUP,),
            desc="run the messenger's per-byte hot loop (frame crc, "
                 "scatter/gather, writev) through the released-GIL native "

@@ -49,10 +49,9 @@ class PerfCounters:
         # zeroing misreports until the next mutation — the owner re-sets
         # them here so `perf reset` restarts rates without lying gauges
         self.resync: Optional[Any] = None
-        # optional owner callback invoked BEFORE dump(): counters whose
-        # source of truth lives outside this process (the reactor worker
-        # processes' shared-memory blocks) refresh here so every dump
-        # reports the whole plane without a polling loop
+        # optional owner callback invoked BEFORE dump() and reset():
+        # counters whose source of truth lives outside the set (the loop
+        # meters' running sums) fold in here so every dump is up to now
         self.presample: Optional[Any] = None
 
     # -- hot path ------------------------------------------------------------

@@ -222,8 +222,7 @@ LOOP_LAYERS = ("client", "messenger", "osd", "ecplan", "store", "background",
 _LAYER_OF_TASK = {
     "client": "client", "librados": "client", "striper": "client",
     "benchmarks": "client",
-    "messenger": "messenger", "reactor": "messenger",
-    "reactor_proc": "messenger", "shm_ring": "messenger",
+    "messenger": "messenger",
     "osd": "osd", "scheduler": "osd", "service": "osd", "peering": "osd",
     "ecutil": "ecplan",
     "pagestore": "store", "memstore": "store", "bluestore": "store",
@@ -621,8 +620,8 @@ class LoopMeter:
             self._leave_thread()  # sections of this thread: off a loop again
             close()
         loop.close = closing
-        # the probe arms itself on the loop's own thread (a reactor's loop
-        # is metered from the thread that builds the worker)
+        # the probe arms itself on the loop's own thread (a loop may be
+        # metered from another, before it runs)
         loop.call_soon_threadsafe(self._arm_probe)
         _METERS.add(self)
         return self
@@ -858,12 +857,6 @@ class LoopMeter:
 _METERS: "weakref.WeakSet[LoopMeter]" = weakref.WeakSet()
 
 
-def _flush_meters(perf: PerfCounters) -> None:
-    for meter in list(_METERS):
-        if meter.perf is perf:
-            meter.flush()
-
-
 # (layer, read, [seconds, calls] folded in so far): what a NATIVE thread
 # did for a layer, kept by its library (the messenger's sender thread:
 # seconds inside writev)
@@ -878,7 +871,8 @@ def thread_source(layer: str, read) -> None:
 
 
 def _presample() -> None:
-    _flush_meters(LOOP_PERF)
+    for meter in list(_METERS):
+        meter.flush()
     for layer, read, seen in _THREAD_SOURCES:
         seconds, calls = read()
         if seconds < seen[0] or calls < seen[1]:
@@ -892,16 +886,11 @@ def _presample() -> None:
 LOOP_PERF.presample = _presample
 
 
-def install_loop_meter(loop=None, name: str = "") -> LoopMeter:
-    """Meter `loop` (default: the running one).  A daemon's home loop
-    feeds the process's `loop` set; a loop given a `name` (a reactor
-    worker's) gets a set of its own, `loop.<name>`.  Idempotent."""
+def install_loop_meter(loop=None) -> LoopMeter:
+    """Meter `loop` (default: the running one) into the process's `loop`
+    set.  Idempotent."""
     loop = loop or asyncio.get_running_loop()
     meter = getattr(loop, "_ceph_meter", None)
     if meter is None:
-        perf = LOOP_PERF
-        if name:
-            perf = build_loop_perf("loop." + name)
-            perf.presample = lambda: _flush_meters(perf)
-        meter = LoopMeter(loop, perf).install()
+        meter = LoopMeter(loop, LOOP_PERF).install()
     return meter

@@ -77,7 +77,7 @@ window that has data (a ping's ack beside its reply, an op's beside
 MOSDOpReply); (2) the BOUND on what the sender must hold — once the
 frames owed cover ``Messenger.ACK_OWED_BYTES`` (4 MiB, one put's worth)
 the ack leaves at once in a window of its own; (3) the messenger's SWEEP
-— one timer a messenger per loop (``_AckSweep``), armed only while a
+— one timer a messenger (``_AckSweep``), armed only while a
 connection of it owes, whose tick writes in ONE loop step the ack of
 every connection whose debt is ``Messenger.ACK_DELAY_S`` old (500 ms),
 through the connection's own write path and accounting.  Most sockets here carry data one way only
@@ -95,39 +95,28 @@ and clears the outbox — un-flushed frames replay from the unacked queue
 onto the adopted transport in seq order, and the receiver's dedupe floor
 makes any flush/replay overlap exactly-once.
 
-Sharded multi-reactor wire plane (reactor.py + the lane layer here):
+One loop, one wire: a messenger lives on ONE event loop (the one that
+ran ``bind`` or its first ``connect``) and reaches a peer over TCP —
+plaintext (``FrameReceiver`` + ``CorkedWriter``; the native wirepath, or
+the python arm where the library does not build) or ``SecureStream`` —
+or, between daemons of one process that both set ``ms_local_fastpath``
+(the test clusters), through ``LocalConnection``.  Nothing else.
 
-- **Reactor pool** (``ms_async_op_threads``): N reactor workers, each a
-  thread with its own event loop owning a shard of sockets (reference
-  AsyncMessenger worker pool).  Outbound data lanes are bound to workers
-  by a stable hash of (peer, lane); inbound sockets shard across the
-  workers' dup'd listening fds.  Socket work (framing, crc, sendmsg,
-  recv memcpy — all GIL-releasing) runs on the owning reactor; dispatch
-  hops back to the daemon's home loop, so daemon state stays
-  single-loop.  Each reactor-owned connection charges a per-worker
-  dispatch throttle (receive backpressure is per shard).
-- **Multi-lane peer striping** (``ms_lanes_per_peer`` > 1, negotiated —
-  an old peer that doesn't advertise ``lanes_ok`` gets one lane): a peer
-  pair opens N parallel lanes, each a full Connection (own cork/outbox,
-  own seq space, own unacked replay queue, own flusher).  Lane 0 is the
-  CONTROL lane — pings, acks, maps, backoffs, health are never queued
-  behind data.  Data-plane messages (LANE_STRIPE types) are striped
-  round-robin across lanes 1..N-1, stamped with a connection-global
-  ``gseq``; the receiving LaneGroup reassembles gseq order before
-  dispatch, so per-(peer,type) ordering (in fact total data-plane
-  order) and the reqid/dedup machinery above are preserved.  Messages
-  with blobs >= ``ms_lane_stripe_min`` are FRAGMENTED: the blob splits
-  into per-lane MLaneSegment frames sent concurrently and reassembled
-  into one buffer on the receiver — one large transfer rides all lanes
-  at once.  A dead lane pins and replays only ITS unacked frames
-  (per-lane sessions); the remaining lanes keep draining, and the gseq
-  reorder buffer absorbs the replayed hole.
-- **Colocated ring transport** (``ms_colocated_ring``): the handshake
-  hello carries a per-process token; when both ends share the process
-  (vstart/test topology, bench loopback arm) the acceptor offers an
-  in-process RingPipe pair in its fin and both sides swap the TCP
-  session for a zero-serialization ring (BufferList views hand over by
-  reference).  Any negotiation failure falls back to TCP transparently.
+Multi-lane peer striping (``ms_lanes_per_peer`` > 1, negotiated — an old
+peer that doesn't advertise ``lanes_ok`` gets one lane): a peer pair
+opens N parallel lanes, each a full Connection (own cork/outbox, own seq
+space, own unacked replay queue, own flusher), all on the messenger's
+loop.  Lane 0 is the CONTROL lane — pings, acks, maps, backoffs, health
+are never queued behind data.  Data-plane messages (LANE_STRIPE types)
+are striped round-robin across lanes 1..N-1, stamped with a
+connection-global ``gseq``; the receiving LaneGroup reassembles gseq
+order before dispatch, so per-(peer,type) ordering (in fact total
+data-plane order) and the reqid/dedup machinery above are preserved.
+Messages with blobs >= ``ms_lane_stripe_min`` are FRAGMENTED: the blob
+splits into per-lane MLaneSegment frames sent concurrently and
+reassembled into one buffer on the receiver.  A dead lane pins and
+replays only ITS unacked frames (per-lane sessions); the remaining lanes
+keep draining, and the gseq reorder buffer absorbs the replayed hole.
 """
 
 from __future__ import annotations
@@ -143,7 +132,6 @@ import json
 import os
 import pickle
 import random
-import socket as socket_mod
 import struct
 import threading
 import time
@@ -159,16 +147,10 @@ from ceph_tpu.common import tracing
 from ceph_tpu.common.perf_counters import PerfCounters, PerfCountersBuilder
 from ceph_tpu.common.throttle import Throttle
 from ceph_tpu.utils import wirepath as _wirepath
-from ceph_tpu.rados.reactor import (PROC_TOKEN, ReactorPool, RingConnection,
-                                    ring_abandon, ring_claim, ring_offer)
-from ceph_tpu.rados.reactor_proc import ShmConnEndpoint, delegate_socket
-from ceph_tpu.rados.shm_ring import (FRAME_HDR as _SHM_FRAME_HDR,
-                                     REC_EOF as _SHM_REC_EOF,
-                                     REC_ERR as _SHM_REC_ERR,
-                                     REC_FRAME as _SHM_REC_FRAME,
-                                     RF_BLOB as _SHM_RF_BLOB,
-                                     RF_FIXED as _SHM_RF_FIXED,
-                                     RF_VERIFIED as _SHM_RF_VERIFIED)
+
+# Per-process identity token; MLaneHello.proc carries a short digest of
+# it, for diagnostics.  Random (not pid): a pid recurs across containers.
+PROC_TOKEN = random.randbytes(16).hex()
 
 
 def _build_wire_perf() -> PerfCounters:
@@ -305,11 +287,9 @@ def _build_wire_perf() -> PerfCounters:
                       "blob frames reusing an app-level crc on the wire")
     b.add_u64_counter("rx_batches", "multi-frame rx dispatch batches")
     b.add_histogram("rx_batch_msgs", "messages per rx dispatch batch")
-    # multi-lane / reactor / ring plane (module docstring "Sharded
-    # multi-reactor wire plane"); per-lane splits ride dynamic
-    # tx_lane<k>_msgs / tx_lane<k>_bytes counters
-    b.add_u64_counter("ring_msgs", "colocated ring handoffs (no framing, "
-                                   "no socket, no serialization)")
+    # multi-lane plane (module docstring "Multi-lane peer striping");
+    # per-lane splits ride dynamic tx_lane<k>_msgs / tx_lane<k>_bytes
+    # counters
     b.add_u64_counter("lane_rx_parked",
                       "striped frames parked awaiting a gseq gap")
     b.add_u64_counter("lane_frag_tx", "lane fragments sent (large blobs "
@@ -349,28 +329,6 @@ def _build_wire_perf() -> PerfCounters:
     # the BENCH record reports wire tx/rx TAILS, not just means
     b.add_histogram("tx_io_us", "socket write+drain µs per flush window")
     b.add_histogram("rx_io_us", "payload read µs per frame")
-    # process-sharded reactor plane (ms_reactor_mode=process): the
-    # byte-loop counters now live in the WORKER PROCESSES' counter
-    # blocks; these proc_* aggregates are refreshed from shared memory
-    # at dump time (perf.presample) so `perf dump`, /metrics and BENCH
-    # see the whole plane, not just the parent's share.  Values are
-    # ABSOLUTE since worker spawn (a perf reset does not zero a worker).
-    b.add_u64("proc_workers", "live reactor worker processes")
-    b.add_u64("proc_delegated_conns",
-              "connections delegated to worker processes (absolute)")
-    b.add_u64("proc_rx_frames",
-              "frames parsed+verified in worker processes (absolute)")
-    b.add_u64("proc_rx_bytes", "frame bytes received by workers (absolute)")
-    b.add_u64("proc_tx_calls", "socket write passes by workers (absolute)")
-    b.add_u64("proc_tx_bytes", "bytes written by workers (absolute)")
-    b.add_u64("proc_native_rx_calls",
-              "released-GIL rx wirepath calls in workers (absolute)")
-    b.add_u64("proc_native_tx_calls",
-              "released-GIL tx wirepath calls in workers (absolute)")
-    b.add_u64("proc_native_bytes",
-              "bytes touched by worker wirepath passes (absolute)")
-    b.add_u64("proc_worker_respawns",
-              "worker processes respawned after death (absolute)")
     return b.create_perf_counters()
 
 BANNER = b"ceph_tpu msgr v2\n"
@@ -490,7 +448,7 @@ class MLaneHello:
     connection-negotiation fields of the wire plane).  Lane 0's hello
     CREATES the group on the acceptor; joining lanes attach to it.
     ``proc`` carries a short digest of the sender's process token for
-    diagnostics only — colocation trust rides the handshake hello."""
+    diagnostics only."""
 
     group: str = ""
     lane: int = 0
@@ -1360,8 +1318,7 @@ class FrameReceiver(asyncio.BufferedProtocol):
 
     def unframed(self) -> bytes:
         """The bytes received that are not a frame yet, as the wire had
-        them: what the worker process a delegated socket goes to has to
-        start from."""
+        them (the framer's tests read where it stands)."""
         if self._body is None:
             return bytes(self._head_mv[self._pos:self._fill])
         return self._front + bytes(self._body[:self._body_pos])
@@ -1755,8 +1712,9 @@ class _Offloop:
 
 
 # the loops' ends of the sender thread; a job's token is the process's.
-# Loops run on several threads under ms_reactor_mode = thread: the lock
-# guards the table, the one-time hooks and each end's users / closed
+# A process runs several loops (one after another in tests, beside each
+# other for a client on a thread of its own): the lock guards the table,
+# the one-time hooks and each end's users / closed
 _OFFLOOPS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 _OFFLOOP_TOKENS = itertools.count(1)
 _OFFLOOP_HOOKS = False
@@ -1803,19 +1761,18 @@ def _offloop_of(loop, wp, user: Optional["Messenger"] = None
 
 
 class _AckSweep:
-    """One messenger's owed acks on one event loop (module docstring
-    "Acks WAIT FOR COMPANY"): the connections that owe, and the ONE timer
+    """One messenger's owed acks (module docstring "Acks WAIT FOR
+    COMPANY"): the connections that owe, and the ONE timer
     that settles what no data window and no bound has.  A tick writes, in
     its own loop step, the ack of every connection whose debt would pass
     ACK_DELAY_S before a further tick could come (a quarter of the
     deadline ahead: at most four ticks a deadline, none while nothing is
     owed), then re-arms for the oldest debt left."""
 
-    __slots__ = ("messenger", "loop", "owing", "timer", "ticks")
+    __slots__ = ("messenger", "owing", "timer", "ticks")
 
-    def __init__(self, messenger: "Messenger", loop) -> None:
+    def __init__(self, messenger: "Messenger") -> None:
         self.messenger = messenger
-        self.loop = loop
         # connections whose debt the sweep has yet to look at; one that
         # was settled another way, or went with its transport, leaves at
         # the next tick
@@ -1827,7 +1784,6 @@ class _AckSweep:
         """`conn` began to owe an ack (its _ack_since says when)."""
         self.owing[conn] = None
         if self.timer is None and not self.messenger._shutdown:
-            # on the connection's own loop (its serve task owes)
             self.timer = asyncio.get_running_loop().call_later(
                 self.messenger.ACK_DELAY_S, self._tick)
 
@@ -1885,27 +1841,11 @@ class Connection:
         self.transport_gen = 0
         self.out_seq = 0
         self.in_seq = 0  # highest data seq dispatched (dedupe floor)
-        # multi-reactor plane: the event loop owning this connection's
-        # transport (all of its coroutine work runs there; cross-loop
-        # senders hop via Messenger._conn_send), the reactor worker when
-        # one owns the shard, and the lane-group membership when this
-        # connection is one lane of a striped peer session
-        try:
-            self.loop: Optional[asyncio.AbstractEventLoop] = \
-                asyncio.get_running_loop()
-        except RuntimeError:
-            self.loop = None
-        self.reactor = None  # ReactorWorker owning this socket's shard
-        # process mode: the reactor worker PROCESS this connection's
-        # socket was delegated to (reader/writer are ShmConnEndpoints)
-        self.shm_worker = None
+        # the lane-group membership when this connection is one lane of
+        # a striped peer session
         self.lane_group: Optional["LaneGroup"] = None
         self.lane_idx = 0
-        # dispatch throttle for THIS connection's loop: the home loop
-        # shares the messenger-wide throttle; each reactor worker gets
-        # its own (receive backpressure is per shard — asyncio futures
-        # inside Throttle are loop-bound)
-        self.throttle = messenger._throttle_here()
+        self.throttle = messenger.dispatch_throttle
         # per-connection session id: acceptors key replay sessions on it, so
         # a REPLACED connection never collides with its predecessor's seqs
         self.session_id = random.randbytes(8).hex()
@@ -1928,14 +1868,12 @@ class Connection:
         # the debt covers, when it began (time.monotonic), the
         # counter of the way it leaves ALONE once it is due (tx_acks_bound
         # / tx_acks_swept; None = not due: only a data window takes it),
-        # the highest seq an ack frame has carried, and the messenger's
-        # sweep on this connection's loop
+        # the highest seq an ack frame has carried
         self._ack_pending = -1
         self._ack_bytes = 0
         self._ack_since = 0.0
         self._ack_due: Optional[str] = None
         self._ack_sent = 0
-        self._ack_sweep: Optional["_AckSweep"] = None
         self._flush_fut: Optional[asyncio.Future] = None
         self._flusher: Optional[asyncio.Task] = None
         self._corked_ok = bool(_cget(messenger.conf, "ms_corked_writev",
@@ -2103,9 +2041,9 @@ class Connection:
                 self._ack_pending = seq
         else:
             self._ack_pending = seq
-            sweep = self._ack_sweep
+            sweep = m._ack_sweep
             if sweep is None:
-                sweep = self._ack_sweep = m._ack_sweep_here()
+                sweep = m._ack_sweep = _AckSweep(m)
             self._ack_since = time.monotonic()
             sweep.owe(self)
         self._ack_bytes += nbytes
@@ -2448,16 +2386,9 @@ class Connection:
         """Payload length of the next COMPLETE frame in hand: a frame
         the FrameReceiver stashed first, else whatever is fully buffered
         on a reader of another kind — the serve loop's rx batching
-        predicate (batch only what needs no network wait).
-        Delegated connections peek the shm ring instead: a fully
-        buffered record needs no worker round-trip."""
+        predicate (batch only what needs no network wait)."""
         if self._rx_stash:
             return self._rx_stash[0][4]
-        if isinstance(self.reader, ShmConnEndpoint):
-            n = self.reader.complete_record_len()
-            if n is None:
-                return None
-            return max(0, n - _SHM_FRAME_HDR.size)
         return Messenger._buffered_frame_len(self.reader)
 
     async def read_frame(self) -> Tuple[int, int, int, bytes, int, Any,
@@ -2469,7 +2400,7 @@ class Connection:
         lands ahead of it); the caller must put() cost back when done
         with the payload.  The reader's type picks the path: a
         FrameReceiver has framed the stream already and this pops its
-        stash; a delegated connection reads its shm ring; a SecureStream
+        stash; a SecureStream
         (bytes are decrypted before they can land) or a plain
         StreamReader takes the readexactly chain below.  Blob frames (FLAG_BLOB) return the bulk bytes
         separately, checked against their own crc32c — ``blob_verified``
@@ -2494,8 +2425,6 @@ class Connection:
                 r.popped(frame[4])
             await self.throttle.get(frame[4])
             return frame
-        if isinstance(r, ShmConnEndpoint):
-            return await self._read_frame_shm()
         hdr = await self.reader.readexactly(_HDR.size)
         length, type_id, version, flags, crc, seq = _HDR.unpack(hdr)
         cost = length
@@ -2547,76 +2476,6 @@ class Connection:
         perf.inc("rx_bytes", _HDR.size + length)
         return (type_id, version, seq, payload, cost, blob,
                 bool(flags & FLAG_FIXED), blob_verified)
-
-    async def _read_frame_shm(self) -> Tuple[int, int, int, bytes, int,
-                                             Any, bool, bool]:
-        """Delegated-connection read_frame: the worker process already
-        parsed, crc-verified (its own wirepath arm) and decompressed the
-        frame; this side consumes the record from the shm ring.  Same
-        contract as read_frame: throttle charged before the payload is
-        copied out (and RETURNED on every error path — the r13 cost
-        discipline extended to the process plane), lane fragments land
-        straight in their slice of the group assembly buffer, EOF and
-        crc failure surface exactly like the socket path's."""
-        ep = self.reader
-        kind, length = await ep.read_record_hdr()
-        if kind == _SHM_REC_EOF:
-            raise ConnectionResetError("delegated transport eof")
-        if kind == _SHM_REC_ERR:
-            raise BadFrame(
-                (await ep.read_exact(length)).decode("utf-8", "replace"))
-        if kind != _SHM_REC_FRAME:
-            raise BadFrame(f"unknown shm record kind {kind}")
-        fh = await ep.read_exact(_SHM_FRAME_HDR.size)
-        type_id, version, rflags, seq, plen, blen = _SHM_FRAME_HDR.unpack(fh)
-        cost = plen + blen
-        await self.throttle.get(cost)
-        t_io = time.monotonic()
-        try:
-            payload = await ep.read_exact(plen)
-            blob = None
-            if rflags & _SHM_RF_BLOB:
-                cls = _MSG_TYPES.get(type_id)
-                dest = None
-                if cls is MLaneSegment and self.lane_group is not None \
-                        and (rflags & _SHM_RF_FIXED) and blen \
-                        and not (seq and seq <= self.in_seq):
-                    # zero-copy reassembly across the process seam: the
-                    # fragment's chunk reads shm -> its assembly slice
-                    # (in_seq guard as in the socket paths — a replayed
-                    # duplicate must not re-open reassembly state)
-                    try:
-                        seg = _unpack_fixed(cls, payload, None)
-                        dest = self.lane_group.frag_view(seg, blen)
-                    except Exception:
-                        dest = None
-                if dest is not None:
-                    await ep.read_into(dest, blen)
-                    blob = dest
-                elif getattr(cls, "BLOB_VIEW_OK", False):
-                    # rx -> install staging: page-aligned so a
-                    # writeback install's h2d reads an aligned source
-                    # (pinnable where pinned DMA exists) — the ring
-                    # views native-gather straight into it, zero
-                    # parent-side per-byte passes after the kernel
-                    from ceph_tpu.rados.pagestore import install_staging
-
-                    blob = install_staging(blen)
-                    await ep.read_into(blob, blen)
-                else:
-                    blob = bytearray(blen)
-                    await ep.read_into(blob, blen)
-        except BaseException:
-            self.throttle.put(cost)
-            raise
-        perf = self.messenger.perf
-        rx_dt = time.monotonic() - t_io
-        perf.tinc("rx_io", rx_dt)
-        perf.hinc("rx_io_us", rx_dt * 1e6)
-        perf.inc("rx_bytes", _HDR.size + cost)
-        return (type_id, version, seq, payload, cost, blob,
-                bool(rflags & _SHM_RF_FIXED),
-                bool(rflags & _SHM_RF_VERIFIED))
 
     async def adopt_transport(self, reader, writer) -> None:
         """Adopt a fresh transport into this session and replay unacked
@@ -2674,8 +2533,8 @@ class Connection:
 
 class LaneGroup:
     """A striped peer session: N lane Connections plus the cross-lane
-    sequencing/reassembly seam (module docstring "Sharded multi-reactor
-    wire plane").  Duck-types the Connection surface daemons touch
+    sequencing/reassembly seam (module docstring "Multi-lane peer
+    striping").  Duck-types the Connection surface daemons touch
     (send / close / peer / peer_name / auth metadata), so handlers reply
     through the group and replies stripe too.
 
@@ -2684,15 +2543,13 @@ class LaneGroup:
     ``frag_min`` split into MLaneSegment fragments sent over ALL data
     lanes concurrently.  RX: every lane's serve loop pushes decoded
     messages here; gseq order is restored (holes park, a dead lane's
-    replay fills them), fragments reassemble, and a single pump task on
-    the messenger's home loop dispatches in order — one serialization
-    point, so the ordering guarantee holds even when lanes live on
-    different reactor threads.
+    replay fills them), fragments reassemble, and a single pump task
+    dispatches in order.
 
     Throttle note: frames PARKED for a gap or a partial reassembly
     release their dispatch-throttle cost at park time (a dead lane may
     hold a gap open for seconds; holding budget hostage would stall the
-    shard's other sessions) — parked memory is instead bounded by
+    messenger's other sessions) — parked memory is instead bounded by
     PARK_CAP, past which the reorderer force-drains in gseq order."""
 
     PARK_CAP = 8192  # parked frames before the reorderer force-drains
@@ -2719,8 +2576,7 @@ class LaneGroup:
                                   1 << 20) or 0)
         self._tx_gseq = 0
         self._rr = 0
-        # rx reorder + reassembly state, guarded for cross-reactor lanes
-        self._lock = threading.Lock()
+        # rx reorder + reassembly state
         self._rx_next = 1
         self._parked: Dict[int, Tuple[Any, Any]] = {}  # gseq -> (conn, msg)
         # gseq -> [seen, chunks, hdr, all_verified, buf, confirmed_ranges]
@@ -2769,7 +2625,7 @@ class LaneGroup:
         cls = type(msg)
         if not getattr(cls, "LANE_STRIPE", False):
             # control plane: lane 0, no gseq — never queued behind data
-            await self.messenger._conn_send(self._lane(0), msg)
+            await self._lane(0).send(msg)
             return
         self._tx_gseq += 1
         gseq = self._tx_gseq
@@ -2783,7 +2639,7 @@ class LaneGroup:
                 return
         idx = 1 + (gseq - 1) % self.n_data_lanes
         self._note_lane_tx(idx, blob_len)
-        await self.messenger._conn_send(self._lane(idx), msg)
+        await self._lane(idx).send(msg)
 
     def _note_lane_tx(self, idx: int, nbytes: int) -> None:
         p = self.messenger.perf
@@ -2831,8 +2687,7 @@ class LaneGroup:
                                 chunk=chunk)
             lane_idx = 1 + (gseq + i - 1) % n
             self._note_lane_tx(lane_idx, len(chunk))
-            sends.append(self.messenger._conn_send(
-                self._lane(lane_idx), frag))
+            sends.append(self._lane(lane_idx).send(frag))
             off += len(chunk)
         self.messenger.perf.inc("lane_frag_tx", n)
         results = await asyncio.gather(*sends, return_exceptions=True)
@@ -2849,24 +2704,22 @@ class LaneGroup:
         feeds the ready run to the single dispatch pump.  Cost transfers
         with READY messages (released after dispatch); parked frames
         release theirs immediately (see class docstring)."""
-        with self._lock:
-            ready = self._ingest(conn, msg)
-            first = True
-            for c, m in ready:
-                # THIS arrival's cost rides the first ready entry
-                # (parked entries released theirs at park time; a
-                # reassembled message inherits its completing
-                # fragment's) — pump returns it, to the ARRIVAL's shard
-                # throttle, after dispatch
-                self._fifo.append((c, m, cost if first else 0, conn))
-                first = False
+        ready = self._ingest(conn, msg)
+        first = True
+        for c, m in ready:
+            # THIS arrival's cost rides the first ready entry (parked
+            # entries released theirs at park time; a reassembled
+            # message inherits its completing fragment's) — pump
+            # returns it after dispatch
+            self._fifo.append((c, m, cost if first else 0))
+            first = False
         if not ready and cost:
-            self.messenger._throttle_put(conn, cost)
+            self.messenger.dispatch_throttle.put(cost)
         if ready:
             self._kick_pump()
 
     def _ingest(self, conn: Connection, msg: Any):
-        """Under _lock: returns the in-order run of (conn, msg) this
+        """Returns the in-order run of (conn, msg) this
         arrival unlocks ([] when it parked)."""
         if type(msg).__name__ == "MLaneSegment":
             msg = self._ingest_fragment(conn, msg)
@@ -2914,20 +2767,19 @@ class LaneGroup:
             # implausible geometry (corrupt/hostile frame): refuse the
             # assembly allocation before the crc check can reject it
             return None
-        with self._lock:
-            st = self._frag_state(seg.gseq, seg.nfrags, seg.total)
-            if st is None:
-                return None
-            if self._range_conflict(st, seg.idx, seg.off, blob_len):
-                # overlaps a CONFIRMED fragment (or re-claims a consumed
-                # idx): land in a private buffer instead — the crc check
-                # will kill the corrupt frame without stomping verified
-                # bytes, and a mere duplicate is dropped by _ingest
-                return None
-            return memoryview(st[4]).cast("B")[seg.off:seg.off + blob_len]
+        st = self._frag_state(seg.gseq, seg.nfrags, seg.total)
+        if st is None:
+            return None
+        if self._range_conflict(st, seg.idx, seg.off, blob_len):
+            # overlaps a CONFIRMED fragment (or re-claims a consumed
+            # idx): land in a private buffer instead — the crc check
+            # will kill the corrupt frame without stomping verified
+            # bytes, and a mere duplicate is dropped by _ingest
+            return None
+        return memoryview(st[4]).cast("B")[seg.off:seg.off + blob_len]
 
     def _frag_state(self, gseq: int, nfrags: int, total: int):
-        """Under _lock: the reassembly entry for gseq, created if absent
+        """The reassembly entry for gseq, created if absent
         and the caps allow; None when refused (stale gseq, geometry
         mismatch, or the FRAG_MAX_* memory bounds)."""
         st = self._frags.get(gseq)
@@ -3023,22 +2875,6 @@ class LaneGroup:
         return msg
 
     def _kick_pump(self) -> None:
-        home = self.messenger.home_loop
-        if home is None:
-            try:
-                home = asyncio.get_running_loop()
-            except RuntimeError:
-                return
-        try:
-            running = asyncio.get_running_loop()
-        except RuntimeError:
-            running = None
-        if running is home:
-            self._ensure_pump()
-        else:
-            home.call_soon_threadsafe(self._ensure_pump)
-
-    def _ensure_pump(self) -> None:
         if self._wake is None:
             self._wake = asyncio.Event()
         self._wake.set()
@@ -3050,7 +2886,7 @@ class LaneGroup:
             self._pump_task.add_done_callback(m._tasks.discard)
 
     async def _pump(self) -> None:
-        """The group's single ordered dispatcher, on the home loop."""
+        """The group's single ordered dispatcher."""
         m = self.messenger
         while not self.closed and not m._shutdown:
             await self._wake.wait()
@@ -3060,13 +2896,11 @@ class LaneGroup:
 
     async def _pump_once(self, m: "Messenger") -> None:
         batch: list = []
-        costs: list = []
-        with self._lock:
-            while self._fifo and len(batch) < m.RX_BATCH_MSGS:
-                conn, msg, cost, cost_conn = self._fifo.popleft()
-                batch.append((conn, msg))
-                if cost:
-                    costs.append((cost_conn, cost))
+        costs = 0
+        while self._fifo and len(batch) < m.RX_BATCH_MSGS:
+            conn, msg, cost = self._fifo.popleft()
+            batch.append((conn, msg))
+            costs += cost
         if not batch:
             return
         try:
@@ -3086,8 +2920,8 @@ class LaneGroup:
         except Exception:
             traceback.print_exc()
         finally:
-            for conn, cost in costs:
-                m._throttle_put(conn, cost)
+            if costs:
+                m.dispatch_throttle.put(costs)
 
     # -- lifecycle ------------------------------------------------------------
 
@@ -3101,21 +2935,19 @@ class LaneGroup:
         self.closed = True
         for conn in self.lanes:
             if conn is not None:
-                await self.messenger._conn_close(conn)
+                await conn.close()
         if self._pump_task is not None:
             self._pump_task.cancel()
         # undispatched fifo entries still hold dispatch-throttle budget
-        # (pump releases after dispatch): return it now or the shard's
-        # receive path leaks it permanently under group churn
-        with self._lock:
-            entries = list(self._fifo)
-            self._fifo.clear()
-            self._parked.clear()
-            self._frags.clear()
-            self._frag_bytes = 0
-        for _c, _m, cost, cost_conn in entries:
-            if cost:
-                self.messenger._throttle_put(cost_conn, cost)
+        # (pump releases after dispatch): return it now or the receive
+        # path leaks it permanently under group churn
+        owed = sum(cost for _c, _m, cost in self._fifo)
+        self._fifo.clear()
+        self._parked.clear()
+        self._frags.clear()
+        self._frag_bytes = 0
+        if owed:
+            self.messenger.dispatch_throttle.put(owed)
 
     def dump(self) -> Dict[str, Any]:
         lanes = []
@@ -3129,22 +2961,12 @@ class LaneGroup:
                 "outbox_frames": c._outbox_frames,
                 "outbox_bytes": c._outbox_bytes,
                 "unacked": len(c.unacked),
-                "out_seq": c.out_seq, "in_seq": c.in_seq,
-                "reactor": c.reactor.index if c.reactor is not None
-                else None,
-                # process mode: worker pid + per-shard shm-ring depths
-                "shm": (c.reader.dump()
-                        if isinstance(c.reader, ShmConnEndpoint)
-                        else None)})
-        with self._lock:
-            parked = len(self._parked)
-            fifo = len(self._fifo)
-            frags = len(self._frags)
+                "out_seq": c.out_seq, "in_seq": c.in_seq})
         return {"peer": list(self.peer), "group": self.group_id,
                 "outbound": self.outbound, "n_lanes": self.n_lanes,
                 "tx_gseq": self._tx_gseq, "rx_next": self._rx_next,
-                "rx_parked": parked, "rx_fifo": fifo,
-                "reassembling": frags, "lanes": lanes}
+                "rx_parked": len(self._parked), "rx_fifo": len(self._fifo),
+                "reassembling": len(self._frags), "lanes": lanes}
 
 
 # -- messenger ---------------------------------------------------------------
@@ -3233,65 +3055,14 @@ class Messenger:
             _cget(self.conf, "ms_local_fastpath", False))
         self._local_conns: Dict[Tuple[str, int], LocalConnection] = {}
         self._loop: Optional[asyncio.AbstractEventLoop] = None
-        # -- sharded multi-reactor wire plane (module docstring) -------------
-        # the daemon's dispatch loop; reactor-owned serve loops hop here
-        self.home_loop: Optional[asyncio.AbstractEventLoop] = None
-        # reactor substrate: thread shards (r13) or forked worker
-        # PROCESSES (ms_reactor_mode=process / CEPH_TPU_REACTOR=) whose
-        # sockets run on truly independent cores, frames crossing via
-        # shm rings into the home-loop dispatch pump (reactor_proc.py)
-        mode = str(_cget(self.conf, "ms_reactor_mode", "thread")
-                   or "thread").strip().lower()
-        env_mode = os.environ.get("CEPH_TPU_REACTOR", "").strip().lower()
-        if env_mode in ("thread", "process"):
-            mode = env_mode
-        elif env_mode in ("0", "off"):
-            mode = "thread"
-        if mode not in ("thread", "process"):
-            mode = "thread"
-        if mode == "process" and not hasattr(os, "fork"):
-            mode = "thread"  # non-posix host: degrade, never fail
-        self.reactor_mode = mode
-        n_reactors = int(_cget(self.conf, "ms_async_op_threads", 0) or 0)
-        if mode == "process" and n_reactors <= 0:
-            n_reactors = 2  # process mode implies a pool
-        self.reactors: Optional[ReactorPool] = (
-            ReactorPool(name, n_reactors, mode=mode,
-                        use_native=self.wirepath is not None)
-            if n_reactors > 0 else None)
-        self.shm_ring_bytes = int(
-            _cget(self.conf, "ms_shm_ring_bytes", 4 << 20) or (4 << 20))
-        self._conn_ids = itertools.count(1)
-        # worker-process counters fold into this set at dump time
-        self.perf.presample = self._refresh_proc_perf
         self.lanes_per_peer = max(1, int(
             _cget(self.conf, "ms_lanes_per_peer", 1) or 1))
-        # colocated ring transport: negotiated at connect time; never
-        # engaged under secure mode, configured auth, or socket-fault
-        # injection (those configurations exist to exercise the real
-        # wire, and authorization decisions key on how a peer proved
-        # itself over it)
-        self._ring_ok = bool(
-            _cget(self.conf, "ms_colocated_ring", False)
-            and not _cget(self.conf, "ms_secure_mode", False)
-            and not _cget(self.conf, "ms_auth_secret", "")
-            and not _cget(self.conf, "auth_cephx", False)
-            and not _cget(self.conf, "ms_inject_socket_failures", 0))
-        # live ring connections (both directions), for dump_reactors
-        # and shutdown — acceptor-side rings are not in _conns
-        self._ring_conns: list = []
         # acceptor-side lane groups, keyed by group id (LRU-capped with
-        # the session table); guarded — lanes may land on reactor loops
+        # the session table)
         self._lane_groups: "collections.OrderedDict[str, LaneGroup]" = (
             collections.OrderedDict())
-        self._lane_lock = threading.Lock()
-        self._sessions_lock = threading.Lock()
-        # per-reactor-loop dispatch throttles (Throttle futures are
-        # loop-bound; backpressure is per shard)
-        self._loop_throttles: Dict[Any, Throttle] = {}
-        # the sweep of owed acks, one per loop that owns connections of
-        # this messenger (the home loop; each reactor worker's)
-        self._ack_sweeps: Dict[Any, _AckSweep] = {}
+        # the sweep of owed acks, made when a connection first owes
+        self._ack_sweep: Optional[_AckSweep] = None
 
     def policy_for(self, peer_type: str) -> Policy:
         return self.policies.get(peer_type, Policy.lossy_client())
@@ -3303,24 +3074,6 @@ class Messenger:
         if log is not None:
             log.dout("ms", level, message)
 
-    # -- cross-loop plumbing (reactor plane) ---------------------------------
-
-    def _throttle_here(self) -> Throttle:
-        """Dispatch throttle for the CURRENT loop: the messenger-wide
-        one on the home loop, a per-worker one on reactor loops."""
-        try:
-            loop = asyncio.get_running_loop()
-        except RuntimeError:
-            return self.dispatch_throttle
-        if self.home_loop is None or loop is self.home_loop:
-            return self.dispatch_throttle
-        t = self._loop_throttles.get(loop)
-        if t is None:
-            t = self._loop_throttles[loop] = Throttle(
-                f"{self.name}-dispatch-shard",
-                _cget(self.conf, "ms_dispatch_throttle_bytes", 100 << 20))
-        return t
-
     def _offloop_here(self) -> Optional[_Offloop]:
         """The CURRENT loop's end of the process's sender thread, this
         messenger among its users until it shuts down; None on the python
@@ -3328,261 +3081,6 @@ class Messenger:
         if self.wirepath is None or self._shutdown:
             return None
         return _offloop_of(asyncio.get_running_loop(), self.wirepath, self)
-
-    def _ack_sweep_here(self) -> "_AckSweep":
-        """The sweep of owed acks for the CURRENT loop (a connection's
-        serve loop asks once)."""
-        loop = asyncio.get_running_loop()
-        return self._ack_sweeps.setdefault(loop, _AckSweep(self, loop))
-
-    def _throttle_put(self, conn, cost: int) -> None:
-        """Return dispatch-throttle budget to ``conn``'s shard, from any
-        loop (Throttle wakeups are loop-bound futures)."""
-        if not cost:
-            return
-        loop = getattr(conn, "loop", None)
-        throttle = getattr(conn, "throttle", None)
-        if throttle is None:
-            return
-        try:
-            here = asyncio.get_running_loop()
-        except RuntimeError:
-            here = None
-        if loop is None or loop is here or loop.is_closed():
-            throttle.put(cost)
-        else:
-            loop.call_soon_threadsafe(throttle.put, cost)
-
-    async def _conn_send(self, conn, msg: Any) -> None:
-        """Send on a connection that may live on another loop (its
-        reactor shard): hop with run_coroutine_threadsafe, no-op hop for
-        home-loop connections."""
-        loop = getattr(conn, "loop", None)
-        if loop is None or loop is asyncio.get_running_loop():
-            await conn.send(msg)
-            return
-        fut = asyncio.run_coroutine_threadsafe(conn.send(msg), loop)
-        await asyncio.wrap_future(fut)
-
-    async def _conn_close(self, conn) -> None:
-        loop = getattr(conn, "loop", None)
-        try:
-            here = asyncio.get_running_loop()
-        except RuntimeError:
-            here = None
-        if loop is None or loop is here or loop.is_closed():
-            await conn.close()
-            return
-        fut = asyncio.run_coroutine_threadsafe(conn.close(), loop)
-        try:
-            await asyncio.wait_for(asyncio.wrap_future(fut), timeout=1.0)
-        except Exception:
-            pass
-
-    async def _dispatch_home(self, conn, msg: Any) -> None:
-        """Invoke the daemon dispatcher on the HOME loop (daemon state is
-        single-loop); serve loops on reactor shards hop here."""
-        if self.dispatcher is None:
-            return
-        if self.home_loop is None \
-                or self.home_loop is asyncio.get_running_loop():
-            was = tracing.mark(self._daemon_layer)
-            try:
-                await self.dispatcher(conn, msg)
-            finally:
-                tracing.mark(was)
-            return
-        fut = asyncio.run_coroutine_threadsafe(
-            self.dispatcher(conn, msg), self.home_loop)
-        await asyncio.wrap_future(fut)
-
-    async def _dispatch_group_home(self, conn, msgs: list) -> None:
-        if self.group_dispatcher is None:
-            return
-        if self.home_loop is None \
-                or self.home_loop is asyncio.get_running_loop():
-            was = tracing.mark(self._daemon_layer)
-            try:
-                await self.group_dispatcher(conn, msgs)
-            finally:
-                tracing.mark(was)
-            return
-        fut = asyncio.run_coroutine_threadsafe(
-            self.group_dispatcher(conn, msgs), self.home_loop)
-        await asyncio.wrap_future(fut)
-
-    # -- process-sharded reactor plane (delegation seam) ---------------------
-
-    def _delegatable(self) -> bool:
-        return (self.reactors is not None
-                and self.reactors.mode == "process")
-
-    def _crc_mode_for(self, crc_fn, crc_enabled: bool) -> str:
-        if not crc_enabled:
-            return "off"
-        return "shared" if crc_fn is checksum else "zlib"
-
-    def _delegate_transport(self, reader, writer, worker, crc_fn,
-                            crc_enabled: bool):
-        """Hand a live plaintext transport to a reactor worker PROCESS:
-        extract the raw socket + any already-buffered rx bytes, build
-        the shm ring pair, send the fd over the worker's ctrl channel,
-        and close the parent's copy (the worker's dup now OWNS the
-        socket — worker death = transport death, the revival signal).
-        Returns (reader, writer) shm endpoints, or None when this
-        transport can't delegate (secure stream, no raw socket, pending
-        tx bytes, worker unavailable) — the caller keeps the in-process
-        transport, a graceful fallback never an error."""
-        pool = self.reactors
-        if not self._delegatable() or not pool.ensure_worker(worker):
-            return None
-        # raw socket extraction (plaintext only — a SecureStream has no
-        # transport to hand across; delegation happens below the AES
-        # layer or not at all)
-        if isinstance(writer, CorkedWriter):
-            transport, sock = writer._transport, writer._sock
-            if writer._buffered:
-                return None  # unsent segments would interleave
-        elif isinstance(writer, asyncio.StreamWriter):
-            transport = writer.transport
-            sock = transport.get_extra_info("socket") \
-                if transport is not None else None
-            sock = getattr(sock, "_sock", sock)
-        else:
-            return None
-        if transport is None or sock is None or transport.is_closing():
-            return None
-        try:
-            if transport.get_write_buffer_size() != 0:
-                return None  # buffered tx would race the worker's writes
-        except Exception:
-            return None
-        if not isinstance(reader, (FrameReceiver, asyncio.StreamReader)):
-            return None
-        try:
-            transport.pause_reading()
-        except Exception:
-            pass
-        # leftover rx bytes: the reader lets go of them only after the
-        # ctrl handoff succeeds, so a failed delegation leaves it intact
-        leftover = reader.unframed() if isinstance(reader, FrameReceiver) \
-            else bytes(reader._buffer)
-        conn_id = next(self._conn_ids)
-        try:
-            ep = delegate_socket(worker, conn_id, sock.fileno(), leftover,
-                                 self.shm_ring_bytes,
-                                 self._crc_mode_for(crc_fn, crc_enabled),
-                                 wp=self.wirepath, perf=self.perf)
-        except OSError:
-            ep = None
-        if ep is None:
-            try:
-                transport.resume_reading()
-            except Exception:
-                pass
-            return None
-        # handoff complete: the worker owns a dup of the fd.  Clear the
-        # captured bytes from the parent reader and close our copy.
-        if isinstance(reader, FrameReceiver):
-            reader._dead = True
-        else:
-            reader._buffer.clear()
-        if isinstance(writer, CorkedWriter):
-            writer._detach()
-        try:
-            transport.close()
-        except Exception:
-            pass
-        # proc_delegated_conns has ONE owner: the presample refresh
-        # (worker.sockets tally) — no inc here, two sources would drift
-        self.dout(4, f"conn {conn_id} delegated to reactor worker "
-                     f"{worker.index} (pid {worker.pid})")
-        return ep, ep
-
-    async def _delegate_conn(self, conn: "Connection", lane: int) -> None:
-        """Delegate a LIVE connection (acceptor side, right after its
-        MLaneHello bound it into a lane group).  Runs under the send
-        lock so an in-flight flush window can't race the writer swap;
-        the caller is the connection's own serve loop, so no reader
-        race exists."""
-        if isinstance(conn.reader, ShmConnEndpoint) or conn.closed:
-            return
-        worker = self.reactors.worker_for(conn.peer, lane)
-        async with conn._send_lock:
-            if conn.closed or isinstance(conn.reader, ShmConnEndpoint):
-                return
-            pair = self._delegate_transport(conn.reader, conn.writer,
-                                            worker, conn.crc_fn,
-                                            conn.crc_enabled)
-            if pair is None:
-                return
-            conn.reader, conn.writer = pair
-            conn.shm_worker = worker
-
-    def _accepted_fd_cb(self, fd: int, worker) -> None:
-        """A worker's accept loop forwarded a fresh inbound socket: run
-        the normal handshake/accept path on the home loop (auth,
-        session resume and ring negotiation need parent state)."""
-        loop = self.home_loop
-        if loop is None or loop.is_closed() or self._shutdown:
-            try:
-                os.close(fd)
-            except OSError:
-                pass
-            return
-
-        async def _adopt():
-            try:
-                sock = socket_mod.socket(fileno=fd)
-            except OSError:
-                try:
-                    os.close(fd)
-                except OSError:
-                    pass
-                return
-            try:
-                sock.setblocking(False)
-                reader, writer = await asyncio.open_connection(sock=sock)
-            except OSError:
-                # close via the OBJECT (it owns the fd now): a raw
-                # os.close here would double-close a number the socket
-                # destructor closes again later — onto whoever reused it
-                sock.close()
-                return
-            await self._accept(reader, writer)
-
-        def _spawn():
-            # runs ON the home loop (call_soon_threadsafe below)
-            t = asyncio.get_running_loop().create_task(_adopt())
-            self._tasks.add(t)
-            t.add_done_callback(self._tasks.discard)
-
-        loop.call_soon_threadsafe(_spawn)
-
-    def _refresh_proc_perf(self) -> None:
-        """perf presample hook: fold the worker processes' counter
-        blocks into the wire set so the daemon's `perf dump` (and with
-        it /metrics and BENCH) reports the WHOLE reactor plane."""
-        pool = self.reactors
-        if pool is None or pool.mode != "process":
-            return
-        agg = pool.counters_sum()
-        if not agg:
-            return
-        p = self.perf
-        p.set("proc_workers",
-              sum(1 for w in pool.workers if w.is_alive()))
-        p.set("proc_delegated_conns",
-              sum(w.sockets for w in pool.workers))
-        p.set("proc_worker_respawns",
-              sum(w.respawns for w in pool.workers))
-        p.set("proc_rx_frames", agg.get("rx_frames", 0))
-        p.set("proc_rx_bytes", agg.get("rx_bytes", 0))
-        p.set("proc_tx_calls", agg.get("tx_calls", 0))
-        p.set("proc_tx_bytes", agg.get("tx_bytes", 0))
-        p.set("proc_native_rx_calls", agg.get("native_rx_calls", 0))
-        p.set("proc_native_tx_calls", agg.get("native_tx_calls", 0))
-        p.set("proc_native_bytes", agg.get("native_bytes", 0))
 
     # -- wire accounting -----------------------------------------------------
 
@@ -3592,7 +3090,7 @@ class Messenger:
         together: what a daemon notes down when the peer did not answer."""
         conn = self._conns.get(tuple(addr))
         lanes = getattr(conn, "lanes", None) or [conn]
-        # (a colocated ring or an absent session holds neither)
+        # (an absent session holds neither)
         return (sum(len(getattr(c, "unacked", ())) for c in lanes),
                 sum(getattr(c, "_outbox_bytes", 0) for c in lanes))
 
@@ -3669,13 +3167,11 @@ class Messenger:
         return s, s
 
     async def _handshake_out(self, reader, writer, lossless: bool,
-                             session_id: str, want_ring: bool = False):
-        """Returns (peer_name, resumed, peer_ckind, lanes_ok, ring_id,
-        reader, writer) — the pair is AES-GCM wrapped when secure mode
-        was negotiated.  ``lanes_ok`` says the acceptor understands the
-        multi-lane plane (old peers fall back to one lane); ``ring_id``
-        is non-empty when the acceptor offered a colocated in-process
-        ring (its fin carries the id; see reactor.py)."""
+                             session_id: str):
+        """Returns (peer_name, resumed, peer_ckind, lanes_ok, reader,
+        writer) — the pair is AES-GCM wrapped when secure mode was
+        negotiated.  ``lanes_ok`` says the acceptor understands the
+        multi-lane plane (old peers fall back to one lane)."""
         secure_want = bool(_cget(self.conf, "ms_secure_mode", False))
         writer.write(BANNER)
         nonce = random.randbytes(16)
@@ -3683,7 +3179,6 @@ class Messenger:
                  "nonce": nonce.hex(), "auth": "",
                  "session": session_id, "lossless": lossless,
                  "secure": secure_want, "ckind": checksum_kind(),
-                 "proc": PROC_TOKEN, "ring": bool(want_ring),
                  "lanes_ok": True}
         if self.ticket is not None:
             hello["ticket"] = self.ticket.hex()
@@ -3727,13 +3222,12 @@ class Messenger:
             reader, writer = self._wrap_secure(reader, writer, skey)
         return (peer_hello.get("name", ""), bool(peer_hello.get("resumed")),
                 peer_hello.get("ckind", "zlib"),
-                bool(peer_hello.get("lanes_ok")),
-                str(fin.get("ring", "") or ""), reader, writer)
+                bool(peer_hello.get("lanes_ok")), reader, writer)
 
     async def _handshake_in(self, reader, writer):
         """Returns (peer_name, peer_type, session, lossless, auth_kind,
-        auth_entity_type, reader, writer) — the pair is AES-GCM wrapped
-        when secure mode was negotiated.  ``auth_kind`` records HOW the
+        auth_entity_type, peer_ckind, reader, writer) — the pair is
+        AES-GCM wrapped when secure mode was negotiated.  ``auth_kind`` records HOW the
         peer proved itself ("ticket", "secret", or "none"): authorization
         decisions (e.g. who may fetch the rotating service secrets) key on
         it, not on the peer's self-declared type."""
@@ -3778,28 +3272,13 @@ class Messenger:
                  "nonce": nonce.hex(),
                  "auth": self._auth_tag(their_nonce, key, transcript),
                  "resumed": resumed, "secure": secure_want,
-                 "ckind": checksum_kind(),
-                 "proc": PROC_TOKEN, "lanes_ok": True}
+                 "ckind": checksum_kind(), "lanes_ok": True}
         writer.write(json.dumps(hello).encode() + b"\n")
         await writer.drain()
         proof = json.loads(await reader.readline())
         expect = self._auth_tag(nonce, key, transcript)
         ok = not expect or hmac.compare_digest(proof.get("auth", ""), expect)
-        # colocated ring offer (reactor.py): only to an AUTHENTICATED
-        # peer that shares our process token and asked for one — the fin
-        # carries the ring id the initiator claims from the in-process
-        # registry.  Never under secure mode (the wire security applies
-        # to wires; a colocated ring has none, but the configuration
-        # asked to exercise the secured path).
-        ring_offered: Optional[Tuple[str, Any, Any]] = None
-        fin: Dict[str, Any] = {"ok": ok}
-        if (ok and self._ring_ok and not secure_want
-                and peer_hello.get("ring")
-                and peer_hello.get("proc") == PROC_TOKEN):
-            ring_id, rx, tx = ring_offer()
-            ring_offered = (ring_id, rx, tx)
-            fin["ring"] = ring_id
-        writer.write(json.dumps(fin).encode() + b"\n")
+        writer.write(json.dumps({"ok": ok}).encode() + b"\n")
         await writer.drain()
         if not ok:
             raise PermissionError(f"auth failed for peer {peer_hello.get('name')}")
@@ -3816,8 +3295,7 @@ class Messenger:
         return (peer_hello.get("name", ""), peer_hello.get("type", "client"),
                 peer_hello.get("session", ""), bool(peer_hello.get("lossless")),
                 auth_kind, auth_entity_type,
-                peer_hello.get("ckind", "zlib"), ring_offered,
-                reader, writer)
+                peer_hello.get("ckind", "zlib"), reader, writer)
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -3832,31 +3310,13 @@ class Messenger:
             await conn.close()
 
     async def bind(self, host: str = "127.0.0.1", port: int = 0) -> Tuple[str, int]:
-        self.home_loop = asyncio.get_running_loop()
         self.server = await asyncio.start_server(self._accept, host, port)
         self.addr = self.server.sockets[0].getsockname()[:2]
-        if self.reactors is not None:
-            self.reactors.log = self.log
-            # shard the listening socket across the reactor workers:
-            # inbound sockets are owned by whichever reactor accepts
-            self.reactors.start()
-            try:
-                if self.reactors.mode == "process":
-                    # worker processes accept on dup'd listening fds
-                    # and forward fresh sockets here for the handshake
-                    self.reactors.serve_shards_process(
-                        self.server.sockets[0], self._accepted_fd_cb)
-                else:
-                    await self.reactors.serve_shards(
-                        self.server.sockets[0], self._accept)
-            except (OSError, NotImplementedError):
-                pass  # platform without dup'd-fd accept: home loop only
         if self._local_fastpath:
             self._loop = asyncio.get_running_loop()
             _LOCAL_REGISTRY[tuple(self.addr)] = self
-        self.dout(1, f"bind {self.addr[0]}:{self.addr[1]} (reactors "
-                     f"{self.reactors.n_workers if self.reactors else 0}, "
-                     f"lanes/peer {self.lanes_per_peer})")
+        self.dout(1, f"bind {self.addr[0]}:{self.addr[1]} "
+                     f"(lanes/peer {self.lanes_per_peer})")
         return self.addr
 
     @staticmethod
@@ -3874,97 +3334,30 @@ class Messenger:
         try:
             try:
                 (peer_name, peer_type, cookie, lossless, auth_kind,
-                 auth_entity_type, peer_ckind, ring_offered,
+                 auth_entity_type, peer_ckind,
                  reader, writer) = await self._handshake_in(reader, writer)
             except (PermissionError, BadFrame, ConnectionError, json.JSONDecodeError,
                     asyncio.IncompleteReadError, ValueError):
                 writer.close()
                 return
-            if ring_offered is not None:
-                # colocated ring negotiated: the TCP socket's job is
-                # done — serve the in-process ring instead
-                ring_id, rx, tx = ring_offered
-                rconn = RingConnection(self, peer, peer_name, rx, tx,
-                                       outbound=False,
-                                       auth_entity_type=auth_entity_type)
-                self._ring_conns.append(rconn)
-                rconn.start_pump()
-                try:
-                    writer.close()
-                except Exception:
-                    pass
-                return
             evicted_conns = []
             if lossless and cookie:
-                with self._sessions_lock:
-                    conn = self._sessions.get(cookie)
-                    if conn is not None:
-                        self._sessions.move_to_end(cookie)
-                    else:
-                        conn = Connection(self, reader, writer, peer,
-                                          Policy.lossless_peer(), peer_name)
-                        self._sessions[cookie] = conn
-                        while len(self._sessions) > MAX_SESSIONS:
-                            _, ev = self._sessions.popitem(last=False)
-                            evicted_conns.append(ev)
+                conn = self._sessions.get(cookie)
+                if conn is not None:
+                    self._sessions.move_to_end(cookie)
+                else:
+                    conn = Connection(self, reader, writer, peer,
+                                      Policy.lossless_peer(), peer_name)
+                    self._sessions[cookie] = conn
+                    while len(self._sessions) > MAX_SESSIONS:
+                        _, ev = self._sessions.popitem(last=False)
+                        evicted_conns.append(ev)
                 for ev in evicted_conns:
-                    await self._conn_close(ev)
-                here = asyncio.get_running_loop()
-                if conn.reader is not reader \
-                        and conn.loop not in (None, here):
-                    # session reconnect landed on a different reactor
-                    # shard than the session's owner: migrate the fresh
-                    # socket to the owning loop (transports and the
-                    # session's replay machinery are loop-bound)
-                    conn.auth_kind = auth_kind
-                    conn.auth_entity_type = auth_entity_type
-                    pair = await self._migrate_transport(reader, writer,
-                                                         conn.loop)
-                    if pair is None:
-                        # unmigratable (secure stream / dead socket):
-                        # forget the session — the initiator's next dial
-                        # starts a fresh one (reqid dedupe above absorbs
-                        # the at-least-once window, acceptor-restart rule)
-                        with self._sessions_lock:
-                            if self._sessions.get(cookie) is conn:
-                                self._sessions.pop(cookie, None)
-                        await self._conn_close(conn)
-                        return
-                    r2, w2 = pair
-                    conn.crc_fn = self._negotiated_crc(peer_ckind)
-
-                    async def _adopt_and_serve():
-                        await conn.adopt_transport(r2, w2)
-                        await self._serve(conn)
-
-                    fut = asyncio.run_coroutine_threadsafe(
-                        _adopt_and_serve(), conn.loop)
-                    await asyncio.wrap_future(fut)
-                    return
+                    await ev.close()
                 if conn.reader is not reader:
                     # session reconnect: adopt the new socket, replay our
                     # un-acked frames (e.g. replies lost in the drop)
-                    pair = None
-                    if (self._delegatable() and conn.lane_group is not None
-                            and conn.lane_idx >= 1):
-                        # revived acceptor-side data lane: its byte work
-                        # goes back to a worker process (pending replies
-                        # replay through the ring inside adopt)
-                        w = self.reactors.worker_for(conn.peer,
-                                                     conn.lane_idx)
-                        pair = self._delegate_transport(
-                            reader, writer, w,
-                            self._negotiated_crc(peer_ckind),
-                            conn.crc_enabled)
-                        if pair is not None:
-                            reader, writer = pair
-                            conn.shm_worker = w
-                    try:
-                        await conn.adopt_transport(reader, writer)
-                    except BaseException:
-                        if pair is not None:
-                            pair[0].close()
-                        raise
+                    await conn.adopt_transport(reader, writer)
             else:
                 conn = Connection(self, reader, writer, peer,
                                   Policy.lossy_client(), peer_name)
@@ -3973,61 +3366,9 @@ class Messenger:
             conn.auth_kind = auth_kind
             conn.auth_entity_type = auth_entity_type
             conn.crc_fn = self._negotiated_crc(peer_ckind)
-            if conn.reactor is None and self.reactors is not None:
-                try:
-                    conn.reactor = next(
-                        w for w in self.reactors.workers
-                        if w.loop is asyncio.get_running_loop())
-                    conn.reactor.sockets += 1
-                except StopIteration:
-                    pass
             await self._serve(conn)
         finally:
             self._tasks.discard(task)
-
-    async def _migrate_transport(self, reader, writer, target_loop):
-        """Move a freshly-accepted plaintext socket to another loop:
-        dup the fd, close the local transport (the dup keeps the socket
-        open), rebuild the stream pair on the target loop with any
-        already-buffered bytes carried over.  Returns (reader, writer)
-        on the target loop, or None when the socket can't be migrated."""
-        if not isinstance(reader, asyncio.StreamReader):
-            return None  # SecureStream: no raw transport to migrate
-        transport = writer.transport
-        try:
-            transport.pause_reading()
-        except Exception:
-            pass
-        leftover = bytes(reader._buffer)
-        reader._buffer.clear()
-        sock = transport.get_extra_info("socket")
-        sock = getattr(sock, "_sock", sock)
-        try:
-            dup = sock.dup()
-            dup.setblocking(False)
-        except Exception:
-            return None
-        transport.close()
-
-        async def _attach():
-            r, w = await asyncio.open_connection(sock=dup)
-            if leftover:
-                # no await between open and feed: the new transport has
-                # not had a chance to deliver socket bytes yet, so the
-                # leftover keeps its position at the front of the stream
-                r.feed_data(leftover)
-            return r, w
-
-        fut = asyncio.run_coroutine_threadsafe(_attach(), target_loop)
-        try:
-            return await asyncio.wait_for(asyncio.wrap_future(fut),
-                                          timeout=2.0)
-        except Exception:
-            try:
-                dup.close()
-            except Exception:
-                pass
-            return None
 
     # rx batch budget: how many already-buffered frames one dispatch
     # round may drain before acking (bounds latency of the first ack and
@@ -4119,8 +3460,6 @@ class Messenger:
                                 msg._wire_verified = True
                             self._note_rx(slot, _HDR.size + cost,
                                           time.monotonic() - t_dec)
-                            if conn.reactor is not None:
-                                conn.reactor.rx_msgs += 1
                             log = self.log
                             if log is not None and log.wants("ms", 20):
                                 # per-frame rx trace: debug_ms 20 only
@@ -4151,13 +3490,6 @@ class Messenger:
                                 conn.in_seq = max(conn.in_seq, seq)
                                 conn.queue_ack(seq, cost)
                             conn.throttle.put(cost)
-                            if msg.lane >= 1 and self._delegatable():
-                                # process mode: a freshly bound DATA
-                                # lane's socket moves to its worker
-                                # process; this serve loop keeps
-                                # running, now pulling records off the
-                                # shm ring instead of the socket
-                                await self._delegate_conn(conn, msg.lane)
                             continue
                         if conn.lane_group is not None:
                             # striped session: the LaneGroup restores
@@ -4198,16 +3530,21 @@ class Messenger:
                                     key = self._type_slot(msg.TYPE_ID).charge
                                     whose[key] = whose.get(key, 0) + 1
                                 tracing.charge_many(whose, claim=False)
-                            await self._dispatch_group_home(
-                                conn, [m for _, m in batch])
+                            was = tracing.mark(self._daemon_layer)
+                            try:
+                                await self.group_dispatcher(
+                                    conn, [m for _, m in batch])
+                            finally:
+                                tracing.mark(was)
                         elif self.dispatcher is not None:
                             many = len(batch) > 1  # else decode's holds
                             for _, msg in batch:
                                 if many:
                                     tracing.charge(self._type_slot(
                                         msg.TYPE_ID).charge, claim=False)
+                                was = tracing.mark(self._daemon_layer)
                                 try:
-                                    await self._dispatch_home(conn, msg)
+                                    await self.dispatcher(conn, msg)
                                 except (asyncio.CancelledError,
                                         GeneratorExit):
                                     raise
@@ -4215,6 +3552,8 @@ class Messenger:
                                     # a dispatcher bug must not wedge the
                                     # session into infinite redelivery
                                     traceback.print_exc()
+                                finally:
+                                    tracing.mark(was)
                     except (asyncio.CancelledError, GeneratorExit):
                         raise
                     except Exception:
@@ -4284,35 +3623,28 @@ class Messenger:
         """Acceptor side of lane negotiation: an MLaneHello (first frame
         on every lane) attaches the carrying connection to its group,
         creating the group on lane 0's hello."""
-        evicted = []
-        with self._lane_lock:
-            group = self._lane_groups.get(m.group)
-            if group is None:
-                group = LaneGroup(self, conn.peer, m.group,
-                                  max(2, m.n_lanes), outbound=False,
-                                  policy=conn.policy)
-                self._lane_groups[m.group] = group
-                while len(self._lane_groups) > MAX_SESSIONS:
-                    _, old = self._lane_groups.popitem(last=False)
-                    evicted.append(old)
-            else:
-                self._lane_groups.move_to_end(m.group)
-        for old in evicted:
-            # full close on the home loop (lanes + pump + queued
-            # throttle costs), not just a flag — _bind_lane may run on
-            # a reactor serve loop, so hop
-            old.closed = True
-            home = self.home_loop
-            if home is not None and not home.is_closed():
-                home.call_soon_threadsafe(
-                    lambda g=old: home.create_task(g.close()))
+        group = self._lane_groups.get(m.group)
+        if group is None:
+            group = LaneGroup(self, conn.peer, m.group,
+                              max(2, m.n_lanes), outbound=False,
+                              policy=conn.policy)
+            self._lane_groups[m.group] = group
+            while len(self._lane_groups) > MAX_SESSIONS:
+                # full close (lanes + pump + queued throttle costs),
+                # not just a flag
+                _, old = self._lane_groups.popitem(last=False)
+                old.closed = True
+                t = asyncio.get_running_loop().create_task(old.close())
+                self._tasks.add(t)
+                t.add_done_callback(self._tasks.discard)
+        else:
+            self._lane_groups.move_to_end(m.group)
         self.dout(4, f"lane {m.lane}/{m.n_lanes} bound for group "
                      f"{m.group[:8]} from {conn.peer[0]}:{conn.peer[1]}")
         group.bind_lane(conn, m.lane)
 
     async def _revive_lane(self, group: LaneGroup, conn: Connection) -> None:
-        """Initiator-side failover for one dead lossless lane: redial on
-        the lane's own loop (the stable worker hash put us here), adopt
+        """Initiator-side failover for one dead lossless lane: redial, adopt
         the fresh transport into the SAME lane session — its pinned
         unacked frames (and only its) replay; the gseq reorder buffer on
         the far side absorbs the refilled hole.  An acceptor that lost
@@ -4338,11 +3670,9 @@ class Messenger:
                 except (ConnectionError, OSError):
                     continue
                 try:
-                    (peer_name, resumed, peer_ckind, lanes_ok, ring_id,
+                    (peer_name, resumed, peer_ckind, lanes_ok,
                      reader, writer) = await self._handshake_out(
                         reader, writer, True, conn.session_id)
-                    if ring_id:
-                        ring_abandon(ring_id)
                 except TRANSPORT_ERRORS:
                     try:
                         writer.close()
@@ -4357,29 +3687,7 @@ class Messenger:
                     await self._group_fatal(group)
                     return
                 conn.crc_fn = self._negotiated_crc(peer_ckind)
-                pair = None
-                if self._delegatable() and conn.lane_idx >= 1:
-                    # the shard revives in a worker PROCESS (a fresh one
-                    # if the old worker died — ensure_worker respawns
-                    # the slot); the pinned unacked frames replay
-                    # through the new shm ring inside adopt_transport
-                    worker = self.reactors.worker_for(group.peer,
-                                                      conn.lane_idx)
-                    pair = self._delegate_transport(reader, writer,
-                                                    worker, conn.crc_fn,
-                                                    conn.crc_enabled)
-                    if pair is not None:
-                        reader, writer = pair
-                        conn.shm_worker = worker
-                try:
-                    await conn.adopt_transport(reader, writer)
-                except BaseException:
-                    # adopt failed/cancelled AFTER the handoff: the shm
-                    # pair must not outlive it (teardown returns parked
-                    # budget + unlinks the shared memory)
-                    if pair is not None:
-                        pair[0].close()
-                    raise
+                await conn.adopt_transport(reader, writer)
                 self.perf.inc("lane_revivals")
                 self.dout(1, f"lane revived in place for group "
                              f"{group.group_id[:8]} peer "
@@ -4413,16 +3721,11 @@ class Messenger:
         transport, unacked replay); dead lossy connections are replaced.
         Serialized per addr so concurrent senders share one session.
 
-        Wire-plane negotiation happens here: a colocated peer that
-        matches our process token gets the in-process ring transport
-        (RingConnection); a lanes-capable peer gets ``ms_lanes_per_peer``
-        parallel lanes (LaneGroup) with data lanes bound to reactor
-        workers by the stable hash; anything else falls back to the
-        single TCP Connection — transparently, the caller just gets an
-        object with ``send``."""
+        Lane negotiation happens here: a lanes-capable peer gets
+        ``ms_lanes_per_peer`` parallel lanes (LaneGroup); anything else
+        the single TCP Connection — transparently, the caller just gets
+        an object with ``send``."""
         addr = tuple(addr)
-        if self.home_loop is None:
-            self.home_loop = asyncio.get_running_loop()
         conn = self._conns.get(addr)
         if conn is not None and not conn.closed:
             return conn
@@ -4438,34 +3741,12 @@ class Messenger:
                 else random.randbytes(8).hex()
             reader, writer = await asyncio.open_connection(*addr)
             try:
-                (peer_name, resumed, peer_ckind, lanes_ok, ring_id,
+                (peer_name, resumed, peer_ckind, lanes_ok,
                  reader, writer) = await self._handshake_out(
-                    reader, writer, policy.replay, session_id,
-                    want_ring=self._ring_ok,
-                )
+                    reader, writer, policy.replay, session_id)
             except Exception:
                 writer.close()
                 raise
-            if ring_id:
-                pair = ring_claim(ring_id)
-                if pair is not None:
-                    # colocated ring negotiated: zero-serialization
-                    # in-process transport; the TCP socket retires
-                    self.dout(1, f"colocated ring negotiated with "
-                                 f"{peer_name or '?'} at "
-                                 f"{addr[0]}:{addr[1]}")
-                    rx, tx = pair
-                    rconn = RingConnection(self, addr, peer_name, rx, tx,
-                                           outbound=True)
-                    self._ring_conns.append(rconn)
-                    rconn.start_pump()
-                    try:
-                        writer.close()
-                    except Exception:
-                        pass
-                    self._conns[addr] = rconn
-                    return rconn
-                # offer vanished (shutdown race): TCP fallback, transparent
             crc_fn = self._negotiated_crc(peer_ckind)
             if reviving:
                 if not resumed:
@@ -4494,8 +3775,7 @@ class Messenger:
                 self._tasks.add(task)
                 task.add_done_callback(self._tasks.discard)
                 return base
-            # multi-lane session: lane 0 (this conn) is the control
-            # lane on the home loop; data lanes ride reactor shards
+            # multi-lane session: lane 0 (this conn) is the control lane
             group = LaneGroup(self, addr, random.randbytes(8).hex(),
                               want_lanes, outbound=True, policy=policy)
             group.bind_lane(base, 0)
@@ -4518,65 +3798,31 @@ class Messenger:
             return group
 
     async def _dial_lane(self, group: LaneGroup, lane_idx: int) -> None:
-        """Open one data lane of a lane group, on the reactor worker the
-        stable hash binds it to: in thread mode the dial runs ON the
-        worker's loop; in process mode the handshake runs here and the
-        socket is then DELEGATED to the worker process (home loop
-        without a pool)."""
-        worker = None
-        proc_mode = self._delegatable()
-        if self.reactors is not None:
-            self.reactors.start()
-            worker = self.reactors.worker_for(group.peer, lane_idx)
-
-        async def _do():
-            reader, writer = await asyncio.open_connection(*group.peer)
-            session_id = random.randbytes(8).hex()
-            try:
-                (peer_name, _resumed, peer_ckind, _lanes_ok, ring_id,
-                 reader, writer) = await self._handshake_out(
-                    reader, writer, group.policy.replay, session_id)
-                if ring_id:
-                    ring_abandon(ring_id)
-            except Exception:
-                writer.close()
-                raise
-            crc_fn = self._negotiated_crc(peer_ckind)
-            shm_worker = None
-            if proc_mode:
-                pair = self._delegate_transport(
-                    reader, writer, worker, crc_fn,
-                    bool(_cget(self.conf, "ms_crc_data", True)))
-                if pair is not None:
-                    reader, writer = pair
-                    shm_worker = worker
-            conn = Connection(self, reader, writer, group.peer,
-                              group.policy, peer_name, outbound=True)
-            conn.crc_fn = crc_fn
-            conn.session_id = session_id
-            if shm_worker is not None:
-                conn.shm_worker = shm_worker
-                worker.dialed += 1
-            elif worker is not None and not proc_mode:
-                conn.reactor = worker
-                worker.sockets += 1
-                worker.dialed += 1
-            group.bind_lane(conn, lane_idx)
-            # the lane's first frame binds it on the acceptor — before
-            # any striped data can ride it
-            await conn.send(MLaneHello(group=group.group_id,
-                                       lane=lane_idx,
-                                       n_lanes=group.n_lanes,
-                                       proc=PROC_TOKEN[:8]))
-            task = asyncio.get_running_loop().create_task(
-                self._serve(conn))
-            self._tasks.add(task)
-            task.add_done_callback(self._tasks.discard)
-
-        if worker is not None and not proc_mode:
-            await worker.submit(_do())
-        else:
-            await _do()
+        """Open one data lane of a lane group."""
+        reader, writer = await asyncio.open_connection(*group.peer)
+        session_id = random.randbytes(8).hex()
+        try:
+            (peer_name, _resumed, peer_ckind, _lanes_ok,
+             reader, writer) = await self._handshake_out(
+                reader, writer, group.policy.replay, session_id)
+        except Exception:
+            writer.close()
+            raise
+        conn = Connection(self, reader, writer, group.peer,
+                          group.policy, peer_name, outbound=True)
+        conn.crc_fn = self._negotiated_crc(peer_ckind)
+        conn.session_id = session_id
+        group.bind_lane(conn, lane_idx)
+        # the lane's first frame binds it on the acceptor — before
+        # any striped data can ride it
+        await conn.send(MLaneHello(group=group.group_id,
+                                   lane=lane_idx,
+                                   n_lanes=group.n_lanes,
+                                   proc=PROC_TOKEN[:8]))
+        task = asyncio.get_running_loop().create_task(
+            self._serve(conn))
+        self._tasks.add(task)
+        task.add_done_callback(self._tasks.discard)
 
     async def send(self, addr: Tuple[str, int], msg: Any, retries: int = 3,
                    peer_type: str = "osd") -> None:
@@ -4623,52 +3869,27 @@ class Messenger:
         self._local_conns.clear()
         # cancel serve loops FIRST: in py3.12 Server.wait_closed() waits for
         # all connection handlers, so live inbound loops would deadlock it.
-        # Tasks living on reactor loops must be cancelled FROM their own
-        # loop (Task.cancel is not thread-safe across loops).
-        here = asyncio.get_running_loop()
-        for sweep in list(self._ack_sweeps.values()):
-            if sweep.loop is here:
-                sweep.cancel()
-            elif not sweep.loop.is_closed():
-                try:
-                    sweep.loop.call_soon_threadsafe(sweep.cancel)
-                except RuntimeError:
-                    pass  # loop shut down under us
+        if self._ack_sweep is not None:
+            self._ack_sweep.cancel()
         for t in list(self._tasks):
-            t_loop = t.get_loop()
-            if t_loop is here:
+            if not t.get_loop().is_closed():
                 t.cancel()
-            elif not t_loop.is_closed():
-                try:
-                    t_loop.call_soon_threadsafe(t.cancel)
-                except RuntimeError:
-                    pass  # loop shut down under us
         for conn in list(self._conns.values()):
-            if isinstance(conn, LaneGroup):
-                await conn.close()
-            else:
-                await self._conn_close(conn)
-        for rconn in list(self._ring_conns):
-            await rconn.close()
-        self._ring_conns.clear()
-        with self._lane_lock:
-            groups = list(self._lane_groups.values())
-            self._lane_groups.clear()
+            await conn.close()
+        groups = list(self._lane_groups.values())
+        self._lane_groups.clear()
         for g in groups:
             await g.close()
-        with self._sessions_lock:
-            sessions = list(self._sessions.values())
-            self._sessions.clear()
+        sessions = list(self._sessions.values())
+        self._sessions.clear()
         for conn in sessions:
-            await self._conn_close(conn)
+            await conn.close()
         if self.server is not None:
             self.server.close()
             try:
                 await asyncio.wait_for(self.server.wait_closed(), timeout=1.0)
             except asyncio.TimeoutError:
                 pass
-        if self.reactors is not None:
-            self.reactors.shutdown()
         # every connection is closed, so nothing of this messenger is on
         # the sender thread: the last messenger of a loop closes the
         # loop's end of it, the last loop's stops the thread
@@ -4680,39 +3901,12 @@ class Messenger:
     # -- wire-plane introspection --------------------------------------------
 
     def dump_reactors(self) -> Dict[str, Any]:
-        """asok ``dump_reactors`` payload: per-reactor socket shards and
-        per-peer lane/ring state (rendered by ``ceph daemon``)."""
-        peers = []
-        rings = []
-        seen = set()
-        groups = [c for c in self._conns.values()
-                  if isinstance(c, LaneGroup)]
-        with self._lane_lock:
-            for g in self._lane_groups.values():
-                groups.append(g)
-        for g in groups:
-            if id(g) in seen:
-                continue
-            seen.add(id(g))
-            peers.append(g.dump())
-        for c in self._ring_conns:
-            rings.append(c.dump())
-        out = {
-            "op_threads": (self.reactors.n_workers
-                           if self.reactors is not None else 0),
-            "reactor_mode": self.reactor_mode,
+        """asok ``dump_reactors`` payload: the wire arm and per-peer lane
+        state (rendered by ``ceph daemon``)."""
+        groups = [c for c in self._conns.values() if isinstance(c, LaneGroup)]
+        groups += [g for g in self._lane_groups.values() if g not in groups]
+        return {
             "lanes_per_peer": self.lanes_per_peer,
-            "colocated_ring": self._ring_ok,
             "wirepath": "native" if self.wirepath is not None else "python",
-            "workers": (self.reactors.dump()
-                        if self.reactors is not None else []),
-            "peers": peers,
-            "rings": rings,
+            "peers": [g.dump() for g in groups],
         }
-        if self._delegatable():
-            # whole-plane view: worker pids + the shm aggregate the
-            # perf presample folds into `perf dump`
-            self._refresh_proc_perf()
-            out["worker_pids"] = [w.pid for w in self.reactors.workers]
-            out["proc_perf"] = self.reactors.counters_sum()
-        return out

@@ -593,10 +593,6 @@ class OSD:
         # queue/store/sched/plugin/crush sets are process-shared (as the
         # resources are); every colocated OSD dumps the same numbers.
         self.ctx.perf.add(self.messenger.perf)
-        for worker in getattr(self.messenger.reactors, "workers", ()):
-            meter = getattr(worker, "meter", None)  # thread-mode reactors
-            if meter is not None:
-                self.ctx.perf.add(meter.perf)
         from ceph_tpu.ops.gf2 import SCHED_PERF
 
         from ceph_tpu.ops.slab import SLAB_PERF
@@ -709,8 +705,7 @@ class OSD:
             "per-class/per-client queue depths and dmClock tags")
         self.ctx.asok.register(
             "dump_reactors", lambda a: self.messenger.dump_reactors(),
-            "wire plane: reactor worker shards, per-peer lane state, "
-            "colocated rings")
+            "wire plane: the wirepath arm and per-peer lane state")
         self.ctx.asok.register(
             "inject_crash", lambda a: self.inject_crash(),
             "raise a fatal exception in the next ping tick "

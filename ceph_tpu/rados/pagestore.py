@@ -74,22 +74,6 @@ _SLAB_SHIFT = 8  # 2**8 pages per lazily-committed sub-slab
 # pagestore's own packed-byte exit is read()
 SLAB_IO_BOUNDARY = ("read",)
 
-_STAGING_ALIGN = 4096
-
-
-def install_staging(nbytes: int) -> memoryview:
-    """Page-aligned host staging for rx->install payloads (the shm
-    messenger's blob landing zone).  Alignment matters twice: the shm
-    consumer's native gather lands ring views on page boundaries, and a
-    later device install's H2D reads a page-aligned source — the
-    pinnable shape where pinned DMA exists; on a CPU-only host it is
-    honestly just aligned host memory.  The returned view keeps its
-    backing allocation alive (numpy base chain)."""
-    n = int(nbytes)
-    raw = np.empty(n + _STAGING_ALIGN, dtype=np.uint8)
-    off = (-raw.ctypes.data) % _STAGING_ALIGN
-    return memoryview(raw[off:off + n]).cast("B")
-
 
 def device_slab_resolved(flag: Optional[bool] = None) -> bool:
     """Whether the store's device arm engages.  CEPH_TPU_DEVICE_SLAB=1

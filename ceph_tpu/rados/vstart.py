@@ -41,15 +41,6 @@ class Cluster:
         if "ms_local_fastpath" not in self.conf \
                 and not any(self.conf.get(k) for k in wire_keys):
             self.conf["ms_local_fastpath"] = True
-        # colocated ring transport (messenger/reactor negotiation): the
-        # connect-time fallback for anything the fastpath's send-time
-        # registry check misses.  Follows the SAME decision as the
-        # fastpath: a conf that explicitly turned the fastpath off is
-        # asking for the real wire (rx batching, sheds, traces over
-        # TCP), so the ring must not silently replace it either.
-        if "ms_colocated_ring" not in self.conf \
-                and self.conf.get("ms_local_fastpath"):
-            self.conf["ms_colocated_ring"] = True
         # crash telemetry: a disk-backed cluster gets a crash spool dir
         # by default (cephadm /var/lib/ceph/crash role) so daemon deaths
         # while the mon is down still leave collectable reports

@@ -110,32 +110,10 @@ def render_op_queue(dump: Dict) -> List[str]:
 
 def render_reactors(dump: Dict) -> List[str]:
     """Render a daemon's ``dump_reactors`` answer (messenger
-    dump_reactors: reactor worker shards, per-peer lane groups, and
-    colocated rings).  Pure so tests can pin the layout."""
-    mode = dump.get("reactor_mode", "thread")
-    lines = [f"wire plane: {dump.get('op_threads', 0)} reactor workers "
-             f"({mode} mode), "
-             f"{dump.get('lanes_per_peer', 1)} lanes/peer, colocated ring "
-             f"{'on' if dump.get('colocated_ring') else 'off'}"]
-    workers = dump.get("workers") or []
-    if workers:
-        lines.append("  reactors:")
-        for w in workers:
-            if w.get("mode") == "process":
-                # process-sharded worker: pid + the shm counter block
-                lines.append(
-                    f"    worker {w.get('id')} pid {w.get('pid')} "
-                    f"{'up' if w.get('alive') else 'DEAD'}: conns "
-                    f"{w.get('conns', 0)} (accepted "
-                    f"{w.get('accepted', 0)}), rx_frames "
-                    f"{w.get('rx_frames', 0)}, tx {w.get('tx_bytes', 0)}B"
-                    + (f", respawns {w.get('respawns')}"
-                       if w.get("respawns") else ""))
-                continue
-            lines.append(
-                f"    worker {w.get('id')}: sockets {w.get('sockets', 0)} "
-                f"(accepted {w.get('accepted', 0)}, dialed "
-                f"{w.get('dialed', 0)})  rx_msgs {w.get('rx_msgs', 0)}")
+    dump_reactors: the wire arm and per-peer lane groups).  Pure so
+    tests can pin the layout."""
+    lines = [f"wire plane: {dump.get('wirepath', 'python')} wirepath, "
+             f"{dump.get('lanes_per_peer', 1)} lanes/peer"]
     for peer in dump.get("peers") or []:
         host, port = (peer.get("peer") or ["?", 0])[:2]
         lines.append(
@@ -149,25 +127,12 @@ def render_reactors(dump: Dict) -> List[str]:
                 lines.append(f"    lane {ln.get('lane')}: absent")
                 continue
             role = "ctl " if ln.get("control") else "data"
-            reactor = ln.get("reactor")
-            shm = ln.get("shm")
             lines.append(
                 f"    lane {ln.get('lane')} [{role}] {ln.get('state')}: "
                 f"outbox {ln.get('outbox_frames', 0)}f/"
                 f"{ln.get('outbox_bytes', 0)}B  unacked "
                 f"{ln.get('unacked', 0)}  seq {ln.get('out_seq', 0)}/"
-                f"{ln.get('in_seq', 0)}"
-                + (f"  reactor {reactor}" if reactor is not None else "")
-                + (f"  shm worker pid {shm.get('worker_pid')} ring "
-                   f"tx {shm.get('tx_ring_fill', 0)}B/"
-                   f"rx {shm.get('rx_ring_fill', 0)}B"
-                   if shm else ""))
-    for ring in dump.get("rings") or []:
-        host, port = (ring.get("peer") or ["?", 0])[:2]
-        lines.append(f"  ring {host}:{port} ({ring.get('peer_name', '')}): "
-                     f"depth rx {ring.get('rx_depth', 0)} / tx "
-                     f"{ring.get('tx_depth', 0)}"
-                     + (" closed" if ring.get("closed") else ""))
+                f"{ln.get('in_seq', 0)}")
     return lines
 
 

@@ -1,8 +1,8 @@
 """async-safety checker family.
 
-The reactor/messenger plane mixes asyncio event loops with real threads
-(reactor workers, the BatchingQueue dispatcher, native calls), which is
-exactly where review keeps catching the same three defects:
+The messenger plane mixes asyncio event loops with real threads (the
+BatchingQueue dispatcher, a store's commit thread, native calls), which
+is exactly where review keeps catching the same defects:
 
 - ``blocking-call``: a synchronous blocker (``time.sleep``, subprocess,
   a blocking ``threading.Lock.acquire``) inside an ``async def`` stalls
@@ -13,26 +13,12 @@ exactly where review keeps catching the same three defects:
   ``async with``; thread locks must be released before awaiting);
 - ``cross-loop-call``: calling ``loop.call_soon``/``create_task`` on a
   STORED loop from sync code may run on a foreign thread — the home-loop
-  idiom is ``call_soon_threadsafe`` (messenger.py/reactor.py hop this
-  way everywhere; this checker keeps it that way);
+  idiom is ``call_soon_threadsafe`` (this checker keeps it that way);
 - ``await-in-section``: a ``with tracing.section(...)`` block (or a
   ``@tracing.sectioned`` coroutine) containing an ``await`` — a section
   is self time of SYNCHRONOUS work on one thread's stack
   (common/tracing.py); across a suspension it would time the whole
-  loop's other work and corrupt the stack the loop meter resets per step;
-- ``shm-ring-payload`` (cross-process seam): objects queued onto a
-  shared-memory ring (ShmRingPipe ``put_record``/``send_bytes``/
-  ``send_gather``) must be WIRE BYTES or fixed-layout packs — a live
-  message/connection/loop/lock object cannot cross a fork, and a
-  reference pushed into shm is silently a different object on the far
-  side.  Flagged: a bare object-ish name (``msg``, ``conn``, ``loop``,
-  ``lock``, ``task``, ``sock`` ...) or ``self`` passed as a ring
-  payload element;
-- ``shm-lifecycle`` (cross-process seam): a module that opens
-  ``multiprocessing.shared_memory.SharedMemory`` must pair it with both
-  ``.close()`` and ``.unlink()`` on some teardown path — a missing
-  close leaks the mapping, a missing unlink leaks /dev/shm segments
-  past every process's death.
+  loop's other work and corrupt the stack the loop meter resets per step.
 
 Heuristic exemptions (calibrated on the shipped tree):
 
@@ -49,7 +35,7 @@ from __future__ import annotations
 
 import ast
 import re
-from typing import List, Optional, Tuple
+from typing import List, Tuple
 
 from ceph_tpu.tools.lint.findings import Finding
 
@@ -68,16 +54,6 @@ _BLOCKING = {
 
 _LOOP_METHODS = {"call_soon", "call_later", "call_at", "create_task"}
 _THREADSAFE = {"call_soon_threadsafe", "run_coroutine_threadsafe"}
-
-# shm ring producer surface (shm_ring.ShmRingPipe / ShmConnEndpoint):
-# payload elements must be byte-plane values, never live objects
-_SHM_PUT = {"put_record", "send_bytes", "send_gather"}
-# bare names that denote live runtime objects on the wrong side of a
-# fork (heuristic, like _LOCKISH: calibrated on the shipped tree)
-_OBJECTISH = re.compile(
-    r"^(msg|message|conn|connection|loop|lock|mutex|task|future|sock"
-    r"|socket|worker|group|self)$")
-
 
 _LOCKISH = re.compile(r"(^|[^a-z])(lock|mutex)")
 # common/tracing.py's self-time sections: synchronous work only
@@ -169,39 +145,7 @@ class _Scanner(ast.NodeVisitor):
         if isinstance(func, ast.Attribute) and func.attr in _LOOP_METHODS:
             self._check_cross_loop(node, func)
 
-        if isinstance(func, ast.Attribute) and func.attr in _SHM_PUT:
-            self._check_shm_payload(node, func)
-
         self.generic_visit(node)
-
-    def _check_shm_payload(self, node, func: ast.Attribute) -> None:
-        """Cross-process seam: ring payload elements must be byte-plane
-        values.  put_record(kind, parts) / send_gather(wp, parts) carry
-        the payload LAST; send_bytes(parts) carries it first."""
-        if not node.args:
-            return
-        payload = node.args[-1]
-        elements = []
-        if isinstance(payload, (ast.List, ast.Tuple)):
-            for e in payload.elts:
-                elements.append(e.value if isinstance(e, ast.Starred)
-                                else e)
-        else:
-            elements.append(payload)
-        for e in elements:
-            name = e.id if isinstance(e, ast.Name) else None
-            if name is not None and _OBJECTISH.match(name):
-                self.findings.append(Finding(
-                    check="async-safety/shm-ring-payload",
-                    file=self.relpath, line=node.lineno,
-                    key=f"{func.attr}:{name}@L{node.lineno}",
-                    message=f"`{name}` queued onto a shared-memory ring "
-                            f"via `{func.attr}` in {self._func_name()}: "
-                            f"only wire-frame bytes / fixed-layout packs "
-                            f"may cross the process seam — a live "
-                            f"object reference is a DIFFERENT object on "
-                            f"the far side of the fork (serialize to "
-                            f"bytes first)"))
 
     def _check_blocking(self, node, func, dotted: str) -> None:
         for pat, why in _BLOCKING.items():
@@ -245,8 +189,7 @@ class _Scanner(ast.NodeVisitor):
             message=f"`{recv}.{func.attr}(...)` from sync code in "
                     f"{self._func_name()}: a stored loop may be homed on "
                     f"another thread — use "
-                    f"`{recv}.call_soon_threadsafe(...)` (the "
-                    f"messenger/reactor home-loop idiom) or prove the "
+                    f"`{recv}.call_soon_threadsafe(...)` or prove the "
                     f"caller is on that loop via "
                     f"`asyncio.get_running_loop()`"))
 
@@ -290,34 +233,6 @@ class _Scanner(ast.NodeVisitor):
         return "<module>"
 
 
-_SHM_OPEN = re.compile(r"\bSharedMemory\s*\(")
-
-
-def _check_shm_lifecycle(relpath: str, text: str,
-                         findings: List[Finding]) -> None:
-    """A module opening SharedMemory must pair it with close AND unlink
-    somewhere on its teardown paths (the /dev/shm segment outlives
-    every process until SOMEONE unlinks; the mapping leaks until
-    someone closes)."""
-    m = _SHM_OPEN.search(text)
-    if m is None:
-        return
-    missing = [what for what, pat in (("close", ".close("),
-                                      ("unlink", ".unlink("))
-               if pat not in text]
-    if missing:
-        line = text[:m.start()].count("\n") + 1
-        findings.append(Finding(
-            check="async-safety/shm-lifecycle", file=relpath, line=line,
-            key=f"shm-lifecycle:{'+'.join(missing)}",
-            message=f"`SharedMemory(` opened with no paired "
-                    f"{' / '.join('.' + w + '()' for w in missing)} in "
-                    f"this module: shared-memory segments outlive every "
-                    f"process until unlinked, and mappings leak until "
-                    f"closed — add the teardown pair "
-                    f"(creator unlinks, both ends close)"))
-
-
 def check(sources: List[Tuple[str, str]]) -> List[Finding]:
     findings: List[Finding] = []
     for relpath, text in sources:
@@ -326,5 +241,4 @@ def check(sources: List[Tuple[str, str]]) -> List[Finding]:
         except SyntaxError:
             continue  # codec family reports unparsable files
         _Scanner(relpath, findings).visit(tree)
-        _check_shm_lifecycle(relpath, text, findings)
     return findings

@@ -241,21 +241,6 @@ def run_wire_floor(args) -> int:
         # ARE the python arm) are the comparable pair.
         ckind = str(cur.get("wirepath_kind") or "python")
         pkind = str(prev.get("wirepath_kind") or "python")
-        # like-for-like reactor MODES too (records older than the
-        # process-sharded plane are the thread arm): a thread-arm
-        # record compared against a process-arm record measures the
-        # substrate swap, not a wire regression — skip the throughput
-        # half with an explanation instead of failing/greenlighting on
-        # an apples-to-oranges pair
-        cmode = str(cur.get("reactor_mode") or "thread")
-        pmode = str(prev.get("reactor_mode") or "thread")
-        if cmode != pmode:
-            print(f"wire-floor: reactor_mode differs (cur={cmode} "
-                  f"prev={pmode}); skipping the throughput floor — "
-                  f"like-for-like modes only (re-run either record "
-                  f"with CEPH_TPU_REACTOR={pmode} to compare)")
-            lane_rc = _wire_lane_identity()
-            return rc or lane_rc
         for key in ("daemon_wire_put_MBps", "daemon_wire_get_MBps"):
             if ckind == pkind:
                 c = float(cur.get(key, 0.0) or 0.0)

@@ -2,14 +2,13 @@
 (native/wirepath.cc via the ctypes bridge) when the native layer builds,
 the pure-Python arm otherwise.
 
-r13's sharded reactor measured the honest limit this module exists to
-move: under the GIL, frame crc, fragment memcpy and writev segment
-assembly serialize every reactor thread, so the multi-reactor TCP arm
-cannot beat the single-loop path.  The native wirepath batches that
-per-byte work into single foreign calls — ctypes drops the GIL around
-them — so a flush window's writev, a burst's crc verify, and a striped
-blob's scatter each cost ONE released-GIL call instead of N interpreter
-iterations (checksum.py's discipline, applied to the whole wire loop).
+Frame crc, fragment memcpy and writev segment assembly in Python cost
+the one loop an interpreter iteration a segment.  The native wirepath
+batches that per-byte work into single foreign calls — ctypes drops the
+GIL around them — so a flush window's writev, a burst's crc verify, and
+a striped blob's scatter each cost ONE released-GIL call instead of N
+interpreter iterations (checksum.py's discipline, applied to the whole
+wire loop).
 
 Resolution mirrors utils/checksum.py: probe once per process, fall back
 silently (hosts without a C++ toolchain run the full suite on the
@@ -17,15 +16,6 @@ python arm), and expose ``kind()`` so BENCH records and /metrics report
 which arm actually ran.  ``CEPH_TPU_WIREPATH=0`` forces the python arm
 process-wide (the CI parity knob); the per-messenger config option
 ``ms_wirepath_native`` gates it per daemon.
-
-Per-process arm resolution under the process-sharded reactor plane
-(``ms_reactor_mode=process``): ReactorProcessWorker.start() resolves
-the arm in the PARENT before forking, so every worker child inherits a
-loaded, probed bridge (ctypes handles survive fork) and never pays —
-or races — a g++ build of its own.  After the fork the cached
-resolution is genuinely per-process state: each worker runs its own
-copy of the native wirepath, its ``wirepath_kind`` counter slot
-reporting which arm that process carries.
 
 The native arm only engages when the process checksum resolver is
 crc32c (checksum.checksum_kind() == "crc32c"): the wirepath's crc

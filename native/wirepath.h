@@ -1,15 +1,13 @@
 // Native wirepath: the messenger's per-byte hot loop below the GIL.
 //
-// The sharded reactor plane (r13) measured an honest wall: on a
-// GIL-bound host the multi-reactor TCP arm cannot beat the single-loop
-// path because every per-byte operation — frame crc, fragment memcpy,
-// writev segment assembly — runs under the interpreter lock.  These
-// entry points batch that work into single foreign calls (ctypes drops
-// the GIL around them), the wire-plane application of the
-// specialize-the-byte-loops technique from "Accelerating XOR-based
-// Erasure Coding using Program Optimization Techniques"
-// (arXiv:2108.02692): the compiler vectorizes the copy/crc loops, and
-// reactor threads overlap while a call runs.
+// In Python every per-byte operation of the wire — frame crc, fragment
+// memcpy, writev segment assembly — runs under the interpreter lock, on
+// the messenger's one loop.  These entry points batch that work into
+// single foreign calls (ctypes drops the GIL around them), the
+// wire-plane application of the specialize-the-byte-loops technique
+// from "Accelerating XOR-based Erasure Coding using Program
+// Optimization Techniques" (arXiv:2108.02692): the compiler vectorizes
+// the copy/crc loops, and other threads run while a call does.
 //
 // Contract shared with ceph_tpu/native/bridge.py and the python arm in
 // ceph_tpu/utils/wirepath.py: every function is a PURE function of its

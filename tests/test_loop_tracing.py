@@ -391,47 +391,6 @@ class TestLoopMeter:
     def test_mark_off_a_metered_loop_is_a_noop(self):
         assert tracing.mark("osd") is None
 
-    def test_a_named_loop_gets_a_set_of_its_own(self):
-        loop = asyncio.new_event_loop()
-        try:
-            meter = tracing.install_loop_meter(loop, name="osd.0-reactor-0")
-            meter.sample_every = 1
-            assert meter.perf.name == "loop.osd.0-reactor-0"
-            assert meter.perf is not LOOP_PERF
-            assert tracing.install_loop_meter(loop) is meter  # idempotent
-
-            async def body():
-                with tracing.section("messenger", "crc"):
-                    spin(0.01)
-            before = LOOP_PERF.dump()
-            loop.run_until_complete(body())
-            got = meter.perf.dump()
-            assert got["self_messenger"]["sum"] >= 0.008
-            assert got["busy"]["sum"] >= 0.008
-            assert moved(before, LOOP_PERF.dump(), "self_messenger") == 0
-            meter.remove()
-        finally:
-            loop.close()
-
-    def test_reactor_workers_meter_their_loops(self):
-        from ceph_tpu.rados.reactor import ReactorWorker
-
-        w = ReactorWorker("osd.9", 0)
-        try:
-            assert w.meter.perf.name == "loop.osd.9-reactor-0"
-            w.meter.sample_every = 1
-            w.ensure_started()
-            done = threading.Event()
-
-            async def body():
-                spin(0.01)
-                done.set()
-            w.spawn(body())
-            assert done.wait(5.0)
-            assert w.meter.perf.dump()["busy"]["sum"] >= 0.008
-        finally:
-            w.stop()
-
     def test_the_loop_set_is_in_every_daemons_collection_once(self):
         a, b = Context("osd.0"), Context("mon.a")
         assert a.perf.get("loop") is LOOP_PERF is b.perf.get("loop")

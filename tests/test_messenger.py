@@ -9,6 +9,7 @@ import zlib
 
 import pytest
 
+from ceph_tpu.rados.auth import AESGCM
 from ceph_tpu.rados.messenger import (
     ACK_TYPE,
     BadFrame,
@@ -33,12 +34,47 @@ def run(coro):
     return asyncio.run(asyncio.wait_for(coro, 30))
 
 
+def _needs_native():
+    from ceph_tpu.utils import wirepath
+    if wirepath.impl() is None:
+        pytest.skip("no native wirepath arm on this host")
+    return wirepath.impl()
+
+
+# ms_secure_mode needs the (gated) AES-GCM backend, as tests/test_auth.py
+requires_crypto = pytest.mark.skipif(
+    AESGCM is None, reason="the `cryptography` package is not installed")
+
+# the wires a messenger has (messenger.py "One loop, one wire"): plaintext
+# TCP framed by FrameReceiver and written by CorkedWriter, on the native
+# arm and on the python arm, and SecureStream's readexactly chain
+_TRANSPORTS = ("tcp-native", "tcp-python",
+               pytest.param("tcp-secure", marks=requires_crypto))
+
+
+def _transport_conf(transport: str, **more) -> dict:
+    """What both ends of a pair set to talk over `transport`."""
+    if transport == "tcp-native":
+        _needs_native()
+        conf = {}
+    elif transport == "tcp-python":
+        conf = {"ms_wirepath_native": False}
+    else:
+        conf = {"ms_auth_secret": "s3", "ms_secure_mode": True}
+    return dict(conf, **more)
+
+
 async def _pair(server_conf=None, client_conf=None, server_type="osd",
                 client_type="osd"):
     server = Messenger("server", server_conf or {}, entity_type=server_type)
     client = Messenger("client", client_conf or {}, entity_type=client_type)
     addr = await server.bind()
     return server, client, addr
+
+
+async def _pair_on(transport: str, server_more=None, client_more=None):
+    return await _pair(_transport_conf(transport, **(server_more or {})),
+                       _transport_conf(transport, **(client_more or {})))
 
 
 class TestHandshakeAuth:
@@ -141,10 +177,11 @@ class TestFrames:
 
         run(go())
 
-    def test_compression_roundtrip(self):
+    @pytest.mark.parametrize("transport", _TRANSPORTS)
+    def test_compression_roundtrip(self, transport):
         async def go():
             conf = {"ms_compress_min_size": 64}
-            server, client, addr = await _pair(conf, conf)
+            server, client, addr = await _pair_on(transport, conf, conf)
             got = asyncio.Queue()
 
             async def dispatch(conn, msg):
@@ -161,14 +198,15 @@ class TestFrames:
         run(go())
 
 
+@pytest.mark.parametrize("transport", _TRANSPORTS)
 class TestLosslessReplay:
-    def test_exactly_once_under_injected_failures(self):
+    def test_exactly_once_under_injected_failures(self, transport):
         async def go():
             # every ~6th send attempt severs the connection; lossless policy
             # must reconnect + replay, and dedupe must prevent double dispatch
             server, client, addr = await _pair(
-                client_conf={"ms_inject_socket_failures": 6}
-            )
+                _transport_conf(transport),
+                _transport_conf(transport, ms_inject_socket_failures=6))
             received = []
 
             async def dispatch(conn, msg):
@@ -190,15 +228,14 @@ class TestLosslessReplay:
 
         run(go())
 
-    def test_bidirectional_rpc_exactly_once_under_failures(self):
+    def test_bidirectional_rpc_exactly_once_under_failures(self, transport):
         async def go():
             # failures injected on BOTH sides: requests and replies each get
             # dropped mid-flight; session replay must deliver every request
             # once to the server and every reply once to the client
             server, client, addr = await _pair(
-                server_conf={"ms_inject_socket_failures": 8},
-                client_conf={"ms_inject_socket_failures": 8},
-            )
+                _transport_conf(transport, ms_inject_socket_failures=8),
+                _transport_conf(transport, ms_inject_socket_failures=8))
             served = []
             replies = []
 
@@ -230,9 +267,9 @@ class TestLosslessReplay:
 
         run(go())
 
-    def test_unacked_queue_trims_on_ack(self):
+    def test_unacked_queue_trims_on_ack(self, transport):
         async def go():
-            server, client, addr = await _pair()
+            server, client, addr = await _pair_on(transport)
             server.dispatcher = _swallow
             conn = await client.connect(addr, peer_type="osd")
             assert conn.policy.replay
@@ -248,11 +285,11 @@ class TestLosslessReplay:
 
         run(go())
 
-    def test_acceptor_session_loss_resets_dedupe_floor(self):
+    def test_acceptor_session_loss_resets_dedupe_floor(self, transport):
         async def go():
             # the acceptor forgetting a session (restart/LRU eviction) must
             # not leave the initiator deaf to the fresh reply stream
-            server, client, addr = await _pair()
+            server, client, addr = await _pair_on(transport)
             replies = []
 
             async def server_dispatch(conn, msg):
@@ -293,9 +330,9 @@ class TestLosslessReplay:
 
         run(go())
 
-    def test_lossy_client_does_not_queue(self):
+    def test_lossy_client_does_not_queue(self, transport):
         async def go():
-            server, client, addr = await _pair()
+            server, client, addr = await _pair_on(transport)
             server.dispatcher = _swallow
             conn = await client.connect(addr, peer_type="client")
             assert not conn.policy.replay
@@ -311,12 +348,12 @@ async def _swallow(conn, msg):
     return None
 
 
+@pytest.mark.parametrize("transport", _TRANSPORTS)
 class TestDispatchThrottle:
-    def test_throttle_applies_backpressure(self):
+    def test_throttle_applies_backpressure(self, transport):
         async def go():
-            server, client, addr = await _pair(
-                server_conf={"ms_dispatch_throttle_bytes": 1}
-            )
+            server, client, addr = await _pair_on(
+                transport, {"ms_dispatch_throttle_bytes": 1})
             # 1-byte budget: each frame exceeds it, but an idle throttle
             # admits one oversize request at a time -> strictly serial
             inflight = []
@@ -354,9 +391,10 @@ class TestCorkedOutbox:
     sendmsg writev (CorkedWriter), piggybacked/batched acks, and the
     replay-queue interaction under injected faults."""
 
-    def test_concurrent_senders_share_flush_windows(self):
+    @pytest.mark.parametrize("transport", _TRANSPORTS)
+    def test_concurrent_senders_share_flush_windows(self, transport):
         async def go():
-            server, client, addr = await _pair()
+            server, client, addr = await _pair_on(transport)
             got = []
 
             async def dispatch(conn, msg):
@@ -386,11 +424,12 @@ class TestCorkedOutbox:
 
         run(go())
 
-    def test_corked_writer_engages_on_plaintext(self):
+    @pytest.mark.parametrize("transport", ["tcp-native", "tcp-python"])
+    def test_corked_writer_engages_on_plaintext(self, transport):
         async def go():
             from ceph_tpu.rados.messenger import CorkedWriter
 
-            server, client, addr = await _pair()
+            server, client, addr = await _pair_on(transport)
             got = asyncio.Queue()
 
             async def dispatch(c, m):
@@ -417,9 +456,10 @@ class TestCorkedOutbox:
 
         run(go())
 
-    def test_acks_batch_and_piggyback(self):
+    @pytest.mark.parametrize("transport", _TRANSPORTS)
+    def test_acks_batch_and_piggyback(self, transport):
         async def go():
-            server, client, addr = await _pair()
+            server, client, addr = await _pair_on(transport)
             server.dispatcher = _swallow
             conn = await client.connect(addr)
             n = 40
@@ -440,15 +480,16 @@ class TestCorkedOutbox:
 
         run(go())
 
-    def test_burst_exactly_once_in_order_under_failures(self):
+    @pytest.mark.parametrize("transport", _TRANSPORTS)
+    def test_burst_exactly_once_in_order_under_failures(self, transport):
         """The ISSUE's outbox-ordering-under-faults gate: lossless
         sessions with ms_inject_socket_failures must deliver COALESCED
         frames (concurrent burst senders sharing flush windows) exactly
         once and in seq order across reconnect replay."""
 
         async def go():
-            server, client, addr = await _pair(
-                client_conf={"ms_inject_socket_failures": 10})
+            server, client, addr = await _pair_on(
+                transport, None, {"ms_inject_socket_failures": 10})
             received = []
 
             async def dispatch(conn, msg):
@@ -479,9 +520,10 @@ class TestCorkedOutbox:
 
         run(go())
 
-    def test_close_fails_pending_window(self):
+    @pytest.mark.parametrize("transport", _TRANSPORTS)
+    def test_close_fails_pending_window(self, transport):
         async def go():
-            server, client, addr = await _pair()
+            server, client, addr = await _pair_on(transport)
             server.dispatcher = _swallow
             conn = await client.connect(addr, peer_type="client")
             assert not conn.policy.replay
@@ -496,11 +538,12 @@ class TestCorkedOutbox:
 
 
 class TestBufferListBlob:
-    def test_scatter_blob_roundtrips_over_socket(self):
+    @pytest.mark.parametrize("transport", _TRANSPORTS)
+    def test_scatter_blob_roundtrips_over_socket(self, transport):
         async def go():
             from ceph_tpu.rados.messenger import BufferList
 
-            server, client, addr = await _pair()
+            server, client, addr = await _pair_on(transport)
             got = asyncio.Queue()
 
             async def dispatch(conn, msg):
@@ -1379,7 +1422,7 @@ class TestAcksWaitForCompany:
             assert d["tx_acks"] == d["tx_acks_rode"] == 1, d
             assert d["tx_flush_mixed"] == d["tx_flush_data"] == 1, d
             await asyncio.sleep(server.ACK_DELAY_S + 0.1)  # the tick came
-            sweep, = server._ack_sweeps.values()
+            sweep = server._ack_sweep
             d = server.perf.dump()
             assert sweep.ticks == 1 and sweep.timer is None
             assert not sweep.owing
@@ -1413,7 +1456,7 @@ class TestAcksWaitForCompany:
             assert d["tx_acks"] == d["tx_acks_bound"] == 1, d
             assert d["ack_frames_covered"] == 3
             assert d["tx_flush_ack"] == 1
-            sweep, = server._ack_sweeps.values()
+            sweep = server._ack_sweep
             assert sweep.ticks == 0
             await client.shutdown()
             await server.shutdown()
@@ -1432,7 +1475,7 @@ class TestAcksWaitForCompany:
             for conn in conns:
                 await conn.send(MTest(seqno=1))
             assert await _until(lambda: server.perf.dump()["rx_msgs"] == 5)
-            sweep, = server._ack_sweeps.values()
+            sweep = server._ack_sweep
             assert len(sweep.owing) == 5
             assert len(_sweep_timers(loop, server)) == 1
             assert await _until(
@@ -1518,7 +1561,7 @@ class TestAcksWaitForCompany:
             await conn.send(MTest(seqno=1))
             loop = asyncio.get_running_loop()
             assert await _until(lambda: _sweep_timers(loop, server))
-            sweep, = server._ack_sweeps.values()
+            sweep = server._ack_sweep
             sconn, = server._sessions.values()
             await server.shutdown()
             assert sweep.timer is None and not sweep.owing
@@ -1539,13 +1582,6 @@ _ARMS = ("native", "python")
 
 def _arm_conf(arm: str) -> dict:
     return {} if arm == "native" else {"ms_wirepath_native": False}
-
-
-def _needs_native():
-    from ceph_tpu.utils import wirepath
-    if wirepath.impl() is None:
-        pytest.skip("no native wirepath arm on this host")
-    return wirepath.impl()
 
 
 async def _corked(conn, server_got=None):
@@ -1787,11 +1823,12 @@ class TestOffloopSend:
         run(go())
 
     @pytest.mark.parametrize("case", [
-        (TestLosslessReplay, "test_exactly_once_under_injected_failures"),
+        (TestLosslessReplay, "test_exactly_once_under_injected_failures",
+         "tcp-native"),
         (TestLosslessReplay,
-         "test_bidirectional_rpc_exactly_once_under_failures"),
+         "test_bidirectional_rpc_exactly_once_under_failures", "tcp-native"),
         (TestCorkedOutbox,
-         "test_burst_exactly_once_in_order_under_failures"),
+         "test_burst_exactly_once_in_order_under_failures", "tcp-native"),
         (None, "test_injected_drops_under_standing_debts_deliver_"
                "exactly_once"),
     ], ids=lambda c: c[1][5:45])
@@ -1799,11 +1836,11 @@ class TestOffloopSend:
             self, case, monkeypatch):
         from ceph_tpu.rados.messenger import CorkedWriter
         wp = _needs_native()
-        cls, name = case
+        cls, name, *args = case
         cls = cls or TestAcksWaitForCompany
         monkeypatch.setattr(CorkedWriter, "OFFLOOP_MIN_BYTES", 0)
         before = wp.wire_sender_stats()
-        getattr(cls(), name)()
+        getattr(cls(), name)(*args)
         after = wp.wire_sender_stats()
         assert after["submitted"] - before["submitted"] >= 10
         assert after["submitted"] == after["completed"] + after["failed"] \
@@ -1991,8 +2028,9 @@ class TestOffloopSend:
         run(go())
 
     def test_loops_on_threads_get_their_ends_under_one_lock(self):
-        """ms_reactor_mode = thread: loops that start together on several
-        threads each get an end of their own, the table is never walked
+        """Loops that start together on several threads (a client on a
+        thread of its own beside the daemons' loop) each get an end of
+        their own, the table is never walked
         while it grows, the hooks are registered once (a second
         thread_source would count loop.thread_messenger twice) and the
         last end to close stops the thread."""
@@ -2061,3 +2099,200 @@ class TestOffloopSend:
             await server.shutdown()
 
         run(go())
+
+
+# -- every wire a messenger has (ISSUE 50): faults, teardown and a cluster's
+# round trip on plaintext TCP (both arms) and on SecureStream ---------------
+
+def _is_on(conn, transport: str) -> bool:
+    """`conn` reads through the reader kind `transport` names."""
+    from ceph_tpu.rados.auth import SecureStream
+    from ceph_tpu.rados.messenger import FrameReceiver
+    if transport == "tcp-secure":
+        return isinstance(conn.reader, SecureStream)
+    return (isinstance(conn.reader, FrameReceiver)
+            and (conn.wp is not None) == (transport == "tcp-native"))
+
+
+@pytest.mark.parametrize("transport", _TRANSPORTS)
+class TestEveryTransport:
+    def test_injected_dup_frames_keep_order_and_the_other_planes_once(
+            self, transport):
+        """ms_inject_dup_frames = 1 sends every MOSDOp as two frames with
+        two seqs (the receiver's dedupe cannot drop the second: the PG
+        log's reqid set does); a type outside the op plane keeps the
+        session's exactly-once.  Both arrive in the order they were sent."""
+        async def go():
+            from ceph_tpu.rados.types import MOSDOp
+            server, client, addr = await _pair_on(
+                transport, None, {"ms_inject_dup_frames": 1})
+            got = []
+
+            async def dispatch(conn, msg):
+                got.append(("op", msg.reqid) if isinstance(msg, MOSDOp)
+                           else ("plain", msg.seqno))
+            server.dispatcher = dispatch
+            n = 30
+            for i in range(n):
+                await client.send(addr, MOSDOp(op="read", oid=f"o{i}",
+                                               reqid=f"r{i:03d}"))
+                await client.send(addr, MTest(seqno=i, blob=b"d" * 4096))
+            assert await _until(lambda: len(got) >= 3 * n, 10.0)
+            await asyncio.sleep(0.1)  # nothing more comes
+            want = []
+            for i in range(n):
+                want += [("op", f"r{i:03d}")] * 2 + [("plain", i)]
+            assert got == want
+            assert _is_on(client._conns[tuple(addr)], transport)
+            await client.shutdown()
+            await server.shutdown()
+
+        run(go())
+
+    def test_a_connection_torn_mid_frame_returns_its_throttle_cost(
+            self, transport):
+        """A frame in dispatch holds its cost; the transport dies with the
+        next frame half arrived (on a SecureStream its header has charged
+        the throttle already); when the serve loop ends, the dispatch
+        throttle holds nothing."""
+        async def go():
+            server, client, addr = await _pair_on(transport)
+            gate = _Gate()
+            server.dispatcher = gate
+            conn = await client.connect(addr, peer_type="client")
+            assert not conn.policy.replay  # nothing replays the torn frame
+            await conn.send(MTest(seqno=0, blob=b"a" * 3000))
+            await asyncio.wait_for(gate.parked.wait(), 5)
+            assert server.dispatch_throttle.current >= 3000
+            assert _is_on(conn, transport)
+            frame = _mk_frame(MTest(seqno=1, blob=b"b" * 9000), 2)
+            conn.writer.write(frame[:len(frame) // 2])
+            await conn.writer.drain()
+            await conn.close()
+            gate.release.set()
+            assert await _until(
+                lambda: server.dispatch_throttle.current == 0, 5.0), \
+                server.dispatch_throttle.current
+            assert gate.got == [(0, b"a" * 3000)]
+            await client.shutdown()
+            await server.shutdown()
+            assert server.dispatch_throttle.current == 0
+
+        run(go())
+
+    def test_a_tcp_cluster_round_trips_4mib_byte_identical(self, transport):
+        """The cells' wire (`ms_local_fastpath: False`): a 4 MiB object
+        through an EC pool of a vstart cluster, on each transport."""
+        async def go():
+            import os
+
+            from ceph_tpu.rados.vstart import Cluster
+            cluster = Cluster(n_osds=4, conf=_transport_conf(
+                transport, ms_local_fastpath=False, osd_auto_repair=False))
+            await cluster.start()
+            try:
+                c = await cluster.client()
+                pool = await c.create_pool("p", profile={
+                    "plugin": "jerasure", "technique": "reed_sol_van",
+                    "k": "2", "m": "1"})
+                blob = os.urandom(4 << 20)
+                await c.put(pool, "big", blob)
+                assert bytes(await c.get(pool, "big")) == blob
+                for osd in cluster.osds.values():
+                    m = osd.messenger
+                    assert not m._local_conns
+                    assert m.perf.get("local_msgs") == 0
+                    assert all(_is_on(conn, transport)
+                               for conn in m._sessions.values()
+                               if not conn.closed)
+                assert sum(o.messenger.perf.get("rx_bytes")
+                           for o in cluster.osds.values()) > 4 << 20
+                await c.stop()
+            finally:
+                await cluster.stop()
+
+        asyncio.run(asyncio.wait_for(go(), 120))
+
+
+@pytest.mark.parametrize("native", [True, False], ids=["native", "python"])
+def test_a_zlib_negotiated_connection_verifies_with_zlib(native):
+    """A pair whose hosts resolved different checksum kinds negotiates
+    zlib frame crcs (`Messenger._negotiated_crc`); the FrameReceiver then
+    has to verify with zlib on either arm: the native crc32c pass would
+    refuse every frame and loop the session through BadFrame."""
+    if native:
+        _needs_native()
+    import pickle
+
+    from ceph_tpu.rados.messenger import FLAG_BLOB, _BLOB_PFX
+    conn = _framer_conn(native)
+    conn.crc_fn = zlib.crc32
+    payload = encode_payload(MTest(text="z", seqno=5))
+    small = _HDR.pack(len(payload), 900, 1, 0, zlib.crc32(payload), 1) \
+        + payload
+    blob = bytes(range(256)) * 512
+    pickled = pickle.dumps({"chunk_crc": 0})
+    head = _BLOB_PFX.pack(len(pickled), zlib.crc32(blob)) + pickled
+    big = _HDR.pack(len(head) + len(blob), 910, 1, FLAG_BLOB,
+                    zlib.crc32(head), 2) + head + blob
+    _feed(conn, small + big)
+    assert conn._rx_error is None
+    assert [f[2] for f in conn._rx_stash] == [1, 2]
+    assert bytes(conn._rx_stash[1][5]) == blob
+    assert conn._rx_stash[1][7]  # the blob's crc was checked, with zlib
+    # the same frames with the OTHER kind's crcs are refused
+    from ceph_tpu.utils.checksum import checksum, checksum_kind
+    if checksum_kind() != "zlib":
+        other = _framer_conn(native)
+        other.crc_fn = zlib.crc32
+        _feed(other, _HDR.pack(len(payload), 900, 1, 0,
+                               checksum(payload) & 0xFFFFFFFF, 1) + payload)
+        assert isinstance(other._rx_error, BadFrame)
+        assert not other._rx_stash
+
+
+@pytest.mark.parametrize("wire", ["tcp", "default"])
+def test_which_connections_a_cluster_runs_on(wire):
+    """A cluster told `ms_local_fastpath: False` (the benchmark's cells)
+    talks through CorkedWriter + FrameReceiver; a default vstart cluster
+    (most of the suite) hands messages over through LocalConnection."""
+    async def go():
+        from ceph_tpu.rados.messenger import (CorkedWriter, FrameReceiver,
+                                              LocalConnection)
+        from ceph_tpu.rados.vstart import Cluster
+        conf = {"osd_auto_repair": False}
+        if wire == "tcp":
+            conf["ms_local_fastpath"] = False
+        cluster = Cluster(n_osds=3, conf=conf)
+        await cluster.start()
+        try:
+            c = await cluster.client()
+            pool = await c.create_pool("p", profile={
+                "plugin": "jerasure", "technique": "reed_sol_van",
+                "k": "2", "m": "1"})
+            for i in range(4):
+                await c.put(pool, f"o{i}", b"x" * 65536)
+            msgrs = [o.messenger for o in cluster.osds.values()]
+            if wire == "tcp":
+                assert not any(m._local_conns for m in msgrs)
+                out = [conn for m in msgrs for conn in m._conns.values()
+                       if conn.out_seq > 2 and not conn.closed]
+                assert out and all(isinstance(conn.writer, CorkedWriter)
+                                   for conn in out)
+                served = [conn for m in msgrs
+                          for conn in m._sessions.values() if not conn.closed]
+                assert served and all(isinstance(conn.reader, FrameReceiver)
+                                      for conn in served)
+                assert all(m.perf.get("local_msgs") == 0 for m in msgrs)
+            else:
+                local = [conn for m in msgrs
+                         for conn in m._local_conns.values()]
+                assert local and all(isinstance(conn, LocalConnection)
+                                     for conn in local)
+                assert sum(m.perf.get("local_msgs") for m in msgrs) > 0
+                assert all(m.perf.get("tx_bytes") == 0 for m in msgrs)
+            await c.stop()
+        finally:
+            await cluster.stop()
+
+    asyncio.run(asyncio.wait_for(go(), 120))

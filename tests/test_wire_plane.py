@@ -1,22 +1,17 @@
-"""Sharded multi-reactor wire plane (reactor.py + messenger lane layer):
-reactor worker pool + stable-hash binding, multi-lane peer striping with
-gseq reassembly and fragmentation, per-(peer,type) ordering under fault
-injection, single-lane-dead failover, the negotiated colocated ring
-transport with TCP fallback, dump_reactors + its renderer, and the
-golden pre-lane frame compatibility rule."""
+"""The messenger's lane layer: multi-lane peer striping with gseq
+reassembly and fragmentation, per-(peer,type) ordering under fault
+injection, single-lane-dead failover, dump_reactors + its renderer, and
+the golden pre-lane frame compatibility rule."""
 
 from __future__ import annotations
 
 import asyncio
 import os
 
-import pytest
-
-from ceph_tpu.rados.messenger import (LaneGroup, Messenger, MLaneHello,
-                                      MLaneSegment, RingConnection,
-                                      _MSG_TYPES, decode_message,
-                                      encode_payload_parts, message)
-from ceph_tpu.rados.reactor import PROC_TOKEN, ReactorPool
+from ceph_tpu.rados.messenger import (PROC_TOKEN, LaneGroup, Messenger,
+                                      MLaneHello, MLaneSegment, _MSG_TYPES,
+                                      decode_message, encode_payload_parts,
+                                      message)
 
 
 # a striped test type mirroring the data-plane declaration pattern
@@ -46,58 +41,6 @@ async def _pair(conf_a=None, conf_b=None):
     await a.bind()
     addr_b = await b.bind()
     return a, b, tuple(addr_b)
-
-
-class TestReactorPool:
-    def test_stable_hash_binding(self):
-        pool = ReactorPool("t", 4)
-        addr = ("127.0.0.1", 6800)
-        w = pool.worker_for(addr, 2)
-        for _ in range(10):
-            assert pool.worker_for(addr, 2) is w
-        # different lanes spread over workers (blake2b of addr+lane)
-        owners = {pool.worker_for(addr, lane).index for lane in range(32)}
-        assert len(owners) > 1
-
-    def test_workers_run_own_loops(self):
-        pool = ReactorPool("t", 2)
-        pool.start()
-        try:
-            loops = {w.loop for w in pool.workers}
-            assert len(loops) == 2
-            for w in pool.workers:
-                assert w.is_alive()
-                assert w.loop.is_running()
-        finally:
-            pool.shutdown()
-        for w in pool.workers:
-            assert not w.is_alive()
-
-    def test_messenger_exchange_over_reactor_pool(self):
-        async def go():
-            a, b, addr_b = await _pair(
-                {"ms_async_op_threads": 2, "ms_lanes_per_peer": 3},
-                {"ms_async_op_threads": 2, "ms_lanes_per_peer": 3})
-            got = []
-            done = asyncio.Event()
-            async def disp(conn, msg):
-                # dispatch must land on the daemon's home loop even when
-                # the socket lives on a reactor thread
-                assert asyncio.get_running_loop() is b.home_loop
-                got.append(msg.seq)
-                if len(got) >= 64:
-                    done.set()
-            b.dispatcher = disp
-            for i in range(64):
-                await a.send(addr_b, MWire(seq=i, data=b"x" * 2048))
-            await asyncio.wait_for(done.wait(), 15)
-            assert got == list(range(64))
-            # data lanes were bound to reactor shards
-            workers = a.dump_reactors()["workers"]
-            assert sum(w["dialed"] for w in workers) >= 2
-            await a.shutdown()
-            await b.shutdown()
-        asyncio.run(go())
 
 
 class TestLaneStriping:
@@ -185,12 +128,11 @@ class TestLaneStriping:
             # strip the capability on the wire: monkeypatch the OUT side
             orig_out = a._handshake_out
 
-            async def patched(reader, writer, lossless, session_id,
-                              want_ring=False):
-                (peer_name, resumed, ckind, _lanes_ok, ring_id,
+            async def patched(reader, writer, lossless, session_id):
+                (peer_name, resumed, ckind, _lanes_ok,
                  r, w) = await orig_out(reader, writer, lossless,
-                                        session_id, want_ring)
-                return (peer_name, resumed, ckind, False, ring_id, r, w)
+                                        session_id)
+                return (peer_name, resumed, ckind, False, r, w)
             a._handshake_out = patched
             await a.send(addr_b, MWire(seq=0, data=b"z" * 2048))
             await asyncio.sleep(0.2)
@@ -263,91 +205,31 @@ class TestLaneOrderingUnderFaults:
         asyncio.run(go())
 
 
-class TestColocatedRing:
-    def test_ring_negotiated_and_zero_serialization(self):
+class TestLaneTeardown:
+    def test_group_close_returns_the_costs_its_fifo_holds(self):
+        """A message the pump has not dispatched yet holds its
+        dispatch-throttle cost; a group closed under it gives it back."""
         async def go():
-            a, b, addr_b = await _pair({"ms_colocated_ring": True},
-                                       {"ms_colocated_ring": True})
-            got = []
-            done = asyncio.Event()
-            async def disp(conn, msg):
-                assert isinstance(conn, RingConnection)
-                got.append(msg)
-                done.set()
-            b.dispatcher = disp
-            view = memoryview(b"ring-payload" * 100)
-            await a.send(addr_b, MWire(seq=1, data=view))
-            await asyncio.wait_for(done.wait(), 5)
-            assert isinstance(a._conns[addr_b], RingConnection)
-            # zero serialization: the blob arrives BY REFERENCE
-            assert got[0].data is view
-            assert a.perf.get("ring_msgs") == 1
-            await a.shutdown()
-            await b.shutdown()
-        asyncio.run(go())
+            from ceph_tpu.rados.messenger import Policy
 
-    def test_ring_replies_flow_back(self):
-        async def go():
-            a, b, addr_b = await _pair({"ms_colocated_ring": True},
-                                       {"ms_colocated_ring": True})
-            replies = []
-            done = asyncio.Event()
-            async def disp_b(conn, msg):
-                await conn.send(MWire(seq=msg.seq + 100))
-            async def disp_a(conn, msg):
-                replies.append(msg.seq)
-                done.set()
-            a.dispatcher = disp_a
-            b.dispatcher = disp_b
-            await a.send(addr_b, MWire(seq=5))
-            await asyncio.wait_for(done.wait(), 5)
-            assert replies == [105]
-            await a.shutdown()
-            await b.shutdown()
-        asyncio.run(go())
+            m = Messenger("t", {"ms_lanes_per_peer": 3})
+            group = LaneGroup(m, ("127.0.0.1", 1), "g" * 16, 3,
+                              outbound=False, policy=Policy.lossless_peer())
 
-    def test_fallback_to_tcp_when_negotiation_fails(self):
-        """Satellite: local-transport fallback — one side without the
-        knob means a plain TCP session, transparently."""
-        async def go():
-            a, b, addr_b = await _pair({"ms_colocated_ring": True},
-                                       {"ms_colocated_ring": False})
-            got = []
-            async def disp(conn, msg):
-                got.append(msg)
-            b.dispatcher = disp
-            await a.send(addr_b, MWire(seq=3, data=b"tcp" * 1000))
-            await asyncio.sleep(0.3)
-            conn = a._conns[addr_b]
-            assert not isinstance(conn, RingConnection)
-            assert a.perf.get("ring_msgs") == 0
-            assert len(got) == 1 and bytes(got[0].data) == b"tcp" * 1000
-            await a.shutdown()
-            await b.shutdown()
-        asyncio.run(go())
-
-    def test_fault_injection_disables_ring(self):
-        # a configuration that exercises the wire keeps real sockets
-        m = Messenger("x", {"ms_colocated_ring": True,
-                            "ms_inject_socket_failures": 5})
-        assert not m._ring_ok
-
-    def test_control_plane_isolated_by_copy(self):
-        async def go():
-            a, b, addr_b = await _pair({"ms_colocated_ring": True},
-                                       {"ms_colocated_ring": True})
-            got = []
-            done = asyncio.Event()
-            async def disp(conn, msg):
-                got.append(msg)
-                done.set()
-            b.dispatcher = disp
-            msg = MCtl(seq=9)  # no FIXED_FIELDS: control-plane rules
-            await a.send(addr_b, msg)
-            await asyncio.wait_for(done.wait(), 5)
-            assert got[0] is not msg and got[0].seq == 9
-            await a.shutdown()
-            await b.shutdown()
+            cost = 4096
+            await m.dispatch_throttle.get(cost)
+            msg = MWire(seq=0, data=b"x")
+            msg.gseq = 1  # in order: lands in the dispatch fifo
+            group.rx_push(None, msg, cost)
+            assert m.dispatch_throttle.current == cost  # held by the fifo
+            parked = MWire(seq=2, data=b"y")
+            parked.gseq = 3  # a hole before it: parks, its cost returns now
+            await m.dispatch_throttle.get(cost)
+            group.rx_push(None, parked, cost)
+            assert m.dispatch_throttle.current == cost
+            await group.close()
+            assert m.dispatch_throttle.current == 0
+            await m.shutdown()
         asyncio.run(go())
 
 
@@ -355,17 +237,15 @@ class TestWirePlaneIntrospection:
     def test_dump_reactors_shape_and_renderer(self):
         async def go():
             a, b, addr_b = await _pair(
-                {"ms_lanes_per_peer": 3, "ms_async_op_threads": 2},
-                {"ms_lanes_per_peer": 3})
+                {"ms_lanes_per_peer": 3}, {"ms_lanes_per_peer": 3})
             async def disp(conn, msg):
                 pass
             b.dispatcher = disp
             await a.send(addr_b, MWire(seq=0, data=b"d" * 4096))
             await asyncio.sleep(0.2)
             dump = a.dump_reactors()
-            assert dump["op_threads"] == 2
             assert dump["lanes_per_peer"] == 3
-            assert len(dump["workers"]) == 2
+            assert dump["wirepath"] in ("native", "python")
             assert len(dump["peers"]) == 1
             lanes = dump["peers"][0]["lanes"]
             assert [ln["lane"] for ln in lanes] == [0, 1, 2]
@@ -374,7 +254,7 @@ class TestWirePlaneIntrospection:
 
             lines = render_reactors(dump)
             text = "\n".join(lines)
-            assert "2 reactor workers" in text
+            assert "3 lanes/peer" in text
             assert "lane 0 [ctl ]" in text
             assert "lane 1 [data]" in text
             await a.shutdown()
@@ -449,9 +329,9 @@ class TestLaneWireCompat:
             assert getattr(msg, "gseq", 0) == 0
 
     def test_proc_token_stable_within_process(self):
-        from ceph_tpu.rados import reactor
+        from ceph_tpu.rados import messenger
 
-        assert reactor.PROC_TOKEN == PROC_TOKEN
+        assert messenger.PROC_TOKEN == PROC_TOKEN
         assert len(PROC_TOKEN) == 32
 
 
