@@ -22,16 +22,19 @@ d helpers (the MSR property): minimum_to_decode returns per-chunk
 sub-chunk semantics and why the OSD read path supports fragmented shard
 reads (reference ECBackend.cc:1049-1071).
 
-TPU note: every pft/mds application is a GF(2^8) matmul over sc_size-byte
-regions; planes with equal erasure signature share matrices, so plane loops
-batch naturally into the shared bit-plane kernel (future optimization; the
-inner codecs already dispatch through their own _apply seam).
+TPU note: a served put's encode runs on the BatchingQueue's "subchunk"
+lane (parallel/service.py, ops/gf2.encode_subchunk_fn: uncouple, the scalar
+code over every plane, couple, one device program over all stripes), for
+which `encode_geometry` hands out what the inner codecs hold; encode_chunks
+below stays the CPU path and the lane's second opinion.  Decode and the
+single-chunk repair are this file's, region by region through the inner
+codecs' own seams.
 """
 
 from __future__ import annotations
 
 import errno
-from typing import Dict, List, Mapping, Optional, Set, Tuple
+from typing import Dict, List, Mapping, NamedTuple, Optional, Set, Tuple
 
 import numpy as np
 
@@ -41,6 +44,19 @@ from ceph_tpu.ec.interface import ErasureCodeError, ErasureCodeProfile, SubChunk
 from ceph_tpu.ec.registry import ErasureCodePlugin
 
 DEFAULT_K, DEFAULT_M, DEFAULT_W = 4, 2, 8
+
+
+class EncodeGeometry(NamedTuple):
+    """What a device lane needs to encode this code without its codec."""
+
+    q: int
+    t: int
+    #: the pairwise transform over GF(2^8): (U, U*) = pair x (C, C*),
+    #: index 0 the node of a pair with the larger x; and its inverse
+    pair: np.ndarray
+    pair_inv: np.ndarray
+    #: the scalar MDS code's [m, k] coding matrix over GF(2^8)
+    generator: np.ndarray
 
 
 class ErasureCodeClay(ErasureCode):
@@ -56,6 +72,7 @@ class ErasureCodeClay(ErasureCode):
         self.sub_chunk_no = 0
         self.mds = None  # inner MDS codec over k+nu data, m coding
         self.pft = None  # inner 2+2 pairwise transform codec
+        self._encode_geometry: Optional[EncodeGeometry] = None
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -126,6 +143,7 @@ class ErasureCodeClay(ErasureCode):
             pft_profile["c"] = "2"
         self.mds = registry.factory(scalar_mds, self.directory, mds_profile)
         self.pft = registry.factory(scalar_mds, self.directory, pft_profile)
+        self._encode_geometry = self._linear_encode(scalar_mds)
 
         profile["plugin"] = self.plugin_name
         profile.setdefault("k", str(self.k))
@@ -148,6 +166,36 @@ class ErasureCodeClay(ErasureCode):
             -(-stripe_width // alignment) * alignment if stripe_width else alignment
         )
         return padded // self.k
+
+    def encode_geometry(self) -> Optional[EncodeGeometry]:
+        """The encode as one linear program over all planes at once, for
+        a lane that runs it (rados/ecutil._lane), or None where it is not
+        one round of the layered decode:
+
+          * nu > 0 (virtual chunks in the grid), or parities that are not
+            whole rows of it (m % q): then a plane's intersection score
+            varies, and planes of a higher score wait for pairs that
+            planes of a lower one complete;
+          * w != 8, or inner codecs that are not GF(2^8) matrix codes in
+            the byte layout (a packet-layout technique, shec): the lane
+            takes the two coding matrices, and nothing else of them."""
+        return self._encode_geometry
+
+    def _linear_encode(self, scalar_mds: str) -> Optional[EncodeGeometry]:
+        if self.nu or self.m % self.q or self.w != 8 or scalar_mds == "shec":
+            return None
+        for inner in (self.mds, self.pft):
+            if (getattr(inner, "bit_layout", None) != "byte"
+                    or getattr(inner, "matrix", None) is None
+                    or inner.w != 8):
+                return None
+        from ceph_tpu.ec.gf import gf
+
+        pair = np.asarray(self.pft.matrix, dtype=np.uint8)
+        return EncodeGeometry(
+            self.q, self.t, pair,
+            np.asarray(gf(8).invert_matrix(self.pft.matrix), dtype=np.uint8),
+            np.asarray(self.mds.matrix, dtype=np.uint8))
 
     # -- node/plane index helpers -------------------------------------------
 

@@ -12,7 +12,7 @@ Two data layouts feed it (see ceph_tpu/ec/codecs.py):
   * byte layout  (reed_sol codes): bit-row j*w+x = bit x of chunk j's bytes;
   * packet layout (cauchy/liberation): bit-row j*w+l = packet l of chunk j.
 
-THE FIVE LANES (ceph_tpu/parallel/service.py runs them, one row each in
+THE SIX LANES (ceph_tpu/parallel/service.py runs them, one row each in
 its LANES table; rados/ecutil.lane_for picks one per codec).  Every
 device program is plain XLA, correct on the CPU backend too.
 
@@ -44,6 +44,15 @@ device program is plain XLA, correct on the CPU backend too.
     packetrows          apply_packetrows_fn: packet layout; a packet IS a
                         bit-row, so the layout stages are block
                         transposes and no bit moves.
+
+  sub-chunk lane — a coupled-layer (CLAY) code's encode: no matrix of the
+  caller's but the code's geometry, and three stages on the packed-bit
+  plane words instead of one product.
+    subchunk            encode_subchunk_fn: bytes -> u32 plane words ->
+                        uncouple (a transpose, a 16x16 schedule, a mask)
+                        -> the scalar code's schedule over every plane ->
+                        couple -> bytes; one program per (geometry,
+                        chunk), no residents.
 
 SCHEDULE CSE (jerasure "smart scheduling" role): xor_schedule_program's
 greedy pairwise pass factors the term pair co-occurring in the most
@@ -566,6 +575,127 @@ def apply_packetrows_fn(bitmatrix: np.ndarray, w: int, packetsize: int):
 
     return _compiled_schedule(f"packetrows.{w}.{packetsize}", bitmatrix,
                               build)
+
+
+def subchunk_pair_bits(pair: np.ndarray) -> np.ndarray:
+    """The [16, 16] GF(2) bit-matrix of a coupled-layer code's 2x2 pairwise
+    transform `pair` (GF(2^8); index 0 of a pair is its node with the
+    larger x) over the inputs (a node's own 8 bit-rows, its partner's 8):
+    rows 0-7 are the node's result where it is the pair's index 0, rows
+    8-15 where it is index 1.  A node on the diagonal takes neither."""
+    from ceph_tpu.ec.matrices import matrix_to_bitmatrix
+
+    bm = matrix_to_bitmatrix(np.asarray(pair, dtype=np.int64), 8)
+    return np.vstack([bm[:8], np.hstack([bm[8:, 8:], bm[8:, :8]])]) \
+        .astype(np.uint8)
+
+
+def _pair_transform(row, y: int, q: int, ops, outs):
+    """One grid row of a coupled-layer code through its pairwise
+    transform.  `row` is [q(x), 8(bit), q(z_0) .. q(z_t-1), M] u32 plane
+    words; node x's partner in plane z is node z_y in the plane whose
+    y-th digit is x, i.e. `row` with its x axis and its z_y axis swapped,
+    so the whole row meets its partners in one transpose and the 16x16
+    schedule (subchunk_pair_bits) runs over all of it; which half of the
+    result a node takes is a static mask over (x, z_y)."""
+    partner = jnp.swapaxes(row, 0, 2 + y)
+    both = _schedule_apply(
+        ops, outs, 16,
+        [row[:, b] for b in range(8)] + [partner[:, b] for b in range(8)])
+    x = np.arange(q).reshape((q,) + (1,) * (row.ndim - 2))
+    zy = np.arange(q).reshape((q,) + (1,) * (row.ndim - 3 - y))
+    out = [jnp.where(x > zy, both[b], jnp.where(x < zy, both[8 + b],
+                                               row[:, b]))
+           for b in range(8)]
+    return jnp.stack(out, axis=1)
+
+
+def gf2_encode_subchunk(q: int, t: int, chunk: int, pair, pair_inv,
+                        generator, data) -> "jnp.ndarray":
+    """A coupled-layer (CLAY) code's encode of [k, n_stripes*chunk] uint8
+    rows to [m, n_stripes*chunk] parity rows, ONE fused jitted call
+    (encode_subchunk_fn)."""
+    return encode_subchunk_fn(q, t, chunk, pair, pair_inv, generator)(data)
+
+
+def encode_subchunk_fn(q: int, t: int, chunk: int, pair, pair_inv,
+                       generator):
+    """The compiled (LRU-cached) jitted call behind gf2_encode_subchunk,
+    one per (geometry, chunk); XLA compiles it once a staged width.
+
+    The code (Vajha et al., FAST 2018; ec/plugins/clay.py has the CPU
+    form): nodes on a q x t grid, node (x, y) = chunk y*q + x, a chunk
+    cut into q^t sub-chunks ("planes") whose index has the base-q digits
+    z_0 .. z_t-1.  With nu = 0 and k, m whole rows of the grid, the data
+    are rows y < k/q and the parities the rows above, every plane has the
+    same intersection score and the layered decode that IS the encode is
+    one round of three stages, all on u32 plane words between one
+    to_packedbit and one from_packedbit:
+
+      clay_uncouple  every data node's U: itself on the diagonal
+                     (z_y = x), else the 2x2 transform `pair` of it and
+                     its partner (_pair_transform: a transpose, a 16x16
+                     XOR schedule, a mask);
+      xor_apply      the scalar MDS code `generator` [m, k] over all
+                     planes of all stripes at once: the packed-bit lane's
+                     static XOR schedule over [k*8, words];
+      clay_couple    the parity rows' U back to C: the same transpose and
+                     `pair_inv`.
+
+    A constant of GF(2^8) is an 8x8 bit-matrix on bit-rows, so no stage
+    leaves the plane words.  The sub-chunk axis is brought in front of
+    the stripes first ([.., stripe, plane, word] -> [.., plane, stripe x
+    word]): the transposes then move whole rows of stripes and the minor
+    dimension stays as wide as the batch."""
+    from ceph_tpu.ec.matrices import matrix_to_bitmatrix
+
+    generator = np.asarray(generator, dtype=np.int64)
+    m, k = generator.shape
+    if k % q or m % q or k + m != q * t:
+        raise ValueError(f"k={k} m={m} are not whole rows of a {q}x{t} grid")
+    n_planes = q ** t
+    if chunk % (n_planes * 32):
+        raise ValueError(f"a chunk of {chunk} B is not {n_planes} "
+                         "sub-chunks of whole u32 bit-plane words")
+    scw = chunk // n_planes // 32  # plane words a sub-chunk
+
+    def planes_first(p, rows):
+        # [rows*8, S*planes*scw] -> [rows/q, q, 8, q.., S*scw]
+        s = p.shape[1] // (n_planes * scw)
+        p = p.reshape(rows * 8, s, n_planes, scw).transpose(0, 2, 1, 3)
+        return p.reshape((rows // q, q, 8) + (q,) * t + (s * scw,))
+
+    def build(ops, outs):
+        unc = xor_schedule_program(subchunk_pair_bits(pair))[:2]
+        cpl = xor_schedule_program(subchunk_pair_bits(pair_inv))[:2]
+
+        @jax.jit
+        def _clay_encode(x):
+            with jax.named_scope("to_packedbit"):
+                planes = _bits_to_words(unpack_bits_bytes(x, 8))
+            with jax.named_scope("clay_uncouple"):
+                c = planes_first(planes, k)
+                u = jnp.stack([_pair_transform(c[y], y, q, *unc)
+                               for y in range(k // q)])
+            with jax.named_scope("xor_apply"):
+                pu = _schedule_apply(ops, outs, k * 8,
+                                     u.reshape(k * 8, -1))
+            with jax.named_scope("clay_couple"):
+                pu = pu.reshape((m // q, q, 8) + u.shape[3:])
+                pc = jnp.stack([_pair_transform(pu[j], k // q + j, q, *cpl)
+                                for j in range(m // q)])
+                s = pc.shape[-1] // scw
+                pc = (pc.reshape(m * 8, n_planes, s, scw)
+                      .transpose(0, 2, 1, 3).reshape(m * 8, -1))
+            with jax.named_scope("from_packedbit"):
+                return pack_bits_bytes(_words_to_bits(pc), 8, m)
+
+        return _clay_encode
+
+    tag = (f"subchunk.{q}.{t}.{chunk}."
+           + np.asarray(pair, dtype=np.uint8).tobytes().hex()
+           + np.asarray(pair_inv, dtype=np.uint8).tobytes().hex())
+    return _compiled_schedule(tag, matrix_to_bitmatrix(generator, 8), build)
 
 
 def pack_bitplanes_u32(data: np.ndarray, w: int = 8) -> np.ndarray:

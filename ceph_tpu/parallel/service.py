@@ -20,7 +20,7 @@ tests and by timed sections).
 LANES: a request names its lane (`kind`), and LANES below holds one row
 per lane — the device program, its numpy mirror, the fan-out shape, the
 column alignment and the submit-time check.  Every lane takes packed
-[n, B] uint8 rows; they differ in the layout stages around the GF(2)
+[n, B] uint8 rows; they differ in the stages around the GF(2)
 product and in what comes back (ceph_tpu/ops/gf2.py has the kernels and
 their measurements; rados/ecutil.lane_for picks the lane for a codec).
 A request hands its rows over as that [n, B] array, or NAMES them in
@@ -44,10 +44,19 @@ bucket are that one pass, on the queue's thread:
                         ([n, nb, w, p] <-> [n*w, nb*p]) around the same
                         schedule; whole w*packetsize blocks, the width
                         buckets to a power of two of blocks; no residents
+    subchunk            coupled-layer (CLAY) codes, encode: the request
+                        carries no bit-matrix but the code's geometry
+                        (subchunk_geometry: q, t, the 2x2 pairwise
+                        transform both ways, the scalar code's generator)
+                        and, where the packet lane has its packet size,
+                        the chunk; three stages on u32 plane words
+                        (uncouple, the packed-bit schedule, couple) in
+                        one program; whole chunks, the width buckets to a
+                        power of two of chunks; no residents
 
 Encode generators and the inverted bit-matrices of decode signatures ride
 the same lanes (ecutil's plans), so no served op dispatches from the
-event loop.
+event loop; a sub-chunk code's decode and repair stay its codec's.
 
 DEVICE-DISPATCH CIRCUIT BREAKER (the robustness layer): every lane owns a
 breaker with three states.  CLOSED: dispatches go to the device; one that
@@ -282,6 +291,74 @@ def _mirror_packetrows(mb, data, w, out_rows, packetsize):
             .reshape(out_rows, cols))
 
 
+# -- the sub-chunk lane's request: a code's geometry instead of a matrix ------
+
+_GEOMETRY_HEAD = 10  # q, t, the pair transform (4), its inverse (4)
+
+
+def subchunk_geometry(q: int, t: int, pair, pair_inv,
+                      generator) -> np.ndarray:
+    """What a "subchunk" request carries where the other lanes carry a
+    bit-matrix: a coupled-layer code's geometry as ONE uint8 array, so
+    that requests of one code group by its bytes like requests of one
+    matrix — [m, 10 + k]: row 0 opens with q, t, the 2x2 pairwise
+    transform over GF(2^8) (U pair from C pair, index 0 the node with
+    the larger x) and its inverse, row-major; columns 10.. of every row
+    are the scalar MDS code's [m, k] generator."""
+    generator = np.asarray(generator, dtype=np.uint8)
+    geom = np.zeros((generator.shape[0], _GEOMETRY_HEAD + generator.shape[1]),
+                    dtype=np.uint8)
+    geom[0, :_GEOMETRY_HEAD] = [
+        q, t, *np.asarray(pair, dtype=np.uint8).reshape(4),
+        *np.asarray(pair_inv, dtype=np.uint8).reshape(4)]
+    geom[:, _GEOMETRY_HEAD:] = generator
+    return geom
+
+
+def _read_geometry(geom: np.ndarray):
+    """(q, t, pair [2, 2], pair_inv [2, 2], generator [m, k]) of a
+    subchunk_geometry array."""
+    head = np.asarray(geom[0, :_GEOMETRY_HEAD], dtype=np.uint8)
+    return (int(head[0]), int(head[1]), head[2:6].reshape(2, 2),
+            head[6:10].reshape(2, 2),
+            np.asarray(geom[:, _GEOMETRY_HEAD:], dtype=np.uint8))
+
+
+def _np_pair_transform(row: np.ndarray, y: int, q: int,
+                       pair: np.ndarray) -> np.ndarray:
+    """Mirror of ops/gf2._pair_transform on bytes: `row` is [q(x), S,
+    q(z_0) .. q(z_t-1), sc]; a node and its partner (the x axis and the
+    z_y axis swapped) go through the 2x2 transform, as index 0 where
+    x > z_y and as index 1 where x < z_y; the diagonal stays."""
+    from ceph_tpu.ec.gf import gf  # numpy only, as this module is
+
+    mul, partner = gf(8).mul_region, np.swapaxes(row, 0, 2 + y)
+    (a, b), (c, d) = np.asarray(pair, dtype=np.int64)
+    as_first = mul(a, row) ^ mul(b, partner)
+    as_second = mul(c, partner) ^ mul(d, row)
+    x = np.arange(q).reshape((q,) + (1,) * (row.ndim - 1))
+    zy = np.arange(q).reshape((q,) + (1,) * (row.ndim - 3 - y))
+    return np.where(x > zy, as_first, np.where(x < zy, as_second, row))
+
+
+def _mirror_subchunk(geom, data, w, out_rows, chunk):
+    # mirror of ops/gf2.encode_subchunk_fn: uncouple the data rows of the
+    # grid, the scalar code over every plane, couple the parity rows
+    from ceph_tpu.ec.gf import gf
+
+    q, t, pair, pair_inv, generator = _read_geometry(geom)
+    k, cols = data.shape
+    grid = (cols // chunk,) + (q,) * t + (chunk // q ** t,)
+    c = data.reshape((k // q, q) + grid)
+    u = np.stack([_np_pair_transform(c[y], y, q, pair)
+                  for y in range(k // q)])
+    pu = gf(8).matmul(generator, u.reshape((k,) + grid))
+    pu = pu.reshape((out_rows // q, q) + grid)
+    pc = np.stack([_np_pair_transform(pu[j], k // q + j, q, pair_inv)
+                   for j in range(out_rows // q)])
+    return pc.reshape(out_rows, cols)
+
+
 # -- the lanes' device programs: (group, staged batch) -> device result ------
 # ops/gf2.py imports jax; this module stays importable without it.
 
@@ -314,6 +391,14 @@ def _device_packetrows(g, batch):
     from ceph_tpu.ops.gf2 import gf2_apply_packetrows
 
     return gf2_apply_packetrows(g.mbits, batch, g.w, g.packetsize)
+
+
+def _device_subchunk(g, batch):
+    from ceph_tpu.ops.gf2 import gf2_encode_subchunk
+
+    q, t, pair, pair_inv, generator = _read_geometry(g.mbits)
+    return gf2_encode_subchunk(q, t, g.packetsize, pair, pair_inv,
+                               generator, batch)
 
 
 # -- a request's rows, named before they are laid out -------------------------
@@ -404,10 +489,17 @@ def _check_packetrows(regions, w, packetsize=0):
             f"-byte blocks, got width {regions.shape[1]}")
 
 
+def _check_subchunk(regions, w, chunk=0):
+    _check_packedbit(regions, w)
+    if chunk < 1 or regions.shape[1] % chunk:
+        raise ValueError(f"subchunk requests are whole chunks of {chunk} "
+                         f"bytes, got width {regions.shape[1]}")
+
+
 class Lane(NamedTuple):
     """All the queue knows about one lane.  A request is packed [n, B]
     uint8 rows (or a StripeRows that names them) under a [out_rows*w,
-    n*w] GF(2) bit-matrix."""
+    n*w] GF(2) bit-matrix (on "subchunk": a code's geometry)."""
 
     #: the lane's one fused program over a staged batch (async: returns a
     #: device handle)
@@ -418,9 +510,12 @@ class Lane(NamedTuple):
     #: fan-out shape: [out_rows, B] bytes, or (bytes, resident bit-rows
     #: [(n+out_rows)*w, ...] that stay on the device)
     resident: bool = False
-    #: column unit a staged batch pads to; None = the group's w*packetsize
+    #: column unit a staged batch pads to; None = the group's own, `block`
     align: Optional[int] = 1
     check: Optional[Callable] = None
+    #: the column unit of a group whose lane has no fixed one, from its w
+    #: and the sixth field of its requests (a packet size; a chunk)
+    block: Callable = lambda w, packetsize: w * packetsize
 
 
 LANES: Dict[str, Lane] = {
@@ -435,6 +530,9 @@ LANES: Dict[str, Lane] = {
         resident=True, align=32, check=_check_packedbit_resident),
     "packetrows": Lane(_device_packetrows, _mirror_packetrows, align=None,
                        check=_check_packetrows),
+    # whole chunks: a bucket's pad is zero chunks, which encode to zero
+    "subchunk": Lane(_device_subchunk, _mirror_subchunk, align=None,
+                     check=_check_subchunk, block=lambda w, chunk: chunk),
 }
 
 
@@ -444,7 +542,7 @@ def staged_cols(kind: str, w: int, packetsize: int, cols: int) -> int:
     unit is its w*packetsize block, the others' divides the 1024-column
     floor, so theirs is the plain pow2 width; ops/gf2.bucket_columns is
     the same policy) — what bounds XLA recompiles across object sizes."""
-    align = LANES[kind].align or w * packetsize
+    align = LANES[kind].align or LANES[kind].block(w, packetsize)
     units, bucket = -(-cols // align), max(1, 1024 // align)
     while bucket < units:
         bucket <<= 1
@@ -516,9 +614,10 @@ class _Group:
     mbits: np.ndarray
     w: int
     out_rows: int
-    # the lane (a key of LANES) and, on the packet-layout lane, its packet size
+    # the lane (a key of LANES) and, where the lane's column unit is the
+    # group's own, what Lane.block makes it from
     kind: str = "packed"
-    packetsize: int = 0  # packet layout only
+    packetsize: int = 0  # packet layout; on "subchunk" the chunk
     requests: List[_Request] = field(default_factory=list)
     pending_bytes: int = 0
 
@@ -667,6 +766,8 @@ class BatchingQueue:
                out_rows: int, kind: str = "packed", packetsize: int = 0,
                *, span=None) -> Future:
         """Queue ONE lane request: the [out_rows*w, n*w] bit-matrix `mbits`
+        (on "subchunk" the code's subchunk_geometry, and its chunk where
+        the packet lane has its packet size)
         over packed [n, B] uint8 `regions` on lane `kind` (LANES).  The
         future resolves to the [out_rows, B] parity/reconstruction bytes,
         or on a resident lane to (those bytes, the data ‖ parity bit-rows
@@ -1151,7 +1252,7 @@ class BatchingQueue:
         import jax
 
         lane = LANES[g.kind]
-        align = lane.align or g.w * g.packetsize
+        align = lane.align or lane.block(g.w, g.packetsize)
         widths = [req.regions.shape[1] for req in g.requests]
         cols = sum(widths)
         staged = np.empty(
@@ -1174,7 +1275,7 @@ class BatchingQueue:
                           (staged.shape[1] - cols) * staged.shape[0])
         batch, nbytes = staged, staged.nbytes
         self.perf.inc("h2d_bytes", nbytes)
-        if g.packetsize and g.packetsize % 4 == 0:
+        if g.kind == "packetrows" and g.packetsize % 4 == 0:
             # a packet is XORed whole, so the device gets it as u32 words
             # when its size allows
             batch, align = batch.view(np.uint32), align // 4

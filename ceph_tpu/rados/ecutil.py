@@ -31,7 +31,7 @@ import numpy as np
 
 from ceph_tpu.common import tracing
 from ceph_tpu.common.perf_counters import PerfCountersBuilder
-from ceph_tpu.parallel.service import StripeRows
+from ceph_tpu.parallel.service import StripeRows, subchunk_geometry
 from ceph_tpu.rados.extent_cache import keepable
 
 
@@ -144,7 +144,9 @@ def concat_safe(codec) -> bool:
     packet (bitmatrix) family operates per w*packetsize block — both
     divide chunks into units the per-stripe alignment already respects.
     Only sub-chunk codecs (CLAY) derive intra-chunk structure from the
-    TOTAL chunk size and must be driven stripe by stripe."""
+    TOTAL chunk size and must be driven stripe by stripe: by their codec
+    on the CPU paths, and as whole chunks on the queue's "subchunk" lane
+    (_lane), which is told the chunk and keeps the stripes apart."""
     try:
         return codec.get_sub_chunk_count() == 1
     except Exception:
@@ -185,7 +187,11 @@ def lane_for(codec, resident: bool = False, cols: int = 0):
     """THE rule for which BatchingQueue lane (a key of
     parallel/service.LANES) applies a bit-matrix for this codec, and the
     dtype that lane takes the matrix in: (kind, dtype), or None where no
-    lane does.  The codec's bit_layout and w decide, nothing else:
+    lane does.  The codec's bit_layout and w decide, and its sub-chunk
+    count, nothing else:
+    a sub-chunk code (CLAY) whose encode is one linear round
+    (`encode_geometry`) rides "subchunk" with that geometry in the
+    matrix's place, keeps no residents, and has no lane otherwise;
     packet-layout codes (cauchy/liberation family) ride "packetrows", the
     packed-bit lane whose layout stages are block transposes, and keep no
     residents; w=8 byte-layout codes the packed-bit pair (static XOR
@@ -195,6 +201,9 @@ def lane_for(codec, resident: bool = False, cols: int = 0):
     resident encode and the tpu plugin's direct seam all ask here."""
     from ceph_tpu.ops.gf2 import packedbit_enabled
 
+    if codec.get_sub_chunk_count() > 1:
+        linear = getattr(codec, "encode_geometry", lambda: None)()
+        return None if resident or linear is None else ("subchunk", np.uint8)
     if getattr(codec, "bit_layout", "byte") == "packet":
         return None if resident else ("packetrows", np.uint8)
     if (getattr(codec, "w", 8) == 8 and not (resident and cols % 32)
@@ -205,24 +214,42 @@ def lane_for(codec, resident: bool = False, cols: int = 0):
 
 def _lane(codec, sinfo: StripeInfo):
     """The lane this codec's encode/decode plans ride, as lane_for's
-    (kind, dtype) plus the packet size on the packet-layout lane.  None
-    when no lane takes the codec: a chunk remap, or chunks that are not
-    whole w*packetsize blocks."""
+    (kind, dtype) plus the packet size on the packet-layout lane and the
+    chunk on the sub-chunk lane.  None when no lane takes the codec: a
+    chunk remap, chunks that are not whole w*packetsize blocks, a
+    sub-chunk code whose encode is not one linear round (lane_for) or
+    whose sub-chunks are not whole u32 plane words."""
     if codec.get_chunk_mapping():
         return None
     lane = lane_for(codec)
+    if lane is None:
+        return None
     if lane[0] == "packetrows":
         if sinfo.chunk_size % (codec.w * codec.packetsize):
             return None
         return (*lane, codec.packetsize)
+    if lane[0] == "subchunk":
+        if sinfo.chunk_size % (codec.get_sub_chunk_count() * 32):
+            return None
+        return (*lane, sinfo.chunk_size)
     return lane
 
 
+def _encode_matrix(codec, lane):
+    """What an encode request of `codec` carries on `lane` (_lane's):
+    the code's geometry on the sub-chunk lane, else the codec's bit
+    generator, or None where it has none."""
+    if lane[0] == "subchunk":
+        return subchunk_geometry(*codec.encode_geometry())
+    return codec.bit_generator()
+
+
 def _lane_item(lane, codec, bitmatrix, rows, out_rows: int):
-    """The lane request for applying `bitmatrix` (an encode generator
-    or an inverted decode signature) to `[n, n_stripes*chunk]` rows (or
-    the StripeRows that names them), as BatchingQueue.submit /
-    submit_group take it: (mbits, rows, w, out_rows, kind[, packetsize])."""
+    """The lane request for applying `bitmatrix` (an encode generator,
+    an inverted decode signature, a sub-chunk code's geometry) to
+    `[n, n_stripes*chunk]` rows (or the StripeRows that names them), as
+    BatchingQueue.submit / submit_group take it: (mbits, rows, w,
+    out_rows, kind[, packetsize])."""
     kind, dtype, *packetsize = lane
     return (np.asarray(bitmatrix).astype(dtype), rows,
             getattr(codec, "w", 8), out_rows, kind, *packetsize)
@@ -250,15 +277,16 @@ def _stripe_rows(sinfo: StripeInfo, data, queued: bool = True):
 @tracing.sectioned("ecplan", "encode_plan")
 def _encode_plan_parts(codec, sinfo: StripeInfo, data):
     """The submit-free half of the queue encode plan for the non-empty
-    buffer `data`: when the codec is batchable (a bit seam, no chunk
-    remap), returns (item, reassemble) — the lane request (_lane_item) a
+    buffer `data`: when the codec is batchable (a bit seam or a
+    sub-chunk geometry, no chunk remap), returns (item, reassemble) —
+    the lane request (_lane_item) a
     caller hands to BatchingQueue.submit or (with several buffers) to
     submit_group as one whole-stripe-group handoff, and what turns that
     request's result into the per-shard blob list.  None when the queue
     path does not apply."""
-    mbits = codec.bit_generator()
-    lane = _lane(codec, sinfo) if mbits is not None else None
-    if lane is None:
+    lane = _lane(codec, sinfo)
+    mbits = _encode_matrix(codec, lane) if lane is not None else None
+    if mbits is None:
         return None
     k = codec.get_data_chunk_count()
     m = codec.get_chunk_count() - k
@@ -287,11 +315,12 @@ def _encode_plan_parts(codec, sinfo: StripeInfo, data):
 
 def _queue_encode_plan(codec, sinfo: StripeInfo, data, queue, span=None):
     """When the codec/queue combination is batchable (a bit seam, byte or
-    packet layout, no chunk remap), submit the whole non-empty buffer as
+    packet layout, or a sub-chunk code with a linear encode; no chunk
+    remap), submit the whole non-empty buffer as
     ONE queue request and return (future, reassemble) — reassemble turns
     the future's result into the per-shard blob list.  None when the
-    queue path does not apply (mapped or sub-chunk codecs, codecs without
-    a bit seam)."""
+    queue path does not apply (mapped codecs, codecs without a bit seam,
+    sub-chunk geometries _lane refuses)."""
     parts = _encode_plan_parts(codec, sinfo, data)
     if parts is None:
         return None
@@ -309,11 +338,12 @@ def batched_encode(codec, sinfo: StripeInfo, data: bytes,
     call: the buffer is re-interleaved into per-shard rows
     (`[k, n_stripes*chunk]`) and the codec transforms all stripes at once
     — through the shared BatchingQueue when one is provided (byte- and
-    packet-layout codecs alike: _lane), else through encode_chunks (one
+    packet-layout codecs, and CLAY on its sub-chunk lane: _lane), else
+    through encode_chunks (one
     direct device dispatch for plugin=tpu, on the caller's thread).
     Byte-identical
     to the per-stripe loop for every concat-safe codec (see concat_safe);
-    CLAY takes the per-stripe path.  Returns one concatenated per-shard
+    without a queue CLAY takes the per-stripe path.  Returns one concatenated per-shard
     buffer each, `[n_shards][n_stripes*chunk]`, in physical shard order.
 
     Blocking variant (tests/benchmark); daemons on an event loop use
@@ -325,8 +355,9 @@ def batched_encode(codec, sinfo: StripeInfo, data: bytes,
     assert sinfo.k == k
     if queue is not None and len(data):
         # the interface's bit seam drives ANY byte- or packet-layout
-        # codec through the queue's lanes; mapped and sub-chunk codecs
-        # take the encode_chunks/per-stripe paths below.
+        # codec through the queue's lanes, a sub-chunk code's geometry
+        # its own lane; mapped codecs and whatever _lane refuses take
+        # the encode_chunks/per-stripe paths below.
         # Single-stripe objects ride the queue too — coalescing across
         # OBJECTS/ops is the point (SURVEY.md §7.5), and small concurrent
         # writes are exactly the dispatch-latency-bound workload.
@@ -391,8 +422,9 @@ async def batched_encode_group_async(codec, sinfo: StripeInfo, buffers,
     submits that only the delay window may happen to coalesce.
 
     Returns the per-buffer shard lists, index-aligned with ``buffers``.
-    Buffers the queue plan cannot take (mapped or sub-chunk codecs, empty
-    objects, no queue) fall back to the plain batched_encode path."""
+    Buffers the queue plan cannot take (mapped codecs, geometries _lane
+    refuses, empty objects, no queue) fall back to the plain
+    batched_encode path."""
     import asyncio
 
     out: List[Optional[List[np.ndarray]]] = [None] * len(buffers)

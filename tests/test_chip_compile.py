@@ -135,6 +135,23 @@ def test_packedbit_converters(one_chip, width):
              out_rows=n)
 
 
+@pytest.mark.parametrize("width", BOTH_WIDTHS)
+def test_encode_subchunk(one_chip, width):
+    """The sub-chunk lane's program (PR 52) for clay k=8 m=4 d=11 at the
+    cell's chunk, 4096 B of 64 sub-chunks: the compiler takes the three
+    stages' transposes at both widths (here 5 s at the dispatch width,
+    24 s at one object's, temp 1.3 GB and 0.27 GB)."""
+    from ceph_tpu.ec.registry import registry
+    from ceph_tpu.ops.gf2 import encode_subchunk_fn
+
+    codec = registry.factory("clay", "", {"plugin": "clay", "k": "8",
+                                          "m": "4", "d": "11"})
+    g = codec.encode_geometry()
+    _compile(encode_subchunk_fn(g.q, g.t, 4096, g.pair, g.pair_inv,
+                                g.generator),
+             _spec((8, WIDTHS[width]), np.uint8, one_chip))
+
+
 def test_xor_packed_planes_decode(one_chip, mats):
     """The resident decode: the 3-erasure signature over u32 plane words."""
     from ceph_tpu.ops.gf2 import xor_packed_fn

@@ -14,7 +14,9 @@ from ceph_tpu.common.perf_counters import (PerfCountersBuilder,
                                            PerfCountersCollection)
 from ceph_tpu.common.tracing import Tracer
 from ceph_tpu.ec.matrices import matrix_to_bitmatrix, vandermonde_coding_matrix
-from ceph_tpu.parallel.service import LANES, BatchingQueue
+from ceph_tpu.ec.registry import registry
+from ceph_tpu.parallel.service import (LANES, BatchingQueue,
+                                       subchunk_geometry)
 from ceph_tpu.rados.pagestore import PagedResidentStore
 
 K, M, W = 2, 1, 8
@@ -87,8 +89,15 @@ class TestEcTpuCounters:
         q = BatchingQueue(max_delay=60.0)  # worker idle: flush() drives
         try:
             rows = _rows()
-            # B is whole W*16-byte blocks for the packet-layout lane
-            futs = [q.submit(_bm(np.int8 if lane in ("packed", "resident")
+            # B is whole W*16-byte blocks for the packet-layout lane, and
+            # one chunk of 4 sub-chunks for the sub-chunk lane, whose
+            # request carries a code's geometry (clay k=2 m=2 d=3)
+            clay = registry.factory("clay", "", {"plugin": "clay", "k": "2",
+                                                 "m": "2", "d": "3"})
+            futs = [q.submit(subchunk_geometry(*clay.encode_geometry()),
+                             rows, W, 2, lane, B) if lane == "subchunk"
+                    else
+                    q.submit(_bm(np.int8 if lane in ("packed", "resident")
                                  else np.uint8),
                              rows, W, M, lane,
                              16 if lane == "packetrows" else 0)

@@ -1105,7 +1105,7 @@ class TestFramer:
     ("rx_copy_share.put",
      ["k8m3.write4m", "k4m2.write4m", "k10m4c.write4m",
       "k8m3.mixed-small", "k8m3.rbd-randwrite4k",
-      "k8m3.write4m-bluestore"], "put_MBps"),
+      "k8m3.write4m-bluestore", "k8m4clay.write4m"], "put_MBps"),
     ("rx_copy_share.get", ["k8m3.randread4m", "k8m3.randread4m-cold"],
      "get_MBps"),
 ])

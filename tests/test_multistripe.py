@@ -635,7 +635,8 @@ class TestExtentCache:
 
 WRITE_CELLS = ["k8m3.write4m", "k4m2.write4m", "k10m4c.write4m",
                "k8m3.mixed-small",  # puts beside gets and deletes (PR 38)
-               "k8m3.write4m-bluestore"]  # the same puts on a disk store
+               "k8m3.write4m-bluestore",  # the same puts on a disk store
+               "k8m4clay.write4m"]  # ... and on the regenerating code
 READ_CELLS = ["k8m3.randread4m", "k8m3.randread4m-cold"]
 
 

@@ -648,7 +648,7 @@ def test_draws_counts_exactly_the_misses():
     ("crush_draws_per_op.put",
      ["k8m3.write4m", "k4m2.write4m", "k10m4c.write4m",
       "k8m3.mixed-small", "k8m3.rbd-randwrite4k",
-      "k8m3.write4m-bluestore"]),
+      "k8m3.write4m-bluestore", "k8m4clay.write4m"]),
     ("crush_draws_per_op.get", ["k8m3.randread4m", "k8m3.randread4m-cold"]),
 ])
 def test_the_engagement_metric_reads_draws_per_op(name, cells):

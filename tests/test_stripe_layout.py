@@ -27,7 +27,8 @@ K, M, CHUNK = 4, 2, 256
 PACKETSIZE = 16
 
 #: lane -> (profile beyond k/m, the plan that rides it, its numpy
-#: reference or None): the five lanes as rados/ecutil.lane_for picks them
+#: reference or None): the five lanes that take a bit-matrix, as
+#: rados/ecutil.lane_for picks them (the sixth: tests/test_clay_lane.py)
 LANE_PLANS = {
     "packedbit": ({"technique": "reed_sol_van", "w": "8"}, "bytes",
                   reed_sol_van),
